@@ -1,10 +1,10 @@
-"""auto_accelerate measured ON THE CHIP -> AUTO_r05.json (VERDICT r4
-item #6): the full search loop — enumerate -> analytic rank ->
+"""auto_accelerate measured ON THE CHIP -> AUTO.json (an earlier chip
+run's record is not reproduced): the full search loop — enumerate -> analytic rank ->
 measured dryruns -> warm start on a second run — executed against real
 hardware for the flagship config, with the trace archived: candidates
 considered, dryruns spent, the chosen strategy, and how it compares to
 the hand-picked bench config (bench.py: ddp + dots_attn_out @ batch 3
-x seq 2048, the measured 56.7% MFU point).
+x seq 2048, 56.7% MFU in an earlier chip run, not reproduced).
 
 Run:  python benchmarks/auto_search.py              # on the chip
       JAX_PLATFORMS=cpu python benchmarks/auto_search.py   # dev run
@@ -24,7 +24,7 @@ sys.path.insert(0, REPO)
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--out", default=os.path.join(REPO, "AUTO_r05.json"))
+    ap.add_argument("--out", default=os.path.join(REPO, "AUTO.json"))
     ap.add_argument("--dryrun-top-k", type=int, default=3)
     ap.add_argument("--model", choices=["llama", "dlrm"],
                     default="llama",

@@ -1,4 +1,5 @@
-"""Long-context artifact -> LONGCTX_r05.json (VERDICT r4 Weak #3/#4).
+"""Long-context artifact -> LONGCTX.json (an earlier chip run's
+record is not reproduced).
 
 Three sections:
   --envelope   on the real chip: the single-chip points (batch x seq
@@ -15,7 +16,7 @@ Three sections:
                charges as exposed).
 
 Run all three (sp16k + project always run; --envelope needs the chip):
-  python benchmarks/longctx.py --envelope --out LONGCTX_r05.json
+  python benchmarks/longctx.py --envelope --out LONGCTX.json
 Parity: atorch distributed_attention.py:21,79 (the reference's
 sequence-parallel long-context path).
 """
@@ -164,7 +165,7 @@ def main(argv=None) -> int:
     ap.add_argument("--envelope", action="store_true",
                     help="measure the single-chip points (needs TPU)")
     ap.add_argument("--out", default=os.path.join(
-        REPO, "LONGCTX_r05.json"
+        REPO, "LONGCTX.json"
     ))
     args = ap.parse_args(argv)
 

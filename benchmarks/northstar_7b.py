@@ -72,11 +72,11 @@ MODELS = {
         ],
     },
 }
-#: single-chip compute efficiency measured on real TPU in round 4
-#: (56.8% MFU, llama-1b, dots_attn_out remat — attention residuals
-#: saved outside the checkpointed segments — Pallas flash attention,
-#: bf16 rope; PROFILE_STEP_r04.json) — the prior the step-time model
-#: extrapolates from
+#: single-chip compute efficiency of an earlier chip run, not
+#: reproduced (56.8% MFU, llama-1b, dots_attn_out remat — attention
+#: residuals saved outside the checkpointed segments — Pallas flash
+#: attention, bf16 rope) — the prior the step-time model extrapolates
+#: from
 MEASURED_MFU_PRIOR = 0.568
 
 

@@ -41,9 +41,8 @@ def main(argv=None):
     )
     ap.add_argument(
         "--cache-dir", default=None,
-        help="tuning cache dir (default: env "
-        "DLROVER_TPU_TUNING_CACHE_DIR, else the tmpfs default "
-        "next to the compile cache)",
+        help="tuning cache dir (default: tuning/ below the compile "
+        "cache dir, common/cachedir.py)",
     )
     args = ap.parse_args(argv)
 

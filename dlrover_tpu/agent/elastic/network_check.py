@@ -58,16 +58,10 @@ num_processes = int(os.environ.get("{NPROC}", "1"))
 process_id = int(os.environ.get("{PID}", "0"))
 if num_processes > 1:
     if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
-        # same contract as trainer/distributed.py: older jax does not
-        # default CPU cross-process collectives to gloo, and without
-        # it the probe dies with "Multiprocess computations aren't
-        # implemented on the CPU backend"
-        try:
-            jax.config.update(
-                "jax_cpu_collectives_implementation", "gloo"
-            )
-        except Exception:
-            pass  # newer jax: gloo is already the default
+        # same contract as trainer/distributed.py
+        jax.config.update(
+            "jax_cpu_collectives_implementation", "gloo"
+        )
     jax.distributed.initialize(coordinator, num_processes, process_id)
     x = jnp.ones((1024 * 1024,), dtype=jnp.float32)
     from jax.sharding import Mesh, PartitionSpec as P, NamedSharding

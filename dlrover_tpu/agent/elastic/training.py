@@ -28,6 +28,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
 from dlrover_tpu.agent.master_client import MasterClient
+from dlrover_tpu.common.cachedir import ENV_JAX_CACHE_DIR, resolve_cache_dir
 from dlrover_tpu.common.constants import (
     NodeAction,
     NodeEnv,
@@ -429,39 +430,10 @@ class ElasticTrainingAgent:
             process_id=process_id, num_processes=num_processes,
             restart_count=self._restart_count,
         )
-        env = dict(os.environ)
-        env.update(self._config.env)
-        env[NodeEnv.COORDINATOR_ADDR] = coordinator
-        env[NodeEnv.PROCESS_ID] = str(process_id)
-        env[NodeEnv.NUM_PROCESSES] = str(num_processes)
-        env[NodeEnv.NODE_RANK] = str(self._config.node_rank)
-        env[NodeEnv.NODE_ID] = str(self._config.node_rank)
-        env[NodeEnv.NODE_NUM] = str(len(world))
-        env[NodeEnv.RESTART_COUNT] = str(self._restart_count)
-        env[NodeEnv.RDZV_ROUND] = str(rdzv_round)
-        env[NodeEnv.MASTER_ADDR] = self._client.master_addr
-        # every worker this agent spawns shares one host-local
-        # compilation cache that OUTLIVES the worker process: a
-        # same-topology restart (crash, hang recovery, preemption
-        # resume) re-jits from disk instead of re-compiling — the warm
-        # half of the <60s failover budget (trainer/compile_cache.py)
-        from dlrover_tpu.trainer.compile_cache import (
-            default_cache_dir,
+        env = self._worker_env(
+            rdzv_round, len(world), process_id, num_processes,
+            coordinator,
         )
-
-        env.setdefault(
-            NodeEnv.COMPILE_CACHE_DIR, default_cache_dir()
-        )
-        # Make the framework importable in the spawned process even when it
-        # is not pip-installed and the entrypoint lives in another directory
-        # (``python script.py`` puts the script's dir on sys.path, not cwd).
-        pkg_root = os.path.dirname(
-            os.path.dirname(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__)))))
-        parts = [p for p in env.get("PYTHONPATH", "").split(os.pathsep) if p]
-        if pkg_root not in parts:
-            # appended, so user PYTHONPATH overrides still take precedence
-            env["PYTHONPATH"] = os.pathsep.join(parts + [pkg_root])
         cmd = [self._config.entrypoint] + list(self._config.args)
         if cmd[0].endswith(".py"):
             cmd = [sys.executable] + cmd
@@ -473,6 +445,43 @@ class ElasticTrainingAgent:
             cmd, env=env, start_new_session=True
         )
         self._restart_count += 1
+
+    def _worker_env(self, rdzv_round: int, node_num: int,
+                    process_id: int, num_processes: int,
+                    coordinator: str) -> Dict[str, str]:
+        """The environment of the next worker incarnation."""
+        env = dict(os.environ)
+        env.update(self._config.env)
+        env[NodeEnv.COORDINATOR_ADDR] = coordinator
+        env[NodeEnv.PROCESS_ID] = str(process_id)
+        env[NodeEnv.NUM_PROCESSES] = str(num_processes)
+        env[NodeEnv.NODE_RANK] = str(self._config.node_rank)
+        env[NodeEnv.NODE_ID] = str(self._config.node_rank)
+        env[NodeEnv.NODE_NUM] = str(node_num)
+        env[NodeEnv.RESTART_COUNT] = str(self._restart_count)
+        env[NodeEnv.RDZV_ROUND] = str(rdzv_round)
+        env[NodeEnv.MASTER_ADDR] = self._client.master_addr
+        # every worker this agent spawns shares one host-local
+        # compilation cache that OUTLIVES the worker process: a
+        # same-topology restart (crash, hang recovery, preemption
+        # resume) re-jits from disk instead of re-compiling — the warm
+        # half of the <60s failover budget (trainer/compile_cache.py).
+        # Placed by JAX's own variable: as given where the job set it,
+        # else the fixed default in the checkout (common/cachedir.py)
+        cache_dir = resolve_cache_dir()
+        if cache_dir:
+            env[ENV_JAX_CACHE_DIR] = cache_dir
+        # Make the framework importable in the spawned process even when it
+        # is not pip-installed and the entrypoint lives in another directory
+        # (``python script.py`` puts the script's dir on sys.path, not cwd).
+        pkg_root = os.path.dirname(
+            os.path.dirname(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__)))))
+        parts = [p for p in env.get("PYTHONPATH", "").split(os.pathsep) if p]
+        if pkg_root not in parts:
+            # appended, so user PYTHONPATH overrides still take precedence
+            env["PYTHONPATH"] = os.pathsep.join(parts + [pkg_root])
+        return env
 
     def _monitor_workers(self) -> RunResult:
         if self._proc is None:
@@ -503,26 +512,39 @@ class ElasticTrainingAgent:
         self._initialize_workers()
 
     def _kill_workers(self, grace: float = 10.0):
-        if self._proc is None or self._proc.poll() is not None:
-            return
+        """Stop the worker AND its coworker children (one session
+        group). A worker that died on its own leaves its children
+        behind; the next incarnation must find chip and shm free, so
+        the group is waited out even when the leader is already dead."""
+        if self._proc is None or self._wait_worker_group_gone(0):
+            return  # nothing left to signal (and its pid may be reused)
         self._signal_worker_group(signal.SIGTERM)
-        try:
-            self._proc.wait(timeout=grace)
-        except subprocess.TimeoutExpired:
+        if not self._wait_worker_group_gone(grace):
             self._signal_worker_group(signal.SIGKILL)
-            self._proc.wait()
+            self._wait_worker_group_gone(grace)
 
     def _signal_worker_group(self, sig):
         """Signal the worker's own session group (start_new_session at
-        spawn) so coworker children die with the trainer; fall back to
-        the single pid if the group is already gone."""
+        spawn, so pgid == the worker's pid) so coworker children die
+        with the trainer; fall back to the single pid if the group is
+        already gone."""
         try:
-            os.killpg(os.getpgid(self._proc.pid), sig)
+            os.killpg(self._proc.pid, sig)
         except (ProcessLookupError, PermissionError, OSError):
             try:
                 self._proc.send_signal(sig)
             except (ProcessLookupError, OSError):
                 pass
+
+    def _wait_worker_group_gone(self, timeout: float) -> bool:
+        deadline = time.monotonic() + timeout
+        while True:
+            self._proc.poll()  # reap the leader
+            if not _group_has_live_member(self._proc.pid):
+                return True
+            if time.monotonic() >= deadline:
+                return False
+            time.sleep(0.05)
 
     def _report_failure(self, result: RunResult):
         self._client.report_failure(
@@ -539,6 +561,23 @@ class ElasticTrainingAgent:
         if self._metrics_server is not None:
             self._metrics_server.stop()
             self._metrics_server = None
+
+
+def _group_has_live_member(pgid: int) -> bool:
+    """True while any process of group ``pgid`` still runs (zombies
+    awaiting a reaper hold nothing and do not count)."""
+    for name in os.listdir("/proc"):
+        if not name.isdigit():
+            continue
+        try:
+            with open(f"/proc/{name}/stat") as f:
+                # "pid (comm) state ppid pgrp ..."; comm may hold ")"
+                state, _, pgrp = f.read().rsplit(")", 1)[1].split()[:3]
+        except (OSError, ValueError, IndexError):
+            continue  # exited between listdir and open
+        if int(pgrp) == pgid and state not in "ZX":
+            return True
+    return False
 
 
 def launch_agent(config: ElasticLaunchConfig,

@@ -25,8 +25,8 @@ BYTES = {"bf16": 2, "fp32": 4}
 # "dots_attn_out" = dots plus the attention custom_vjp residuals
 # (q,k,v,o,lse) saved outside the checkpointed segments — more live
 # activation bytes than dots, but the backward never re-runs the
-# attention forward kernel (measured on v5e: 52.99% -> 56.8% MFU at
-# the same batch; see bench.py / PROFILE_STEP_r04.json)
+# attention forward kernel (52.99% -> 56.8% MFU at the same batch in
+# an earlier chip run, not reproduced)
 ACT_FACTOR = {
     "off": 30.0, "dots": 12.0, "dots_attn_out": 16.0, "minimal": 2.5,
 }

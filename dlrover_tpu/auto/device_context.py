@@ -20,7 +20,10 @@ import jax
 
 from dlrover_tpu.common.log import default_logger as logger
 
-#: peak dense bf16 TFLOP/s per chip by TPU generation (public specs)
+#: peak dense bf16 TFLOP/s per chip by TPU generation. v5e: Google
+#: Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at
+#: 819 GB/s per chip); the other rows are the same pages' figures for
+#: their generations
 PEAK_TFLOPS = {
     "v4": 275.0,
     "v5e": 197.0,
@@ -30,7 +33,7 @@ PEAK_TFLOPS = {
     "v6": 918.0,
 }
 
-#: HBM bytes per chip by generation
+#: HBM bytes per chip by generation (same source)
 HBM_BYTES = {
     "v4": 32e9,
     "v5e": 16e9,
@@ -40,8 +43,11 @@ HBM_BYTES = {
     "v6": 32e9,
 }
 
-_DEFAULT_PEAK = 459.0e12  # assume v5p class when unknown
-_DEFAULT_HBM = 95e9
+#: what a device that is NOT a TPU (the CPU test mesh, fake devices in
+#: strategy-search tests) is sized as, so the search has numbers to
+#: rank with. No TPU run reaches these: an unknown TPU kind raises.
+NON_TPU_STAND_IN_PEAK = 459.0e12
+NON_TPU_STAND_IN_HBM = 95e9
 
 
 def _kind_key(device) -> Optional[str]:
@@ -49,17 +55,23 @@ def _kind_key(device) -> Optional[str]:
     for key in PEAK_TFLOPS:
         if key in kind:
             return key
+    if getattr(device, "platform", "") == "tpu":
+        raise ValueError(
+            f"TPU device kind {device.device_kind!r} is not in the "
+            "peak table (auto/device_context.py); add its published "
+            "figures instead of guessing"
+        )
     return None
 
 
 def peak_flops_per_chip(device) -> float:
     key = _kind_key(device)
-    return PEAK_TFLOPS[key] * 1e12 if key else _DEFAULT_PEAK
+    return PEAK_TFLOPS[key] * 1e12 if key else NON_TPU_STAND_IN_PEAK
 
 
 def hbm_bytes_per_chip(device) -> float:
     key = _kind_key(device)
-    return HBM_BYTES[key] if key else _DEFAULT_HBM
+    return HBM_BYTES[key] if key else NON_TPU_STAND_IN_HBM
 
 
 @dataclasses.dataclass(frozen=True)

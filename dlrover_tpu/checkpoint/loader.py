@@ -422,20 +422,23 @@ def _leaf_value(fetcher: _Fetcher, leaf: Dict[str, Any],
     domain and landed onto the target sharding."""
     import jax
 
+    from dlrover_tpu.trainer.checkpoint import target_sharding
+
+    sharding = target_sharding(target)
     pkey = mf.path_key(leaf["path"])
     kind = leaf.get("kind")
     if kind == "py":
         return leaf.get("v")
     if kind == "array":
         arr = fetcher.get(pkey, "full", leaf.get("replicas"))
-        if target is not None and isinstance(target, jax.Array):
-            return jax.device_put(arr, target.sharding)
+        if sharding is not None:
+            return jax.device_put(arr, sharding)
         return arr
     if kind != "shards":
         raise ckpt_store.ArchiveError(f"unknown leaf kind {kind!r}")
     shape = tuple(int(n) for n in leaf["shape"])
-    if target is not None and isinstance(target, jax.Array):
-        needed = target.sharding.addressable_devices_indices_map(shape)
+    if sharding is not None:
+        needed = sharding.addressable_devices_indices_map(shape)
         assembled: Dict[str, np.ndarray] = {}
         arrays = []
         for dev, idx in needed.items():
@@ -447,7 +450,7 @@ def _leaf_value(fetcher: _Fetcher, leaf: Dict[str, Any],
                 )
             arrays.append(jax.device_put(assembled[ikey], dev))
         return jax.make_array_from_single_device_arrays(
-            shape, target.sharding, arrays
+            shape, sharding, arrays
         )
     return _gather_domain(fetcher, leaf, pkey, _full_domain(shape))
 

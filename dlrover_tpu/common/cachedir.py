@@ -1,10 +1,19 @@
-"""Private host-local cache directories (shared hardening logic).
+"""Where the host-local caches live, and the hardening of the default.
 
 Two subsystems persist host-local state that a restarted worker will
 TRUST: the XLA compile cache (deserialized executables,
 trainer/compile_cache.py) and the kernel tuning cache (block-size
-decisions, ops/tuning.py). Both live under world-writable roots
-(/dev/shm, /tmp), so both need the same two defenses:
+decisions, ops/tuning.py, in ``tuning/`` below the compile cache).
+
+Placement has one knob, JAX's own: where ``JAX_COMPILATION_CACHE_DIR``
+is set, every process of a job (launcher, agent, worker, restarted
+worker) keeps its caches there, as given, and no code names another
+directory. Where it is not set, the default is one fixed directory in
+the checkout — the path is part of the compile cache's key, so a
+directory that moves never hits — and the agent exports it to its
+workers under JAX's variable.
+
+The default gets two defenses before anything is loaded from it:
 
  - never adopt a directory owned by another uid (a pre-created trap
    would let another local user seed entries we load);
@@ -16,17 +25,29 @@ decisions, ops/tuning.py). Both live under world-writable roots
 
 import os
 import stat
-import tempfile
 from typing import Optional
 
 from dlrover_tpu.common.log import default_logger as logger
 
+#: JAX's own variable; jax.config reads it when jax is imported
+ENV_JAX_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
 
-def default_cache_base() -> str:
-    """tmpfs when available: survives process restarts, not host
-    replacement (a replacement host has different devices anyway)."""
-    return "/dev/shm" if os.path.isdir("/dev/shm") else (
-        tempfile.gettempdir()
+_CHECKOUT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
+
+
+def default_cache_dir() -> str:
+    """The fixed, git-ignored cache directory inside the checkout."""
+    return os.path.join(_CHECKOUT, ".jax_cache")
+
+
+def resolve_cache_dir() -> Optional[str]:
+    """The directory this process keeps its caches in: JAX's variable
+    as given, else the hardened default (None if it cannot be
+    trusted)."""
+    return os.environ.get(ENV_JAX_CACHE_DIR) or ensure_private_dir(
+        default_cache_dir()
     )
 
 

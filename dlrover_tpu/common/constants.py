@@ -148,13 +148,6 @@ class NodeEnv:
     RDZV_ROUND = "DLROVER_TPU_RDZV_ROUND"
     # data sharding
     AUTO_SHARDING = "DLROVER_TPU_AUTO_SHARDING"
-    # host-local persistent XLA compilation cache directory shared by
-    # every worker incarnation on this host (trainer/compile_cache.py);
-    # "off" disables
-    COMPILE_CACHE_DIR = "DLROVER_TPU_COMPILE_CACHE_DIR"
-    # host-local persistent kernel tuning cache, co-located with the
-    # compile cache (ops/tuning.py); "off" disables persistence
-    TUNING_CACHE_DIR = "DLROVER_TPU_TUNING_CACHE_DIR"
     # seconds of reclaim notice a preempted node can count on; the
     # drain sequence (fault_tolerance/drain.py) budgets its emergency
     # checkpoint + shard relinquish inside this window

@@ -10,6 +10,7 @@ keeps N batches in flight to the TPU with ``jax.device_put`` (dispatch is
 async in JAX — overlap comes free; the buffer bounds host memory).
 """
 
+import contextlib
 import multiprocessing as mp
 import os
 import threading
@@ -20,6 +21,23 @@ import jax
 
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.data.shm_ring import RingClosed, ShmRing
+
+
+@contextlib.contextmanager
+def _spawn_env_pinned_to_cpu():
+    """A spawned coworker re-imports the training script as
+    ``__mp_main__``, jax and all. Its parent holds the chip, and a
+    chip belongs to one process: whatever the child does with jax, it
+    does on the CPU. (jax read the parent's own value at import.)"""
+    was = os.environ.get("JAX_PLATFORMS")
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    try:
+        yield
+    finally:
+        if was is None:
+            del os.environ["JAX_PLATFORMS"]
+        else:
+            os.environ["JAX_PLATFORMS"] = was
 
 
 def _producer_main(ring_name: str, dataset_fn, worker_id: int,
@@ -77,8 +95,9 @@ class ShmDataLoader:
             )
             for w in range(num_workers)
         ]
-        for p in self._procs:
-            p.start()
+        with _spawn_env_pinned_to_cpu():
+            for p in self._procs:
+                p.start()
         self._watcher = threading.Thread(
             target=self._close_when_done, daemon=True,
             name="shm-ring-watcher",

@@ -244,9 +244,9 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     f32 and each output element is one mul-add of unit-magnitude
     factors, so bf16 rotation adds at most half-ulp noise PER ELEMENT
     (no accumulation chain) — while an f32 rope forces the q/k
-    projections to materialize f32 copies to HBM. Measured on v5e
-    (PROFILE_STEP_r04.json): the f32 rope fusion alone was 10.3 ms of a
-    595 ms step, 1.7% of device time for zero accuracy benefit."""
+    projections to materialize f32 copies to HBM. An earlier chip run,
+    not reproduced, put the f32 rope fusion alone at 1.7% of device
+    time for zero accuracy benefit."""
     x1, x2 = jnp.split(x, 2, axis=-1)
     c = cos[None, :, None, :].astype(x.dtype)
     s = sin[None, :, None, :].astype(x.dtype)

@@ -80,7 +80,9 @@ def flash_attention(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
 ) -> jax.Array:
-    """Memory-efficient attention: Pallas kernel on TPU, XLA elsewhere.
+    """Memory-efficient attention: Pallas kernel on TPU; off the TPU
+    (CPU tests, rehearsals) the dense reference, which no TPU run
+    reaches at a shape the kernel takes.
 
     Layout [batch, seq, heads, head_dim] (the models' native layout).
     ``block_q``/``block_k`` cap the kernel block sizes (None = tuned);
@@ -89,36 +91,37 @@ def flash_attention(
 
     Block selection lives in ops/tuning.py: the persisted on-device
     autotuner answers from its cache (or measures once per shape per
-    host on TPU), with the old static largest-power-of-two heuristic
-    as the prior and the only path off-TPU. Selection runs at trace
-    time — by the time XLA sees the program the blocks are static.
+    host on TPU, outside this trace), with the static
+    largest-power-of-two heuristic as the prior and the only path
+    off-TPU. By the time XLA sees the program the blocks are static.
     """
-    if _use_pallas(q, k):
-        from dlrover_tpu.ops import tuning
-        from dlrover_tpu.ops.pallas.flash_attention import (
-            flash_attention_tpu,
-        )
+    if not _use_pallas(q, k):
+        return mha_reference(q, k, v, causal=causal, scale=scale)
+    from dlrover_tpu.ops import tuning
+    from dlrover_tpu.ops.pallas.flash_attention import (
+        flash_attention_tpu,
+    )
 
-        seq = q.shape[1]
-        g = q.shape[2] // k.shape[2]
-        blocks = tuning.get_blocks(
-            seq=seq,
-            head_dim=q.shape[3],
-            group=g,
-            dtype=jnp.dtype(q.dtype).name,
-            causal=causal,
-            block_q=block_q,
-            block_k=block_k,
+    blocks = tuning.get_blocks(
+        seq=q.shape[1],
+        head_dim=q.shape[3],
+        group=q.shape[2] // k.shape[2],
+        dtype=jnp.dtype(q.dtype).name,
+        causal=causal,
+        block_q=block_q,
+        block_k=block_k,
+    )
+    if blocks is None:
+        # a dense [s, s] fallback here would pass every check and
+        # cost the run its memory and its speed in silence
+        raise ValueError(
+            f"no kernel blocks tile seq={q.shape[1]} under caps "
+            f"block_q={block_q} block_k={block_k}"
         )
-        if blocks is None:
-            # caller capped blocks below the kernel's 128-lane minimum
-            # (or nothing divides seq) — XLA path is always correct
-            return mha_reference(q, k, v, causal=causal, scale=scale)
-        bq, bk = blocks
-        return flash_attention_tpu(
-            q, k, v, causal=causal, scale=scale, block_q=bq, block_k=bk,
-        )
-    return mha_reference(q, k, v, causal=causal, scale=scale)
+    bq, bk = blocks
+    return flash_attention_tpu(
+        q, k, v, causal=causal, scale=scale, block_q=bq, block_k=bk,
+    )
 
 
 def _use_pallas(q: jax.Array, k: jax.Array) -> bool:
@@ -130,3 +133,39 @@ def _use_pallas(q: jax.Array, k: jax.Array) -> bool:
     d = q.shape[-1]
     s = q.shape[1]
     return d % 64 == 0 and s % 128 == 0 and k.shape[1] == s
+
+
+def make_sharded_attention(mesh, q_spec, kv_spec, causal: bool = True):
+    """An ``attn_fn(q, k, v)`` for a GSPMD-partitioned step: the same
+    ``flash_attention`` under ``shard_map``, because a Pallas kernel
+    cannot be partitioned automatically. ``q_spec``/``kv_spec`` split
+    the batch dim (and the heads dim over a tensor axis); the sequence
+    stays whole, so no collective is needed inside. A dim its mesh
+    axes do not divide is left whole instead."""
+    from jax.sharding import PartitionSpec as P
+
+    from dlrover_tpu.parallel.compat import shard_map
+
+    def fit(spec, shape):
+        parts = []
+        for dim, axes in zip(shape, tuple(spec) + (None,) * 4):
+            names = (axes,) if isinstance(axes, str) else (axes or ())
+            n = 1
+            for a in names:
+                n *= mesh.shape[a]
+            parts.append(axes if dim % n == 0 else None)
+        return parts
+
+    def attn_fn(q, k, v):
+        qp, kp = fit(q_spec, q.shape), fit(kv_spec, k.shape)
+        if qp[2] is None or kp[2] is None:
+            qp[2] = kp[2] = None  # heads split together or not at all
+        kp[0] = qp[0]
+        qs, ks = P(*qp), P(*kp)
+        return shard_map(
+            functools.partial(flash_attention, causal=causal),
+            mesh=mesh, in_specs=(qs, ks, ks), out_specs=qs,
+            check_vma=False,
+        )(q, k, v)
+
+    return attn_fn

@@ -1,48 +1,3 @@
-"""Version-tolerant ``shard_map``: one import site for the whole repo.
+"""One import site for ``shard_map`` (``jax.shard_map``, ``check_vma``)."""
 
-The codebase targets jax>=0.8 (``jax.shard_map`` with ``check_vma``),
-but deployment images pin older jaxlib builds where shard_map still
-lives in ``jax.experimental.shard_map`` and the replication-check
-keyword is ``check_rep``. Every parallel module imports from here so
-the skew is absorbed in exactly one place.
-"""
-
-import inspect
-
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # pre-0.6 jax keeps shard_map under experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_ACCEPTS_CHECK_VMA = "check_vma" in inspect.signature(
-    _shard_map
-).parameters
-
-
-def abstract_mesh(axis_sizes, axis_names):
-    """``jax.sharding.AbstractMesh`` across the signature change: new
-    jax takes (sizes, names), pre-0.6 takes one ((name, size), ...)
-    shape tuple."""
-    from jax.sharding import AbstractMesh
-
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-
-
-def shard_map(f=None, **kwargs):
-    """``jax.shard_map`` with new-style kwargs translated for old jax:
-    ``check_vma`` -> ``check_rep``, and ``axis_names`` (the manual
-    axes) -> ``auto`` (its complement over the mesh axes)."""
-    if not _ACCEPTS_CHECK_VMA:
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        if "axis_names" in kwargs:
-            manual = frozenset(kwargs.pop("axis_names"))
-            kwargs["auto"] = (
-                frozenset(kwargs["mesh"].axis_names) - manual
-            )
-    if f is None:
-        return _shard_map(**kwargs)
-    return _shard_map(f, **kwargs)
+from jax import shard_map  # noqa: F401

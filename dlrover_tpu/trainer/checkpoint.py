@@ -235,6 +235,15 @@ def _local_shards(pytree):
     return _materialize_staged(_stage_local_shards(pytree))
 
 
+def target_sharding(target):
+    """The sharding a restore lands a leaf on: a concrete array's, or
+    an abstract ``ShapeDtypeStruct``'s (a target that holds no second
+    state on the device). None = leave the leaf on the host."""
+    if isinstance(target, (jax.Array, jax.ShapeDtypeStruct)):
+        return target.sharding
+    return None
+
+
 def _restore_shards(snapshot, target=None):
     """Rebuild arrays from local-shard snapshots. With a ``target`` pytree of
     sharded arrays (same treedef), restores onto the target's shardings;
@@ -244,8 +253,8 @@ def _restore_shards(snapshot, target=None):
     def rebuild(snap, tgt=None):
         if isinstance(snap, dict) and snap.get("__jax_shards__"):
             shards = snap["shards"]
-            if tgt is not None and isinstance(tgt, jax.Array):
-                sharding = tgt.sharding
+            sharding = target_sharding(tgt)
+            if sharding is not None:
                 # index is a tuple of slices; key by repr for hashability
                 per_index = {repr(i): d for i, d in shards}
                 full = None

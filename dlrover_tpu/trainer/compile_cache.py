@@ -10,114 +10,65 @@ traces the same program over the same mesh, so the compile is 100%
 redundant; JAX's persistent compilation cache turns it into a
 disk read.
 
-Deployment shape: the agent points every worker it spawns at a
-host-local tmpfs directory (``/dev/shm``) that OUTLIVES the worker
-process — a restarted worker hits the executables its predecessor
-compiled. The cache key covers the HLO, the compile options, and the
-device topology, so a world-size change after elasticity simply misses
-the cache and compiles fresh (correct, just cold); a same-topology
-restart — the common failover case: process crash, hang recovery,
-preemption resume on the same hosts — hits it.
+Deployment shape: every worker an agent spawns shares one host-local
+directory that OUTLIVES the worker process — a restarted worker hits
+the executables its predecessor compiled. Where it lives is
+common/cachedir.py's one rule: ``JAX_COMPILATION_CACHE_DIR`` as given,
+else a fixed directory in the checkout. The cache key covers the HLO,
+the compile options, and the device topology, so a world-size change
+after elasticity simply misses the cache and compiles fresh (correct,
+just cold); a same-topology restart — the common failover case:
+process crash, hang recovery, preemption resume on the same hosts —
+hits it.
 
-Measured effect is recorded in ``FAILOVER_r05.json``
-(benchmarks/failover_warm.py): restart→first-new-step, cold vs warm,
-on the real chip.
+Cold vs warm restart→first-new-step: an earlier chip run, not
+reproduced; ``chip_smoke.py``'s resume phase prints both.
 """
 
+import contextlib
 import os
-from typing import Optional
+from typing import Dict, Iterator, Optional
 
 from dlrover_tpu.common.cachedir import (
-    default_cache_base,
-    ensure_private_dir,
+    ENV_JAX_CACHE_DIR,
+    resolve_cache_dir,
 )
-from dlrover_tpu.common.constants import NodeEnv
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.telemetry import counter, gauge, record
 
-#: env contract (agent -> worker); value "off" disables the cache
-ENV_CACHE_DIR = NodeEnv.COMPILE_CACHE_DIR
 #: compiles faster than this are not cached (jax's default 1s floor
 #: would skip small-but-many programs whose SUM is the restart tax)
 ENV_MIN_COMPILE_SECS = "DLROVER_TPU_COMPILE_CACHE_MIN_SECS"
 
-_DISABLED = ("off", "none", "0", "")
-#: force-arm the cache on a jax the safety gate would refuse
-ENV_FORCE = "DLROVER_TPU_COMPILE_CACHE_FORCE"
 
+def setup_compilation_cache() -> Optional[str]:
+    """Enable jax's persistent compilation cache; returns the directory
+    or None when the default cannot be trusted.
 
-def _persistent_cache_safe() -> bool:
-    """Old jaxlib builds (<0.6) SEGFAULT re-loading serialized
-    executables from the persistent cache (observed on 0.4.37: a
-    restarted worker dies rc=-11 at its first jit, turning the warm
-    path this cache exists to accelerate into a crash loop). Refuse to
-    arm the cache there; ``DLROVER_TPU_COMPILE_CACHE_FORCE=1``
-    overrides for builds known locally to be fine."""
-    if os.getenv(ENV_FORCE, "") == "1":
-        return True
+    ``JAX_COMPILATION_CACHE_DIR``, where set, is used as given — jax
+    read it at import and this function names no other directory.
+    Unset, the cache goes to the fixed directory in the checkout,
+    after the ownership check (entries are executables this process
+    will LOAD: train cold instead of trusting a loose dir). Must run
+    before the first ``jit`` executes — ``init_from_env`` calls it, so
+    agent-launched workers get it for free; standalone scripts can
+    call it directly.
+    """
     import jax
 
-    try:
-        major, minor = (int(x) for x in jax.__version__.split(".")[:2])
-    except ValueError:
-        return True  # unparseable dev version: assume modern
-    return (major, minor) >= (0, 6)
-
-
-def default_cache_dir() -> str:
-    """Host-local tmpfs so the cache survives process restarts but not
-    host replacement (a replacement host has different devices anyway).
-    Per-uid suffix: cache entries are DESERIALIZED EXECUTABLES, so a
-    fixed path under world-writable /dev/shm would let another local
-    user pre-create it and seed attacker-controlled entries
-    (setup_compilation_cache additionally enforces ownership+0700)."""
-    return os.path.join(
-        default_cache_base(), f"dlrover_tpu_compile_cache_{os.getuid()}"
-    )
-
-
-def setup_compilation_cache(
-    cache_dir: Optional[str] = None,
-) -> Optional[str]:
-    """Enable jax's persistent compilation cache; returns the directory
-    (created if needed) or None when disabled.
-
-    Resolution order: explicit arg > ``DLROVER_TPU_COMPILE_CACHE_DIR``
-    > the tmpfs default. Must run before the first ``jit`` executes —
-    ``init_from_env`` calls it, so agent-launched workers get it for
-    free; standalone scripts can call it directly.
-    """
+    cache_dir = resolve_cache_dir()
     if cache_dir is None:
-        cache_dir = os.getenv(ENV_CACHE_DIR)
-    if cache_dir is None:
-        cache_dir = default_cache_dir()
-    if cache_dir.strip().lower() in _DISABLED:
-        logger.info("persistent compilation cache disabled")
-        return None
-    if not _persistent_cache_safe():
-        logger.warning(
-            "persistent compilation cache disabled: this jax build "
-            "cannot reload serialized executables safely (set %s=1 "
-            "to override)", ENV_FORCE,
-        )
-        return None
-    # entries are executables this process will LOAD: refuse a dir
-    # someone else owns (exist_ok would happily adopt a pre-created
-    # trap under a shared /dev/shm or /tmp) and force 0700 on adopted
-    # dirs (common/cachedir.py) — train cold instead of trusting loose
-    if ensure_private_dir(cache_dir) is None:
         logger.error("compilation cache disabled (untrusted dir)")
         return None
-    import jax
-
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if not os.environ.get(ENV_JAX_CACHE_DIR):
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update(
         "jax_persistent_cache_min_compile_time_secs",
         float(os.getenv(ENV_MIN_COMPILE_SECS, "0.1")),
     )
     # size floor off: the restart path re-runs EVERY program, small
-    # ones included (the dir lives on tmpfs; jax_compilation_cache_max_size
-    # stays at its default, bounding growth)
+    # ones included (jax_compilation_cache_max_size stays at its
+    # default, bounding growth)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     logger.info("persistent compilation cache at %s", cache_dir)
     global _armed_dir, _armed_entries
@@ -131,6 +82,31 @@ def setup_compilation_cache(
         "compile_cache.armed", dir=cache_dir, entries=_armed_entries,
     )
     return cache_dir
+
+
+@contextlib.contextmanager
+def cache_events() -> Iterator[Dict[str, int]]:
+    """Count jax's own persistent-cache events inside the block:
+    ``{"requests": n, "hits": m}`` for the programs compiled there
+    (a request that is not a hit compiled). Wrap
+    exactly the compile that matters (the train step's) — a restored
+    worker also compiles small programs its predecessor never did,
+    so a directory-wide count would read its warm step as a miss."""
+    from jax import monitoring
+
+    seen = {"requests": 0, "hits": 0}
+
+    def listener(event, **_):
+        if event == "/jax/compilation_cache/compile_requests_use_cache":
+            seen["requests"] += 1
+        elif event == "/jax/compilation_cache/cache_hits":
+            seen["hits"] += 1
+
+    monitoring.register_event_listener(listener)
+    try:
+        yield seen
+    finally:
+        monitoring.unregister_event_listener(listener)
 
 
 def cache_entries(cache_dir: str) -> int:

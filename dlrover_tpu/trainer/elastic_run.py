@@ -51,13 +51,6 @@ def parse_args(argv=None):
                         help="pre-flight host/chip health check")
     parser.add_argument("--standalone", action="store_true",
                         help="self-host a local master subprocess")
-    parser.add_argument("--compile_cache_dir", type=str,
-                        default=os.getenv(NodeEnv.COMPILE_CACHE_DIR, ""),
-                        help="persistent XLA compilation cache dir "
-                             "(host-local tmpfs; restarted workers "
-                             "re-jit from disk). Default: "
-                             "/dev/shm/dlrover_tpu_compile_cache; "
-                             "'off' disables")
     parser.add_argument("--master_addr", type=str,
                         default=os.getenv(NodeEnv.MASTER_ADDR, ""))
     parser.add_argument("--relay_fanout", type=int,
@@ -91,6 +84,8 @@ def launch_local_master(node_num: int = 1) -> Tuple[subprocess.Popen, str]:
         ],
         stdout=subprocess.PIPE,
         text=True,
+        # the worker needs the chip: the master never touches one
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
     )
     port = None
     deadline = time.time() + 30
@@ -150,8 +145,6 @@ def run(args) -> int:
         args=entry_args,
         env={NodeEnv.MASTER_ADDR: master_addr},
     )
-    if args.compile_cache_dir:
-        config.env[NodeEnv.COMPILE_CACHE_DIR] = args.compile_cache_dir
     relay_tier: Optional[RelayTier] = None
     if args.relay_fanout > 0:
         # hierarchical fan-in (ISSUE 16/18): the tier is sized to the

@@ -25,19 +25,6 @@ from dlrover_tpu.parallel import sharding as shd
 from dlrover_tpu.parallel.mesh import create_mesh
 
 
-def _donation_reshards_safely() -> bool:
-    """True when this jax can donate an input whose sharding differs
-    from the output's (resharding donation landed around 0.6; before
-    that XLA fails the compile with an INTERNAL aliasing error)."""
-    try:
-        major, minor = (
-            int(x) for x in jax.__version__.split(".")[:2]
-        )
-    except ValueError:
-        return True  # unparseable dev version: assume modern
-    return (major, minor) >= (0, 6)
-
-
 class ShardedTrainer:
     """Builds sharded init / train-step functions for a pytree model.
 
@@ -126,6 +113,29 @@ class ShardedTrainer:
         with self.mesh:
             return self._jit_init(rng)
 
+    def abstract_state(self):
+        """``(params, opt_state)`` as ShapeDtypeStructs in the layout
+        of the strategy: a restore target that costs no second state
+        on the device, and what an ahead-of-time lowering takes."""
+        abs_params = jax.eval_shape(self._init_fn, jax.random.key(0))
+        abs_opt = jax.eval_shape(self.optimizer.init, abs_params)
+        opt_shardings = self.opt_shardings or shd.opt_state_shardings(
+            abs_opt, abs_params, self.param_shardings, self.mesh
+        )
+
+        def place(tree, shardings):
+            return jax.tree.map(
+                lambda a, s: jax.ShapeDtypeStruct(
+                    a.shape, a.dtype, sharding=s
+                ),
+                tree, shardings,
+            )
+
+        return (
+            place(abs_params, self.param_shardings),
+            place(abs_opt, opt_shardings),
+        )
+
     # -- train step ------------------------------------------------------
     @property
     def train_step(self):
@@ -185,23 +195,24 @@ class ShardedTrainer:
                     micro, (jnp.zeros(()), zeros), batch
                 )
                 loss = loss_sum / accum
-                grads = jax.tree.map(lambda g: g / accum, grads_sum)
+                # summed in f32, handed on in the params' dtype: the
+                # optimizer then sees what it sees without accumulation
+                # (f32 grads would turn bf16 Adam moments into f32 ones
+                # at the first update — twice the state, and a second
+                # compile for the step that takes it back in)
+                grads = jax.tree.map(
+                    lambda g, p: (g / accum).astype(p.dtype),
+                    grads_sum, params,
+                )
             updates, opt_state = self.optimizer.update(
                 grads, opt_state, params
             )
             params = optax.apply_updates(params, updates)
             return params, opt_state, loss
 
-        # pre-0.6 jax cannot alias a donated input whose sharding
-        # differs from the out_sharding (XLA INTERNAL error at compile
-        # time), and callers legitimately pass replicated params into
-        # a sharded-output step (first step after init/restore) —
-        # donation is a memory optimization, correctness must not
-        # depend on it
-        donate = (0, 1) if _donation_reshards_safely() else ()
         self._jit_step = jax.jit(
             step,
-            donate_argnums=donate,
+            donate_argnums=(0, 1),
             out_shardings=(
                 self.param_shardings, self.opt_shardings, None,
             ),
@@ -253,6 +264,22 @@ def make_trainer_for_llama(
         )
 
         attn_fn = make_context_parallel_attn(mesh, kind="ring")
+    elif attn_fn is None and mesh.size > 1:
+        # GSPMD cannot partition a Pallas kernel: hand each device its
+        # own sequences (and, under a tensor axis, its own kv-head
+        # groups) explicitly — attention needs no collective for either
+        from dlrover_tpu.ops.attention import make_sharded_attention
+
+        rules = shd.get_rules(strategy)
+        attn_fn = make_sharded_attention(
+            mesh,
+            q_spec=shd.spec_for_axes(
+                ("batch", None, "heads", None), rules, mesh
+            ),
+            kv_spec=shd.spec_for_axes(
+                ("batch", None, "kv_heads", None), rules, mesh
+            ),
+        )
     loss = lambda params, batch: llama.next_token_loss(  # noqa: E731
         params, batch, cfg, attn_fn=attn_fn
     )

@@ -18,7 +18,10 @@ elastic control plane come from one framework.
 """
 
 import argparse
+import functools
+import json
 import os
+import re
 import sys
 import time
 
@@ -29,11 +32,19 @@ import optax
 from dlrover_tpu.agent.master_client import build_master_client
 from dlrover_tpu.data.elastic_shm import ElasticShmDataLoader
 from dlrover_tpu.models import llama
+from dlrover_tpu.ops import tuning
 from dlrover_tpu.parallel.mesh import create_mesh
 from dlrover_tpu.trainer.checkpoint import FlashCheckpointer
+from dlrover_tpu.trainer.compile_cache import cache_events
 from dlrover_tpu.trainer.distributed import init_from_env
 from dlrover_tpu.trainer.elastic import ElasticTrainer
 from dlrover_tpu.trainer.sharded import make_trainer_for_llama
+
+MODELS = {
+    "llama_tiny": llama.llama_tiny,
+    # sized as bench.py sizes it for a 16 GB chip
+    "llama_1b": functools.partial(llama.llama_1b, remat="dots_attn_out"),
+}
 
 
 def synth_batch(start: int, end: int, seq_len: int = 128,
@@ -59,16 +70,78 @@ class _BatchFn:
         return synth_batch(start, end, self.seq_len, self.vocab)
 
 
+def _append_report(path: str, **fields):
+    """One JSON line per event: a crashed incarnation's lines survive
+    it, and the next incarnation adds its own."""
+    with open(path, "a") as f:
+        f.write(json.dumps(fields) + "\n")
+
+
+def _compile_step(trainer, params, opt_state, mb):
+    """Compile the train step ahead of its first call (the call then
+    reuses the executable) and say what only this process can know
+    about it."""
+    t0 = time.time()
+    with cache_events() as events:
+        compiled = trainer.train_step.lower(
+            params, opt_state, mb
+        ).compile()
+    text = compiled.as_text()
+    collectives = {
+        op: len(re.findall(rf"\b{op}(?:-start)?\(", text))
+        for op in ("all-gather", "reduce-scatter", "all-reduce",
+                   "all-to-all", "collective-permute")
+    }
+    leaves = jax.tree.leaves(params)
+    return {
+        "step_compile_secs": round(time.time() - t0, 3),
+        "step_cache_requests": events["requests"],
+        "step_cache_hits": events["hits"],
+        "kernel_in_step": "tpu_custom_call" in text,
+        "collectives": collectives,
+        "tuning": tuning.last_selection(),
+        # where the parameters actually live
+        "param_bytes_total": sum(x.nbytes for x in leaves),
+        "param_bytes_by_device": {
+            str(dev): int(n) for dev, n in sorted(_bytes_by_device(
+                leaves
+            ).items())
+        },
+    }
+
+
+def _bytes_by_device(arrays):
+    out = {}
+    for x in arrays:
+        for shard in x.addressable_shards:
+            out[shard.device.id] = (
+                out.get(shard.device.id, 0) + shard.data.nbytes
+            )
+    return out
+
+
 def main():
     parser = argparse.ArgumentParser()
+    parser.add_argument("--model", choices=sorted(MODELS),
+                        default="llama_tiny")
     parser.add_argument("--steps", type=int, default=50)
-    parser.add_argument("--batch-size", type=int, default=8)
+    parser.add_argument("--batch-size", type=int, default=8,
+                        help="global batch per optimizer step")
+    parser.add_argument("--accum-steps", type=int, default=1,
+                        help="microbatches the global batch is cut "
+                             "into")
     parser.add_argument("--seq-len", type=int, default=128)
+    parser.add_argument("--num-devices", type=int, default=0,
+                        help="mesh size; 0 = every device")
     parser.add_argument("--num-workers", type=int, default=2)
     parser.add_argument("--strategy", type=str, default="fsdp")
     parser.add_argument("--ckpt-dir", type=str,
                         default="/tmp/llama_ckpt")
     parser.add_argument("--out", type=str, default="")
+    parser.add_argument("--report", type=str, default="",
+                        help="append JSON lines saying what this "
+                             "process saw: device, compiled step, "
+                             "tuning, cache, losses, peak memory")
     parser.add_argument("--timing-out", type=str, default="",
                         help="append '<restart_count>,<secs_to_first_"
                              "step>' per incarnation (the failover "
@@ -78,29 +151,38 @@ def main():
     t_proc_start = time.time()
     env = init_from_env()
     client = build_master_client()
-    cfg = llama.llama_tiny()
+    cfg = MODELS[args.model]()
 
-    mesh = create_mesh([("data", 1), ("fsdp", len(jax.devices()))])
+    devices = jax.devices()[:args.num_devices or None]
+    mesh = create_mesh(
+        [("data", 1), ("fsdp", len(devices))], devices=devices
+    )
     trainer = make_trainer_for_llama(
         cfg, mesh, strategy=args.strategy,
+        accum_steps=args.accum_steps,
         optimizer=optax.adamw(1e-3),
     )
-    params, opt_state = trainer.init(jax.random.key(0))
 
     ckpt = FlashCheckpointer(
         persist_dir=os.path.join(args.ckpt_dir, "persist"),
         ram_dir=os.path.join(args.ckpt_dir, "ram"),
         persist_interval=0, use_orbax=False,
     )
-    state = {"params": params, "opt_state": opt_state,
-             "step": jax.numpy.array(0)}
-    restored, _ = ckpt.restore(target=state)
+    # an abstract target: a resume at full width holds ONE state on
+    # the device, not a fresh one beside the restored one
+    abs_params, abs_opt = trainer.abstract_state()
+    restored, _ = ckpt.restore(target={
+        "params": abs_params, "opt_state": abs_opt,
+        "step": jax.ShapeDtypeStruct((), jax.numpy.int32),
+    })
     start_step = 0
     if restored is not None:
         params = restored["params"]
         opt_state = restored["opt_state"]
         start_step = int(restored["step"])
         print(f"RESTORED from step {start_step}", flush=True)
+    else:
+        params, opt_state = trainer.init(jax.random.key(0))
 
     # hang detection + fault injection ride on the elastic reporter
     reporter = ElasticTrainer(
@@ -120,11 +202,16 @@ def main():
         sharding=trainer.batch_sharding,
     )
 
-    step, loss = start_step, None
+    device = devices[0]
+    step, loss, losses = start_step, None, []
     first_step_done = False
     try:
         for batch in loader:
-            mb = jax.tree.map(lambda x: x[None], batch)  # 1 microbatch
+            mb = trainer.microbatch(batch)
+            if args.report and not first_step_done:
+                step_facts = _compile_step(
+                    trainer, params, opt_state, mb
+                )
             params, opt_state, loss = trainer.train_step(
                 params, opt_state, mb
             )
@@ -133,7 +220,7 @@ def main():
                 # process start -> first optimizer step retired
                 # (bootstrap + restore + trace + XLA compile or a
                 # persistent-cache read — compile_cache.py)
-                float(loss)  # device sync
+                loss.block_until_ready()
                 t_first = time.time() - t_proc_start
                 first_step_done = True
                 print(
@@ -143,7 +230,27 @@ def main():
                 if args.timing_out:
                     with open(args.timing_out, "a") as f:
                         f.write(f"{env.restart_count},{t_first:.3f}\n")
+                if args.report:
+                    _append_report(
+                        args.report, event="first_step",
+                        restart_count=env.restart_count,
+                        start_step=start_step,
+                        platform=device.platform,
+                        device_kind=device.device_kind,
+                        device_count=len(jax.devices()),
+                        model=args.model, vocab_size=cfg.vocab_size,
+                        batch=args.batch_size,
+                        accum=args.accum_steps, seq=args.seq_len,
+                        strategy=args.strategy,
+                        mesh=dict(mesh.shape),
+                        first_step_secs=round(t_first, 3),
+                        compile_cache_dir=(
+                            jax.config.jax_compilation_cache_dir
+                        ),
+                        **step_facts,
+                    )
             step += 1
+            losses.append((step, loss))
             reporter.report_step(step)
             if step % 10 == 0 or step >= args.steps:
                 ckpt.save(
@@ -168,6 +275,15 @@ def main():
     if args.out:
         with open(args.out, "w") as f:
             f.write(f"{step},{loss_val:.6f},{start_step}")
+    if args.report:
+        _append_report(
+            args.report, event="final",
+            restart_count=env.restart_count, step=step,
+            losses=[[s, float(x)] for s, x in losses],
+            peak_bytes_in_use=(device.memory_stats() or {}).get(
+                "peak_bytes_in_use"
+            ),
+        )
     return 0
 
 

@@ -21,8 +21,9 @@ from dlrover_tpu.trainer import profiler
 
 @pytest.fixture
 def cache_dir(tmp_path, monkeypatch):
+    # the tuning cache lives in tuning/ below the compile cache dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     d = str(tmp_path / "tuning")
-    monkeypatch.setenv(tuning.ENV_TUNING_CACHE_DIR, d)
     tuning.reset_cache_memo()
     yield d
     tuning.reset_cache_memo()
@@ -112,8 +113,8 @@ def test_corrupt_entry_is_a_miss_not_an_error(cache_dir):
 
 
 def test_corrupt_entry_falls_back_to_heuristic(cache_dir, monkeypatch):
-    """get_blocks over a corrupt entry: no raise, and with measurement
-    unavailable the heuristic prior comes back."""
+    """get_blocks over a corrupt entry: no raise, the sweep runs
+    again, and when only the prior could be timed it comes back."""
     key_file = _key(device_kind="cpu", dtype="float32")
     cache = tuning.get_cache()
     with open(os.path.join(cache.path, key_file.filename()), "w") as f:
@@ -124,7 +125,8 @@ def test_corrupt_entry_falls_back_to_heuristic(cache_dir, monkeypatch):
         lambda *a: [type("D", (), {"device_kind": "cpu"})()],
     )
     monkeypatch.setattr(
-        tuning, "measure_candidates", lambda key, cands: []
+        tuning, "measure_candidates",
+        lambda key, cands: [(*cands[0], 1.0)],  # only the prior timed
     )
     blocks = tuning.get_blocks(
         seq=2048, head_dim=64, group=8, dtype="float32", causal=True
@@ -281,3 +283,70 @@ def test_caller_caps_join_the_filter(cache_dir):
         seq=2048, head_dim=64, group=1, dtype="bfloat16", causal=True,
         block_q=32,
     ) is None
+
+
+# ------------------------------------------- measuring from inside a trace
+
+
+def _tpu_like(monkeypatch):
+    """Measurement forced on, on the interpret-mode kernel."""
+    monkeypatch.setattr(tuning, "_measurement_enabled", lambda: True)
+    real = tuning.measure_candidates
+    monkeypatch.setattr(
+        tuning, "measure_candidates",
+        lambda key, cands: real(key, cands[:1], n=1, warmup=1),
+    )
+
+
+def test_sweep_reached_from_inside_jit_measures_outside_the_trace(
+    cache_dir, monkeypatch
+):
+    """flash_attention is jitted and calls get_blocks in its body, so
+    the sweep always starts inside a trace; its inputs and timings
+    must be concrete all the same, and the winner must say so."""
+    _tpu_like(monkeypatch)
+    seen = []
+    real_timeit = tuning.timeit
+
+    def spy(fn, *args, **kw):
+        seen.append([type(a).__name__ for a in args])
+        t = real_timeit(fn, *args, **kw)
+        assert isinstance(t, float) and t > 0
+        return t
+
+    monkeypatch.setattr(tuning, "timeit", spy)
+
+    @jax.jit
+    def step(x):
+        blocks = tuning.get_blocks(
+            seq=128, head_dim=64, group=1, dtype="float32",
+            causal=True,
+        )
+        assert blocks == (128, 128)
+        return x * 2
+
+    jax.grad(lambda x: step(x).sum())(jnp.ones(4))
+    assert seen and all(
+        "Tracer" not in name for names in seen for name in names
+    )
+    sel = tuning.last_selection()
+    assert sel["source"] == "measured" and sel["seq"] == 128
+    assert len(os.listdir(cache_dir)) == 1
+
+
+def test_sweep_with_no_survivor_raises_and_stores_nothing(
+    cache_dir, monkeypatch
+):
+    _tpu_like(monkeypatch)
+
+    def broken(fn, *args, **kw):
+        raise RuntimeError("Mosaic says no")
+
+    monkeypatch.setattr(tuning, "timeit", broken)
+    with pytest.raises(RuntimeError, match="timed none"):
+        tuning.get_blocks(
+            seq=128, head_dim=64, group=1, dtype="float32",
+            causal=True,
+        )
+    cache = tuning.get_cache()
+    assert cache.entries() == 0 and not cache._mem

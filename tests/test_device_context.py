@@ -1,6 +1,7 @@
 """Device context tests (AT8 parity: auto/device_context.py)."""
 
 import jax
+import pytest
 
 from dlrover_tpu.auto.device_context import (
     DeviceContext,
@@ -22,8 +23,9 @@ def test_chip_tables():
     assert hbm_bytes_per_chip(FakeDev("TPU v5 lite")) == 16e9
     assert peak_flops_per_chip(FakeDev("TPU v5p")) == 459.0e12
     assert hbm_bytes_per_chip(FakeDev("TPU v4")) == 32e9
-    # unknown chips fall back to the v5p class
-    assert peak_flops_per_chip(FakeDev("TPU v9 mega")) == 459.0e12
+    # an unknown TPU is an error, not a v5p
+    with pytest.raises(ValueError, match="v9 mega"):
+        peak_flops_per_chip(FakeDev("TPU v9 mega"))
 
 
 def test_build_context_counts_hosts():

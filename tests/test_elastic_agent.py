@@ -213,3 +213,69 @@ def test_agent_restarts_on_membership_change(master):
         assert first_proc.poll() is not None  # old process was stopped
         agent.stop()
         t.join(timeout=10)
+
+
+_IMPORT_ONLY = """
+import importlib.util, sys
+import dlrover_tpu.master.main
+import dlrover_tpu.trainer.elastic_run
+import dlrover_tpu.agent.elastic.training
+import dlrover_tpu.data.shm_dataloader
+import dlrover_tpu.data.elastic_shm
+# the example as a coworker sees it: imported, not run
+spec = importlib.util.spec_from_file_location("__mp_main__", sys.argv[1])
+spec.loader.exec_module(importlib.util.module_from_spec(spec))
+from jax._src import xla_bridge
+print("BACKENDS", sorted(xla_bridge._backends))
+"""
+
+
+def test_importing_the_launcher_path_initialises_no_backend():
+    """One process for each chip: launcher, master, agent and the
+    spawned coworkers (which re-import the example) may import jax but
+    must never open a backend — the worker needs the chip."""
+    import subprocess
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run(
+        [sys.executable, "-c", _IMPORT_ONLY,
+         os.path.join(repo, "examples", "llama_train.py")],
+        env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=repo),
+        capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "BACKENDS []" in out.stdout, out.stdout[-500:]
+
+
+def test_restart_waits_out_the_dead_workers_children(master):
+    """A worker that dies on its own leaves its coworker children
+    behind (own session group); the next incarnation must not start
+    while one of them still runs."""
+    with tempfile.TemporaryDirectory() as tmp:
+        pidfile = os.path.join(tmp, "child.pid")
+        script = os.path.join(tmp, "w.py")
+        with open(script, "w") as f:
+            f.write(
+                "import os, subprocess, sys, time\n"
+                "if os.environ['DLROVER_TPU_RESTART_COUNT'] == '0':\n"
+                "    c = subprocess.Popen([sys.executable, '-c',\n"
+                "        'import time; time.sleep(600)'])\n"
+                f"    open({pidfile!r}, 'w').write(str(c.pid))\n"
+                "    os._exit(17)\n"
+                f"pid = int(open({pidfile!r}).read())\n"
+                "try:\n"
+                "    os.kill(pid, 0)\n"
+                "    state = open(f'/proc/{pid}/stat').read()"
+                ".rsplit(')', 1)[1].split()[0]\n"
+                "    sys.exit(0 if state in 'ZX' else 3)\n"
+                "except ProcessLookupError:\n"
+                "    sys.exit(0)\n"
+            )
+        client = _client(master, 0)
+        client.report_rdzv_params(1, 1, 0.5, 1)
+        config = ElasticLaunchConfig(
+            min_nodes=1, max_nodes=1, node_rank=0, max_restarts=1,
+            monitor_interval=0.2, entrypoint=script,
+        )
+        result = ElasticTrainingAgent(config, client).run()
+        assert result.state == WorkerState.SUCCEEDED, result

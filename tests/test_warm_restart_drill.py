@@ -13,8 +13,9 @@ process-start -> first-step time beat the first's because its jit was
 a disk read (the cache directory the agent wired into the worker env).
 
 The on-chip measurement (1.1B flagship, cold vs warm, real compile
-times) is ``benchmarks/failover_warm.py`` -> FAILOVER_r05.json; this
-drill keeps the mechanism honest in CI on the CPU backend.
+times) is ``benchmarks/failover_warm.py`` (an earlier chip run, not
+reproduced) and ``chip_smoke.py``'s resume phase; this drill keeps the
+mechanism honest in CI on the CPU backend.
 """
 
 import os
@@ -39,14 +40,6 @@ def _read_timings(path):
 
 
 def test_warm_restart_beats_cold_via_compile_cache():
-    from dlrover_tpu.trainer import compile_cache
-
-    if not compile_cache._persistent_cache_safe():
-        pytest.skip(
-            "this jax build cannot reload serialized executables; the "
-            "safety gate keeps the cache off, so there is no warm "
-            "path to measure"
-        )
     with tempfile.TemporaryDirectory() as tmp:
         out_file = os.path.join(tmp, "result.txt")
         timing_file = os.path.join(tmp, "timing.csv")
@@ -56,7 +49,6 @@ def test_warm_restart_beats_cold_via_compile_cache():
             "--standalone", "--nnodes", "1:1",
             "--max_restarts", "2",
             "--monitor_interval", "0.3",
-            "--compile_cache_dir", cache_dir,
             os.path.join(REPO, "examples", "llama_train.py"), "--",
             "--steps", "30", "--batch-size", "8", "--seq-len", "64",
             "--num-workers", "1",
@@ -65,6 +57,7 @@ def test_warm_restart_beats_cold_via_compile_cache():
         ]
         env = dict(os.environ)
         env["JAX_PLATFORMS"] = "cpu"
+        env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
         # crash at step 15: incarnation 0 pays the cold compile and
         # leaves a step-10 flash snapshot; incarnation 1 restores and
         # re-jits the SAME program over the SAME topology — the
