@@ -41,7 +41,7 @@ from dlrover_tpu.common.constants import (
 from dlrover_tpu.common.grpc_utils import find_free_port
 from dlrover_tpu.fault_tolerance.drain import DRAIN_EXIT_CODE
 from dlrover_tpu.common.log import default_logger as logger
-from dlrover_tpu.telemetry import counter, record
+from dlrover_tpu.telemetry import counter, record, tracing
 from dlrover_tpu.telemetry.http import start_metrics_server
 
 
@@ -110,6 +110,13 @@ class MasterRendezvousHandler:
     def next_rendezvous(self):
         """Block until a world forms. Returns
         (round, world, process_id, num_processes, coordinator_addr)."""
+        attrs: Dict[str, int] = {}
+        with tracing.span("agent.rendezvous", attrs):
+            out = self._next_rendezvous()
+            attrs["round"], attrs["world"] = out[0], len(out[1])
+        return out
+
+    def _next_rendezvous(self):
         start = time.time()
 
         def _hello():
@@ -372,8 +379,8 @@ class ElasticTrainingAgent:
                         self._restart_count,
                     )
                     return result
-                self._report_failure(result)
                 if result.return_code in (137, -9):
+                    self._report_failure(result)
                     # OOM-class death: a LOCAL relaunch cannot help —
                     # the same memory limit kills it again. Escalate to
                     # the master (parity: the reference never restarts
@@ -399,9 +406,11 @@ class ElasticTrainingAgent:
                         self._remaining_restarts,
                     )
                     self._restart_workers(
-                        "process_failure", rc=result.return_code
+                        "process_failure", failed=result,
+                        rc=result.return_code,
                     )
                 else:
+                    self._report_failure(result)
                     return result
             elif self._restart_requested.is_set():
                 self._restart_requested.clear()
@@ -441,9 +450,12 @@ class ElasticTrainingAgent:
         # loaders) form one process group, so group-wide signals (the
         # preempt injection, a real node drain) hit the whole training
         # tree without touching the agent or launcher above it
-        self._proc = subprocess.Popen(
-            cmd, env=env, start_new_session=True
-        )
+        attrs = {"restart_count": self._restart_count}
+        with tracing.span("agent.spawn", attrs):
+            self._proc = subprocess.Popen(
+                cmd, env=env, start_new_session=True
+            )
+            attrs["pid"] = self._proc.pid
         self._restart_count += 1
 
     def _worker_env(self, rdzv_round: int, node_num: int,
@@ -491,6 +503,12 @@ class ElasticTrainingAgent:
             return RunResult(WorkerState.HEALTHY)
         if rc == 0:
             return RunResult(WorkerState.SUCCEEDED, 0)
+        # zero length: the instant this agent learned of the death
+        # (the monitor interval sits between it and the death itself)
+        tracing.add_span(
+            "agent.exit_detected", time.time(), 0.0,
+            attrs={"rc": rc, "restart_count": self._restart_count},
+        )
         return RunResult(WorkerState.FAILED, rc)
 
     def _membership_changed(self) -> bool:
@@ -498,30 +516,38 @@ class ElasticTrainingAgent:
         (parity: training.py:446)."""
         return self._client.num_nodes_waiting() > 0
 
-    def _restart_workers(self, reason: str = "unspecified", **extra):
-        counter(
-            "dlrover_agent_worker_restarts_total",
-            "Training-process restarts by trigger", ["reason"],
-        ).labels(reason=reason).inc()
-        record(
-            "scale.restart", reason=reason,
-            node_rank=self._config.node_rank,
-            restart_count=self._restart_count, **extra,
-        )
-        self._kill_workers()
-        self._initialize_workers()
+    def _restart_workers(self, reason: str = "unspecified",
+                         failed: Optional[RunResult] = None, **extra):
+        """Kill what is left of the worker group and start the next
+        incarnation; ``failed`` is the exit to report to the master
+        first (a process failure)."""
+        with tracing.span("agent.restart", {"reason": reason}):
+            if failed is not None:
+                self._report_failure(failed)
+            counter(
+                "dlrover_agent_worker_restarts_total",
+                "Training-process restarts by trigger", ["reason"],
+            ).labels(reason=reason).inc()
+            record(
+                "scale.restart", reason=reason,
+                node_rank=self._config.node_rank,
+                restart_count=self._restart_count, **extra,
+            )
+            self._kill_workers()
+            self._initialize_workers()
 
     def _kill_workers(self, grace: float = 10.0):
         """Stop the worker AND its coworker children (one session
         group). A worker that died on its own leaves its children
         behind; the next incarnation must find chip and shm free, so
         the group is waited out even when the leader is already dead."""
-        if self._proc is None or self._wait_worker_group_gone(0):
-            return  # nothing left to signal (and its pid may be reused)
-        self._signal_worker_group(signal.SIGTERM)
-        if not self._wait_worker_group_gone(grace):
-            self._signal_worker_group(signal.SIGKILL)
-            self._wait_worker_group_gone(grace)
+        with tracing.span("agent.kill_group"):
+            if self._proc is None or self._wait_worker_group_gone(0):
+                return  # nothing left to signal (its pid may be reused)
+            self._signal_worker_group(signal.SIGTERM)
+            if not self._wait_worker_group_gone(grace):
+                self._signal_worker_group(signal.SIGKILL)
+                self._wait_worker_group_gone(grace)
 
     def _signal_worker_group(self, sig):
         """Signal the worker's own session group (start_new_session at
@@ -547,11 +573,14 @@ class ElasticTrainingAgent:
             time.sleep(0.05)
 
     def _report_failure(self, result: RunResult):
-        self._client.report_failure(
-            f"training process exited rc={result.return_code}",
-            TrainingExceptionLevel.PROCESS_ERROR,
-            self._restart_count,
-        )
+        with tracing.span(
+            "agent.report_failure", {"rc": result.return_code}
+        ):
+            self._client.report_failure(
+                f"training process exited rc={result.return_code}",
+                TrainingExceptionLevel.PROCESS_ERROR,
+                self._restart_count,
+            )
 
     def stop(self):
         self._stopped = True

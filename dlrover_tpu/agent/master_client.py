@@ -1082,12 +1082,13 @@ def build_master_client(master_addr: Optional[str] = None,
         node_id = int(os.getenv(NodeEnv.NODE_ID, "0"))
     if node_type is None:
         node_type = os.getenv(NodeEnv.NODE_TYPE, "worker")
-    if master_addr:
-        _master_client = MasterClient(
-            master_addr, node_id, node_type, timeout
-        )
-    else:
-        _master_client = LocalMasterClient(node_id, node_type)
+    with tracing.span("boot.master_client"):
+        if master_addr:
+            _master_client = MasterClient(
+                master_addr, node_id, node_type, timeout
+            )
+        else:
+            _master_client = LocalMasterClient(node_id, node_type)
     return _master_client
 
 

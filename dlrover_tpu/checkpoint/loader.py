@@ -29,7 +29,7 @@ import numpy as np
 
 from dlrover_tpu.checkpoint import manifest as mf
 from dlrover_tpu.common.log import default_logger as logger
-from dlrover_tpu.telemetry import counter, record
+from dlrover_tpu.telemetry import counter, record, tracing
 from dlrover_tpu.trainer import ckpt_store
 
 __all__ = [
@@ -54,6 +54,14 @@ def _np_dtype(name: str) -> np.dtype:
         import ml_dtypes  # noqa: F401  (registers extension dtypes)
 
         return np.dtype(name)
+
+
+def _nbytes(raw) -> int:
+    """Size of what a source handed back: member bytes, or an array
+    from an in-process source."""
+    if isinstance(raw, (bytes, bytearray, memoryview)):
+        return len(raw)
+    return int(getattr(raw, "nbytes", 0))
 
 
 def _decode_member(raw: bytes, enc: Optional[Dict[str, Any]]) -> np.ndarray:
@@ -310,8 +318,11 @@ class _Fetcher:
         enc = self.catalog.encodings.get(key)
         tried: List[str] = []
         for i, src in enumerate(self.sources):
+            size = {"tier": src.tier}
             try:
-                raw = src.fetch(pkey, ikey, procs)
+                with tracing.span("ckpt.restore.fetch", size):
+                    raw = src.fetch(pkey, ikey, procs)
+                    size["bytes"] = _nbytes(raw)
             except Exception as e:
                 logger.warning(
                     "%s-tier shard fetch failed: %s", src.tier, e
@@ -327,12 +338,14 @@ class _Fetcher:
                 # nothing to decode or digest-verify — downstream
                 # device_put moves it device-to-device
                 self.stats[src.tier] = self.stats.get(src.tier, 0) + 1
-                self.stats["bytes"] += int(getattr(raw, "nbytes", 0))
+                self.stats["bytes"] += size["bytes"]
                 self.cache[key] = raw
                 return raw
-            if want is not None and (
-                hashlib.sha256(raw).hexdigest() != want
-            ):
+            got = want
+            if want is not None:
+                with tracing.span("ckpt.restore.digest", size):
+                    got = hashlib.sha256(raw).hexdigest()
+            if got != want:
                 # the PR 9 walk-down contract, extended per shard:
                 # journal the mismatch, then RE-FETCH this one shard
                 # from the next tier before giving up on the step
@@ -355,7 +368,8 @@ class _Fetcher:
                 continue
             if enc is None and hasattr(src, "enc_for"):
                 enc = src.enc_for(key)
-            arr = _decode_member(raw, enc)
+            with tracing.span("ckpt.restore.decode", size):
+                arr = _decode_member(raw, enc)
             self.stats[src.tier] += 1
             self.stats["bytes"] += len(raw)
             if src.tier == "peer":
@@ -386,21 +400,25 @@ def _gather_domain(fetcher: _Fetcher, leaf: Dict[str, Any],
     dtype = _np_dtype(leaf["dtype"])
     out = np.empty(mf.domain_shape(nidx), dtype=dtype)
     covered = 0
-    for d in domains:
-        ov = mf.overlap(d["idx"], nidx)
-        if ov is None:
-            continue
-        src = fetcher.get(
-            pkey, mf.index_key(d["idx"]), d.get("replicas")
-        ).reshape(mf.domain_shape(d["idx"]))
-        dst_sl = tuple(
-            slice(s - n[0], e - n[0]) for (s, e), n in zip(ov, nidx)
-        )
-        src_sl = tuple(
-            slice(s - o[0], e - o[0]) for (s, e), o in zip(ov, d["idx"])
-        )
-        out[dst_sl] = src[src_sl]
-        covered += mf.domain_volume(ov)
+    # the saved layout is not the needed one: the fetches are this
+    # span's children, the copies into place its own time
+    with tracing.span("ckpt.restore.assemble", {"bytes": out.nbytes}):
+        for d in domains:
+            ov = mf.overlap(d["idx"], nidx)
+            if ov is None:
+                continue
+            src = fetcher.get(
+                pkey, mf.index_key(d["idx"]), d.get("replicas")
+            ).reshape(mf.domain_shape(d["idx"]))
+            dst_sl = tuple(
+                slice(s - n[0], e - n[0]) for (s, e), n in zip(ov, nidx)
+            )
+            src_sl = tuple(
+                slice(s - o[0], e - o[0])
+                for (s, e), o in zip(ov, d["idx"])
+            )
+            out[dst_sl] = src[src_sl]
+            covered += mf.domain_volume(ov)
     if covered != mf.domain_volume(nidx):
         raise ShardUnavailableError(
             f"step {fetcher.catalog.step}: domain {nidx} of "
@@ -427,12 +445,20 @@ def _leaf_value(fetcher: _Fetcher, leaf: Dict[str, Any],
     sharding = target_sharding(target)
     pkey = mf.path_key(leaf["path"])
     kind = leaf.get("kind")
+
+    def put(arr, where):
+        # the enqueue: the copy itself completes later
+        with tracing.span(
+            "ckpt.restore.device_put", {"bytes": _nbytes(arr)}
+        ):
+            return jax.device_put(arr, where)
+
     if kind == "py":
         return leaf.get("v")
     if kind == "array":
         arr = fetcher.get(pkey, "full", leaf.get("replicas"))
         if sharding is not None:
-            return jax.device_put(arr, sharding)
+            return put(arr, sharding)
         return arr
     if kind != "shards":
         raise ckpt_store.ArchiveError(f"unknown leaf kind {kind!r}")
@@ -448,7 +474,7 @@ def _leaf_value(fetcher: _Fetcher, leaf: Dict[str, Any],
                 assembled[ikey] = _gather_domain(
                     fetcher, leaf, pkey, nidx
                 )
-            arrays.append(jax.device_put(assembled[ikey], dev))
+            arrays.append(put(assembled[ikey], dev))
         return jax.make_array_from_single_device_arrays(
             shape, sharding, arrays
         )

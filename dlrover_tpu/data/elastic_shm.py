@@ -20,6 +20,7 @@ from typing import Any, Callable, Iterable, Iterator, Optional
 
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.data.shm_dataloader import DevicePrefetch, ShmDataLoader
+from dlrover_tpu.telemetry import tracing
 
 
 @dataclass
@@ -119,17 +120,20 @@ class ElasticShmDataLoader:
             fetch_batch=fetch_batch,
             lookahead=lookahead,
         )
-        self._loader = ShmDataLoader(
-            producer,
-            num_workers=num_workers,
-            slot_bytes=slot_bytes,
-            num_slots=num_slots,
-            pre_sharded=True,  # disjointness comes from the master
-        )
-        self._prefetch = DevicePrefetch(
-            self._loader, depth=prefetch_depth, sharding=sharding,
-            transform=transform,
-        )
+        with tracing.span("boot.data_plane", {
+            "coworkers": num_workers, "slots": num_slots,
+        }):
+            self._loader = ShmDataLoader(
+                producer,
+                num_workers=num_workers,
+                slot_bytes=slot_bytes,
+                num_slots=num_slots,
+                pre_sharded=True,  # disjointness comes from the master
+            )
+            self._prefetch = DevicePrefetch(
+                self._loader, depth=prefetch_depth, sharding=sharding,
+                transform=transform,
+            )
         logger.info(
             "ElasticShmDataLoader: %d coworkers, dataset=%s size=%d "
             "batch=%d", num_workers, dataset_name, dataset_size,

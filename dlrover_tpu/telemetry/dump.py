@@ -201,6 +201,18 @@ def dump_trace(path: str, out: str = "",
         + (f" -> {out}" if out else ""),
         file=sys.stderr,
     )
+    # where the time sits: self time (a span less what its children
+    # cover) summed by name, largest first
+    own = tracing.self_times(records)
+    by_name: Dict[str, float] = {}
+    for rec in records:
+        if rec.get("span") in own:
+            name = str(rec.get("name", "?"))
+            by_name[name] = by_name.get(name, 0.0) + own[rec["span"]]
+    for name, secs in sorted(
+        by_name.items(), key=lambda kv: (-kv[1], kv[0])
+    )[:10]:
+        print(f"--   self {secs:10.3f} s  {name}", file=sys.stderr)
     return 0
 
 

@@ -12,7 +12,12 @@ stay wired into the hot paths permanently:
     one module global and returns a shared no-op context manager — no
     object is created, so a train loop crossing dozens of span sites
     per step pays nanoseconds when tracing is off
-    (``benchmarks/trace_overhead.py`` measures it);
+    (``benchmarks/trace_overhead.py`` measures it). A site that hands
+    over ``attrs`` (bytes, leaves) also builds that small dict when
+    off: the checkpoint's per-leaf sites do, once a leaf of megabytes;
+    the per-step sites (``data.*``, ``train.*``) hand over none. Any
+    clock reading or count that exists only for a span is taken under
+    ``enabled()``;
   * **lock-free ring**: finished spans append to a bounded
     ``collections.deque`` — a single CPython bytecode op (GIL-atomic),
     no lock on the record path; the tail is always available to the
@@ -36,13 +41,19 @@ stay wired into the hot paths permanently:
     (common/grpc_utils.py injects/extracts it). The merge links
     cross-process parent/child edges with Perfetto flow events. All of
     this lives strictly behind the ``_enabled`` check: the disabled
-    path is still one global read + the shared no-op.
+    path is still one global read + the shared no-op;
+  * **one clock with the device trace**: in a process that has
+    imported ``jax`` a live span also enters a
+    ``jax.profiler.TraceAnnotation`` of its name, so while a profiler
+    session runs the span sits on the ``/host:CPU`` plane of the same
+    ``.xplane.pb`` as the chip's operations. The launcher and the
+    agent never import jax for this. Still behind ``_enabled``.
 
 Usage::
 
     from dlrover_tpu.telemetry import tracing
 
-    with tracing.span("data_load"):
+    with tracing.span("data.fetch"):
         batch = next(it)
 
     tracing.add_span("rdzv.training", started_ts, duration_s,
@@ -58,6 +69,7 @@ import itertools
 import json
 import os
 import socket
+import sys
 import threading
 import time
 import zlib
@@ -69,7 +81,6 @@ from dlrover_tpu.common.log import default_logger as logger
 
 ENV_TRACE = "DLROVER_TPU_TRACE"
 ENV_TRACE_DIR = "DLROVER_TPU_TRACE_DIR"
-ENV_TRACE_RING = "DLROVER_TPU_TRACE_RING"
 
 __all__ = [
     "ENV_TRACE",
@@ -85,6 +96,7 @@ __all__ = [
     "tail",
     "clear",
     "summarize",
+    "self_times",
     "chrome_trace",
     "merge_trace_dir",
     "read_span_file",
@@ -210,6 +222,24 @@ class _NoopSpan:
 
 _NOOP = _NoopSpan()
 
+#: ``jax.profiler.TraceAnnotation`` once this process has imported jax
+_annotation_cls = None
+
+
+def _annotation(name: str):
+    """An entered profiler annotation of ``name``, or None in a
+    process without jax (launcher, agent, master: never imported for
+    this). Outside a profiler session entering one is a flag check."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is None:
+            return None
+        _annotation_cls = profiler.TraceAnnotation
+    ann = _annotation_cls(name)
+    ann.__enter__()
+    return ann
+
 
 class _Span:
     """A live span: wall-clock start (cross-process alignment) plus a
@@ -218,7 +248,7 @@ class _Span:
     id and becomes the context for its body — children and outbound
     RPCs parent under it."""
 
-    __slots__ = ("_name", "_attrs", "_ts", "_t0",
+    __slots__ = ("_name", "_attrs", "_ts", "_t0", "_ann",
                  "trace_id", "span_id", "_parent", "_tok")
 
     def __init__(self, name: str, attrs: Optional[Dict[str, Any]]):
@@ -236,12 +266,15 @@ class _Span:
             self.trace_id = _new_id()
             self._parent = None
         self._tok = _context.set((self.trace_id, self.span_id))
+        self._ann = _annotation(self._name)
         self._ts = time.time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         dur = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         try:
             _context.reset(self._tok)
         except ValueError:
@@ -259,7 +292,8 @@ def span(name: str, attrs: Optional[Dict[str, Any]] = None):
     safe to leave in a train loop permanently. ``attrs`` (a plain dict,
     deliberately not ``**kwargs`` — a kwargs catch-all would allocate
     even on the disabled path) lands in the record and the Chrome
-    ``args`` pane."""
+    ``args`` pane; a site that passes one builds it with tracing off
+    too, so a site on a per-step path passes none."""
     if not _enabled:
         return _NOOP
     return _Span(name, attrs)
@@ -403,19 +437,12 @@ def _configure_from_env() -> None:
     """Import-time arming, mirroring the journal's env contract: the
     launcher exports one variable and master, agent, and every worker
     inherit it."""
-    ring = os.getenv(ENV_TRACE_RING, "").strip()
-    capacity = None
-    if ring.isdigit():
-        capacity = int(ring)
     trace_dir = os.getenv(ENV_TRACE_DIR, "").strip()
     flag = os.getenv(ENV_TRACE, "").strip().lower()
     if trace_dir:
-        enable(trace_dir, capacity=capacity)
+        enable(trace_dir)
     elif flag not in ("", "0", "off", "false"):
-        enable(capacity=capacity)
-    elif capacity is not None:
-        enable(capacity=capacity)
-        disable()
+        enable()
 
 
 # ----------------------------------------------------------------- reading
@@ -461,6 +488,38 @@ def summarize(names: Optional[Iterable[str]] = None,
     for agg in out.values():
         if agg["count"]:
             agg["mean_ms"] = agg["total_ms"] / agg["count"]
+    return out
+
+
+def self_times(records: List[Dict[str, Any]]) -> Dict[str, float]:
+    """Seconds of each span that none of its children cover, by span
+    id: its duration less the union of its direct children's
+    intervals, cut to its own. A layer's self time is what its span
+    holds beyond the layers below it. Records without a span id are
+    left out; a child whose parent is not among ``records`` only
+    counts as itself."""
+    children: Dict[str, List[Tuple[float, float]]] = {}
+    for rec in records:
+        parent = rec.get("parent")
+        if parent:
+            start = float(rec.get("ts", 0.0))
+            children.setdefault(str(parent), []).append(
+                (start, start + float(rec.get("dur", 0.0)))
+            )
+    out: Dict[str, float] = {}
+    for rec in records:
+        sid = rec.get("span")
+        if not sid:
+            continue
+        start = float(rec.get("ts", 0.0))
+        end = start + float(rec.get("dur", 0.0))
+        covered, reach = 0.0, start
+        for lo, hi in sorted(children.get(str(sid), ())):
+            lo, hi = max(lo, reach), min(hi, end)
+            if hi > lo:
+                covered += hi - lo
+                reach = hi
+        out[str(sid)] = max(0.0, end - start - covered)
     return out
 
 

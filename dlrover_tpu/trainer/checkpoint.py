@@ -268,7 +268,12 @@ def _restore_shards(snapshot, target=None):
                         if full is None:
                             full = _assemble(snap)
                         data = full[idx]
-                    arrays.append(jax.device_put(np.asarray(data), d))
+                    data = np.asarray(data)
+                    # the enqueue: the copy itself completes later
+                    with tracing.span(
+                        "ckpt.restore.device_put", {"bytes": data.nbytes}
+                    ):
+                        arrays.append(jax.device_put(data, d))
                 return jax.make_array_from_single_device_arrays(
                     snap["shape"], sharding, arrays
                 )
@@ -277,8 +282,11 @@ def _restore_shards(snapshot, target=None):
 
     def _assemble(snap):
         full = np.zeros(snap["shape"], dtype=snap["dtype"])
-        for idx, data in snap["shards"]:
-            full[idx] = np.asarray(data)
+        with tracing.span(
+            "ckpt.restore.assemble", {"bytes": full.nbytes}
+        ):
+            for idx, data in snap["shards"]:
+                full[idx] = np.asarray(data)
         return full
 
     def is_snap(x):
@@ -788,7 +796,17 @@ class FlashCheckpointer:
     def _serialize_job_inner(self, job: _SaveJob) -> None:
         t0 = time.perf_counter()
         try:
-            snapshot = _materialize_staged(job.staged)
+            size = {}
+            with tracing.span("ckpt.write.materialize", size):
+                snapshot = _materialize_staged(job.staged)
+                if tracing.enabled():
+                    size["bytes"] = sum(
+                        d.nbytes
+                        for leaf in jax.tree.leaves(
+                            snapshot, is_leaf=_is_snap_leaf
+                        ) if _is_snap_leaf(leaf)
+                        for _, d in leaf["shards"]
+                    )
             job.staged = None  # drop device handles promptly
             job.staged_evt.set()
         except Exception as e:
@@ -1214,11 +1232,26 @@ class FlashCheckpointer:
         matches it — a walk-down to an older step must never be
         served another step's bytes.
         """
+        attrs: Dict[str, Any] = {}
+        with tracing.span("ckpt.restore", attrs):
+            state, got = self._restore(target, step, extra_sources)
+            if state is not None and tracing.enabled():
+                leaves = jax.tree.leaves(state)
+                attrs.update(
+                    step=got, tier=self.last_restore_tier,
+                    leaves=len(leaves),
+                    bytes=sum(getattr(x, "nbytes", 0) for x in leaves),
+                )
+        return state, got
+
+    def _restore(self, target, step, extra_sources):
         self._drain_saves()
         # per-tier shard-move stats of the newest v2 assembly (consumed
         # by reshard/migrate.py to attribute where shards came from);
         # None until a topology restore runs
         self.last_restore_stats = None
+        #: the tier the newest restore was served from
+        self.last_restore_tier = None
         auto_mode = step is None
         if not (auto_mode and self._n_processes > 1):
             # no agreement collective on this path: let failures
@@ -1236,7 +1269,8 @@ class FlashCheckpointer:
         #   2. consensus step selection (collective #1)
         #   3. the fallible restore attempt; failure = a failed vote
         #   4. outcome agreement (collective #2)
-        step = self._consensus_step(self._local_candidate_steps())
+        with tracing.span("ckpt.restore.select"):
+            step = self._consensus_step(self._local_candidate_steps())
         state, got = None, None
         if step is not None:
             try:
@@ -1291,44 +1325,47 @@ class FlashCheckpointer:
                       step: Optional[int] = None,
                       extra_sources: Optional[List[Any]] = None):
         t0 = time.time()
-        ram = dict(self._list_ram())
-        auto_step = step is None
-        # one store scan serves both step selection and the fallback
-        # candidate list (each available_steps call lists the bucket
-        # and HEADs every committed step — don't do it twice); both
-        # consumers are auto-mode only (an explicit step never walks
-        # down), so explicit-step restores skip the scan entirely
-        avail: Optional[list] = None
-        if self._manager is None and auto_step:
-            # an unreachable store must not kill the whole attempt:
-            # the RAM and peer tiers can still restore the step
-            try:
-                avail = ckpt_store.available_steps(
-                    self._store, self._process_index
-                )
-            except Exception as e:
-                logger.warning("persist-tier listing failed: %s", e)
-                avail = []
-        if step is None:
-            if self._manager is not None:
-                # the Orbax path needs the same cross-process agreement
-                # as the store path: a returning host's stale RAM tier
-                # must not out-vote the shared persistent steps
+        # tier listing and consensus (the manifest read below too)
+        with tracing.span("ckpt.restore.select"):
+            ram = dict(self._list_ram())
+            auto_step = step is None
+            # one store scan serves both step selection and the fallback
+            # candidate list (each available_steps call lists the bucket
+            # and HEADs every committed step — don't do it twice); both
+            # consumers are auto-mode only (an explicit step never walks
+            # down), so explicit-step restores skip the scan entirely
+            avail: Optional[list] = None
+            if self._manager is None and auto_step:
+                # an unreachable store must not kill the whole attempt:
+                # the RAM and peer tiers can still restore the step
                 try:
-                    orbax_steps = set(self._manager.all_steps() or [])
-                except Exception:
-                    orbax_steps = set()
-                step = self._consensus_step(set(ram) | orbax_steps)
-            else:
-                local_steps = set(ram) | set(avail or [])
-                step = self._consensus_step(local_steps)
+                    avail = ckpt_store.available_steps(
+                        self._store, self._process_index
+                    )
+                except Exception as e:
+                    logger.warning("persist-tier listing failed: %s", e)
+                    avail = []
+            if step is None:
+                if self._manager is not None:
+                    # the Orbax path needs the same cross-process agreement
+                    # as the store path: a returning host's stale RAM tier
+                    # must not out-vote the shared persistent steps
+                    try:
+                        orbax_steps = set(self._manager.all_steps() or [])
+                    except Exception:
+                        orbax_steps = set()
+                    step = self._consensus_step(set(ram) | orbax_steps)
+                else:
+                    local_steps = set(ram) | set(avail or [])
+                    step = self._consensus_step(local_steps)
         if step is None:
             return None, None
         if step in ram:
             tainted = False
             try:
                 with open(ram[step], "rb") as f:
-                    man = ckpt_store.read_manifest(f)
+                    with tracing.span("ckpt.restore.select"):
+                        man = ckpt_store.read_manifest(f)
                     # an auto-selected step saved inside an anomaly
                     # window must not be restored — the corruption the
                     # sentinel tripped on may already be in it. An
@@ -1342,9 +1379,7 @@ class FlashCheckpointer:
                         logger.info(
                             "Restored step %d from RAM tier", step
                         )
-                        _observe_ckpt(
-                            "restore", "ram", step, time.time() - t0,
-                        )
+                        self._restored("ram", step, t0)
                         return state, step
             except Exception as e:
                 logger.warning("RAM restore failed (%s); trying persistent",
@@ -1371,10 +1406,7 @@ class FlashCheckpointer:
             else:
                 restored = self._manager.restore(step)
             logger.info("Restored step %d from persistent tier", step)
-            _observe_ckpt(
-                "restore", "persistent", step, time.time() - t0,
-                backend="orbax",
-            )
+            self._restored("persistent", step, t0, backend="orbax")
             return restored, step
         # auto-selection may land on a step whose persist shard is gone
         # (e.g. a RAM-tier step never persisted): fall back down the
@@ -1422,9 +1454,8 @@ class FlashCheckpointer:
                         "Step %d not restorable; restored older "
                         "step %d", step, cand,
                     )
-                _observe_ckpt(
-                    "restore", tier, cand, time.time() - t0,
-                    backend="store", requested_step=step,
+                self._restored(
+                    tier, cand, t0, backend="store", requested_step=step,
                 )
                 return state, cand
             # legacy monolithic path (format v1, or a v2 single-proc
@@ -1471,12 +1502,18 @@ class FlashCheckpointer:
                     "Step %d not restorable from persist tier; "
                     "restored older step %d", step, cand,
                 )
-            _observe_ckpt(
-                "restore", "persistent", cand, time.time() - t0,
-                backend="store", requested_step=step,
+            self._restored(
+                "persistent", cand, t0, backend="store",
+                requested_step=step,
             )
             return _restore_shards(snapshot, target), cand
         return None, None
+
+    def _restored(self, tier: str, step: int, t0: float, **extra):
+        """A restore served from ``tier``: remembered for the
+        ``ckpt.restore`` span, observed like every checkpoint op."""
+        self.last_restore_tier = tier
+        _observe_ckpt("restore", tier, step, time.time() - t0, **extra)
 
     def _restore_local_archive(self, f, man, step: int, target,
                                extra_sources=None):

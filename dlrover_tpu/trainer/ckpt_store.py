@@ -39,11 +39,14 @@ import io
 import json
 import os
 import shutil
+import time
 import zipfile
 from abc import ABC, abstractmethod
 from typing import Any, BinaryIO, Dict, List, Optional, Tuple
 
 import numpy as np
+
+from dlrover_tpu.telemetry import tracing
 
 __all__ = [
     "ArchiveError",
@@ -76,14 +79,23 @@ class DigestMismatchError(ArchiveError):
 
 class _HashingWriter:
     """Tee writes into a hash while streaming a member into the zip —
-    the digest costs no extra pass over the data at save time."""
+    the digest costs no extra pass over the data at save time.
+    ``digest_secs`` is what the hash took of it, for
+    ``ckpt.write.digest``: read off the clock only with tracing on."""
 
     def __init__(self, inner: BinaryIO, digest):
         self._inner = inner
         self._digest = digest
+        self._timed = tracing.enabled()
+        self.digest_secs = 0.0
 
     def write(self, data):
+        if not self._timed:
+            self._digest.update(data)
+            return self._inner.write(data)
+        t0 = time.perf_counter()
         self._digest.update(data)
+        self.digest_secs += time.perf_counter() - t0
         return self._inner.write(data)
 
     def flush(self):
@@ -222,23 +234,35 @@ def snapshot_to_file(snapshot: Any, step: int, fileobj: BinaryIO,
             name = f"a{counter[0]}"
             counter[0] += 1
             arr = np.asarray(arr)
-            if (
-                arr.dtype.kind == "V"
-                or arr.dtype.name not in np.sctypeDict
-            ):
-                manifest["encodings"][name] = {
-                    "dtype": arr.dtype.name,
-                    "shape": list(arr.shape),
-                }
-                arr = np.frombuffer(arr.tobytes(), dtype=np.uint8)
-            if not arr.flags["C_CONTIGUOUS"]:
-                # ascontiguousarray only when needed: it promotes 0-d
-                # scalars to 1-d, which would corrupt shard shapes
-                arr = np.ascontiguousarray(arr)
+            size = {"bytes": arr.nbytes}
+            with tracing.span("ckpt.write.encode", size):
+                if (
+                    arr.dtype.kind == "V"
+                    or arr.dtype.name not in np.sctypeDict
+                ):
+                    manifest["encodings"][name] = {
+                        "dtype": arr.dtype.name,
+                        "shape": list(arr.shape),
+                    }
+                    arr = np.frombuffer(arr.tobytes(), dtype=np.uint8)
+                if not arr.flags["C_CONTIGUOUS"]:
+                    # ascontiguousarray only when needed: it promotes
+                    # 0-d scalars to 1-d, which would corrupt shard
+                    # shapes
+                    arr = np.ascontiguousarray(arr)
             digest = hashlib.sha256()
-            with zf.open(name + ".npy", "w", force_zip64=True) as m:
+            # numpy's chunking, the zip member's crc32 and the write
+            # to the medium; the hash's share of it is the child
+            with tracing.span("ckpt.write.io", size), zf.open(
+                name + ".npy", "w", force_zip64=True
+            ) as m:
+                t0 = time.time()
+                writer = _HashingWriter(m, digest)
                 np.lib.format.write_array(
-                    _HashingWriter(m, digest), arr, allow_pickle=False
+                    writer, arr, allow_pickle=False
+                )
+                tracing.add_span(
+                    "ckpt.write.digest", t0, writer.digest_secs, size
                 )
             manifest["digests"][name + ".npy"] = digest.hexdigest()
             return name
@@ -356,9 +380,16 @@ def _load_archive_file(fileobj: BinaryIO):
             manifest = json.loads(zf.read(_MANIFEST).decode("utf-8"))
             _verify_digests(zf, manifest)
         fileobj.seek(0)
-        arrays = np.load(fileobj, allow_pickle=False)
+        lazy = np.load(fileobj, allow_pickle=False)
         # materialize while the file object is open
-        arrays = {k: arrays[k] for k in arrays.files if k != _MANIFEST}
+        arrays = {}
+        for k in lazy.files:
+            if k == _MANIFEST:
+                continue
+            size = {}
+            with tracing.span("ckpt.restore.fetch", size):
+                arrays[k] = lazy[k]
+                size["bytes"] = arrays[k].nbytes
     except ArchiveError:
         raise
     except Exception as e:
@@ -379,9 +410,12 @@ def _load_archive_file(fileobj: BinaryIO):
                 f"archive uses unavailable dtype {enc.get('dtype')!r}: {e}"
             )
         try:
-            arrays[name] = np.frombuffer(
-                arrays[name].tobytes(), dtype=dtype
-            ).reshape(enc["shape"])
+            with tracing.span(
+                "ckpt.restore.decode", {"bytes": arrays[name].nbytes}
+            ):
+                arrays[name] = np.frombuffer(
+                    arrays[name].tobytes(), dtype=dtype
+                ).reshape(enc["shape"])
         except (ValueError, TypeError) as e:
             raise ArchiveError(
                 f"archive member {name} inconsistent with its recorded "
@@ -402,7 +436,12 @@ def _verify_digests(zf: zipfile.ZipFile, manifest) -> None:
         if member not in members:
             raise ArchiveError(f"archive missing member {member!r}")
         h = hashlib.sha256()
-        with zf.open(member) as m:
+        # a pass of its own over the member: the read from the medium
+        # and the zip's crc32 are in it beside the hash
+        with tracing.span(
+            "ckpt.restore.digest",
+            {"bytes": zf.getinfo(member).file_size},
+        ), zf.open(member) as m:
             for chunk in iter(lambda: m.read(_STREAM_CHUNK), b""):
                 h.update(chunk)
         if h.hexdigest() != want:

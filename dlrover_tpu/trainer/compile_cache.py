@@ -27,6 +27,7 @@ reproduced; ``chip_smoke.py``'s resume phase prints both.
 
 import contextlib
 import os
+import time
 from typing import Dict, Iterator, Optional
 
 from dlrover_tpu.common.cachedir import (
@@ -34,7 +35,7 @@ from dlrover_tpu.common.cachedir import (
     resolve_cache_dir,
 )
 from dlrover_tpu.common.log import default_logger as logger
-from dlrover_tpu.telemetry import counter, gauge, record
+from dlrover_tpu.telemetry import counter, gauge, record, tracing
 
 #: compiles faster than this are not cached (jax's default 1s floor
 #: would skip small-but-many programs whose SUM is the restart tax)
@@ -56,6 +57,8 @@ def setup_compilation_cache() -> Optional[str]:
     """
     import jax
 
+    if tracing.enabled():
+        trace_compiles()
     cache_dir = resolve_cache_dir()
     if cache_dir is None:
         logger.error("compilation cache disabled (untrusted dir)")
@@ -82,6 +85,42 @@ def setup_compilation_cache() -> Optional[str]:
         "compile_cache.armed", dir=cache_dir, entries=_armed_entries,
     )
     return cache_dir
+
+
+#: jax's own duration events -> the span each becomes. The backend
+#: compile's event wraps the persistent cache's lookup, so on a hit
+#: ``xla.cache_read`` lies inside ``xla.backend_compile``.
+_COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "xla.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "xla.lower",
+    "/jax/core/compile/backend_compile_duration": "xla.backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "xla.cache_read",
+}
+_compiles_traced = False
+
+
+def _compile_span(event: str, duration: float, **kw) -> None:
+    name = _COMPILE_SPANS.get(event)
+    if name is not None:
+        attrs = {"event": event}
+        if "fun_name" in kw:
+            attrs["fun_name"] = kw["fun_name"]
+        # jax reports when the work ends: it began ``duration`` ago
+        tracing.add_span(name, time.time() - duration, duration, attrs)
+
+
+def trace_compiles() -> None:
+    """Turn every trace, lowering, backend compile and persistent
+    cache read jax makes in this process into a span, tagged with the
+    step ``tracing.set_step`` last named: which step recompiled, from
+    inside the program. One listener a process, however often called;
+    a no-op site by site while tracing is off."""
+    global _compiles_traced
+    if not _compiles_traced:
+        from jax import monitoring
+
+        _compiles_traced = True
+        monitoring.register_event_duration_secs_listener(_compile_span)
 
 
 @contextlib.contextmanager

@@ -13,7 +13,7 @@ from dataclasses import dataclass
 from dlrover_tpu.common.constants import NodeEnv
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.common.log import set_process_index
-from dlrover_tpu.telemetry import record
+from dlrover_tpu.telemetry import record, tracing
 
 
 @dataclass
@@ -45,7 +45,9 @@ def read_dist_env() -> DistributedEnv:
 
 def init_from_env(timeout_s: int = 300) -> DistributedEnv:
     """Initialize jax.distributed from the agent-provided env (no-op for a
-    single process).
+    single process), then open the backend: the first ``jax.devices()``
+    call of the process is made here, where ``boot.backend_open`` can
+    time it. Every caller made that call right after this one.
 
     ``DLROVER_TPU_DIST_HEARTBEAT_TIMEOUT`` (seconds) bounds how long a
     process blocks on collectives with a dead peer before the runtime
@@ -61,10 +63,11 @@ def init_from_env(timeout_s: int = 300) -> DistributedEnv:
         setup_compilation_cache,
     )
 
-    setup_compilation_cache()
-    if env.is_distributed and env.coordinator_addr:
-        import jax
+    import jax
 
+    with tracing.span("boot.compile_cache_setup"):
+        setup_compilation_cache()
+    if env.is_distributed and env.coordinator_addr:
         # decided from the env, NOT jax.default_backend(): touching a
         # backend before jax.distributed.initialize() would create a
         # single-process client and the world would silently not form
@@ -83,13 +86,21 @@ def init_from_env(timeout_s: int = 300) -> DistributedEnv:
             env.coordinator_addr, env.num_processes, env.process_id,
             hb_timeout,
         )
-        jax.distributed.initialize(
-            coordinator_address=env.coordinator_addr,
-            num_processes=env.num_processes,
-            process_id=env.process_id,
-            initialization_timeout=timeout_s,
-            heartbeat_timeout_seconds=hb_timeout,
-        )
+        with tracing.span("boot.distributed_init", {
+            "num_processes": env.num_processes,
+        }):
+            jax.distributed.initialize(
+                coordinator_address=env.coordinator_addr,
+                num_processes=env.num_processes,
+                process_id=env.process_id,
+                initialization_timeout=timeout_s,
+                heartbeat_timeout_seconds=hb_timeout,
+            )
+    attrs = {}
+    with tracing.span("boot.backend_open", attrs):
+        devices = jax.devices()
+        attrs["platform"] = devices[0].platform
+        attrs["device_count"] = len(devices)
     # the authoritative index is now known: tag log lines and the
     # journal envelope with it (common/log.py), then journal the init
     # so restarts are attributable on the timeline

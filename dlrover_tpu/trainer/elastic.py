@@ -324,75 +324,79 @@ class ElasticTrainer:
         NaN/SDC anomalies; the (possibly injection-corrupted) effective
         loss is returned so drills observe the same value the sentinel
         saw."""
-        self._global_step = step if step is not None else (
-            self._global_step + 1
-        )
-        # spans and flight records carry the step they happened at
-        tracing.set_step(self._global_step)
-        # step duration feeds the fleet roll-up plane (ISSUE 17): the
-        # master answers fleet p99 step time from these sketches with
-        # zero agent scrapes
-        now_mono = time.monotonic()
-        if self._last_step_mono is not None:
-            fleet.observe("step", now_mono - self._last_step_mono)
-            fleet.incr("steps")
-        self._last_step_mono = now_mono
-        if not self._first_step_seen:
-            # the first completed step carries the compile: classify
-            # warm (persistent-cache hit) vs cold for the journal
-            self._first_step_seen = True
-            try:
-                from dlrover_tpu.trainer.compile_cache import (
-                    report_first_compile,
-                )
+        # what elastic supervision costs the loop a step: hang
+        # detection, fault injection, the polls of the master's
+        # rollback and transition orders, its step count
+        with tracing.span("train.report_step"):
+            self._global_step = step if step is not None else (
+                self._global_step + 1
+            )
+            # spans and flight records carry the step they happened at
+            tracing.set_step(self._global_step)
+            # step duration feeds the fleet roll-up plane (ISSUE 17): the
+            # master answers fleet p99 step time from these sketches with
+            # zero agent scrapes
+            now_mono = time.monotonic()
+            if self._last_step_mono is not None:
+                fleet.observe("step", now_mono - self._last_step_mono)
+                fleet.incr("steps")
+            self._last_step_mono = now_mono
+            if not self._first_step_seen:
+                # the first completed step carries the compile: classify
+                # warm (persistent-cache hit) vs cold for the journal
+                self._first_step_seen = True
+                try:
+                    from dlrover_tpu.trainer.compile_cache import (
+                        report_first_compile,
+                    )
 
-                report_first_compile(
-                    time.monotonic() - self._created_ts
-                )
-            except Exception as e:  # telemetry never stops training
-                logger.warning("compile-cache telemetry failed: %s", e)
-        # a completed step is the proof of useful work: it opens the
-        # training phase and closes any hang/restart window
-        self._goodput.on_step()
-        if self._hang_detector is not None:
-            self._hang_detector.record_step(self._global_step)
-        if self._trace_capture is not None:
-            self._trace_capture.step(self._global_step)
-        if self._fault_injector is not None:
-            self._fault_injector.maybe_inject(self._global_step)
-        if loss is not None:
-            loss = float(loss)
+                    report_first_compile(
+                        time.monotonic() - self._created_ts
+                    )
+                except Exception as e:  # telemetry never stops training
+                    logger.warning("compile-cache telemetry failed: %s", e)
+            # a completed step is the proof of useful work: it opens the
+            # training phase and closes any hang/restart window
+            self._goodput.on_step()
+            if self._hang_detector is not None:
+                self._hang_detector.record_step(self._global_step)
+            if self._trace_capture is not None:
+                self._trace_capture.step(self._global_step)
             if self._fault_injector is not None:
-                # corruption drills (nan@N / sdc@N) poison the scalar
-                # here so the sentinel sees exactly what a corrupting
-                # host would produce
-                loss = self._fault_injector.corrupt_loss(
-                    self._global_step, loss
-                )
-            if self._sentinel is not None:
-                self._sentinel.check(
-                    self._global_step, loss, grad_norm
-                )
-        elif self._sentinel is not None:
-            # no scalar this step: still poll for rollback orders
-            # issued on another rank's anomaly
-            self._sentinel.poll_rollback_order()
-        if self._mesh_transition is not None:
-            # mesh-transition orders are adopted here (exactly-once by
-            # order id) and executed by the step loop at the boundary
-            # it chooses — see pending_reshard()
-            self._mesh_transition.poll_order()
-        if (
-            self._master_client is not None
-            and self._global_step % self._report_interval == 0
-        ):
-            try:
-                self._master_client.report_global_step(
-                    self._global_step, time.time()
-                )
-            except Exception as e:
-                logger.warning("report_global_step failed: %s", e)
-        return loss
+                self._fault_injector.maybe_inject(self._global_step)
+            if loss is not None:
+                loss = float(loss)
+                if self._fault_injector is not None:
+                    # corruption drills (nan@N / sdc@N) poison the scalar
+                    # here so the sentinel sees exactly what a corrupting
+                    # host would produce
+                    loss = self._fault_injector.corrupt_loss(
+                        self._global_step, loss
+                    )
+                if self._sentinel is not None:
+                    self._sentinel.check(
+                        self._global_step, loss, grad_norm
+                    )
+            elif self._sentinel is not None:
+                # no scalar this step: still poll for rollback orders
+                # issued on another rank's anomaly
+                self._sentinel.poll_rollback_order()
+            if self._mesh_transition is not None:
+                # mesh-transition orders are adopted here (exactly-once by
+                # order id) and executed by the step loop at the boundary
+                # it chooses — see pending_reshard()
+                self._mesh_transition.poll_order()
+            if (
+                self._master_client is not None
+                and self._global_step % self._report_interval == 0
+            ):
+                try:
+                    self._master_client.report_global_step(
+                        self._global_step, time.time()
+                    )
+                except Exception as e:
+                    logger.warning("report_global_step failed: %s", e)
+            return loss
 
     # ---------------------------------------------------------- checkpoint
 

@@ -24,6 +24,7 @@ from dlrover_tpu.agent.relay import ENV_RELAY_ADDR, ENV_RELAY_FANOUT, RelayTier
 from dlrover_tpu.common.constants import NodeEnv
 from dlrover_tpu.common.grpc_utils import addr_connected
 from dlrover_tpu.common.log import default_logger as logger
+from dlrover_tpu.telemetry import tracing
 
 
 def parse_args(argv=None):
@@ -76,6 +77,11 @@ def _parse_nnodes(spec: str) -> Tuple[int, int]:
 def launch_local_master(node_num: int = 1) -> Tuple[subprocess.Popen, str]:
     """Start a standalone master subprocess and discover its port
     (parity: elastic_run.py:106)."""
+    with tracing.span("launch.master_start"):
+        return _launch_local_master(node_num)
+
+
+def _launch_local_master(node_num: int) -> Tuple[subprocess.Popen, str]:
     proc = subprocess.Popen(
         [
             sys.executable, "-m", "dlrover_tpu.master.main",
@@ -106,6 +112,13 @@ def launch_local_master(node_num: int = 1) -> Tuple[subprocess.Popen, str]:
 
 
 def run(args) -> int:
+    # the root of the launcher process's spans: master start, the
+    # agent's rendezvous, spawns and restarts all sit under it
+    with tracing.span("launch.run"):
+        return _run(args)
+
+
+def _run(args) -> int:
     min_nodes, max_nodes = _parse_nnodes(args.nnodes)
     master_proc: Optional[subprocess.Popen] = None
     master_addr = args.master_addr

@@ -172,16 +172,21 @@ class ShardedTrainer:
                 ),
                 batch,
             )
+            # stable names in the lowered program and the device
+            # trace: "loss" is forward and backward, "optimizer" the
+            # update and its application
             if accum == 1:
-                loss, grads = grad_fn(
-                    params, jax.tree.map(lambda x: x[0], batch)
-                )
+                with jax.named_scope("loss"):
+                    loss, grads = grad_fn(
+                        params, jax.tree.map(lambda x: x[0], batch)
+                    )
                 grads = constrain_grads(grads)
             else:
 
                 def micro(carry, mb):
                     loss_sum, grads_sum = carry
-                    loss, grads = grad_fn(params, mb)
+                    with jax.named_scope("loss"):
+                        loss, grads = grad_fn(params, mb)
                     grads = constrain_grads(grads)
                     return (
                         loss_sum + loss,
@@ -204,10 +209,11 @@ class ShardedTrainer:
                     lambda g, p: (g / accum).astype(p.dtype),
                     grads_sum, params,
                 )
-            updates, opt_state = self.optimizer.update(
-                grads, opt_state, params
-            )
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = self.optimizer.update(
+                    grads, opt_state, params
+                )
+                params = optax.apply_updates(params, updates)
             return params, opt_state, loss
 
         self._jit_step = jax.jit(
