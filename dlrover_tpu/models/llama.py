@@ -255,23 +255,39 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     )
 
 
-def _pre_attn(cfg: LlamaConfig, x, layer_params, cos, sin):
+# logical axes (parallel/sharding.py) of the activations a ``constrain``
+# callable pins
+_RESIDUAL = ("batch", "seq", "embed")
+_Q = ("batch", "seq", "heads", None)
+_KV = ("batch", "seq", "kv_heads", None)
+_MLP = ("batch", "seq", "mlp")
+
+
+def _free(x, logical_axes):
+    """The default ``constrain``: every layout is the compiler's choice,
+    which on one device is no choice at all."""
+    return x
+
+
+def _pre_attn(cfg: LlamaConfig, x, layer_params, cos, sin,
+              constrain=_free):
     """Block segment 1: attn-norm + q/k/v projections + rope."""
     b, s, h = x.shape
     nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     p = layer_params
-    y = rms_norm(x, p["attn_norm"], cfg.norm_eps)
-    q = (y @ p["wq"]).reshape(b, s, nh, hd)
-    k = (y @ p["wk"]).reshape(b, s, nkv, hd)
-    v = (y @ p["wv"]).reshape(b, s, nkv, hd)
+    y = rms_norm(constrain(x, _RESIDUAL), p["attn_norm"], cfg.norm_eps)
+    q = constrain((y @ p["wq"]).reshape(b, s, nh, hd), _Q)
+    k = constrain((y @ p["wk"]).reshape(b, s, nkv, hd), _KV)
+    v = constrain((y @ p["wv"]).reshape(b, s, nkv, hd), _KV)
     return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
 
 
-def _post_attn(cfg: LlamaConfig, x, attn, layer_params):
+def _post_attn(cfg: LlamaConfig, x, attn, layer_params,
+               constrain=_free):
     """Block segment 2: output projection + residual + MLP."""
     b, s, h = x.shape
     p = layer_params
-    x = x + attn.reshape(b, s, -1) @ p["wo"]
+    x = constrain(x + attn.reshape(b, s, -1) @ p["wo"], _RESIDUAL)
     y = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
     if cfg.num_experts > 0:
         from dlrover_tpu.parallel.moe import moe_mlp
@@ -281,18 +297,20 @@ def _post_attn(cfg: LlamaConfig, x, attn, layer_params):
             k=cfg.moe_top_k,
             capacity_factor=cfg.moe_capacity_factor,
         )
-        return x + out, aux
-    gate = jax.nn.silu(y @ p["w_gate"])
-    x = x + (gate * (y @ p["w_up"])) @ p["w_down"]
+        return constrain(x + out, _RESIDUAL), aux
+    gate = jax.nn.silu(constrain(y @ p["w_gate"], _MLP))
+    up = constrain(y @ p["w_up"], _MLP)
+    x = constrain(x + (gate * up) @ p["w_down"], _RESIDUAL)
     return x, jnp.zeros((), jnp.float32)
 
 
-def _block(cfg: LlamaConfig, x, layer_params, cos, sin, attn_fn):
+def _block(cfg: LlamaConfig, x, layer_params, cos, sin, attn_fn,
+           constrain=_free):
     """One decoder block. x: [batch, seq, hidden]. Returns (x, aux_loss)
     where aux_loss is the MoE balance loss (0 for dense)."""
-    q, k, v = _pre_attn(cfg, x, layer_params, cos, sin)
+    q, k, v = _pre_attn(cfg, x, layer_params, cos, sin, constrain)
     attn = attn_fn(q, k, v)
-    return _post_attn(cfg, x, attn, layer_params)
+    return _post_attn(cfg, x, attn, layer_params, constrain)
 
 
 def hidden_states(
@@ -300,17 +318,29 @@ def hidden_states(
     tokens: jax.Array,  # int32 [batch, seq]
     cfg: LlamaConfig,
     attn_fn=None,
+    constrain=None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Final-norm hidden states [batch, seq, hidden] + MoE aux loss."""
+    """Final-norm hidden states [batch, seq, hidden] + MoE aux loss.
+
+    ``constrain(x, logical_axes) -> x`` pins the layout of the
+    activations between the matmuls (the residual stream, q/k/v, the
+    two MLP products) to the strategy's rule table, so that a
+    partitioner faced with ``x[batch/n, seq, embed] @ w[embed/n, mlp]``
+    gathers the weight and leaves the activation where it is. None
+    leaves every layout to the compiler."""
     if attn_fn is None:
         attn_fn = partial(flash_attention, causal=True)
+    if constrain is None:
+        constrain = _free
     s = tokens.shape[1]
     cos, sin = rope_tables(s, cfg.head_dim, cfg.rope_theta)
-    x = params["embed"][tokens]
+    x = constrain(params["embed"][tokens], _RESIDUAL)
 
     def body(carry, layer_params):
         x, aux_sum = carry
-        x, aux = _block(cfg, x, layer_params, cos, sin, attn_fn)
+        x, aux = _block(
+            cfg, x, layer_params, cos, sin, attn_fn, constrain
+        )
         return (x, aux_sum + aux), None
 
     if cfg.remat == "dots_attn_out":
@@ -324,10 +354,10 @@ def hidden_states(
             jax.checkpoint_policies.dots_with_no_batch_dims_saveable
         )
         pre = jax.checkpoint(
-            partial(_pre_attn, cfg), policy=policy,
+            partial(_pre_attn, cfg, constrain=constrain), policy=policy,
         )
         post = jax.checkpoint(
-            partial(_post_attn, cfg), policy=policy,
+            partial(_post_attn, cfg, constrain=constrain), policy=policy,
         )
 
         def body(carry, layer_params):  # noqa: F811
@@ -349,6 +379,7 @@ def hidden_states(
     (x, aux), _ = jax.lax.scan(
         body, (x, jnp.zeros((), jnp.float32)), params["blocks"]
     )
+    x = constrain(x, _RESIDUAL)
     return rms_norm(x, params["final_norm"], cfg.norm_eps), aux
 
 
@@ -416,12 +447,15 @@ def _chunked_ce(x: jax.Array, lm_head: jax.Array, targets: jax.Array,
 
 def next_token_loss(
     params: Dict, batch: Tuple[jax.Array, jax.Array], cfg: LlamaConfig,
-    attn_fn=None,
+    attn_fn=None, constrain=None,
 ) -> jax.Array:
     """Mean next-token cross entropy. batch = (tokens, targets), both
-    int32 [batch, seq]; target < 0 masks the position out."""
+    int32 [batch, seq]; target < 0 masks the position out.
+    ``constrain``: see ``hidden_states``."""
     tokens, targets = batch
-    x, aux = hidden_states(params, tokens, cfg, attn_fn=attn_fn)
+    x, aux = hidden_states(
+        params, tokens, cfg, attn_fn=attn_fn, constrain=constrain
+    )
     if cfg.loss_chunk > 0:
         nll_sum, cnt = _chunked_ce(
             x, params["lm_head"], targets, cfg.loss_chunk

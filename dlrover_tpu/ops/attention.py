@@ -145,19 +145,11 @@ def make_sharded_attention(mesh, q_spec, kv_spec, causal: bool = True):
     from jax.sharding import PartitionSpec as P
 
     from dlrover_tpu.parallel.compat import shard_map
-
-    def fit(spec, shape):
-        parts = []
-        for dim, axes in zip(shape, tuple(spec) + (None,) * 4):
-            names = (axes,) if isinstance(axes, str) else (axes or ())
-            n = 1
-            for a in names:
-                n *= mesh.shape[a]
-            parts.append(axes if dim % n == 0 else None)
-        return parts
+    from dlrover_tpu.parallel.sharding import fit_spec
 
     def attn_fn(q, k, v):
-        qp, kp = fit(q_spec, q.shape), fit(kv_spec, k.shape)
+        qp = list(fit_spec(q_spec, q.shape, mesh))
+        kp = list(fit_spec(kv_spec, k.shape, mesh))
         if qp[2] is None or kp[2] is None:
             qp[2] = kp[2] = None  # heads split together or not at all
         kp[0] = qp[0]

@@ -29,6 +29,7 @@ Logical axis conventions used by dlrover_tpu.models:
   norm       — 1-D norm/bias scales
 """
 
+import math
 from typing import Any, Dict, Mapping, Optional, Tuple, Union
 
 import jax
@@ -52,9 +53,17 @@ def ddp_rules() -> Rules:
 
 
 def fsdp_rules() -> Rules:
-    """ZeRO-3: every param's largest shardable dim split over fsdp; batch
-    over data+fsdp. XLA's all-gather-on-use + reduce-scatter-on-grad is the
-    torch FSDP wrap (zero_optimization.py:126) done by the compiler."""
+    """ZeRO-3: every param split over fsdp on its first dim that is not
+    ``layers`` (for a matmul's weight the forward contraction dim);
+    batch over data+fsdp. The all-gather of a layer's weights before use
+    and the reduce-scatter of their gradients (printed by the TPU
+    compiler as an ``all-reduce-scatter`` fusion) are the torch FSDP
+    wrap (zero_optimization.py:126) done by the compiler, provided the
+    model pins its activations to ``batch`` (``constrain``, as
+    ``trainer.sharded.make_trainer_for_llama`` does on a mesh of more
+    than one device): a partitioner left free moves the smaller operand
+    of ``x[batch/n, seq, embed] @ w[embed/n, mlp]``, which at a few
+    sequences a chip is ``x``."""
     return {
         "batch": (DATA_AXIS, FSDP_AXIS),
         "embed": FSDP_AXIS,
@@ -289,9 +298,22 @@ def batch_sharding(mesh: Mesh, rules: Rules,
     )
 
 
+def fit_spec(spec: P, shape: Tuple[int, ...], mesh: Mesh) -> P:
+    """``spec`` with every dim of ``shape`` that its mesh axes do not
+    divide left whole instead (kv heads fewer than the tensor axis, a
+    batch smaller than the mesh)."""
+    parts = []
+    for dim, axes in zip(shape, tuple(spec) + (None,) * len(shape)):
+        names = (axes,) if isinstance(axes, str) else (axes or ())
+        n = math.prod(mesh.shape[a] for a in names)
+        parts.append(axes if dim % n == 0 else None)
+    return P(*parts)
+
+
 def constrain(x, mesh: Mesh, rules: Rules,
               logical_axes: Tuple[Optional[str], ...]):
     """In-model sharding hint (replaces the reference's explicit collective
-    mappings): ``constrain(h, mesh, rules, ("batch", "seq", "embed"))``."""
-    spec = spec_for_axes(logical_axes, rules, mesh)
+    mappings): ``constrain(h, mesh, rules, ("batch", "seq", "embed"))``.
+    A NamedSharding over ``mesh``, so it needs no mesh context."""
+    spec = fit_spec(spec_for_axes(logical_axes, rules, mesh), x.shape, mesh)
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))

@@ -261,6 +261,20 @@ def make_trainer_for_llama(
 
     if mesh is None:
         mesh = create_mesh([(shd.DATA_AXIS, 1), (shd.FSDP_AXIS, -1)])
+    rules = shd.get_rules(strategy)
+    constrain = None
+    if mesh.size > 1:
+        # pin the activations to the rule table's own axes: every
+        # matmul's input and output stay on their batch shards, so the
+        # only way left to resolve a weight's sharded contraction dim
+        # is to gather the weight (and reduce-scatter its gradient).
+        # Left free, GSPMD moves the smaller operand: it all-to-alls
+        # the activations onto the weight's shards and gathers the MLP
+        # hidden back whole. NamedShardings, so the loss can be jitted
+        # on its own outside a mesh context.
+        def constrain(x, logical_axes):
+            return shd.constrain(x, mesh, rules, logical_axes)
+
     if attn_fn is None and strategy == "sequence":
         # the sequence strategy's entire point: without ring attention
         # GSPMD gathers K/V and materializes the [seq, seq] scores —
@@ -276,7 +290,6 @@ def make_trainer_for_llama(
         # groups) explicitly — attention needs no collective for either
         from dlrover_tpu.ops.attention import make_sharded_attention
 
-        rules = shd.get_rules(strategy)
         attn_fn = make_sharded_attention(
             mesh,
             q_spec=shd.spec_for_axes(
@@ -287,7 +300,7 @@ def make_trainer_for_llama(
             ),
         )
     loss = lambda params, batch: llama.next_token_loss(  # noqa: E731
-        params, batch, cfg, attn_fn=attn_fn
+        params, batch, cfg, attn_fn=attn_fn, constrain=constrain
     )
     init = lambda rng: llama.init_params(rng, cfg)  # noqa: E731
     logger.info(

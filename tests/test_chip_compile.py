@@ -15,7 +15,9 @@ every test file.
 """
 
 import dataclasses
+import math
 import os
+import re
 
 import numpy as np
 import pytest
@@ -90,6 +92,146 @@ def _abstract_step_args(trainer, batch, seq):
         sharding=trainer.microbatch_sharding,
     )
     return (*trainer.abstract_state(), (tok, tok))
+
+
+# ---------------------------------------------------------------------------
+# a census of the collectives in a compiled step's text
+
+_ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2,
+             "s32": 4, "u32": 4, "f32": 4, "s64": 8, "f64": 8}
+_COLLECTIVES = ("all-gather", "all-reduce", "all-to-all",
+                "collective-permute", "reduce-scatter")
+#: instructions that move or cut a value and compute nothing: what an
+#: all-gather's operand may pass through on its way from a parameter
+_MOVES = ("copy", "copy-start", "copy-done", "bitcast", "reshape",
+          "transpose", "slice", "slice-start", "slice-done",
+          "dynamic-slice")
+
+
+def _parse_hlo(text):
+    """``{computation: {instruction: (result, op, operands, attrs)}}``,
+    the instruction each fusion or loop body is called from, each
+    computation's root, and the entry computation's name."""
+    comps, called_from, roots, entry, cur = {}, {}, {}, None, None
+    for line in text.splitlines():
+        m = re.match(r"(ENTRY )?%([\w.\-]+) \(.*\) -> .*\{$", line)
+        if m:
+            cur = m.group(2)
+            comps[cur] = {}
+            entry = cur if m.group(1) else entry
+            continue
+        m = re.match(
+            r"\s+(ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\((.*)$", line
+        )
+        if not (m and cur):
+            continue
+        root, name, result, op, rest = m.groups()
+        args, _, attrs = rest.partition(")")
+        operands = re.findall(r"%([\w.\-]+)", args)
+        if op == "parameter":
+            operands = [args.strip()]  # its number
+        comps[cur][name] = (result, op, operands, attrs)
+        if root:
+            roots[cur] = name
+        for callee in re.findall(r"(?:calls|body)=%([\w.\-]+)", attrs):
+            called_from[callee] = (cur, name)
+    return comps, called_from, roots, entry
+
+
+def _origin(hlo, comp, name):
+    """``"parameter"`` if the value is an argument of the step (or a
+    cut of one: a layer of a scan-stacked weight) that nothing has
+    computed on yet, else ``"computed"``."""
+    comps, called_from, roots, entry = hlo
+    for _ in range(64):
+        _, op, operands, attrs = comps[comp][name]
+        if op == "parameter":
+            if comp == entry:
+                return "parameter"
+            if comp not in called_from:
+                return "computed"
+            comp, site = called_from[comp]
+            name = comps[comp][site][2][int(operands[0])]
+        elif op == "get-tuple-element":
+            index = int(re.search(r"index=(\d+)", attrs).group(1))
+            source = comps[comp][operands[0]]
+            if source[1] == "parameter" and comp in called_from:
+                # an element of a loop body's carry that the body
+                # hands on as it came (a weight): follow what the loop
+                # was started with
+                if comps[comp][roots[comp]][2][index] != name:
+                    return "computed"
+                comp, site = called_from[comp]
+                init = comps[comp][comps[comp][site][2][0]]
+                if init[1] != "tuple":
+                    return "computed"
+                name = init[2][index]
+            elif source[1] in ("copy-start", "slice-start"):
+                name = operands[0]
+            else:
+                return "computed"
+        elif op in _MOVES or (
+            op == "custom-call" and "ConcatBitcast" in attrs
+        ):
+            name = operands[0]
+        elif op == "fusion":
+            callee = re.search(r"calls=%([\w.\-]+)", attrs).group(1)
+            if any(
+                o not in _MOVES + ("parameter", "constant")
+                for _, o, _, _ in comps[callee].values()
+            ):
+                return "computed"
+            name = operands[0]  # a cut: the value is its first operand
+        else:
+            return "computed"
+    return "computed"
+
+
+def collective_census(text):
+    """One entry for each collective of a compiled step's text:
+    ``kind``, ``dtype``, ``shape`` and ``bytes`` of its (largest)
+    result, ``in_loop``, ``origin`` of its operand (``_origin``) and
+    ``scatter`` (inside an ``all-reduce-scatter`` fusion, which is how
+    this compiler prints a reduce-scatter). An all-gather the compiler
+    has split into the three stages of an asynchronous fusion counts
+    once, at its start."""
+    hlo = _parse_hlo(text)
+    comps, called_from = hlo[:2]
+
+    def in_loop(comp):
+        while comp in called_from:
+            comp, name = called_from[comp]
+            if "body=" in comps[comp][name][3]:
+                return True
+        return False
+
+    census = []
+    for comp, instructions in comps.items():
+        for name, (result, op, operands, _) in instructions.items():
+            kind = op[:-6] if op.endswith("-start") else op
+            if kind not in _COLLECTIVES:
+                continue
+            caller = called_from.get(comp, ("", ""))[1]
+            if comp.startswith("async_collective_fusion") or (
+                caller.startswith("async-collective-done")
+            ):
+                continue  # a later stage of a gather counted at its start
+            sized = [
+                (math.prod(int(d) for d in dims.split(",") if d)
+                 * _ITEMSIZE[dtype], dtype, dims)
+                for dtype, dims in re.findall(
+                    r"\b([a-z]+\d+|pred)\[([\d,]*)\]", result
+                )
+            ]
+            size, dtype, dims = max(sized)
+            census.append({
+                "kind": kind, "dtype": dtype, "bytes": size,
+                "shape": tuple(int(d) for d in dims.split(",") if d),
+                "in_loop": in_loop(comp),
+                "origin": _origin(hlo, comp, operands[0]),
+                "scatter": comp.startswith("all-reduce-scatter"),
+            })
+    return census
 
 
 def _kernel_args(one_chip):
@@ -167,3 +309,57 @@ def test_fsdp_step_lowers_over_four_chips(topo, on_tpu_path):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "all-gather" in text and "reduce-scatter" in text
+
+
+def test_fsdp_step_gathers_weights_not_activations(topo, on_tpu_path):
+    """Mistral-7B widths, two layers, 4 x 4096 over the four chips
+    (the yardstick's ``fsdp4-4x4096`` at a depth that compiles in
+    seconds). With the activations pinned to their batch shards the
+    only traffic left is ZeRO-3's: each layer's seven weights gathered
+    once for the forward and once for the backward, their gradients
+    reduce-scattered. Left free, the partitioner all-to-alls the
+    activations onto the weights' shards instead (seven a layer) and
+    gathers the ``[4, 4096, 14336]`` MLP hidden back whole."""
+    cfg = llama.LlamaConfig(
+        vocab_size=32000, hidden_size=4096, intermediate_size=14336,
+        num_layers=2, num_heads=32, num_kv_heads=8, max_seq_len=4096,
+        remat="dots_attn_out",
+    )
+    batch, seq = 4, 4096
+    mesh = Mesh(
+        np.array(topo.devices).reshape(1, 4), ("data", "fsdp")
+    )
+    trainer = make_trainer_for_llama(
+        cfg, mesh, strategy="fsdp", optimizer=optax.adamw(1e-4),
+    )
+    compiled = trainer.train_step.lower(
+        *_abstract_step_args(trainer, batch, seq)
+    ).compile(compiler_options=LEAST_EFFORT)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    census = collective_census(text)
+    kinds = {c["kind"] for c in census}
+    assert "all-to-all" not in kinds, [
+        c for c in census if c["kind"] == "all-to-all"
+    ]
+    gathers = [c for c in census if c["kind"] == "all-gather"]
+    assert not [
+        c for c in gathers
+        if batch in c["shape"] and cfg.intermediate_size in c["shape"]
+    ]
+    # inside the two loops: the layer's weights, each exactly twice
+    in_loop = [c for c in gathers if c["in_loop"]]
+    assert len(in_loop) == 2 * 7, in_loop
+    assert all(c["origin"] == "parameter" for c in in_loop), in_loop
+    # above 64 MB nothing but weights is gathered, with one exception
+    # outside the loops: the embedding's cotangent, which the scatter-
+    # add into a vocab-sharded table needs whole (134 MB once a step;
+    # reducing a whole table's gradient instead would move 262 MB)
+    large = [
+        c for c in gathers
+        if c["bytes"] > 64e6 and c["origin"] != "parameter"
+    ]
+    assert [(c["shape"], c["in_loop"]) for c in large] in (
+        [], [((batch, seq, cfg.hidden_size), False)],
+    ), large
+    assert sum(c["scatter"] for c in census) >= 7, census
