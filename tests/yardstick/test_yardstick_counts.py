@@ -1,6 +1,7 @@
 """The yardstick's operation and byte counts against numbers worked
 by hand from the published sizes."""
 
+import importlib
 import json
 import os
 
@@ -71,6 +72,48 @@ def test_roofline_names_the_memory_bound():
     assert (seconds, bound) == (pytest.approx(1.0), "memory")
 
 
-def test_unknown_family_has_no_counts():
-    with pytest.raises(ValueError):
-        counts.shape({"family": "mamba"})
+@pytest.mark.parametrize("module,name,rest", [
+    ("counts", "shape", ()),
+    ("counts", "matmul_params", ()),
+    ("counts", "train_flops_per_token", (64,)),
+    ("counts", "attention_kernel_step", (1, 64)),
+    ("worker", "program_config", ({},)),
+    ("reference", "loss", ({}, None, None)),
+])
+def test_unknown_family_has_no_counts(module, name, rest):
+    """No default family: the file that does not exist is named."""
+    ask = getattr(importlib.import_module("yardstick." + module), name)
+    with pytest.raises(
+        cells.UnknownName, match=r"yardstick/(families|references)/"
+        r"mamba\.py does not exist",
+    ):
+        ask({"family": "mamba"}, *rest)
+
+
+#: taken from the parent commit (9b7561d), where the counts were one
+#: chain of branches in counts.py: configuration -> sequence length,
+#: sequences a chip, and the four counts at those
+PARENT_COUNTS = {
+    "mistral-7b-l4.steady": (
+        4096, 3.0, 1003487232, 6423576576.0,
+        (5772436045824.0, 3019898880.0)),
+    "gpt2-xl.steady": (
+        1024, 12.0, 1554971200, 9801686400.0,
+        (6764573491200.0, 22649241600.0)),
+    "mistral-7b-l16.fsdp4": (
+        4096, 1.0, 3620732928, 23335010304.0,
+        (7696581394432.0, 4026531840.0)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(PARENT_COUNTS))
+def test_the_move_into_family_files_changed_no_count(cell):
+    seq, per_chip, params, train, kernel = PARENT_COUNTS[cell]
+    entry, c, traffic = cells.load_cell(cell, cells.benchmark(
+        os.path.join(cells.CHECKOUT, "BENCHMARK.json")))
+    assert traffic["seq"] == seq
+    assert traffic["global_batch"] / entry["chips"] == per_chip
+    got = counts.matmul_params(c)
+    assert (got, type(got)) == (params, int)
+    assert counts.train_flops_per_token(c, seq) == train
+    assert counts.attention_kernel_step(c, per_chip, seq) == kernel

@@ -3,8 +3,9 @@
 A cell names a configuration and a traffic mix; the configuration is
 ``configs/<config>.json``, the mix ``traffic/<traffic>.json``, the
 mix's ``kind`` the module ``kinds/<kind>.py``, a per-layer metric the
-module ``layer_metrics/<metric>.py``. Nothing here, in ``run.py`` or
-in ``worker.py`` names one of them.
+module ``layer_metrics/<metric>.py``, the configuration's ``family``
+the modules ``families/<family>.py`` and ``references/<family>.py``.
+Nothing here, in ``run.py`` or in ``worker.py`` names one of them.
 """
 
 import importlib
@@ -16,8 +17,8 @@ CHECKOUT = os.path.dirname(HERE)
 
 
 class UnknownName(Exception):
-    """A cell, configuration, mix, kind, metric or device kind that
-    no file or entry defines."""
+    """A cell, configuration, mix, kind, metric, family or device
+    kind that no file or entry defines."""
 
 
 def _read_json(path, what):
@@ -86,6 +87,13 @@ def _module(package, name, what):
 
 def kind_module(traffic):
     return _module("kinds", traffic["kind"], "job kind")
+
+
+def family_module(config, package="families"):
+    """What the yardstick knows of the configuration's family: under
+    ``families`` its sizes, counts and program config (no JAX at
+    import), under ``references`` its plain forward loss."""
+    return _module(package, config["family"], "family")
 
 
 def metric_module(name):

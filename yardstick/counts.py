@@ -2,7 +2,9 @@
 a later PR that claims a gain cannot change what a token costs.
 
 Every function takes a configuration as its file holds it (the
-source's own key names) and never imports the program.
+source's own key names) and never imports the program. What differs
+from family to family is in ``families/<family>.py``, found by the
+configuration's ``family``; nothing here names one.
 
 Conventions, stated once:
 
@@ -19,46 +21,44 @@ Conventions, stated once:
   The output head is one, tied or not.
 """
 
+import functools
+
+from yardstick import cells
+
+
+def _family_first(dense):
+    """``dense``, unless the configuration's family file defines a
+    function of the same name: a family whose layers are windowed,
+    latent or linear counts them itself."""
+
+    @functools.wraps(dense)
+    def count(config, *args):
+        return getattr(
+            cells.family_module(config), dense.__name__, dense
+        )(config, *args)
+
+    return count
+
 
 def shape(config):
-    """The sizes the count needs, under one set of names for both
-    families."""
-    family = config["family"]
-    if family == "llama":
-        heads = config["num_attention_heads"]
-        return {
-            "family": family,
-            "hidden": config["hidden_size"],
-            "ffn": config["intermediate_size"],
-            "layers": config["num_hidden_layers"],
-            "heads": heads,
-            "kv_heads": config["num_key_value_heads"],
-            "head_dim": config.get(
-                "head_dim", config["hidden_size"] // heads
-            ),
-            "vocab": config["vocab_size"],
-            "ffn_matrices": 3,  # gate, up, down
-        }
-    if family == "gpt":
-        heads = config["n_head"]
-        return {
-            "family": family,
-            "hidden": config["n_embd"],
-            # GPT-2's rule where the source leaves it null
-            "ffn": config["n_inner"] or 4 * config["n_embd"],
-            "layers": config["n_layer"],
-            "heads": heads,
-            "kv_heads": heads,
-            "head_dim": config["n_embd"] // heads,
-            "vocab": config["vocab_size"],
-            "ffn_matrices": 2,  # fc, proj
-        }
-    raise ValueError(f"no counts for family {family!r}")
+    """The sizes the count needs, under one set of names for every
+    family: ``hidden``, ``ffn``, ``layers``, ``heads``, ``kv_heads``,
+    ``head_dim``, ``vocab``."""
+    return cells.family_module(config).shape(config)
 
 
 def matmul_params(config):
-    """Weights that a token is multiplied by in one forward pass."""
-    s = shape(config)
+    """Weights that a token is multiplied by in one forward pass:
+    the family's own count (``dense_matmul_params`` of its shape for
+    a dense block; the router and the experts a token is routed to,
+    not all of them, for a sparse one)."""
+    return cells.family_module(config).matmul_params(config)
+
+
+def dense_matmul_params(s):
+    """A dense decoder's ``matmul_params`` from its ``shape``, which
+    then also says how many matrices its feed-forward has
+    (``ffn_matrices``)."""
     h, d = s["hidden"], s["head_dim"]
     per_layer = (
         h * s["heads"] * d          # q
@@ -69,6 +69,7 @@ def matmul_params(config):
     return s["layers"] * per_layer + h * s["vocab"]
 
 
+@_family_first
 def attention_forward_flops_per_token(config, seq):
     """Scores and weighted values, causal, all layers: each of the
     two products costs ``seq * head_dim`` operations a token and
@@ -77,6 +78,7 @@ def attention_forward_flops_per_token(config, seq):
     return 2.0 * s["layers"] * s["heads"] * s["head_dim"] * seq
 
 
+@_family_first
 def train_flops_per_token(config, seq):
     """Forward and backward, no recomputation."""
     forward = (
@@ -86,6 +88,7 @@ def train_flops_per_token(config, seq):
     return 3.0 * forward
 
 
+@_family_first
 def attention_kernel_step(config, sequences, seq):
     """What the attention kernels of one training step must do for
     ``sequences`` sequences on one chip, all layers: ``(flops,

@@ -6,11 +6,12 @@ own copy: ``init_from_env``, the master client, a trainer from
 ``models.make_trainer_for`` over the mesh the example builds, the
 coworker shm data plane, the flash checkpointer with its default RAM
 tier, the elastic reporter (hang detection, fault injection). What it
-adds: the model comes from a configuration file, the job from a
-traffic file, and the loop from ``kinds/<kind>.py``; and it appends
-what it saw, as JSON lines, to the report that ``run.py`` reads.
+adds: the model comes from a configuration file (through its
+family's ``families/<family>.py``), the job from a traffic file, and
+the loop from ``kinds/<kind>.py``; and it appends what it saw, as JSON
+lines, to the report that ``run.py`` reads.
 
-It holds nothing that names a cell.
+It holds nothing that names a cell or a family.
 """
 
 import time
@@ -68,49 +69,7 @@ class SeededTokens:
 def program_config(config, traffic):
     """The program's own config object from the configuration file
     (the source's key names) and the mix's step settings."""
-    import jax.numpy as jnp
-
-    dtype = jnp.dtype(config["dtype"])
-    if config["family"] == "llama":
-        from dlrover_tpu.models.llama import LlamaConfig
-
-        heads = config["num_attention_heads"]
-        if config["hidden_size"] != heads * config["head_dim"]:
-            raise ValueError(
-                "models/llama.py derives the head size from the "
-                "hidden size: this configuration's differs"
-            )
-        return LlamaConfig(
-            vocab_size=config["vocab_size"],
-            hidden_size=config["hidden_size"],
-            intermediate_size=config["intermediate_size"],
-            num_layers=config["num_hidden_layers"],
-            num_heads=heads,
-            num_kv_heads=config["num_key_value_heads"],
-            max_seq_len=traffic["seq"],
-            rope_theta=config["rope_theta"],
-            norm_eps=config["rms_norm_eps"],
-            dtype=dtype, remat=traffic["remat"],
-            loss_chunk=traffic["loss_chunk"],
-        )
-    if config["family"] == "gpt":
-        from dlrover_tpu.models.gpt import GPTConfig
-
-        return GPTConfig(
-            vocab_size=config["vocab_size"],
-            hidden_size=config["n_embd"],
-            # GPT-2's rule where the source leaves it null
-            intermediate_size=(config["n_inner"]
-                               or 4 * config["n_embd"]),
-            num_layers=config["n_layer"],
-            num_heads=config["n_head"],
-            max_seq_len=config["n_positions"],
-            norm_eps=config["layer_norm_epsilon"],
-            tie_lm_head=config["tie_word_embeddings"],
-            dtype=dtype, remat=traffic["remat"],
-            loss_chunk=traffic["loss_chunk"],
-        )
-    raise cells.UnknownName(f"no family {config['family']!r}")
+    return cells.family_module(config).program_config(config, traffic)
 
 
 class Context:
