@@ -53,10 +53,25 @@ class LlamaConfig:
     # (0 = off). Saves ~vocab/hidden x activation memory at the head.
     loss_chunk: int = 0
     # MoE (0 = dense): replaces every block's MLP with a top-k routed
-    # expert SwiGLU (parallel/moe.py); experts shard on the expert axis
+    # expert SwiGLU (parallel/moe.py). With every expert on the device
+    # the routing is dropless; over an ``expert`` mesh axis larger
+    # than one it is bucketed by ``moe_capacity_factor`` and overflow
+    # is dropped. A factor of 0 states dropless routing, which such a
+    # mesh refuses instead of running it with drops.
     num_experts: int = 0
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
+    # the source's own keys (``OlmoeConfig``): whether the k routing
+    # weights are renormalised to sum to one; the load-balance loss's
+    # coefficient; and, not a key of that config but of its paper, the
+    # router z-loss's. Both losses are summed over layers.
+    norm_topk_prob: bool = True
+    router_aux_loss_coef: float = 0.01
+    router_z_loss_coef: float = 0.001
+    # RMSNorm of the whole q and k projections, before the split into
+    # heads and the rotary embedding (``OlmoeAttention``'s q_norm,
+    # k_norm)
+    qk_norm: bool = False
 
     def __post_init__(self):
         if self.remat not in ("off", "dots", "dots_attn_out",
@@ -144,6 +159,9 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Dict:
         "wo": dense_init(ks[3], L, nh * hd, h, in_axis=1),
         "mlp_norm": norm_init(L, h),
     }
+    if cfg.qk_norm:
+        block["q_norm"] = norm_init(L, nh * hd)
+        block["k_norm"] = norm_init(L, nkv * hd)
     if cfg.num_experts > 0:
         E = cfg.num_experts
         block.update({
@@ -180,6 +198,9 @@ def param_axes(cfg: LlamaConfig) -> Dict:
         "wo": ("layers", "heads", "embed"),
         "mlp_norm": ("layers", "norm"),
     }
+    if cfg.qk_norm:
+        blocks["q_norm"] = ("layers", "norm")
+        blocks["k_norm"] = ("layers", "norm")
     if cfg.num_experts > 0:
         blocks.update({
             "router": ("layers", "embed", None),
@@ -210,6 +231,7 @@ def param_count(cfg: LlamaConfig) -> int:
         mlp = 3 * h * m
     per_layer = (
         2 * h  # norms
+        + (nh * hd + nkv * hd if cfg.qk_norm else 0)
         + h * nh * hd + 2 * h * nkv * hd + nh * hd * h  # attention
         + mlp
     )
@@ -276,26 +298,52 @@ def _pre_attn(cfg: LlamaConfig, x, layer_params, cos, sin,
     nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     p = layer_params
     y = rms_norm(constrain(x, _RESIDUAL), p["attn_norm"], cfg.norm_eps)
-    q = constrain((y @ p["wq"]).reshape(b, s, nh, hd), _Q)
-    k = constrain((y @ p["wk"]).reshape(b, s, nkv, hd), _KV)
+    q, k = y @ p["wq"], y @ p["wk"]
+    if cfg.qk_norm:
+        q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, p["k_norm"], cfg.norm_eps)
+    q = constrain(q.reshape(b, s, nh, hd), _Q)
+    k = constrain(k.reshape(b, s, nkv, hd), _KV)
     v = constrain((y @ p["wv"]).reshape(b, s, nkv, hd), _KV)
     return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
 
 
+def _expert_mlp(cfg: LlamaConfig, expert_parallel: bool):
+    """The routed MLP ``(y, router, w_gate, w_up, w_down) -> (out,
+    aux)`` of an expert config: dropless where every expert is on the
+    device, capacity-bucketed where they are sharded over an
+    ``expert`` mesh axis (parallel/moe.py)."""
+    from dlrover_tpu.parallel import moe
+
+    routing = dict(
+        k=cfg.moe_top_k, norm_topk_prob=cfg.norm_topk_prob,
+        balance_coef=cfg.router_aux_loss_coef,
+        z_coef=cfg.router_z_loss_coef,
+    )
+    if not expert_parallel:
+        return partial(moe.dropless_moe_mlp, **routing)
+    if cfg.moe_capacity_factor <= 0:
+        raise ValueError(
+            "this configuration states dropless routing "
+            "(moe_capacity_factor 0), and experts sharded over an "
+            "'expert' mesh axis are bucketed by capacity and drop "
+            "what overflows: refused, not run with drops"
+        )
+    return partial(
+        moe.moe_mlp, capacity_factor=cfg.moe_capacity_factor, **routing
+    )
+
+
 def _post_attn(cfg: LlamaConfig, x, attn, layer_params,
-               constrain=_free):
+               constrain=_free, expert_parallel=False):
     """Block segment 2: output projection + residual + MLP."""
     b, s, h = x.shape
     p = layer_params
     x = constrain(x + attn.reshape(b, s, -1) @ p["wo"], _RESIDUAL)
     y = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
     if cfg.num_experts > 0:
-        from dlrover_tpu.parallel.moe import moe_mlp
-
-        out, aux = moe_mlp(
-            y, p["router"], p["w_gate"], p["w_up"], p["w_down"],
-            k=cfg.moe_top_k,
-            capacity_factor=cfg.moe_capacity_factor,
+        out, aux = _expert_mlp(cfg, expert_parallel)(
+            y, p["router"], p["w_gate"], p["w_up"], p["w_down"]
         )
         return constrain(x + out, _RESIDUAL), aux
     gate = jax.nn.silu(constrain(y @ p["w_gate"], _MLP))
@@ -305,12 +353,31 @@ def _post_attn(cfg: LlamaConfig, x, attn, layer_params,
 
 
 def _block(cfg: LlamaConfig, x, layer_params, cos, sin, attn_fn,
-           constrain=_free):
+           constrain=_free, expert_parallel=False):
     """One decoder block. x: [batch, seq, hidden]. Returns (x, aux_loss)
     where aux_loss is the MoE balance loss (0 for dense)."""
     q, k, v = _pre_attn(cfg, x, layer_params, cos, sin, constrain)
     attn = attn_fn(q, k, v)
-    return _post_attn(cfg, x, attn, layer_params, constrain)
+    return _post_attn(
+        cfg, x, attn, layer_params, constrain, expert_parallel
+    )
+
+
+def _dots_policy(cfg: LlamaConfig):
+    """What the ``dots`` remat policies keep: the results of the plain
+    matmuls and, of an expert layer, the gate and up products of the
+    grouped matmul (a ``ragged_dot`` is no ``dot_general``, so the
+    stock policy would recompute both in the backward pass; the sorted
+    rows and the down product's input are cheap to make again)."""
+    dots = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+    if cfg.num_experts == 0:
+        return dots
+    return jax.checkpoint_policies.save_from_both_policies(
+        dots,
+        jax.checkpoint_policies.save_only_these_names(
+            "moe_gate", "moe_up"
+        ),
+    )
 
 
 def hidden_states(
@@ -319,8 +386,11 @@ def hidden_states(
     cfg: LlamaConfig,
     attn_fn=None,
     constrain=None,
+    expert_parallel: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """Final-norm hidden states [batch, seq, hidden] + MoE aux loss.
+    ``expert_parallel``: the experts are sharded over an ``expert``
+    mesh axis (the trainer says so from its mesh).
 
     ``constrain(x, logical_axes) -> x`` pins the layout of the
     activations between the matmuls (the residual stream, q/k/v, the
@@ -339,7 +409,8 @@ def hidden_states(
     def body(carry, layer_params):
         x, aux_sum = carry
         x, aux = _block(
-            cfg, x, layer_params, cos, sin, attn_fn, constrain
+            cfg, x, layer_params, cos, sin, attn_fn, constrain,
+            expert_parallel,
         )
         return (x, aux_sum + aux), None
 
@@ -350,14 +421,14 @@ def hidden_states(
         # activations, so the backward pass never re-runs the forward
         # kernel (under plain "dots" the re-fwd is ~7% of the step).
         # Costs the saved residuals' HBM (~q+k+v+o+lse per layer).
-        policy = (
-            jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-        )
+        policy = _dots_policy(cfg)
         pre = jax.checkpoint(
             partial(_pre_attn, cfg, constrain=constrain), policy=policy,
         )
         post = jax.checkpoint(
-            partial(_post_attn, cfg, constrain=constrain), policy=policy,
+            partial(_post_attn, cfg, constrain=constrain,
+                    expert_parallel=expert_parallel),
+            policy=policy,
         )
 
         def body(carry, layer_params):  # noqa: F811
@@ -368,10 +439,7 @@ def hidden_states(
             return (x, aux_sum + aux), None
 
     elif cfg.remat == "dots":
-        body = jax.checkpoint(
-            body,
-            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-        )
+        body = jax.checkpoint(body, policy=_dots_policy(cfg))
     elif cfg.remat == "minimal":
         body = jax.checkpoint(
             body, policy=jax.checkpoint_policies.nothing_saveable
@@ -447,14 +515,16 @@ def _chunked_ce(x: jax.Array, lm_head: jax.Array, targets: jax.Array,
 
 def next_token_loss(
     params: Dict, batch: Tuple[jax.Array, jax.Array], cfg: LlamaConfig,
-    attn_fn=None, constrain=None,
+    attn_fn=None, constrain=None, expert_parallel: bool = False,
 ) -> jax.Array:
-    """Mean next-token cross entropy. batch = (tokens, targets), both
-    int32 [batch, seq]; target < 0 masks the position out.
-    ``constrain``: see ``hidden_states``."""
+    """Mean next-token cross entropy (plus, for an expert config, the
+    scaled balance and z losses of every layer). batch = (tokens,
+    targets), both int32 [batch, seq]; target < 0 masks the position
+    out. ``constrain``, ``expert_parallel``: see ``hidden_states``."""
     tokens, targets = batch
     x, aux = hidden_states(
-        params, tokens, cfg, attn_fn=attn_fn, constrain=constrain
+        params, tokens, cfg, attn_fn=attn_fn, constrain=constrain,
+        expert_parallel=expert_parallel,
     )
     if cfg.loss_chunk > 0:
         nll_sum, cnt = _chunked_ce(
@@ -464,7 +534,37 @@ def next_token_loss(
         logits = (x @ params["lm_head"]).astype(jnp.float32)
         nll_sum, cnt = _masked_nll(logits, targets)
     ce = nll_sum / jnp.maximum(cnt, 1.0)
-    return ce + aux  # aux arrives pre-scaled (parallel/moe.py coefs)
+    return ce + aux  # aux arrives scaled (the config's coefficients)
+
+
+def routing_stats(params: Dict, tokens: jax.Array, cfg: LlamaConfig,
+                  attn_fn=None) -> jax.Array:
+    """Tokens per expert and layer, int32 [layers, experts], for
+    ``tokens`` [batch, seq]: each row sums to ``batch x seq x
+    moe_top_k``. A forward pass that also records, a layer, what the
+    router chose for the hidden states it really sees (jit-able)."""
+    if cfg.num_experts == 0:
+        raise ValueError("routing_stats: a dense config has no router")
+    from dlrover_tpu.parallel.moe import tokens_per_expert
+
+    if attn_fn is None:
+        attn_fn = partial(flash_attention, causal=True)
+    cos, sin = rope_tables(tokens.shape[1], cfg.head_dim, cfg.rope_theta)
+
+    def body(x, p):
+        q, k, v = _pre_attn(cfg, x, p, cos, sin)
+        attn = attn_fn(q, k, v)
+        seen = rms_norm(
+            x + attn.reshape(*x.shape[:2], -1) @ p["wo"],
+            p["mlp_norm"], cfg.norm_eps,
+        )
+        x, _ = _post_attn(cfg, x, attn, p)
+        return x, tokens_per_expert(seen, p["router"], cfg.moe_top_k)
+
+    _, counts = jax.lax.scan(
+        body, params["embed"][tokens], params["blocks"]
+    )
+    return counts
 
 
 def flops_per_token(cfg: LlamaConfig, seq_len: int) -> float:

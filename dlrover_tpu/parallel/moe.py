@@ -13,6 +13,15 @@ reference wrote by hand — and the expert/non-expert gradient split falls
 out of the sharding rules (expert params simply aren't replicated), no
 special DDP needed. Gating runs in fp32; an auxiliary load-balance loss
 (Switch-style) and router z-loss are returned for the trainer to add.
+
+Two paths, one routing rule. Where every expert lives on the device
+(no ``expert`` mesh axis), ``dropless_moe_mlp``: the ``tokens x k``
+assignments are sorted by expert, gathered into expert order, run
+through one grouped matmul a projection (ops/grouped_matmul.py) and
+gathered back. No ``[N, E, C]`` tensor, no capacity: every assignment
+is computed. Where experts are sharded over an ``expert`` axis,
+``moe_mlp``: the capacity-bucketed einsums above, which drop what
+overflows. The model file chooses between them by the mesh.
 """
 
 from typing import Tuple
@@ -26,10 +35,30 @@ BALANCE_LOSS_COEF = 1e-2
 Z_LOSS_COEF = 1e-3
 
 
+def expert_counts(experts: jax.Array, e: int) -> jax.Array:
+    """How many of the assignments ``experts`` (int, any shape) each of
+    the ``e`` experts received: int32 [e], sums to ``experts.size``."""
+    return jnp.sum(
+        jax.nn.one_hot(experts.reshape(-1), e, dtype=jnp.int32), axis=0
+    )
+
+
+def balance_loss(probs: jax.Array, experts: jax.Array) -> jax.Array:
+    """Load-balance loss (Switch eq. 4 over all k choices, as
+    ``OlmoeForCausalLM``'s ``load_balancing_loss_func`` counts them):
+    ``E * sum_e f_e * p_e``, ``f_e`` the share of the ``N x k``
+    assignments that expert e received, ``p_e`` its mean router
+    probability. ``probs`` [N, E] float32, ``experts`` int [N, k]."""
+    e = probs.shape[-1]
+    f = expert_counts(experts, e) / experts.size
+    return e * jnp.sum(f * jnp.mean(probs, axis=0))
+
+
 def topk_gating(
     logits: jax.Array,  # [tokens, experts] fp32
     k: int,
     capacity: int,
+    norm_topk_prob: bool = True,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Top-k routing with per-expert capacity.
 
@@ -39,13 +68,7 @@ def topk_gating(
     """
     n, e = logits.shape
     probs = jax.nn.softmax(logits, axis=-1)
-
-    # aux load-balance loss (Switch eq.4): E * sum_e f_e * p_e, using the
-    # top-1 assignment fraction f_e and mean router prob p_e
-    top1 = jnp.argmax(probs, axis=-1)
-    f = jnp.mean(jax.nn.one_hot(top1, e, dtype=jnp.float32), axis=0)
-    p = jnp.mean(probs, axis=0)
-    aux_loss = e * jnp.sum(f * p)
+    aux_loss = balance_loss(probs, jax.lax.top_k(probs, k)[1])
 
     dispatch = jnp.zeros((n, e, capacity), jnp.float32)
     combine = jnp.zeros((n, e, capacity), jnp.float32)
@@ -75,7 +98,7 @@ def topk_gating(
         combine = combine + contrib * gate[:, None, None]
         masked_probs = masked_probs * (1.0 - onehot)  # exclude chosen
 
-    if k > 1:
+    if k > 1 and norm_topk_prob:
         # renormalize combine weights over the selected experts
         denom = jnp.sum(combine, axis=(1, 2), keepdims=True)
         combine = combine / jnp.where(denom == 0.0, 1.0, denom)
@@ -93,8 +116,13 @@ def moe_mlp(
     w_down: jax.Array,  # [experts, mlp, hidden]
     k: int = 2,
     capacity_factor: float = 1.25,
+    norm_topk_prob: bool = True,
+    balance_coef: float = BALANCE_LOSS_COEF,
+    z_coef: float = Z_LOSS_COEF,
 ) -> Tuple[jax.Array, jax.Array]:
-    """MoE SwiGLU block: route -> expert compute -> combine.
+    """MoE SwiGLU block: route -> expert compute -> combine, with a
+    capacity an expert (overflow is dropped): the path for experts
+    sharded over an ``expert`` mesh axis.
 
     Returns (out [batch, seq, hidden], aux_loss). ``aux_loss`` is FULLY
     scaled (balance + z-loss coefficients applied here) — callers add it
@@ -111,11 +139,13 @@ def moe_mlp(
     router_logits = (flat.astype(jnp.float32)
                      @ gate_w.astype(jnp.float32))  # [N, E]
     # router z-loss keeps logits small (stability on bf16)
-    z_loss = Z_LOSS_COEF * jnp.mean(
+    z_loss = z_coef * jnp.mean(
         jax.nn.logsumexp(router_logits, axis=-1) ** 2
     )
-    dispatch, combine, balance = topk_gating(router_logits, k, capacity)
-    aux = BALANCE_LOSS_COEF * balance + z_loss
+    dispatch, combine, balance = topk_gating(
+        router_logits, k, capacity, norm_topk_prob
+    )
+    aux = balance_coef * balance + z_loss
 
     xe = jnp.einsum(
         "nec,nd->ecd", dispatch.astype(x.dtype), flat
@@ -127,3 +157,179 @@ def moe_mlp(
         "nec,ecd->nd", combine.astype(x.dtype), ye
     ).reshape(b, s, h)
     return out, aux
+
+
+# -- the dropless path ------------------------------------------------------
+
+@jax.custom_vjp
+def _to_expert_order(flat, order, inverse):
+    """Row i of the result is the token of assignment ``order[i]``:
+    ``flat[order // k]``, [N * k, H]. ``order`` is a permutation of the
+    ``N x k`` assignments (token-major) and ``inverse`` its inverse, so
+    the transpose is a gather too (each token's k rows, summed) and
+    not the scatter-add autodiff would write."""
+    return flat[order // (order.shape[0] // flat.shape[0])]
+
+
+def _to_expert_order_fwd(flat, order, inverse):
+    k = order.shape[0] // flat.shape[0]
+    return flat[order // k], (inverse, flat.shape[0])
+
+
+def _to_expert_order_bwd(res, g):
+    inverse, n = res
+    back = g[inverse].reshape(n, -1, g.shape[-1])
+    return (
+        jnp.sum(back.astype(jnp.float32), axis=1).astype(g.dtype),
+        None, None,
+    )
+
+
+_to_expert_order.defvjp(_to_expert_order_fwd, _to_expert_order_bwd)
+
+
+@jax.custom_vjp
+def _to_token_order(rows, order, inverse):
+    """``rows[inverse]``: the expert-ordered rows back in assignment
+    order (token-major). A permutation, so its transpose is the gather
+    by ``order``."""
+    return rows[inverse]
+
+
+def _to_token_order_fwd(rows, order, inverse):
+    return rows[inverse], order
+
+
+def _to_token_order_bwd(order, g):
+    return g[order], None, None
+
+
+_to_token_order.defvjp(_to_token_order_fwd, _to_token_order_bwd)
+
+
+def route(
+    flat: jax.Array,  # [N, H]
+    gate_w: jax.Array,  # [H, E]
+    k: int,
+    norm_topk_prob: bool,
+    balance_coef: float = BALANCE_LOSS_COEF,
+    z_coef: float = Z_LOSS_COEF,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Router of the dropless path: ``(weights [N, k] float32,
+    experts int32 [N, k], aux)``. The weights are the float32
+    softmax's own top-k values (ties to the lower index, as
+    ``jax.lax.top_k`` gives them), renormalised over the k only where
+    the configuration says so; at k = 1 they stay raw in either case,
+    or the router would get no gradient through the LM loss. ``aux``
+    is scaled: the balance loss over all k choices plus the z-loss."""
+    logits = flat.astype(jnp.float32) @ gate_w.astype(jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, experts = jax.lax.top_k(probs, k)
+    aux = balance_coef * balance_loss(probs, experts) + z_coef * (
+        jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
+    )
+    if norm_topk_prob and k > 1:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights, experts, aux
+
+
+def dropless_moe_mlp(
+    x: jax.Array,  # [batch, seq, hidden]
+    gate_w: jax.Array,  # [hidden, experts]
+    w_gate: jax.Array,  # [experts, hidden, mlp]
+    w_up: jax.Array,  # [experts, hidden, mlp]
+    w_down: jax.Array,  # [experts, mlp, hidden]
+    k: int = 2,
+    norm_topk_prob: bool = True,
+    balance_coef: float = BALANCE_LOSS_COEF,
+    z_coef: float = Z_LOSS_COEF,
+) -> Tuple[jax.Array, jax.Array]:
+    """MoE SwiGLU block in which every one of the ``N x k``
+    assignments is computed: ``(out [batch, seq, hidden], aux)``,
+    ``aux`` scaled as ``moe_mlp``'s. All experts on this device.
+
+    The four scopes name every device op's ``op_name``: ``moe.route``
+    (router, softmax, top-k, aux losses), ``moe.dispatch`` (stable
+    sort of the assignments by expert, gather into expert order),
+    ``moe.experts`` (the grouped matmuls; their two results are named
+    ``moe_gate`` and ``moe_up`` for a remat policy to keep),
+    ``moe.combine`` (gather back, sum over a token's k; the weights
+    were applied inside the experts)."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    from dlrover_tpu.ops.grouped_matmul import grouped_matmul
+
+    b, s, h = x.shape
+    e = gate_w.shape[-1]
+    n = b * s
+    flat = x.reshape(n, h)
+    with jax.named_scope("moe.route"):
+        weights, experts, aux = route(
+            flat, gate_w, k, norm_topk_prob, balance_coef, z_coef
+        )
+    with jax.named_scope("moe.dispatch"):
+        assigned = experts.reshape(n * k)
+        order = jnp.argsort(assigned, stable=True).astype(jnp.int32)
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(n * k, dtype=jnp.int32)
+        )
+        group_sizes = expert_counts(assigned, e)
+        rows = _to_expert_order(flat, order, inverse)
+    with jax.named_scope("moe.experts"):
+        gate = checkpoint_name(
+            grouped_matmul(rows, w_gate, group_sizes), "moe_gate"
+        )
+        up = checkpoint_name(
+            grouped_matmul(rows, w_up, group_sizes), "moe_up"
+        )
+        # the routing weight goes onto the down product's input: the
+        # product is linear in it, and the weight's gradient then
+        # needs that input (made again from the two kept products)
+        # and not the down product's result, which nothing keeps
+        hidden = (
+            (jax.nn.silu(gate) * up).astype(jnp.float32)
+            * weights.reshape(n * k)[order][:, None]
+        ).astype(x.dtype)
+        rows = grouped_matmul(hidden, w_down, group_sizes)
+    with jax.named_scope("moe.combine"):
+        mine = _to_token_order(rows, order, inverse).reshape(n, k, h)
+        out = jnp.sum(mine.astype(jnp.float32), axis=1).astype(x.dtype)
+    return out.reshape(b, s, h), aux
+
+
+def tokens_per_expert(
+    x: jax.Array, gate_w: jax.Array, k: int
+) -> jax.Array:
+    """How many of ``x``'s tokens the router sends to each expert,
+    int32 [experts]: sums to ``tokens x k``."""
+    flat = x.reshape(-1, x.shape[-1])
+    _, experts, _ = route(flat, gate_w, k, norm_topk_prob=False)
+    return expert_counts(experts, gate_w.shape[-1])
+
+
+def set_expert_load_gauges(counts) -> Tuple[float, float]:
+    """From tokens per expert and layer ([layers, experts], what
+    ``models.llama.routing_stats`` returns) set the gauges
+    ``moe_expert_load_max_over_mean`` and
+    ``moe_expert_load_min_over_mean`` (``GET /metrics``): the busiest
+    and the idlest expert of any layer against its layer's mean. On one
+    device a dropless step's time does not depend on them; sharded
+    over an ``expert`` axis the busiest expert's device sets it."""
+    import numpy as np
+
+    from dlrover_tpu.telemetry.registry import gauge
+
+    load = np.asarray(counts, dtype=np.float64)
+    ratio = load / np.maximum(load.mean(axis=-1, keepdims=True), 1e-9)
+    most, least = float(ratio.max()), float(ratio.min())
+    gauge(
+        "moe_expert_load_max_over_mean",
+        "tokens of the busiest expert of any layer over its layer's "
+        "mean, at the last evaluation",
+    ).set(most)
+    gauge(
+        "moe_expert_load_min_over_mean",
+        "tokens of the idlest expert of any layer over its layer's "
+        "mean, at the last evaluation",
+    ).set(least)
+    return most, least

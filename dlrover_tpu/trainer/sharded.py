@@ -299,8 +299,17 @@ def make_trainer_for_llama(
                 ("batch", None, "kv_heads", None), rules, mesh
             ),
         )
+    # experts sharded over an ``expert`` axis take the capacity-bucketed
+    # einsum path; with every expert on each device the routing is
+    # dropless (parallel/moe.py): the mesh decides, no flag
+    expert_parallel = mesh.shape.get(shd.EXPERT_AXIS, 1) > 1
+    if cfg.num_experts > 0:
+        # a dropless config on such a mesh is refused here, before
+        # anything is traced
+        llama._expert_mlp(cfg, expert_parallel)
     loss = lambda params, batch: llama.next_token_loss(  # noqa: E731
-        params, batch, cfg, attn_fn=attn_fn, constrain=constrain
+        params, batch, cfg, attn_fn=attn_fn, constrain=constrain,
+        expert_parallel=expert_parallel,
     )
     init = lambda rng: llama.init_params(rng, cfg)  # noqa: E731
     logger.info(

@@ -34,6 +34,7 @@ from dlrover_tpu.data.elastic_shm import ElasticShmDataLoader
 from dlrover_tpu.models import llama
 from dlrover_tpu.ops import tuning
 from dlrover_tpu.parallel.mesh import create_mesh
+from dlrover_tpu.parallel.moe import set_expert_load_gauges
 from dlrover_tpu.trainer.checkpoint import FlashCheckpointer
 from dlrover_tpu.trainer.compile_cache import cache_events
 from dlrover_tpu.trainer.distributed import init_from_env
@@ -44,6 +45,8 @@ MODELS = {
     "llama_tiny": llama.llama_tiny,
     # sized as bench.py sizes it for a 16 GB chip
     "llama_1b": functools.partial(llama.llama_1b, remat="dots_attn_out"),
+    # 4 experts, top-2: dropless on one device (parallel/moe.py)
+    "llama_moe_tiny": llama.llama_moe_tiny,
 }
 
 
@@ -202,6 +205,12 @@ def main():
         sharding=trainer.batch_sharding,
     )
 
+    routing_stats = None
+    if cfg.num_experts > 0:
+        routing_stats = jax.jit(
+            functools.partial(llama.routing_stats, cfg=cfg)
+        )
+
     device = devices[0]
     step, loss, losses = start_step, None, []
     first_step_done = False
@@ -253,6 +262,14 @@ def main():
             losses.append((step, loss))
             reporter.report_step(step)
             if step % 10 == 0 or step >= args.steps:
+                if routing_stats is not None:
+                    # the periodic evaluation: how evenly the router
+                    # spreads this batch (GET /metrics)
+                    most, least = set_expert_load_gauges(
+                        routing_stats(params, mb[0][0])
+                    )
+                    print(f"EXPERT_LOAD step={step} max/mean="
+                          f"{most:.3f} min/mean={least:.3f}", flush=True)
                 ckpt.save(
                     step,
                     {"params": params, "opt_state": opt_state,

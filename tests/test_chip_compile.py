@@ -275,6 +275,42 @@ def test_flash_kernel_compiles_at_llama_1b_shape(
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)],
+                         ids=["gate_up", "down"])
+def test_grouped_matmul_kernels_compile_at_olmoe_shapes(
+    topo, monkeypatch, k, n
+):
+    """The expert projections of OLMoE-1B-7B at 3 x 4096 tokens, top-8
+    of 64: the forward product and both backward ones are Pallas
+    kernels at ``grouped_matmul.TILES`` (a tile larger in any
+    dimension is refused for its VMEM)."""
+    from dlrover_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "_use_pallas", lambda lhs, rhs: True)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    rows, experts = 3 * 4096 * 8, 64
+    args = (
+        jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip),
+        jax.ShapeDtypeStruct((experts, k, n), jnp.bfloat16,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((experts,), jnp.int32, sharding=one_chip),
+    )
+
+    def loss(lhs, rhs, sizes):
+        out = gm.grouped_matmul(lhs, rhs, sizes)
+        return out.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        *args).compile().as_text()
+    assert "tpu_custom_call" in text
+    calls = re.findall(r"%([\w.]*gmm[\w.]*) = [^\n]*custom-call", text)
+    # the rows' gradient (gmm against the transposed matrices) and
+    # the matrices' (tgmm); the forward product is not needed for them
+    assert len([c for c in calls if "tgmm" in c]) == 1, calls
+    assert len([c for c in calls if "tgmm" not in c]) == 1, calls
+    assert "ragged-dot" not in text
+
+
 def test_llama_1b_step_fits_one_chip_at_batch_3(topo, on_tpu_path):
     """The memory edge: the compiler refuses what does not fit 16 GB
     (batch 4 is refused: "Used 16.64G of 15.75G hbm")."""
