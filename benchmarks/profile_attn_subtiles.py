@@ -1,0 +1,128 @@
+"""Sweep the causal sub-tile of the flash-attention kernels (dev tool).
+
+``ops/pallas/flash_attention.py`` walks a grid block that straddles
+the diagonal in square sub-tiles whose edge ``_sub_tiles`` gives for
+each of its three kernels. This script is how those edges were found: at
+the shapes the yardstick's cells run, at the blocks the static
+heuristic gives them, it times the forward alone for every edge of
+``--subs`` in the forward kernel, and forward-and-backward (the
+forward whole) for every edge in the dq and in the dk/dv kernel; no
+edge is the whole-block body. Beside them, forward and forward-and-
+backward of what the file's own rule gives (``"rule": true``) and of
+the whole-block body at each smaller pair of grid blocks of
+``--blocks``: what the grid's own skipping gives with no walk at all.
+Each timed call runs ``--layers`` attention calls in one ``lax.scan``
+so that the host's clock times tens of milliseconds. Only a TPU run
+says anything: ``chiprun -- python3 benchmarks/profile_attn_subtiles.py``.
+"""
+
+import argparse
+import json
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax  # noqa: E402
+import jax.numpy as jnp
+import numpy as np
+
+from dlrover_tpu.ops import tuning
+from dlrover_tpu.ops.pallas import flash_attention as fa
+
+#: name: (batch, seq, heads, kv_heads, head_dim) of a cell's step.
+#: Mistral's [3, 4096, 32/8, 128] is not here: a group is never
+#: sub-tiled (``flash_attention._fits``). Its row in PERF.md, and the
+#: rolled-loop, ``--unroll`` and ``--scratch-state`` readings there,
+#: came from earlier versions of this script and of the kernel file
+#: that are not in the tree
+SHAPES = {
+    "gpt2-xl": (12, 1024, 25, 25, 64),
+    "olmoe": (3, 4096, 16, 16, 128),
+}
+
+
+def _stack(layers, block_q, block_k):
+    def attn(q, k, v):
+        return fa.flash_attention_tpu(
+            q, k, v, causal=True, block_q=block_q, block_k=block_k
+        )
+
+    def forward(q, k, v):
+        out, _ = jax.lax.scan(
+            lambda x, _: (attn(x, k, v), None), q, None, length=layers
+        )
+        return out
+
+    def loss(q, k, v):
+        return forward(q, k, v).astype(jnp.float32).mean()
+
+    return jax.jit(forward), jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__)
+    ap.add_argument("--shapes", default=",".join(SHAPES))
+    ap.add_argument("--subs", default="128,256,512")
+    ap.add_argument("--blocks", default="512x512,256x256")
+    ap.add_argument("--layers", type=int, default=8)
+    ap.add_argument("--n", type=int, default=10)
+    ap.add_argument("--out", default="chiprun_out/attn_subtiles.jsonl")
+    args = ap.parse_args(argv)
+    if jax.default_backend() != "tpu":
+        print("not a TPU: interpret mode times nothing", file=sys.stderr)
+        return 1
+    subs = [int(s) for s in args.subs.split(",") if s]
+    smaller = [
+        tuple(int(n) for n in pair.split("x"))
+        for pair in args.blocks.split(",") if pair
+    ]
+    os.makedirs(os.path.dirname(args.out), exist_ok=True)
+    rng = np.random.default_rng(0)
+    rule = fa._sub_tiles
+    whole = {"fwd": None, "dq": None, "dkv": None}
+    both = ("fwd_ms", "fwd_bwd_ms")
+    for name in args.shapes.split(","):
+        batch, seq, heads, kv_heads, d = SHAPES[name]
+        blocks = tuning.heuristic_blocks(seq, heads // kv_heads)
+        q, k, v = (
+            jnp.asarray(
+                rng.standard_normal((batch, seq, h, d)), jnp.bfloat16
+            )
+            for h in (heads, kv_heads, kv_heads)
+        )
+        # (grid blocks, an edge a kernel or None for the file's rule,
+        # what to time)
+        settings = [(blocks, whole, both), (blocks, None, both)] + [
+            (pair, whole, both) for pair in smaller
+        ] + [
+            (blocks, dict(whole, **{kernel: sub}),
+             both[:1] if kernel == "fwd" else both[1:])
+            for kernel in whole for sub in subs if sub < max(blocks)
+        ]
+        for (block_q, block_k), edges, keys in settings:
+            fa._sub_tiles = rule if edges is None else (
+                lambda kernel, bq, bk, g, d: fa._fits(  # noqa: B023
+                    edges[kernel], g, bq, bk
+                )
+            )
+            fns = dict(zip(both, _stack(args.layers, block_q, block_k)))
+            row = {
+                "shape": name, "blocks": [block_q, block_k],
+                **(edges or {"rule": True}),
+            }
+            try:
+                for key in keys:
+                    row[key] = 1e3 / args.layers * tuning.timeit(
+                        fns[key], q, k, v, n=args.n, warmup=2
+                    )
+            except Exception as e:  # an edge the chip's compiler refuses
+                row["error"] = str(e)[-300:]
+            print(json.dumps(row), flush=True)
+            with open(args.out, "a") as f:
+                f.write(json.dumps(row) + "\n")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

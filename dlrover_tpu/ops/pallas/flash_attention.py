@@ -21,6 +21,27 @@ Backward follows the FlashAttention-2 structure: a dQ kernel (grid over
 q-blocks, accumulating over k-blocks) and a dK/dV kernel (grid over
 k-blocks, accumulating over q-blocks), with the softmax re-derived from
 the saved logsumexp.
+
+What a causally live grid step computes (``_walk``). Blocks wholly
+above the diagonal are skipped by the grid (``pl.when`` + the clamped
+index maps). Where a kernel is given an edge (``_sub_tiles``: for each
+kernel from the shapes it sees, as read on the chip; none with a
+group or with unequal blocks), a block under the diagonal is one
+product without the mask, and the block on the diagonal is walked in
+rows of square sub-tiles: row n is ONE product over the block's
+leading n + 1 column tiles, the ones in which it has an element on or
+under the diagonal, and the mask. The loop over rows is a
+``fori_loop`` that the lowering unrolls, so that rows are sliced at
+static offsets and the ``lax.switch`` that picks a row's width has a
+constant index: the jaxpr holds one body for each width (one a column
+tile of the block, not one a sub-tile), the lowered kernel one a row.
+Rolled loops over column tiles (trip counts from ``program_id``, one
+masked and one unmasked body) read 1.3 to 4 times slower than the
+whole block on a v5e: every iteration pays the MXU's fill and drain, a
+lane reduction a row and dynamic slices (PERF.md section 6, PR 31).
+Where no edge is given, and without ``causal``, the body is the whole
+block's single product as before. ``causal_tile_census`` counts what
+the walk skips.
 """
 
 import functools
@@ -35,38 +56,199 @@ NEG_INF = -1e30
 LANES = 128
 
 
-def _causal_mask(q_start, k_start, g, block_q, block_k):
-    """[g*block_q, block_k] bool: row token >= col token.
+def _causal_mask(q_start, k_start, g, rows, cols):
+    """[g*rows, cols] bool: row token >= col token.
 
-    Rows are g-major (row = g_idx*block_q + q_idx), so the query position
-    is ``q_start + row % block_q`` — computed with a bitwise AND
-    (block sizes are powers of two) to stay on Mosaic's supported ops.
+    Rows are g-major (row = g_idx*rows + q_idx), so the query position
+    is ``q_start + row % rows`` — computed with a bitwise AND
+    (``rows`` is a power of two: a block, or a sub-tile's edge) to stay
+    on Mosaic's supported ops.
     """
-    rows = jax.lax.broadcasted_iota(
-        jnp.int32, (g * block_q, block_k), 0
+    shape = (g * rows, cols)
+    q_idx = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    k_idx = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return jax.lax.ge(
+        jax.lax.add(q_start, jax.lax.bitwise_and(q_idx, rows - 1)),
+        jax.lax.add(k_start, k_idx),
     )
-    cols = jax.lax.broadcasted_iota(
-        jnp.int32, (g * block_q, block_k), 1
-    )
-    return (q_start + (rows & (block_q - 1))) >= (k_start + cols)
 
 
-def _stack_groups(ref, g):
-    """[1, g, block, d] ref -> [g*block, d] value, via per-group slices
+def _stack_groups(ref, g, rows=slice(None)):
+    """[1, g, block, d] ref -> [g*rows, d] value, via per-group slices
     stacked on sublanes (the relayout Mosaic supports; a direct 4-D
     reshape hits "unsupported shape cast")."""
     if g == 1:
-        return ref[0, 0]
-    return jnp.concatenate([ref[0, gi] for gi in range(g)], axis=0)
+        return ref[0, 0, rows]
+    return jnp.concatenate([ref[0, gi, rows] for gi in range(g)], axis=0)
 
 
-def _stack_cols(ref, g):
-    """[1, g, 1, block] ref (lanes) -> [g*block, 1] column (sublanes)."""
+def _stack_cols(ref, g, rows=slice(None)):
+    """[1, g, 1, block] ref (lanes) -> [g*rows, 1] column (sublanes)."""
     if g == 1:
-        return ref[0, 0, 0][:, None]
+        return ref[0, 0, 0, rows][:, None]
     return jnp.concatenate(
-        [ref[0, gi, 0][:, None] for gi in range(g)], axis=0
+        [ref[0, gi, 0, rows][:, None] for gi in range(g)], axis=0
     )
+
+
+# ---------------------------------------------------------------------------
+# sub-tiles of a causal block
+
+def _sub_tiles(kernel, block_q, block_k, g, head_dim):
+    """The edge of the square sub-tiles in which ``kernel`` ("fwd",
+    "dq" or "dkv") walks the (block_q, block_k) grid block on the
+    diagonal; None where it takes the block whole.
+
+    As read on a v5e at the blocks ``ops/tuning.py heuristic_blocks``
+    gives (``benchmarks/profile_attn_subtiles.py``; PERF.md section 6,
+    PR 31): the two backward kernels gain at every shape tried, most at
+    256 (128 reads the same and holds twice the bodies, 512 skips too
+    little). The forward gains only at 64-wide heads, and there only
+    at 128; at 128-wide heads every edge reads level or slower than
+    the whole block. With a group the backward kernels gain 0.5% of a
+    Mistral step and a body reads g times as many slices: tracing them
+    cost a second of every process's set-up, so a group stays whole."""
+    if kernel == "fwd":
+        return _fits(128 if head_dim <= 64 else None, g, block_q, block_k)
+    return _fits(256, g, block_q, block_k)
+
+
+def _fits(edge, g, block_q, block_k):
+    """``edge`` where there is no group (the kernels slice a sub-tile's
+    rows out of one head's), the blocks are equal (a row's width is
+    then its number in the block: ``_walk``; no cell runs unequal
+    blocks without a group, and none was timed), and it tiles them in
+    more than one."""
+    if edge is None or g > 1 or block_q != block_k or block_q % edge:
+        return None
+    return edge if edge < block_q else None
+
+
+def causal_tile_census(seq, block_q, block_k, sub_q, sub_k):
+    """Over one head's causal [seq, seq] scores, in (sub_q, sub_k)
+    sub-tiles: (sub-tiles the causally live grid blocks cover, which is
+    what a whole-block body computes; sub-tiles with an element on or
+    under the diagonal, which is what the walk computes; those of them
+    that straddle the diagonal, where the mask decides something)."""
+    n_rows, n_cols = block_q // sub_q, block_k // sub_k
+    covered = computed = masked = 0
+    for q_start in range(0, seq, block_q):
+        for k_start in range(0, seq, block_k):
+            if q_start + block_q - 1 < k_start:
+                continue
+            covered += n_rows * n_cols
+            for q0 in range(q_start, q_start + block_q, sub_q):
+                # column tiles whose first column <= the row's last
+                # position; of them wholly under the diagonal, those
+                # whose last column <= q0
+                n_live = (q0 + sub_q - 1 - k_start) // sub_k + 1
+                n_full = (q0 - k_start + 1) // sub_k
+                n_live, n_full = (
+                    max(0, min(n, n_cols)) for n in (n_live, n_full)
+                )
+                computed += n_live
+                masked += n_live - n_full
+    return covered, computed, masked
+
+
+def _set_census_gauges(kernel, seq, block_q, block_k, sub):
+    """Where a causal kernel is built (trace time): what share of the
+    sub-tiles its live blocks cover it computes, and how many of them
+    straddle the diagonal."""
+    from dlrover_tpu.telemetry.registry import gauge
+
+    covered, computed, masked = causal_tile_census(
+        seq, block_q, block_k, sub or block_q, sub or block_k
+    )
+    gauge(
+        "attn_tiles_computed_share",
+        "sub-tiles a causal attention kernel computes over those its "
+        "live grid blocks cover, at the last one built",
+        labelnames=("kernel",),
+    ).labels(kernel=kernel).set(computed / covered)
+    gauge(
+        "attn_tiles_masked_share",
+        "sub-tiles that straddle the diagonal, over the same",
+        labelnames=("kernel",),
+    ).labels(kernel=kernel).set(masked / covered)
+
+
+def _rows_of(r0, size, block_q):
+    """Index of ``size`` query positions from ``r0`` on in their block
+    (a part of it only without a group: ``_sub_tiles``)."""
+    return slice(None) if size == block_q else pl.ds(r0, size)
+
+
+# The kernels' elementwise math is written with ``jax.lax``'s own
+# operations, not jnp's: a jnp operator is a jitted function that is
+# traced anew for each shape it meets (0.35 ms against 0.09 when first
+# met, traced in the sandbox; a chip host's shared cores are slower),
+# and a kernel that holds a body a width meets many. The jaxpr is the
+# same.
+
+def _scores(q, k, scale, g, diagonal):
+    """Scaled scores of g-major rows ``q`` over columns ``k``, masked
+    where ``diagonal`` gives their first (query, key) positions."""
+    # bf16 x bf16 -> fp32 accumulate: the MXU's native mode. Casting
+    # inputs to fp32 first would fall off the fast path (~4x slower).
+    s = jax.lax.mul(jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ), scale)  # [g*rows, cols]
+    if diagonal is not None:
+        mask = _causal_mask(*diagonal, g, q.shape[0] // g, k.shape[0])
+        s = jax.lax.select(mask, s, jax.lax.full_like(s, NEG_INF))
+    return s
+
+
+def _row_reduce(reduce, x):
+    """``reduce`` over the columns of ``x``, kept as a [rows, 1] column."""
+    return jax.lax.expand_dims(reduce(x, (1,)), (1,))
+
+
+def _walk(causal, block_q, block_k, sub, q_start, k_start, compute):
+    """What one grid step computes, as calls of
+    ``compute(r0, size, cols, diagonal)``: the ``size`` query positions
+    of the block from ``r0`` on against the block's leading key
+    positions ``cols``, in one product; ``diagonal`` is None, or the
+    product's first (query, key) positions where it takes the mask.
+
+    Without ``causal``, one call over the block. With it, a block
+    wholly above the diagonal is skipped. Where ``sub`` is None every
+    other block is one masked call. Else (the blocks are equal:
+    ``_fits``) a block under the diagonal is one call without the
+    mask, and the one on it is one call a row of sub-tiles of edge
+    ``sub``, over the row's live column tiles: n + 1 in row n."""
+
+    def whole(masked):
+        compute(0, block_q, slice(None),
+                (q_start, k_start) if masked else None)
+
+    if not causal:
+        return whole(False)
+    live = q_start + block_q - 1 >= k_start
+    if sub is None:
+        return pl.when(live)(lambda: whole(True))
+    under = q_start >= k_start + block_k - 1
+    straddling = jnp.logical_and(live, jnp.logical_not(under))
+    pl.when(under)(lambda: whole(False))
+    n_tiles = block_q // sub
+
+    def row_tile(n, _):
+        """Row n of the diagonal block's sub-tiles. Where the unrolled
+        loop is lowered n is a constant, and only its width's body is
+        lowered."""
+        r0 = pl.multiple_of(n * sub, sub)
+        return jax.lax.switch(n, [
+            functools.partial(
+                compute, r0, sub, slice(0, w * sub),
+                (q_start + r0, k_start),
+            ) for w in range(1, n_tiles + 1)
+        ])
+
+    @pl.when(straddling)
+    def _rows():
+        jax.lax.fori_loop(0, n_tiles, row_tile, None, unroll=True)
 
 
 # ---------------------------------------------------------------------------
@@ -74,7 +256,7 @@ def _stack_cols(ref, g):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, scale, causal, g,
-                block_q, block_k):
+                block_q, block_k, sub):
     i = pl.program_id(1)  # q block
     j = pl.program_id(2)  # k block (minor: sequential, scratch persists)
     nk = pl.num_programs(2)
@@ -87,36 +269,33 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     q_start = i * block_q
     k_start = j * block_k
-    # causal: skip blocks fully above the diagonal
-    run = True
-    if causal:
-        run = q_start + block_q - 1 >= k_start
 
-    @pl.when(run)
-    def _compute():
-        q = _stack_groups(q_ref, g)
-        # bf16 x bf16 -> fp32 accumulate: the MXU's native mode. Casting
-        # inputs to fp32 first would fall off the fast path (~4x slower).
-        s = jax.lax.dot_general(
-            q, k_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [g*block_q, block_k]
-        if causal:
-            mask = _causal_mask(q_start, k_start, g, block_q, block_k)
-            s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_scr[:, :1]  # [g*block_q, 1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)  # [g*block_q, block_k]
-        corr = jnp.exp(m_prev - m_new)  # [g*block_q, 1]
-        l_new = l_scr[:, :1] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0],
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+    def compute(r0, size, cols, diagonal):
+        rows = _rows_of(r0, size, block_q)
+        s = _scores(
+            _stack_groups(q_ref, g, rows), k_ref[0, cols], scale, g, diagonal
         )
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        m_prev = m_scr[rows, :1]  # [g*size, 1]
+        m_new = jax.lax.max(m_prev, _row_reduce(jax.lax.reduce_max, s))
+        p = jax.lax.exp(jax.lax.sub(s, m_new))  # [g*size, cols]
+        corr = jax.lax.exp(jax.lax.sub(m_prev, m_new))  # [g*size, 1]
+        l_new = jax.lax.add(
+            jax.lax.mul(l_scr[rows, :1], corr),
+            _row_reduce(jax.lax.reduce_sum, p),
+        )
+        acc_scr[rows] = jax.lax.add(
+            jax.lax.mul(acc_scr[rows], corr),
+            jax.lax.dot_general(
+                jax.lax.convert_element_type(p, v_ref.dtype), v_ref[0, cols],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ),
+        )
+        lanes = (m_new.shape[0], LANES)
+        m_scr[rows] = jnp.broadcast_to(m_new, lanes)
+        l_scr[rows] = jnp.broadcast_to(l_new, lanes)
+
+    _walk(causal, block_q, block_k, sub, q_start, k_start, compute)
 
     @pl.when(j == nk - 1)
     def _finalize():
@@ -138,6 +317,19 @@ def _check_blocks(seq, block_q, block_k):
     if block_q & (block_q - 1):
         # the causal mask derives query positions with `rows & (block_q-1)`
         raise ValueError(f"block_q must be a power of two, got {block_q}")
+
+
+def _kernel(body, name, seq, causal, g, block_q, block_k, head_dim, scale):
+    """``body`` ("fwd", "dq" or "dkv" by ``name``) with its static
+    arguments; building a causal one sets the census gauges."""
+    sub = None
+    if causal:
+        sub = _sub_tiles(name, block_q, block_k, g, head_dim)
+        _set_census_gauges(name, seq, block_q, block_k, sub)
+    return functools.partial(
+        body, scale=scale, causal=causal, g=g,
+        block_q=block_q, block_k=block_k, sub=sub,
+    )
 
 
 def _kv_index(causal, block_q, block_k):
@@ -167,9 +359,8 @@ def _fwd(q, k, v, scale, causal, block_q, block_k):
     block_k = min(block_k, seq)
     _check_blocks(seq, block_q, block_k)
     grid = (bkh, seq // block_q, seq // block_k)
-    kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, g=g,
-        block_q=block_q, block_k=block_k,
+    kernel = _kernel(
+        _fwd_kernel, "fwd", seq, causal, g, block_q, block_k, d, scale
     )
     kv_idx = _kv_index(causal, block_q, block_k)
     return pl.pallas_call(
@@ -202,8 +393,40 @@ def _fwd(q, k, v, scale, causal, block_q, block_k):
 # ---------------------------------------------------------------------------
 # backward
 
+# The order of the reads and products below is the order in which the
+# whole-block kernels have always issued them, and it matters. With a
+# group's whole blocks, dS computed before dV is accumulated (P and dS
+# both held whole) and V read ahead of the scores read 64.0 ms of
+# ``attn_kernel_ms`` at Mistral's shapes where this order reads 62.5.
+# The kernels that walk sub-tiles are the other way about in dK/dV
+# alone: dS first reads 62.3 ms at gpt2-xl's shape, dV first 75.1
+# (PERF.md section 6, PR 31).
+
+def _q_side(q_ref, do_ref, lse_ref, delta_ref, g, rows):
+    """What the backward kernels read of the query positions ``rows``:
+    q, dO [g*rows, d], lse, delta [g*rows, 1]."""
+    return (
+        _stack_groups(q_ref, g, rows), _stack_groups(do_ref, g, rows),
+        _stack_cols(lse_ref, g, rows), _stack_cols(delta_ref, g, rows),
+    )
+
+
+def _p(q, lse, k, scale, g, diagonal):
+    """The softmax re-derived from the saved logsumexp: [g*rows, cols]."""
+    return jax.lax.exp(jax.lax.sub(_scores(q, k, scale, g, diagonal), lse))
+
+
+def _ds(p, do, v, delta):
+    """dS (before its ``scale``) from the softmax and dO."""
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return jax.lax.mul(p, jax.lax.sub(dp, delta))
+
+
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               acc_scr, *, scale, causal, g, block_q, block_k):
+               acc_scr, *, scale, causal, g, block_q, block_k, sub):
     i = pl.program_id(1)
     j = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -214,34 +437,21 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     q_start = i * block_q
     k_start = j * block_k
-    run = True
-    if causal:
-        run = q_start + block_q - 1 >= k_start
 
-    @pl.when(run)
-    def _compute():
-        q = _stack_groups(q_ref, g)
-        do = _stack_groups(do_ref, g)
-        lse = _stack_cols(lse_ref, g)  # [g*bq, 1]
-        delta = _stack_cols(delta_ref, g)
-        s = jax.lax.dot_general(
-            q, k_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        if causal:
-            mask = _causal_mask(q_start, k_start, g, block_q, block_k)
-            s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse)  # [g*bq, bk]
-        dp = jax.lax.dot_general(
-            do, v_ref[0],
-            (((1,), (1,)), ((), ())),
+    def compute(r0, size, cols, diagonal):
+        rows = _rows_of(r0, size, block_q)
+        q, do, lse, delta = _q_side(
+            q_ref, do_ref, lse_ref, delta_ref, g, rows
+        )
+        p = _p(q, lse, k_ref[0, cols], scale, g, diagonal)
+        ds = _ds(p, do, v_ref[0, cols], delta)
+        acc_scr[rows] += jax.lax.dot_general(
+            jax.lax.convert_element_type(ds, k_ref.dtype), k_ref[0, cols],
+            (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        ds = p * (dp - delta)  # [g*bq, bk]
-        acc_scr[:] += jax.lax.dot_general(
-            ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+
+    _walk(causal, block_q, block_k, sub, q_start, k_start, compute)
 
     @pl.when(j == nk - 1)
     def _finalize():
@@ -252,7 +462,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr,
-                *, scale, causal, g, block_q, block_k):
+                *, scale, causal, g, block_q, block_k, sub):
     j = pl.program_id(1)  # k block (major)
     i = pl.program_id(2)  # q block (minor: accumulates)
     nq = pl.num_programs(2)
@@ -264,41 +474,33 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     q_start = i * block_q
     k_start = j * block_k
-    run = True
-    if causal:
-        run = q_start + block_q - 1 >= k_start
 
-    @pl.when(run)
-    def _compute():
-        q = _stack_groups(q_ref, g)
-        do = _stack_groups(do_ref, g)
-        lse = _stack_cols(lse_ref, g)  # [g*bq, 1]
-        delta = _stack_cols(delta_ref, g)
-        s = jax.lax.dot_general(
-            q, k_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        if causal:
-            mask = _causal_mask(q_start, k_start, g, block_q, block_k)
-            s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        # dV += P^T @ dO — contracting over g*block_q rows also sums the
-        # GQA group's contributions (the repeat-bwd reduction, for free)
-        dv_scr[:] += jax.lax.dot_general(
-            p.astype(do_ref.dtype), do, (((0,), (0,)), ((), ())),
+    def compute(r0, size, cols, diagonal):
+        rows = _rows_of(r0, size, block_q)
+        q, do, lse, delta = _q_side(
+            q_ref, do_ref, lse_ref, delta_ref, g, rows
+        )
+        p = _p(q, lse, k_ref[0, cols], scale, g, diagonal)
+        if sub is not None:  # dS first where sub-tiles are walked (above)
+            ds = _ds(p, do, v_ref[0, cols], delta)
+        # dV += P^T @ dO — contracting over the g*size rows also sums
+        # the GQA group's contributions (the repeat-bwd reduction, for
+        # free)
+        dv_scr[cols] += jax.lax.dot_general(
+            jax.lax.convert_element_type(p, do.dtype), do,
+            (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        dp = jax.lax.dot_general(
-            do, v_ref[0],
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta)
+        if sub is None:
+            ds = _ds(p, do, v_ref[0, cols], delta)
         # dK += dS^T @ Q (scale applied once at finalize)
-        dk_scr[:] += jax.lax.dot_general(
-            ds.astype(q_ref.dtype), q, (((0,), (0,)), ((), ())),
+        dk_scr[cols] += jax.lax.dot_general(
+            jax.lax.convert_element_type(ds, q.dtype), q,
+            (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+
+    _walk(causal, block_q, block_k, sub, q_start, k_start, compute)
 
     @pl.when(i == nq - 1)
     def _finalize():
@@ -315,9 +517,8 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k):
         o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1
     )[:, :, None, :]  # [bkh, g, 1, seq] (4-D for TPU block tiling)
 
-    dq_kernel = functools.partial(
-        _dq_kernel, scale=scale, causal=causal, g=g,
-        block_q=block_q, block_k=block_k,
+    dq_kernel = _kernel(
+        _dq_kernel, "dq", seq, causal, g, block_q, block_k, d, scale
     )
     kv_idx = _kv_index(causal, block_q, block_k)
     in_specs_q = [
@@ -340,9 +541,8 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k):
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)
 
-    dkv_kernel = functools.partial(
-        _dkv_kernel, scale=scale, causal=causal, g=g,
-        block_q=block_q, block_k=block_k,
+    dkv_kernel = _kernel(
+        _dkv_kernel, "dkv", seq, causal, g, block_q, block_k, d, scale
     )
 
     def q_side_idx(sublane):

@@ -104,9 +104,11 @@ MODULE_BUDGET_OVERRIDES = {
     "test_streaming_e2e": 120.0,
     "test_auto": 120.0,
     # compiles for a described v5e: the 22-layer one-chip step, the
-    # four-chip fsdp step, eight kernels — held to two cores so as
-    # not to starve the drills: measured 31s alone
-    "test_chip_compile": 180.0,
+    # four-chip fsdp step, twelve kernels — held to two cores so as
+    # not to starve the drills: measured 64s alone, 72s beside five
+    # other workers on a quiet machine (PR 31; 189s on a loaded one
+    # when it was 88s alone)
+    "test_chip_compile": 240.0,
     "test_context_parallel": 180.0,
     "test_flash_attention": 180.0,
     "test_gpt": 120.0,

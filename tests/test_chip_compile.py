@@ -275,6 +275,159 @@ def test_flash_kernel_compiles_at_llama_1b_shape(
     assert "tpu_custom_call" in compiled.as_text()
 
 
+#: [batch, seq, heads, kv_heads, head_dim] of the attention calls the
+#: yardstick's cells make: gpt2-xl at 12 x 1024, OLMoE and Mistral at
+#: 3 x 4096 (the fsdp4 cell runs Mistral's with one sequence a chip)
+CELL_ATTENTION = {
+    "gpt2-xl": (12, 1024, 25, 25, 64),
+    "olmoe": (3, 4096, 16, 16, 128),
+    "mistral": (3, 4096, 32, 8, 128),
+}
+
+
+def _sum_grad(attn):
+    return jax.grad(
+        lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2),
+    )
+
+
+#: forward alone only where the forward kernel is walked: the other two
+#: shapes' forward is the whole-block body, which the forward-and-
+#: backward compile holds as well
+@pytest.mark.parametrize("cell,grad", [
+    ("gpt2-xl", False), ("gpt2-xl", True), ("olmoe", True), ("mistral", True),
+], ids=lambda v: v if isinstance(v, str) else ("fwd", "fwd_bwd")[v])
+def test_sub_tiled_kernels_compile_at_the_cells_shapes(
+    topo, on_tpu_path, cell, grad
+):
+    """At the heuristic's blocks (what a cell runs with the tuner
+    off). Without a group the block on the diagonal is walked in rows
+    of sub-tiles: a switch over the widths a row can have, slices of
+    the scratch rows and, in the backward kernels, of the lanes of the
+    logsumexp."""
+    batch, seq, heads, kv_heads, d = CELL_ATTENTION[cell]
+    bq, bk = tuning.heuristic_blocks(seq, heads // kv_heads)
+    # a group is not sub-tiled: Mistral's kernels are the whole-block ones
+    assert (heads > kv_heads) != any(
+        fa._sub_tiles(kernel, bq, bk, heads // kv_heads, d)
+        for kernel in ("fwd", "dq", "dkv")
+    )
+
+    def attn(q, k, v):
+        return fa.flash_attention_tpu(
+            q, k, v, causal=True, block_q=bq, block_k=bk
+        )
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    q, kv = (
+        jax.ShapeDtypeStruct(
+            (batch, seq, h, d), jnp.bfloat16, sharding=one_chip
+        ) for h in (heads, kv_heads)
+    )
+    fn = _sum_grad(attn) if grad else attn
+    compiled = jax.jit(fn).lower(q, kv, kv).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _count_equations(jaxpr):
+    """Equations of a jaxpr and of every jaxpr inside it (loop bodies,
+    branches)."""
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+    count = 0
+    for eqn in jaxpr.eqns:
+        count += 1
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                if hasattr(getattr(sub, "jaxpr", sub), "eqns"):
+                    count += _count_equations(sub)
+    return count
+
+
+def _one_head(causal, edge, monkeypatch, sharding=None):
+    """Attention and its gradients at gpt2-xl's shape, one (1024, 1024)
+    block a head, the diagonal block walked in sub-tiles of ``edge``
+    (None: whole) by all three kernels; and one head's argument."""
+    monkeypatch.setattr(
+        fa, "_sub_tiles", lambda kernel, bq, bk, g, d: fa._fits(edge, g, bq, bk)
+    )
+    return _sum_grad(
+        lambda q, k, v: fa.flash_attention_tpu(
+            q, k, v, causal=causal, block_q=1024, block_k=1024)
+    ), jax.ShapeDtypeStruct((1, 1024, 1, 64), jnp.bfloat16, sharding=sharding)
+
+
+def _kernel_sizes(causal, edge, monkeypatch):
+    """Equation counts of the forward, dq and dk/dv kernels' jaxprs."""
+    fn, q = _one_head(causal, edge, monkeypatch)
+    sizes = []
+
+    def find(jaxpr):
+        for eqn in getattr(jaxpr, "jaxpr", jaxpr).eqns:
+            if eqn.primitive.name == "pallas_call":
+                sizes.append(_count_equations(eqn.params["jaxpr"]))
+                continue
+            for value in eqn.params.values():
+                if hasattr(getattr(value, "jaxpr", value), "eqns"):
+                    find(value)
+
+    find(jax.make_jaxpr(fn)(q, q, q))
+    assert len(sizes) == 3, sizes
+    return sizes
+
+
+def _lowered_sizes(edge, monkeypatch, chip):
+    """Bytes of the three causal kernels as a step's lowering for
+    ``chip`` holds them (a ``tpu_custom_call`` line each, the Mosaic
+    module inside it)."""
+    fn, q = _one_head(True, edge, monkeypatch, SingleDeviceSharding(chip))
+    text = jax.jit(fn).lower(q, q, q).as_text()
+    sizes = [len(line) for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    assert len(sizes) == 3, sizes
+    return sizes
+
+
+EDGES = (128, 256, 512, None)
+
+
+def test_kernel_jaxpr_grows_with_the_edge_not_the_sub_tiles(monkeypatch):
+    """What is traced (a kernel's Python runs once a ``pallas_call``,
+    five times a process, in every process's ``setup_s``): a kernel's
+    jaxpr holds one body for each width a row of sub-tiles can have,
+    one a column tile of the block, inside one loop over the rows. 64
+    sub-tiles of 128 add to the whole-block kernel twice what 16 of 256
+    add, and those twice what 4 of 512 add; a body a sub-tile would add
+    four times as much at each step."""
+    at = {edge: _kernel_sizes(True, edge, monkeypatch) for edge in EDGES}
+    plain = _kernel_sizes(False, None, monkeypatch)
+    for k in range(3):
+        a, b, c, whole = (at[edge][k] for edge in EDGES)
+        assert plain[k] < whole < c < b < a, (at, plain)
+        assert a - b == 2 * (b - c), at
+        # the whole-block causal kernel is the non-causal body, the
+        # mask and the test of whether the block is live
+        assert whole < 1.5 * plain[k], (at, plain)
+        assert a < 10 * plain[k], (at, plain)
+
+
+def test_lowered_kernel_holds_a_body_a_row(topo, on_tpu_path, monkeypatch):
+    """What is lowered: the loop over rows is unrolled there and each
+    row's switch has a constant index, so the Mosaic program grows by
+    one body a row of sub-tiles (2, 4, 8 at 512, 256, 128), not by one
+    a sub-tile (3, 10, 36), and not by every width in every row (4,
+    16, 64)."""
+    at = {edge: _lowered_sizes(edge, monkeypatch, topo.devices[0])
+          for edge in EDGES}
+    for k in range(3):
+        a, b, c, whole = (at[edge][k] for edge in EDGES)
+        assert whole < c < b < a, at
+        # rows double at each step: 2.0-2.2 read; a body a sub-tile
+        # would read 3.7, every width in every row 4
+        assert a - b < 2.6 * (b - c), at
+        assert a < 3 * whole, at
+
+
 @pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)],
                          ids=["gate_up", "down"])
 def test_grouped_matmul_kernels_compile_at_olmoe_shapes(

@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from dlrover_tpu.ops.attention import mha_reference
+from dlrover_tpu.ops.pallas import flash_attention
 from dlrover_tpu.ops.pallas.flash_attention import flash_attention_tpu
 
 
@@ -97,3 +98,95 @@ def test_bf16_forward_close():
         out.astype(np.float32), ref.astype(np.float32),
         rtol=5e-2, atol=5e-2,
     )
+
+
+# (seq, block_q, block_k, g, head_dim, causal): at the three cells'
+# blocks and groupings (gpt2-xl's one block a head and OLMoE's grid of
+# 1024-blocks, the diagonal ones walked in sub-tiles; Mistral's (256,
+# 1024) at a group of 4, whole: a group is not sub-tiled), a mixed pair
+# with a group and two pairs of blocks that differ without one (whole:
+# only equal blocks are walked), and without causal the whole-block
+# body
+SUB_TILED = [
+    (1024, 1024, 1024, 1, 64, True),
+    (2048, 1024, 1024, 1, 128, True),
+    (2048, 256, 1024, 4, 128, True),
+    (1024, 256, 512, 2, 64, True),
+    (1024, 1024, 512, 1, 64, True),
+    (2048, 512, 1024, 1, 64, True),
+    (1024, 1024, 1024, 1, 64, False),
+]
+
+
+@pytest.fixture(scope="module", params=SUB_TILED, ids=str)
+def sub_tiled(request):
+    """Forward and the three gradients, kernel and reference, once a
+    case."""
+    seq, bq, bk, g, d, causal = request.param
+    q, k, v = _rand_qkv(jax.random.key(5), 1, seq, g, 1, d)
+
+    def both(attn):
+        out, grads = jax.value_and_grad(
+            lambda q, k, v: jnp.sum(attn(q, k, v) ** 2),
+            argnums=(0, 1, 2), has_aux=False,
+        )(q, k, v)
+        return dict(zip(("dq", "dk", "dv"), grads), fwd=attn(q, k, v))
+
+    flash = both(lambda q, k, v: flash_attention_tpu(
+        q, k, v, causal=causal, block_q=bq, block_k=bk))
+    return flash, both(lambda q, k, v: mha_reference(q, k, v, causal=causal))
+
+
+@pytest.mark.parametrize("what", ["fwd", "dq", "dk", "dv"])
+def test_sub_tiled_blocks_match_reference(sub_tiled, what):
+    flash, ref = sub_tiled
+    tol = 2e-3 if what == "fwd" else 5e-3
+    np.testing.assert_allclose(
+        flash[what], ref[what], rtol=tol, atol=tol,
+        err_msg=f"{what} mismatch",
+    )
+
+
+@pytest.mark.parametrize("sub", [128, 256, 512])
+@pytest.mark.parametrize(
+    "seq,bq,bk", [c[:3] for c in SUB_TILED[:6]] + [(4096, 1024, 512)]
+)
+def test_causal_tile_census_counts_the_mask(seq, bq, bk, sub):
+    sub_q, sub_k = min(sub, bq), min(sub, bk)
+    keep = np.tril(np.ones((seq, seq), bool))
+
+    def tiles(rows, cols):
+        """[seq/rows, seq/cols] of (any kept, all kept)."""
+        t = keep.reshape(seq // rows, rows, seq // cols, cols)
+        return t.any(axis=(1, 3)), t.all(axis=(1, 3))
+
+    live_blocks, _ = tiles(bq, bk)
+    some, every = tiles(sub_q, sub_k)
+    assert flash_attention.causal_tile_census(
+        seq, bq, bk, sub_q, sub_k
+    ) == (
+        int(live_blocks.sum()) * (bq // sub_q) * (bk // sub_k),
+        int(some.sum()), int((some & ~every).sum()),
+    )
+
+
+def test_census_at_gpt2_xl_and_its_gauges():
+    assert flash_attention.causal_tile_census(
+        1024, 1024, 1024, 128, 128) == (64, 36, 8)
+    from dlrover_tpu.telemetry.registry import default_registry
+
+    q, k, v = _rand_qkv(jax.random.key(6), 1, 512, 1, 1, 64)
+    jax.grad(lambda q: jnp.sum(flash_attention_tpu(
+        q, k, v, causal=True, block_q=512, block_k=512)))(q)
+    text = default_registry().to_prometheus_text()
+    for kernel in ("fwd", "dq", "dkv"):
+        edge = flash_attention._sub_tiles(kernel, 512, 512, 1, 64) or 512
+        covered, computed, masked = flash_attention.causal_tile_census(
+            512, 512, 512, edge, edge)
+        for name, value in (
+            ("attn_tiles_computed_share", computed / covered),
+            ("attn_tiles_masked_share", masked / covered),
+        ):
+            line = next(ln for ln in text.splitlines()
+                        if ln.startswith(f'{name}{{kernel="{kernel}"}} '))
+            assert float(line.split()[1]) == value
