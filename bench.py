@@ -397,9 +397,9 @@ def main():
         if on_tpu else 0.0
     )
 
-    # which flash-attention blocks the step actually ran with, and
-    # where they came from (ops/tuning.py: cache | measured |
-    # heuristic); null off-TPU where the Pallas path never dispatches
+    # which flash-attention blocks the step actually ran with
+    # (ops/tuning.py's static rule); null off-TPU where the Pallas
+    # path never dispatches
     from dlrover_tpu.ops import tuning
 
     sel = tuning.last_selection()

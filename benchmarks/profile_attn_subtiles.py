@@ -20,6 +20,7 @@ import argparse
 import json
 import os
 import sys
+import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -40,6 +41,18 @@ SHAPES = {
     "gpt2-xl": (12, 1024, 25, 25, 64),
     "olmoe": (3, 4096, 16, 16, 128),
 }
+
+
+def timeit(fn, *args, n=10, warmup=2):
+    """Mean wall-clock seconds per call."""
+    for _ in range(warmup):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    t0 = time.perf_counter()
+    for _ in range(n):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / n
 
 
 def _stack(layers, block_q, block_k):
@@ -113,7 +126,7 @@ def main(argv=None):
             }
             try:
                 for key in keys:
-                    row[key] = 1e3 / args.layers * tuning.timeit(
+                    row[key] = 1e3 / args.layers * timeit(
                         fns[key], q, k, v, n=args.n, warmup=2
                     )
             except Exception as e:  # an edge the chip's compiler refuses

@@ -228,9 +228,9 @@ def check_worker(saw, problems, rc, reports, first, steps):
         problems.append(f"platform {first['platform']}")
     if not first["kernel_in_step"]:
         problems.append("no tpu_custom_call in the compiled step")
-    source = (first["tuning"] or {}).get("source")
-    if source not in ("measured", "cache"):
-        problems.append(f"tuning source {source!r}")
+    blocks = first["tuning"] or {}
+    if None in (blocks.get("block_q"), blocks.get("block_k")):
+        problems.append(f"no attention blocks recorded: {blocks!r}")
     final = [r for r in reports if r["event"] == "final"]
     if not final:
         problems.append("the worker reported no end")

@@ -124,25 +124,11 @@ def dryrun_abstract(
     analytic model (auto/analyser.py) is approximate, at compile cost
     but zero HBM. Returns (argument_bytes, temp_bytes, output_bytes).
     """
-    from dlrover_tpu.parallel import sharding as shd
-
     trainer = build_trainer(cfg, strategy, devices, optimizer)
-    abs_params = jax.eval_shape(trainer._init_fn, jax.random.key(0))
-    abs_opt = jax.eval_shape(trainer.optimizer.init, abs_params)
-    # attach the trainer's layouts to the abstract args: donation pins
-    # input shardings to output shardings, and leaving inputs
-    # unspecified lets XLA infer layouts that break that aliasing
-    opt_shardings = trainer.opt_shardings or shd.opt_state_shardings(
-        abs_opt, abs_params, trainer.param_shardings, trainer.mesh
-    )
-    abs_params = jax.tree.map(
-        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
-        abs_params, trainer.param_shardings,
-    )
-    abs_opt = jax.tree.map(
-        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
-        abs_opt, opt_shardings,
-    )
+    # the trainer's layouts on the abstract args: donation pins input
+    # shardings to output shardings, and leaving inputs unspecified
+    # lets XLA infer layouts that break that aliasing
+    abs_params, abs_opt = trainer.abstract_state()
     from dlrover_tpu.models import example_batch
 
     mb = global_batch // max(strategy.accum_steps, 1)

@@ -1,9 +1,8 @@
 """Where the host-local caches live, and the hardening of the default.
 
-Two subsystems persist host-local state that a restarted worker will
-TRUST: the XLA compile cache (deserialized executables,
-trainer/compile_cache.py) and the kernel tuning cache (block-size
-decisions, ops/tuning.py, in ``tuning/`` below the compile cache).
+The XLA compile cache (deserialized executables,
+trainer/compile_cache.py) is host-local state that a restarted worker
+will TRUST.
 
 Placement has one knob, JAX's own: where ``JAX_COMPILATION_CACHE_DIR``
 is set, every process of a job (launcher, agent, worker, restarted

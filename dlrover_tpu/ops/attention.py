@@ -13,6 +13,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from dlrover_tpu.ops import tuning
+
 NEG_INF = -1e30
 
 
@@ -85,40 +87,32 @@ def flash_attention(
     reaches at a shape the kernel takes.
 
     Layout [batch, seq, heads, head_dim] (the models' native layout).
-    ``block_q``/``block_k`` cap the kernel block sizes (None = tuned);
-    the GQA group folds into the kernel's matmul rows, so the effective
-    q-block is ``group * block_q`` rows.
-
-    Block selection lives in ops/tuning.py: the persisted on-device
-    autotuner answers from its cache (or measures once per shape per
-    host on TPU, outside this trace), with the static
-    largest-power-of-two heuristic as the prior and the only path
-    off-TPU. By the time XLA sees the program the blocks are static.
+    ``block_q``/``block_k`` cap the kernel block sizes; the GQA group
+    folds into the kernel's matmul rows, so the effective q-block is
+    ``group * block_q`` rows. The blocks are ``ops/tuning.py``'s static
+    rule on the shapes, and ``tuning.last_selection()`` names them.
     """
     if not _use_pallas(q, k):
         return mha_reference(q, k, v, causal=causal, scale=scale)
-    from dlrover_tpu.ops import tuning
     from dlrover_tpu.ops.pallas.flash_attention import (
         flash_attention_tpu,
     )
 
-    blocks = tuning.get_blocks(
-        seq=q.shape[1],
-        head_dim=q.shape[3],
-        group=q.shape[2] // k.shape[2],
-        dtype=jnp.dtype(q.dtype).name,
-        causal=causal,
-        block_q=block_q,
-        block_k=block_k,
-    )
+    seq, group = q.shape[1], q.shape[2] // k.shape[2]
+    blocks = tuning.heuristic_blocks(seq, group, block_q, block_k)
     if blocks is None:
         # a dense [s, s] fallback here would pass every check and
         # cost the run its memory and its speed in silence
         raise ValueError(
-            f"no kernel blocks tile seq={q.shape[1]} under caps "
+            f"no kernel blocks tile seq={seq} under caps "
             f"block_q={block_q} block_k={block_k}"
         )
     bq, bk = blocks
+    tuning.record(
+        kernel="flash_attention", seq=seq, head_dim=q.shape[3],
+        gqa_group=group, dtype=jnp.dtype(q.dtype).name, causal=causal,
+        block_q=bq, block_k=bk,
+    )
     return flash_attention_tpu(
         q, k, v, causal=causal, scale=scale, block_q=bq, block_k=bk,
     )

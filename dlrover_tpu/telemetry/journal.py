@@ -2,14 +2,14 @@
 
 Every consequential control-plane and training-plane event — rendezvous
 rounds, scale actions, checkpoint save/restore, compile-cache state,
-kernel-tuning decisions, hang detections, fault injections — writes
+hang detections, fault injections — writes
 through here, so failure attribution after a restart reads one ordered
 timeline instead of grepping stderr across processes (the ElasWave /
 HSDP-at-100k lesson: elastic decisions are only auditable if the events
 that drove them are durable and ordered).
 
 Envelope per event (payload nested under ``data`` so domain fields —
-a tuning key's ``seq``, say — can never collide with the envelope)::
+a sequence length ``seq``, say — can never collide with the envelope)::
 
     {"seq": 17, "ts": 1754300000.123, "host": "tpu-vm-3", "pid": 4242,
      "proc": 2, "kind": "checkpoint.save", "data": {...payload}}

@@ -4,7 +4,7 @@ The observability substrate the master's decisions are only as good as
 (ISSUE 2; cf. the failure-attribution telemetry underneath HSDP-scale
 fault tolerance, arXiv:2602.00277): one process-wide registry that
 counters, gauges, and histograms from every layer (servicer RPCs, speed
-monitor, rendezvous, checkpoint, kernel tuning) register into, rendered
+monitor, rendezvous, checkpoint) register into, rendered
 two ways:
 
   * ``to_prometheus_text()`` — the Prometheus text exposition format

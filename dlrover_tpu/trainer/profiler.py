@@ -17,42 +17,11 @@ Two consumers:
 
 import time
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 
 from dlrover_tpu.common.log import default_logger as logger
-from dlrover_tpu.telemetry import default_journal
-
-# ---------------------------------------------------------------- tuning
-# Kernel-autotuning events (ops/tuning.py): each block-size decision —
-# cache hit, fresh measurement, or heuristic fallback — writes through
-# the structured event journal (telemetry/journal.py) as kind
-# ``tuning.decision``, so the decisions land on the same attributable
-# timeline as rendezvous/checkpoint/fault events. This adapter keeps
-# the original per-process API: ``tuning_events()`` returns the same
-# flat dicts it always did, now read back out of the journal ring.
-
-_TUNING_KIND = "tuning.decision"
-
-
-def record_tuning_event(**fields) -> None:
-    """Record one kernel-tuning decision (called by ops/tuning.py)."""
-    evt = dict(fields)
-    evt.setdefault("time", time.time())
-    default_journal().record(_TUNING_KIND, **evt)
-    logger.info("kernel tuning event: %s", evt)
-
-
-def tuning_events() -> List[Dict[str, Any]]:
-    """All tuning decisions made by this process, oldest first — the
-    pre-journal flat-dict shape (journal envelope stripped)."""
-    out = []
-    for event in default_journal().events(_TUNING_KIND):
-        evt = dict(event.get("data") or {})
-        evt.setdefault("time", event["ts"])
-        out.append(evt)
-    return out
 
 
 @dataclass

@@ -93,11 +93,10 @@ class ShardedTrainer:
 
     # -- init ------------------------------------------------------------
     def init(self, rng: jax.Array):
-        """Initialize (params, opt_state), laid out per the strategy.
-
-        Params get explicit out_shardings; optimizer-state shardings are
-        propagated by GSPMD from the params they mirror (no bookkeeping of
-        optax state internals needed).
+        """Initialize (params, opt_state) in the layout
+        ``abstract_state()`` names: the one a restore gives. (Left to
+        propagation, Adam's moments come out replicated under ``fsdp``:
+        zeros propagate nothing.)
         """
         if self._jit_init is None:
 
@@ -108,7 +107,9 @@ class ShardedTrainer:
 
             self._jit_init = jax.jit(
                 _init,
-                out_shardings=(self.param_shardings, self.opt_shardings),
+                out_shardings=jax.tree.map(
+                    lambda a: a.sharding, self.abstract_state()
+                ),
             )
         with self.mesh:
             return self._jit_init(rng)

@@ -14,10 +14,13 @@ only one process may hold the library, and every xdist worker imports
 every test file.
 """
 
+import base64
 import dataclasses
+import json
 import math
 import os
 import re
+import threading
 
 import numpy as np
 import pytest
@@ -81,9 +84,6 @@ def on_tpu_path(monkeypatch):
     ``jax.default_backend()``, which is the CPU here."""
     monkeypatch.setattr(fa, "_interpret", lambda: False)
     monkeypatch.setattr(attention, "_use_pallas", lambda q, k: True)
-    # the tuner would now try to time kernels on a chip that is not
-    # there; the heuristic pair is what an untuned chip run starts from
-    monkeypatch.setattr(tuning, "_measurement_enabled", lambda: False)
 
 
 def _abstract_step_args(trainer, batch, seq):
@@ -247,17 +247,20 @@ def _kernel_args(one_chip):
     return q, kv, kv
 
 
+#: the rule's pair at a group of 8 and every narrower block_k
+LLAMA_1B_BLOCKS = [(128, 1024), (128, 512), (128, 256), (128, 128)]
+
+
 @pytest.mark.parametrize("pair", range(4))
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
 def test_flash_kernel_compiles_at_llama_1b_shape(
     topo, on_tpu_path, pair, grad
 ):
     cfg = llama.llama_1b()
-    grid = tuning.candidate_grid(
+    assert LLAMA_1B_BLOCKS[0] == tuning.heuristic_blocks(
         SEQ, cfg.num_heads // cfg.num_kv_heads
     )
-    assert len(grid) == 4, grid
-    bq, bk = grid[pair]
+    bq, bk = LLAMA_1B_BLOCKS[pair]
 
     def attn(q, k, v):
         return fa.flash_attention_tpu(
@@ -301,11 +304,10 @@ def _sum_grad(attn):
 def test_sub_tiled_kernels_compile_at_the_cells_shapes(
     topo, on_tpu_path, cell, grad
 ):
-    """At the heuristic's blocks (what a cell runs with the tuner
-    off). Without a group the block on the diagonal is walked in rows
-    of sub-tiles: a switch over the widths a row can have, slices of
-    the scratch rows and, in the backward kernels, of the lanes of the
-    logsumexp."""
+    """At the rule's blocks (what a cell runs). Without a group the
+    block on the diagonal is walked in rows of sub-tiles: a switch over
+    the widths a row can have, slices of the scratch rows and, in the
+    backward kernels, of the lanes of the logsumexp."""
     batch, seq, heads, kv_heads, d = CELL_ATTENTION[cell]
     bq, bk = tuning.heuristic_blocks(seq, heads // kv_heads)
     # a group is not sub-tiled: Mistral's kernels are the whole-block ones
@@ -328,6 +330,77 @@ def test_sub_tiled_kernels_compile_at_the_cells_shapes(
     fn = _sum_grad(attn) if grad else attn
     compiled = jax.jit(fn).lower(q, kv, kv).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _lowered_kernels(fn, *args):
+    """A lowering's text with each ``tpu_custom_call``'s payload taken
+    out, and the payloads' Mosaic modules printed without source
+    locations (those name the call stack, and so the caller)."""
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    payload = re.compile(r'backend_config = "(.*?)"(?=[,}])')
+    text = jax.jit(fn).lower(*args).as_text()
+    modules = []
+    ctx = mlir.make_ir_context()
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        for escaped in payload.findall(text):
+            config = json.loads(re.sub(
+                r"\\([0-9A-Fa-f]{2})",
+                lambda m: chr(int(m.group(1), 16)), escaped,
+            ))
+            body = base64.b64decode(config["custom_call_config"]["body"])
+            modules.append(ir.Module.parse(body).operation.get_asm(
+                enable_debug_info=False
+            ))
+    return payload.sub("", text), modules
+
+
+@pytest.mark.parametrize("variable", [None, "off"], ids=["unset", "off"])
+@pytest.mark.parametrize("cell", list(CELL_ATTENTION))
+def test_dispatch_lowers_the_rules_kernels_whatever_the_environment(
+    topo, on_tpu_path, monkeypatch, cell, variable
+):
+    """What a process on the chip lowers for a cell's attention and
+    its gradients is ``flash_attention_tpu`` at the rule's pair, with
+    the variable every mix of ``yardstick/traffic/`` still sets and
+    without it; nothing is timed on the way (a sweep ran on a thread
+    of its own and ended in ``jax.clear_caches()``)."""
+    batch, seq, heads, kv_heads, d = CELL_ATTENTION[cell]
+    bq, bk = tuning.heuristic_blocks(seq, heads // kv_heads)
+    if variable is None:
+        monkeypatch.delenv("DLROVER_TPU_ATTN_TUNING", raising=False)
+    else:
+        monkeypatch.setenv("DLROVER_TPU_ATTN_TUNING", variable)
+    # as a chip's process sees it
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    q, kv = (
+        jax.ShapeDtypeStruct(
+            (batch, seq, h, d), jnp.bfloat16, sharding=one_chip
+        ) for h in (heads, kv_heads)
+    )
+    want = _lowered_kernels(_sum_grad(
+        lambda q, k, v: fa.flash_attention_tpu(
+            q, k, v, causal=True, block_q=bq, block_k=bk)
+    ), q, kv, kv)
+    assert len(want[1]) == 3, len(want[1])
+
+    def refuse(*args, **kwargs):
+        raise AssertionError("a thread or jax.clear_caches()")
+
+    monkeypatch.setattr(threading.Thread, "start", refuse)
+    monkeypatch.setattr(jax, "clear_caches", refuse)
+    # the undecorated function: inlined like the kernel's own wrapper,
+    # and traced whatever this process has traced before
+    got = _lowered_kernels(
+        _sum_grad(attention.flash_attention.__wrapped__), q, kv, kv
+    )
+    assert got == want
+    assert tuning.last_selection()["source"] == "static"
 
 
 def _count_equations(jaxpr):

@@ -1,5 +1,5 @@
 """Unified telemetry: registry, journal, exposition, dump CLI, and the
-instrumentation wired into servicer / event queue / tuning adapter."""
+instrumentation wired into servicer / event queue."""
 
 import json
 import re
@@ -140,8 +140,8 @@ def test_journal_seq_monotonic_and_file_roundtrip(tmp_path):
 
 def test_journal_kind_prefix_filter_and_payload_isolation():
     j = EventJournal(None)
-    # payload keys that LOOK like envelope keys (a tuning key's `seq`
-    # is a sequence LENGTH) stay in data, never shadow the envelope
+    # payload keys that LOOK like envelope keys (a `seq` that is a
+    # sequence LENGTH) stay in data, never shadow the envelope
     j.record("checkpoint.save", step=1, seq=999, ts=-5.0, pid=-1)
     j.record("checkpoint.restore", step=2)
     j.record("checkpointing", step=3)  # not a dotted child
@@ -426,24 +426,6 @@ def test_event_queue_counts_dropped_oldest():
     assert T.default_registry().get(
         "dlrover_event_queue_dropped_total"
     ).value == 2
-
-
-def test_tuning_events_adapter_keeps_legacy_shape():
-    from dlrover_tpu.trainer import profiler
-
-    profiler.record_tuning_event(
-        kernel="flash_attention", block_q=512, block_k=256,
-        source="measured", tuning_seconds=1.25,
-    )
-    evs = profiler.tuning_events()
-    assert len(evs) == 1
-    evt = evs[0]
-    # the pre-journal flat-dict contract
-    assert evt["block_q"] == 512 and evt["source"] == "measured"
-    assert "time" in evt and "kind" not in evt and "seq" not in evt
-    # and the same decision is on the structured timeline
-    jevs = T.default_journal().events("tuning.decision")
-    assert len(jevs) == 1 and jevs[0]["data"]["block_k"] == 256
 
 
 def test_hang_detector_journals_stall():
