@@ -11,6 +11,10 @@ edge is the whole-block body. Beside them, forward and forward-and-
 backward of what the file's own rule gives (``"rule": true``) and of
 the whole-block body at each smaller pair of grid blocks of
 ``--blocks``: what the grid's own skipping gives with no walk at all.
+And, without a group, the rule's kernels with the backward the other
+way than ``_one_backward_kernel`` has it for the shape: the dq and
+dk/dv pair (``"backward_kernels": 2``) against the one kernel that
+keeps a head's dQ in VMEM (``1``): how that rule's edge was read.
 Each timed call runs ``--layers`` attention calls in one ``lax.scan``
 so that the host's clock times tens of milliseconds. Only a TPU run
 says anything: ``chiprun -- python3 benchmarks/profile_attn_subtiles.py``.
@@ -32,14 +36,15 @@ from dlrover_tpu.ops import tuning
 from dlrover_tpu.ops.pallas import flash_attention as fa
 
 #: name: (batch, seq, heads, kv_heads, head_dim) of a cell's step.
-#: Mistral's [3, 4096, 32/8, 128] is not here: a group is never
-#: sub-tiled (``flash_attention._fits``). Its row in PERF.md, and the
-#: rolled-loop, ``--unroll`` and ``--scratch-state`` readings there,
-#: came from earlier versions of this script and of the kernel file
-#: that are not in the tree
+#: Mistral's group is never sub-tiled (``flash_attention._fits``) and
+#: keeps two backward kernels: no edge is swept there. Its sub-tile
+#: row in PERF.md, and the rolled-loop, ``--unroll`` and
+#: ``--scratch-state`` readings there, came from earlier versions of
+#: this script and of the kernel file that are not in the tree
 SHAPES = {
     "gpt2-xl": (12, 1024, 25, 25, 64),
     "olmoe": (3, 4096, 16, 16, 128),
+    "mistral": (3, 4096, 32, 8, 128),
 }
 
 
@@ -92,37 +97,47 @@ def main(argv=None):
     ]
     os.makedirs(os.path.dirname(args.out), exist_ok=True)
     rng = np.random.default_rng(0)
-    rule = fa._sub_tiles
+    rule, one_kernel = fa._sub_tiles, fa._one_backward_kernel
     whole = {"fwd": None, "dq": None, "dkv": None}
     both = ("fwd_ms", "fwd_bwd_ms")
     for name in args.shapes.split(","):
         batch, seq, heads, kv_heads, d = SHAPES[name]
-        blocks = tuning.heuristic_blocks(seq, heads // kv_heads)
+        group = heads // kv_heads
+        blocks = tuning.heuristic_blocks(seq, group)
         q, k, v = (
             jnp.asarray(
                 rng.standard_normal((batch, seq, h, d)), jnp.bfloat16
             )
             for h in (heads, kv_heads, kv_heads)
         )
+        ruled = 1 if one_kernel(group, seq, d) else 2
         # (grid blocks, an edge a kernel or None for the file's rule,
-        # what to time)
-        settings = [(blocks, whole, both), (blocks, None, both)] + [
-            (pair, whole, both) for pair in smaller
+        # backward kernels, what to time); the sweeps of an edge with
+        # the backward as the pair they were read with
+        settings = [(blocks, whole, 2, both), (blocks, None, ruled, both)] + [
+            (blocks, None, 3 - ruled, both[1:])
+        ] * (group == 1) + [
+            (pair, whole, 2, both) for pair in smaller
         ] + [
-            (blocks, dict(whole, **{kernel: sub}),
+            (blocks, dict(whole, **{kernel: sub}), 2,
              both[:1] if kernel == "fwd" else both[1:])
-            for kernel in whole for sub in subs if sub < max(blocks)
+            for kernel in whole for sub in subs
+            if sub < max(blocks) and group == 1
         ]
-        for (block_q, block_k), edges, keys in settings:
+        for (block_q, block_k), edges, kernels, keys in settings:
             fa._sub_tiles = rule if edges is None else (
                 lambda kernel, bq, bk, g, d: fa._fits(  # noqa: B023
                     edges[kernel], g, bq, bk
                 )
             )
+            fa._one_backward_kernel = (
+                lambda g, seq, d: kernels == 1  # noqa: B023
+            )
             fns = dict(zip(both, _stack(args.layers, block_q, block_k)))
             row = {
                 "shape": name, "blocks": [block_q, block_k],
                 **(edges or {"rule": True}),
+                "backward_kernels": kernels,
             }
             try:
                 for key in keys:

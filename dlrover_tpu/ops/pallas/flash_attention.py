@@ -20,7 +20,13 @@ i uses kv head i // group, matching jnp.repeat semantics).
 Backward follows the FlashAttention-2 structure: a dQ kernel (grid over
 q-blocks, accumulating over k-blocks) and a dK/dV kernel (grid over
 k-blocks, accumulating over q-blocks), with the softmax re-derived from
-the saved logsumexp.
+the saved logsumexp. The two recompute the scores, the softmax and dS
+of every live block, seven products where the mathematics has five.
+Where a head's whole float32 dQ can stay in VMEM across the dK/dV
+kernel's grid (``_one_backward_kernel``: no group, and few enough
+positions), the backward is that kernel alone with one product more,
+dQ += dS K on the dS it has formed (``_dqkv_kernel``): every operand
+is read once and every score computed once.
 
 What a causally live grid step computes (``_walk``). Blocks wholly
 above the diagonal are skipped by the grid (``pl.when`` + the clamped
@@ -96,8 +102,9 @@ def _stack_cols(ref, g, rows=slice(None)):
 
 def _sub_tiles(kernel, block_q, block_k, g, head_dim):
     """The edge of the square sub-tiles in which ``kernel`` ("fwd",
-    "dq" or "dkv") walks the (block_q, block_k) grid block on the
-    diagonal; None where it takes the block whole.
+    "dq", "dkv", or "dqkv": the dk/dv kernel that accumulates dQ too)
+    walks the (block_q, block_k) grid block on the diagonal; None where
+    it takes the block whole.
 
     As read on a v5e at the blocks ``ops/tuning.py heuristic_blocks``
     gives (``benchmarks/profile_attn_subtiles.py``; PERF.md section 6,
@@ -320,8 +327,8 @@ def _check_blocks(seq, block_q, block_k):
 
 
 def _kernel(body, name, seq, causal, g, block_q, block_k, head_dim, scale):
-    """``body`` ("fwd", "dq" or "dkv" by ``name``) with its static
-    arguments; building a causal one sets the census gauges."""
+    """``body`` ("fwd", "dq", "dkv" or "dqkv" by ``name``) with its
+    static arguments; building a causal one sets the census gauges."""
     sub = None
     if causal:
         sub = _sub_tiles(name, block_q, block_k, g, head_dim)
@@ -462,7 +469,9 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr,
-                *, scale, causal, g, block_q, block_k, sub):
+                *, scale, causal, g, block_q, block_k, sub, add_dq=None):
+    """``add_dq(r0, size, cols, ds)``, where given, takes each dS the
+    walk forms (cast for the products) on to dQ: ``_dqkv_kernel``."""
     j = pl.program_id(1)  # k block (major)
     i = pl.program_id(2)  # q block (minor: accumulates)
     nq = pl.num_programs(2)
@@ -493,12 +502,16 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         )
         if sub is None:
             ds = _ds(p, do, v_ref[0, cols], delta)
-        # dK += dS^T @ Q (scale applied once at finalize)
-        dk_scr[cols] += jax.lax.dot_general(
-            jax.lax.convert_element_type(ds, q.dtype), q,
-            (((0,), (0,)), ((), ())),
+        # dK += dS^T @ Q (scale applied once at finalize), spelled out
+        # to keep dS as cast: read, cast, product, as ``+=`` issues them
+        dk = dk_scr[cols]
+        ds = jax.lax.convert_element_type(ds, q.dtype)
+        dk_scr[cols] = dk + jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+        if add_dq is not None:
+            add_dq(r0, size, cols, ds)
 
     _walk(causal, block_q, block_k, sub, q_start, k_start, compute)
 
@@ -508,7 +521,66 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
+def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                 dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
+                 *, scale, block_q, **static):
+    """The dK/dV kernel, and dQ of the whole head (no group) summed in
+    ``dq_scr`` [seq, d] over the grid's (j, i) steps of one head: its
+    output block does not move with them. A query block meets its key
+    blocks in ascending j, as in ``_dq_kernel``."""
+    j, i = pl.program_id(1), pl.program_id(2)
+    seq = dq_scr.shape[0]
+
+    @pl.when(jnp.logical_and(j == 0, i == 0))
+    def _init():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
+
+    def add_dq(r0, size, cols, ds):
+        if block_q == seq:  # one block a head: static offsets
+            rows = _rows_of(r0, size, seq)
+        else:
+            rows = pl.ds(pl.multiple_of(i * block_q + r0, size), size)
+        dq_scr[rows] += jax.lax.dot_general(
+            ds, k_ref[0, cols], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    _dkv_kernel(
+        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+        dk_ref, dv_ref, dk_scr, dv_scr,
+        scale=scale, block_q=block_q, add_dq=add_dq, **static,
+    )
+
+    @pl.when(jnp.logical_and(
+        j == pl.num_programs(1) - 1, i == pl.num_programs(2) - 1
+    ))
+    def _finalize():
+        dq_ref[0, 0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
+
+
+#: what a head's float32 dQ may hold of VMEM beside the dK/dV kernel's
+#: own blocks and scratch: the largest that was compiled and timed
+#: (4096 positions of 128, under the default scoped limit)
+DQ_RESIDENT_BYTES = 2 * 1024 * 1024
+
+
+def _one_backward_kernel(g, seq, head_dim):
+    """Whether the backward is ``_dqkv_kernel`` alone: no group (with
+    one, dQ is g times as large beside g times the slices a body, and
+    tracing a group's bodies cost a second of set-up: ``_sub_tiles``),
+    and the head's float32 dQ within ``DQ_RESIDENT_BYTES``.
+
+    As read on a v5e (``benchmarks/profile_attn_subtiles.py``, forward
+    and backward of a call; PERF.md section 6, PR 33): 4.68 ms against
+    the pair's 5.08 at one (1024, 1024) block a 64-wide head, 6.46
+    against 7.83 at 4 x 4 such blocks of a 128-wide head, where dQ's
+    rows are sliced at an offset from ``program_id``."""
+    return g == 1 and seq * head_dim * 4 <= DQ_RESIDENT_BYTES
+
+
 def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k):
+    from dlrover_tpu.telemetry.registry import gauge
+
     bkh, g, seq, d = q.shape
     block_q = min(block_q, seq)
     block_k = min(block_k, seq)
@@ -517,32 +589,40 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k):
         o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1
     )[:, :, None, :]  # [bkh, g, 1, seq] (4-D for TPU block tiling)
 
-    dq_kernel = _kernel(
-        _dq_kernel, "dq", seq, causal, g, block_q, block_k, d, scale
-    )
-    kv_idx = _kv_index(causal, block_q, block_k)
-    in_specs_q = [
-        pl.BlockSpec((1, g, block_q, d), lambda b, i, j: (b, 0, i, 0)),
-        pl.BlockSpec((1, block_k, d), kv_idx),  # k
-        pl.BlockSpec((1, block_k, d), kv_idx),  # v
-        pl.BlockSpec((1, g, block_q, d), lambda b, i, j: (b, 0, i, 0)),
-        pl.BlockSpec((1, g, 1, block_q), lambda b, i, j: (b, 0, 0, i)),
-        pl.BlockSpec((1, g, 1, block_q), lambda b, i, j: (b, 0, 0, i)),
-    ]
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid=(bkh, seq // block_q, seq // block_k),
-        in_specs=in_specs_q,
-        out_specs=pl.BlockSpec(
-            (1, g, block_q, d), lambda b, i, j: (b, 0, i, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct((bkh, g, seq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((g * block_q, d), jnp.float32)],
-        interpret=_interpret(),
-    )(q, k, v, do, lse, delta)
+    one_kernel = _one_backward_kernel(g, seq, d)
+    gauge(
+        "attn_backward_kernels",
+        "Pallas kernels of the attention backward at the last one "
+        "built: 1 (dq, dk and dv together) or 2 (dq; dk and dv)",
+    ).set(1 if one_kernel else 2)
+    if not one_kernel:
+        dq_kernel = _kernel(
+            _dq_kernel, "dq", seq, causal, g, block_q, block_k, d, scale
+        )
+        kv_idx = _kv_index(causal, block_q, block_k)
+        in_specs_q = [
+            pl.BlockSpec((1, g, block_q, d), lambda b, i, j: (b, 0, i, 0)),
+            pl.BlockSpec((1, block_k, d), kv_idx),  # k
+            pl.BlockSpec((1, block_k, d), kv_idx),  # v
+            pl.BlockSpec((1, g, block_q, d), lambda b, i, j: (b, 0, i, 0)),
+            pl.BlockSpec((1, g, 1, block_q), lambda b, i, j: (b, 0, 0, i)),
+            pl.BlockSpec((1, g, 1, block_q), lambda b, i, j: (b, 0, 0, i)),
+        ]
+        dq = pl.pallas_call(
+            dq_kernel,
+            grid=(bkh, seq // block_q, seq // block_k),
+            in_specs=in_specs_q,
+            out_specs=pl.BlockSpec(
+                (1, g, block_q, d), lambda b, i, j: (b, 0, i, 0)
+            ),
+            out_shape=jax.ShapeDtypeStruct((bkh, g, seq, d), q.dtype),
+            scratch_shapes=[pltpu.VMEM((g * block_q, d), jnp.float32)],
+            interpret=_interpret(),
+        )(q, k, v, do, lse, delta)
 
     dkv_kernel = _kernel(
-        _dkv_kernel, "dkv", seq, causal, g, block_q, block_k, d, scale
+        *((_dqkv_kernel, "dqkv") if one_kernel else (_dkv_kernel, "dkv")),
+        seq, causal, g, block_q, block_k, d, scale
     )
 
     def q_side_idx(sublane):
@@ -567,25 +647,31 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k):
         pl.BlockSpec((1, g, 1, block_q), q_side_idx(False)),
         pl.BlockSpec((1, g, 1, block_q), q_side_idx(False)),
     ]
-    dk, dv = pl.pallas_call(
+    # the one kernel's dQ: the head's whole [seq, d], its block the
+    # same at every (j, i), written back once a head
+    grads = pl.pallas_call(
         dkv_kernel,
         grid=(bkh, seq // block_k, seq // block_q),
         in_specs=in_specs_kv,
-        out_specs=[
+        out_specs=one_kernel * [
+            pl.BlockSpec((1, 1, seq, d), lambda b, j, i: (b, 0, 0, 0)),
+        ] + [
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
         ],
-        out_shape=[
+        out_shape=one_kernel * [
+            jax.ShapeDtypeStruct((bkh, 1, seq, d), q.dtype),
+        ] + [
             jax.ShapeDtypeStruct((bkh, seq, d), k.dtype),
             jax.ShapeDtypeStruct((bkh, seq, d), v.dtype),
         ],
-        scratch_shapes=[
+        scratch_shapes=one_kernel * [pltpu.VMEM((seq, d), jnp.float32)] + [
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)
-    return dq, dk, dv
+    return grads if one_kernel else (dq, *grads)
 
 
 # ---------------------------------------------------------------------------

@@ -286,6 +286,9 @@ CELL_ATTENTION = {
     "olmoe": (3, 4096, 16, 16, 128),
     "mistral": (3, 4096, 32, 8, 128),
 }
+#: the backward's kernels there: one where a head's float32 dQ stays
+#: in VMEM (256 KB, 2 MB), the dq and dk/dv pair at Mistral's group
+CELL_BACKWARD_KERNELS = {"gpt2-xl": 1, "olmoe": 1, "mistral": 2}
 
 
 def _sum_grad(attn):
@@ -328,8 +331,12 @@ def test_sub_tiled_kernels_compile_at_the_cells_shapes(
         ) for h in (heads, kv_heads)
     )
     fn = _sum_grad(attn) if grad else attn
-    compiled = jax.jit(fn).lower(q, kv, kv).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(fn).lower(q, kv, kv).compile().as_text()
+    # the one backward kernel holds OLMoE's whole dQ (2 MB of float32
+    # beside a [4096, 128] output block) under the default VMEM limit
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) == (
+        1 + CELL_BACKWARD_KERNELS[cell] if grad else 1
+    )
 
 
 def _lowered_kernels(fn, *args):
@@ -387,7 +394,8 @@ def test_dispatch_lowers_the_rules_kernels_whatever_the_environment(
         lambda q, k, v: fa.flash_attention_tpu(
             q, k, v, causal=True, block_q=bq, block_k=bk)
     ), q, kv, kv)
-    assert len(want[1]) == 3, len(want[1])
+    # a Mosaic module for the forward and one a backward kernel
+    assert len(want[1]) == 1 + CELL_BACKWARD_KERNELS[cell], len(want[1])
 
     def refuse(*args, **kwargs):
         raise AssertionError("a thread or jax.clear_caches()")
@@ -417,12 +425,21 @@ def _count_equations(jaxpr):
     return count
 
 
-def _one_head(causal, edge, monkeypatch, sharding=None):
+#: the backward's kernels: the one the rule gives this shape, and the
+#: dq and dk/dv pair that longer heads keep
+BACKWARDS = pytest.mark.parametrize("backward", [1, 2], ids=["dqkv", "dq_dkv"])
+
+
+def _one_head(causal, edge, backward, monkeypatch, sharding=None):
     """Attention and its gradients at gpt2-xl's shape, one (1024, 1024)
     block a head, the diagonal block walked in sub-tiles of ``edge``
-    (None: whole) by all three kernels; and one head's argument."""
+    (None: whole) by every kernel, the backward in ``backward``
+    kernels; and one head's argument."""
     monkeypatch.setattr(
         fa, "_sub_tiles", lambda kernel, bq, bk, g, d: fa._fits(edge, g, bq, bk)
+    )
+    monkeypatch.setattr(
+        fa, "_one_backward_kernel", lambda g, seq, d: backward == 1
     )
     return _sum_grad(
         lambda q, k, v: fa.flash_attention_tpu(
@@ -430,9 +447,10 @@ def _one_head(causal, edge, monkeypatch, sharding=None):
     ), jax.ShapeDtypeStruct((1, 1024, 1, 64), jnp.bfloat16, sharding=sharding)
 
 
-def _kernel_sizes(causal, edge, monkeypatch):
-    """Equation counts of the forward, dq and dk/dv kernels' jaxprs."""
-    fn, q = _one_head(causal, edge, monkeypatch)
+def _kernel_sizes(causal, edge, backward, monkeypatch):
+    """Equation counts of the forward and the backward kernels'
+    jaxprs."""
+    fn, q = _one_head(causal, edge, backward, monkeypatch)
     sizes = []
 
     def find(jaxpr):
@@ -445,36 +463,47 @@ def _kernel_sizes(causal, edge, monkeypatch):
                     find(value)
 
     find(jax.make_jaxpr(fn)(q, q, q))
-    assert len(sizes) == 3, sizes
+    assert len(sizes) == 1 + backward, sizes
     return sizes
 
 
-def _lowered_sizes(edge, monkeypatch, chip):
-    """Bytes of the three causal kernels as a step's lowering for
-    ``chip`` holds them (a ``tpu_custom_call`` line each, the Mosaic
-    module inside it)."""
-    fn, q = _one_head(True, edge, monkeypatch, SingleDeviceSharding(chip))
+def _lowered_sizes(edge, backward, monkeypatch, chip):
+    """Bytes of the causal kernels as a step's lowering for ``chip``
+    holds them (a ``tpu_custom_call`` line each, the Mosaic module
+    inside it)."""
+    fn, q = _one_head(
+        True, edge, backward, monkeypatch, SingleDeviceSharding(chip)
+    )
     text = jax.jit(fn).lower(q, q, q).as_text()
     sizes = [len(line) for line in text.splitlines()
              if "tpu_custom_call" in line]
-    assert len(sizes) == 3, sizes
+    assert len(sizes) == 1 + backward, sizes
     return sizes
 
 
 EDGES = (128, 256, 512, None)
 
 
-def test_kernel_jaxpr_grows_with_the_edge_not_the_sub_tiles(monkeypatch):
+@BACKWARDS
+def test_kernel_jaxpr_grows_with_the_edge_not_the_sub_tiles(
+    monkeypatch, backward
+):
     """What is traced (a kernel's Python runs once a ``pallas_call``,
-    five times a process, in every process's ``setup_s``): a kernel's
-    jaxpr holds one body for each width a row of sub-tiles can have,
-    one a column tile of the block, inside one loop over the rows. 64
-    sub-tiles of 128 add to the whole-block kernel twice what 16 of 256
-    add, and those twice what 4 of 512 add; a body a sub-tile would add
-    four times as much at each step."""
-    at = {edge: _kernel_sizes(True, edge, monkeypatch) for edge in EDGES}
-    plain = _kernel_sizes(False, None, monkeypatch)
-    for k in range(3):
+    four or five times a process, in every process's ``setup_s``): a
+    kernel's jaxpr holds one body for each width a row of sub-tiles can
+    have, one a column tile of the block, inside one loop over the
+    rows. 64 sub-tiles of 128 add to the whole-block kernel twice what
+    16 of 256 add, and those twice what 4 of 512 add; a body a sub-tile
+    would add four times as much at each step. The one backward kernel
+    holds the dk/dv kernel's bodies with a product more in each, not a
+    second set."""
+    at = {edge: _kernel_sizes(True, edge, backward, monkeypatch)
+          for edge in EDGES}
+    plain = _kernel_sizes(False, None, backward, monkeypatch)
+    if backward == 1:
+        pair = _kernel_sizes(True, 256, 2, monkeypatch)
+        assert pair[2] < at[256][1] < pair[2] + 0.25 * pair[1], (at, pair)
+    for k in range(1 + backward):
         a, b, c, whole = (at[edge][k] for edge in EDGES)
         assert plain[k] < whole < c < b < a, (at, plain)
         assert a - b == 2 * (b - c), at
@@ -484,15 +513,18 @@ def test_kernel_jaxpr_grows_with_the_edge_not_the_sub_tiles(monkeypatch):
         assert a < 10 * plain[k], (at, plain)
 
 
-def test_lowered_kernel_holds_a_body_a_row(topo, on_tpu_path, monkeypatch):
+@BACKWARDS
+def test_lowered_kernel_holds_a_body_a_row(
+    topo, on_tpu_path, monkeypatch, backward
+):
     """What is lowered: the loop over rows is unrolled there and each
     row's switch has a constant index, so the Mosaic program grows by
     one body a row of sub-tiles (2, 4, 8 at 512, 256, 128), not by one
     a sub-tile (3, 10, 36), and not by every width in every row (4,
     16, 64)."""
-    at = {edge: _lowered_sizes(edge, monkeypatch, topo.devices[0])
+    at = {edge: _lowered_sizes(edge, backward, monkeypatch, topo.devices[0])
           for edge in EDGES}
-    for k in range(3):
+    for k in range(1 + backward):
         a, b, c, whole = (at[edge][k] for edge in EDGES)
         assert whole < c < b < a, at
         # rows double at each step: 2.0-2.2 read; a body a sub-tile

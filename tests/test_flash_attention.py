@@ -4,6 +4,8 @@ Runs the Pallas kernels in interpret mode on CPU (the reference's CUDA
 flash-attn tests are GPU-gated; interpret mode gives us full coverage
 without a TPU)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -179,7 +181,8 @@ def test_census_at_gpt2_xl_and_its_gauges():
     jax.grad(lambda q: jnp.sum(flash_attention_tpu(
         q, k, v, causal=True, block_q=512, block_k=512)))(q)
     text = default_registry().to_prometheus_text()
-    for kernel in ("fwd", "dq", "dkv"):
+    # without a group the backward is one kernel, under its own name
+    for kernel in ("fwd", "dqkv"):
         edge = flash_attention._sub_tiles(kernel, 512, 512, 1, 64) or 512
         covered, computed, masked = flash_attention.causal_tile_census(
             512, 512, 512, edge, edge)
@@ -190,3 +193,127 @@ def test_census_at_gpt2_xl_and_its_gauges():
             line = next(ln for ln in text.splitlines()
                         if ln.startswith(f'{name}{{kernel="{kernel}"}} '))
             assert float(line.split()[1]) == value
+
+
+# (seq, block, head_dim, causal) without a group, where the backward
+# is one kernel: one block a head and several (the resident dQ sums
+# over the key blocks a query block meets), both head widths, and at
+# blocks of 512 the diagonal walked in sub-tiles of 256
+ONE_BACKWARD_KERNEL = [
+    (256, 256, 64, True),
+    (256, 256, 128, False),
+    (512, 128, 64, True),
+    (512, 128, 128, True),
+    (512, 128, 64, False),
+    (512, 512, 128, True),
+    (1024, 512, 64, True),
+]
+
+
+def _kernels_of_grads(attn, q, k, v):
+    """The kernels in the jaxpr of ``attn``'s gradients, by name of
+    their body."""
+    names = []
+
+    def find(jaxpr):
+        for eqn in getattr(jaxpr, "jaxpr", jaxpr).eqns:
+            if eqn.primitive.name == "pallas_call":
+                names.append(eqn.params["jaxpr"].debug_info.func_name)
+                continue
+            for value in eqn.params.values():
+                if hasattr(getattr(value, "jaxpr", value), "eqns"):
+                    find(value)
+
+    find(jax.make_jaxpr(functools.partial(_grads, attn))(q, k, v))
+    return names
+
+
+def _grads(attn, q, k, v):
+    return jax.grad(
+        lambda q, k, v: jnp.sum(attn(q, k, v) ** 2), argnums=(0, 1, 2)
+    )(q, k, v)
+
+
+@pytest.fixture(scope="module", params=ONE_BACKWARD_KERNEL, ids=str)
+def one_backward_kernel(request):
+    """dq, dk, dv of the one backward kernel, of the dq and dk/dv pair
+    at the same blocks, and of the reference, once a case."""
+    seq, block, d, causal = request.param
+    q, k, v = _rand_qkv(jax.random.key(7), 2, seq, 2, 2, d)
+
+    def attn(q, k, v):
+        return flash_attention_tpu(
+            q, k, v, causal=causal, block_q=block, block_k=block)
+
+    assert _kernels_of_grads(attn, q, k, v) == [
+        "_fwd_kernel", "_dqkv_kernel"]
+    one = _grads(attn, q, k, v)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(
+            flash_attention, "_one_backward_kernel", lambda g, seq, d: False)
+        assert _kernels_of_grads(attn, q, k, v) == [
+            "_fwd_kernel", "_dq_kernel", "_dkv_kernel"]
+        pair = _grads(attn, q, k, v)
+    ref = _grads(lambda q, k, v: mha_reference(q, k, v, causal=causal),
+                 q, k, v)
+    return tuple(dict(zip(("dq", "dk", "dv"), g)) for g in (one, pair, ref))
+
+
+@pytest.mark.parametrize("what", ["dq", "dk", "dv"])
+def test_one_backward_kernel_matches_reference_and_the_pair(
+    one_backward_kernel, what
+):
+    one, pair, ref = one_backward_kernel
+    np.testing.assert_allclose(
+        one[what], ref[what], rtol=5e-3, atol=5e-3,
+        err_msg=f"{what} against the reference",
+    )
+    # the same products in the same order, and dQ's sums over key
+    # blocks in the dq kernel's order: float32 agrees to rounding
+    np.testing.assert_allclose(
+        one[what], pair[what], rtol=1e-6, atol=1e-6,
+        err_msg=f"{what} against the dq and dk/dv kernels",
+    )
+
+
+def _backward_kernels_gauge():
+    from dlrover_tpu.telemetry.registry import default_registry
+
+    return default_registry().get("attn_backward_kernels").value
+
+
+@pytest.mark.parametrize("g,kernels", [
+    (1, ["_fwd_kernel", "_dqkv_kernel"]),
+    (4, ["_fwd_kernel", "_dq_kernel", "_dkv_kernel"]),
+])
+def test_a_group_keeps_two_backward_kernels(g, kernels):
+    q, k, v = _rand_qkv(jax.random.key(8), 1, 256, g, 1, 64)
+
+    def attn(q, k, v):
+        return flash_attention_tpu(
+            q, k, v, causal=True, block_q=128, block_k=128)
+
+    assert _kernels_of_grads(attn, q, k, v) == kernels
+    assert _backward_kernels_gauge() == len(kernels) - 1
+    for got, want in zip(
+        _grads(attn, q, k, v),
+        _grads(lambda q, k, v: mha_reference(q, k, v, causal=True), q, k, v),
+    ):
+        np.testing.assert_allclose(got, want, rtol=5e-3, atol=5e-3)
+
+
+def test_a_head_too_long_for_vmem_keeps_two_backward_kernels(monkeypatch):
+    """The rule reads shapes: the head's float32 dQ against the budget."""
+    rule = flash_attention._one_backward_kernel
+    budget = flash_attention.DQ_RESIDENT_BYTES
+    assert rule(1, 1024, 64) and rule(1, 4096, 128)  # gpt2-xl, OLMoE
+    assert not rule(4, 4096, 128)  # Mistral
+    assert rule(1, budget // (4 * 128), 128)
+    assert not rule(1, 2 * budget // (4 * 128), 128)
+    monkeypatch.setattr(flash_attention, "DQ_RESIDENT_BYTES", 256 * 64 * 4)
+    q, k, v = _rand_qkv(jax.random.key(9), 1, 512, 1, 1, 64)
+    assert _kernels_of_grads(
+        functools.partial(flash_attention_tpu, block_q=128, block_k=128),
+        q, k, v,
+    ) == ["_fwd_kernel", "_dq_kernel", "_dkv_kernel"]
+    assert _backward_kernels_gauge() == 2
