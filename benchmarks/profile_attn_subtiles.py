@@ -15,6 +15,11 @@ And, without a group, the rule's kernels with the backward the other
 way than ``_one_backward_kernel`` has it for the shape: the dq and
 dk/dv pair (``"backward_kernels": 2``) against the one kernel that
 keeps a head's dQ in VMEM (``1``): how that rule's edge was read.
+A shape with a window (``smallthinker``: 16,384 positions, 28 query
+heads on 4, the window 4096) is timed at the rule's blocks with the
+window and without it: what the blocks skipped under the band's lower
+edge save (``"window"`` in the row). Its batch of one sequence runs
+``--layers`` calls like the others.
 Each timed call runs ``--layers`` attention calls in one ``lax.scan``
 so that the host's clock times tens of milliseconds. Only a TPU run
 says anything: ``chiprun -- python3 benchmarks/profile_attn_subtiles.py``.
@@ -45,7 +50,10 @@ SHAPES = {
     "gpt2-xl": (12, 1024, 25, 25, 64),
     "olmoe": (3, 4096, 16, 16, 128),
     "mistral": (3, 4096, 32, 8, 128),
+    "smallthinker": (1, 16384, 28, 4, 128),
 }
+#: the window of a shape's windowed layers
+WINDOWS = {"smallthinker": 4096}
 
 
 def timeit(fn, *args, n=10, warmup=2):
@@ -60,10 +68,11 @@ def timeit(fn, *args, n=10, warmup=2):
     return (time.perf_counter() - t0) / n
 
 
-def _stack(layers, block_q, block_k):
+def _stack(layers, block_q, block_k, window=None):
     def attn(q, k, v):
         return fa.flash_attention_tpu(
-            q, k, v, causal=True, block_q=block_q, block_k=block_k
+            q, k, v, causal=True, block_q=block_q, block_k=block_k,
+            window=window,
         )
 
     def forward(q, k, v):
@@ -124,7 +133,12 @@ def main(argv=None):
             for kernel in whole for sub in subs
             if sub < max(blocks) and group == 1
         ]
-        for (block_q, block_k), edges, kernels, keys in settings:
+        windows = [None]
+        if name in WINDOWS:  # the rule's kernels, without and with it
+            settings, windows = settings[1:2], [None, WINDOWS[name]]
+        for ((block_q, block_k), edges, kernels, keys), window in (
+            (setting, window) for setting in settings for window in windows
+        ):
             fa._sub_tiles = rule if edges is None else (
                 lambda kernel, bq, bk, g, d: fa._fits(  # noqa: B023
                     edges[kernel], g, bq, bk
@@ -133,11 +147,14 @@ def main(argv=None):
             fa._one_backward_kernel = (
                 lambda g, seq, d: kernels == 1  # noqa: B023
             )
-            fns = dict(zip(both, _stack(args.layers, block_q, block_k)))
+            fns = dict(zip(
+                both, _stack(args.layers, block_q, block_k, window)
+            ))
             row = {
                 "shape": name, "blocks": [block_q, block_k],
                 **(edges or {"rule": True}),
                 "backward_kernels": kernels,
+                **({"window": window} if name in WINDOWS else {}),
             }
             try:
                 for key in keys:

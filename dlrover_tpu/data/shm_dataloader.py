@@ -48,11 +48,17 @@ def _producer_main(ring_name: str, dataset_fn, worker_id: int,
     disjoint stream (e.g. master-coordinated shards via ShardingClient)
     and the round-robin filter is skipped."""
     ring = ShmRing.attach(ring_name)
+    # a full ring is a consumer busy elsewhere (its step program
+    # compiles for longer than a push waits): wait on for as long as
+    # the consumer, this process's parent, is there
+    parent = os.getppid()
     try:
         for i, batch in enumerate(dataset_fn()):
             if not pre_sharded and i % num_workers != worker_id:
                 continue
-            ring.push(batch)
+            ring.push(
+                batch, keep_waiting=lambda: os.getppid() == parent
+            )
     except RingClosed:
         pass
     except Exception as e:  # pragma: no cover - crash path

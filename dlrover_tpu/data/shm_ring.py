@@ -154,9 +154,19 @@ class ShmRing:
 
     # -- batch framing ----------------------------------------------------
 
-    def push(self, batch: Any, timeout_ms: int = 60_000):
-        """Push a numpy array / tuple of arrays / arbitrary pytree."""
-        self.push_bytes(_encode(batch), timeout_ms)
+    def push(self, batch: Any, timeout_ms: int = 60_000,
+             keep_waiting=None):
+        """Push a numpy array / tuple of arrays / arbitrary pytree.
+        A ring still full after ``timeout_ms`` raises ``TimeoutError``,
+        unless ``keep_waiting()`` says to wait that long again: a
+        consumer that compiles for minutes is busy, not gone."""
+        data = _encode(batch)
+        while True:
+            try:
+                return self.push_bytes(data, timeout_ms)
+            except TimeoutError:
+                if keep_waiting is None or not keep_waiting():
+                    raise
 
     def pop(self, timeout_ms: int = 60_000) -> Any:
         return _decode(self.pop_bytes(timeout_ms))

@@ -72,6 +72,42 @@ class LlamaConfig:
     # heads and the rotary embedding (``OlmoeAttention``'s q_norm,
     # k_norm)
     qk_norm: bool = False
+    # the width of one head, where the source states it apart from
+    # ``hidden_size // num_heads`` (which None gives)
+    head_dim: Optional[int] = None
+    # a layer pattern, in the source's keys (``SmallThinkerConfig``):
+    # layer l attends within ``sliding_window_size`` keys where
+    # ``sliding_window_layout[l]`` is 1 and to every earlier key where
+    # it is 0; it rotates q and k where ``rope_layout[l]`` is 1 and
+    # gives them no position at all where it is 0. One entry a layer;
+    # None is full attention, and the rotary embedding, in every
+    # layer. The layers are scanned a period at a time, the period the
+    # shortest repeat of the two layouts.
+    sliding_window_size: Optional[int] = None
+    sliding_window_layout: Optional[Tuple[int, ...]] = None
+    rope_layout: Optional[Tuple[int, ...]] = None
+    # what the router reads: "post_attn_norm", the normed residual
+    # stream the experts read, or "block_input", the block's input
+    # before any norm (a router placed before attention)
+    moe_router_input: str = "post_attn_norm"
+    # the gate's activation in an expert: "silu" or "relu"
+    moe_expert_act: str = "silu"
+    # the experts this device holds of each layer's ``num_experts``:
+    # ``moe_experts_held`` of them from ``moe_first_expert_held`` on
+    # (0 held: all). The router keeps its width and top-k is over all;
+    # the layer gives the held experts' part of the sum
+    # (parallel/moe.py). Dropless on one device only.
+    moe_first_expert_held: int = 0
+    moe_experts_held: int = 0
+    # the standard deviation ``init_params`` draws the embedding at,
+    # for a run from scratch. Whoever pre-trains a model whose router
+    # reads the block's input sets it near the layers' own output: at
+    # 0.02 the un-normed stream is, from the third layer on, the
+    # component every token of a sequence shares (uniform attention
+    # hands each token the mean of the values), the router ranks by
+    # it, and every token starts on the same k experts (PERF.md
+    # section 6, PR 34).
+    embed_init_std: float = 0.02
 
     def __post_init__(self):
         if self.remat not in ("off", "dots", "dots_attn_out",
@@ -79,10 +115,65 @@ class LlamaConfig:
             # unknown strings would silently fall through the remat
             # if/elif chains as "off" — an unexplained OOM, not an error
             raise ValueError(f"unknown remat policy {self.remat!r}")
+        if self.head_dim is None:
+            object.__setattr__(
+                self, "head_dim", self.hidden_size // self.num_heads
+            )
+        for name in ("sliding_window_layout", "rope_layout"):
+            layout = getattr(self, name)
+            if layout is None:
+                continue
+            object.__setattr__(self, name, tuple(int(x) for x in layout))
+            if len(layout) != self.num_layers:
+                raise ValueError(
+                    f"{name} has {len(layout)} entries for "
+                    f"{self.num_layers} layers"
+                )
+        if self.sliding_window_layout is not None and (
+                any(self.sliding_window_layout)
+                and not self.sliding_window_size):
+            raise ValueError(
+                "sliding_window_layout asks for a window and "
+                "sliding_window_size gives none"
+            )
+        if self.moe_router_input not in ("post_attn_norm", "block_input"):
+            raise ValueError(
+                f"unknown moe_router_input {self.moe_router_input!r}"
+            )
+        if self.moe_expert_act not in ("silu", "relu"):
+            raise ValueError(
+                f"unknown moe_expert_act {self.moe_expert_act!r}"
+            )
+        if self.num_experts > 0:
+            if not self.moe_experts_held:
+                object.__setattr__(
+                    self, "moe_experts_held", self.num_experts
+                )
+            if not (0 <= self.moe_first_expert_held
+                    <= self.num_experts - self.moe_experts_held):
+                raise ValueError(
+                    f"experts {self.moe_first_expert_held}.."
+                    f"{self.moe_first_expert_held + self.moe_experts_held}"
+                    f" of {self.num_experts}"
+                )
 
-    @property
-    def head_dim(self) -> int:
-        return self.hidden_size // self.num_heads
+    def layer_kinds(self) -> Tuple[Tuple[Optional[int], bool], ...]:
+        """``(window, rope)`` of each layer of one period: the window
+        (None: every earlier key) and whether q and k are rotated. The
+        period is the shortest repeat of the two layouts; one layer of
+        kind ``(None, True)`` where there is no layout."""
+        n = self.num_layers
+        windows = [
+            self.sliding_window_size if on else None
+            for on in self.sliding_window_layout or (0,) * n
+        ]
+        ropes = [bool(on) for on in self.rope_layout or (1,) * n]
+        kinds = tuple(zip(windows, ropes))
+        period = next(
+            p for p in range(1, n + 1)
+            if n % p == 0 and kinds == kinds[:p] * (n // p)
+        )
+        return kinds[:period]
 
 
 def llama2_7b(**kw) -> LlamaConfig:
@@ -163,12 +254,12 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Dict:
         block["q_norm"] = norm_init(L, nh * hd)
         block["k_norm"] = norm_init(L, nkv * hd)
     if cfg.num_experts > 0:
-        E = cfg.num_experts
+        E, held = cfg.num_experts, cfg.moe_experts_held
         block.update({
             "router": dense_init(ks[7], L, h, E, in_axis=1),
-            "w_gate": dense_init(ks[4], L, E, h, m, in_axis=2),
-            "w_up": dense_init(ks[5], L, E, h, m, in_axis=2),
-            "w_down": dense_init(ks[6], L, E, m, h, in_axis=2),
+            "w_gate": dense_init(ks[4], L, held, h, m, in_axis=2),
+            "w_up": dense_init(ks[5], L, held, h, m, in_axis=2),
+            "w_down": dense_init(ks[6], L, held, m, h, in_axis=2),
         })
     else:
         block.update({
@@ -180,7 +271,7 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Dict:
         "embed": (
             jax.random.normal(
                 k_embed, (cfg.vocab_size, h), dtype=jnp.float32
-            ) * 0.02
+            ) * cfg.embed_init_std
         ).astype(cfg.dtype),
         "blocks": block,
         "final_norm": norm_init(h),
@@ -226,7 +317,7 @@ def param_count(cfg: LlamaConfig) -> int:
     L, h, m = cfg.num_layers, cfg.hidden_size, cfg.intermediate_size
     nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     if cfg.num_experts > 0:
-        mlp = h * cfg.num_experts + 3 * h * m * cfg.num_experts
+        mlp = h * cfg.num_experts + 3 * h * m * cfg.moe_experts_held
     else:
         mlp = 3 * h * m
     per_layer = (
@@ -292,8 +383,11 @@ def _free(x, logical_axes):
 
 
 def _pre_attn(cfg: LlamaConfig, x, layer_params, cos, sin,
-              constrain=_free):
-    """Block segment 1: attn-norm + q/k/v projections + rope."""
+              constrain=_free, rope=True):
+    """Block segment 1: attn-norm + q/k/v projections + rope (none in
+    a layer whose kind says so); and, where the router reads the
+    block's input, its logits, which ``_post_attn`` is handed past
+    attention: ``(q, k, v, logits or None)``."""
     b, s, h = x.shape
     nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     p = layer_params
@@ -305,7 +399,14 @@ def _pre_attn(cfg: LlamaConfig, x, layer_params, cos, sin,
     q = constrain(q.reshape(b, s, nh, hd), _Q)
     k = constrain(k.reshape(b, s, nkv, hd), _KV)
     v = constrain((y @ p["wv"]).reshape(b, s, nkv, hd), _KV)
-    return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
+    if rope:
+        q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+    logits = None
+    if cfg.num_experts > 0 and cfg.moe_router_input == "block_input":
+        from dlrover_tpu.parallel.moe import router_logits
+
+        logits = router_logits(x, p["router"])
+    return q, k, v, logits
 
 
 def _expert_mlp(cfg: LlamaConfig, expert_parallel: bool):
@@ -321,7 +422,20 @@ def _expert_mlp(cfg: LlamaConfig, expert_parallel: bool):
         z_coef=cfg.router_z_loss_coef,
     )
     if not expert_parallel:
-        return partial(moe.dropless_moe_mlp, **routing)
+        return partial(
+            moe.dropless_moe_mlp, act=cfg.moe_expert_act,
+            first_held=cfg.moe_first_expert_held, **routing
+        )
+    if (cfg.moe_experts_held != cfg.num_experts
+            or cfg.moe_router_input != "post_attn_norm"
+            or cfg.moe_expert_act != "silu"):
+        raise ValueError(
+            "a share of the experts held on one device "
+            "(moe_experts_held), a router on the block's input and a "
+            "relu gate are the dropless path's, on one device: over "
+            "an 'expert' mesh axis larger than one they are refused "
+            "(experts over chips: ROADMAP B9)"
+        )
     if cfg.moe_capacity_factor <= 0:
         raise ValueError(
             "this configuration states dropless routing "
@@ -335,14 +449,19 @@ def _expert_mlp(cfg: LlamaConfig, expert_parallel: bool):
 
 
 def _post_attn(cfg: LlamaConfig, x, attn, layer_params,
-               constrain=_free, expert_parallel=False):
-    """Block segment 2: output projection + residual + MLP."""
+               router_logits=None, constrain=_free, expert_parallel=False):
+    """Block segment 2: output projection + residual + MLP.
+    ``router_logits``: ``_pre_attn``'s, where the router reads the
+    block's input."""
     b, s, h = x.shape
     p = layer_params
     x = constrain(x + attn.reshape(b, s, -1) @ p["wo"], _RESIDUAL)
     y = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
     if cfg.num_experts > 0:
-        out, aux = _expert_mlp(cfg, expert_parallel)(
+        mlp = _expert_mlp(cfg, expert_parallel)
+        if router_logits is not None:
+            mlp = partial(mlp, logits=router_logits)
+        out, aux = mlp(
             y, p["router"], p["w_gate"], p["w_up"], p["w_down"]
         )
         return constrain(x + out, _RESIDUAL), aux
@@ -353,14 +472,67 @@ def _post_attn(cfg: LlamaConfig, x, attn, layer_params,
 
 
 def _block(cfg: LlamaConfig, x, layer_params, cos, sin, attn_fn,
-           constrain=_free, expert_parallel=False):
+           constrain=_free, expert_parallel=False, rope=True):
     """One decoder block. x: [batch, seq, hidden]. Returns (x, aux_loss)
     where aux_loss is the MoE balance loss (0 for dense)."""
-    q, k, v = _pre_attn(cfg, x, layer_params, cos, sin, constrain)
+    q, k, v, logits = _pre_attn(
+        cfg, x, layer_params, cos, sin, constrain, rope
+    )
     attn = attn_fn(q, k, v)
     return _post_attn(
-        cfg, x, attn, layer_params, constrain, expert_parallel
+        cfg, x, attn, layer_params, logits, constrain, expert_parallel
     )
+
+
+def _attention_of(cfg: LlamaConfig, attn_fn, window):
+    """``attn_fn`` as a layer of one kind calls it. A config with a
+    layer pattern names the two kinds of call, ``attn.full`` and
+    ``attn.window``, in their device ops' ``op_name``, and hands a
+    windowed layer's window to ``attn_fn`` (which has to take it)."""
+    if cfg.sliding_window_layout is None:
+        return attn_fn
+
+    def attend(q, k, v):
+        if window is None:
+            with jax.named_scope("attn.full"):
+                return attn_fn(q, k, v)
+        with jax.named_scope("attn.window"):
+            return attn_fn(q, k, v, window=window)
+
+    return attend
+
+
+def _scan_layers(layers, carry, blocks):
+    """``carry`` through every layer, a period of the layer pattern a
+    scan step: ``layers`` holds one ``layer(carry, layer_params) ->
+    (carry, out)`` for each layer of a period (``layer_kinds()``),
+    ``blocks`` the parameters stacked ``[layers, ...]`` as they are
+    kept. Returns ``(carry, outs stacked [layers, ...])``. With one
+    kind of layer this is the plain scan over ``blocks``."""
+    period = len(layers)
+    if period == 1:
+        return jax.lax.scan(layers[0], carry, blocks)
+    per_period = jax.tree.map(
+        lambda a: a.reshape(-1, period, *a.shape[1:]), blocks
+    )
+
+    def body(carry, period_params):
+        outs = []
+        for i, layer in enumerate(layers):
+            carry, out = layer(
+                carry, jax.tree.map(lambda a: a[i], period_params)
+            )
+            outs.append(out)
+        if outs[0] is None:
+            return carry, None
+        return carry, jax.tree.map(lambda *o: jnp.stack(o), *outs)
+
+    carry, outs = jax.lax.scan(body, carry, per_period)
+    if outs is not None:
+        outs = jax.tree.map(
+            lambda a: a.reshape(-1, *a.shape[2:]), outs
+        )
+    return carry, outs
 
 
 def _dots_policy(cfg: LlamaConfig):
@@ -406,46 +578,56 @@ def hidden_states(
     cos, sin = rope_tables(s, cfg.head_dim, cfg.rope_theta)
     x = constrain(params["embed"][tokens], _RESIDUAL)
 
-    def body(carry, layer_params):
-        x, aux_sum = carry
-        x, aux = _block(
-            cfg, x, layer_params, cos, sin, attn_fn, constrain,
-            expert_parallel,
-        )
-        return (x, aux_sum + aux), None
+    def layer_of(kind):
+        """One layer of ``kind`` under the config's remat policy."""
+        window, rope = kind
+        attend = _attention_of(cfg, attn_fn, window)
 
-    if cfg.remat == "dots_attn_out":
-        # "dots" remat on the segments AROUND attention, with the
-        # attention call OUTSIDE any checkpoint: its custom_vjp
-        # residuals (q, k, v, o, lse) are then kept like ordinary
-        # activations, so the backward pass never re-runs the forward
-        # kernel (under plain "dots" the re-fwd is ~7% of the step).
-        # Costs the saved residuals' HBM (~q+k+v+o+lse per layer).
-        policy = _dots_policy(cfg)
-        pre = jax.checkpoint(
-            partial(_pre_attn, cfg, constrain=constrain), policy=policy,
-        )
-        post = jax.checkpoint(
-            partial(_post_attn, cfg, constrain=constrain,
-                    expert_parallel=expert_parallel),
-            policy=policy,
-        )
-
-        def body(carry, layer_params):  # noqa: F811
+        def body(carry, layer_params):
             x, aux_sum = carry
-            q, k, v = pre(x, layer_params, cos, sin)
-            attn = attn_fn(q, k, v)
-            x, aux = post(x, attn, layer_params)
+            x, aux = _block(
+                cfg, x, layer_params, cos, sin, attend, constrain,
+                expert_parallel, rope,
+            )
             return (x, aux_sum + aux), None
 
-    elif cfg.remat == "dots":
-        body = jax.checkpoint(body, policy=_dots_policy(cfg))
-    elif cfg.remat == "minimal":
-        body = jax.checkpoint(
-            body, policy=jax.checkpoint_policies.nothing_saveable
-        )
-    (x, aux), _ = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), params["blocks"]
+        if cfg.remat == "dots_attn_out":
+            # "dots" remat on the segments AROUND attention, with the
+            # attention call OUTSIDE any checkpoint: its custom_vjp
+            # residuals (q, k, v, o, lse) are then kept like ordinary
+            # activations, so the backward pass never re-runs the
+            # forward kernel (under plain "dots" the re-fwd is ~7% of
+            # the step). Costs the saved residuals' HBM (~q+k+v+o+lse
+            # per layer).
+            policy = _dots_policy(cfg)
+            pre = jax.checkpoint(
+                partial(_pre_attn, cfg, constrain=constrain, rope=rope),
+                policy=policy,
+            )
+            post = jax.checkpoint(
+                partial(_post_attn, cfg, constrain=constrain,
+                        expert_parallel=expert_parallel),
+                policy=policy,
+            )
+
+            def body(carry, layer_params):  # noqa: F811
+                x, aux_sum = carry
+                q, k, v, logits = pre(x, layer_params, cos, sin)
+                attn = attend(q, k, v)
+                x, aux = post(x, attn, layer_params, logits)
+                return (x, aux_sum + aux), None
+
+        elif cfg.remat == "dots":
+            body = jax.checkpoint(body, policy=_dots_policy(cfg))
+        elif cfg.remat == "minimal":
+            body = jax.checkpoint(
+                body, policy=jax.checkpoint_policies.nothing_saveable
+            )
+        return body
+
+    (x, aux), _ = _scan_layers(
+        [layer_of(kind) for kind in cfg.layer_kinds()],
+        (x, jnp.zeros((), jnp.float32)), params["blocks"],
     )
     x = constrain(x, _RESIDUAL)
     return rms_norm(x, params["final_norm"], cfg.norm_eps), aux
@@ -545,36 +727,55 @@ def routing_stats(params: Dict, tokens: jax.Array, cfg: LlamaConfig,
     router chose for the hidden states it really sees (jit-able)."""
     if cfg.num_experts == 0:
         raise ValueError("routing_stats: a dense config has no router")
-    from dlrover_tpu.parallel.moe import tokens_per_expert
+    from dlrover_tpu.parallel.moe import (
+        logits_per_expert, router_logits,
+    )
 
     if attn_fn is None:
         attn_fn = partial(flash_attention, causal=True)
     cos, sin = rope_tables(tokens.shape[1], cfg.head_dim, cfg.rope_theta)
 
-    def body(x, p):
-        q, k, v = _pre_attn(cfg, x, p, cos, sin)
-        attn = attn_fn(q, k, v)
-        seen = rms_norm(
-            x + attn.reshape(*x.shape[:2], -1) @ p["wo"],
-            p["mlp_norm"], cfg.norm_eps,
-        )
-        x, _ = _post_attn(cfg, x, attn, p)
-        return x, tokens_per_expert(seen, p["router"], cfg.moe_top_k)
+    def layer_of(kind):
+        window, rope = kind
+        attend = _attention_of(cfg, attn_fn, window)
 
-    _, counts = jax.lax.scan(
-        body, params["embed"][tokens], params["blocks"]
+        def body(x, p):
+            q, k, v, logits = _pre_attn(cfg, x, p, cos, sin, rope=rope)
+            attn = attend(q, k, v)
+            if logits is None:
+                logits = router_logits(rms_norm(
+                    x + attn.reshape(*x.shape[:2], -1) @ p["wo"],
+                    p["mlp_norm"], cfg.norm_eps,
+                ), p["router"])
+            counts = logits_per_expert(logits, cfg.moe_top_k)
+            x, _ = _post_attn(cfg, x, attn, p, logits)
+            return x, counts
+
+        return body
+
+    _, counts = _scan_layers(
+        [layer_of(kind) for kind in cfg.layer_kinds()],
+        params["embed"][tokens], params["blocks"],
     )
     return counts
 
 
 def flops_per_token(cfg: LlamaConfig, seq_len: int) -> float:
     """Approximate training FLOPs per token (6N_active + attention
-    quadratic). For MoE, only the top-k routed experts execute per token,
-    so N counts k experts — not all E."""
+    quadratic, at ``num_heads x head_dim`` and by each layer's kind).
+    For MoE, only the top-k routed experts execute per token, so N
+    counts k experts — not all E."""
     n = param_count(cfg) - cfg.vocab_size * cfg.hidden_size  # tied-ish
     if cfg.num_experts > 0:
         L, h, m = cfg.num_layers, cfg.hidden_size, cfg.intermediate_size
-        inactive = cfg.num_experts - min(cfg.moe_top_k, cfg.num_experts)
-        n -= L * 3 * h * m * inactive
-    attn = 12 * cfg.num_layers * cfg.hidden_size * seq_len
+        # of the experts held here a token meets its k's share
+        met = (min(cfg.moe_top_k, cfg.num_experts)
+               * cfg.moe_experts_held / cfg.num_experts)
+        n -= L * 3 * h * m * (cfg.moe_experts_held - met)
+    # scores and weighted values against every key a query's layer
+    # lets it see, causality not counted: the sequence, or the window
+    kinds = cfg.layer_kinds()
+    keys = sum(min(window or seq_len, seq_len) for window, _ in kinds)
+    attn = (12 * cfg.num_heads * cfg.head_dim
+            * cfg.num_layers // len(kinds) * keys)
     return 6.0 * n + attn

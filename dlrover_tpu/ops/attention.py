@@ -26,13 +26,15 @@ def mha_reference(
     scale: Optional[float] = None,
     mask: Optional[jax.Array] = None,  # bool [q_len, kv_len], True=keep
     return_lse: bool = False,
+    window: Optional[int] = None,
 ):
     """Plain XLA attention with GQA head-group broadcast.
 
     Computes in float32 for softmax stability, returns q.dtype. XLA fuses
     the mask/softmax chain; on TPU the two einsums hit the MXU directly.
     With ``return_lse`` also returns the logsumexp [batch, heads, q_len]
-    (float32) for blockwise/ring combination.
+    (float32) for blockwise/ring combination. With ``window`` (causal
+    only) query i sees key j iff ``j <= i`` and ``i - j < window``.
     """
     b, qlen, h, d = q.shape
     _, klen, kvh, _ = k.shape
@@ -49,7 +51,13 @@ def mha_reference(
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", qf, kf)
     if causal:
         tril = jnp.tril(jnp.ones((qlen, klen), dtype=bool), k=klen - qlen)
+        if window is not None:
+            tril &= ~jnp.tril(
+                jnp.ones((qlen, klen), dtype=bool), k=klen - qlen - window
+            )
         mask = tril if mask is None else (mask & tril)
+    elif window is not None:
+        raise ValueError("a window is a causal band: causal=False")
     if mask is not None:
         scores = jnp.where(mask[None, None, None], scores, NEG_INF)
     # explicit online-softmax form; p hard-zeroed under the mask so a
@@ -71,7 +79,8 @@ def mha_reference(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("causal", "scale", "block_q", "block_k")
+    jax.jit,
+    static_argnames=("causal", "scale", "block_q", "block_k", "window"),
 )
 def flash_attention(
     q: jax.Array,
@@ -81,6 +90,7 @@ def flash_attention(
     scale: Optional[float] = None,
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Memory-efficient attention: Pallas kernel on TPU; off the TPU
     (CPU tests, rehearsals) the dense reference, which no TPU run
@@ -91,9 +101,13 @@ def flash_attention(
     folds into the kernel's matmul rows, so the effective q-block is
     ``group * block_q`` rows. The blocks are ``ops/tuning.py``'s static
     rule on the shapes, and ``tuning.last_selection()`` names them.
+    ``window`` (causal only, static in the kernel): query i sees key
+    j iff ``j <= i`` and ``i - j < window``.
     """
     if not _use_pallas(q, k):
-        return mha_reference(q, k, v, causal=causal, scale=scale)
+        return mha_reference(
+            q, k, v, causal=causal, scale=scale, window=window
+        )
     from dlrover_tpu.ops.pallas.flash_attention import (
         flash_attention_tpu,
     )
@@ -111,10 +125,11 @@ def flash_attention(
     tuning.record(
         kernel="flash_attention", seq=seq, head_dim=q.shape[3],
         gqa_group=group, dtype=jnp.dtype(q.dtype).name, causal=causal,
-        block_q=bq, block_k=bk,
+        block_q=bq, block_k=bk, window=window,
     )
     return flash_attention_tpu(
         q, k, v, causal=causal, scale=scale, block_q=bq, block_k=bk,
+        window=window,
     )
 
 
@@ -141,7 +156,7 @@ def make_sharded_attention(mesh, q_spec, kv_spec, causal: bool = True):
     from dlrover_tpu.parallel.compat import shard_map
     from dlrover_tpu.parallel.sharding import fit_spec
 
-    def attn_fn(q, k, v):
+    def attn_fn(q, k, v, window=None):
         qp = list(fit_spec(q_spec, q.shape, mesh))
         kp = list(fit_spec(kv_spec, k.shape, mesh))
         if qp[2] is None or kp[2] is None:
@@ -149,7 +164,9 @@ def make_sharded_attention(mesh, q_spec, kv_spec, causal: bool = True):
         kp[0] = qp[0]
         qs, ks = P(*qp), P(*kp)
         return shard_map(
-            functools.partial(flash_attention, causal=causal),
+            functools.partial(
+                flash_attention, causal=causal, window=window
+            ),
             mesh=mesh, in_specs=(qs, ks, ks), out_specs=qs,
             check_vma=False,
         )(q, k, v)

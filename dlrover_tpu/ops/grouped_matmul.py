@@ -3,8 +3,12 @@ dropless mixture-of-experts layer (parallel/moe.py).
 
 ``lhs`` holds rows sorted by group, ``rhs`` one matrix a group, and
 ``group_sizes`` how many consecutive rows belong to each: row ``r`` of
-group ``g`` is multiplied by ``rhs[g]``. Groups may be empty; their
-sizes sum to the number of rows.
+group ``g`` is multiplied by ``rhs[g]``. Groups may be empty. Their
+sizes sum to the number of rows, or to fewer where the caller says the
+groups do not fill the buffer (``filled=False``: a layer that holds a
+share of its experts sorts the rows of the absent ones last): rows
+past the sum then come back zero, take no product's time and give
+their operand no gradient. What a kernel leaves in them is never read.
 
 Two routes to the same product, chosen by what one v5e chip read at
 98,304 rows against 64 matrices of 2048 x 1024 (PERF.md, PR 29; ms
@@ -13,40 +17,119 @@ for the forward product / for it and its two backward products):
 - ``jax.lax.ragged_dot``, which the chip's compiler lowers to a
   Mosaic kernel of its own over 512 x 512 x 512 tiles
   (``ragged-dot-none`` in a trace): 4.03 / 13.99. Also what runs off
-  the TPU, as XLA's plain expansion, and for shapes the tiles below
-  do not divide.
-- ``jax.experimental.pallas.ops.tpu.megablox``'s ``gmm`` (and, behind
-  its ``custom_vjp``, ``gmm`` against the transposed matrices for the
+  the TPU, as XLA's plain expansion, and for shapes ``tiles`` cannot
+  tile.
+- ``jax.experimental.pallas.ops.tpu.megablox``'s ``gmm`` (and, in the
+  backward pass, ``gmm`` against the transposed matrices for the
   rows' gradient and ``tgmm`` for the matrices'), the same walk over
-  tiles group by group with the rows of a neighbouring group masked.
-  At its default 128-cubed tiles 38.7 / 128.5; at ``TILES`` 3.15 /
-  9.85, which is why it is the route on the chip. Larger tiles in any
-  dimension do not fit the kernel's VMEM.
+  tiles group by group with the rows of a neighbouring group masked,
+  over the active tiles only. At its default 128-cubed tiles 38.7 /
+  128.5; at (512, 1024, 1024) 3.15 / 9.85, which is why it is the
+  route on the chip. Larger tiles in any dimension do not fit the
+  kernel's VMEM.
+
+The tiles are a static rule on the shapes (``tiles``), as the
+attention blocks are (ops/tuning.py): each product of the three, the
+forward's and the two backward ones, gets the tiles of its own
+contraction and columns, so a 2560 x 768 expert is walked in (512,
+640, 768) forward and (512, 768, 640) for the rows' gradient.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
 
-#: (rows, contraction, columns) of one tile of the megablox kernels
-TILES = (512, 1024, 1024)
+#: the most rows, and the widest contraction and columns, of one tile
+#: of the megablox kernels: what was timed, and what fits their VMEM
+TILE_CAPS = (512, 1024, 1024)
+LANES = 128
+
+
+def tiles(rows: int, contraction: int, columns: int):
+    """(rows, contraction, columns) of one tile: in each dimension the
+    largest multiple of 128 that divides it within ``TILE_CAPS``
+    ((512, 1024, 1024) at 2048 x 1024, (512, 640, 768) at 2560 x
+    768); None where a dimension has no such divisor."""
+    picked = tuple(
+        max((t for t in range(LANES, cap + 1, LANES) if size % t == 0),
+            default=None)
+        for size, cap in zip((rows, contraction, columns), TILE_CAPS)
+    )
+    return None if None in picked else picked
 
 
 def _use_pallas(lhs: jax.Array, rhs: jax.Array) -> bool:
     if jax.default_backend() != "tpu":
         return False
-    shape = (lhs.shape[0], rhs.shape[1], rhs.shape[2])
-    return lhs.dtype == rhs.dtype == jnp.bfloat16 and not any(
-        size % tile for size, tile in zip(shape, TILES)
+    return lhs.dtype == rhs.dtype == jnp.bfloat16 and tiles(
+        lhs.shape[0], rhs.shape[1], rhs.shape[2]
+    ) is not None
+
+
+def _interpret() -> bool:
+    return jax.default_backend() != "tpu"
+
+
+def _within(rows: int, group_sizes: jax.Array) -> jax.Array:
+    """bool [rows, 1]: the rows that belong to a group."""
+    return (jnp.arange(rows, dtype=jnp.int32)
+            < jnp.sum(group_sizes))[:, None]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gmm(lhs, rhs, group_sizes, filled):
+    return _gmm_fwd(lhs, rhs, group_sizes, filled)[0]
+
+
+def _gmm_fwd(lhs, rhs, group_sizes, filled):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    out = gmm(
+        lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
+        tiling=tiles(lhs.shape[0], rhs.shape[1], rhs.shape[2]),
+        interpret=_interpret(),
     )
+    if not filled:
+        out = jnp.where(_within(out.shape[0], group_sizes), out, 0)
+    return out, (lhs, rhs, group_sizes)
+
+
+def _gmm_bwd(filled, residual, grad):
+    """As megablox's own ``custom_vjp``, with each product's own
+    tiles, and the rows past the groups' sum zero."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+
+    lhs, rhs, group_sizes = residual
+    rows, (_, k, n) = lhs.shape[0], rhs.shape
+    grad_lhs = gmm(
+        grad, rhs, group_sizes, preferred_element_type=lhs.dtype,
+        tiling=tiles(rows, n, k), transpose_rhs=True,
+        interpret=_interpret(),
+    )
+    if not filled:
+        grad_lhs = jnp.where(_within(rows, group_sizes), grad_lhs, 0)
+    grad_rhs = tgmm(
+        lhs.swapaxes(0, 1), grad, group_sizes,
+        preferred_element_type=rhs.dtype, tiling=tiles(rows, k, n),
+        num_actual_groups=rhs.shape[0], interpret=_interpret(),
+    )
+    return grad_lhs, grad_rhs, None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
 
 
 def grouped_matmul(
     lhs: jax.Array,  # [rows, k], rows sorted by group
     rhs: jax.Array,  # [groups, k, n]
-    group_sizes: jax.Array,  # int32 [groups], sums to rows
+    group_sizes: jax.Array,  # int32 [groups], sums to rows or fewer
+    filled: bool = True,
 ) -> jax.Array:
     """``out[r] = lhs[r] @ rhs[group of r]``, [rows, n] in ``lhs``'s
-    dtype, accumulated in float32."""
+    dtype, accumulated in float32. ``filled``: the sizes sum to the
+    number of rows; where the caller says they may not, rows past the
+    sum are zero, forward and in ``lhs``'s gradient."""
     if lhs.ndim != 2 or rhs.ndim != 3 or lhs.shape[1] != rhs.shape[1]:
         raise ValueError(
             f"grouped_matmul: lhs {lhs.shape} against rhs {rhs.shape}"
@@ -58,12 +141,14 @@ def grouped_matmul(
         )
     group_sizes = group_sizes.astype(jnp.int32)
     if _use_pallas(lhs, rhs):
-        from jax.experimental.pallas.ops.tpu.megablox import gmm
-
-        return gmm(
-            lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
-            tiling=TILES,
-        )
-    return jax.lax.ragged_dot(
+        return _gmm(lhs, rhs, group_sizes, filled)
+    if not filled:
+        # the mask on both sides of the product: a row past the sum
+        # reads as zero and, by the select's own transpose, is given
+        # no gradient
+        within = _within(lhs.shape[0], group_sizes)
+        lhs = jnp.where(within, lhs, 0)
+    out = jax.lax.ragged_dot(
         lhs, rhs, group_sizes, preferred_element_type=lhs.dtype
     )
+    return out if filled else jnp.where(within, out, 0)

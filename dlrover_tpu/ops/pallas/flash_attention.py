@@ -48,6 +48,16 @@ lane reduction a row and dynamic slices (PERF.md section 6, PR 31).
 Where no edge is given, and without ``causal``, the body is the whole
 block's single product as before. ``causal_tile_census`` counts what
 the walk skips.
+
+A window (``window``, static: query i sees key j iff ``j <= i`` and
+``i - j < window``) is a second edge, under the diagonal. A grid block
+wholly under it is skipped as one above the diagonal is: not computed
+(``pl.when``) and not fetched (the index maps are clamped at both ends
+of a row's or column's live blocks). A block that either edge crosses
+takes the mask of both; one between them is one product without a
+mask. A windowed call takes whole blocks: the sub-tile walk above was
+settled without a group and without a window, and no cell runs a
+window without a group.
 """
 
 import functools
@@ -76,6 +86,22 @@ def _causal_mask(q_start, k_start, g, rows, cols):
     return jax.lax.ge(
         jax.lax.add(q_start, jax.lax.bitwise_and(q_idx, rows - 1)),
         jax.lax.add(k_start, k_idx),
+    )
+
+
+def _band_mask(q_start, k_start, g, rows, cols, window):
+    """``_causal_mask`` and the window's lower edge: row token >= col
+    token > row token - window."""
+    shape = (g * rows, cols)
+    q_idx = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    k_idx = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    # query position less key position
+    ahead = jax.lax.sub(
+        jax.lax.add(q_start, jax.lax.bitwise_and(q_idx, rows - 1)),
+        jax.lax.add(k_start, k_idx),
+    )
+    return jax.lax.bitwise_and(
+        jax.lax.ge(ahead, 0), jax.lax.lt(ahead, window)
     )
 
 
@@ -131,53 +157,62 @@ def _fits(edge, g, block_q, block_k):
     return edge if edge < block_q else None
 
 
-def causal_tile_census(seq, block_q, block_k, sub_q, sub_k):
+def _band(q0, rows, k0, cols, window):
+    """Of the [rows, cols] scores whose first (query, key) positions
+    are (q0, k0): ``(live, whole)``: whether any is inside the band
+    ``key <= query < key + window`` (all of ``key <= query`` without a
+    window), and whether all are. Python ints or traced values."""
+    live = q0 + rows - 1 >= k0
+    whole = q0 >= k0 + cols - 1
+    if window is not None:
+        live &= q0 - (k0 + cols - 1) < window
+        whole &= q0 + rows - 1 - k0 < window
+    return live, whole
+
+
+def causal_tile_census(seq, block_q, block_k, sub_q, sub_k, window=None):
     """Over one head's causal [seq, seq] scores, in (sub_q, sub_k)
-    sub-tiles: (sub-tiles the causally live grid blocks cover, which is
-    what a whole-block body computes; sub-tiles with an element on or
-    under the diagonal, which is what the walk computes; those of them
-    that straddle the diagonal, where the mask decides something)."""
-    n_rows, n_cols = block_q // sub_q, block_k // sub_k
+    sub-tiles: (sub-tiles the live grid blocks cover, which is what a
+    whole-block body computes; sub-tiles with an element inside the
+    band, on or under the diagonal and within ``window`` of it, which
+    is what the walk computes; those of them that an edge of the band
+    crosses, where the mask decides something)."""
     covered = computed = masked = 0
     for q_start in range(0, seq, block_q):
         for k_start in range(0, seq, block_k):
-            if q_start + block_q - 1 < k_start:
+            if not _band(q_start, block_q, k_start, block_k, window)[0]:
                 continue
-            covered += n_rows * n_cols
+            covered += (block_q // sub_q) * (block_k // sub_k)
             for q0 in range(q_start, q_start + block_q, sub_q):
-                # column tiles whose first column <= the row's last
-                # position; of them wholly under the diagonal, those
-                # whose last column <= q0
-                n_live = (q0 + sub_q - 1 - k_start) // sub_k + 1
-                n_full = (q0 - k_start + 1) // sub_k
-                n_live, n_full = (
-                    max(0, min(n, n_cols)) for n in (n_live, n_full)
-                )
-                computed += n_live
-                masked += n_live - n_full
+                for k0 in range(k_start, k_start + block_k, sub_k):
+                    live, whole = _band(q0, sub_q, k0, sub_k, window)
+                    computed += live
+                    masked += live and not whole
     return covered, computed, masked
 
 
-def _set_census_gauges(kernel, seq, block_q, block_k, sub):
+def _set_census_gauges(kernel, seq, block_q, block_k, sub, window):
     """Where a causal kernel is built (trace time): what share of the
     sub-tiles its live blocks cover it computes, and how many of them
-    straddle the diagonal."""
+    an edge of the band crosses."""
     from dlrover_tpu.telemetry.registry import gauge
 
     covered, computed, masked = causal_tile_census(
-        seq, block_q, block_k, sub or block_q, sub or block_k
+        seq, block_q, block_k, sub or block_q, sub or block_k, window
     )
+    labels = dict(kernel=kernel, window=str(window or "none"))
     gauge(
         "attn_tiles_computed_share",
         "sub-tiles a causal attention kernel computes over those its "
         "live grid blocks cover, at the last one built",
-        labelnames=("kernel",),
-    ).labels(kernel=kernel).set(computed / covered)
+        labelnames=("kernel", "window"),
+    ).labels(**labels).set(computed / covered)
     gauge(
         "attn_tiles_masked_share",
-        "sub-tiles that straddle the diagonal, over the same",
-        labelnames=("kernel",),
-    ).labels(kernel=kernel).set(masked / covered)
+        "sub-tiles that the diagonal or the window's edge crosses, "
+        "over the same",
+        labelnames=("kernel", "window"),
+    ).labels(**labels).set(masked / covered)
 
 
 def _rows_of(r0, size, block_q):
@@ -193,7 +228,7 @@ def _rows_of(r0, size, block_q):
 # and a kernel that holds a body a width meets many. The jaxpr is the
 # same.
 
-def _scores(q, k, scale, g, diagonal):
+def _scores(q, k, scale, g, diagonal, window=None):
     """Scaled scores of g-major rows ``q`` over columns ``k``, masked
     where ``diagonal`` gives their first (query, key) positions."""
     # bf16 x bf16 -> fp32 accumulate: the MXU's native mode. Casting
@@ -203,7 +238,9 @@ def _scores(q, k, scale, g, diagonal):
         preferred_element_type=jnp.float32,
     ), scale)  # [g*rows, cols]
     if diagonal is not None:
-        mask = _causal_mask(*diagonal, g, q.shape[0] // g, k.shape[0])
+        edges = (*diagonal, g, q.shape[0] // g, k.shape[0])
+        mask = (_causal_mask(*edges) if window is None
+                else _band_mask(*edges, window))
         s = jax.lax.select(mask, s, jax.lax.full_like(s, NEG_INF))
     return s
 
@@ -213,7 +250,8 @@ def _row_reduce(reduce, x):
     return jax.lax.expand_dims(reduce(x, (1,)), (1,))
 
 
-def _walk(causal, block_q, block_k, sub, q_start, k_start, compute):
+def _walk(causal, block_q, block_k, sub, q_start, k_start, compute,
+          window=None):
     """What one grid step computes, as calls of
     ``compute(r0, size, cols, diagonal)``: the ``size`` query positions
     of the block from ``r0`` on against the block's leading key
@@ -225,7 +263,12 @@ def _walk(causal, block_q, block_k, sub, q_start, k_start, compute):
     other block is one masked call. Else (the blocks are equal:
     ``_fits``) a block under the diagonal is one call without the
     mask, and the one on it is one call a row of sub-tiles of edge
-    ``sub``, over the row's live column tiles: n + 1 in row n."""
+    ``sub``, over the row's live column tiles: n + 1 in row n.
+
+    With a ``window`` (whole blocks only): a block wholly outside the
+    band is skipped, one wholly inside it is one call without the
+    mask, and one that the diagonal or the window's edge crosses is
+    one call with it."""
 
     def whole(masked):
         compute(0, block_q, slice(None),
@@ -233,6 +276,11 @@ def _walk(causal, block_q, block_k, sub, q_start, k_start, compute):
 
     if not causal:
         return whole(False)
+    if window is not None:
+        live, inside = _band(q_start, block_q, k_start, block_k, window)
+        pl.when(inside)(lambda: whole(False))
+        crossed = jnp.logical_and(live, jnp.logical_not(inside))
+        return pl.when(crossed)(lambda: whole(True))
     live = q_start + block_q - 1 >= k_start
     if sub is None:
         return pl.when(live)(lambda: whole(True))
@@ -263,7 +311,7 @@ def _walk(causal, block_q, block_k, sub, q_start, k_start, compute):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, scale, causal, g,
-                block_q, block_k, sub):
+                block_q, block_k, sub, window=None):
     i = pl.program_id(1)  # q block
     j = pl.program_id(2)  # k block (minor: sequential, scratch persists)
     nk = pl.num_programs(2)
@@ -280,7 +328,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     def compute(r0, size, cols, diagonal):
         rows = _rows_of(r0, size, block_q)
         s = _scores(
-            _stack_groups(q_ref, g, rows), k_ref[0, cols], scale, g, diagonal
+            _stack_groups(q_ref, g, rows), k_ref[0, cols], scale, g,
+            diagonal, window,
         )
         m_prev = m_scr[rows, :1]  # [g*size, 1]
         m_new = jax.lax.max(m_prev, _row_reduce(jax.lax.reduce_max, s))
@@ -302,7 +351,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_scr[rows] = jnp.broadcast_to(m_new, lanes)
         l_scr[rows] = jnp.broadcast_to(l_new, lanes)
 
-    _walk(causal, block_q, block_k, sub, q_start, k_start, compute)
+    _walk(causal, block_q, block_k, sub, q_start, k_start, compute,
+          window)
 
     @pl.when(j == nk - 1)
     def _finalize():
@@ -326,21 +376,29 @@ def _check_blocks(seq, block_q, block_k):
         raise ValueError(f"block_q must be a power of two, got {block_q}")
 
 
-def _kernel(body, name, seq, causal, g, block_q, block_k, head_dim, scale):
+def _kernel(body, name, seq, causal, g, block_q, block_k, head_dim, scale,
+            window=None):
     """``body`` ("fwd", "dq", "dkv" or "dqkv" by ``name``) with its
-    static arguments; building a causal one sets the census gauges."""
+    static arguments; building a causal one sets the census gauges.
+    A windowed one takes whole blocks (``_walk``)."""
     sub = None
     if causal:
-        sub = _sub_tiles(name, block_q, block_k, g, head_dim)
-        _set_census_gauges(name, seq, block_q, block_k, sub)
-    return functools.partial(
-        body, scale=scale, causal=causal, g=g,
+        if window is None:
+            sub = _sub_tiles(name, block_q, block_k, g, head_dim)
+        _set_census_gauges(name, seq, block_q, block_k, sub, window)
+    static = dict(
+        scale=scale, causal=causal, g=g,
         block_q=block_q, block_k=block_k, sub=sub,
     )
+    if window is not None:
+        static["window"] = window
+    return functools.partial(body, **static)
 
 
-def _kv_index(causal, block_q, block_k):
-    """K/V block index for grid step (b, i, j), diagonal-clamped.
+def _kv_index(causal, block_q, block_k, window=None):
+    """K/V block index for grid step (b, i, j), clamped to the
+    diagonal and, with a window, up to the first block that holds a
+    key the q block's first query sees.
 
     A causally SKIPPED (j, i) step computes nothing (pl.when), but the
     pipeline would still stream its K/V block from HBM — dead traffic
@@ -353,12 +411,15 @@ def _kv_index(causal, block_q, block_k):
         if causal:
             diag = (i * block_q + block_q - 1) // block_k
             j = jnp.minimum(j, diag)
+        if window is not None:
+            first = jnp.maximum(i * block_q - window + 1, 0) // block_k
+            j = jnp.maximum(j, first)
         return (b, j, 0)
 
     return index
 
 
-def _fwd(q, k, v, scale, causal, block_q, block_k):
+def _fwd(q, k, v, scale, causal, block_q, block_k, window=None):
     """q: [bk_h, g, seq, d]; k,v: [bk_h, seq, d] ->
     (o [bk_h, g, seq, d], lse [bk_h, g, 1, seq] f32)."""
     bkh, g, seq, d = q.shape
@@ -367,9 +428,10 @@ def _fwd(q, k, v, scale, causal, block_q, block_k):
     _check_blocks(seq, block_q, block_k)
     grid = (bkh, seq // block_q, seq // block_k)
     kernel = _kernel(
-        _fwd_kernel, "fwd", seq, causal, g, block_q, block_k, d, scale
+        _fwd_kernel, "fwd", seq, causal, g, block_q, block_k, d, scale,
+        window,
     )
-    kv_idx = _kv_index(causal, block_q, block_k)
+    kv_idx = _kv_index(causal, block_q, block_k, window)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -418,9 +480,11 @@ def _q_side(q_ref, do_ref, lse_ref, delta_ref, g, rows):
     )
 
 
-def _p(q, lse, k, scale, g, diagonal):
+def _p(q, lse, k, scale, g, diagonal, window=None):
     """The softmax re-derived from the saved logsumexp: [g*rows, cols]."""
-    return jax.lax.exp(jax.lax.sub(_scores(q, k, scale, g, diagonal), lse))
+    return jax.lax.exp(
+        jax.lax.sub(_scores(q, k, scale, g, diagonal, window), lse)
+    )
 
 
 def _ds(p, do, v, delta):
@@ -433,7 +497,8 @@ def _ds(p, do, v, delta):
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               acc_scr, *, scale, causal, g, block_q, block_k, sub):
+               acc_scr, *, scale, causal, g, block_q, block_k, sub,
+               window=None):
     i = pl.program_id(1)
     j = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -450,7 +515,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         q, do, lse, delta = _q_side(
             q_ref, do_ref, lse_ref, delta_ref, g, rows
         )
-        p = _p(q, lse, k_ref[0, cols], scale, g, diagonal)
+        p = _p(q, lse, k_ref[0, cols], scale, g, diagonal, window)
         ds = _ds(p, do, v_ref[0, cols], delta)
         acc_scr[rows] += jax.lax.dot_general(
             jax.lax.convert_element_type(ds, k_ref.dtype), k_ref[0, cols],
@@ -458,7 +523,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             preferred_element_type=jnp.float32,
         )
 
-    _walk(causal, block_q, block_k, sub, q_start, k_start, compute)
+    _walk(causal, block_q, block_k, sub, q_start, k_start, compute,
+          window)
 
     @pl.when(j == nk - 1)
     def _finalize():
@@ -469,7 +535,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr,
-                *, scale, causal, g, block_q, block_k, sub, add_dq=None):
+                *, scale, causal, g, block_q, block_k, sub, add_dq=None,
+                window=None):
     """``add_dq(r0, size, cols, ds)``, where given, takes each dS the
     walk forms (cast for the products) on to dQ: ``_dqkv_kernel``."""
     j = pl.program_id(1)  # k block (major)
@@ -489,7 +556,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         q, do, lse, delta = _q_side(
             q_ref, do_ref, lse_ref, delta_ref, g, rows
         )
-        p = _p(q, lse, k_ref[0, cols], scale, g, diagonal)
+        p = _p(q, lse, k_ref[0, cols], scale, g, diagonal, window)
         if sub is not None:  # dS first where sub-tiles are walked (above)
             ds = _ds(p, do, v_ref[0, cols], delta)
         # dV += P^T @ dO — contracting over the g*size rows also sums
@@ -513,7 +580,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if add_dq is not None:
             add_dq(r0, size, cols, ds)
 
-    _walk(causal, block_q, block_k, sub, q_start, k_start, compute)
+    _walk(causal, block_q, block_k, sub, q_start, k_start, compute,
+          window)
 
     @pl.when(i == nq - 1)
     def _finalize():
@@ -578,7 +646,8 @@ def _one_backward_kernel(g, seq, head_dim):
     return g == 1 and seq * head_dim * 4 <= DQ_RESIDENT_BYTES
 
 
-def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k):
+def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
+         window=None):
     from dlrover_tpu.telemetry.registry import gauge
 
     bkh, g, seq, d = q.shape
@@ -597,9 +666,10 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k):
     ).set(1 if one_kernel else 2)
     if not one_kernel:
         dq_kernel = _kernel(
-            _dq_kernel, "dq", seq, causal, g, block_q, block_k, d, scale
+            _dq_kernel, "dq", seq, causal, g, block_q, block_k, d, scale,
+            window,
         )
-        kv_idx = _kv_index(causal, block_q, block_k)
+        kv_idx = _kv_index(causal, block_q, block_k, window)
         in_specs_q = [
             pl.BlockSpec((1, g, block_q, d), lambda b, i, j: (b, 0, i, 0)),
             pl.BlockSpec((1, block_k, d), kv_idx),  # k
@@ -622,7 +692,7 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k):
 
     dkv_kernel = _kernel(
         *((_dqkv_kernel, "dqkv") if one_kernel else (_dkv_kernel, "dkv")),
-        seq, causal, g, block_q, block_k, d, scale
+        seq, causal, g, block_q, block_k, d, scale, window
     )
 
     def q_side_idx(sublane):
@@ -630,11 +700,15 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k):
         UP to the first causally-live q block of k-block j — skipped
         steps (q entirely above the diagonal) re-reference the block
         the first live step fetches, so they cost no bandwidth (same
-        trick as _kv_index)."""
+        trick as _kv_index) — and, with a window, DOWN to the block of
+        the last query that sees the k block's last key."""
 
         def index(b, j, i):
             if causal:
                 i = jnp.maximum(i, (j * block_k) // block_q)
+            if window is not None:
+                last = (j * block_k + block_k + window - 2) // block_q
+                i = jnp.minimum(i, jnp.minimum(last, seq // block_q - 1))
             return (b, 0, i, 0) if sublane else (b, 0, 0, i)
 
         return index
@@ -677,21 +751,21 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k):
 # ---------------------------------------------------------------------------
 # public wrapper with custom VJP
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_gqa(q, k, v, scale, causal, block_q, block_k):
-    o, _ = _fwd(q, k, v, scale, causal, block_q, block_k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_gqa(q, k, v, scale, causal, block_q, block_k, window=None):
+    o, _ = _fwd(q, k, v, scale, causal, block_q, block_k, window)
     return o
 
 
-def _flash_fwd_rule(q, k, v, scale, causal, block_q, block_k):
-    o, lse = _fwd(q, k, v, scale, causal, block_q, block_k)
+def _flash_fwd_rule(q, k, v, scale, causal, block_q, block_k, window):
+    o, lse = _fwd(q, k, v, scale, causal, block_q, block_k, window)
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd_rule(scale, causal, block_q, block_k, res, do):
+def _flash_bwd_rule(scale, causal, block_q, block_k, window, res, do):
     q, k, v, o, lse = res
     dq, dk, dv = _bwd(
-        q, k, v, o, lse, do, scale, causal, block_q, block_k
+        q, k, v, o, lse, do, scale, causal, block_q, block_k, window
     )
     return dq, dk, dv
 
@@ -707,10 +781,21 @@ def flash_attention_tpu(
     scale: Optional[float] = None,
     block_q: int = 512,
     block_k: int = 512,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Flash attention in the models' [batch, seq, heads, head_dim]
-    layout; GQA folded into the kernels' matmul rows (no KV repeat)."""
+    layout; GQA folded into the kernels' matmul rows (no KV repeat).
+    ``window``: query i sees key j iff ``j <= i`` and ``i - j <
+    window`` (causal only); one that reaches every key is no window."""
     b, s, h, d = q.shape
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError(
+                f"window={window}: a window is a causal band of at "
+                "least one key"
+            )
+        if window >= s:
+            window = None
     kvh = k.shape[2]
     g = h // kvh
     scale = scale if scale is not None else d ** -0.5
@@ -722,6 +807,7 @@ def flash_attention_tpu(
 
     o = _flash_gqa(
         qg, kv_layout(k), kv_layout(v), scale, causal, block_q, block_k,
+        window,
     )
     return o.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 

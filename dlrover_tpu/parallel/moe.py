@@ -22,8 +22,17 @@ gathered back. No ``[N, E, C]`` tensor, no capacity: every assignment
 is computed. Where experts are sharded over an ``expert`` axis,
 ``moe_mlp``: the capacity-bucketed einsums above, which drop what
 overflows. The model file chooses between them by the mesh.
+
+A share of the experts. The dropless path can be told which experts
+this device holds (``first_held``, and as many as the expert matrices
+it is given): the router keeps its width, top-k is over all of them,
+and the layer returns the part of the sum that the held experts give,
+every assignment to one of them computed whatever the imbalance. What
+the absent experts would have added is left out; nothing stands in for
+their devices or for the exchange with them.
 """
 
+import functools
 from typing import Tuple
 
 import jax
@@ -207,9 +216,28 @@ def _to_token_order_bwd(order, g):
 _to_token_order.defvjp(_to_token_order_fwd, _to_token_order_bwd)
 
 
+def router_logits(x: jax.Array, gate_w: jax.Array) -> jax.Array:
+    """``x [..., H] @ gate_w [H, E]`` in float32."""
+    return x.astype(jnp.float32) @ gate_w.astype(jnp.float32)
+
+
 def route(
     flat: jax.Array,  # [N, H]
     gate_w: jax.Array,  # [H, E]
+    k: int,
+    norm_topk_prob: bool,
+    balance_coef: float = BALANCE_LOSS_COEF,
+    z_coef: float = Z_LOSS_COEF,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``route_logits`` of the router's own product with ``flat``."""
+    return route_logits(
+        router_logits(flat, gate_w), k, norm_topk_prob, balance_coef,
+        z_coef,
+    )
+
+
+def route_logits(
+    logits: jax.Array,  # [N, E] float32: ``router_logits``
     k: int,
     norm_topk_prob: bool,
     balance_coef: float = BALANCE_LOSS_COEF,
@@ -219,10 +247,10 @@ def route(
     experts int32 [N, k], aux)``. The weights are the float32
     softmax's own top-k values (ties to the lower index, as
     ``jax.lax.top_k`` gives them), renormalised over the k only where
-    the configuration says so; at k = 1 they stay raw in either case,
-    or the router would get no gradient through the LM loss. ``aux``
-    is scaled: the balance loss over all k choices plus the z-loss."""
-    logits = flat.astype(jnp.float32) @ gate_w.astype(jnp.float32)
+    the configuration says so (which makes them the softmax over the
+    k chosen logits); at k = 1 they stay raw in either case, or the
+    router would get no gradient through the LM loss. ``aux`` is
+    scaled: the balance loss over all k choices plus the z-loss."""
     probs = jax.nn.softmax(logits, axis=-1)
     weights, experts = jax.lax.top_k(probs, k)
     aux = balance_coef * balance_loss(probs, experts) + z_coef * (
@@ -233,64 +261,100 @@ def route(
     return weights, experts, aux
 
 
+#: the gate's activation in an expert, by the model file's name for it
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 def dropless_moe_mlp(
     x: jax.Array,  # [batch, seq, hidden]
     gate_w: jax.Array,  # [hidden, experts]
-    w_gate: jax.Array,  # [experts, hidden, mlp]
-    w_up: jax.Array,  # [experts, hidden, mlp]
-    w_down: jax.Array,  # [experts, mlp, hidden]
+    w_gate: jax.Array,  # [held, hidden, mlp]
+    w_up: jax.Array,  # [held, hidden, mlp]
+    w_down: jax.Array,  # [held, mlp, hidden]
     k: int = 2,
     norm_topk_prob: bool = True,
     balance_coef: float = BALANCE_LOSS_COEF,
     z_coef: float = Z_LOSS_COEF,
+    logits: jax.Array = None,  # [batch, seq, experts] float32
+    act: str = "silu",
+    first_held: int = 0,
 ) -> Tuple[jax.Array, jax.Array]:
-    """MoE SwiGLU block in which every one of the ``N x k``
-    assignments is computed: ``(out [batch, seq, hidden], aux)``,
-    ``aux`` scaled as ``moe_mlp``'s. All experts on this device.
+    """MoE gated block (``act(gate) * up``, then down) in which every
+    one of the ``N x k`` assignments to an expert on this device is
+    computed: ``(out [batch, seq, hidden], aux)``, ``aux`` scaled as
+    ``moe_mlp``'s. ``logits``: the router's, where the model computes
+    them elsewhere than from ``x`` (``router_logits``); else from
+    ``x`` here.
 
     The four scopes name every device op's ``op_name``: ``moe.route``
     (router, softmax, top-k, aux losses), ``moe.dispatch`` (stable
     sort of the assignments by expert, gather into expert order),
     ``moe.experts`` (the grouped matmuls; their two results are named
     ``moe_gate`` and ``moe_up`` for a remat policy to keep),
-    ``moe.combine`` (gather back, sum over a token's k; the weights
-    were applied inside the experts)."""
+    ``moe.combine`` (the rows back to their tokens, summed over a
+    token's k; the weights were applied inside the experts).
+
+    The device holds the ``held`` experts ``first_held ...`` whose
+    matrices it is given: all of the router's, or a share. With a
+    share, routing, weights and ``aux`` are over all experts as
+    before; the assignments to absent experts sort behind the held
+    ones' and fall outside every group, so they cost the grouped
+    matmuls no row, and ``out`` is the held experts' part of the sum.
+    A token's k experts may all be held, so the buffers are the
+    ``N x k`` rows that hold every routing, and gathers and
+    elementwise work are paid for each of them, held or not; the
+    absent experts' rows come out of the grouped matmuls as zeros."""
     from jax.ad_checkpoint import checkpoint_name
 
     from dlrover_tpu.ops.grouped_matmul import grouped_matmul
 
     b, s, h = x.shape
-    e = gate_w.shape[-1]
+    e, held = gate_w.shape[-1], w_gate.shape[0]
+    if not 0 <= first_held <= e - held:
+        raise ValueError(
+            f"experts {first_held}..{first_held + held - 1} of {e}"
+        )
+    filled = held == e
     n = b * s
     flat = x.reshape(n, h)
     with jax.named_scope("moe.route"):
-        weights, experts, aux = route(
-            flat, gate_w, k, norm_topk_prob, balance_coef, z_coef
+        if logits is None:
+            logits = router_logits(flat, gate_w)
+        weights, experts, aux = route_logits(
+            logits.reshape(n, e), k, norm_topk_prob, balance_coef, z_coef
         )
     with jax.named_scope("moe.dispatch"):
         assigned = experts.reshape(n * k)
+        if not filled:
+            # held experts by their place here, every absent one last
+            assigned = assigned - first_held
+            assigned = jnp.where(
+                (assigned >= 0) & (assigned < held), assigned, held
+            )
         order = jnp.argsort(assigned, stable=True).astype(jnp.int32)
         inverse = jnp.zeros_like(order).at[order].set(
             jnp.arange(n * k, dtype=jnp.int32)
         )
-        group_sizes = expert_counts(assigned, e)
+        group_sizes = (
+            expert_counts(assigned, e) if filled
+            else expert_counts(assigned, held + 1)[:held]
+        )
         rows = _to_expert_order(flat, order, inverse)
     with jax.named_scope("moe.experts"):
-        gate = checkpoint_name(
-            grouped_matmul(rows, w_gate, group_sizes), "moe_gate"
+        grouped = functools.partial(
+            grouped_matmul, group_sizes=group_sizes, filled=filled
         )
-        up = checkpoint_name(
-            grouped_matmul(rows, w_up, group_sizes), "moe_up"
-        )
+        gate = checkpoint_name(grouped(rows, w_gate), "moe_gate")
+        up = checkpoint_name(grouped(rows, w_up), "moe_up")
         # the routing weight goes onto the down product's input: the
         # product is linear in it, and the weight's gradient then
         # needs that input (made again from the two kept products)
         # and not the down product's result, which nothing keeps
         hidden = (
-            (jax.nn.silu(gate) * up).astype(jnp.float32)
+            (ACTIVATIONS[act](gate) * up).astype(jnp.float32)
             * weights.reshape(n * k)[order][:, None]
         ).astype(x.dtype)
-        rows = grouped_matmul(hidden, w_down, group_sizes)
+        rows = grouped(hidden, w_down)
     with jax.named_scope("moe.combine"):
         mine = _to_token_order(rows, order, inverse).reshape(n, k, h)
         out = jnp.sum(mine.astype(jnp.float32), axis=1).astype(x.dtype)
@@ -302,9 +366,16 @@ def tokens_per_expert(
 ) -> jax.Array:
     """How many of ``x``'s tokens the router sends to each expert,
     int32 [experts]: sums to ``tokens x k``."""
-    flat = x.reshape(-1, x.shape[-1])
-    _, experts, _ = route(flat, gate_w, k, norm_topk_prob=False)
-    return expert_counts(experts, gate_w.shape[-1])
+    return logits_per_expert(router_logits(x, gate_w), k)
+
+
+def logits_per_expert(logits: jax.Array, k: int) -> jax.Array:
+    """``tokens_per_expert`` from the router's logits [..., experts]."""
+    e = logits.shape[-1]
+    _, experts, _ = route_logits(
+        logits.reshape(-1, e), k, norm_topk_prob=False
+    )
+    return expert_counts(experts, e)
 
 
 def set_expert_load_gauges(counts) -> Tuple[float, float]:
@@ -333,3 +404,27 @@ def set_expert_load_gauges(counts) -> Tuple[float, float]:
         "mean, at the last evaluation",
     ).set(least)
     return most, least
+
+
+def set_rows_held_gauge(counts, first_held: int, held: int) -> float:
+    """From the same counts set the gauge ``moe_rows_held_share``:
+    assignments to the experts this device holds
+    (``first_held ... first_held + held - 1``) over all ``N x k``, all
+    layers together. 1 where every expert is here; ``held / experts``
+    under even routing. It is the share of the dropless buffers' rows
+    that the grouped matmuls compute."""
+    import numpy as np
+
+    from dlrover_tpu.telemetry.registry import gauge
+
+    load = np.asarray(counts, dtype=np.float64)
+    share = float(
+        load[..., first_held:first_held + held].sum()
+        / max(load.sum(), 1e-9)
+    )
+    gauge(
+        "moe_rows_held_share",
+        "assignments to experts this device holds over all tokens x "
+        "k, at the last evaluation",
+    ).set(share)
+    return share

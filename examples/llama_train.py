@@ -34,7 +34,9 @@ from dlrover_tpu.data.elastic_shm import ElasticShmDataLoader
 from dlrover_tpu.models import llama
 from dlrover_tpu.ops import tuning
 from dlrover_tpu.parallel.mesh import create_mesh
-from dlrover_tpu.parallel.moe import set_expert_load_gauges
+from dlrover_tpu.parallel.moe import (
+    set_expert_load_gauges, set_rows_held_gauge,
+)
 from dlrover_tpu.trainer.checkpoint import FlashCheckpointer
 from dlrover_tpu.trainer.compile_cache import cache_events
 from dlrover_tpu.trainer.distributed import init_from_env
@@ -265,11 +267,15 @@ def main():
                 if routing_stats is not None:
                     # the periodic evaluation: how evenly the router
                     # spreads this batch (GET /metrics)
-                    most, least = set_expert_load_gauges(
-                        routing_stats(params, mb[0][0])
+                    counts = routing_stats(params, mb[0][0])
+                    most, least = set_expert_load_gauges(counts)
+                    held = set_rows_held_gauge(
+                        counts, cfg.moe_first_expert_held,
+                        cfg.moe_experts_held,
                     )
                     print(f"EXPERT_LOAD step={step} max/mean="
-                          f"{most:.3f} min/mean={least:.3f}", flush=True)
+                          f"{most:.3f} min/mean={least:.3f} "
+                          f"held={held:.3f}", flush=True)
                 ckpt.save(
                     step,
                     {"params": params, "opt_state": opt_state,

@@ -108,5 +108,6 @@ def test_dispatch_is_recorded(monkeypatch, heads, kv_heads):
     assert tuning.last_selection() == {
         "kernel": "flash_attention", "seq": 2048, "head_dim": 64,
         "gqa_group": group, "dtype": "bfloat16", "causal": True,
-        "block_q": bq, "block_k": bk, "source": "static",
+        "block_q": bq, "block_k": bk, "window": None,
+        "source": "static",
     }

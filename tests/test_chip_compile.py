@@ -339,6 +339,33 @@ def test_sub_tiled_kernels_compile_at_the_cells_shapes(
     )
 
 
+@pytest.mark.parametrize("window", [None, 4096], ids=["full", "window"])
+def test_windowed_kernels_compile_at_smallthinkers_shape(
+    topo, on_tpu_path, window
+):
+    """One 16,384-token sequence, 28 query heads on 4 of 128: the
+    rule's (128, 1024) blocks fold a group of 7 into 896 rows, no
+    power of two; the forward, dq and dk/dv kernels with the window's
+    masks and both-ended index clamps, and without."""
+    batch, seq, heads, kv_heads, d = 1, 16384, 28, 4, 128
+    assert tuning.heuristic_blocks(seq, heads // kv_heads) == (128, 1024)
+
+    def attn(q, k, v):
+        return fa.flash_attention_tpu(
+            q, k, v, causal=True, block_q=128, block_k=1024, window=window
+        )
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    q, kv = (
+        jax.ShapeDtypeStruct(
+            (batch, seq, h, d), jnp.bfloat16, sharding=one_chip
+        ) for h in (heads, kv_heads)
+    )
+    text = jax.jit(_sum_grad(attn)).lower(q, kv, kv).compile().as_text()
+    assert len(
+        re.findall(r'custom_call_target="tpu_custom_call"', text)) == 3
+
+
 def _lowered_kernels(fn, *args):
     """A lowering's text with each ``tpu_custom_call``'s payload taken
     out, and the payloads' Mosaic modules printed without source
@@ -533,20 +560,26 @@ def test_lowered_kernel_holds_a_body_a_row(
         assert a < 3 * whole, at
 
 
-@pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)],
-                         ids=["gate_up", "down"])
-def test_grouped_matmul_kernels_compile_at_olmoe_shapes(
-    topo, monkeypatch, k, n
+@pytest.mark.parametrize("rows,experts,k,n", [
+    (3 * 4096 * 8, 64, 2048, 1024), (3 * 4096 * 8, 64, 1024, 2048),
+    (98304, 16, 2560, 768), (98304, 16, 768, 2560),
+], ids=["olmoe_gate_up", "olmoe_down", "smallthinker_gate_up",
+        "smallthinker_down"])
+def test_grouped_matmul_kernels_compile_at_the_cells_shapes(
+    topo, monkeypatch, rows, experts, k, n
 ):
     """The expert projections of OLMoE-1B-7B at 3 x 4096 tokens, top-8
-    of 64: the forward product and both backward ones are Pallas
-    kernels at ``grouped_matmul.TILES`` (a tile larger in any
+    of 64, and of SmallThinker's 16 held experts in the 98,304 rows
+    of 16,384 tokens x 6: the forward product and both backward ones are
+    Pallas kernels at the tiles ``grouped_matmul.tiles`` gives each
+    ((512, 1024, 1024) at OLMoE's; (512, 640, 768) and, for the rows'
+    gradient, (512, 768, 640) at 2560 x 768; a tile larger in any
     dimension is refused for its VMEM)."""
     from dlrover_tpu.ops import grouped_matmul as gm
 
     monkeypatch.setattr(gm, "_use_pallas", lambda lhs, rhs: True)
+    monkeypatch.setattr(gm, "_interpret", lambda: False)
     one_chip = SingleDeviceSharding(topo.devices[0])
-    rows, experts = 3 * 4096 * 8, 64
     args = (
         jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip),
         jax.ShapeDtypeStruct((experts, k, n), jnp.bfloat16,
@@ -555,7 +588,7 @@ def test_grouped_matmul_kernels_compile_at_olmoe_shapes(
     )
 
     def loss(lhs, rhs, sizes):
-        out = gm.grouped_matmul(lhs, rhs, sizes)
+        out = gm.grouped_matmul(lhs, rhs, sizes, filled=experts == 64)
         return out.astype(jnp.float32).sum()
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(

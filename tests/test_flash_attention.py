@@ -191,7 +191,8 @@ def test_census_at_gpt2_xl_and_its_gauges():
             ("attn_tiles_masked_share", masked / covered),
         ):
             line = next(ln for ln in text.splitlines()
-                        if ln.startswith(f'{name}{{kernel="{kernel}"}} '))
+                        if ln.startswith(
+                            f'{name}{{kernel="{kernel}",window="none"}} '))
             assert float(line.split()[1]) == value
 
 
@@ -317,3 +318,4 @@ def test_a_head_too_long_for_vmem_keeps_two_backward_kernels(monkeypatch):
         q, k, v,
     ) == ["_fwd_kernel", "_dq_kernel", "_dkv_kernel"]
     assert _backward_kernels_gauge() == 2
+

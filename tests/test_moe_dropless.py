@@ -183,3 +183,4 @@ def test_capacity_path_follows_the_config_too():
             ref, ref_aux = moe.dropless_moe_mlp(*args, 2, norm)
             np.testing.assert_allclose(out, ref, atol=2e-5)
             np.testing.assert_allclose(aux, ref_aux, rtol=1e-6)
+

@@ -119,3 +119,34 @@ def test_device_prefetch_preserves_order():
     assert [int(np.asarray(b)[0, 0]) for b in prefetched] == list(
         range(12)
     )
+
+
+def test_push_waits_on_while_told_to():
+    """A ring still full when a push's wait ends is a consumer busy
+    elsewhere (a step program compiles for minutes): the coworkers'
+    push asks whether to wait that long again, and gives up only when
+    told (PR 34: the producers of a 16k-token cell died after 60 s of
+    a 100 s set-up)."""
+    ring = ShmRing(_name("wait"), slot_bytes=1024, num_slots=1)
+    try:
+        ring.push(np.zeros(4, np.int32))
+        asked = []
+
+        def keep_waiting():
+            asked.append(len(asked))
+            if len(asked) == 2:
+                ring.pop()  # the consumer comes back
+            return True
+
+        ring.push(np.ones(4, np.int32), timeout_ms=50,
+                  keep_waiting=keep_waiting)
+        assert asked == [0, 1]
+        np.testing.assert_array_equal(ring.pop(), np.ones(4, np.int32))
+        ring.push(np.zeros(4, np.int32))
+        with pytest.raises(TimeoutError):
+            ring.push(np.ones(4, np.int32), timeout_ms=50,
+                      keep_waiting=lambda: False)
+        with pytest.raises(TimeoutError):
+            ring.push(np.ones(4, np.int32), timeout_ms=50)
+    finally:
+        ring.destroy()
