@@ -36,6 +36,7 @@ contraction and columns, so a 2560 x 768 expert is walked in (512,
 """
 
 import functools
+import inspect
 
 import jax
 import jax.numpy as jnp
@@ -152,3 +153,105 @@ def grouped_matmul(
         lhs, rhs, group_sizes, preferred_element_type=lhs.dtype
     )
     return out if filled else jnp.where(within, out, 0)
+
+
+def add_rhs_gradient(
+    into: jax.Array,  # [groups, k, n]
+    lhs: jax.Array,  # [rows, k], rows sorted by group
+    grad: jax.Array,  # [rows, n]
+    group_sizes: jax.Array,  # int32 [groups], sums to rows or fewer
+) -> jax.Array:
+    """``into[g] + lhs[rows of g].T @ grad[rows of g]``: what
+    ``grouped_matmul(lhs, rhs, group_sizes)`` gives ``rhs`` for the
+    cotangent ``grad`` of its result, accumulated in float32 and
+    added to ``into`` in ``into``'s dtype. For a caller that
+    multiplies the same matrices in several pieces (parallel/moe.py's
+    walk in chunks) and keeps a float32 ``into`` until the last: the
+    sum is then rounded to the matrices' dtype once, as one product
+    over all rows rounds it. On the TPU ``tgmm`` reads and writes
+    ``into`` in place, a group's tile once, where a product of its
+    own and an add would pass over every matrix three times a piece.
+    Rows past the sum take no part."""
+    group_sizes = group_sizes.astype(jnp.int32)
+    if _use_pallas(lhs, jax.ShapeDtypeStruct(into.shape, grad.dtype)):
+        from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
+
+        return tgmm(
+            lhs.swapaxes(0, 1), grad, group_sizes,
+            preferred_element_type=into.dtype,
+            tiling=tiles(lhs.shape[0], *into.shape[1:]),
+            num_actual_groups=into.shape[0], existing_out=into,
+            interpret=_interpret(),
+        )
+    _, to_rhs = jax.vjp(
+        lambda rhs: jax.lax.ragged_dot(
+            lhs.astype(into.dtype), rhs, group_sizes
+        ),
+        jnp.zeros_like(into),
+    )
+    within = _within(lhs.shape[0], group_sizes)
+    return into + to_rhs(jnp.where(within, grad, 0).astype(into.dtype))[0]
+
+
+#: indices of one group of ``add_rows``' product: a tile's contraction
+ROW_BLOCK = 512
+
+
+def _add_on_mxu(out: jax.Array, rows: jax.Array) -> bool:
+    if jax.default_backend() != "tpu":
+        return False
+    return (
+        rows.dtype == jnp.bfloat16 and out.dtype == jnp.float32
+        and out.shape[0] % ROW_BLOCK == 0
+        and tiles(rows.shape[0], ROW_BLOCK, out.shape[1]) is not None
+    )
+
+
+def add_rows(out: jax.Array, index: jax.Array, rows: jax.Array) -> jax.Array:
+    """``out.at[index].add(rows)``: ``out`` [n, h] float32, ``rows``
+    [m, h], ``index`` int32 [m] within ``0 .. n - 1``, an index as
+    often as it likes. How a layer that walks its rows in chunks
+    (parallel/moe.py) adds a chunk's results to their tokens.
+
+    The chip's scatter-add takes a row at a time (7.4 ms for 8,192
+    rows of 2560 into 16,384, 1.75 with the indices sorted and said
+    to be; a gather of as many rows 0.35: PERF.md, PR 35). On the TPU
+    the sum is therefore a grouped product: the rows sorted by index,
+    a group for each ``ROW_BLOCK`` consecutive indices, and ``tgmm``
+    of the one-hot place of each row in its block against the rows,
+    added into ``out`` in place: exact, products of 0 or 1 summed in
+    float32."""
+    if not _add_on_mxu(out, rows):
+        return out.at[index].add(rows.astype(out.dtype))
+    n, h = out.shape
+    by_index = jnp.argsort(index)
+    index, rows = index[by_index], rows[by_index]
+    starts = jnp.searchsorted(
+        index, jnp.arange(0, n + 1, ROW_BLOCK, dtype=index.dtype)
+    )
+    place = (
+        (index % ROW_BLOCK)[:, None]
+        == jnp.arange(ROW_BLOCK, dtype=index.dtype)[None, :]
+    ).astype(rows.dtype)
+    return _rows_by_place(
+        place, rows, (starts[1:] - starts[:-1]).astype(jnp.int32),
+        out.reshape(n // ROW_BLOCK, ROW_BLOCK, h),
+    ).reshape(n, h)
+
+
+@jax.jit
+def _rows_by_place(place, rows, group_sizes, out):
+    """``add_rows``' product, jitted under its own name around
+    megablox's ``tgmm`` without that function's own ``jit``: a device
+    trace names a Pallas call after the innermost jitted function
+    that holds it, and ``tgmm.<n>`` in a trace is an expert matmul's
+    gradient (``_gmm_bwd``, ``add_rhs_gradient``), which this is not
+    (tests/test_chip_compile.py holds both names)."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
+
+    return inspect.unwrap(tgmm)(
+        place.swapaxes(0, 1), rows, group_sizes,
+        preferred_element_type=out.dtype,
+        tiling=tiles(rows.shape[0], ROW_BLOCK, rows.shape[1]),
+        existing_out=out, interpret=_interpret(),
+    )

@@ -29,7 +29,11 @@ it is given): the router keeps its width, top-k is over all of them,
 and the layer returns the part of the sum that the held experts give,
 every assignment to one of them computed whatever the imbalance. What
 the absent experts would have added is left out; nothing stands in for
-their devices or for the exchange with them.
+their devices or for the exchange with them. A share walks the sorted
+assignments in chunks of ``CHUNK_ROWS`` and does a chunk's work only
+where the chunk starts before the held experts' rows end
+(``_walk_held_rows``): its time and its memory follow the rows that
+are here, not the ``tokens x k`` that may be.
 """
 
 import functools
@@ -264,6 +268,233 @@ def route_logits(
 #: the gate's activation in an expert, by the model file's name for it
 ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
+#: rows of one chunk of a share's walk: a multiple of 512, so that
+#: ``grouped_matmul.tiles`` keeps its row tile. The chip's verdict at
+#: [16384, 2560], 16 of 64 experts held, top-6 (PERF.md, PR 35;
+#: ``benchmarks/profile_moe_share.py``): a live chunk costs 3.8 ms at
+#: 4,096 rows, 5.1 at 8,192 and 7.2 at 12,288, forward and backward
+#: under a remat policy that runs the forward twice. Smaller chunks
+#: follow the held share more closely and pay a turn's fixed costs
+#: (the tokens' sum and the matrices' three float32 gradient sums
+#: read and written once a turn) more often, larger ones round a
+#: layer's share up further: in the cell 4,096 reads 4.4% fewer
+#: tokens/s than this and 12,288 0.3% more for a second of set-up
+CHUNK_ROWS = 8192
+
+
+def walk_chunks(assignments: int) -> Tuple[int, int]:
+    """``(rows of a chunk, chunks)`` of a share's walk over
+    ``assignments`` sorted rows: ``CHUNK_ROWS``, or all of them where
+    they are fewer; the last chunk may reach past the end."""
+    rows = min(CHUNK_ROWS, assignments)
+    return rows, -(-assignments // rows)
+
+
+def _chunk(order, group_sizes, start, rows, weights):
+    """Of the ``rows`` sorted assignments from ``start``: which they
+    are, their tokens (the last token for an id past ``N x k``), and
+    how many of them each held expert has (the groups' row ranges
+    clipped to the chunk's: they sum to the held rows inside it, and
+    rows past that sum come out of the grouped matmuls as zeros)."""
+    n, k = weights.shape
+    with jax.named_scope("moe.dispatch"):
+        chosen = jax.lax.dynamic_slice(order, (start,), (rows,))
+        ends = jnp.cumsum(group_sizes)
+        sizes = (jnp.clip(ends, start, start + rows)
+                 - jnp.clip(ends - group_sizes, start, start + rows))
+        return chosen, jnp.minimum(chosen // k, n - 1), sizes
+
+
+def _products(rows, matrices, sizes):
+    """``rows`` [C, .] of a chunk against each of the experts'
+    ``matrices``, by the chunk's group ``sizes``. The scope is opened
+    in here, under any transform of the caller's: a device trace
+    names a kernel after the innermost name, and the grouped
+    matmuls' readers know ``gmm``."""
+    from dlrover_tpu.ops.grouped_matmul import grouped_matmul
+
+    with jax.named_scope("moe.experts"):
+        return tuple(
+            grouped_matmul(rows, w, sizes, filled=False)
+            for w in matrices
+        )
+
+
+def _gated(gate, up, scale, act):
+    """``act(gate) * up``, each row times its routing weight
+    ``scale`` [C] float32, in the products' dtype: the down
+    projection's input."""
+    with jax.named_scope("moe.experts"):
+        return (
+            (ACTIVATIONS[act](gate) * up).astype(jnp.float32)
+            * scale[:, None]
+        ).astype(gate.dtype)
+
+
+def _over_live_chunks(live, carry, order, group_sizes, rows):
+    """``carry`` after ``live(carry, start)`` for each chunk of
+    ``rows`` of ``order`` that starts before the groups' rows end, in
+    turn; a chunk past them costs a branch not taken."""
+    held_rows = jnp.sum(group_sizes)
+
+    def step(carry, start):
+        return jax.lax.cond(
+            start < held_rows, live, lambda carry, _: carry, carry, start
+        ), None
+
+    return jax.lax.scan(
+        step, carry, jnp.arange(0, order.shape[0], rows, dtype=jnp.int32)
+    )[0]
+
+
+def _scales(weights, order):
+    """The routing weights by assignment id, a zero for each id of
+    ``order`` past ``N x k``."""
+    return jnp.pad(
+        weights.reshape(-1), (0, order.shape[0] - weights.size)
+    )
+
+
+def _walk(act, flat, weights, w_gate, w_up, w_down, order, group_sizes):
+    """The held experts' part of the layer's sum, [N, H] in ``flat``'s
+    dtype. ``order`` [chunks x C]: the assignments (token-major ids
+    into ``weights`` [N, k]) sorted by expert, the held experts' first
+    and in ``group_sizes``' order, then the ids ``N x k ...`` that no
+    token has. A chunk that starts at or past the held rows' end takes
+    the empty branch; a live one gathers its C token rows, runs the
+    three grouped matmuls on its own group sizes and adds its C
+    results to their tokens in float32 (a token may occur more than
+    once in a chunk). With every assignment held every chunk is live.
+
+    Differentiated as ``_walk_held_rows``, whose backward pass is
+    the same walk written out (``_walk_bwd``)."""
+    from dlrover_tpu.ops.grouped_matmul import add_rows
+
+    rows, _ = walk_chunks(weights.size)
+    scales = _scales(weights, order)
+
+    def live(out, start):
+        chosen, tokens, sizes = _chunk(
+            order, group_sizes, start, rows, weights
+        )
+        with jax.named_scope("moe.dispatch"):
+            mine = flat[tokens]
+        gate, up = _products(mine, (w_gate, w_up), sizes)
+        (mine,) = _products(
+            _gated(gate, up, scales[chosen], act), (w_down,), sizes
+        )
+        with jax.named_scope("moe.combine"):
+            return add_rows(out, tokens, mine)
+
+    return _over_live_chunks(
+        live, jnp.zeros(flat.shape, jnp.float32), order, group_sizes, rows
+    ).astype(flat.dtype)
+
+
+#: ``_walk`` under reverse mode, its backward pass written out
+#: (``_walk_bwd``). Left to autodiff, the loop's transpose hands
+#: every chunk, live or not, a cotangent the size of the experts'
+#: matrices and of ``flat`` to add, keeps a turn's rows for every
+#: chunk or makes them again, and sums the matrices' gradients in
+#: their own dtype, rounded once a chunk: at the shapes above 67-71
+#: ms a layer where this reads 16, and a step that plans 17.4G of the
+#: chip's 15.75G (PERF.md, PR 35; ``profile_moe_share.py --autodiff``)
+_walk_held_rows = jax.custom_vjp(_walk, nondiff_argnums=(0,))
+
+
+def _walk_fwd(act, *args):
+    return _walk(act, *args), args
+
+
+def _walk_bwd(act, args, g):
+    """The same walk for the cotangent ``g`` [N, H] of the result:
+    a live chunk makes its two first products again, takes ``g``'s
+    rows of its tokens back through the three stages (each stage
+    differentiated by JAX, the chain between them written out so that
+    the matrices' gradients can be added where they are kept), and
+    adds what comes out for its rows to their tokens. The matrices'
+    gradients are summed in float32 over the chunks (an expert's rows
+    may lie in several) and rounded to the matrices' dtype once, as
+    the one pass rounds them."""
+    from dlrover_tpu.ops.grouped_matmul import add_rhs_gradient, add_rows
+
+    flat, weights, w_gate, w_up, w_down, order, group_sizes = args
+    rows, _ = walk_chunks(weights.size)
+    scales = _scales(weights, order)
+    matrices = (w_gate, w_up, w_down)
+
+    def live(grads, start):
+        d_flat, d_scales, d_w_gate, d_w_up, d_w_down = grads
+        chosen, tokens, sizes = _chunk(
+            order, group_sizes, start, rows, weights
+        )
+        with jax.named_scope("moe.dispatch"):
+            mine = flat[tokens]
+        with jax.named_scope("moe.combine"):
+            cotangent = g[tokens]
+        (gate, up), to_rows = jax.vjp(
+            lambda r: _products(r, (w_gate, w_up), sizes), mine
+        )
+        hidden, to_products = jax.vjp(
+            functools.partial(_gated, act=act), gate, up, scales[chosen]
+        )
+        _, to_hidden = jax.vjp(
+            lambda h: _products(h, (w_down,), sizes), hidden
+        )
+        d_gate, d_up, d_scale = to_products(*to_hidden((cotangent,)))
+        (d_mine,) = to_rows((d_gate, d_up))
+        with jax.named_scope("moe.experts"):
+            d_w_gate = add_rhs_gradient(d_w_gate, mine, d_gate, sizes)
+            d_w_up = add_rhs_gradient(d_w_up, mine, d_up, sizes)
+            d_w_down = add_rhs_gradient(
+                d_w_down, hidden, cotangent, sizes
+            )
+        with jax.named_scope("moe.dispatch"):
+            return (
+                add_rows(d_flat, tokens, d_mine),
+                d_scales.at[chosen].set(d_scale, unique_indices=True),
+                d_w_gate, d_w_up, d_w_down,
+            )
+
+    d_flat, d_scales, *d_matrices = _over_live_chunks(
+        live,
+        (jnp.zeros(flat.shape, jnp.float32), jnp.zeros_like(scales),
+         *(jnp.zeros(w.shape, jnp.float32) for w in matrices)),
+        order, group_sizes, rows,
+    )
+    return (
+        d_flat.astype(flat.dtype),
+        d_scales[:weights.size].reshape(weights.shape),
+        *(d.astype(w.dtype) for d, w in zip(d_matrices, matrices)),
+        None, None,
+    )
+
+
+_walk_held_rows.defvjp(_walk_fwd, _walk_bwd)
+
+
+def _share(flat, weights, experts, w_gate, w_up, w_down, act, first_held):
+    """``dropless_moe_mlp``'s result [N, H] where the device holds
+    the ``w_gate.shape[0]`` experts from ``first_held`` of the more
+    that ``experts`` [N, k] chooses among."""
+    held, nk = w_gate.shape[0], experts.size
+    rows, chunks = walk_chunks(nk)
+    with jax.named_scope("moe.dispatch"):
+        # held experts by their place here, every absent one last
+        assigned = experts.reshape(nk) - first_held
+        assigned = jnp.where(
+            (assigned >= 0) & (assigned < held), assigned, held
+        )
+        # the last chunk's ids past N x k: each its own, behind all
+        order = jnp.concatenate([
+            jnp.argsort(assigned, stable=True).astype(jnp.int32),
+            jnp.arange(nk, rows * chunks, dtype=jnp.int32),
+        ])
+        group_sizes = expert_counts(assigned, held + 1)[:held]
+    return _walk_held_rows(
+        act, flat, weights, w_gate, w_up, w_down, order, group_sizes
+    )
+
 
 def dropless_moe_mlp(
     x: jax.Array,  # [batch, seq, hidden]
@@ -295,15 +526,22 @@ def dropless_moe_mlp(
     token's k; the weights were applied inside the experts).
 
     The device holds the ``held`` experts ``first_held ...`` whose
-    matrices it is given: all of the router's, or a share. With a
-    share, routing, weights and ``aux`` are over all experts as
-    before; the assignments to absent experts sort behind the held
-    ones' and fall outside every group, so they cost the grouped
-    matmuls no row, and ``out`` is the held experts' part of the sum.
-    A token's k experts may all be held, so the buffers are the
-    ``N x k`` rows that hold every routing, and gathers and
-    elementwise work are paid for each of them, held or not; the
-    absent experts' rows come out of the grouped matmuls as zeros."""
+    matrices it is given: all of the router's, or a share. With
+    every expert here the layer is one pass over the ``N x k`` rows.
+    With a share, routing, weights and ``aux`` are over all experts
+    as before and ``out`` is the held experts' part of the sum: the
+    assignments to absent experts sort behind the held ones', and
+    the sorted rows are walked in chunks of ``CHUNK_ROWS``
+    (``_walk_held_rows``) of which only those that start before the
+    held rows' end are gathered, multiplied and added to their
+    tokens. A token's k experts may all be held, and then every
+    chunk is live; what a share costs is the sort of all ``N x k``
+    keys, then a row gather, the three products and the rows' sum
+    into their tokens (``ops/grouped_matmul.py add_rows``) for the
+    held rows rounded up to a chunk, with no ``N x k``-row buffer at
+    any routing. The backward pass is the same walk, and
+    makes a chunk's two first products again whatever the remat
+    policy keeps."""
     from jax.ad_checkpoint import checkpoint_name
 
     from dlrover_tpu.ops.grouped_matmul import grouped_matmul
@@ -314,7 +552,6 @@ def dropless_moe_mlp(
         raise ValueError(
             f"experts {first_held}..{first_held + held - 1} of {e}"
         )
-    filled = held == e
     n = b * s
     flat = x.reshape(n, h)
     with jax.named_scope("moe.route"):
@@ -323,26 +560,22 @@ def dropless_moe_mlp(
         weights, experts, aux = route_logits(
             logits.reshape(n, e), k, norm_topk_prob, balance_coef, z_coef
         )
+    if held < e:
+        out = _share(
+            flat, weights, experts, w_gate, w_up, w_down, act, first_held
+        )
+        return out.reshape(b, s, h), aux
     with jax.named_scope("moe.dispatch"):
         assigned = experts.reshape(n * k)
-        if not filled:
-            # held experts by their place here, every absent one last
-            assigned = assigned - first_held
-            assigned = jnp.where(
-                (assigned >= 0) & (assigned < held), assigned, held
-            )
         order = jnp.argsort(assigned, stable=True).astype(jnp.int32)
         inverse = jnp.zeros_like(order).at[order].set(
             jnp.arange(n * k, dtype=jnp.int32)
         )
-        group_sizes = (
-            expert_counts(assigned, e) if filled
-            else expert_counts(assigned, held + 1)[:held]
-        )
+        group_sizes = expert_counts(assigned, e)
         rows = _to_expert_order(flat, order, inverse)
     with jax.named_scope("moe.experts"):
         grouped = functools.partial(
-            grouped_matmul, group_sizes=group_sizes, filled=filled
+            grouped_matmul, group_sizes=group_sizes
         )
         gate = checkpoint_name(grouped(rows, w_gate), "moe_gate")
         up = checkpoint_name(grouped(rows, w_up), "moe_up")
@@ -427,4 +660,31 @@ def set_rows_held_gauge(counts, first_held: int, held: int) -> float:
         "assignments to experts this device holds over all tokens x "
         "k, at the last evaluation",
     ).set(share)
+    return share
+
+
+def set_chunks_walked_gauge(counts, first_held: int, held: int) -> float:
+    """From the same counts set the gauge ``moe_chunks_walked_share``
+    {``chunk_rows``}: the chunks of a share's walk that are live (a
+    layer's held rows rounded up to whole chunks, ``walk_chunks``)
+    over all chunks, all layers together. 1 where every expert is
+    here (one pass, no walk). Over ``moe_rows_held_share`` it is what
+    the chunk's rounding costs."""
+    import numpy as np
+
+    from dlrover_tpu.telemetry.registry import gauge
+
+    load = np.asarray(counts, dtype=np.int64)
+    load = load.reshape(-1, load.shape[-1])
+    rows, chunks = walk_chunks(int(load[0].sum()))
+    share = 1.0
+    if held < load.shape[-1]:
+        here = load[:, first_held:first_held + held].sum(axis=-1)
+        share = float((-(-here // rows)).sum() / (chunks * len(load)))
+    gauge(
+        "moe_chunks_walked_share",
+        "live chunks of the walk over a share's sorted assignments "
+        "over all chunks, at the last evaluation",
+        ("chunk_rows",),
+    ).labels(chunk_rows=str(rows)).set(share)
     return share

@@ -35,7 +35,7 @@ from dlrover_tpu.models import llama
 from dlrover_tpu.ops import tuning
 from dlrover_tpu.parallel.mesh import create_mesh
 from dlrover_tpu.parallel.moe import (
-    set_expert_load_gauges, set_rows_held_gauge,
+    set_chunks_walked_gauge, set_expert_load_gauges, set_rows_held_gauge,
 )
 from dlrover_tpu.trainer.checkpoint import FlashCheckpointer
 from dlrover_tpu.trainer.compile_cache import cache_events
@@ -269,13 +269,14 @@ def main():
                     # spreads this batch (GET /metrics)
                     counts = routing_stats(params, mb[0][0])
                     most, least = set_expert_load_gauges(counts)
-                    held = set_rows_held_gauge(
-                        counts, cfg.moe_first_expert_held,
-                        cfg.moe_experts_held,
-                    )
+                    here = (cfg.moe_first_expert_held,
+                            cfg.moe_experts_held)
+                    held = set_rows_held_gauge(counts, *here)
+                    walked = set_chunks_walked_gauge(counts, *here)
                     print(f"EXPERT_LOAD step={step} max/mean="
                           f"{most:.3f} min/mean={least:.3f} "
-                          f"held={held:.3f}", flush=True)
+                          f"held={held:.3f} walked={walked:.3f}",
+                          flush=True)
                 ckpt.save(
                     step,
                     {"params": params, "opt_state": opt_state,

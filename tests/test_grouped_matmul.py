@@ -1,7 +1,9 @@
 """``ops/grouped_matmul.py``: the tile rule from the shapes, and
 buffers that the groups do not fill (a share of the experts sorts the
 absent ones' rows last): forward and both gradients against a loop
-over the groups, on both routes (megablox in interpret mode)."""
+over the groups, on both routes (megablox in interpret mode); the two
+sums that a walk in chunks adds a chunk's part to (``add_rows``,
+``add_rhs_gradient``)."""
 
 import jax
 import jax.numpy as jnp
@@ -90,3 +92,56 @@ def test_rows_past_the_groups_sum(route, k, n, monkeypatch):
     np.testing.assert_allclose(
         d_rhs.astype(jnp.float32), w_rhs.astype(jnp.float32), **scale)
     assert float(jnp.abs(d_rhs[1]).max()) == 0.0  # the empty group
+
+
+# -- the sums a chunk of parallel/moe.py's walk adds its part to ----------
+
+@pytest.mark.parametrize("rows", [256, 384])
+def test_add_rows_as_a_grouped_product(monkeypatch, rows):
+    """The TPU's way to add rows to their indices (sorted by index, a
+    group a block of indices, the one-hot places against the rows) in
+    interpret mode, against the scatter-add that runs elsewhere:
+    indices that occur several times, blocks that get no row."""
+    from dlrover_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "ROW_BLOCK", 128)
+    ks = jax.random.split(jax.random.key(rows), 3)
+    out = jax.random.normal(ks[0], (512, 256))
+    update = jax.random.normal(ks[1], (rows, 256)).astype(jnp.bfloat16)
+    index = jax.random.randint(ks[2], (rows,), 0, 300)  # none past 299
+    index = index.at[:5].set(7)
+    want = gm.add_rows(out, index, update)
+    monkeypatch.setattr(gm, "_add_on_mxu", lambda out, rows: True)
+    got = gm.add_rows(out, index, update)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    np.testing.assert_array_equal(got[300:], out[300:])
+
+
+@pytest.mark.parametrize("sizes", [(100, 0, 156, 0), (30, 40, 50, 8)])
+def test_add_rhs_gradient_in_place(monkeypatch, sizes):
+    """A chunk's part of the matrices' gradient added to the float32
+    sum that earlier chunks left: the kernel that reads and writes
+    the sum in place (interpret mode) and the product and the add
+    that run off the TPU, with groups that are empty and rows past
+    the groups' sum. Neither rounds to the rows' bfloat16."""
+    from dlrover_tpu.ops import grouped_matmul as gm
+
+    ks = jax.random.split(jax.random.key(sum(sizes)), 3)
+    lhs = jax.random.normal(ks[0], (256, 128)).astype(jnp.bfloat16)
+    grad = jax.random.normal(ks[1], (256, 256)).astype(jnp.bfloat16)
+    into = jax.random.normal(ks[2], (4, 128, 256))
+    group_sizes = jnp.array(sizes, jnp.int32)
+    by_hand, start = [], 0
+    for g, size in enumerate(sizes):
+        rows = slice(start, start + size)
+        by_hand.append(into[g] + jnp.dot(
+            lhs[rows].T.astype(jnp.float32), grad[rows].astype(jnp.float32),
+            precision="highest"))
+        start += size
+    want = gm.add_rhs_gradient(into, lhs, grad, group_sizes)
+    monkeypatch.setattr(gm, "_use_pallas", lambda lhs, rhs: True)
+    got = gm.add_rhs_gradient(into, lhs, grad, group_sizes)
+    for result in (want, got):
+        assert result.dtype == jnp.float32
+        # a bfloat16 sum would be off by up to 0.06 at these sizes
+        np.testing.assert_allclose(result, jnp.stack(by_hand), atol=2e-4)

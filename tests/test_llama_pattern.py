@@ -373,8 +373,8 @@ def test_a_share_leaves_no_row_of_an_absent_expert_in_a_group():
     finally:
         gm.grouped_matmul = real
     np.testing.assert_array_equal(seen["sizes"], counts[2:5])
-    # the buffers are all tokens x k rows: a token's three experts
-    # may all be held
+    # one chunk of the walk: the 96 assignments are fewer than
+    # ``CHUNK_ROWS`` (chunks and their edges: test_moe_share_walk.py)
     assert seen["filled"] is False and seen["rows"] == 32 * 3
 
 

@@ -2,7 +2,8 @@
 and the grouped matmul under it (ops/grouped_matmul.py); what
 models/llama.py gained with them is in test_llama_experts.py. Compared with a dense-mask computation
 that shares no line with the path: every expert on every token, masked
-by the top-k of the float32 softmax."""
+by the top-k of the float32 softmax. A share's walk of the sorted rows
+in chunks: test_moe_share_walk.py."""
 
 import jax
 import jax.numpy as jnp
@@ -183,4 +184,3 @@ def test_capacity_path_follows_the_config_too():
             ref, ref_aux = moe.dropless_moe_mlp(*args, 2, norm)
             np.testing.assert_allclose(out, ref, atol=2e-5)
             np.testing.assert_allclose(aux, ref_aux, rtol=1e-6)
-
