@@ -8,7 +8,10 @@ is where that constant comes from: the layer alone at
 64 experts of 768, ReLU gate, 16 held), forward and forward-and-
 backward (the gradients of the input, the logits and the three
 matrices), for every share of the assignments on the held experts in
-``--shares`` and every chunk in ``--chunks``. The router's logits are
+``--shares`` and every chunk in ``--chunks`` (rows as given: the
+script sets ``CHUNK_WIDTH`` to its own rows' width). ``--hidden 2048
+--mlp 1792 --experts 32 --top 4 --held 8 --tokens 32768 --act silu``
+are ``lfm2-8b-a1b-ep4``'s. The router's logits are
 drawn once and the held experts' columns shifted until the share is
 the one asked for (``"held_share"`` in a row is what came out;
 ``"live_chunks"`` of ``"chunks"`` what the walk then does).
@@ -46,7 +49,6 @@ import numpy as np
 
 from dlrover_tpu.parallel import moe
 
-HIDDEN, MLP, EXPERTS, TOP = 2560, 768, 64, 6
 
 
 @contextlib.contextmanager
@@ -103,16 +105,16 @@ def timeit(fn, *args, n=10, warmup=2):
     return (time.perf_counter() - t0) / n
 
 
-def held_share(logits, shift, held):
-    """The share of the top-6 assignments on experts ``0 .. held - 1``
+def held_share(logits, shift, held, top):
+    """The share of the top-k assignments on experts ``0 .. held - 1``
     with their logits shifted by ``shift``."""
     moved = logits.copy()
     moved[:, :held] += shift
-    chosen = np.argpartition(-moved, TOP - 1, axis=-1)[:, :TOP]
+    chosen = np.argpartition(-moved, top - 1, axis=-1)[:, :top]
     return float((chosen < held).mean())
 
 
-def shift_for(logits, share, held):
+def shift_for(logits, share, held, top):
     """The shift that puts ``share`` of the assignments on the held
     experts, by bisection (the share rises with the shift)."""
     if share <= 0 or share >= 1:
@@ -120,7 +122,7 @@ def shift_for(logits, share, held):
     low, high = -20.0, 20.0
     for _ in range(40):
         mid = (low + high) / 2
-        if held_share(logits, mid, held) < share:
+        if held_share(logits, mid, held, top) < share:
             low = mid
         else:
             high = mid
@@ -133,6 +135,11 @@ def main(argv=None):
     ap.add_argument("--chunks", default="2048,4096,8192,12288")
     ap.add_argument("--held", type=int, default=16)
     ap.add_argument("--tokens", type=int, default=16384)
+    ap.add_argument("--hidden", type=int, default=2560)
+    ap.add_argument("--mlp", type=int, default=768)
+    ap.add_argument("--experts", type=int, default=64)
+    ap.add_argument("--top", type=int, default=6)
+    ap.add_argument("--act", default="relu")
     ap.add_argument("--n", type=int, default=10)
     ap.add_argument("--autodiff", action="store_true")
     ap.add_argument("--out", default="chiprun_out/moe_share.jsonl")
@@ -142,27 +149,32 @@ def main(argv=None):
         return 1
     os.makedirs(os.path.dirname(args.out), exist_ok=True)
     rng = np.random.default_rng(0)
-    walk = hasattr(moe, "walk_chunks") and args.held < EXPERTS
-    rows = args.tokens * TOP
-    chunks = [int(c) for c in args.chunks.split(",")] if walk else [None]
-    drawn = rng.standard_normal((args.tokens, EXPERTS)).astype(np.float32)
-    x = jnp.asarray(
-        rng.standard_normal((1, args.tokens, HIDDEN)), jnp.bfloat16
+    hidden, mlp, experts, top = (
+        args.hidden, args.mlp, args.experts, args.top
     )
-    router = jnp.zeros((HIDDEN, EXPERTS), jnp.bfloat16)  # logits given
+    walk = hasattr(moe, "walk_chunks") and args.held < experts
+    if hasattr(moe, "CHUNK_WIDTH"):
+        moe.CHUNK_WIDTH = hidden  # ``--chunks`` are rows at this width
+    rows = args.tokens * top
+    chunks = [int(c) for c in args.chunks.split(",")] if walk else [None]
+    drawn = rng.standard_normal((args.tokens, experts)).astype(np.float32)
+    x = jnp.asarray(
+        rng.standard_normal((1, args.tokens, hidden)), jnp.bfloat16
+    )
+    router = jnp.zeros((hidden, experts), jnp.bfloat16)  # logits given
     w_gate, w_up, w_down = (
         jnp.asarray(
             rng.standard_normal((args.held, *shape)) * shape[0] ** -0.5,
             jnp.bfloat16,
         )
-        for shape in ((HIDDEN, MLP), (HIDDEN, MLP), (MLP, HIDDEN))
+        for shape in ((hidden, mlp), (hidden, mlp), (mlp, hidden))
     )
 
     for share in (float(s) for s in args.shares.split(",")):
-        shift = shift_for(drawn, share, args.held)
+        shift = shift_for(drawn, share, args.held, top)
         logits = drawn.copy()
         logits[:, :args.held] += shift
-        came_out = held_share(drawn, shift, args.held)
+        came_out = held_share(drawn, shift, args.held, top)
         for chunk in chunks:
             if walk:
                 moe.CHUNK_ROWS = chunk
@@ -187,8 +199,8 @@ def main(argv=None):
                 # for its shapes
                 def layer(x, logits, w_gate, w_up, w_down):
                     return moe.dropless_moe_mlp(
-                        x, router, w_gate, w_up, w_down, k=TOP,
-                        norm_topk_prob=True, logits=logits, act="relu",
+                        x, router, w_gate, w_up, w_down, k=top,
+                        norm_topk_prob=True, logits=logits, act=args.act,
                     )
 
                 def loss(*operands):

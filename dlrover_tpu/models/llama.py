@@ -19,13 +19,26 @@ for XLA instead of translated:
 """
 
 import dataclasses
+import math
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from dlrover_tpu.ops.attention import flash_attention
+from dlrover_tpu.ops.short_conv import gated_short_conv
+
+
+class LayerKind(NamedTuple):
+    """What a layer is made of: its operator (``"full_attention"``,
+    or ``"conv"``, the gated short convolution), for attention the
+    window (None: every earlier key) and whether q and k are rotated,
+    and its feed-forward (``"dense"`` or ``"experts"``)."""
+    operator: str = "full_attention"
+    window: Optional[int] = None
+    rope: bool = True
+    ffn: str = "dense"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -108,6 +121,36 @@ class LlamaConfig:
     # it, and every token starts on the same k experts (PERF.md
     # section 6, PR 34).
     embed_init_std: float = 0.02
+    # a stack of two kinds of operator, in the source's keys
+    # (``Lfm2MoeConfig``): ``layer_types[l]`` is "full_attention" or
+    # "conv", the gated short convolution of ``conv_L_cache`` taps
+    # (ops/short_conv.py) in attention's place. The first
+    # ``num_dense_layers`` layers have a dense MLP of
+    # ``intermediate_size`` where the others have experts of
+    # ``moe_intermediate_size`` (None: ``intermediate_size``); they run
+    # ahead of the scan, and the scan walks a period of what follows.
+    # With either key the parameters are kept by position
+    # (``init_params``): layers of two kinds own different leaves.
+    layer_types: Optional[Tuple[str, ...]] = None
+    num_dense_layers: int = 0
+    moe_intermediate_size: Optional[int] = None
+    conv_L_cache: int = 3
+    # RMSNorm of each head's ``head_dim`` values of q and of k, one
+    # ``head_dim``-wide scale each, before the rotary embedding
+    # (``Lfm2MoeAttention``'s q_layernorm, k_layernorm; ``qk_norm``
+    # above is over the whole projection)
+    qk_head_norm: bool = False
+    # logits over the embedding's own rows, ``h @ embed.T``: no
+    # ``lm_head`` leaf
+    tie_word_embeddings: bool = False
+    # the router's gate ("softmax" over the experts, or "sigmoid" of
+    # each) and a selection bias (``use_expert_bias``: the k experts
+    # are the top-k of score plus ``expert_bias``, the weights the
+    # scores without it; a float32 buffer that starts at zero, that
+    # no gradient reaches and that the trainer's optimizer leaves
+    # alone: parallel/moe.py ``route_logits``)
+    moe_gate: str = "softmax"
+    use_expert_bias: bool = False
 
     def __post_init__(self):
         if self.remat not in ("off", "dots", "dots_attn_out",
@@ -144,6 +187,36 @@ class LlamaConfig:
             raise ValueError(
                 f"unknown moe_expert_act {self.moe_expert_act!r}"
             )
+        if self.moe_intermediate_size is None:
+            object.__setattr__(
+                self, "moe_intermediate_size", self.intermediate_size
+            )
+        if self.layer_types is not None:
+            object.__setattr__(
+                self, "layer_types", tuple(self.layer_types)
+            )
+            if len(self.layer_types) != self.num_layers:
+                raise ValueError(
+                    f"layer_types has {len(self.layer_types)} entries "
+                    f"for {self.num_layers} layers"
+                )
+            unknown = set(self.layer_types) - {"conv", "full_attention"}
+            if unknown:
+                raise ValueError(
+                    f"layer_types names {sorted(unknown)}: the "
+                    "operators here are 'conv' and 'full_attention'"
+                )
+        if self.num_dense_layers and not (
+                self.num_experts > 0
+                and self.num_dense_layers < self.num_layers):
+            raise ValueError(
+                f"num_dense_layers {self.num_dense_layers}: dense "
+                "layers lead a stack of expert layers and run ahead "
+                f"of its scanned periods, of which {self.num_layers} "
+                f"layers with {self.num_experts} experts leave none"
+            )
+        if self.moe_gate not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown moe_gate {self.moe_gate!r}")
         if self.num_experts > 0:
             if not self.moe_experts_held:
                 object.__setattr__(
@@ -157,23 +230,54 @@ class LlamaConfig:
                     f" of {self.num_experts}"
                 )
 
-    def layer_kinds(self) -> Tuple[Tuple[Optional[int], bool], ...]:
-        """``(window, rope)`` of each layer of one period: the window
-        (None: every earlier key) and whether q and k are rotated. The
-        period is the shortest repeat of the two layouts; one layer of
-        kind ``(None, True)`` where there is no layout."""
+    @property
+    def by_position(self) -> bool:
+        """Whether the parameters are kept by position (``lead`` and
+        ``period``) and not as one stack of like layers
+        (``blocks``)."""
+        return self.layer_types is not None or self.num_dense_layers > 0
+
+    def layer_plan(self) -> Tuple[Tuple[LayerKind, ...],
+                                  Tuple[LayerKind, ...]]:
+        """``(lead, period)``: the kinds of the layers that run ahead
+        of the scan (the ``num_dense_layers`` leading dense ones) and
+        of one period of the layers that follow, the shortest repeat
+        of their kinds."""
         n = self.num_layers
         windows = [
             self.sliding_window_size if on else None
             for on in self.sliding_window_layout or (0,) * n
         ]
         ropes = [bool(on) for on in self.rope_layout or (1,) * n]
-        kinds = tuple(zip(windows, ropes))
-        period = next(
-            p for p in range(1, n + 1)
-            if n % p == 0 and kinds == kinds[:p] * (n // p)
+        kinds = tuple(
+            LayerKind(
+                operator, *(
+                    (None, False) if operator == "conv"
+                    else (windows[i], ropes[i])
+                ),
+                "experts" if self.num_experts > 0
+                and i >= self.num_dense_layers else "dense",
+            )
+            for i, operator in enumerate(
+                self.layer_types or ("full_attention",) * n
+            )
         )
-        return kinds[:period]
+        lead, rest = (kinds[:self.num_dense_layers],
+                      kinds[self.num_dense_layers:])
+        period = next(
+            p for p in range(1, len(rest) + 1)
+            if len(rest) % p == 0 and rest == rest[:p] * (len(rest) // p)
+        )
+        return lead, rest[:period]
+
+    def layer_kinds(self) -> Tuple[Tuple[Optional[int], bool], ...]:
+        """``(window, rope)`` of each layer of one period: the window
+        (None: every earlier key) and whether q and k are rotated. The
+        period is the shortest repeat of the two layouts; one layer of
+        kind ``(None, True)`` where there is no layout."""
+        return tuple(
+            (kind.window, kind.rope) for kind in self.layer_plan()[1]
+        )
 
 
 def llama2_7b(**kw) -> LlamaConfig:
@@ -225,108 +329,181 @@ def llama_tiny(**kw) -> LlamaConfig:
 # ---------------------------------------------------------------------------
 # params
 
-def init_params(rng: jax.Array, cfg: LlamaConfig) -> Dict:
-    """Initialize the parameter pytree. Block weights carry a leading
-    layers dim (scan stacking)."""
-    h, m, L = cfg.hidden_size, cfg.intermediate_size, cfg.num_layers
-    nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    k_embed, k_blocks, k_out = jax.random.split(rng, 3)
-
-    def norm_init(*shape):
-        return jnp.ones(shape, dtype=jnp.float32)
-
-    def dense_init(key, *shape, in_axis=0):
-        fan_in = shape[in_axis]
-        std = fan_in ** -0.5
-        return (jax.random.normal(key, shape, dtype=jnp.float32) * std
-                ).astype(cfg.dtype)
-
-    ks = jax.random.split(k_blocks, 8)
-    block = {
-        "attn_norm": norm_init(L, h),
-        "wq": dense_init(ks[0], L, h, nh * hd, in_axis=1),
-        "wk": dense_init(ks[1], L, h, nkv * hd, in_axis=1),
-        "wv": dense_init(ks[2], L, h, nkv * hd, in_axis=1),
-        "wo": dense_init(ks[3], L, nh * hd, h, in_axis=1),
-        "mlp_norm": norm_init(L, h),
-    }
-    if cfg.qk_norm:
-        block["q_norm"] = norm_init(L, nh * hd)
-        block["k_norm"] = norm_init(L, nkv * hd)
-    if cfg.num_experts > 0:
-        E, held = cfg.num_experts, cfg.moe_experts_held
-        block.update({
-            "router": dense_init(ks[7], L, h, E, in_axis=1),
-            "w_gate": dense_init(ks[4], L, held, h, m, in_axis=2),
-            "w_up": dense_init(ks[5], L, held, h, m, in_axis=2),
-            "w_down": dense_init(ks[6], L, held, m, h, in_axis=2),
+def _leaves(cfg: LlamaConfig, kind: LayerKind) -> Dict:
+    """``{leaf: (shape, logical axes, deviation)}`` of one layer of
+    ``kind``: its matrices, drawn fan-in normal (the fan-in second to
+    last in every shape), its RMSNorm scales (deviation None: ones),
+    a convolution's taps and an expert layer's selection bias
+    (deviation 0: zeros)."""
+    h, hd = cfg.hidden_size, cfg.head_dim
+    nh, nkv = cfg.num_heads, cfg.num_kv_heads
+    norms = {"attn_norm": h, "mlp_norm": h}
+    if kind.operator == "conv":
+        matrices = {
+            "conv_in": ((h, 3 * h), ("embed", "mlp")),
+            "conv_out": ((h, h), ("mlp", "embed")),
+        }
+    else:
+        matrices = {
+            "wq": ((h, nh * hd), ("embed", "heads")),
+            "wk": ((h, nkv * hd), ("embed", "kv_heads")),
+            "wv": ((h, nkv * hd), ("embed", "kv_heads")),
+            "wo": ((nh * hd, h), ("heads", "embed")),
+        }
+        if cfg.qk_norm:
+            norms.update(q_norm=nh * hd, k_norm=nkv * hd)
+        elif cfg.qk_head_norm:
+            norms.update(q_norm=hd, k_norm=hd)
+    if kind.ffn == "experts":
+        held, m = cfg.moe_experts_held, cfg.moe_intermediate_size
+        matrices.update({
+            "router": ((h, cfg.num_experts), ("embed", None)),
+            "w_gate": ((held, h, m), ("expert", "embed", "mlp")),
+            "w_up": ((held, h, m), ("expert", "embed", "mlp")),
+            "w_down": ((held, m, h), ("expert", "mlp", "embed")),
         })
     else:
-        block.update({
-            "w_gate": dense_init(ks[4], L, h, m, in_axis=1),
-            "w_up": dense_init(ks[5], L, h, m, in_axis=1),
-            "w_down": dense_init(ks[6], L, m, h, in_axis=1),
+        m = cfg.intermediate_size
+        matrices.update({
+            "w_gate": ((h, m), ("embed", "mlp")),
+            "w_up": ((h, m), ("embed", "mlp")),
+            "w_down": ((m, h), ("mlp", "embed")),
         })
+    leaves = {
+        name: (shape, axes, shape[-2] ** -0.5)
+        for name, (shape, axes) in matrices.items()
+    }
+    leaves.update({
+        name: ((width,), ("norm",), None) for name, width in norms.items()
+    })
+    if kind.operator == "conv":
+        taps = cfg.conv_L_cache
+        leaves["conv_w"] = ((h, taps), ("mlp", None), taps ** -0.5)
+    if kind.ffn == "experts" and cfg.use_expert_bias:
+        leaves["expert_bias"] = ((cfg.num_experts,), (None,), 0)
+    return leaves
+
+
+#: which of ``jax.random.split(key, 8)`` draws a leaf
+_DRAW = {"wq": 0, "wk": 1, "wv": 2, "wo": 3, "w_gate": 4, "w_up": 5,
+         "w_down": 6, "router": 7, "conv_in": 0, "conv_w": 1,
+         "conv_out": 3}
+
+
+def _init_layers(key, cfg: LlamaConfig, kind: LayerKind, stack=()):
+    """The leaves of layers of ``kind``, each with the leading dims
+    ``stack``: the norms' scales and the selection bias in float32,
+    the rest in the config's dtype."""
+    ks = jax.random.split(key, 8)
+    layers = {}
+    for name, (shape, _, std) in _leaves(cfg, kind).items():
+        if not std:  # a norm's scale at one, the selection bias at zero
+            layers[name] = jnp.full(
+                stack + shape, 1.0 if std is None else 0.0, jnp.float32
+            )
+            continue
+        layers[name] = (
+            jax.random.normal(ks[_DRAW[name]], stack + shape, jnp.float32)
+            * std
+        ).astype(cfg.dtype)
+    return layers
+
+
+def _layer_axes(cfg: LlamaConfig, kind: LayerKind, stack=()):
     return {
+        name: stack + axes
+        for name, (_, axes, _) in _leaves(cfg, kind).items()
+    }
+
+
+def init_params(rng: jax.Array, cfg: LlamaConfig) -> Dict:
+    """Initialize the parameter pytree. Block weights carry a leading
+    layers dim (scan stacking): one stack ``blocks`` of like layers,
+    or, where layers of several kinds own different leaves
+    (``cfg.by_position``), the leading layers one by one in ``lead``
+    and in ``period`` a stack ``[periods, ...]`` for each position of
+    the scanned period. A tied head has no ``lm_head``."""
+    h = cfg.hidden_size
+    k_embed, k_blocks, k_out = jax.random.split(rng, 3)
+    lead, period = cfg.layer_plan()
+    params = {
         "embed": (
             jax.random.normal(
                 k_embed, (cfg.vocab_size, h), dtype=jnp.float32
             ) * cfg.embed_init_std
         ).astype(cfg.dtype),
-        "blocks": block,
-        "final_norm": norm_init(h),
-        "lm_head": dense_init(k_out, h, cfg.vocab_size, in_axis=0),
+        "final_norm": jnp.ones((h,), jnp.float32),
     }
+    if cfg.by_position:
+        keys = jax.random.split(k_blocks, len(lead) + len(period))
+        periods = (cfg.num_layers - len(lead)) // len(period)
+        params["lead"] = [
+            _init_layers(key, cfg, kind) for key, kind in zip(keys, lead)
+        ]
+        params["period"] = [
+            _init_layers(key, cfg, kind, (periods,))
+            for key, kind in zip(keys[len(lead):], period)
+        ]
+    else:
+        params["blocks"] = _init_layers(
+            k_blocks, cfg, period[0], (cfg.num_layers,)
+        )
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = (
+            jax.random.normal(k_out, (h, cfg.vocab_size), jnp.float32)
+            * h ** -0.5
+        ).astype(cfg.dtype)
+    return params
 
 
 def param_axes(cfg: LlamaConfig) -> Dict:
     """Logical-axes tree mirroring init_params (see parallel/sharding.py)."""
-    blocks = {
-        "attn_norm": ("layers", "norm"),
-        "wq": ("layers", "embed", "heads"),
-        "wk": ("layers", "embed", "kv_heads"),
-        "wv": ("layers", "embed", "kv_heads"),
-        "wo": ("layers", "heads", "embed"),
-        "mlp_norm": ("layers", "norm"),
-    }
-    if cfg.qk_norm:
-        blocks["q_norm"] = ("layers", "norm")
-        blocks["k_norm"] = ("layers", "norm")
-    if cfg.num_experts > 0:
-        blocks.update({
-            "router": ("layers", "embed", None),
-            "w_gate": ("layers", "expert", "embed", "mlp"),
-            "w_up": ("layers", "expert", "embed", "mlp"),
-            "w_down": ("layers", "expert", "mlp", "embed"),
-        })
+    lead, period = cfg.layer_plan()
+    axes = {"embed": ("vocab", "embed"), "final_norm": ("norm",)}
+    if cfg.by_position:
+        axes["lead"] = [_layer_axes(cfg, kind) for kind in lead]
+        axes["period"] = [
+            _layer_axes(cfg, kind, ("layers",)) for kind in period
+        ]
     else:
-        blocks.update({
-            "w_gate": ("layers", "embed", "mlp"),
-            "w_up": ("layers", "embed", "mlp"),
-            "w_down": ("layers", "mlp", "embed"),
-        })
-    return {
-        "embed": ("vocab", "embed"),
-        "blocks": blocks,
-        "final_norm": ("norm",),
-        "lm_head": ("embed", "vocab"),
-    }
+        axes["blocks"] = _layer_axes(cfg, period[0], ("layers",))
+    if not cfg.tie_word_embeddings:
+        axes["lm_head"] = ("embed", "vocab")
+    return axes
+
+
+def frozen_params(cfg: LlamaConfig) -> Optional[Dict]:
+    """A tree of bools mirroring init_params, True at the leaves an
+    optimizer must neither update nor decay (the router's selection
+    bias); None where there is no such leaf."""
+    if not (cfg.num_experts > 0 and cfg.use_expert_bias):
+        return None
+    return jax.tree_util.tree_map_with_path(
+        lambda path, _: path[-1].key == "expert_bias",
+        jax.eval_shape(lambda: init_params(jax.random.key(0), cfg)),
+    )
+
+
+def _layers_of_each_kind(cfg: LlamaConfig):
+    """``[(kind, how many layers of it)]`` over the whole stack."""
+    lead, period = cfg.layer_plan()
+    periods = (cfg.num_layers - len(lead)) // len(period)
+    return [(kind, 1) for kind in lead] + [
+        (kind, periods) for kind in period
+    ]
 
 
 def param_count(cfg: LlamaConfig) -> int:
-    L, h, m = cfg.num_layers, cfg.hidden_size, cfg.intermediate_size
-    nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    if cfg.num_experts > 0:
-        mlp = h * cfg.num_experts + 3 * h * m * cfg.moe_experts_held
-    else:
-        mlp = 3 * h * m
-    per_layer = (
-        2 * h  # norms
-        + (nh * hd + nkv * hd if cfg.qk_norm else 0)
-        + h * nh * hd + 2 * h * nkv * hd + nh * hd * h  # attention
-        + mlp
+    def layer(kind):
+        return sum(
+            math.prod(shape) for shape, _, _ in _leaves(cfg, kind).values()
+        )
+
+    embeddings = 1 if cfg.tie_word_embeddings else 2
+    return (
+        cfg.vocab_size * cfg.hidden_size * embeddings + cfg.hidden_size
+        + sum(n * layer(kind) for kind, n in _layers_of_each_kind(cfg))
     )
-    return cfg.vocab_size * h * 2 + h + L * per_layer
 
 
 # ---------------------------------------------------------------------------
@@ -383,30 +560,43 @@ def _free(x, logical_axes):
 
 
 def _pre_attn(cfg: LlamaConfig, x, layer_params, cos, sin,
-              constrain=_free, rope=True):
-    """Block segment 1: attn-norm + q/k/v projections + rope (none in
-    a layer whose kind says so); and, where the router reads the
-    block's input, its logits, which ``_post_attn`` is handed past
-    attention: ``(q, k, v, logits or None)``."""
+              constrain=_free, kind=LayerKind()):
+    """Block segment 1, up to the operator's call: the norm and, for
+    attention, the q/k/v projections, the heads' norms and rope (none
+    in a layer whose kind says so), for the convolution its input
+    projection; and, where the router reads the block's input, its
+    logits, which ``_post_attn`` is handed past the operator:
+    ``(the operator's arguments, logits or None)``."""
     b, s, h = x.shape
     nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     p = layer_params
     y = rms_norm(constrain(x, _RESIDUAL), p["attn_norm"], cfg.norm_eps)
+
+    def logits():
+        if (kind.ffn != "experts"
+                or cfg.moe_router_input != "block_input"):
+            return None
+        from dlrover_tpu.parallel.moe import router_logits
+
+        return router_logits(x, p["router"])
+
+    if kind.operator == "conv":
+        with jax.named_scope("conv.in_proj"):
+            bcu = constrain(y @ p["conv_in"], _MLP)
+        return (bcu, p["conv_w"]), logits()
     q, k = y @ p["wq"], y @ p["wk"]
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = rms_norm(k, p["k_norm"], cfg.norm_eps)
     q = constrain(q.reshape(b, s, nh, hd), _Q)
     k = constrain(k.reshape(b, s, nkv, hd), _KV)
+    if cfg.qk_head_norm:
+        q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, p["k_norm"], cfg.norm_eps)
     v = constrain((y @ p["wv"]).reshape(b, s, nkv, hd), _KV)
-    if rope:
+    if kind.rope:
         q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
-    logits = None
-    if cfg.num_experts > 0 and cfg.moe_router_input == "block_input":
-        from dlrover_tpu.parallel.moe import router_logits
-
-        logits = router_logits(x, p["router"])
-    return q, k, v, logits
+    return (q, k, v), logits()
 
 
 def _expert_mlp(cfg: LlamaConfig, expert_parallel: bool):
@@ -424,17 +614,20 @@ def _expert_mlp(cfg: LlamaConfig, expert_parallel: bool):
     if not expert_parallel:
         return partial(
             moe.dropless_moe_mlp, act=cfg.moe_expert_act,
-            first_held=cfg.moe_first_expert_held, **routing
+            first_held=cfg.moe_first_expert_held, gate=cfg.moe_gate,
+            **routing
         )
     if (cfg.moe_experts_held != cfg.num_experts
             or cfg.moe_router_input != "post_attn_norm"
-            or cfg.moe_expert_act != "silu"):
+            or cfg.moe_expert_act != "silu"
+            or cfg.moe_gate != "softmax" or cfg.use_expert_bias):
         raise ValueError(
             "a share of the experts held on one device "
-            "(moe_experts_held), a router on the block's input and a "
-            "relu gate are the dropless path's, on one device: over "
-            "an 'expert' mesh axis larger than one they are refused "
-            "(experts over chips: ROADMAP B9)"
+            "(moe_experts_held), a router on the block's input, a "
+            "relu gate, a sigmoid router and its selection bias are "
+            "the dropless path's, on one device: over an 'expert' "
+            "mesh axis larger than one they are refused (experts "
+            "over chips: ROADMAP B9)"
         )
     if cfg.moe_capacity_factor <= 0:
         raise ValueError(
@@ -448,19 +641,30 @@ def _expert_mlp(cfg: LlamaConfig, expert_parallel: bool):
     )
 
 
-def _post_attn(cfg: LlamaConfig, x, attn, layer_params,
-               router_logits=None, constrain=_free, expert_parallel=False):
-    """Block segment 2: output projection + residual + MLP.
-    ``router_logits``: ``_pre_attn``'s, where the router reads the
-    block's input."""
-    b, s, h = x.shape
+def _operator_out(x, out, layer_params, kind: LayerKind):
+    """The operator's result through its output projection."""
+    b, s, _ = x.shape
+    if kind.operator == "conv":
+        with jax.named_scope("conv.out_proj"):
+            return out @ layer_params["conv_out"]
+    return out.reshape(b, s, -1) @ layer_params["wo"]
+
+
+def _post_attn(cfg: LlamaConfig, x, out, layer_params,
+               router_logits=None, constrain=_free, expert_parallel=False,
+               kind=LayerKind()):
+    """Block segment 2, from the operator's result ``out``: output
+    projection + residual + MLP. ``router_logits``: ``_pre_attn``'s,
+    where the router reads the block's input."""
     p = layer_params
-    x = constrain(x + attn.reshape(b, s, -1) @ p["wo"], _RESIDUAL)
+    x = constrain(x + _operator_out(x, out, p, kind), _RESIDUAL)
     y = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
-    if cfg.num_experts > 0:
+    if kind.ffn == "experts":
         mlp = _expert_mlp(cfg, expert_parallel)
         if router_logits is not None:
             mlp = partial(mlp, logits=router_logits)
+        if cfg.use_expert_bias:
+            mlp = partial(mlp, bias=p["expert_bias"])
         out, aux = mlp(
             y, p["router"], p["w_gate"], p["w_up"], p["w_down"]
         )
@@ -471,33 +675,44 @@ def _post_attn(cfg: LlamaConfig, x, attn, layer_params,
     return x, jnp.zeros((), jnp.float32)
 
 
-def _block(cfg: LlamaConfig, x, layer_params, cos, sin, attn_fn,
-           constrain=_free, expert_parallel=False, rope=True):
-    """One decoder block. x: [batch, seq, hidden]. Returns (x, aux_loss)
-    where aux_loss is the MoE balance loss (0 for dense)."""
-    q, k, v, logits = _pre_attn(
-        cfg, x, layer_params, cos, sin, constrain, rope
+def _block(cfg: LlamaConfig, x, layer_params, cos, sin, operate,
+           constrain=_free, expert_parallel=False, kind=LayerKind()):
+    """One decoder block of ``kind`` around its operator's call
+    ``operate`` (``_operator_of``). x: [batch, seq, hidden]. Returns
+    (x, aux_loss) where aux_loss is the MoE balance loss (0 for
+    dense)."""
+    operands, logits = _pre_attn(
+        cfg, x, layer_params, cos, sin, constrain, kind
     )
-    attn = attn_fn(q, k, v)
     return _post_attn(
-        cfg, x, attn, layer_params, logits, constrain, expert_parallel
+        cfg, x, operate(*operands), layer_params, logits, constrain,
+        expert_parallel, kind,
     )
 
 
-def _attention_of(cfg: LlamaConfig, attn_fn, window):
-    """``attn_fn`` as a layer of one kind calls it. A config with a
-    layer pattern names the two kinds of call, ``attn.full`` and
-    ``attn.window``, in their device ops' ``op_name``, and hands a
+def _operator_of(cfg: LlamaConfig, attn_fn, kind: LayerKind):
+    """The call at a layer of ``kind``'s heart, on what ``_pre_attn``
+    hands it: ``attn_fn`` on q, k, v, or the gated short convolution
+    on its projection and taps. A config with a layer pattern names
+    the kinds of call, ``attn.full``, ``attn.window`` and
+    ``conv.mix``, in their device ops' ``op_name``, and hands a
     windowed layer's window to ``attn_fn`` (which has to take it)."""
-    if cfg.sliding_window_layout is None:
+    if kind.operator == "conv":
+
+        def mix(bcu, w):
+            with jax.named_scope("conv.mix"):
+                return gated_short_conv(bcu, w)
+
+        return mix
+    if cfg.sliding_window_layout is None and cfg.layer_types is None:
         return attn_fn
 
     def attend(q, k, v):
-        if window is None:
+        if kind.window is None:
             with jax.named_scope("attn.full"):
                 return attn_fn(q, k, v)
         with jax.named_scope("attn.window"):
-            return attn_fn(q, k, v, window=window)
+            return attn_fn(q, k, v, window=kind.window)
 
     return attend
 
@@ -505,23 +720,32 @@ def _attention_of(cfg: LlamaConfig, attn_fn, window):
 def _scan_layers(layers, carry, blocks):
     """``carry`` through every layer, a period of the layer pattern a
     scan step: ``layers`` holds one ``layer(carry, layer_params) ->
-    (carry, out)`` for each layer of a period (``layer_kinds()``),
-    ``blocks`` the parameters stacked ``[layers, ...]`` as they are
-    kept. Returns ``(carry, outs stacked [layers, ...])``. With one
+    (carry, out)`` for each layer of a period (``layer_plan()``),
+    ``blocks`` the parameters as they are kept: one stack ``[layers,
+    ...]`` of like layers, or a list with a stack ``[periods, ...]``
+    of its own leaves for each position of the period. Returns
+    ``(carry, outs stacked [layers, ...])``. With one stack and one
     kind of layer this is the plain scan over ``blocks``."""
     period = len(layers)
-    if period == 1:
-        return jax.lax.scan(layers[0], carry, blocks)
-    per_period = jax.tree.map(
-        lambda a: a.reshape(-1, period, *a.shape[1:]), blocks
-    )
+    if isinstance(blocks, dict):
+        if period == 1:
+            return jax.lax.scan(layers[0], carry, blocks)
+        per_period = jax.tree.map(
+            lambda a: a.reshape(-1, period, *a.shape[1:]), blocks
+        )
+
+        def position(period_params, i):
+            return jax.tree.map(lambda a: a[i], period_params)
+    else:
+        per_period = blocks
+
+        def position(period_params, i):
+            return period_params[i]
 
     def body(carry, period_params):
         outs = []
         for i, layer in enumerate(layers):
-            carry, out = layer(
-                carry, jax.tree.map(lambda a: a[i], period_params)
-            )
+            carry, out = layer(carry, position(period_params, i))
             outs.append(out)
         if outs[0] is None:
             return carry, None
@@ -533,6 +757,19 @@ def _scan_layers(layers, carry, blocks):
             lambda a: a.reshape(-1, *a.shape[2:]), outs
         )
     return carry, outs
+
+
+def _through_layers(cfg: LlamaConfig, layer_of, carry, params):
+    """``carry`` through the whole stack: the leading layers one by
+    one, then the periods under ``_scan_layers``; ``layer_of(kind)``
+    makes a layer. Returns ``(carry, the scanned layers' outs)``."""
+    lead, period = cfg.layer_plan()
+    for kind, layer_params in zip(lead, params.get("lead", ())):
+        carry, _ = layer_of(kind)(carry, layer_params)
+    return _scan_layers(
+        [layer_of(kind) for kind in period], carry,
+        params["period" if cfg.by_position else "blocks"],
+    )
 
 
 def _dots_policy(cfg: LlamaConfig):
@@ -580,41 +817,40 @@ def hidden_states(
 
     def layer_of(kind):
         """One layer of ``kind`` under the config's remat policy."""
-        window, rope = kind
-        attend = _attention_of(cfg, attn_fn, window)
+        operate = _operator_of(cfg, attn_fn, kind)
 
         def body(carry, layer_params):
             x, aux_sum = carry
             x, aux = _block(
-                cfg, x, layer_params, cos, sin, attend, constrain,
-                expert_parallel, rope,
+                cfg, x, layer_params, cos, sin, operate, constrain,
+                expert_parallel, kind,
             )
             return (x, aux_sum + aux), None
 
         if cfg.remat == "dots_attn_out":
-            # "dots" remat on the segments AROUND attention, with the
-            # attention call OUTSIDE any checkpoint: its custom_vjp
-            # residuals (q, k, v, o, lse) are then kept like ordinary
-            # activations, so the backward pass never re-runs the
-            # forward kernel (under plain "dots" the re-fwd is ~7% of
-            # the step). Costs the saved residuals' HBM (~q+k+v+o+lse
-            # per layer).
+            # "dots" remat on the segments AROUND the operator, with
+            # the operator's call OUTSIDE any checkpoint: its
+            # custom_vjp residuals (attention's q, k, v, o, lse) are
+            # then kept like ordinary activations, so the backward
+            # pass never re-runs the forward kernel (under plain
+            # "dots" the re-fwd is ~7% of the step). Costs the saved
+            # residuals' HBM (~q+k+v+o+lse per layer).
             policy = _dots_policy(cfg)
             pre = jax.checkpoint(
-                partial(_pre_attn, cfg, constrain=constrain, rope=rope),
+                partial(_pre_attn, cfg, constrain=constrain, kind=kind),
                 policy=policy,
             )
             post = jax.checkpoint(
                 partial(_post_attn, cfg, constrain=constrain,
-                        expert_parallel=expert_parallel),
+                        expert_parallel=expert_parallel, kind=kind),
                 policy=policy,
             )
 
             def body(carry, layer_params):  # noqa: F811
                 x, aux_sum = carry
-                q, k, v, logits = pre(x, layer_params, cos, sin)
-                attn = attend(q, k, v)
-                x, aux = post(x, attn, layer_params, logits)
+                operands, logits = pre(x, layer_params, cos, sin)
+                out = operate(*operands)
+                x, aux = post(x, out, layer_params, logits)
                 return (x, aux_sum + aux), None
 
         elif cfg.remat == "dots":
@@ -625,12 +861,19 @@ def hidden_states(
             )
         return body
 
-    (x, aux), _ = _scan_layers(
-        [layer_of(kind) for kind in cfg.layer_kinds()],
-        (x, jnp.zeros((), jnp.float32)), params["blocks"],
+    (x, aux), _ = _through_layers(
+        cfg, layer_of, (x, jnp.zeros((), jnp.float32)), params
     )
     x = constrain(x, _RESIDUAL)
     return rms_norm(x, params["final_norm"], cfg.norm_eps), aux
+
+
+def _head(params: Dict, cfg: LlamaConfig) -> jax.Array:
+    """The output head [hidden, vocab]: its own leaf, or the
+    embedding's rows where the two are tied."""
+    if cfg.tie_word_embeddings:
+        return params["embed"].T
+    return params["lm_head"]
 
 
 def forward(
@@ -644,7 +887,7 @@ def forward(
     ring attention under sequence parallelism). With ``return_aux`` also
     returns the summed MoE auxiliary loss."""
     x, aux = hidden_states(params, tokens, cfg, attn_fn=attn_fn)
-    logits = (x @ params["lm_head"]).astype(jnp.float32)
+    logits = (x @ _head(params, cfg)).astype(jnp.float32)
     if return_aux:
         return logits, aux
     return logits
@@ -710,72 +953,113 @@ def next_token_loss(
     )
     if cfg.loss_chunk > 0:
         nll_sum, cnt = _chunked_ce(
-            x, params["lm_head"], targets, cfg.loss_chunk
+            x, _head(params, cfg), targets, cfg.loss_chunk
         )
     else:
-        logits = (x @ params["lm_head"]).astype(jnp.float32)
+        logits = (x @ _head(params, cfg)).astype(jnp.float32)
         nll_sum, cnt = _masked_nll(logits, targets)
     ce = nll_sum / jnp.maximum(cnt, 1.0)
     return ce + aux  # aux arrives scaled (the config's coefficients)
 
 
-def routing_stats(params: Dict, tokens: jax.Array, cfg: LlamaConfig,
-                  attn_fn=None) -> jax.Array:
-    """Tokens per expert and layer, int32 [layers, experts], for
-    ``tokens`` [batch, seq]: each row sums to ``batch x seq x
-    moe_top_k``. A forward pass that also records, a layer, what the
-    router chose for the hidden states it really sees (jit-able)."""
+def _routed(params, tokens, cfg: LlamaConfig, attn_fn, stat):
+    """``stat(logits, layer_params)`` of every expert layer, stacked
+    [expert layers, ...], for ``tokens`` [batch, seq]: a forward pass
+    that also records, a layer, what the router saw (its logits for
+    the hidden states it really reads; jit-able)."""
     if cfg.num_experts == 0:
         raise ValueError("routing_stats: a dense config has no router")
-    from dlrover_tpu.parallel.moe import (
-        logits_per_expert, router_logits,
-    )
+    from dlrover_tpu.parallel.moe import router_logits
 
     if attn_fn is None:
         attn_fn = partial(flash_attention, causal=True)
     cos, sin = rope_tables(tokens.shape[1], cfg.head_dim, cfg.rope_theta)
 
     def layer_of(kind):
-        window, rope = kind
-        attend = _attention_of(cfg, attn_fn, window)
+        operate = _operator_of(cfg, attn_fn, kind)
 
         def body(x, p):
-            q, k, v, logits = _pre_attn(cfg, x, p, cos, sin, rope=rope)
-            attn = attend(q, k, v)
-            if logits is None:
-                logits = router_logits(rms_norm(
-                    x + attn.reshape(*x.shape[:2], -1) @ p["wo"],
-                    p["mlp_norm"], cfg.norm_eps,
-                ), p["router"])
-            counts = logits_per_expert(logits, cfg.moe_top_k)
-            x, _ = _post_attn(cfg, x, attn, p, logits)
-            return x, counts
+            operands, logits = _pre_attn(cfg, x, p, cos, sin, kind=kind)
+            out = operate(*operands)
+            seen = None
+            if kind.ffn == "experts":
+                if logits is None:
+                    logits = router_logits(rms_norm(
+                        x + _operator_out(x, out, p, kind),
+                        p["mlp_norm"], cfg.norm_eps,
+                    ), p["router"])
+                seen = stat(logits, p)
+            x, _ = _post_attn(cfg, x, out, p, logits, kind=kind)
+            return x, seen
 
         return body
 
-    _, counts = _scan_layers(
-        [layer_of(kind) for kind in cfg.layer_kinds()],
-        params["embed"][tokens], params["blocks"],
+    return _through_layers(
+        cfg, layer_of, params["embed"][tokens], params
+    )[1]
+
+
+def _selection(cfg: LlamaConfig, layer_params) -> Dict:
+    """How ``layer_params``' router selects: ``route_logits``' gate
+    and bias."""
+    routing = {"gate": cfg.moe_gate}
+    if cfg.use_expert_bias:
+        routing["bias"] = layer_params["expert_bias"]
+    return routing
+
+
+def routing_stats(params: Dict, tokens: jax.Array, cfg: LlamaConfig,
+                  attn_fn=None) -> jax.Array:
+    """Tokens per expert and expert layer, int32 [layers, experts],
+    for ``tokens`` [batch, seq]: each row sums to ``batch x seq x
+    moe_top_k``."""
+    from dlrover_tpu.parallel.moe import logits_per_expert
+
+    return _routed(
+        params, tokens, cfg, attn_fn,
+        lambda logits, p: logits_per_expert(
+            logits, cfg.moe_top_k, **_selection(cfg, p)
+        ),
     )
-    return counts
+
+
+def bias_changed_stats(params: Dict, tokens: jax.Array,
+                       cfg: LlamaConfig, attn_fn=None) -> jax.Array:
+    """Assignments an expert layer's selection bias changed, int32
+    [layers], of its ``batch x seq x moe_top_k``: those among a
+    token's top-k of score plus bias and not of the score alone."""
+    from dlrover_tpu.parallel.moe import bias_changed
+
+    return _routed(
+        params, tokens, cfg, attn_fn,
+        lambda logits, p: bias_changed(
+            logits, cfg.moe_top_k, **_selection(cfg, p)
+        ),
+    )
 
 
 def flops_per_token(cfg: LlamaConfig, seq_len: int) -> float:
     """Approximate training FLOPs per token (6N_active + attention
-    quadratic, at ``num_heads x head_dim`` and by each layer's kind).
-    For MoE, only the top-k routed experts execute per token, so N
-    counts k experts — not all E."""
-    n = param_count(cfg) - cfg.vocab_size * cfg.hidden_size  # tied-ish
+    quadratic, at ``num_heads x head_dim`` and by each layer's kind;
+    the convolution's taps are not counted). For MoE, only the top-k
+    routed experts execute per token, so N counts k experts — not all
+    E."""
+    n = param_count(cfg)
+    if not cfg.tie_word_embeddings:
+        n -= cfg.vocab_size * cfg.hidden_size  # tied-ish
+    kinds = _layers_of_each_kind(cfg)
     if cfg.num_experts > 0:
-        L, h, m = cfg.num_layers, cfg.hidden_size, cfg.intermediate_size
+        h, m = cfg.hidden_size, cfg.moe_intermediate_size
         # of the experts held here a token meets its k's share
         met = (min(cfg.moe_top_k, cfg.num_experts)
                * cfg.moe_experts_held / cfg.num_experts)
-        n -= L * 3 * h * m * (cfg.moe_experts_held - met)
+        n -= 3 * h * m * (cfg.moe_experts_held - met) * sum(
+            count for kind, count in kinds if kind.ffn == "experts"
+        )
     # scores and weighted values against every key a query's layer
     # lets it see, causality not counted: the sequence, or the window
-    kinds = cfg.layer_kinds()
-    keys = sum(min(window or seq_len, seq_len) for window, _ in kinds)
-    attn = (12 * cfg.num_heads * cfg.head_dim
-            * cfg.num_layers // len(kinds) * keys)
-    return 6.0 * n + attn
+    keys = sum(
+        count * min(kind.window or seq_len, seq_len)
+        for kind, count in kinds if kind.operator != "conv"
+    )
+    return 6.0 * n + 12 * cfg.num_heads * cfg.head_dim * keys

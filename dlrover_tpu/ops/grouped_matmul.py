@@ -47,17 +47,36 @@ TILE_CAPS = (512, 1024, 1024)
 LANES = 128
 
 
-def tiles(rows: int, contraction: int, columns: int):
+#: the most elements of a [contraction, columns] tile of a float32
+#: result that ``tgmm`` adds to in place: it holds the tile five
+#: times (the sum read and written, each double-buffered, and its own
+#: accumulator), and 1024 x 896 of them are refused at 21.25M of the
+#: 16M of scoped VMEM where 640 x 768 and 512 x 896 compile
+IN_PLACE_TILE = 512 * 1024
+
+
+def tiles(rows: int, contraction: int, columns: int, most: int = None):
     """(rows, contraction, columns) of one tile: in each dimension the
     largest multiple of 128 that divides it within ``TILE_CAPS``
     ((512, 1024, 1024) at 2048 x 1024, (512, 640, 768) at 2560 x
-    768); None where a dimension has no such divisor."""
-    picked = tuple(
-        max((t for t in range(LANES, cap + 1, LANES) if size % t == 0),
-            default=None)
+    768, (512, 1024, 896) at 2048 x 1792); None where a dimension has
+    no such divisor. ``most``: the most elements of the [contraction,
+    columns] face, where the caller's result is wider than bf16; the
+    largest face within it, the longer contraction among equals
+    ((512, 512, 896) at 2048 x 1792, and 2560 x 768's as above)."""
+    fits = [
+        [t for t in range(LANES, cap + 1, LANES) if size % t == 0]
         for size, cap in zip((rows, contraction, columns), TILE_CAPS)
-    )
-    return None if None in picked else picked
+    ]
+    if not all(fits):
+        return None
+    faces = [
+        (k, n) for k in fits[1] for n in fits[2]
+        if most is None or k * n <= most
+    ]
+    if not faces:
+        return None
+    return (max(fits[0]), *max(faces, key=lambda f: (f[0] * f[1], f[0])))
 
 
 def _use_pallas(lhs: jax.Array, rhs: jax.Array) -> bool:
@@ -179,7 +198,10 @@ def add_rhs_gradient(
         return tgmm(
             lhs.swapaxes(0, 1), grad, group_sizes,
             preferred_element_type=into.dtype,
-            tiling=tiles(lhs.shape[0], *into.shape[1:]),
+            tiling=tiles(
+                lhs.shape[0], *into.shape[1:],
+                most=IN_PLACE_TILE if into.dtype.itemsize > 2 else None,
+            ),
             num_actual_groups=into.shape[0], existing_out=into,
             interpret=_interpret(),
         )

@@ -225,6 +225,14 @@ def router_logits(x: jax.Array, gate_w: jax.Array) -> jax.Array:
     return x.astype(jnp.float32) @ gate_w.astype(jnp.float32)
 
 
+#: the router's gate, by the model file's name for it: what turns an
+#: expert's logit into its score
+GATES = {
+    "softmax": lambda logits: jax.nn.softmax(logits, axis=-1),
+    "sigmoid": jax.nn.sigmoid,
+}
+
+
 def route(
     flat: jax.Array,  # [N, H]
     gate_w: jax.Array,  # [H, E]
@@ -232,11 +240,12 @@ def route(
     norm_topk_prob: bool,
     balance_coef: float = BALANCE_LOSS_COEF,
     z_coef: float = Z_LOSS_COEF,
+    **routing,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """``route_logits`` of the router's own product with ``flat``."""
     return route_logits(
         router_logits(flat, gate_w), k, norm_topk_prob, balance_coef,
-        z_coef,
+        z_coef, **routing,
     )
 
 
@@ -246,47 +255,89 @@ def route_logits(
     norm_topk_prob: bool,
     balance_coef: float = BALANCE_LOSS_COEF,
     z_coef: float = Z_LOSS_COEF,
+    gate: str = "softmax",
+    bias: jax.Array = None,  # [E] float32
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Router of the dropless path: ``(weights [N, k] float32,
     experts int32 [N, k], aux)``. The weights are the float32
-    softmax's own top-k values (ties to the lower index, as
+    gate's own top-k scores (ties to the lower index, as
     ``jax.lax.top_k`` gives them), renormalised over the k only where
-    the configuration says so (which makes them the softmax over the
-    k chosen logits); at k = 1 they stay raw in either case, or the
-    router would get no gradient through the LM loss. ``aux`` is
-    scaled: the balance loss over all k choices plus the z-loss."""
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, experts = jax.lax.top_k(probs, k)
-    aux = balance_coef * balance_loss(probs, experts) + z_coef * (
+    the configuration says so (which makes a softmax gate's the
+    softmax over the k chosen logits); at k = 1 they stay raw in
+    either case, or the router would get no gradient through the LM
+    loss. ``aux`` is scaled: the balance loss over all k choices plus
+    the z-loss.
+
+    ``gate`` "sigmoid" scores each expert by itself (a sum over the k
+    then takes ``Lfm2MoeSparseMoeBlock``'s 1e-6 beside it). ``bias``:
+    the k experts are the top-k of score plus bias and the weights
+    the scores there, without it; the bias is a buffer that no
+    gradient reaches (a balance kept by moving it is the trainer's
+    to keep, and nothing here moves it). The balance term reads the
+    scores normalised to sum to one over the experts, which a
+    softmax's are."""
+    probs = GATES[gate](logits)
+    if bias is None:
+        weights, experts = jax.lax.top_k(probs, k)
+    else:
+        _, experts = jax.lax.top_k(
+            probs + jax.lax.stop_gradient(bias), k
+        )
+        weights = jnp.take_along_axis(probs, experts, axis=-1)
+    shares = probs
+    if gate != "softmax":
+        shares = probs / jnp.sum(probs, axis=-1, keepdims=True)
+    aux = balance_coef * balance_loss(shares, experts) + z_coef * (
         jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
     )
     if norm_topk_prob and k > 1:
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        total = jnp.sum(weights, axis=-1, keepdims=True)
+        if gate == "sigmoid":
+            total = total + 1e-6
+        weights = weights / total
     return weights, experts, aux
 
 
 #: the gate's activation in an expert, by the model file's name for it
 ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
-#: rows of one chunk of a share's walk: a multiple of 512, so that
-#: ``grouped_matmul.tiles`` keeps its row tile. The chip's verdict at
-#: [16384, 2560], 16 of 64 experts held, top-6 (PERF.md, PR 35;
-#: ``benchmarks/profile_moe_share.py``): a live chunk costs 3.8 ms at
-#: 4,096 rows, 5.1 at 8,192 and 7.2 at 12,288, forward and backward
-#: under a remat policy that runs the forward twice. Smaller chunks
-#: follow the held share more closely and pay a turn's fixed costs
-#: (the tokens' sum and the matrices' three float32 gradient sums
-#: read and written once a turn) more often, larger ones round a
-#: layer's share up further: in the cell 4,096 reads 4.4% fewer
-#: tokens/s than this and 12,288 0.3% more for a second of set-up
+#: rows of one chunk of a share's walk at rows ``CHUNK_WIDTH`` wide: a
+#: multiple of 512, so that ``grouped_matmul.tiles`` keeps its row
+#: tile. The chip's verdict at [16384, 2560], 16 of 64 experts held,
+#: top-6 (PERF.md, PR 35; ``benchmarks/profile_moe_share.py``): a live
+#: chunk costs 3.8 ms at 4,096 rows, 5.1 at 8,192 and 7.2 at 12,288,
+#: forward and backward under a remat policy that runs the forward
+#: twice. Smaller chunks follow the held share more closely and pay a
+#: turn's fixed costs (the tokens' sum and the matrices' three float32
+#: gradient sums read and written once a turn) more often, larger
+#: ones round a layer's share up further: in the cell 4,096 reads
+#: 4.4% fewer tokens/s than this and 12,288 0.3% more for a second of
+#: set-up
 CHUNK_ROWS = 8192
+#: the width those rows were timed at. A chunk is sized by the bytes
+#: of its gathered rows, so narrower rows make a longer chunk: 10,240
+#: at 2048. The chip's verdict there, [32768, 2048], 8 of 32 experts
+#: of 1792 held, top-4 (PERF.md, PR 36; forward plus the gradients'
+#: program, ms a layer, at held shares of 0.249 / 0.251): 8,192 rows
+#: 40.8 / 46.1 (the even share, a row a token, ends on the fourth
+#: chunk's edge at every batch of 8,192-token sequences, and a fifth
+#: live chunk costs 5.3), 10,240 rows 42.3 / 42.5, 12,288 rows 39.4
+#: / 39.6 (three live chunks). So bytes do not pick the fastest chunk
+#: at that width; they pick one whose cost is level across the even
+#: share, and leave the 2560-wide walk as it was timed.
+CHUNK_WIDTH = 2560
 
 
-def walk_chunks(assignments: int) -> Tuple[int, int]:
+def walk_chunks(assignments: int, width: int) -> Tuple[int, int]:
     """``(rows of a chunk, chunks)`` of a share's walk over
-    ``assignments`` sorted rows: ``CHUNK_ROWS``, or all of them where
-    they are fewer; the last chunk may reach past the end."""
-    rows = min(CHUNK_ROWS, assignments)
+    ``assignments`` sorted rows ``width`` wide: ``CHUNK_ROWS`` at
+    ``CHUNK_WIDTH``, as many more as the rows are narrower, in whole
+    tiles of 512; or all the assignments where they are fewer. The
+    last chunk may reach past the end."""
+    rows = CHUNK_ROWS * CHUNK_WIDTH // width
+    if rows > 512:
+        rows -= rows % 512
+    rows = min(rows, assignments)
     return rows, -(-assignments // rows)
 
 
@@ -370,7 +421,7 @@ def _walk(act, flat, weights, w_gate, w_up, w_down, order, group_sizes):
     the same walk written out (``_walk_bwd``)."""
     from dlrover_tpu.ops.grouped_matmul import add_rows
 
-    rows, _ = walk_chunks(weights.size)
+    rows, _ = walk_chunks(weights.size, flat.shape[1])
     scales = _scales(weights, order)
 
     def live(out, start):
@@ -419,7 +470,7 @@ def _walk_bwd(act, args, g):
     from dlrover_tpu.ops.grouped_matmul import add_rhs_gradient, add_rows
 
     flat, weights, w_gate, w_up, w_down, order, group_sizes = args
-    rows, _ = walk_chunks(weights.size)
+    rows, _ = walk_chunks(weights.size, flat.shape[1])
     scales = _scales(weights, order)
     matrices = (w_gate, w_up, w_down)
 
@@ -478,7 +529,7 @@ def _share(flat, weights, experts, w_gate, w_up, w_down, act, first_held):
     the ``w_gate.shape[0]`` experts from ``first_held`` of the more
     that ``experts`` [N, k] chooses among."""
     held, nk = w_gate.shape[0], experts.size
-    rows, chunks = walk_chunks(nk)
+    rows, chunks = walk_chunks(nk, flat.shape[1])
     with jax.named_scope("moe.dispatch"):
         # held experts by their place here, every absent one last
         assigned = experts.reshape(nk) - first_held
@@ -509,6 +560,7 @@ def dropless_moe_mlp(
     logits: jax.Array = None,  # [batch, seq, experts] float32
     act: str = "silu",
     first_held: int = 0,
+    **routing,  # ``route_logits``' gate and bias
 ) -> Tuple[jax.Array, jax.Array]:
     """MoE gated block (``act(gate) * up``, then down) in which every
     one of the ``N x k`` assignments to an expert on this device is
@@ -558,7 +610,8 @@ def dropless_moe_mlp(
         if logits is None:
             logits = router_logits(flat, gate_w)
         weights, experts, aux = route_logits(
-            logits.reshape(n, e), k, norm_topk_prob, balance_coef, z_coef
+            logits.reshape(n, e), k, norm_topk_prob, balance_coef,
+            z_coef, **routing,
         )
     if held < e:
         out = _share(
@@ -602,13 +655,30 @@ def tokens_per_expert(
     return logits_per_expert(router_logits(x, gate_w), k)
 
 
-def logits_per_expert(logits: jax.Array, k: int) -> jax.Array:
-    """``tokens_per_expert`` from the router's logits [..., experts]."""
+def logits_per_expert(
+    logits: jax.Array, k: int, **routing
+) -> jax.Array:
+    """``tokens_per_expert`` from the router's logits [..., experts],
+    chosen as ``route_logits`` chooses (``routing``: its gate and
+    bias)."""
     e = logits.shape[-1]
     _, experts, _ = route_logits(
-        logits.reshape(-1, e), k, norm_topk_prob=False
+        logits.reshape(-1, e), k, norm_topk_prob=False, **routing
     )
     return expert_counts(experts, e)
+
+
+def bias_changed(
+    logits: jax.Array, k: int, bias: jax.Array, gate: str = "softmax"
+) -> jax.Array:
+    """How many of the ``tokens x k`` assignments the selection bias
+    changed: the experts among a token's top-k of score plus bias
+    that are not among its top-k of the score alone. int32 scalar."""
+    flat = logits.reshape(-1, logits.shape[-1])
+    _, biased, _ = route_logits(flat, k, False, gate=gate, bias=bias)
+    _, plain, _ = route_logits(flat, k, False, gate=gate)
+    kept = jnp.any(biased[:, :, None] == plain[:, None, :], axis=-1)
+    return jnp.sum(~kept, dtype=jnp.int32)
 
 
 def set_expert_load_gauges(counts) -> Tuple[float, float]:
@@ -663,20 +733,22 @@ def set_rows_held_gauge(counts, first_held: int, held: int) -> float:
     return share
 
 
-def set_chunks_walked_gauge(counts, first_held: int, held: int) -> float:
+def set_chunks_walked_gauge(counts, first_held: int, held: int,
+                            width: int) -> float:
     """From the same counts set the gauge ``moe_chunks_walked_share``
     {``chunk_rows``}: the chunks of a share's walk that are live (a
     layer's held rows rounded up to whole chunks, ``walk_chunks``)
     over all chunks, all layers together. 1 where every expert is
     here (one pass, no walk). Over ``moe_rows_held_share`` it is what
-    the chunk's rounding costs."""
+    the chunk's rounding costs. ``width``: the layer's rows' (the
+    model's hidden size), which sizes a chunk."""
     import numpy as np
 
     from dlrover_tpu.telemetry.registry import gauge
 
     load = np.asarray(counts, dtype=np.int64)
     load = load.reshape(-1, load.shape[-1])
-    rows, chunks = walk_chunks(int(load[0].sum()))
+    rows, chunks = walk_chunks(int(load[0].sum()), width)
     share = 1.0
     if held < load.shape[-1]:
         here = load[:, first_held:first_held + held].sum(axis=-1)
@@ -687,4 +759,24 @@ def set_chunks_walked_gauge(counts, first_held: int, held: int) -> float:
         "over all chunks, at the last evaluation",
         ("chunk_rows",),
     ).labels(chunk_rows=str(rows)).set(share)
+    return share
+
+
+def set_bias_changed_gauge(changed, assignments: int) -> float:
+    """From the assignments a selection bias changed, a layer
+    (``models.llama.bias_changed_stats``), of ``assignments`` a layer
+    set the gauge ``moe_bias_changed_share``: all layers together. 0
+    where the bias is flat; what a router's balance owes to its
+    bias."""
+    import numpy as np
+
+    from dlrover_tpu.telemetry.registry import gauge
+
+    changed = np.asarray(changed, dtype=np.float64)
+    share = float(changed.sum() / max(changed.size * assignments, 1))
+    gauge(
+        "moe_bias_changed_share",
+        "assignments the router's selection bias changed over all "
+        "tokens x k, at the last evaluation",
+    ).set(share)
     return share

@@ -364,8 +364,17 @@ def pipeline_llama_forward(
     cos, sin = llama.rope_tables(s, cfg.head_dim, cfg.rope_theta)
     x = params["embed"][tokens]
 
+    lead, (kind, *others) = cfg.layer_plan()
+    if lead or others:
+        raise ValueError(
+            "the pipeline's stages take a stack of like layers: this "
+            "configuration has a layer pattern or leading dense layers"
+        )
+
     def block_fn(x, layer_params):
-        return llama._block(cfg, x, layer_params, cos, sin, attn_fn)
+        return llama._block(
+            cfg, x, layer_params, cos, sin, attn_fn, kind=kind
+        )
 
     # honor the config's activation-checkpointing policy per block, same
     # as the un-pipelined llama.forward. "dots_attn_out" maps to "dots"

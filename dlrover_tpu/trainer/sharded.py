@@ -39,6 +39,10 @@ class ShardedTrainer:
       accum_steps: microbatches per optimizer update.
       batch_extra_axes: logical axes of batch dims after "batch"
         (e.g. ("seq",) for token arrays under sequence parallelism).
+      frozen: a tree of bools mirroring params, True at the leaves
+        the optimizer leaves as they are: its update there is
+        dropped, weight decay with it (a buffer kept among the
+        parameters, such as a router's selection bias).
     """
 
     def __init__(
@@ -52,6 +56,7 @@ class ShardedTrainer:
         accum_steps: int = 1,
         batch_extra_axes: Tuple[Optional[str], ...] = ("seq",),
         value_and_grad: Optional[Callable] = None,
+        frozen: Any = None,
     ):
         self.mesh = mesh
         self.rules = shd.get_rules(strategy)
@@ -63,6 +68,7 @@ class ShardedTrainer:
         # custom (params, batch) -> (loss, grads), e.g. optim.wsam's
         # sharpness-aware double evaluation
         self._value_and_grad = value_and_grad
+        self._frozen = frozen
         self.param_shardings = shd.tree_shardings(
             axes_tree, mesh, self.rules
         )
@@ -214,6 +220,11 @@ class ShardedTrainer:
                 updates, opt_state = self.optimizer.update(
                     grads, opt_state, params
                 )
+                if self._frozen is not None:
+                    updates = jax.tree.map(
+                        lambda u, still: jnp.zeros_like(u) if still else u,
+                        updates, self._frozen,
+                    )
                 params = optax.apply_updates(params, updates)
             return params, opt_state, loss
 
@@ -321,4 +332,5 @@ def make_trainer_for_llama(
     return ShardedTrainer(
         loss, init, llama.param_axes(cfg), mesh, strategy=strategy,
         optimizer=optimizer, accum_steps=accum_steps,
+        frozen=llama.frozen_params(cfg),
     )

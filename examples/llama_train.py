@@ -35,7 +35,8 @@ from dlrover_tpu.models import llama
 from dlrover_tpu.ops import tuning
 from dlrover_tpu.parallel.mesh import create_mesh
 from dlrover_tpu.parallel.moe import (
-    set_chunks_walked_gauge, set_expert_load_gauges, set_rows_held_gauge,
+    set_bias_changed_gauge, set_chunks_walked_gauge,
+    set_expert_load_gauges, set_rows_held_gauge,
 )
 from dlrover_tpu.trainer.checkpoint import FlashCheckpointer
 from dlrover_tpu.trainer.compile_cache import cache_events
@@ -207,11 +208,15 @@ def main():
         sharding=trainer.batch_sharding,
     )
 
-    routing_stats = None
+    routing_stats = bias_changed_stats = None
     if cfg.num_experts > 0:
         routing_stats = jax.jit(
             functools.partial(llama.routing_stats, cfg=cfg)
         )
+        if cfg.use_expert_bias:
+            bias_changed_stats = jax.jit(
+                functools.partial(llama.bias_changed_stats, cfg=cfg)
+            )
 
     device = devices[0]
     step, loss, losses = start_step, None, []
@@ -272,11 +277,19 @@ def main():
                     here = (cfg.moe_first_expert_held,
                             cfg.moe_experts_held)
                     held = set_rows_held_gauge(counts, *here)
-                    walked = set_chunks_walked_gauge(counts, *here)
-                    print(f"EXPERT_LOAD step={step} max/mean="
-                          f"{most:.3f} min/mean={least:.3f} "
-                          f"held={held:.3f} walked={walked:.3f}",
-                          flush=True)
+                    walked = set_chunks_walked_gauge(
+                        counts, *here, cfg.hidden_size
+                    )
+                    line = (f"EXPERT_LOAD step={step} max/mean="
+                            f"{most:.3f} min/mean={least:.3f} "
+                            f"held={held:.3f} walked={walked:.3f}")
+                    if bias_changed_stats is not None:
+                        changed = set_bias_changed_gauge(
+                            bias_changed_stats(params, mb[0][0]),
+                            mb[0][0].size * cfg.moe_top_k,
+                        )
+                        line += f" bias_changed={changed:.3f}"
+                    print(line, flush=True)
                 ckpt.save(
                     step,
                     {"params": params, "opt_state": opt_state,

@@ -682,7 +682,8 @@ def test_smallthinker_step_walks_its_share_in_chunks(
     kernels = re.findall(
         r"%([\w.\-]+) = (\w+\[[\d,]+\])[^\n]*"
         r"custom_call_target=\"tpu_custom_call\"", text)
-    chunk, held = moe.walk_chunks(rows)[0], cfg.moe_experts_held
+    chunk, held = (moe.walk_chunks(rows, cfg.hidden_size)[0],
+                   cfg.moe_experts_held)
     matmuls = [name for name, result in kernels
                if re.match(rf"\w+\[({chunk}|{held}),", result)]
     sums = [name for name, result in kernels if result.startswith(
@@ -690,6 +691,82 @@ def test_smallthinker_step_walks_its_share_in_chunks(
     assert len(matmuls) >= 9 and len(sums) >= 2
     assert all(KERNEL.search(name) for name in matmuls), matmuls
     assert not any(KERNEL.search(name) for name in sums), sums
+
+
+#: ``peak_memory_in_bytes`` of ``lfm2-8b-a1b-ep4.steady``'s step as
+#: this file compiles it (4 x 8,192, thirteen layers, remat
+#: ``minimal``, the loss unchunked; PERF.md, PR 36): 8.0 GB of it the
+#: state. At nine layers it plans 11.64 GB, which is the room the
+#: third period took; ``loss_chunk`` 2048 is refused at thirteen
+LFM2_STEP_BYTES = 15_468_132_864
+
+
+def test_lfm2_step_holds_the_convolutions_kernels(
+    topo, on_tpu_path, monkeypatch
+):
+    """``lfm2-8b-a1b-ep4.steady``'s step: it fits and plans no more
+    than was read when the cell was built; a conv layer's two Pallas
+    calls (the forward, twice under ``minimal``, and the backward
+    with the taps' gradient) are named as the benchmark's
+    ``short_conv_ms`` tells them, and as neither the attention's nor
+    the experts' readers do (a compiled step's instruction names are
+    a device trace's); the in-place float32 sums of the 2048 x 1792
+    experts' gradients take the tiles that fit (1024 x 896 of them
+    are refused); and every op of the convolution carries a
+    ``conv.*`` scope."""
+    from dlrover_tpu.ops import grouped_matmul as gm, short_conv
+    from dlrover_tpu.ops.pallas import short_conv as conv_kernels
+    from yardstick import cells, worker
+    from yardstick.layer_metrics import (
+        attn_kernel_ms, moe_expert_ms, short_conv_ms,
+    )
+
+    monkeypatch.setattr(gm, "_use_pallas", lambda lhs, rhs: True)
+    monkeypatch.setattr(gm, "_interpret", lambda: False)
+    monkeypatch.setattr(gm, "_add_on_mxu", lambda out, rows: True)
+    monkeypatch.setattr(short_conv, "_use_pallas", lambda bcu, w: True)
+    monkeypatch.setattr(conv_kernels, "_interpret", lambda: False)
+    _, config, traffic = cells.load_cell("lfm2-8b-a1b-ep4.steady")
+    cfg = worker.program_config(config, traffic)
+    assert (cfg.remat, cfg.loss_chunk) == ("minimal", 0)
+    mesh = Mesh(
+        np.array(topo.devices[:1]).reshape(1, 1), ("data", "fsdp"))
+    trainer = make_trainer_for_llama(
+        cfg, mesh, strategy=traffic["strategy"],
+        optimizer=optax.adamw(traffic["optimizer"]["learning_rate"]),
+    )
+    compiled = trainer.train_step.lower(*_abstract_step_args(
+        trainer, traffic["global_batch"], traffic["seq"])).compile()
+    assert compiled.memory_analysis().peak_memory_in_bytes <= (
+        LFM2_STEP_BYTES)
+    text = compiled.as_text()
+    kernels = re.findall(
+        r"%([\w.\-]+) = ([^\n]*?) custom-call\([^\n]*"
+        r"custom_call_target=\"tpu_custom_call\"[^\n]*"
+        r"op_name=\"([^\"]*)\"", text)
+    conv = [(name, op) for name, _, op in kernels
+            if short_conv_ms.KERNEL.search(name)]
+    # three conv positions of the period and the leading layer, each
+    # the forward, the forward again and the backward
+    assert len(conv) == 4 * 3, [name for name, _ in conv]
+    assert all("conv.mix" in op for _, op in conv)
+    others = [name for name, _, op in kernels if "conv.mix" not in op]
+    assert others and not any(
+        short_conv_ms.KERNEL.search(name) for name in others)
+    assert not any(
+        attn_kernel_ms.KERNEL.search(name)
+        or moe_expert_ms.KERNEL.search(name) for name, _ in conv)
+    assert sum(bool(attn_kernel_ms.KERNEL.search(n)) for n in others) == 4
+    assert sum(bool(moe_expert_ms.KERNEL.search(n)) for n in others) >= 9
+    # [batch, seq, 3 x hidden] and [batch, seq, hidden] of the
+    # convolution: whatever computes on them says whose op it is
+    wide = re.compile(r"= \w+\[4,8192,6144\]")
+    unscoped = [
+        line[:160] for line in text.splitlines()
+        if wide.search(line) and "op_name=" in line
+        and "conv." not in line and "fusion(" in line
+    ]
+    assert not unscoped
 
 
 def test_fsdp_step_lowers_over_four_chips(topo, on_tpu_path):

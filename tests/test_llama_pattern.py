@@ -151,7 +151,8 @@ def _loop_over_layers(params, batch, cfg):
         x, aux = llama._block(
             cfg, x, p, cos, sin,
             lambda q, k, v: mha_reference(q, k, v, window=window),
-            rope=bool(cfg.rope_layout[i]),
+            kind=llama.LayerKind(
+                rope=bool(cfg.rope_layout[i]), ffn="experts"),
         )
         aux_sum = aux_sum + aux
     x = llama.rms_norm(x, params["final_norm"], cfg.norm_eps)

@@ -25,6 +25,26 @@ def _objective(fn, k, norm):
     return objective
 
 
+def _chunks_of(monkeypatch, rows, width=TOKENS + 8):
+    """``rows`` a chunk at rows ``width`` wide (``_share_layer``'s
+    are 40): the two constants ``moe.walk_chunks`` sizes a chunk
+    from."""
+    monkeypatch.setattr(moe, "CHUNK_ROWS", rows)
+    monkeypatch.setattr(moe, "CHUNK_WIDTH", width)
+
+
+def test_a_chunk_is_sized_by_its_rows_bytes():
+    """``CHUNK_ROWS`` at the width they were timed at, as many more
+    as the rows are narrower, in whole tiles of 512; all the
+    assignments where they are fewer."""
+    rows, width = moe.CHUNK_ROWS, moe.CHUNK_WIDTH
+    assert moe.walk_chunks(12 * rows, width) == (rows, 12)
+    assert moe.walk_chunks(16 * rows, width * 4 // 5) == (rows * 5 // 4, 13)
+    assert moe.walk_chunks(16 * rows, 2 * width) == (rows // 2, 32)
+    assert moe.walk_chunks(131072, 3000) == (6656, 20)  # 6,990 in tiles
+    assert moe.walk_chunks(96, 64) == (96, 1)
+
+
 def _share_layer(held_rows, seed=11):
     """32 tokens whose top-3 of 8 experts are set by hand, so that
     exactly ``held_rows`` of the 96 assignments fall on experts
@@ -89,7 +109,7 @@ def test_a_shares_walk_equals_the_one_pass(monkeypatch, held_rows, what,
     """``out``, ``aux`` and the gradients of ``x``, the router and the
     held experts' three matrices, in chunks of 16 (six of them) and of
     20 (five, the last padded past the 96 assignments)."""
-    monkeypatch.setattr(moe, "CHUNK_ROWS", chunk)
+    _chunks_of(monkeypatch, chunk)
     args, chosen = _share_layer(held_rows)
     held = (chosen >= FIRST) & (chosen < FIRST + HELD)
     assert held.sum() == held_rows
@@ -140,7 +160,7 @@ def test_every_expert_here_is_one_pass_and_a_share_a_loop(monkeypatch):
     assert "cond[" not in whole and "scan[" not in whole
     share = text(_walked)
     assert share.count("scan[") == 2 and "cond[" in share
-    monkeypatch.setattr(moe, "CHUNK_ROWS", 16)
+    _chunks_of(monkeypatch, 16)
     assert text(moe.dropless_moe_mlp) == whole
     assert text(_walked) != share
 
@@ -156,13 +176,15 @@ def test_chunks_walked_gauge():
     counts[2, FIRST + 3] = rows + 1  # one row into a second
     counts[:, 7] = 2 * rows - counts[:, FIRST:FIRST + HELD].sum(-1)
     assert (counts.sum(-1) == 6 * rows).all()
-    share = moe.set_chunks_walked_gauge(counts, FIRST, HELD)
+    share = moe.set_chunks_walked_gauge(
+        counts, FIRST, HELD, moe.CHUNK_WIDTH)
     assert share == pytest.approx((0 + 1 + 2) / 18)
     from dlrover_tpu.telemetry.registry import default_registry
 
     assert (f'moe_chunks_walked_share{{chunk_rows="{rows}"}} {share}'
             in default_registry().to_prometheus_text())
-    assert moe.set_chunks_walked_gauge(counts, 0, E) == 1.0
+    assert moe.set_chunks_walked_gauge(
+        counts, 0, E, moe.CHUNK_WIDTH) == 1.0
     assert share >= moe.set_rows_held_gauge(counts, FIRST, HELD)
 
 
@@ -193,9 +215,9 @@ def test_an_experts_gradient_over_many_chunks_is_rounded_once(
             x, router, *(w[:1] for w in matrices), 1, True, logits=logits)
         return jnp.sum(jnp.sin(out.astype(jnp.float32)))
 
-    monkeypatch.setattr(moe, "CHUNK_ROWS", n)
+    _chunks_of(monkeypatch, n, h)
     whole = jax.grad(objective)(matrices)
-    monkeypatch.setattr(moe, "CHUNK_ROWS", chunk)
+    _chunks_of(monkeypatch, chunk, h)
     in_chunks = jax.grad(objective)(matrices)
     for name, got, want in zip(("w_gate", "w_up", "w_down"), in_chunks,
                                whole):
