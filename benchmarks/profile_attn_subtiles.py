@@ -11,15 +11,16 @@ edge is the whole-block body. Beside them, forward and forward-and-
 backward of what the file's own rule gives (``"rule": true``) and of
 the whole-block body at each smaller pair of grid blocks of
 ``--blocks``: what the grid's own skipping gives with no walk at all.
-And, without a group, the rule's kernels with the backward the other
-way than ``_one_backward_kernel`` has it for the shape: the dq and
-dk/dv pair (``"backward_kernels": 2``) against the one kernel that
-keeps a head's dQ in VMEM (``1``): how that rule's edge was read.
+And the rule's kernels with the backward the other way than
+``_one_backward_kernel`` has it for the shape: the dq and dk/dv pair
+(``"backward_kernels": 2``) against the one kernel (``1``) that keeps
+in VMEM a head's dQ without a group, a kv head's dK and dV with one:
+how that rule's edges were read.
 A shape with a window (``smallthinker``: 16,384 positions, 28 query
-heads on 4, the window 4096) is timed at the rule's blocks with the
-window and without it: what the blocks skipped under the band's lower
-edge save (``"window"`` in the row). Its batch of one sequence runs
-``--layers`` calls like the others.
+heads on 4, the window 4096) is timed at the rule's blocks, both
+backwards, with the window and without it: what the blocks skipped
+under the band's lower edge save (``"window"`` in the row). Its batch
+of one sequence runs ``--layers`` calls like the others.
 Each timed call runs ``--layers`` attention calls in one ``lax.scan``
 so that the host's clock times tens of milliseconds. Only a TPU run
 says anything: ``chiprun -- python3 benchmarks/profile_attn_subtiles.py``.
@@ -41,9 +42,9 @@ from dlrover_tpu.ops import tuning
 from dlrover_tpu.ops.pallas import flash_attention as fa
 
 #: name: (batch, seq, heads, kv_heads, head_dim) of a cell's step.
-#: Mistral's group is never sub-tiled (``flash_attention._fits``) and
-#: keeps two backward kernels: no edge is swept there. Its sub-tile
-#: row in PERF.md, and the rolled-loop, ``--unroll`` and
+#: A group is never sub-tiled (``flash_attention._fits``): no edge is
+#: swept at Mistral's, ``smallthinker``'s or ``lfm2``'s. Mistral's
+#: sub-tile row in PERF.md, and the rolled-loop, ``--unroll`` and
 #: ``--scratch-state`` readings there, came from earlier versions of
 #: this script and of the kernel file that are not in the tree
 SHAPES = {
@@ -51,6 +52,7 @@ SHAPES = {
     "olmoe": (3, 4096, 16, 16, 128),
     "mistral": (3, 4096, 32, 8, 128),
     "smallthinker": (1, 16384, 28, 4, 128),
+    "lfm2": (4, 8192, 32, 8, 64),
 }
 #: the window of a shape's windowed layers
 WINDOWS = {"smallthinker": 4096}
@@ -123,9 +125,10 @@ def main(argv=None):
         # (grid blocks, an edge a kernel or None for the file's rule,
         # backward kernels, what to time); the sweeps of an edge with
         # the backward as the pair they were read with
-        settings = [(blocks, whole, 2, both), (blocks, None, ruled, both)] + [
-            (blocks, None, 3 - ruled, both[1:])
-        ] * (group == 1) + [
+        settings = [
+            (blocks, whole, 2, both), (blocks, None, ruled, both),
+            (blocks, None, 3 - ruled, both[1:]),
+        ] + [
             (pair, whole, 2, both) for pair in smaller
         ] + [
             (blocks, dict(whole, **{kernel: sub}), 2,
@@ -135,7 +138,7 @@ def main(argv=None):
         ]
         windows = [None]
         if name in WINDOWS:  # the rule's kernels, without and with it
-            settings, windows = settings[1:2], [None, WINDOWS[name]]
+            settings, windows = settings[1:3], [None, WINDOWS[name]]
         for ((block_q, block_k), edges, kernels, keys), window in (
             (setting, window) for setting in settings for window in windows
         ):

@@ -22,11 +22,17 @@ q-blocks, accumulating over k-blocks) and a dK/dV kernel (grid over
 k-blocks, accumulating over q-blocks), with the softmax re-derived from
 the saved logsumexp. The two recompute the scores, the softmax and dS
 of every live block, seven products where the mathematics has five.
-Where a head's whole float32 dQ can stay in VMEM across the dK/dV
-kernel's grid (``_one_backward_kernel``: no group, and few enough
-positions), the backward is that kernel alone with one product more,
-dQ += dS K on the dS it has formed (``_dqkv_kernel``): every operand
-is read once and every score computed once.
+Where the smaller gradient can stay in VMEM in float32 across a head's
+grid steps (``_one_backward_kernel``), the backward is one kernel in
+which every operand is read once and every score computed once, and
+the larger gradient leaves a block at a time. Without a group the
+smaller one is a head's dQ: the dK/dV kernel with one product more,
+dQ += dS K on the dS it has formed (``_dqkv_kernel``). With a group dQ
+is g times a head's and the smaller ones are the kv head's dK and dV,
+[seq, head_dim] whatever g is: the dQ kernel with two products more,
+dK += dS^T Q and dV += P^T dO (``_dq_dkv_kernel``), which states the
+VMEM it needs (``_dkv_resident_vmem_bytes``). The pair is what a head
+too long for either budget keeps.
 
 What a causally live grid step computes (``_walk``). Blocks wholly
 above the diagonal are skipped by the grid (``pl.when`` + the clamped
@@ -128,9 +134,10 @@ def _stack_cols(ref, g, rows=slice(None)):
 
 def _sub_tiles(kernel, block_q, block_k, g, head_dim):
     """The edge of the square sub-tiles in which ``kernel`` ("fwd",
-    "dq", "dkv", or "dqkv": the dk/dv kernel that accumulates dQ too)
-    walks the (block_q, block_k) grid block on the diagonal; None where
-    it takes the block whole.
+    "dq", "dkv", "dqkv": the dk/dv kernel that accumulates dQ too, or
+    "dq_dkv": the dq kernel that accumulates dK and dV too, which only
+    a group runs) walks the (block_q, block_k) grid block on the
+    diagonal; None where it takes the block whole.
 
     As read on a v5e at the blocks ``ops/tuning.py heuristic_blocks``
     gives (``benchmarks/profile_attn_subtiles.py``; PERF.md section 6,
@@ -378,8 +385,8 @@ def _check_blocks(seq, block_q, block_k):
 
 def _kernel(body, name, seq, causal, g, block_q, block_k, head_dim, scale,
             window=None):
-    """``body`` ("fwd", "dq", "dkv" or "dqkv" by ``name``) with its
-    static arguments; building a causal one sets the census gauges.
+    """``body`` ("fwd", "dq", "dkv", "dqkv" or "dq_dkv" by ``name``)
+    with its static arguments; building a causal one sets the census gauges.
     A windowed one takes whole blocks (``_walk``)."""
     sub = None
     if causal:
@@ -498,7 +505,10 @@ def _ds(p, do, v, delta):
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                acc_scr, *, scale, causal, g, block_q, block_k, sub,
-               window=None):
+               add_dkv=None, window=None):
+    """``add_dkv(p, ds, q, do)``, where given, takes the softmax and
+    each dS the walk forms (cast for the products) on to dV and dK:
+    ``_dq_dkv_kernel``."""
     i = pl.program_id(1)
     j = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -516,12 +526,15 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             q_ref, do_ref, lse_ref, delta_ref, g, rows
         )
         p = _p(q, lse, k_ref[0, cols], scale, g, diagonal, window)
-        ds = _ds(p, do, v_ref[0, cols], delta)
+        ds = jax.lax.convert_element_type(
+            _ds(p, do, v_ref[0, cols], delta), k_ref.dtype
+        )
         acc_scr[rows] += jax.lax.dot_general(
-            jax.lax.convert_element_type(ds, k_ref.dtype), k_ref[0, cols],
-            (((1,), (0,)), ((), ())),
+            ds, k_ref[0, cols], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+        if add_dkv is not None:
+            add_dkv(p, ds, q, do)
 
     _walk(causal, block_q, block_k, sub, q_start, k_start, compute,
           window)
@@ -626,24 +639,101 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_ref[0, 0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
 
 
+def _dq_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                   dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
+                   *, scale, block_k, **static):
+    """The dQ kernel, and dK and dV of the whole kv head summed in
+    ``dk_scr`` and ``dv_scr`` [seq, d] over the grid's (i, j) steps of
+    one head: their output blocks do not move with them. The dual of
+    ``_dqkv_kernel``, for a group: g query heads' dQ is g times a
+    head's, dK and dV are a head's whatever g is. A key block meets
+    its query blocks in ascending i, as in ``_dkv_kernel``."""
+    i, j = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(jnp.logical_and(i == 0, j == 0))
+    def _init():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    def add_dkv(p, ds, q, do):
+        cols = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        # contracting over the g*block_q rows also sums the group; dK
+        # ahead of dV reads 2 to 3% faster than after it at every
+        # grouped cell's shape (PERF.md section 6, PR 37)
+        dk_scr[cols] += jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dv_scr[cols] += jax.lax.dot_general(
+            jax.lax.convert_element_type(p, do.dtype), do,
+            (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    _dq_kernel(
+        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr,
+        scale=scale, block_k=block_k, add_dkv=add_dkv, **static,
+    )
+
+    @pl.when(jnp.logical_and(
+        i == pl.num_programs(1) - 1, j == pl.num_programs(2) - 1
+    ))
+    def _finalize():
+        dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+
 #: what a head's float32 dQ may hold of VMEM beside the dK/dV kernel's
 #: own blocks and scratch: the largest that was compiled and timed
 #: (4096 positions of 128, under the default scoped limit)
 DQ_RESIDENT_BYTES = 2 * 1024 * 1024
+#: what a kv head's float32 dK and dV may hold of VMEM beside the dQ
+#: kernel's own blocks and scratch, rows padded to whole lanes: the
+#: largest that was compiled and timed (16,384 positions of 128; the
+#: call states what it needs, ``_dkv_resident_vmem_bytes``)
+DKV_RESIDENT_BYTES = 16 * 1024 * 1024
+
+
+def _lanes(head_dim):
+    """What a row of ``head_dim`` holds of VMEM: whole lanes."""
+    return -(-head_dim // LANES) * LANES
 
 
 def _one_backward_kernel(g, seq, head_dim):
-    """Whether the backward is ``_dqkv_kernel`` alone: no group (with
-    one, dQ is g times as large beside g times the slices a body, and
-    tracing a group's bodies cost a second of set-up: ``_sub_tiles``),
-    and the head's float32 dQ within ``DQ_RESIDENT_BYTES``.
+    """Whether the backward is one kernel, which keeps the smaller
+    gradient in VMEM over a head's grid steps and lets the larger
+    leave a block at a time. Without a group that is
+    ``_dqkv_kernel`` where the head's float32 dQ is within
+    ``DQ_RESIDENT_BYTES``; with one (dQ is g times a head's)
+    ``_dq_dkv_kernel`` where the kv head's float32 dK and dV are
+    within ``DKV_RESIDENT_BYTES``.
 
     As read on a v5e (``benchmarks/profile_attn_subtiles.py``, forward
-    and backward of a call; PERF.md section 6, PR 33): 4.68 ms against
-    the pair's 5.08 at one (1024, 1024) block a 64-wide head, 6.46
-    against 7.83 at 4 x 4 such blocks of a 128-wide head, where dQ's
-    rows are sliced at an offset from ``program_id``."""
-    return g == 1 and seq * head_dim * 4 <= DQ_RESIDENT_BYTES
+    and backward of a call; PERF.md section 6, PRs 33 and 37): 4.68 ms
+    against the pair's 5.08 at one (1024, 1024) block a 64-wide head,
+    6.46 against 7.83 at 4 x 4 such blocks of a 128-wide head, where
+    dQ's rows are sliced at an offset from ``program_id``."""
+    if g == 1:
+        return seq * head_dim * 4 <= DQ_RESIDENT_BYTES
+    return 2 * seq * _lanes(head_dim) * 4 <= DKV_RESIDENT_BYTES
+
+
+#: what ``_dq_dkv_kernel`` asks of VMEM beside the resident dK and dV,
+#: for everything the dQ kernel had (its streamed blocks, dQ's sum and
+#: a block's scores, which ``ops/tuning.py ROWS_CAP`` bounds). The
+#: chip's compiler allocates 2.5 to 9.1 MiB of it in the cells' steps,
+#: but the limit is not only a ceiling: with the default 16 MiB here
+#: ``mistral-7b-l4.steady`` read 0.15% under what it reads with 27
+#: (PERF.md section 6, PR 37)
+OTHER_VMEM_BYTES = 28 * 1024 * 1024
+
+
+def _dkv_resident_vmem_bytes(seq, head_dim, itemsize):
+    """What ``_dq_dkv_kernel`` asks of VMEM: the kv head's float32 dK
+    and dV and their whole-head output blocks (two buffers each), rows
+    of whole lanes, and ``OTHER_VMEM_BYTES``."""
+    return (2 * seq * _lanes(head_dim) * (4 + 2 * itemsize)
+            + OTHER_VMEM_BYTES)
 
 
 def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
@@ -658,42 +748,70 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
         o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1
     )[:, :, None, :]  # [bkh, g, 1, seq] (4-D for TPU block tiling)
 
-    one_kernel = _one_backward_kernel(g, seq, d)
+    form = "pair"
+    if _one_backward_kernel(g, seq, d):
+        form = "dq_resident" if g == 1 else "dkv_resident"
     gauge(
         "attn_backward_kernels",
         "Pallas kernels of the attention backward at the last one "
-        "built: 1 (dq, dk and dv together) or 2 (dq; dk and dv)",
-    ).set(1 if one_kernel else 2)
-    if not one_kernel:
-        dq_kernel = _kernel(
-            _dq_kernel, "dq", seq, causal, g, block_q, block_k, d, scale,
-            window,
+        "built of a form: 1 (dq, dk and dv together, the head's dq or "
+        "the kv head's dk and dv resident) or 2 (dq; dk and dv)",
+        labelnames=("form",),
+    ).labels(form=form).set(2 if form == "pair" else 1)
+
+    def build(body, name):
+        return _kernel(
+            body, name, seq, causal, g, block_q, block_k, d, scale, window
         )
+
+    def by_query_blocks(resident):
+        """The dq kernel's grid, (b, i, j): dq, and where the kv head's
+        dK and dV are ``resident`` those too: their whole [seq, d],
+        their blocks the same at every (i, j), written back once a
+        head. That call says what VMEM it takes (the default scoped
+        limit is 16 MiB of a v5e core's 128)."""
         kv_idx = _kv_index(causal, block_q, block_k, window)
-        in_specs_q = [
-            pl.BlockSpec((1, g, block_q, d), lambda b, i, j: (b, 0, i, 0)),
-            pl.BlockSpec((1, block_k, d), kv_idx),  # k
-            pl.BlockSpec((1, block_k, d), kv_idx),  # v
-            pl.BlockSpec((1, g, block_q, d), lambda b, i, j: (b, 0, i, 0)),
-            pl.BlockSpec((1, g, 1, block_q), lambda b, i, j: (b, 0, 0, i)),
-            pl.BlockSpec((1, g, 1, block_q), lambda b, i, j: (b, 0, 0, i)),
-        ]
-        dq = pl.pallas_call(
-            dq_kernel,
+
+        def q_idx(b, i, j):
+            return (b, 0, i, 0)
+
+        def lse_idx(b, i, j):
+            return (b, 0, 0, i)
+
+        return pl.pallas_call(
+            build(*((_dq_dkv_kernel, "dq_dkv") if resident
+                    else (_dq_kernel, "dq"))),
             grid=(bkh, seq // block_q, seq // block_k),
-            in_specs=in_specs_q,
-            out_specs=pl.BlockSpec(
-                (1, g, block_q, d), lambda b, i, j: (b, 0, i, 0)
-            ),
-            out_shape=jax.ShapeDtypeStruct((bkh, g, seq, d), q.dtype),
-            scratch_shapes=[pltpu.VMEM((g * block_q, d), jnp.float32)],
+            in_specs=[
+                pl.BlockSpec((1, g, block_q, d), q_idx),
+                pl.BlockSpec((1, block_k, d), kv_idx),  # k
+                pl.BlockSpec((1, block_k, d), kv_idx),  # v
+                pl.BlockSpec((1, g, block_q, d), q_idx),
+                pl.BlockSpec((1, g, 1, block_q), lse_idx),
+                pl.BlockSpec((1, g, 1, block_q), lse_idx),
+            ],
+            out_specs=[pl.BlockSpec((1, g, block_q, d), q_idx)] + resident * [
+                pl.BlockSpec((1, seq, d), lambda b, i, j: (b, 0, 0)),
+                pl.BlockSpec((1, seq, d), lambda b, i, j: (b, 0, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((bkh, g, seq, d), q.dtype),
+            ] + resident * [
+                jax.ShapeDtypeStruct((bkh, seq, d), k.dtype),
+                jax.ShapeDtypeStruct((bkh, seq, d), v.dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((g * block_q, d), jnp.float32),
+            ] + resident * [
+                pltpu.VMEM((seq, d), jnp.float32),
+                pltpu.VMEM((seq, d), jnp.float32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_dkv_resident_vmem_bytes(
+                    seq, d, q.dtype.itemsize)
+            ) if resident else None,
             interpret=_interpret(),
         )(q, k, v, do, lse, delta)
-
-    dkv_kernel = _kernel(
-        *((_dqkv_kernel, "dqkv") if one_kernel else (_dkv_kernel, "dkv")),
-        seq, causal, g, block_q, block_k, d, scale, window
-    )
 
     def q_side_idx(sublane):
         """Q/dO/lse/delta block index for dkv's (b, j, i) grid, clamped
@@ -713,39 +831,47 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
 
         return index
 
-    in_specs_kv = [
-        pl.BlockSpec((1, g, block_q, d), q_side_idx(True)),
-        pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),  # k
-        pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),  # v
-        pl.BlockSpec((1, g, block_q, d), q_side_idx(True)),
-        pl.BlockSpec((1, g, 1, block_q), q_side_idx(False)),
-        pl.BlockSpec((1, g, 1, block_q), q_side_idx(False)),
-    ]
-    # the one kernel's dQ: the head's whole [seq, d], its block the
-    # same at every (j, i), written back once a head
-    grads = pl.pallas_call(
-        dkv_kernel,
-        grid=(bkh, seq // block_k, seq // block_q),
-        in_specs=in_specs_kv,
-        out_specs=one_kernel * [
-            pl.BlockSpec((1, 1, seq, d), lambda b, j, i: (b, 0, 0, 0)),
-        ] + [
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=one_kernel * [
-            jax.ShapeDtypeStruct((bkh, 1, seq, d), q.dtype),
-        ] + [
-            jax.ShapeDtypeStruct((bkh, seq, d), k.dtype),
-            jax.ShapeDtypeStruct((bkh, seq, d), v.dtype),
-        ],
-        scratch_shapes=one_kernel * [pltpu.VMEM((seq, d), jnp.float32)] + [
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-        ],
-        interpret=_interpret(),
-    )(q, k, v, do, lse, delta)
-    return grads if one_kernel else (dq, *grads)
+    def by_key_blocks(resident):
+        """The dk/dv kernel's grid, (b, j, i): dk and dv, and ahead of
+        them where the head's dQ is ``resident`` that too: its whole
+        [seq, d], its block the same at every (j, i), written back once
+        a head."""
+        return pl.pallas_call(
+            build(*((_dqkv_kernel, "dqkv") if resident
+                    else (_dkv_kernel, "dkv"))),
+            grid=(bkh, seq // block_k, seq // block_q),
+            in_specs=[
+                pl.BlockSpec((1, g, block_q, d), q_side_idx(True)),
+                pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),  # k
+                pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),  # v
+                pl.BlockSpec((1, g, block_q, d), q_side_idx(True)),
+                pl.BlockSpec((1, g, 1, block_q), q_side_idx(False)),
+                pl.BlockSpec((1, g, 1, block_q), q_side_idx(False)),
+            ],
+            out_specs=resident * [
+                pl.BlockSpec((1, 1, seq, d), lambda b, j, i: (b, 0, 0, 0)),
+            ] + [
+                pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+                pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+            ],
+            out_shape=resident * [
+                jax.ShapeDtypeStruct((bkh, 1, seq, d), q.dtype),
+            ] + [
+                jax.ShapeDtypeStruct((bkh, seq, d), k.dtype),
+                jax.ShapeDtypeStruct((bkh, seq, d), v.dtype),
+            ],
+            scratch_shapes=resident * [pltpu.VMEM((seq, d), jnp.float32)] + [
+                pltpu.VMEM((block_k, d), jnp.float32),
+                pltpu.VMEM((block_k, d), jnp.float32),
+            ],
+            interpret=_interpret(),
+        )(q, k, v, do, lse, delta)
+
+    if form == "dkv_resident":
+        return by_query_blocks(True)
+    if form == "dq_resident":
+        return by_key_blocks(True)
+    return (*by_query_blocks(False), *by_key_blocks(False))
 
 
 # ---------------------------------------------------------------------------

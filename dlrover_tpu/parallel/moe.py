@@ -481,11 +481,17 @@ def _walk_bwd(act, args, g):
         )
         with jax.named_scope("moe.dispatch"):
             mine = flat[tokens]
-        with jax.named_scope("moe.combine"):
-            cotangent = g[tokens]
         (gate, up), to_rows = jax.vjp(
             lambda r: _products(r, (w_gate, w_up), sizes), mine
         )
+        # ``g``'s rows are gathered once both products of ``mine`` are
+        # made: the two gathers depend on nothing of each other, and the
+        # chip's scheduler, left the choice, has put ``g``'s between
+        # the two products, a chunk's rows more alive at the step's
+        # planned peak (PERF.md section 6, PR 37)
+        g_then, gate, up = jax.lax.optimization_barrier((g, gate, up))
+        with jax.named_scope("moe.combine"):
+            cotangent = g_then[tokens]
         hidden, to_products = jax.vjp(
             functools.partial(_gated, act=act), gate, up, scales[chosen]
         )

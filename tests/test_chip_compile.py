@@ -281,15 +281,29 @@ def test_flash_kernel_compiles_at_llama_1b_shape(
 
 #: [batch, seq, heads, kv_heads, head_dim] of the attention calls the
 #: yardstick's cells make: gpt2-xl at 12 x 1024, OLMoE and Mistral at
-#: 3 x 4096 (the fsdp4 cell runs Mistral's with one sequence a chip)
+#: 3 x 4096 (the fsdp4 cell runs Mistral's with one sequence a chip),
+#: lfm2 at 4 x 8192, smallthinker at 1 x 16384
 CELL_ATTENTION = {
     "gpt2-xl": (12, 1024, 25, 25, 64),
     "olmoe": (3, 4096, 16, 16, 128),
     "mistral": (3, 4096, 32, 8, 128),
+    "lfm2": (4, 8192, 32, 8, 64),
+    "smallthinker": (1, 16384, 28, 4, 128),
 }
-#: the backward's kernels there: one where a head's float32 dQ stays
-#: in VMEM (256 KB, 2 MB), the dq and dk/dv pair at Mistral's group
-CELL_BACKWARD_KERNELS = {"gpt2-xl": 1, "olmoe": 1, "mistral": 2}
+#: the backward is one kernel at each: a head's float32 dQ stays in
+#: VMEM without a group (256 KB, 2 MB), a kv head's float32 dK and dV
+#: with one (4, 8 and 16 MB)
+CELL_BACKWARD_FORM = {
+    "gpt2-xl": "dq_resident", "olmoe": "dq_resident",
+    "mistral": "dkv_resident", "lfm2": "dkv_resident",
+    "smallthinker": "dkv_resident",
+}
+#: what the grouped backward call asks of a v5e core's 128 MiB of VMEM
+#: (``jax/_src/pallas/mosaic/tpu_info.py``): the kv head's float32 dK
+#: and dV and two buffers of each one's output block, 8, 16 and 32
+#: MiB, and ``OTHER_VMEM_BYTES``, 28, for everything else
+CELL_BACKWARD_VMEM = {"mistral": 36 * 2 ** 20, "lfm2": 44 * 2 ** 20,
+                      "smallthinker": 60 * 2 ** 20}
 
 
 def _sum_grad(attn):
@@ -304,6 +318,7 @@ def _sum_grad(attn):
 #: backward compile holds as well
 @pytest.mark.parametrize("cell,grad", [
     ("gpt2-xl", False), ("gpt2-xl", True), ("olmoe", True), ("mistral", True),
+    ("lfm2", True), ("smallthinker", True),
 ], ids=lambda v: v if isinstance(v, str) else ("fwd", "fwd_bwd")[v])
 def test_sub_tiled_kernels_compile_at_the_cells_shapes(
     topo, on_tpu_path, cell, grad
@@ -314,10 +329,11 @@ def test_sub_tiled_kernels_compile_at_the_cells_shapes(
     backward kernels, of the lanes of the logsumexp."""
     batch, seq, heads, kv_heads, d = CELL_ATTENTION[cell]
     bq, bk = tuning.heuristic_blocks(seq, heads // kv_heads)
-    # a group is not sub-tiled: Mistral's kernels are the whole-block ones
-    assert (heads > kv_heads) != any(
-        fa._sub_tiles(kernel, bq, bk, heads // kv_heads, d)
-        for kernel in ("fwd", "dq", "dkv")
+    group = heads // kv_heads
+    # a group is not sub-tiled: its kernels are the whole-block ones
+    assert (group > 1) != any(
+        fa._sub_tiles(kernel, bq, bk, group, d)
+        for kernel in ("fwd", "dq", "dkv", "dq_dkv")
     )
 
     def attn(q, k, v):
@@ -334,10 +350,20 @@ def test_sub_tiled_kernels_compile_at_the_cells_shapes(
     fn = _sum_grad(attn) if grad else attn
     text = jax.jit(fn).lower(q, kv, kv).compile().as_text()
     # the one backward kernel holds OLMoE's whole dQ (2 MB of float32
-    # beside a [4096, 128] output block) under the default VMEM limit
+    # beside a [4096, 128] output block) under the default VMEM limit;
+    # a kv head's dK and dV with their whole-head output blocks take
+    # more, and the call says how much
     assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) == (
-        1 + CELL_BACKWARD_KERNELS[cell] if grad else 1
+        2 if grad else 1
     )
+    asked = [int(n) for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             for n in re.findall(
+                 r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', line)]
+    default = 16 * 2 ** 20  # written out beside a call that asks
+    asked = [n for n in asked if n != default]
+    assert asked == ([CELL_BACKWARD_VMEM[cell]] if grad and group > 1
+                     else [])
 
 
 @pytest.mark.parametrize("window", [None, 4096], ids=["full", "window"])
@@ -346,9 +372,10 @@ def test_windowed_kernels_compile_at_smallthinkers_shape(
 ):
     """One 16,384-token sequence, 28 query heads on 4 of 128: the
     rule's (128, 1024) blocks fold a group of 7 into 896 rows, no
-    power of two; the forward, dq and dk/dv kernels with the window's
-    masks and both-ended index clamps, and without."""
-    batch, seq, heads, kv_heads, d = 1, 16384, 28, 4, 128
+    power of two; the forward kernel and the one backward kernel (a kv
+    head's float32 dK and dV, 16 MB, resident) with the window's masks
+    and both-ended index clamps, and without."""
+    batch, seq, heads, kv_heads, d = CELL_ATTENTION["smallthinker"]
     assert tuning.heuristic_blocks(seq, heads // kv_heads) == (128, 1024)
 
     def attn(q, k, v):
@@ -364,7 +391,7 @@ def test_windowed_kernels_compile_at_smallthinkers_shape(
     )
     text = jax.jit(_sum_grad(attn)).lower(q, kv, kv).compile().as_text()
     assert len(
-        re.findall(r'custom_call_target="tpu_custom_call"', text)) == 3
+        re.findall(r'custom_call_target="tpu_custom_call"', text)) == 2
 
 
 def _lowered_kernels(fn, *args):
@@ -422,8 +449,12 @@ def test_dispatch_lowers_the_rules_kernels_whatever_the_environment(
         lambda q, k, v: fa.flash_attention_tpu(
             q, k, v, causal=True, block_q=bq, block_k=bk)
     ), q, kv, kv)
-    # a Mosaic module for the forward and one a backward kernel
-    assert len(want[1]) == 1 + CELL_BACKWARD_KERNELS[cell], len(want[1])
+    # a Mosaic module for the forward and one for the backward kernel
+    backward = {"dq_resident": "_dqkv_kernel",
+                "dkv_resident": "_dq_dkv_kernel"}
+    assert re.findall(r'kernel_name = "(\w+)"', want[0]) == [
+        "_fwd_kernel", backward[CELL_BACKWARD_FORM[cell]]]
+    assert len(want[1]) == 2, len(want[1])
 
     def refuse(*args, **kwargs):
         raise AssertionError("a thread or jax.clear_caches()")
@@ -479,6 +510,13 @@ def _kernel_sizes(causal, edge, backward, monkeypatch):
     """Equation counts of the forward and the backward kernels'
     jaxprs."""
     fn, q = _one_head(causal, edge, backward, monkeypatch)
+    sizes = _pallas_call_sizes(fn, q, q, q)
+    assert len(sizes) == 1 + backward, sizes
+    return sizes
+
+
+def _pallas_call_sizes(fn, *args):
+    """Equation counts of the kernels in ``fn``'s jaxpr, in order."""
     sizes = []
 
     def find(jaxpr):
@@ -490,8 +528,7 @@ def _kernel_sizes(causal, edge, backward, monkeypatch):
                 if hasattr(getattr(value, "jaxpr", value), "eqns"):
                     find(value)
 
-    find(jax.make_jaxpr(fn)(q, q, q))
-    assert len(sizes) == 1 + backward, sizes
+    find(jax.make_jaxpr(fn)(*args))
     return sizes
 
 
@@ -539,6 +576,36 @@ def test_kernel_jaxpr_grows_with_the_edge_not_the_sub_tiles(
         # mask and the test of whether the block is live
         assert whole < 1.5 * plain[k], (at, plain)
         assert a < 10 * plain[k], (at, plain)
+
+
+def _grouped_kernel_sizes(g, window):
+    """Equation counts of the forward and the backward kernels' jaxprs
+    at a group's whole blocks, two (256, 1024) blocks across."""
+    fn = _sum_grad(
+        lambda q, k, v: fa.flash_attention_tpu(
+            q, k, v, causal=True, block_q=256, block_k=1024, window=window)
+    )
+    q, kv = (jax.ShapeDtypeStruct((1, 2048, h, 128), jnp.bfloat16)
+             for h in (g, 1))
+    return _pallas_call_sizes(fn, q, kv, kv)
+
+
+@pytest.mark.parametrize("window", [None, 512], ids=["full", "window"])
+@pytest.mark.parametrize("g", [4, 7])
+def test_grouped_backward_traces_the_dq_body_and_two_products(
+    monkeypatch, g, window
+):
+    """What a group's one backward kernel adds to every process's
+    set-up: the dq kernel's body with two products and two sums more
+    in it (with a window, in each of its two bodies), not the dk/dv
+    kernel's slices and scores a second time: a third fewer equations
+    than the pair it stands in for (113 against 79 + 81 at Mistral's
+    group, 229 against 184 + 184 at smallthinker's with the window)."""
+    _, one = _grouped_kernel_sizes(g, window)
+    monkeypatch.setattr(fa, "_one_backward_kernel", lambda g, seq, d: False)
+    _, dq, dkv = _grouped_kernel_sizes(g, window)
+    assert dq < one < dq + 0.5 * dkv, (one, dq, dkv)
+    assert one < 0.75 * (dq + dkv), (one, dq, dkv)
 
 
 @BACKWARDS
@@ -628,7 +695,15 @@ def test_llama_1b_step_fits_one_chip_at_batch_3(topo, on_tpu_path):
 #: layer alone plans 1.15 GB where the one pass planned 2.17), but
 #: its backward loop carries the three matrices' gradient sums of a
 #: layer in float32, 0.38 GB that live through the loop, and the
-#: step plans 4.6% more than it did
+#: step plans 4.6% more than it did. Since PR 37 the walk's backward
+#: gathers the cotangent's rows once both products of the tokens' rows
+#: are made (``parallel/moe.py _walk_bwd``), and the step plans
+#: 16,244,227,584 whichever form the attention backward takes; left
+#: the choice, the chip's scheduler put that gather between the two
+#: products in the last layer it walks once the attention backward
+#: was one call (its tie is broken by instruction names, and the
+#: walk's five grouped matmuls were then ``tpu_custom_call.98`` to
+#: ``.102``), and planned 16,298,763,776
 SMALLTHINKER_STEP_BYTES = {"one pass": 15_542_064_128,
                            "walk": 16_256_820_736}
 
@@ -756,7 +831,9 @@ def test_lfm2_step_holds_the_convolutions_kernels(
     assert not any(
         attn_kernel_ms.KERNEL.search(name)
         or moe_expert_ms.KERNEL.search(name) for name, _ in conv)
-    assert sum(bool(attn_kernel_ms.KERNEL.search(n)) for n in others) == 4
+    # a period's attention layer: the forward, the forward again and
+    # the one backward kernel
+    assert sum(bool(attn_kernel_ms.KERNEL.search(n)) for n in others) == 3
     assert sum(bool(moe_expert_ms.KERNEL.search(n)) for n in others) >= 9
     # [batch, seq, 3 x hidden] and [batch, seq, hidden] of the
     # convolution: whatever computes on them says whose op it is

@@ -196,18 +196,29 @@ def test_census_at_gpt2_xl_and_its_gauges():
             assert float(line.split()[1]) == value
 
 
-# (seq, block, head_dim, causal) without a group, where the backward
-# is one kernel: one block a head and several (the resident dQ sums
-# over the key blocks a query block meets), both head widths, and at
-# blocks of 512 the diagonal walked in sub-tiles of 256
+# (seq, block_q, block_k, g, head_dim, causal, window) where the
+# backward is one kernel. Without a group (the head's dQ resident): one
+# block a head and several (the resident dQ sums over the key blocks a
+# query block meets), both head widths, and at blocks of 512 the
+# diagonal walked in sub-tiles of 256. With a group (the kv head's dK
+# and dV resident, summed over the query blocks a key block meets):
+# groups of 4 and 7, both head widths, equal and unequal blocks, more
+# than one each way (four query blocks on two key blocks at 256
+# positions), without a window and with one that leaves the last
+# query block's first key block dead and crosses the one before
 ONE_BACKWARD_KERNEL = [
-    (256, 256, 64, True),
-    (256, 256, 128, False),
-    (512, 128, 64, True),
-    (512, 128, 128, True),
-    (512, 128, 64, False),
-    (512, 512, 128, True),
-    (1024, 512, 64, True),
+    (256, 256, 256, 1, 64, True, None),
+    (256, 256, 256, 1, 128, False, None),
+    (512, 128, 128, 1, 64, True, None),
+    (512, 128, 128, 1, 128, True, None),
+    (512, 128, 128, 1, 64, False, None),
+    (512, 512, 512, 1, 128, True, None),
+    (1024, 512, 512, 1, 64, True, None),
+    (256, 64, 128, 4, 64, True, None),
+    (256, 64, 128, 4, 128, True, 60),
+    (256, 64, 128, 7, 128, True, None),
+    (256, 64, 128, 7, 64, True, 44),
+    (256, 128, 128, 4, 64, False, None),
 ]
 
 
@@ -239,15 +250,19 @@ def _grads(attn, q, k, v):
 def one_backward_kernel(request):
     """dq, dk, dv of the one backward kernel, of the dq and dk/dv pair
     at the same blocks, and of the reference, once a case."""
-    seq, block, d, causal = request.param
-    q, k, v = _rand_qkv(jax.random.key(7), 2, seq, 2, 2, d)
+    seq, block_q, block_k, g, d, causal, window = request.param
+    # two kv heads a sequence: the resident scratch is zeroed anew
+    # where the grid moves to the next head
+    q, k, v = _rand_qkv(
+        jax.random.key(7), 2 if g == 1 else 1, seq, 2 * g, 2, d)
 
     def attn(q, k, v):
         return flash_attention_tpu(
-            q, k, v, causal=causal, block_q=block, block_k=block)
+            q, k, v, causal=causal, block_q=block_q, block_k=block_k,
+            window=window)
 
     assert _kernels_of_grads(attn, q, k, v) == [
-        "_fwd_kernel", "_dqkv_kernel"]
+        "_fwd_kernel", "_dqkv_kernel" if g == 1 else "_dq_dkv_kernel"]
     one = _grads(attn, q, k, v)
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(
@@ -255,8 +270,9 @@ def one_backward_kernel(request):
         assert _kernels_of_grads(attn, q, k, v) == [
             "_fwd_kernel", "_dq_kernel", "_dkv_kernel"]
         pair = _grads(attn, q, k, v)
-    ref = _grads(lambda q, k, v: mha_reference(q, k, v, causal=causal),
-                 q, k, v)
+    ref = _grads(
+        lambda q, k, v: mha_reference(q, k, v, causal=causal, window=window),
+        q, k, v)
     return tuple(dict(zip(("dq", "dk", "dv"), g)) for g in (one, pair, ref))
 
 
@@ -269,33 +285,40 @@ def test_one_backward_kernel_matches_reference_and_the_pair(
         one[what], ref[what], rtol=5e-3, atol=5e-3,
         err_msg=f"{what} against the reference",
     )
-    # the same products in the same order, and dQ's sums over key
-    # blocks in the dq kernel's order: float32 agrees to rounding
-    np.testing.assert_allclose(
-        one[what], pair[what], rtol=1e-6, atol=1e-6,
+    # the same products on the same values, dQ's sums over key blocks
+    # in the dq kernel's order and dK's and dV's over query blocks in
+    # the dk/dv kernel's: float32 agrees to the last bit
+    np.testing.assert_array_equal(
+        one[what], pair[what],
         err_msg=f"{what} against the dq and dk/dv kernels",
     )
 
 
-def _backward_kernels_gauge():
+def _backward_kernels_gauge(form):
+    """The gauge's child under ``form``, set anew by the next backward
+    built of that form."""
     from dlrover_tpu.telemetry.registry import default_registry
 
-    return default_registry().get("attn_backward_kernels").value
+    return default_registry().get(
+        "attn_backward_kernels").labels(form=form)
 
 
-@pytest.mark.parametrize("g,kernels", [
-    (1, ["_fwd_kernel", "_dqkv_kernel"]),
-    (4, ["_fwd_kernel", "_dq_kernel", "_dkv_kernel"]),
+@pytest.mark.parametrize("g,kernel,form", [
+    (1, "_dqkv_kernel", "dq_resident"),
+    (4, "_dq_dkv_kernel", "dkv_resident"),
+    (7, "_dq_dkv_kernel", "dkv_resident"),
 ])
-def test_a_group_keeps_two_backward_kernels(g, kernels):
+def test_a_group_keeps_its_kv_heads_gradients_resident(g, kernel, form):
     q, k, v = _rand_qkv(jax.random.key(8), 1, 256, g, 1, 64)
 
     def attn(q, k, v):
         return flash_attention_tpu(
             q, k, v, causal=True, block_q=128, block_k=128)
 
-    assert _kernels_of_grads(attn, q, k, v) == kernels
-    assert _backward_kernels_gauge() == len(kernels) - 1
+    _grads(attn, q, k, v)  # the gauge is there once a backward was built
+    _backward_kernels_gauge(form).set(0)
+    assert _kernels_of_grads(attn, q, k, v) == ["_fwd_kernel", kernel]
+    assert _backward_kernels_gauge(form).value == 1
     for got, want in zip(
         _grads(attn, q, k, v),
         _grads(lambda q, k, v: mha_reference(q, k, v, causal=True), q, k, v),
@@ -303,19 +326,38 @@ def test_a_group_keeps_two_backward_kernels(g, kernels):
         np.testing.assert_allclose(got, want, rtol=5e-3, atol=5e-3)
 
 
-def test_a_head_too_long_for_vmem_keeps_two_backward_kernels(monkeypatch):
-    """The rule reads shapes: the head's float32 dQ against the budget."""
+@pytest.mark.parametrize("g", [1, 4])
+def test_a_head_too_long_for_vmem_keeps_two_backward_kernels(monkeypatch, g):
+    """The rule reads shapes: without a group the head's float32 dQ
+    against its budget; with one the kv head's float32 dK and dV, rows
+    of whole lanes, against theirs, whatever the group is."""
     rule = flash_attention._one_backward_kernel
     budget = flash_attention.DQ_RESIDENT_BYTES
     assert rule(1, 1024, 64) and rule(1, 4096, 128)  # gpt2-xl, OLMoE
-    assert not rule(4, 4096, 128)  # Mistral
     assert rule(1, budget // (4 * 128), 128)
     assert not rule(1, 2 * budget // (4 * 128), 128)
+    # Mistral, lfm2, smallthinker, chip_smoke.py's llama_1b
+    assert rule(4, 4096, 128) and rule(4, 8192, 64)
+    assert rule(7, 16384, 128) and rule(8, 2048, 64)
+    budget = flash_attention.DKV_RESIDENT_BYTES
+    for group in (2, 7, 16):
+        assert rule(group, budget // (2 * 4 * 128), 128)
+        assert not rule(group, 2 * budget // (2 * 4 * 128), 128)
+        # a 64-wide row holds a whole lane row of VMEM
+        assert rule(group, budget // (2 * 4 * 128), 64)
+        assert not rule(group, 2 * budget // (2 * 4 * 128), 64)
     monkeypatch.setattr(flash_attention, "DQ_RESIDENT_BYTES", 256 * 64 * 4)
-    q, k, v = _rand_qkv(jax.random.key(9), 1, 512, 1, 1, 64)
+    monkeypatch.setattr(
+        flash_attention, "DKV_RESIDENT_BYTES", 2 * 256 * 128 * 4)
+    q, k, v = _rand_qkv(jax.random.key(9), 1, 512, g, 1, 64)
     assert _kernels_of_grads(
         functools.partial(flash_attention_tpu, block_q=128, block_k=128),
         q, k, v,
     ) == ["_fwd_kernel", "_dq_kernel", "_dkv_kernel"]
-    assert _backward_kernels_gauge() == 2
+    assert _backward_kernels_gauge("pair").value == 2
+    # at the edge: 256 positions are within both
+    assert _kernels_of_grads(
+        functools.partial(flash_attention_tpu, block_q=128, block_k=128),
+        q[:, :256], k[:, :256], v[:, :256],
+    )[1:] == ["_dqkv_kernel" if g == 1 else "_dq_dkv_kernel"]
 
