@@ -4,6 +4,7 @@ Prints ONE JSON line::
 
     {"metric": "trace_overhead_disabled_ns", "value": N, "unit": "ns",
      "disabled_ns": N, "enabled_ring_ns": N, "enabled_file_ns": N,
+     "gc_hook_ns": N, "gc_span_file_ns": N, "watchdog_tick_ns": N,
      "pass_lt_1us_disabled": true, ...}
 
 The budget that matters is the DISABLED path: span sites stay wired
@@ -12,11 +13,19 @@ into the train step, RPC handler, and checkpoint lanes permanently, so
 module-global check plus a shared no-op context manager — no
 allocation). The enabled numbers size what turning tracing on costs
 per span: ring-only (a dict build + deque append) and write-through
-(one ``os.write`` of a JSON line).
+(one ``os.write`` of a JSON line). Beside them, what ISSUE 38 added:
+``gc_hook_ns``, what the collector's hook adds to one collection that
+leaves no span (a quick one of the youngest generation: two calls, two
+clock readings, a profiler annotation where jax is imported: not
+here); ``gc_span_file_ns``, what it adds to one that does (generation
+1, written through); and ``watchdog_tick_ns``, one check of the hang
+detector's watchdog while no step is late (it wakes one to four times
+a step, on its own thread, whether tracing is on or off).
 
 No jax import — this measures pure-Python overhead.
 """
 
+import gc
 import json
 import os
 import shutil
@@ -24,6 +33,7 @@ import sys
 import tempfile
 import time
 
+from dlrover_tpu.fault_tolerance.hanging_detector import HangingDetector
 from dlrover_tpu.telemetry import tracing
 
 
@@ -45,6 +55,51 @@ def _spin_enabled(n: int):
     for _ in range(n):
         with span("bench.enabled"):
             pass
+
+
+def _collect(generation: int):
+    def spin(n: int):
+        collect = gc.collect
+        for _ in range(n):
+            collect(generation)
+    return spin
+
+
+def _gc_hook_ns(generation: int, n: int, trace_dir=None) -> float:
+    """What the hook adds to one ``gc.collect(generation)``: on less
+    off, the collector's own automatic runs held off meanwhile."""
+    gc.collect()
+    gc.disable()
+    try:
+        tracing.disable()
+        _collect(generation)(1_000)
+        off = _per_call_ns(n, _collect(generation))
+        tracing.enable(trace_dir=trace_dir, capacity=4096)
+        _collect(generation)(1_000)
+        on = _per_call_ns(n, _collect(generation))
+        tracing.disable()
+    finally:
+        gc.enable()
+    return on - off
+
+
+def _watchdog_tick_ns(n: int) -> float:
+    """One ``_check_once`` of a detector that knows its cadence (a
+    full history of 50 durations) while the next step is not late."""
+    now = [1000.0]
+    det = HangingDetector(clock=lambda: now[0])
+    for step in range(51):
+        det.record_step(step)
+        now[0] += 0.34
+
+    def spin(k: int):
+        check = det._check_once
+        for _ in range(k):
+            check()
+
+    now[0] -= 0.2
+    spin(1_000)
+    return _per_call_ns(n, spin)
 
 
 def main() -> int:
@@ -69,6 +124,7 @@ def main() -> int:
         span_files = [
             f for f in os.listdir(tmp) if f.startswith("spans-")
         ]
+        gc_span_file_ns = _gc_hook_ns(1, 20_000, trace_dir=tmp)
     finally:
         shutil.rmtree(tmp, ignore_errors=True)
 
@@ -79,6 +135,9 @@ def main() -> int:
         "disabled_ns": round(disabled_ns, 1),
         "enabled_ring_ns": round(ring_ns, 1),
         "enabled_file_ns": round(file_ns, 1),
+        "gc_hook_ns": round(_gc_hook_ns(0, 100_000), 1),
+        "gc_span_file_ns": round(gc_span_file_ns, 1),
+        "watchdog_tick_ns": round(_watchdog_tick_ns(100_000), 1),
         "pass_lt_1us_disabled": disabled_ns < 1000.0,
         "span_files_written": len(span_files),
         "python": sys.version.split()[0],

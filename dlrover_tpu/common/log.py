@@ -20,7 +20,10 @@ _FORMAT = (
     "[%(filename)s:%(lineno)d]%(proc_tag)s %(message)s"
 )
 
-_proc_lock = threading.Lock()
+#: reentrant: a span's record asks for the index, and the collector's
+#: hook (telemetry/tracing.py) can write a span from inside any
+#: allocation of the thread it interrupts, this lock's holder included
+_proc_lock = threading.RLock()
 _process_index: Optional[int] = None
 
 

@@ -81,10 +81,17 @@ def crash_dir() -> str:
 # ------------------------------------------------------------ thread stacks
 
 
-def thread_stacks() -> List[Dict[str, Any]]:
+def thread_stacks(main_only: bool = False,
+                  limit: Optional[int] = None) -> List[Dict[str, Any]]:
     """Every live thread's Python stack, outermost frame first. The
-    view a hang needs: which lock/join/RPC each thread is parked on."""
+    view a hang needs: which lock/join/RPC each thread is parked on.
+    ``main_only`` keeps the main thread alone and ``limit`` the
+    innermost frames of each: what the hang detector's watchdog
+    samples while a step is late."""
     frames = sys._current_frames()
+    if main_only:
+        ident = threading.main_thread().ident
+        frames = {i: f for i, f in frames.items() if i == ident}
     by_ident = {t.ident: t for t in threading.enumerate()}
     stacks = []
     for ident, frame in frames.items():
@@ -95,7 +102,7 @@ def thread_stacks() -> List[Dict[str, Any]]:
             "daemon": bool(th.daemon) if th else None,
             "stack": [
                 line.rstrip("\n")
-                for line in traceback.format_stack(frame)
+                for line in traceback.format_stack(frame, limit)
             ],
         })
     stacks.sort(key=lambda s: (s["name"] != "MainThread", s["name"]))

@@ -47,7 +47,12 @@ stay wired into the hot paths permanently:
     ``jax.profiler.TraceAnnotation`` of its name, so while a profiler
     session runs the span sits on the ``/host:CPU`` plane of the same
     ``.xplane.pb`` as the chip's operations. The launcher and the
-    agent never import jax for this. Still behind ``_enabled``.
+    agent never import jax for this. Still behind ``_enabled``;
+  * **the collector's pauses**: while tracing is on one
+    ``gc.callbacks`` hook writes ``gc.collect`` {generation,
+    collected} for every collection of generation 1 or 2, and any of
+    generation 0 that took 1 ms or more, on the thread it ran on and
+    under that thread's live span. Off there is no hook.
 
 Usage::
 
@@ -65,6 +70,7 @@ programmatically via :func:`enable`.
 """
 
 import contextvars
+import gc
 import itertools
 import json
 import os
@@ -366,6 +372,41 @@ def _finish(name: str, ts: float, dur: float,
             )
 
 
+# ------------------------------------------------------------- collector
+
+#: a collection of the youngest generation is a span only from here on
+GC_SPAN_MIN_S = 1e-3
+
+#: the running collection's start (wall clock, perf_counter) and its
+#: profiler annotation: the collector runs one collection at a time
+_gc_started: Tuple[float, float, Any] = (0.0, 0.0, None)
+
+
+def _gc_hook(phase: str, info: Dict[str, int]) -> None:
+    """``gc.callbacks`` entry: the collection as a ``gc.collect`` span
+    on the thread it interrupted, a child of that thread's live span
+    (of none: the loop was between two sites), and like a live span
+    an annotation on the profiler's clock."""
+    global _gc_started
+    if phase == "start":
+        _gc_started = (
+            time.time(), time.perf_counter(), _annotation("gc.collect")
+        )
+        return
+    ts, t0, ann = _gc_started
+    if not t0:  # hooked in the middle of this collection
+        return
+    _gc_started = (0.0, 0.0, None)
+    dur = time.perf_counter() - t0
+    if ann is not None:
+        ann.__exit__(None, None, None)
+    if info["generation"] or dur >= GC_SPAN_MIN_S:
+        add_span("gc.collect", ts, dur, {
+            "generation": info["generation"],
+            "collected": info["collected"],
+        })
+
+
 # ----------------------------------------------------------- configuration
 
 
@@ -381,14 +422,19 @@ def enable(trace_dir: Optional[str] = None,
             _ring = deque(_ring, maxlen=max(1, capacity))
         if trace_dir:
             _open_file(trace_dir)
+        if _gc_hook not in gc.callbacks:
+            gc.callbacks.append(_gc_hook)
         _enabled = True
 
 
 def disable() -> None:
-    """Stop recording; the ring keeps its tail for post-mortems."""
+    """Stop recording and take the collector's hook away; the ring
+    keeps its tail for post-mortems."""
     global _enabled
     with _lock:
         _enabled = False
+        if _gc_hook in gc.callbacks:
+            gc.callbacks.remove(_gc_hook)
         _close_file()
 
 
