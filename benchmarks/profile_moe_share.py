@@ -30,6 +30,14 @@ its transpose, a turn of the loop once kept whole (``"backward":
 (``"autodiff_remat"``). ``"planned_bytes"`` in a row is the compiled
 gradient program's ``peak_memory_in_bytes``.
 
+The layer alone before and after the backward walk's in-place sums
+stopped visiting the experts a chunk holds no row of (PERF.md, PR 39;
+one v5e chip, the parent ``f484514`` beside the change in one call,
+held share 0.25, ``--n 20``; forward / the gradients' program, ms):
+at ``lfm2-8b-a1b-ep4``'s shapes and 10,240 rows a chunk (4 live of
+13) 12.47 / 30.05 -> 12.13 / 26.46; at ``smallthinker-21b-a3b-ep4``'s
+and 8,192 (3 live of 12) 6.51 / 15.66 -> 6.51 / 13.44.
+
 On no cell's path. Only a TPU run says anything:
 ``chiprun -- python3 benchmarks/profile_moe_share.py``.
 """

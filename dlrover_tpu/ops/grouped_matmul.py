@@ -28,6 +28,20 @@ for the forward product / for it and its two backward products):
   route on the chip. Larger tiles in any dimension do not fit the
   kernel's VMEM.
 
+A caller that multiplies the same matrices in several pieces
+(parallel/moe.py's walk in chunks) keeps float32 sums over the pieces
+and adds a piece's part in place: ``add_rhs_gradient`` for the
+matrices' gradient, ``add_rows`` for the rows' sum into their tokens.
+Both are the repo's own in-place ``tgmm`` (ops/pallas/grouped_sum.py:
+megablox's kernel with its sum required), which gives a group a grid
+step only where the piece holds a row of it, so a piece moves the sums
+of the experts it has rows of and not of all (PERF.md, PR 39; one
+v5e chip, 10,240 rows of 3 of 8 experts into ``f32[8, 2048, 1792]``:
+0.49 ms a call where megablox's, which visits every group to write
+it, reads 0.73). With no sum to add to (``_gmm_bwd``, one product
+over all rows) every group has to be written once, and megablox's
+``tgmm`` stays.
+
 The tiles are a static rule on the shapes (``tiles``), as the
 attention blocks are (ops/tuning.py): each product of the three, the
 forward's and the two backward ones, gets the tiles of its own
@@ -36,7 +50,6 @@ contraction and columns, so a 2560 x 768 expert is walked in (512,
 """
 
 import functools
-import inspect
 
 import jax
 import jax.numpy as jnp
@@ -48,7 +61,7 @@ LANES = 128
 
 
 #: the most elements of a [contraction, columns] tile of a float32
-#: result that ``tgmm`` adds to in place: it holds the tile five
+#: result that the in-place ``tgmm`` adds to: it holds the tile five
 #: times (the sum read and written, each double-buffered, and its own
 #: accumulator), and 1024 x 896 of them are refused at 21.25M of the
 #: 16M of scoped VMEM where 640 x 768 and 512 x 896 compile
@@ -118,7 +131,9 @@ def _gmm_fwd(lhs, rhs, group_sizes, filled):
 def _gmm_bwd(filled, residual, grad):
     """As megablox's own ``custom_vjp``, with each product's own
     tiles, and the rows past the groups' sum zero."""
-    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import (
+        gmm, tgmm as whole_tgmm,
+    )
 
     lhs, rhs, group_sizes = residual
     rows, (_, k, n) = lhs.shape[0], rhs.shape
@@ -129,7 +144,7 @@ def _gmm_bwd(filled, residual, grad):
     )
     if not filled:
         grad_lhs = jnp.where(_within(rows, group_sizes), grad_lhs, 0)
-    grad_rhs = tgmm(
+    grad_rhs = whole_tgmm(
         lhs.swapaxes(0, 1), grad, group_sizes,
         preferred_element_type=rhs.dtype, tiling=tiles(rows, k, n),
         num_actual_groups=rhs.shape[0], interpret=_interpret(),
@@ -187,24 +202,15 @@ def add_rhs_gradient(
     multiplies the same matrices in several pieces (parallel/moe.py's
     walk in chunks) and keeps a float32 ``into`` until the last: the
     sum is then rounded to the matrices' dtype once, as one product
-    over all rows rounds it. On the TPU ``tgmm`` reads and writes
-    ``into`` in place, a group's tile once, where a product of its
-    own and an add would pass over every matrix three times a piece.
-    Rows past the sum take no part."""
+    over all rows rounds it. On the TPU the in-place ``tgmm``
+    (ops/pallas/grouped_sum.py) reads and writes ``into`` where it
+    is, a tile of a group that has rows in the piece once and a group
+    without a row not at all, where a product of its own and an add
+    would pass over every matrix three times a piece. Rows past the
+    sum take no part."""
     group_sizes = group_sizes.astype(jnp.int32)
     if _use_pallas(lhs, jax.ShapeDtypeStruct(into.shape, grad.dtype)):
-        from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
-
-        return tgmm(
-            lhs.swapaxes(0, 1), grad, group_sizes,
-            preferred_element_type=into.dtype,
-            tiling=tiles(
-                lhs.shape[0], *into.shape[1:],
-                most=IN_PLACE_TILE if into.dtype.itemsize > 2 else None,
-            ),
-            num_actual_groups=into.shape[0], existing_out=into,
-            interpret=_interpret(),
-        )
+        return tgmm(into, lhs, grad, group_sizes)
     _, to_rhs = jax.vjp(
         lambda rhs: jax.lax.ragged_dot(
             lhs.astype(into.dtype), rhs, group_sizes
@@ -239,10 +245,12 @@ def add_rows(out: jax.Array, index: jax.Array, rows: jax.Array) -> jax.Array:
     rows of 2560 into 16,384, 1.75 with the indices sorted and said
     to be; a gather of as many rows 0.35: PERF.md, PR 35). On the TPU
     the sum is therefore a grouped product: the rows sorted by index,
-    a group for each ``ROW_BLOCK`` consecutive indices, and ``tgmm``
-    of the one-hot place of each row in its block against the rows,
-    added into ``out`` in place: exact, products of 0 or 1 summed in
-    float32."""
+    a group for each ``ROW_BLOCK`` consecutive indices, and the
+    in-place ``tgmm`` of the one-hot place of each row in its block
+    against the rows, added into ``out`` where it is: exact, products
+    of 0 or 1 summed in float32. A block that gets no row is not
+    visited (at the cells' shapes nearly every block gets one from
+    every chunk but a layer's last)."""
     if not _add_on_mxu(out, rows):
         return out.at[index].add(rows.astype(out.dtype))
     n, h = out.shape
@@ -262,18 +270,35 @@ def add_rows(out: jax.Array, index: jax.Array, rows: jax.Array) -> jax.Array:
 
 
 @jax.jit
-def _rows_by_place(place, rows, group_sizes, out):
-    """``add_rows``' product, jitted under its own name around
-    megablox's ``tgmm`` without that function's own ``jit``: a device
-    trace names a Pallas call after the innermost jitted function
-    that holds it, and ``tgmm.<n>`` in a trace is an expert matmul's
-    gradient (``_gmm_bwd``, ``add_rhs_gradient``), which this is not
-    (tests/test_chip_compile.py holds both names)."""
-    from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
+def tgmm(into, lhs, grad, group_sizes):
+    """``add_rhs_gradient``'s product on the TPU, jitted under
+    megablox's name for it: a device trace names a Pallas call after
+    the innermost jitted function that holds it, and ``tgmm.<n>`` in
+    a trace is an expert matmul's gradient, ``_gmm_bwd``'s whole or
+    a piece's part here (tests/test_chip_compile.py holds the name)."""
+    from dlrover_tpu.ops.pallas.grouped_sum import add_grouped_product
 
-    return inspect.unwrap(tgmm)(
-        place.swapaxes(0, 1), rows, group_sizes,
-        preferred_element_type=out.dtype,
-        tiling=tiles(rows.shape[0], ROW_BLOCK, rows.shape[1]),
-        existing_out=out, interpret=_interpret(),
+    return add_grouped_product(
+        into, lhs, grad, group_sizes,
+        tiles(
+            lhs.shape[0], *into.shape[1:],
+            most=IN_PLACE_TILE if into.dtype.itemsize > 2 else None,
+        ),
+        interpret=_interpret(),
+    )
+
+
+@jax.jit
+def _rows_by_place(place, rows, group_sizes, out):
+    """``add_rows``' product, the same kernel jitted under a name of
+    its own: ``tgmm.<n>`` in a trace is an expert matmul's gradient,
+    which this is not (tests/test_chip_compile.py holds both
+    names). A block of indices that gets no row of the piece is
+    neither read nor written."""
+    from dlrover_tpu.ops.pallas.grouped_sum import add_grouped_product
+
+    return add_grouped_product(
+        out, place, rows, group_sizes,
+        tiles(rows.shape[0], ROW_BLOCK, rows.shape[1]),
+        interpret=_interpret(),
     )

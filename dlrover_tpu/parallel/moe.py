@@ -308,11 +308,12 @@ ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 #: chunk costs 3.8 ms at 4,096 rows, 5.1 at 8,192 and 7.2 at 12,288,
 #: forward and backward under a remat policy that runs the forward
 #: twice. Smaller chunks follow the held share more closely and pay a
-#: turn's fixed costs (the tokens' sum and the matrices' three float32
-#: gradient sums read and written once a turn) more often, larger
-#: ones round a layer's share up further: in the cell 4,096 reads
-#: 4.4% fewer tokens/s than this and 12,288 0.3% more for a second of
-#: set-up
+#: turn's fixed costs (the tokens' float32 sum rewritten, and the
+#: float32 gradient sums of the experts the chunk holds rows of read
+#: and written, once a turn) more often, larger ones round a layer's
+#: share up further: in the cell 4,096 read 4.4% fewer tokens/s than
+#: this and 12,288 0.3% more for a second of set-up (when a turn still
+#: moved every held expert's three sums: before PR 39)
 CHUNK_ROWS = 8192
 #: the width those rows were timed at. A chunk is sized by the bytes
 #: of its gathered rows, so narrower rows make a longer chunk: 10,240
@@ -739,6 +740,16 @@ def set_rows_held_gauge(counts, first_held: int, held: int) -> float:
     return share
 
 
+def _walk_of(counts, width: int):
+    """``(counts as int64 [layers, experts], rows of a chunk,
+    chunks)`` of the walk a layer with these counts makes."""
+    import numpy as np
+
+    load = np.asarray(counts, dtype=np.int64)
+    load = load.reshape(-1, load.shape[-1])
+    return (load, *walk_chunks(int(load[0].sum()), width))
+
+
 def set_chunks_walked_gauge(counts, first_held: int, held: int,
                             width: int) -> float:
     """From the same counts set the gauge ``moe_chunks_walked_share``
@@ -748,13 +759,9 @@ def set_chunks_walked_gauge(counts, first_held: int, held: int,
     here (one pass, no walk). Over ``moe_rows_held_share`` it is what
     the chunk's rounding costs. ``width``: the layer's rows' (the
     model's hidden size), which sizes a chunk."""
-    import numpy as np
-
     from dlrover_tpu.telemetry.registry import gauge
 
-    load = np.asarray(counts, dtype=np.int64)
-    load = load.reshape(-1, load.shape[-1])
-    rows, chunks = walk_chunks(int(load[0].sum()), width)
+    load, rows, chunks = _walk_of(counts, width)
     share = 1.0
     if held < load.shape[-1]:
         here = load[:, first_held:first_held + held].sum(axis=-1)
@@ -763,6 +770,44 @@ def set_chunks_walked_gauge(counts, first_held: int, held: int,
         "moe_chunks_walked_share",
         "live chunks of the walk over a share's sorted assignments "
         "over all chunks, at the last evaluation",
+        ("chunk_rows",),
+    ).labels(chunk_rows=str(rows)).set(share)
+    return share
+
+
+def set_sums_visited_gauge(counts, first_held: int, held: int,
+                           width: int) -> float:
+    """From the same counts set the gauge ``moe_sums_visited_share``
+    {``chunk_rows``}: the held experts with a row in a live chunk,
+    summed over the live chunks of a share's walk, over held experts
+    x live chunks, all layers together. It is the share of the
+    experts' float32 gradient sums that the backward walk's in-place
+    products read and write (``ops/grouped_matmul.py
+    add_rhs_gradient`` visits only the groups that have rows in the
+    piece); the rows are sorted by expert, so under even routing a
+    chunk holds rows of ``rows / (rows an expert) + 1`` experts or
+    so. 1 where every expert is here (one pass, every sum written
+    once); 0 where no chunk is live. ``width`` as above."""
+    import numpy as np
+
+    from dlrover_tpu.telemetry.registry import gauge
+
+    load, rows, _ = _walk_of(counts, width)
+    share = 1.0
+    if held < load.shape[-1]:
+        here = load[:, first_held:first_held + held]
+        ends = np.cumsum(here, axis=-1)
+        # the chunks an expert's rows lie in: its first row's to its
+        # last row's
+        visits = np.where(
+            here > 0, (ends - 1) // rows - (ends - here) // rows + 1, 0
+        ).sum()
+        live = (-(-ends[:, -1] // rows)).sum()
+        share = float(visits / max(held * live, 1))
+    gauge(
+        "moe_sums_visited_share",
+        "held experts with a row in a live chunk of a share's walk "
+        "over held experts x live chunks, at the last evaluation",
         ("chunk_rows",),
     ).labels(chunk_rows=str(rows)).set(share)
     return share

@@ -36,7 +36,7 @@ from dlrover_tpu.ops import tuning
 from dlrover_tpu.parallel.mesh import create_mesh
 from dlrover_tpu.parallel.moe import (
     set_bias_changed_gauge, set_chunks_walked_gauge,
-    set_expert_load_gauges, set_rows_held_gauge,
+    set_expert_load_gauges, set_rows_held_gauge, set_sums_visited_gauge,
 )
 from dlrover_tpu.trainer.checkpoint import FlashCheckpointer
 from dlrover_tpu.trainer.compile_cache import cache_events
@@ -280,9 +280,13 @@ def main():
                     walked = set_chunks_walked_gauge(
                         counts, *here, cfg.hidden_size
                     )
+                    visited = set_sums_visited_gauge(
+                        counts, *here, cfg.hidden_size
+                    )
                     line = (f"EXPERT_LOAD step={step} max/mean="
                             f"{most:.3f} min/mean={least:.3f} "
-                            f"held={held:.3f} walked={walked:.3f}")
+                            f"held={held:.3f} walked={walked:.3f} "
+                            f"sums_visited={visited:.3f}")
                     if bias_changed_stats is not None:
                         changed = set_bias_changed_gauge(
                             bias_changed_stats(params, mb[0][0]),

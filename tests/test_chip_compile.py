@@ -32,6 +32,7 @@ from jax.sharding import Mesh, SingleDeviceSharding
 
 from dlrover_tpu.models import llama
 from dlrover_tpu.ops import attention, tuning
+from dlrover_tpu.ops.pallas import grouped_sum
 from dlrover_tpu.ops.pallas import flash_attention as fa
 from dlrover_tpu.parallel import moe
 from dlrover_tpu.trainer.sharded import make_trainer_for_llama
@@ -628,6 +629,10 @@ def test_lowered_kernel_holds_a_body_a_row(
         assert a < 3 * whole, at
 
 
+def _not_here(*args, **kwargs):
+    raise AssertionError("the in-place grouped sum, where nothing is summed")
+
+
 @pytest.mark.parametrize("rows,experts,k,n", [
     (3 * 4096 * 8, 64, 2048, 1024), (3 * 4096 * 8, 64, 1024, 2048),
     (98304, 16, 2560, 768), (98304, 16, 768, 2560),
@@ -647,6 +652,9 @@ def test_grouped_matmul_kernels_compile_at_the_cells_shapes(
 
     monkeypatch.setattr(gm, "_use_pallas", lambda lhs, rhs: True)
     monkeypatch.setattr(gm, "_interpret", lambda: False)
+    # one product over all rows has no sum to add to: every group is
+    # written once, by megablox's ``tgmm``, not by the in-place kernel
+    monkeypatch.setattr(grouped_sum, "add_grouped_product", _not_here)
     one_chip = SingleDeviceSharding(topo.devices[0])
     args = (
         jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip),
@@ -668,6 +676,44 @@ def test_grouped_matmul_kernels_compile_at_the_cells_shapes(
     assert len([c for c in calls if "tgmm" in c]) == 1, calls
     assert len([c for c in calls if "tgmm" not in c]) == 1, calls
     assert "ragged-dot" not in text
+
+
+def test_olmoe_step_keeps_megabloxs_tgmm(topo, on_tpu_path, monkeypatch):
+    """``olmoe-1b-7b-1chip.steady``'s step, traced and lowered for
+    the chip (not compiled): with every expert held the layer is one
+    pass, the matrices' gradients are megablox's ``tgmm`` writing
+    every group once (no ``existing_out``), three a scanned layer,
+    and the in-place kernel of a share's walk is not on its path."""
+    import importlib
+
+    from dlrover_tpu.ops import grouped_matmul as gm
+    from yardstick import cells, worker
+
+    megablox = importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+    whole, sums = megablox.tgmm, []
+
+    def tgmm(*args, existing_out=None, **kwargs):
+        sums.append(existing_out)
+        return whole(*args, existing_out=existing_out, **kwargs)
+
+    monkeypatch.setattr(megablox, "tgmm", tgmm)
+    monkeypatch.setattr(grouped_sum, "add_grouped_product", _not_here)
+    monkeypatch.setattr(gm, "_use_pallas", lambda lhs, rhs: True)
+    monkeypatch.setattr(gm, "_interpret", lambda: False)
+    _, config, traffic = cells.load_cell("olmoe-1b-7b-1chip.steady")
+    cfg = worker.program_config(config, traffic)
+    assert cfg.moe_experts_held in (0, cfg.num_experts)
+    mesh = Mesh(
+        np.array(topo.devices[:1]).reshape(1, 1), ("data", "fsdp"))
+    trainer = make_trainer_for_llama(
+        cfg, mesh, strategy=traffic["strategy"],
+        optimizer=optax.adamw(traffic["optimizer"]["learning_rate"]),
+    )
+    text = trainer.train_step.lower(*_abstract_step_args(
+        trainer, traffic["global_batch"], traffic["seq"])).as_text()
+    assert "tpu_custom_call" in text
+    assert len(sums) == 3 and all(s is None for s in sums), sums
 
 
 def test_llama_1b_step_fits_one_chip_at_batch_3(topo, on_tpu_path):
@@ -766,6 +812,30 @@ def test_smallthinker_step_walks_its_share_in_chunks(
     assert len(matmuls) >= 9 and len(sums) >= 2
     assert all(KERNEL.search(name) for name in matmuls), matmuls
     assert not any(KERNEL.search(name) for name in sums), sums
+    _in_place_sums_keep_their_names(text, held, sums)
+
+
+def _in_place_sums_keep_their_names(text, held, token_sums):
+    """The calls of ``ops/pallas/grouped_sum.py`` in a compiled step
+    (a Pallas call whose result is an operand's buffer): into
+    ``f32[held, ., .]`` the experts' gradient sums, named ``tgmm.<n>``
+    after the jitted function that holds them, three a walked layer,
+    which ``moe_expert_ms`` counts; into the tokens' blocks
+    ``_rows_by_place.<n>``, which it does not."""
+    from yardstick.layer_metrics.moe_expert_ms import KERNEL
+
+    in_place = re.findall(
+        r"%([\w.\-]+) = (\w+\[[\d,]+\])[^\n]*"
+        r"custom_call_target=\"tpu_custom_call\"[^\n]*"
+        r"output_to_operand_aliasing", text)
+    gradient_sums = [name for name, result in in_place
+                     if result.startswith(f"f32[{held},")]
+    assert len(gradient_sums) >= 3 and len(gradient_sums) % 3 == 0
+    assert all(re.fullmatch(r"tgmm(\.\d+)?", name) and KERNEL.search(name)
+               for name in gradient_sums), gradient_sums
+    assert token_sums and set(token_sums) <= {n for n, _ in in_place}
+    assert all(re.fullmatch(r"_rows_by_place(\.\d+)?", name)
+               for name in token_sums), token_sums
 
 
 #: ``peak_memory_in_bytes`` of ``lfm2-8b-a1b-ep4.steady``'s step as
@@ -835,6 +905,11 @@ def test_lfm2_step_holds_the_convolutions_kernels(
     # the one backward kernel
     assert sum(bool(attn_kernel_ms.KERNEL.search(n)) for n in others) == 3
     assert sum(bool(moe_expert_ms.KERNEL.search(n)) for n in others) >= 9
+    _in_place_sums_keep_their_names(
+        text, cfg.moe_experts_held,
+        [name for name, result, _ in kernels if result.startswith(
+            f"f32[{traffic['global_batch'] * traffic['seq'] // gm.ROW_BLOCK}"
+            f",{gm.ROW_BLOCK},")])
     # [batch, seq, 3 x hidden] and [batch, seq, hidden] of the
     # convolution: whatever computes on them says whose op it is
     wide = re.compile(r"= \w+\[4,8192,6144\]")

@@ -188,6 +188,48 @@ def test_chunks_walked_gauge():
     assert share >= moe.set_rows_held_gauge(counts, FIRST, HELD)
 
 
+@pytest.mark.parametrize("tokens,top,experts,held,width,visits,of", [
+    (32768, 4, 32, 8, 2048, 10, 32),  # lfm2: 3, 3, 3, 1 of 8
+    (16384, 6, 64, 16, 2560, 18, 48),  # smallthinker: 6, 6, 6 of 16
+], ids=["lfm2", "smallthinker"])
+def test_sums_visited_gauge_under_even_routing(
+    tokens, top, experts, held, width, visits, of
+):
+    """At both cells' shapes with every expert given the same rows:
+    the held experts with a row in each live chunk (the rows are
+    sorted by expert, so an expert's rows lie in one chunk or in two
+    neighbours) over held experts x live chunks, two layers alike."""
+    counts = np.full((2, experts), tokens * top // experts, np.int64)
+    rows, _ = moe.walk_chunks(tokens * top, width)
+    share = moe.set_sums_visited_gauge(counts, 0, held, width)
+    assert share == pytest.approx(visits / of)
+    from dlrover_tpu.telemetry.registry import default_registry
+
+    assert (f'moe_sums_visited_share{{chunk_rows="{rows}"}} {share}'
+            in default_registry().to_prometheus_text())
+    # every expert held: one pass, every sum written once
+    assert moe.set_sums_visited_gauge(counts, 0, experts, width) == 1.0
+
+
+def test_sums_visited_gauge_counts_the_chunks_an_expert_lies_in():
+    """Hand-made counts, ``CHUNK_ROWS`` rows a chunk: a layer whose
+    held rows are one expert's over three chunks (3 visits of 4 x 3),
+    a layer with a row each in a first chunk (4 of 4 x 1), and a layer
+    that holds nothing (no live chunk, no visit)."""
+    rows = moe.CHUNK_ROWS
+    counts = np.zeros((3, E), np.int64)
+    counts[0, FIRST + 1] = 2 * rows + 5
+    counts[1, FIRST:FIRST + HELD] = 1
+    counts[:, 7] = 6 * rows - counts[:, FIRST:FIRST + HELD].sum(-1)
+    share = moe.set_sums_visited_gauge(
+        counts, FIRST, HELD, moe.CHUNK_WIDTH)
+    assert share == pytest.approx((3 + HELD) / (HELD * (3 + 1)))
+    nothing = np.zeros((1, E), np.int64)
+    nothing[0, 7] = 6 * rows
+    assert moe.set_sums_visited_gauge(
+        nothing, FIRST, HELD, moe.CHUNK_WIDTH) == 0.0
+
+
 @pytest.mark.parametrize("chunk", [8, 24])
 def test_an_experts_gradient_over_many_chunks_is_rounded_once(
     monkeypatch, chunk
