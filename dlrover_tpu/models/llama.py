@@ -32,9 +32,11 @@ from dlrover_tpu.ops.short_conv import gated_short_conv
 
 class LayerKind(NamedTuple):
     """What a layer is made of: its operator (``"full_attention"``,
-    or ``"conv"``, the gated short convolution), for attention the
-    window (None: every earlier key) and whether q and k are rotated,
-    and its feed-forward (``"dense"`` or ``"experts"``)."""
+    ``"latent_attention"``, whose q, k and v come through low-rank
+    projections, or ``"conv"``, the gated short convolution), for
+    attention the window (None: every earlier key) and whether q and
+    k are rotated, and its feed-forward (``"dense"`` or
+    ``"experts"``)."""
     operator: str = "full_attention"
     window: Optional[int] = None
     rope: bool = True
@@ -151,6 +153,40 @@ class LlamaConfig:
     # alone: parallel/moe.py ``route_logits``)
     moe_gate: str = "softmax"
     use_expert_bias: bool = False
+    # what is added to the sum of a token's k weights before they are
+    # divided by it (None: the gate's own, 1e-6 under "sigmoid" and
+    # nothing under "softmax"), and a factor on the weights after it
+    # (``routed_scaling_factor``)
+    moe_topk_norm_eps: Optional[float] = None
+    moe_routed_scaling: float = 1.0
+    # experts that every token takes, beside the routed ones and
+    # unweighted (``n_shared_experts``): one gated MLP of that many
+    # times ``moe_intermediate_size``, whole on every device
+    moe_shared_experts: int = 0
+    # latent attention, in the source's keys (``DeepseekV3Config``):
+    # with ``kv_lora_rank`` every attention layer's operator is
+    # "latent_attention". q comes from a ``q_lora_rank``-wide
+    # projection through an RMSNorm; k's un-rotated part and v from a
+    # ``kv_lora_rank``-wide one through another; each head's q and k
+    # are ``qk_nope_head_dim`` such columns and ``qk_rope_head_dim``
+    # rotated ones, the rotated part of k one head's that all heads
+    # share; v is ``v_head_dim`` wide (``head_dim`` is not read).
+    # ``rope_interleave``: the rotation pairs columns (2i, 2i + 1),
+    # not (i, i + half).
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleave: bool = False
+    # multi-token prediction (``num_nextn_predict_layers``): that many
+    # further blocks past the stack, each fed the block before's
+    # output and the next token's embedding and read by the model's
+    # own head one token further ahead; their mean losses join
+    # ``next_token_loss`` at ``mtp_loss_weight``. Depth 1 is what is
+    # built.
+    mtp_layers: int = 0
+    mtp_loss_weight: float = 0.3
 
     def __post_init__(self):
         if self.remat not in ("off", "dots", "dots_attn_out",
@@ -217,6 +253,25 @@ class LlamaConfig:
             )
         if self.moe_gate not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown moe_gate {self.moe_gate!r}")
+        if self.latent and not (
+                self.q_lora_rank and self.qk_nope_head_dim
+                and self.qk_rope_head_dim and self.v_head_dim
+                and self.num_kv_heads == self.num_heads
+                and self.layer_types is None
+                and self.sliding_window_layout is None
+                and self.rope_layout is None
+                and not (self.qk_norm or self.qk_head_norm)):
+            raise ValueError(
+                "latent attention (kv_lora_rank) takes q_lora_rank, "
+                "qk_nope_head_dim, qk_rope_head_dim and v_head_dim, "
+                "as many kv heads as heads, and no layer pattern or "
+                "norm of whole q and k beside it"
+            )
+        if self.mtp_layers not in (0, 1):
+            raise ValueError(
+                f"mtp_layers {self.mtp_layers}: one prediction module "
+                "past the stack is what is built"
+            )
         if self.num_experts > 0:
             if not self.moe_experts_held:
                 object.__setattr__(
@@ -229,6 +284,15 @@ class LlamaConfig:
                     f"{self.moe_first_expert_held + self.moe_experts_held}"
                     f" of {self.num_experts}"
                 )
+
+    @property
+    def latent(self) -> bool:
+        return self.kv_lora_rank is not None
+
+    @property
+    def rope_dim(self) -> int:
+        """How many of a head's columns the rotary embedding turns."""
+        return self.qk_rope_head_dim if self.latent else self.head_dim
 
     @property
     def by_position(self) -> bool:
@@ -259,7 +323,10 @@ class LlamaConfig:
                 and i >= self.num_dense_layers else "dense",
             )
             for i, operator in enumerate(
-                self.layer_types or ("full_attention",) * n
+                self.layer_types or (
+                    "latent_attention" if self.latent
+                    else "full_attention",
+                ) * n
             )
         )
         lead, rest = (kinds[:self.num_dense_layers],
@@ -314,6 +381,21 @@ def llama_moe_tiny(**kw) -> LlamaConfig:
     return llama_tiny(**kw)
 
 
+def llama_latent_tiny(**kw) -> LlamaConfig:
+    """Test-sized config with latent attention (24-wide q and k of
+    which 8 columns are rotated in pairs, 16-wide v), a leading dense
+    layer, a shared expert beside 8 routed ones (sigmoid scores, a
+    selection bias, weights times 2.5) and one prediction module."""
+    return llama_tiny(**{**dict(
+        num_layers=3, num_dense_layers=1, num_kv_heads=4, num_experts=8,
+        moe_top_k=2, moe_intermediate_size=32, moe_gate="sigmoid",
+        use_expert_bias=True, moe_topk_norm_eps=1e-20,
+        moe_routed_scaling=2.5, moe_shared_experts=1, q_lora_rank=48,
+        kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, rope_interleave=True, mtp_layers=1,
+    ), **kw})
+
+
 def llama_tiny(**kw) -> LlamaConfig:
     """Test-sized config that still exercises GQA + scan + remat."""
     kw.setdefault("vocab_size", 256)
@@ -343,6 +425,20 @@ def _leaves(cfg: LlamaConfig, kind: LayerKind) -> Dict:
             "conv_in": ((h, 3 * h), ("embed", "mlp")),
             "conv_out": ((h, h), ("mlp", "embed")),
         }
+    elif kind.operator == "latent_attention":
+        rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+        nope, rope, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                          cfg.v_head_dim)
+        matrices = {
+            "wq_a": ((h, rq), ("embed", None)),
+            "wq_b": ((rq, nh * (nope + rope)), (None, "heads")),
+            # [c_kv | the one rotated key]
+            "wkv_a": ((h, rkv + rope), ("embed", None)),
+            # a head's [k_nope | v]
+            "wkv_b": ((rkv, nh * (nope + vd)), (None, "heads")),
+            "wo": ((nh * vd, h), ("heads", "embed")),
+        }
+        norms.update(q_a_norm=rq, kv_a_norm=rkv)
     else:
         matrices = {
             "wq": ((h, nh * hd), ("embed", "heads")),
@@ -362,6 +458,13 @@ def _leaves(cfg: LlamaConfig, kind: LayerKind) -> Dict:
             "w_up": ((held, h, m), ("expert", "embed", "mlp")),
             "w_down": ((held, m, h), ("expert", "mlp", "embed")),
         })
+        if cfg.moe_shared_experts:
+            ms = cfg.moe_shared_experts * m
+            matrices.update({
+                "ws_gate": ((h, ms), ("embed", "mlp")),
+                "ws_up": ((h, ms), ("embed", "mlp")),
+                "ws_down": ((ms, h), ("mlp", "embed")),
+            })
     else:
         m = cfg.intermediate_size
         matrices.update({
@@ -384,10 +487,12 @@ def _leaves(cfg: LlamaConfig, kind: LayerKind) -> Dict:
     return leaves
 
 
-#: which of ``jax.random.split(key, 8)`` draws a leaf
+#: which of ``jax.random.split(key, 8)`` draws a leaf; from 8 on the
+#: key folded with the number (the eight stay what they were)
 _DRAW = {"wq": 0, "wk": 1, "wv": 2, "wo": 3, "w_gate": 4, "w_up": 5,
          "w_down": 6, "router": 7, "conv_in": 0, "conv_w": 1,
-         "conv_out": 3}
+         "conv_out": 3, "wq_a": 8, "wq_b": 9, "wkv_a": 10, "wkv_b": 11,
+         "ws_gate": 12, "ws_up": 13, "ws_down": 14}
 
 
 def _init_layers(key, cfg: LlamaConfig, kind: LayerKind, stack=()):
@@ -402,9 +507,12 @@ def _init_layers(key, cfg: LlamaConfig, kind: LayerKind, stack=()):
                 stack + shape, 1.0 if std is None else 0.0, jnp.float32
             )
             continue
+        draw = _DRAW[name]
         layers[name] = (
-            jax.random.normal(ks[_DRAW[name]], stack + shape, jnp.float32)
-            * std
+            jax.random.normal(
+                ks[draw] if draw < 8 else jax.random.fold_in(key, draw),
+                stack + shape, jnp.float32,
+            ) * std
         ).astype(cfg.dtype)
     return layers
 
@@ -422,7 +530,10 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Dict:
     or, where layers of several kinds own different leaves
     (``cfg.by_position``), the leading layers one by one in ``lead``
     and in ``period`` a stack ``[periods, ...]`` for each position of
-    the scanned period. A tied head has no ``lm_head``."""
+    the scanned period. A tied head has no ``lm_head``. ``mtp`` holds
+    the prediction modules past the stack: the norms of the two
+    inputs, the merge ``eh_proj`` [2 hidden, hidden], a block of the
+    last layer's kind and a final norm of its own."""
     h = cfg.hidden_size
     k_embed, k_blocks, k_out = jax.random.split(rng, 3)
     lead, period = cfg.layer_plan()
@@ -453,6 +564,18 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Dict:
             jax.random.normal(k_out, (h, cfg.vocab_size), jnp.float32)
             * h ** -0.5
         ).astype(cfg.dtype)
+    if cfg.mtp_layers:
+        k_merge, k_block = jax.random.split(jax.random.fold_in(rng, 3))
+        params["mtp"] = [{
+            "embed_norm": jnp.ones((h,), jnp.float32),
+            "hidden_norm": jnp.ones((h,), jnp.float32),
+            "eh_proj": (
+                jax.random.normal(k_merge, (2 * h, h), jnp.float32)
+                * (2 * h) ** -0.5
+            ).astype(cfg.dtype),
+            "block": _init_layers(k_block, cfg, period[-1]),
+            "final_norm": jnp.ones((h,), jnp.float32),
+        }]
     return params
 
 
@@ -469,6 +592,13 @@ def param_axes(cfg: LlamaConfig) -> Dict:
         axes["blocks"] = _layer_axes(cfg, period[0], ("layers",))
     if not cfg.tie_word_embeddings:
         axes["lm_head"] = ("embed", "vocab")
+    if cfg.mtp_layers:
+        axes["mtp"] = [{
+            "embed_norm": ("norm",), "hidden_norm": ("norm",),
+            "eh_proj": ("mlp", "embed"),
+            "block": _layer_axes(cfg, period[-1]),
+            "final_norm": ("norm",),
+        }]
     return axes
 
 
@@ -485,12 +615,13 @@ def frozen_params(cfg: LlamaConfig) -> Optional[Dict]:
 
 
 def _layers_of_each_kind(cfg: LlamaConfig):
-    """``[(kind, how many layers of it)]`` over the whole stack."""
+    """``[(kind, how many layers of it)]`` over the whole stack and
+    the prediction modules' blocks."""
     lead, period = cfg.layer_plan()
     periods = (cfg.num_layers - len(lead)) // len(period)
     return [(kind, 1) for kind in lead] + [
         (kind, periods) for kind in period
-    ]
+    ] + cfg.mtp_layers * [(period[-1], 1)]
 
 
 def param_count(cfg: LlamaConfig) -> int:
@@ -500,9 +631,11 @@ def param_count(cfg: LlamaConfig) -> int:
         )
 
     embeddings = 1 if cfg.tie_word_embeddings else 2
+    h = cfg.hidden_size
     return (
-        cfg.vocab_size * cfg.hidden_size * embeddings + cfg.hidden_size
+        cfg.vocab_size * h * embeddings + h
         + sum(n * layer(kind) for kind, n in _layers_of_each_kind(cfg))
+        + cfg.mtp_layers * (2 * h * h + 3 * h)
     )
 
 
@@ -527,8 +660,13 @@ def rope_tables(
     return jnp.cos(angles), jnp.sin(angles)
 
 
-def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """x: [batch, seq, heads, head_dim]; rotate pairs (even, odd).
+def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
+               interleaved: bool = False) -> jax.Array:
+    """x: [batch, seq, heads, head_dim]; rotate the pairs of columns
+    (i, i + half), or with ``interleaved`` the pairs (2i, 2i + 1),
+    which then leave in the order (evens, odds): q and k permuted
+    alike score the same (``DeepseekV3``'s
+    ``apply_rotary_pos_emb_interleave`` leaves them so too).
 
     Computed in x's own dtype: the angles (cos/sin tables) are built in
     f32 and each output element is one mul-add of unit-magnitude
@@ -537,7 +675,11 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     projections to materialize f32 copies to HBM. An earlier chip run,
     not reproduced, put the f32 rope fusion alone at 1.7% of device
     time for zero accuracy benefit."""
-    x1, x2 = jnp.split(x, 2, axis=-1)
+    if interleaved:
+        pairs = x.reshape(*x.shape[:-1], -1, 2)
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+    else:
+        x1, x2 = jnp.split(x, 2, axis=-1)
     c = cos[None, :, None, :].astype(x.dtype)
     s = sin[None, :, None, :].astype(x.dtype)
     return jnp.concatenate(
@@ -562,8 +704,9 @@ def _free(x, logical_axes):
 def _pre_attn(cfg: LlamaConfig, x, layer_params, cos, sin,
               constrain=_free, kind=LayerKind()):
     """Block segment 1, up to the operator's call: the norm and, for
-    attention, the q/k/v projections, the heads' norms and rope (none
-    in a layer whose kind says so), for the convolution its input
+    attention, the q/k/v projections (``_latent_qkv`` for latent
+    attention), the heads' norms and rope (none in a layer whose kind
+    says so), for the convolution its input
     projection; and, where the router reads the block's input, its
     logits, which ``_post_attn`` is handed past the operator:
     ``(the operator's arguments, logits or None)``."""
@@ -584,6 +727,8 @@ def _pre_attn(cfg: LlamaConfig, x, layer_params, cos, sin,
         with jax.named_scope("conv.in_proj"):
             bcu = constrain(y @ p["conv_in"], _MLP)
         return (bcu, p["conv_w"]), logits()
+    if kind.operator == "latent_attention":
+        return _latent_qkv(cfg, y, p, cos, sin, constrain), logits()
     q, k = y @ p["wq"], y @ p["wk"]
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"], cfg.norm_eps)
@@ -597,6 +742,40 @@ def _pre_attn(cfg: LlamaConfig, x, layer_params, cos, sin,
     if kind.rope:
         q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
     return (q, k, v), logits()
+
+
+def _latent_qkv(cfg: LlamaConfig, y, p, cos, sin, constrain=_free):
+    """q, k [b, s, heads, nope + rope] and v [b, s, heads, v_head_dim]
+    of latent attention from the normed stream ``y``: q through its
+    low-rank projection and an RMSNorm; the un-rotated part of every
+    head's k, and v, through another; the rotated part of k straight
+    from ``y``, one head's, which every head shares. The scores are
+    over a head's whole q and k, so attention's own default scale is
+    ``(nope + rope) ** -0.5``."""
+    b, s, _ = y.shape
+    nh, nope, rope = (cfg.num_heads, cfg.qk_nope_head_dim,
+                      cfg.qk_rope_head_dim)
+    with jax.named_scope("mla.q_down"):
+        c_q = rms_norm(y @ p["wq_a"], p["q_a_norm"], cfg.norm_eps)
+    with jax.named_scope("mla.kv_down"):
+        c_kv, k_rope = jnp.split(
+            y @ p["wkv_a"], [cfg.kv_lora_rank], axis=-1
+        )
+        c_kv = rms_norm(c_kv, p["kv_a_norm"], cfg.norm_eps)
+    with jax.named_scope("mla.up"):
+        q = constrain((c_q @ p["wq_b"]).reshape(b, s, nh, -1), _Q)
+        kv = constrain((c_kv @ p["wkv_b"]).reshape(b, s, nh, -1), _KV)
+        q_nope, q_rope = jnp.split(q, [nope], axis=-1)
+        k_nope, v = jnp.split(kv, [nope], axis=-1)
+        q_rope = apply_rope(q_rope, cos, sin, cfg.rope_interleave)
+        k_rope = apply_rope(
+            k_rope[:, :, None, :], cos, sin, cfg.rope_interleave
+        )
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope, (b, s, nh, rope))], axis=-1
+        )
+    return q, k, v
 
 
 def _expert_mlp(cfg: LlamaConfig, expert_parallel: bool):
@@ -615,16 +794,20 @@ def _expert_mlp(cfg: LlamaConfig, expert_parallel: bool):
         return partial(
             moe.dropless_moe_mlp, act=cfg.moe_expert_act,
             first_held=cfg.moe_first_expert_held, gate=cfg.moe_gate,
-            **routing
+            norm_eps=cfg.moe_topk_norm_eps,
+            scaling=cfg.moe_routed_scaling, **routing
         )
     if (cfg.moe_experts_held != cfg.num_experts
             or cfg.moe_router_input != "post_attn_norm"
             or cfg.moe_expert_act != "silu"
-            or cfg.moe_gate != "softmax" or cfg.use_expert_bias):
+            or cfg.moe_gate != "softmax" or cfg.use_expert_bias
+            or cfg.moe_topk_norm_eps is not None
+            or cfg.moe_routed_scaling != 1.0 or cfg.moe_shared_experts):
         raise ValueError(
             "a share of the experts held on one device "
             "(moe_experts_held), a router on the block's input, a "
-            "relu gate, a sigmoid router and its selection bias are "
+            "relu gate, a sigmoid router and its selection bias, a "
+            "factor on the routing weights and a shared expert are "
             "the dropless path's, on one device: over an 'expert' "
             "mesh axis larger than one they are refused (experts "
             "over chips: ROADMAP B9)"
@@ -665,6 +848,10 @@ def _post_attn(cfg: LlamaConfig, x, out, layer_params,
             mlp = partial(mlp, logits=router_logits)
         if cfg.use_expert_bias:
             mlp = partial(mlp, bias=p["expert_bias"])
+        if cfg.moe_shared_experts:
+            mlp = partial(
+                mlp, shared=(p["ws_gate"], p["ws_up"], p["ws_down"])
+            )
         out, aux = mlp(
             y, p["router"], p["w_gate"], p["w_up"], p["w_down"]
         )
@@ -696,7 +883,8 @@ def _operator_of(cfg: LlamaConfig, attn_fn, kind: LayerKind):
     on its projection and taps. A config with a layer pattern names
     the kinds of call, ``attn.full``, ``attn.window`` and
     ``conv.mix``, in their device ops' ``op_name``, and hands a
-    windowed layer's window to ``attn_fn`` (which has to take it)."""
+    windowed layer's window to ``attn_fn`` (which has to take it);
+    latent attention's call is ``attn.latent``."""
     if kind.operator == "conv":
 
         def mix(bcu, w):
@@ -704,6 +892,13 @@ def _operator_of(cfg: LlamaConfig, attn_fn, kind: LayerKind):
                 return gated_short_conv(bcu, w)
 
         return mix
+    if kind.operator == "latent_attention":
+
+        def attend_latent(q, k, v):
+            with jax.named_scope("attn.latent"):
+                return attn_fn(q, k, v)
+
+        return attend_latent
     if cfg.sliding_window_layout is None and cfg.layer_types is None:
         return attn_fn
 
@@ -789,17 +984,13 @@ def _dots_policy(cfg: LlamaConfig):
     )
 
 
-def hidden_states(
-    params: Dict,
-    tokens: jax.Array,  # int32 [batch, seq]
-    cfg: LlamaConfig,
-    attn_fn=None,
-    constrain=None,
-    expert_parallel: bool = False,
-) -> Tuple[jax.Array, jax.Array]:
-    """Final-norm hidden states [batch, seq, hidden] + MoE aux loss.
-    ``expert_parallel``: the experts are sharded over an ``expert``
-    mesh axis (the trainer says so from its mesh).
+def _run_stack(params, tokens, cfg: LlamaConfig, attn_fn=None,
+               constrain=None, expert_parallel: bool = False):
+    """``(the residual stream out of the last layer, before the final
+    norm; the MoE aux loss; layer_of)``: ``layer_of(kind)`` makes one
+    more layer of ``kind`` under the config's remat policy, for a
+    prediction module past the stack. The arguments are
+    ``hidden_states``'.
 
     ``constrain(x, logical_axes) -> x`` pins the layout of the
     activations between the matmuls (the residual stream, q/k/v, the
@@ -812,7 +1003,7 @@ def hidden_states(
     if constrain is None:
         constrain = _free
     s = tokens.shape[1]
-    cos, sin = rope_tables(s, cfg.head_dim, cfg.rope_theta)
+    cos, sin = rope_tables(s, cfg.rope_dim, cfg.rope_theta)
     x = constrain(params["embed"][tokens], _RESIDUAL)
 
     def layer_of(kind):
@@ -864,7 +1055,23 @@ def hidden_states(
     (x, aux), _ = _through_layers(
         cfg, layer_of, (x, jnp.zeros((), jnp.float32)), params
     )
-    x = constrain(x, _RESIDUAL)
+    return constrain(x, _RESIDUAL), aux, layer_of
+
+
+def hidden_states(
+    params: Dict,
+    tokens: jax.Array,  # int32 [batch, seq]
+    cfg: LlamaConfig,
+    attn_fn=None,
+    constrain=None,
+    expert_parallel: bool = False,
+) -> Tuple[jax.Array, jax.Array]:
+    """Final-norm hidden states [batch, seq, hidden] + MoE aux loss.
+    ``expert_parallel``: the experts are sharded over an ``expert``
+    mesh axis (the trainer says so from its mesh)."""
+    x, aux, _ = _run_stack(
+        params, tokens, cfg, attn_fn, constrain, expert_parallel
+    )
     return rms_norm(x, params["final_norm"], cfg.norm_eps), aux
 
 
@@ -938,28 +1145,106 @@ def _chunked_ce(x: jax.Array, lm_head: jax.Array, targets: jax.Array,
     return nll_sum, cnt
 
 
+def _mean_ce(x, head, targets, chunk: int) -> jax.Array:
+    """Mean cross entropy of the normed states ``x`` through ``head``
+    over the positions whose target is >= 0."""
+    if chunk > 0:
+        nll_sum, cnt = _chunked_ce(x, head, targets, chunk)
+    else:
+        logits = (x @ head).astype(jnp.float32)
+        nll_sum, cnt = _masked_nll(logits, targets)
+    return nll_sum / jnp.maximum(cnt, 1.0)
+
+
+def _mtp_states(cfg: LlamaConfig, params, module, x, ahead, layer_of):
+    """``(normed hidden states, aux)`` of the prediction module
+    ``module``: position i's state ``x[i]`` out of the stack (before
+    the final norm) and the embedding of the token after it,
+    ``ahead[i]``, each normed, side by side through ``eh_proj``; one
+    block of the stack's last kind; the module's own final norm."""
+    with jax.named_scope("mtp.merge"):
+        merged = jnp.concatenate([
+            rms_norm(params["embed"][ahead], module["embed_norm"],
+                     cfg.norm_eps),
+            rms_norm(x, module["hidden_norm"], cfg.norm_eps),
+        ], axis=-1) @ module["eh_proj"]
+    with jax.named_scope("mtp.block"):
+        (x, aux), _ = layer_of(cfg.layer_plan()[1][-1])(
+            (merged, jnp.zeros((), jnp.float32)), module["block"]
+        )
+    return rms_norm(x, module["final_norm"], cfg.norm_eps), aux
+
+
+def _losses(params, batch, cfg: LlamaConfig, attn_fn=None,
+            constrain=None, expert_parallel: bool = False):
+    """``(the main head's mean cross entropy, the prediction module's
+    (0 without one), the scaled aux losses of every expert layer)``.
+
+    The prediction module reads position i's state and token i + 1
+    and is scored, through the model's own head, on token i + 2
+    (``targets[i + 1]``): a sequence's last position has no token
+    after it (the roll hands it the first, and its target masks it
+    out) and its last two no target."""
+    tokens, targets = batch
+    x, aux, layer_of = _run_stack(
+        params, tokens, cfg, attn_fn, constrain, expert_parallel
+    )
+    normed = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    head = _head(params, cfg)
+    ce = _mean_ce(normed, head, targets, cfg.loss_chunk)
+    if not cfg.mtp_layers:
+        return ce, jnp.zeros((), jnp.float32), aux
+    (module,) = params["mtp"]
+    y, mtp_aux = _mtp_states(
+        cfg, params, module, x, jnp.roll(tokens, -1, axis=1), layer_of
+    )
+    with jax.named_scope("mtp.head"):
+        mtp_ce = _mean_ce(
+            y, head,
+            jnp.pad(targets[:, 1:], ((0, 0), (0, 1)), constant_values=-1),
+            cfg.loss_chunk,
+        )
+    return ce, mtp_ce, aux + mtp_aux
+
+
 def next_token_loss(
     params: Dict, batch: Tuple[jax.Array, jax.Array], cfg: LlamaConfig,
     attn_fn=None, constrain=None, expert_parallel: bool = False,
 ) -> jax.Array:
     """Mean next-token cross entropy (plus, for an expert config, the
-    scaled balance and z losses of every layer). batch = (tokens,
-    targets), both int32 [batch, seq]; target < 0 masks the position
-    out. ``constrain``, ``expert_parallel``: see ``hidden_states``."""
-    tokens, targets = batch
-    x, aux = hidden_states(
-        params, tokens, cfg, attn_fn=attn_fn, constrain=constrain,
-        expert_parallel=expert_parallel,
+    scaled balance and z losses of every layer, and with a prediction
+    module its own mean cross entropy at ``mtp_loss_weight``). batch
+    = (tokens, targets), both int32 [batch, seq]; target < 0 masks
+    the position out. ``constrain``, ``expert_parallel``: see
+    ``hidden_states``."""
+    ce, mtp_ce, aux = _losses(
+        params, batch, cfg, attn_fn, constrain, expert_parallel
     )
-    if cfg.loss_chunk > 0:
-        nll_sum, cnt = _chunked_ce(
-            x, _head(params, cfg), targets, cfg.loss_chunk
-        )
-    else:
-        logits = (x @ _head(params, cfg)).astype(jnp.float32)
-        nll_sum, cnt = _masked_nll(logits, targets)
-    ce = nll_sum / jnp.maximum(cnt, 1.0)
+    if cfg.mtp_layers:
+        ce = ce + cfg.mtp_loss_weight * mtp_ce
     return ce + aux  # aux arrives scaled (the config's coefficients)
+
+
+def mtp_loss(params: Dict, batch, cfg: LlamaConfig, attn_fn=None):
+    """The prediction module's own mean cross entropy on ``batch``,
+    unweighted: what ``next_token_loss`` adds at
+    ``mtp_loss_weight``."""
+    return _losses(params, batch, cfg, attn_fn)[1]
+
+
+def set_mtp_loss_gauge(value) -> float:
+    """Set the gauge ``mtp_loss`` (``GET /metrics``) to ``mtp_loss``'
+    value at an evaluation: beside the step's loss it says whether
+    the prediction module learns with the trunk."""
+    from dlrover_tpu.telemetry.registry import gauge
+
+    value = float(value)
+    gauge(
+        "mtp_loss",
+        "mean cross entropy of the multi-token prediction module, "
+        "unweighted, at the last evaluation",
+    ).set(value)
+    return value
 
 
 def _routed(params, tokens, cfg: LlamaConfig, attn_fn, stat):
@@ -973,7 +1258,7 @@ def _routed(params, tokens, cfg: LlamaConfig, attn_fn, stat):
 
     if attn_fn is None:
         attn_fn = partial(flash_attention, causal=True)
-    cos, sin = rope_tables(tokens.shape[1], cfg.head_dim, cfg.rope_theta)
+    cos, sin = rope_tables(tokens.shape[1], cfg.rope_dim, cfg.rope_theta)
 
     def layer_of(kind):
         operate = _operator_of(cfg, attn_fn, kind)
@@ -1041,12 +1326,14 @@ def bias_changed_stats(params: Dict, tokens: jax.Array,
 def flops_per_token(cfg: LlamaConfig, seq_len: int) -> float:
     """Approximate training FLOPs per token (6N_active + attention
     quadratic, at ``num_heads x head_dim`` and by each layer's kind;
-    the convolution's taps are not counted). For MoE, only the top-k
-    routed experts execute per token, so N counts k experts — not all
-    E."""
+    the convolution's taps are not counted), a prediction module's
+    block and second pass through the head included. For MoE, only
+    the top-k routed experts execute per token, so N counts k experts
+    — not all E."""
     n = param_count(cfg)
     if not cfg.tie_word_embeddings:
         n -= cfg.vocab_size * cfg.hidden_size  # tied-ish
+    n += cfg.mtp_layers * cfg.vocab_size * cfg.hidden_size
     kinds = _layers_of_each_kind(cfg)
     if cfg.num_experts > 0:
         h, m = cfg.hidden_size, cfg.moe_intermediate_size
@@ -1062,4 +1349,9 @@ def flops_per_token(cfg: LlamaConfig, seq_len: int) -> float:
         count * min(kind.window or seq_len, seq_len)
         for kind, count in kinds if kind.operator != "conv"
     )
-    return 6.0 * n + 12 * cfg.num_heads * cfg.head_dim * keys
+    # a head's scores contract over q and k's width, its weighted
+    # values are v's wide
+    qk, v = cfg.head_dim, cfg.head_dim
+    if cfg.latent:
+        qk, v = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.v_head_dim
+    return 6.0 * n + 6 * cfg.num_heads * (qk + v) * keys

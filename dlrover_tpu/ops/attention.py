@@ -21,7 +21,7 @@ NEG_INF = -1e30
 def mha_reference(
     q: jax.Array,  # [batch, q_len, heads, head_dim]
     k: jax.Array,  # [batch, kv_len, kv_heads, head_dim]
-    v: jax.Array,  # [batch, kv_len, kv_heads, head_dim]
+    v: jax.Array,  # [batch, kv_len, kv_heads, v_head_dim]
     causal: bool = True,
     scale: Optional[float] = None,
     mask: Optional[jax.Array] = None,  # bool [q_len, kv_len], True=keep
@@ -69,7 +69,7 @@ def mha_reference(
     l = jnp.sum(p, axis=-1, keepdims=True)
     l_safe = jnp.where(l == 0.0, 1.0, l)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", p / l_safe, vf)
-    out = out.reshape(b, qlen, h, d).astype(q.dtype)
+    out = out.reshape(b, qlen, h, v.shape[3]).astype(q.dtype)
     if not return_lse:
         return out
     lse = (m + jnp.log(l_safe))[..., 0]  # [b, kvh, group, qlen]
@@ -96,7 +96,9 @@ def flash_attention(
     (CPU tests, rehearsals) the dense reference, which no TPU run
     reaches at a shape the kernel takes.
 
-    Layout [batch, seq, heads, head_dim] (the models' native layout).
+    Layout [batch, seq, heads, head_dim] (the models' native layout);
+    v, and so the result, may have a width of its own, and the default
+    ``scale`` is q and k's ``head_dim ** -0.5``.
     ``block_q``/``block_k`` cap the kernel block sizes; the GQA group
     folds into the kernel's matmul rows, so the effective q-block is
     ``group * block_q`` rows. The blocks are ``ops/tuning.py``'s static
@@ -126,6 +128,8 @@ def flash_attention(
         kernel="flash_attention", seq=seq, head_dim=q.shape[3],
         gqa_group=group, dtype=jnp.dtype(q.dtype).name, causal=causal,
         block_q=bq, block_k=bk, window=window,
+        # v's width where it is not q and k's (latent attention)
+        **({} if v.shape[3] == q.shape[3] else {"v_head_dim": v.shape[3]}),
     )
     return flash_attention_tpu(
         q, k, v, causal=causal, scale=scale, block_q=bq, block_k=bk,

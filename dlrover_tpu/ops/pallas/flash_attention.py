@@ -55,6 +55,15 @@ Where no edge is given, and without ``causal``, the body is the whole
 block's single product as before. ``causal_tile_census`` counts what
 the walk skips.
 
+v may be narrower or wider than q and k (latent attention: q and k
+192 wide, v 128). Nothing in a kernel's body knows: the scores
+contract over q and k's width, the forward's accumulator, o, dO and
+dV are v's wide, dQ and dK q's. A block's last dimension is then the
+array's whole width, a lane and a half at 192. The one-kernel rule
+reads the wider of the two; a head's resident dQ of 8,192 x 192 is
+past the default scoped limit's room, and that call states what it
+takes as the grouped one does.
+
 A window (``window``, static: query i sees key j iff ``j <= i`` and
 ``i - j < window``) is a second edge, under the diagonal. A grid block
 wholly under it is skipped as one above the diagonal is: not computed
@@ -428,8 +437,11 @@ def _kv_index(causal, block_q, block_k, window=None):
 
 def _fwd(q, k, v, scale, causal, block_q, block_k, window=None):
     """q: [bk_h, g, seq, d]; k,v: [bk_h, seq, d] ->
-    (o [bk_h, g, seq, d], lse [bk_h, g, 1, seq] f32)."""
+    (o [bk_h, g, seq, dv], lse [bk_h, g, 1, seq] f32). ``v`` may be
+    narrower or wider than q and k (``dv``): the scores contract over
+    ``d``, the result and its accumulator are ``dv`` wide."""
     bkh, g, seq, d = q.shape
+    dv = v.shape[-1]
     block_q = min(block_q, seq)
     block_k = min(block_k, seq)
     _check_blocks(seq, block_q, block_k)
@@ -445,22 +457,22 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, window=None):
         in_specs=[
             pl.BlockSpec((1, g, block_q, d), lambda b, i, j: (b, 0, i, 0)),
             pl.BlockSpec((1, block_k, d), kv_idx),
-            pl.BlockSpec((1, block_k, d), kv_idx),
+            pl.BlockSpec((1, block_k, dv), kv_idx),
         ],
         out_specs=[
-            pl.BlockSpec((1, g, block_q, d), lambda b, i, j: (b, 0, i, 0)),
+            pl.BlockSpec((1, g, block_q, dv), lambda b, i, j: (b, 0, i, 0)),
             # [bkh, g, 1, seq]: keeps the lse block's last two dims
             # (1, block_q) under the TPU (8,128)-or-full tiling rule
             pl.BlockSpec((1, g, 1, block_q), lambda b, i, j: (b, 0, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bkh, g, seq, d), q.dtype),
+            jax.ShapeDtypeStruct((bkh, g, seq, dv), q.dtype),
             jax.ShapeDtypeStruct((bkh, g, 1, seq), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((g * block_q, LANES), jnp.float32),
             pltpu.VMEM((g * block_q, LANES), jnp.float32),
-            pltpu.VMEM((g * block_q, d), jnp.float32),
+            pltpu.VMEM((g * block_q, dv), jnp.float32),
         ],
         interpret=_interpret(),
     )(q, k, v)
@@ -685,8 +697,15 @@ def _dq_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 #: what a head's float32 dQ may hold of VMEM beside the dK/dV kernel's
 #: own blocks and scratch: the largest that was compiled and timed
-#: (4096 positions of 128, under the default scoped limit)
-DQ_RESIDENT_BYTES = 2 * 1024 * 1024
+#: (8,192 positions of 192, latent attention's: in the cell 727 ms of
+#: ``attn_kernel_ms`` against the pair's 881, 18,579 tokens/s against
+#: 17,220; PERF.md section 6, PR 42)
+DQ_RESIDENT_BYTES = 6 * 1024 * 1024
+#: up to here the head's dQ fits beside the rest under the default
+#: scoped limit (4096 positions of 128) and the call is the one it
+#: always was; past it the call states what it needs
+#: (``_dq_resident_vmem_bytes``)
+DQ_UNSTATED_BYTES = 2 * 1024 * 1024
 #: what a kv head's float32 dK and dV may hold of VMEM beside the dQ
 #: kernel's own blocks and scratch, rows padded to whole lanes: the
 #: largest that was compiled and timed (16,384 positions of 128; the
@@ -712,7 +731,8 @@ def _one_backward_kernel(g, seq, head_dim):
     and backward of a call; PERF.md section 6, PRs 33 and 37): 4.68 ms
     against the pair's 5.08 at one (1024, 1024) block a 64-wide head,
     6.46 against 7.83 at 4 x 4 such blocks of a 128-wide head, where
-    dQ's rows are sliced at an offset from ``program_id``."""
+    dQ's rows are sliced at an offset from ``program_id``.
+    ``head_dim``: the wider of q and k's and v's, where they differ."""
     if g == 1:
         return seq * head_dim * 4 <= DQ_RESIDENT_BYTES
     return 2 * seq * _lanes(head_dim) * 4 <= DKV_RESIDENT_BYTES
@@ -736,11 +756,20 @@ def _dkv_resident_vmem_bytes(seq, head_dim, itemsize):
             + OTHER_VMEM_BYTES)
 
 
+def _dq_resident_vmem_bytes(seq, head_dim, itemsize):
+    """What ``_dqkv_kernel`` asks of VMEM where it has to ask: the
+    head's float32 dQ and its whole-head output block (two buffers),
+    rows of whole lanes, and ``OTHER_VMEM_BYTES``."""
+    return (seq * _lanes(head_dim) * (4 + 2 * itemsize)
+            + OTHER_VMEM_BYTES)
+
+
 def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
          window=None):
     from dlrover_tpu.telemetry.registry import gauge
 
     bkh, g, seq, d = q.shape
+    dv = v.shape[-1]  # v, o and dO's width; q, k and dQ are d wide
     block_q = min(block_q, seq)
     block_k = min(block_k, seq)
     _check_blocks(seq, block_q, block_k)
@@ -749,7 +778,7 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
     )[:, :, None, :]  # [bkh, g, 1, seq] (4-D for TPU block tiling)
 
     form = "pair"
-    if _one_backward_kernel(g, seq, d):
+    if _one_backward_kernel(g, seq, max(d, dv)):
         form = "dq_resident" if g == 1 else "dkv_resident"
     gauge(
         "attn_backward_kernels",
@@ -785,30 +814,30 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
             in_specs=[
                 pl.BlockSpec((1, g, block_q, d), q_idx),
                 pl.BlockSpec((1, block_k, d), kv_idx),  # k
-                pl.BlockSpec((1, block_k, d), kv_idx),  # v
-                pl.BlockSpec((1, g, block_q, d), q_idx),
+                pl.BlockSpec((1, block_k, dv), kv_idx),  # v
+                pl.BlockSpec((1, g, block_q, dv), q_idx),
                 pl.BlockSpec((1, g, 1, block_q), lse_idx),
                 pl.BlockSpec((1, g, 1, block_q), lse_idx),
             ],
             out_specs=[pl.BlockSpec((1, g, block_q, d), q_idx)] + resident * [
                 pl.BlockSpec((1, seq, d), lambda b, i, j: (b, 0, 0)),
-                pl.BlockSpec((1, seq, d), lambda b, i, j: (b, 0, 0)),
+                pl.BlockSpec((1, seq, dv), lambda b, i, j: (b, 0, 0)),
             ],
             out_shape=[
                 jax.ShapeDtypeStruct((bkh, g, seq, d), q.dtype),
             ] + resident * [
                 jax.ShapeDtypeStruct((bkh, seq, d), k.dtype),
-                jax.ShapeDtypeStruct((bkh, seq, d), v.dtype),
+                jax.ShapeDtypeStruct((bkh, seq, dv), v.dtype),
             ],
             scratch_shapes=[
                 pltpu.VMEM((g * block_q, d), jnp.float32),
             ] + resident * [
                 pltpu.VMEM((seq, d), jnp.float32),
-                pltpu.VMEM((seq, d), jnp.float32),
+                pltpu.VMEM((seq, dv), jnp.float32),
             ],
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=_dkv_resident_vmem_bytes(
-                    seq, d, q.dtype.itemsize)
+                    seq, max(d, dv), q.dtype.itemsize)
             ) if resident else None,
             interpret=_interpret(),
         )(q, k, v, do, lse, delta)
@@ -843,8 +872,8 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
             in_specs=[
                 pl.BlockSpec((1, g, block_q, d), q_side_idx(True)),
                 pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),  # k
-                pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),  # v
-                pl.BlockSpec((1, g, block_q, d), q_side_idx(True)),
+                pl.BlockSpec((1, block_k, dv), lambda b, j, i: (b, j, 0)),  # v
+                pl.BlockSpec((1, g, block_q, dv), q_side_idx(True)),
                 pl.BlockSpec((1, g, 1, block_q), q_side_idx(False)),
                 pl.BlockSpec((1, g, 1, block_q), q_side_idx(False)),
             ],
@@ -852,18 +881,22 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
                 pl.BlockSpec((1, 1, seq, d), lambda b, j, i: (b, 0, 0, 0)),
             ] + [
                 pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+                pl.BlockSpec((1, block_k, dv), lambda b, j, i: (b, j, 0)),
             ],
             out_shape=resident * [
                 jax.ShapeDtypeStruct((bkh, 1, seq, d), q.dtype),
             ] + [
                 jax.ShapeDtypeStruct((bkh, seq, d), k.dtype),
-                jax.ShapeDtypeStruct((bkh, seq, d), v.dtype),
+                jax.ShapeDtypeStruct((bkh, seq, dv), v.dtype),
             ],
             scratch_shapes=resident * [pltpu.VMEM((seq, d), jnp.float32)] + [
                 pltpu.VMEM((block_k, d), jnp.float32),
-                pltpu.VMEM((block_k, d), jnp.float32),
+                pltpu.VMEM((block_k, dv), jnp.float32),
             ],
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_dq_resident_vmem_bytes(
+                    seq, d, q.dtype.itemsize)
+            ) if resident and seq * d * 4 > DQ_UNSTATED_BYTES else None,
             interpret=_interpret(),
         )(q, k, v, do, lse, delta)
 
@@ -902,7 +935,7 @@ _flash_gqa.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 def flash_attention_tpu(
     q: jax.Array,  # [batch, seq, heads, head_dim]
     k: jax.Array,  # [batch, seq, kv_heads, head_dim]
-    v: jax.Array,
+    v: jax.Array,  # [batch, seq, kv_heads, v_head_dim]
     causal: bool = True,
     scale: Optional[float] = None,
     block_q: int = 512,
@@ -912,7 +945,9 @@ def flash_attention_tpu(
     """Flash attention in the models' [batch, seq, heads, head_dim]
     layout; GQA folded into the kernels' matmul rows (no KV repeat).
     ``window``: query i sees key j iff ``j <= i`` and ``i - j <
-    window`` (causal only); one that reaches every key is no window."""
+    window`` (causal only); one that reaches every key is no window.
+    ``v`` may have a width of its own, which is the result's; the
+    default ``scale`` is q and k's ``head_dim ** -0.5``."""
     b, s, h, d = q.shape
     if window is not None:
         if not causal or window < 1:
@@ -929,13 +964,13 @@ def flash_attention_tpu(
     qg = q.transpose(0, 2, 1, 3).reshape(b * kvh, g, s, d)
 
     def kv_layout(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * kvh, s, d)
+        return x.transpose(0, 2, 1, 3).reshape(b * kvh, s, x.shape[3])
 
     o = _flash_gqa(
         qg, kv_layout(k), kv_layout(v), scale, causal, block_q, block_k,
         window,
     )
-    return o.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+    return o.reshape(b, h, s, v.shape[3]).transpose(0, 2, 1, 3)
 
 
 def _interpret() -> bool:

@@ -257,6 +257,8 @@ def route_logits(
     z_coef: float = Z_LOSS_COEF,
     gate: str = "softmax",
     bias: jax.Array = None,  # [E] float32
+    norm_eps: float = None,
+    scaling: float = 1.0,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Router of the dropless path: ``(weights [N, k] float32,
     experts int32 [N, k], aux)``. The weights are the float32
@@ -269,7 +271,10 @@ def route_logits(
     the z-loss.
 
     ``gate`` "sigmoid" scores each expert by itself (a sum over the k
-    then takes ``Lfm2MoeSparseMoeBlock``'s 1e-6 beside it). ``bias``:
+    then takes ``Lfm2MoeSparseMoeBlock``'s 1e-6 beside it, or the
+    ``norm_eps`` given in its place: ``DeepseekV3TopkRouter``'s is
+    1e-20). ``scaling``: a factor on the weights, after the sum
+    (``routed_scaling_factor``). ``bias``:
     the k experts are the top-k of score plus bias and the weights
     the scores there, without it; the bias is a buffer that no
     gradient reaches (a balance kept by moving it is the trainer's
@@ -292,9 +297,13 @@ def route_logits(
     )
     if norm_topk_prob and k > 1:
         total = jnp.sum(weights, axis=-1, keepdims=True)
-        if gate == "sigmoid":
-            total = total + 1e-6
+        if norm_eps is None and gate == "sigmoid":
+            norm_eps = 1e-6
+        if norm_eps:
+            total = total + norm_eps
         weights = weights / total
+    if scaling != 1.0:
+        weights = weights * scaling
     return weights, experts, aux
 
 
@@ -567,14 +576,20 @@ def dropless_moe_mlp(
     logits: jax.Array = None,  # [batch, seq, experts] float32
     act: str = "silu",
     first_held: int = 0,
-    **routing,  # ``route_logits``' gate and bias
+    shared: Tuple[jax.Array, jax.Array, jax.Array] = None,
+    **routing,  # ``route_logits``' gate, bias, norm_eps and scaling
 ) -> Tuple[jax.Array, jax.Array]:
     """MoE gated block (``act(gate) * up``, then down) in which every
     one of the ``N x k`` assignments to an expert on this device is
     computed: ``(out [batch, seq, hidden], aux)``, ``aux`` scaled as
     ``moe_mlp``'s. ``logits``: the router's, where the model computes
     them elsewhere than from ``x`` (``router_logits``); else from
-    ``x`` here.
+    ``x`` here. ``shared``: the gate, up and down matrices ([hidden,
+    mlp'], [hidden, mlp'], [mlp', hidden]) of an expert that every
+    token takes, unweighted and whole on every device, added to
+    ``out`` (scope ``moe.shared``): of the devices that share a layer
+    each computes it for its own tokens, so over the shares it counts
+    once.
 
     The four scopes name every device op's ``op_name``: ``moe.route``
     (router, softmax, top-k, aux losses), ``moe.dispatch`` (stable
@@ -620,11 +635,20 @@ def dropless_moe_mlp(
             logits.reshape(n, e), k, norm_topk_prob, balance_coef,
             z_coef, **routing,
         )
+    def with_shared(out):
+        if shared is None:
+            return out
+        with jax.named_scope("moe.shared"):
+            ws_gate, ws_up, ws_down = shared
+            return out + (
+                ACTIVATIONS[act](x @ ws_gate) * (x @ ws_up)
+            ) @ ws_down
+
     if held < e:
         out = _share(
             flat, weights, experts, w_gate, w_up, w_down, act, first_held
         )
-        return out.reshape(b, s, h), aux
+        return with_shared(out.reshape(b, s, h)), aux
     with jax.named_scope("moe.dispatch"):
         assigned = experts.reshape(n * k)
         order = jnp.argsort(assigned, stable=True).astype(jnp.int32)
@@ -651,7 +675,7 @@ def dropless_moe_mlp(
     with jax.named_scope("moe.combine"):
         mine = _to_token_order(rows, order, inverse).reshape(n, k, h)
         out = jnp.sum(mine.astype(jnp.float32), axis=1).astype(x.dtype)
-    return out.reshape(b, s, h), aux
+    return with_shared(out.reshape(b, s, h)), aux
 
 
 def tokens_per_expert(

@@ -361,7 +361,7 @@ def pipeline_llama_forward(
     if attn_fn is None:
         attn_fn = functools.partial(flash_attention, causal=True)
     s = tokens.shape[1]
-    cos, sin = llama.rope_tables(s, cfg.head_dim, cfg.rope_theta)
+    cos, sin = llama.rope_tables(s, cfg.rope_dim, cfg.rope_theta)
     x = params["embed"][tokens]
 
     lead, (kind, *others) = cfg.layer_plan()

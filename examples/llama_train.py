@@ -50,6 +50,7 @@ MODELS = {
     "llama_1b": functools.partial(llama.llama_1b, remat="dots_attn_out"),
     # 4 experts, top-2: dropless on one device (parallel/moe.py)
     "llama_moe_tiny": llama.llama_moe_tiny,
+    "llama_latent_tiny": llama.llama_latent_tiny,
 }
 
 
@@ -218,6 +219,10 @@ def main():
                 functools.partial(llama.bias_changed_stats, cfg=cfg)
             )
 
+    mtp_loss = None
+    if cfg.mtp_layers:
+        mtp_loss = jax.jit(functools.partial(llama.mtp_loss, cfg=cfg))
+
     device = devices[0]
     step, loss, losses = start_step, None, []
     first_step_done = False
@@ -294,6 +299,15 @@ def main():
                         )
                         line += f" bias_changed={changed:.3f}"
                     print(line, flush=True)
+                if mtp_loss is not None:
+                    # the prediction module's own term of the loss
+                    # on this step's first microbatch, beside the
+                    # step's loss (GET /metrics)
+                    term = llama.set_mtp_loss_gauge(
+                        mtp_loss(params, (mb[0][0], mb[1][0]))
+                    )
+                    print(f"MTP_LOSS step={step} loss={float(loss):.4f} "
+                          f"mtp_loss={term:.4f}", flush=True)
                 ckpt.save(
                     step,
                     {"params": params, "opt_state": opt_state,

@@ -127,23 +127,43 @@ MODULE_BUDGET_OVERRIDES = {
     # (PR 35)
     "test_moe_share_walk": 90.0,
     "test_attention_window": 150.0,
-    "test_llama_pattern": 180.0,
+    # since PR 42 the share test with a shared expert too, six cases
+    # more: 158 s alone, 270 s beside five other workers
+    "test_llama_pattern": 340.0,
     # nine-layer hybrid models jitted forward and backward under each
     # remat policy, the convolution's kernels in interpret mode: 70 s
-    # alone (PR 36)
-    "test_llama_hybrid": 150.0,
+    # alone (PR 36), 175 s beside five other workers (PR 42)
+    "test_llama_hybrid": 220.0,
+    # the latent model and its prediction module jitted forward and
+    # backward under each remat policy, a trainer over eight CPU
+    # devices: 85 s alone (PR 42)
+    "test_llama_latent": 220.0,
+    # the dropless layer at every remat policy: 71 s beside five other
+    # workers (PR 42)
+    "test_moe_dropless": 100.0,
     # eleven changed references and a changed program jitted at the
     # tiny size, nine layers each: 75 s alone (PR 36)
     "test_yardstick_lfm2": 150.0,
     # launcher, agent, worker and coworkers at thirteen tiny layers:
     # 45 s alone, 64 s beside three other workers (PR 36)
     "test_yardstick_lfm2_rehearsal": 120.0,
+    # eighteen changed references jitted at the tiny size on two
+    # batches, the program under three remat policies: 80 s alone,
+    # 312 s beside five other workers, whose cores the compiler of
+    # each edited module wants too (PR 42)
+    "test_yardstick_joyai": 400.0,
+    # launcher, agent, worker and coworkers at three tiny layers and
+    # the prediction module: 40 s alone (PR 42)
+    "test_yardstick_joyai_rehearsal": 120.0,
     # two traced rehearsals (launcher, agent, worker, coworkers), one
     # of them holding a step for a second: 35 s alone, 84 s beside five
     # other workers (PR 38)
     "test_yardstick_host_stall": 150.0,
     "test_context_parallel": 180.0,
-    "test_flash_attention": 180.0,
+    # since PR 42 the kernels at latent attention's (192, 128) too,
+    # one backward kernel and the pair: 195 s alone, 272 s beside five
+    # other workers
+    "test_flash_attention": 340.0,
     "test_gpt": 120.0,
     "test_moe": 120.0,
     "test_parallel": 120.0,

@@ -395,6 +395,37 @@ def test_windowed_kernels_compile_at_smallthinkers_shape(
         re.findall(r'custom_call_target="tpu_custom_call"', text)) == 2
 
 
+def test_latent_kernels_compile_at_joyais_shape(topo, on_tpu_path):
+    """``joyai-llm-flash-ep8.steady``'s attention: four sequences of
+    8,192, 32 heads, q and k 192 wide (a lane and a half), v 128: the
+    rule's (1024, 1024) blocks, the forward kernel and the one
+    backward kernel with a head's float32 dQ resident (6 MiB, 8 in
+    whole lanes), which states what it takes of VMEM, through the
+    dispatch a TPU process takes."""
+    q, v = (
+        jax.ShapeDtypeStruct(
+            (4, 8192, 32, d), jnp.bfloat16,
+            sharding=SingleDeviceSharding(topo.devices[0]),
+        ) for d in (192, 128)
+    )
+    assert tuning.heuristic_blocks(8192, 1) == (1024, 1024)
+    assert fa._one_backward_kernel(1, 8192, 192)
+    compiled = jax.jit(_sum_grad(attention.flash_attention)).lower(
+        q, q, v).compile()
+    kernels = re.findall(
+        r"%([\w.\-]+) = ([^\n]*?) custom-call\([^\n]*"
+        r"custom_call_target=\"tpu_custom_call\"", compiled.as_text())
+    assert len(kernels) == 2
+    asked = [int(n) for n in re.findall(
+        r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"',
+        compiled.as_text())]
+    assert max(asked) == 16 * 2 ** 20 + fa.OTHER_VMEM_BYTES
+    # o and dv 128 wide, dq and dk 192
+    assert sorted(re.findall(r"8192,(\d+)\]", " ".join(
+        result for _, result in kernels))) == ["128", "128", "192", "192"]
+    assert tuning.last_selection()["v_head_dim"] == 128
+
+
 def _lowered_kernels(fn, *args):
     """A lowering's text with each ``tpu_custom_call``'s payload taken
     out, and the payloads' Mosaic modules printed without source

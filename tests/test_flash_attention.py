@@ -361,3 +361,93 @@ def test_a_head_too_long_for_vmem_keeps_two_backward_kernels(monkeypatch, g):
         q[:, :256], k[:, :256], v[:, :256],
     )[1:] == ["_dqkv_kernel" if g == 1 else "_dq_dkv_kernel"]
 
+
+
+# -- q and k wider than v (latent attention) ---------------------------------
+
+# [seq, block_q, block_k, causal]: q and k 192 wide (a lane and a
+# half), v, and so o, dO and dV, 128 wide; no group. Several blocks
+# each way, one block walked in sub-tiles of 256, and no mask
+UNEQUAL_WIDTHS = [
+    (256, 128, 128, True),
+    (512, 512, 512, True),
+    (256, 64, 128, True),
+    (256, 128, 128, False),
+]
+
+
+@pytest.fixture(scope="module", params=UNEQUAL_WIDTHS, ids=str)
+def unequal_widths(request):
+    """o, dq, dk, dv at (192, 128) of the kernels with the one
+    backward kernel, with the dq and dk/dv pair (what a head of 8,192
+    positions took until the rule admitted its dQ), and of the
+    reference."""
+    seq, block_q, block_k, causal = request.param
+    kq, kk, kv = jax.random.split(jax.random.key(11), 3)
+    q = jax.random.normal(kq, (2, seq, 2, 192))
+    k = jax.random.normal(kk, (2, seq, 2, 192))
+    v = jax.random.normal(kv, (2, seq, 2, 128))
+
+    def attn(q, k, v):
+        return flash_attention_tpu(
+            q, k, v, causal=causal, block_q=block_q, block_k=block_k)
+
+    def ref(q, k, v):
+        return mha_reference(q, k, v, causal=causal)
+
+    def all_four(fn):
+        return dict(zip(
+            ("fwd", "dq", "dk", "dv"), (fn(q, k, v), *_grads(fn, q, k, v))))
+
+    assert attn(q, k, v).shape == (2, seq, 2, 128)
+    assert _kernels_of_grads(attn, q, k, v) == [
+        "_fwd_kernel", "_dqkv_kernel"]
+    one = all_four(attn)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(
+            flash_attention, "_one_backward_kernel", lambda g, seq, d: False)
+        assert _kernels_of_grads(attn, q, k, v) == [
+            "_fwd_kernel", "_dq_kernel", "_dkv_kernel"]
+        pair = all_four(attn)
+    return one, pair, all_four(ref)
+
+
+@pytest.mark.parametrize("what", ["fwd", "dq", "dk", "dv"])
+def test_unequal_widths_match_reference(unequal_widths, what):
+    one, pair, ref = unequal_widths
+    assert one[what].shape == ref[what].shape
+    assert ref[what].shape[-1] == (128 if what in ("fwd", "dv") else 192)
+    for got in (one, pair):
+        np.testing.assert_allclose(
+            got[what], ref[what], rtol=5e-3, atol=5e-3, err_msg=what)
+
+
+def test_the_default_scale_is_q_and_ks_width():
+    """192 ** -0.5, not v's 128 ** -0.5, in the kernel and in the
+    reference alike."""
+    kq, kk, kv = jax.random.split(jax.random.key(12), 3)
+    q = jax.random.normal(kq, (1, 128, 1, 192))
+    k = jax.random.normal(kk, (1, 128, 1, 192))
+    v = jax.random.normal(kv, (1, 128, 1, 128))
+    want = mha_reference(q, k, v, scale=192 ** -0.5)
+    np.testing.assert_array_equal(mha_reference(q, k, v), want)
+    np.testing.assert_allclose(
+        flash_attention_tpu(q, k, v, block_q=128, block_k=128), want,
+        rtol=2e-3, atol=2e-3)
+    other = mha_reference(q, k, v, scale=128 ** -0.5)
+    assert float(jnp.abs(other - want).max()) > 0.01
+
+
+def test_a_long_head_at_latent_widths_keeps_its_dq_resident():
+    """The cell's shape: 8,192 positions of 192 are 6 MiB of float32
+    dQ, the budget's edge; past what fits beside the rest under the
+    default scoped limit the call states its VMEM (rows of whole
+    lanes: the float32 sum and two buffers of the output block, 16
+    MiB, and ``OTHER_VMEM_BYTES``)."""
+    rule = flash_attention._one_backward_kernel
+    assert rule(1, 8192, 192) and not rule(1, 16384, 192)
+    assert 8192 * 192 * 4 == flash_attention.DQ_RESIDENT_BYTES
+    assert 8192 * 192 * 4 > flash_attention.DQ_UNSTATED_BYTES
+    assert 4096 * 128 * 4 <= flash_attention.DQ_UNSTATED_BYTES  # OLMoE
+    assert flash_attention._dq_resident_vmem_bytes(8192, 192, 2) == (
+        8192 * 256 * 8 + flash_attention.OTHER_VMEM_BYTES)

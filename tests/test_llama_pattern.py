@@ -310,26 +310,58 @@ def _one_layer(seed, router_bias=None, n=(2, 16), h=32, m=16, e=8):
     ("every assignment on one share", [50, 50, 50, 50, 0, 0, 0, 0]),
 ])
 @pytest.mark.parametrize("shares", [2, 4])
-def test_the_shares_parts_add_up_to_the_uncut_layer(case, bias, shares):
+@pytest.mark.parametrize("shared", [False, True], ids=[
+    "routed alone", "a shared expert and 2.5 on the weights"])
+def test_the_shares_parts_add_up_to_the_uncut_layer(
+        case, bias, shares, shared):
     """The guide's share test: the parts of one layer's result that
     all the shares give add up to what the layer that holds every
     expert gives, forward and in the gradient of its input; routing,
-    weights and aux are each share's own copy of the same numbers."""
+    weights and aux are each share's own copy of the same numbers.
+    With ``shared``: every share computes the shared expert's term
+    alike, for its own tokens, so over the shares it counts once; the
+    routed weights carry the factor, the shared term does not."""
     x, gate_w, w_gate, w_up, w_down, logits = _one_layer(3, bias)
     kw = dict(k=3, norm_topk_prob=True, z_coef=0.0, act="relu",
               logits=logits)
+    alike = lambda x: 0.0  # noqa: E731: what every share computes alike
+    if shared:
+        keys = jax.random.split(jax.random.key(13), 3)
+        ws_gate, ws_up = (
+            jax.random.normal(k, (32, 24)) * 32 ** -0.5 for k in keys[:2])
+        ws_down = jax.random.normal(keys[2], (24, 32)) * 24 ** -0.5
+        kw.update(gate="sigmoid", norm_eps=1e-20, scaling=2.5,
+                  shared=(ws_gate, ws_up, ws_down))
+        alike = lambda x: (  # noqa: E731
+            jax.nn.relu(x @ ws_gate) * (x @ ws_up)) @ ws_down
 
     def part(x, first, held):
         cut = slice(first, first + held)
-        return moe.dropless_moe_mlp(
+        out, aux = moe.dropless_moe_mlp(
             x, gate_w, w_gate[cut], w_up[cut], w_down[cut],
             first_held=first, **kw)
+        return out - alike(x), aux  # the share's routed part
 
-    whole, aux = part(x, 0, 8)
+    def uncut(x):
+        out, aux = part(x, 0, 8)
+        return out + alike(x), aux  # the shared term counted once
+
+    whole, aux = uncut(x)
     held = 8 // shares
     parts = [part(x, first, held) for first in range(0, 8, held)]
     np.testing.assert_allclose(
-        sum(out for out, _ in parts), whole, rtol=1e-5, atol=1e-5)
+        sum(out for out, _ in parts) + alike(x), whole,
+        rtol=1e-5, atol=1e-5)
+    if shared:
+        assert float(jnp.abs(alike(x)).max()) > 0.1
+        # the factor is on the routed weights alone: the same layer
+        # at 1 gives the routed parts' sum over 2.5 and the same
+        # shared term
+        unit = moe.dropless_moe_mlp(
+            x, gate_w, w_gate, w_up, w_down, **{**kw, "scaling": 1.0})[0]
+        np.testing.assert_allclose(
+            2.5 * (unit - alike(x)) + alike(x), whole,
+            rtol=1e-5, atol=1e-5)
     for _, share_aux in parts:
         assert float(share_aux) == pytest.approx(float(aux), rel=1e-6)
     counts = np.asarray(moe.logits_per_expert(logits, 3))
@@ -337,7 +369,8 @@ def test_the_shares_parts_add_up_to_the_uncut_layer(case, bias, shares):
         assert counts.max() == 32 and (counts == 0).sum() == 3
     elif case.startswith("every"):
         assert counts[:4].sum() == 32 * 3
-    if case == "every assignment on one share" and shares == 2:
+    if (case == "every assignment on one share" and shares == 2
+            and not shared):
         np.testing.assert_allclose(
             parts[0][0], whole, rtol=1e-5, atol=1e-5)
         assert float(jnp.abs(parts[1][0]).max()) == 0.0
@@ -345,11 +378,10 @@ def test_the_shares_parts_add_up_to_the_uncut_layer(case, bias, shares):
     def through(fn):
         return jax.grad(lambda x: jnp.sum(fn(x) ** 2))(x)
 
-    summed = through(lambda x: sum(
+    summed = through(lambda x: alike(x) + sum(
         part(x, first, held)[0] for first in range(0, 8, held)))
     np.testing.assert_allclose(
-        summed, through(lambda x: part(x, 0, 8)[0]),
-        rtol=1e-4, atol=1e-5)
+        summed, through(lambda x: uncut(x)[0]), rtol=1e-4, atol=1e-5)
 
 
 def test_a_share_leaves_no_row_of_an_absent_expert_in_a_group():
