@@ -744,38 +744,67 @@ def _pre_attn(cfg: LlamaConfig, x, layer_params, cos, sin,
     return (q, k, v), logits()
 
 
+def _evens_then_odds(w):
+    """The columns of a weight whose products are rotated, in the
+    order ``apply_rope(..., interleaved=True)`` leaves a head's
+    columns in: (0, 2, ..., 1, 3, ...). Taken on the weight, a few
+    megabytes a layer, the product comes out in that order and the
+    rotation is the half-split form with no shuffle of the
+    activations; each column is the dot product it was."""
+    return jnp.concatenate([w[..., 0::2], w[..., 1::2]], axis=-1)
+
+
 def _latent_qkv(cfg: LlamaConfig, y, p, cos, sin, constrain=_free):
-    """q, k [b, s, heads, nope + rope] and v [b, s, heads, v_head_dim]
-    of latent attention from the normed stream ``y``: q through its
-    low-rank projection and an RMSNorm; the un-rotated part of every
-    head's k, and v, through another; the rotated part of k straight
-    from ``y``, one head's, which every head shares. The scores are
-    over a head's whole q and k, so attention's own default scale is
-    ``(nope + rope) ** -0.5``."""
-    b, s, _ = y.shape
-    nh, nope, rope = (cfg.num_heads, cfg.qk_nope_head_dim,
-                      cfg.qk_rope_head_dim)
+    """Latent attention's operands from the normed stream ``y``, in
+    the parts their products make and ``flash_attention`` takes
+    (``_latent_up``): q through its low-rank projection and an
+    RMSNorm; the un-rotated part of every head's k, and v, through
+    another; the rotated part of k straight from ``y``, one head's,
+    which every head shares."""
+    rank, wkv_a = cfg.kv_lora_rank, p["wkv_a"]
+    if cfg.rope_interleave:
+        wkv_a = jnp.concatenate(
+            [wkv_a[:, :rank], _evens_then_odds(wkv_a[:, rank:])], axis=-1
+        )
     with jax.named_scope("mla.q_down"):
         c_q = rms_norm(y @ p["wq_a"], p["q_a_norm"], cfg.norm_eps)
     with jax.named_scope("mla.kv_down"):
-        c_kv, k_rope = jnp.split(
-            y @ p["wkv_a"], [cfg.kv_lora_rank], axis=-1
-        )
+        c_kv, k_rope = jnp.split(y @ wkv_a, [rank], axis=-1)
         c_kv = rms_norm(c_kv, p["kv_a_norm"], cfg.norm_eps)
+    return _latent_up(cfg, c_q, c_kv, k_rope, p, cos, sin, constrain)
+
+
+def _latent_up(cfg: LlamaConfig, c_q, c_kv, k_rope, p, cos, sin,
+               constrain=_free):
+    """``(q_nope, k_nope, v, q_rope, k_rope)`` from the normed latents
+    ``c_q`` and ``c_kv`` and the un-rotated one key ``k_rope`` [b, s,
+    rope]: a head's un-rotated q and k [b, s, heads, nope], v [b, s,
+    heads, v_head_dim], its rotated q [b, s, heads, rope] and the
+    rotated key that every head shares [b, s, 1, rope]. Each part is a
+    product of its own on static columns of ``wq_b`` and ``wkv_b``: no
+    [b, s, heads, nope + rope] array is built, split or shuffled, and
+    the one key is never copied to a head. The scores are over a
+    head's whole q and k, so attention's own default scale is ``(nope
+    + rope) ** -0.5``. With ``rope_interleave`` the rotated columns,
+    q's here and k's as they come, are in ``apply_rope``'s (evens,
+    odds) order, which scores as the source's order does."""
+    nh, nope = cfg.num_heads, cfg.qk_nope_head_dim
+    wq_b = p["wq_b"].reshape(-1, nh, nope + cfg.qk_rope_head_dim)
+    wkv_b = p["wkv_b"].reshape(-1, nh, nope + cfg.v_head_dim)
+    wq_rope = wq_b[..., nope:]
+    if cfg.rope_interleave:
+        wq_rope = _evens_then_odds(wq_rope)
     with jax.named_scope("mla.up"):
-        q = constrain((c_q @ p["wq_b"]).reshape(b, s, nh, -1), _Q)
-        kv = constrain((c_kv @ p["wkv_b"]).reshape(b, s, nh, -1), _KV)
-        q_nope, q_rope = jnp.split(q, [nope], axis=-1)
-        k_nope, v = jnp.split(kv, [nope], axis=-1)
-        q_rope = apply_rope(q_rope, cos, sin, cfg.rope_interleave)
-        k_rope = apply_rope(
-            k_rope[:, :, None, :], cos, sin, cfg.rope_interleave
-        )
-        q = jnp.concatenate([q_nope, q_rope], axis=-1)
-        k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(k_rope, (b, s, nh, rope))], axis=-1
-        )
-    return q, k, v
+
+        def up(c, w, axes):
+            return constrain(jnp.einsum("bsr,rhd->bshd", c, w), axes)
+
+        q_nope = up(c_q, wq_b[..., :nope], _Q)
+        q_rope = apply_rope(up(c_q, wq_rope, _Q), cos, sin)
+        k_nope = up(c_kv, wkv_b[..., :nope], _KV)
+        v = up(c_kv, wkv_b[..., nope:], _KV)
+        k_rope = apply_rope(k_rope[:, :, None, :], cos, sin)
+    return q_nope, k_nope, v, q_rope, k_rope
 
 
 def _expert_mlp(cfg: LlamaConfig, expert_parallel: bool):
@@ -884,7 +913,9 @@ def _operator_of(cfg: LlamaConfig, attn_fn, kind: LayerKind):
     the kinds of call, ``attn.full``, ``attn.window`` and
     ``conv.mix``, in their device ops' ``op_name``, and hands a
     windowed layer's window to ``attn_fn`` (which has to take it);
-    latent attention's call is ``attn.latent``."""
+    latent attention's call is ``attn.latent``, and hands ``attn_fn``
+    the rotated parts as ``q_rope`` and ``k_rope`` (which it has to
+    take, as ``flash_attention`` and ``mha_reference`` do)."""
     if kind.operator == "conv":
 
         def mix(bcu, w):
@@ -894,9 +925,9 @@ def _operator_of(cfg: LlamaConfig, attn_fn, kind: LayerKind):
         return mix
     if kind.operator == "latent_attention":
 
-        def attend_latent(q, k, v):
+        def attend_latent(q, k, v, q_rope, k_rope):
             with jax.named_scope("attn.latent"):
-                return attn_fn(q, k, v)
+                return attn_fn(q, k, v, q_rope=q_rope, k_rope=k_rope)
 
         return attend_latent
     if cfg.sliding_window_layout is None and cfg.layer_types is None:

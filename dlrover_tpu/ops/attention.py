@@ -18,6 +18,20 @@ from dlrover_tpu.ops import tuning
 NEG_INF = -1e30
 
 
+def whole_q_and_k(q, k, q_rope=None, k_rope=None):
+    """q and k each in one array from the parts ``flash_attention``
+    takes: a head's ``q | q_rope`` and ``k | k_rope``, the one rotated
+    key copied to every kv head. Without the rotated parts, q and k as
+    they came."""
+    if q_rope is None:
+        return q, k
+    every_heads = jnp.broadcast_to(k_rope, (*k.shape[:3], k_rope.shape[3]))
+    return (
+        jnp.concatenate([q, q_rope], axis=-1),
+        jnp.concatenate([k, every_heads], axis=-1),
+    )
+
+
 def mha_reference(
     q: jax.Array,  # [batch, q_len, heads, head_dim]
     k: jax.Array,  # [batch, kv_len, kv_heads, head_dim]
@@ -27,6 +41,8 @@ def mha_reference(
     mask: Optional[jax.Array] = None,  # bool [q_len, kv_len], True=keep
     return_lse: bool = False,
     window: Optional[int] = None,
+    q_rope: Optional[jax.Array] = None,  # [batch, q_len, heads, rope_dim]
+    k_rope: Optional[jax.Array] = None,  # [batch, kv_len, 1, rope_dim]
 ):
     """Plain XLA attention with GQA head-group broadcast.
 
@@ -35,7 +51,9 @@ def mha_reference(
     With ``return_lse`` also returns the logsumexp [batch, heads, q_len]
     (float32) for blockwise/ring combination. With ``window`` (causal
     only) query i sees key j iff ``j <= i`` and ``i - j < window``.
+    With ``q_rope`` and ``k_rope`` q and k are ``whole_q_and_k``'s.
     """
+    q, k = whole_q_and_k(q, k, q_rope, k_rope)
     b, qlen, h, d = q.shape
     _, klen, kvh, _ = k.shape
     if h % kvh:
@@ -91,6 +109,8 @@ def flash_attention(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
     window: Optional[int] = None,
+    q_rope: Optional[jax.Array] = None,
+    k_rope: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Memory-efficient attention: Pallas kernel on TPU; off the TPU
     (CPU tests, rehearsals) the dense reference, which no TPU run
@@ -105,10 +125,18 @@ def flash_attention(
     rule on the shapes, and ``tuning.last_selection()`` names them.
     ``window`` (causal only, static in the kernel): query i sees key
     j iff ``j <= i`` and ``i - j < window``.
+
+    Latent attention hands a head's q and k in the parts its products
+    make: ``q`` and ``k`` the un-rotated columns, ``q_rope`` [batch,
+    seq, heads, r] and ``k_rope`` [batch, seq, 1, r] the rotated ones,
+    the key's one for every head. What a caller hands decides what
+    runs: with the parts the kernels read them as they are, with whole
+    q and k the kernels are the ones they always were.
     """
     if not _use_pallas(q, k):
         return mha_reference(
-            q, k, v, causal=causal, scale=scale, window=window
+            q, k, v, causal=causal, scale=scale, window=window,
+            q_rope=q_rope, k_rope=k_rope,
         )
     from dlrover_tpu.ops.pallas.flash_attention import (
         flash_attention_tpu,
@@ -124,16 +152,19 @@ def flash_attention(
             f"block_q={block_q} block_k={block_k}"
         )
     bq, bk = blocks
+    head_dim = q.shape[3] + (0 if q_rope is None else q_rope.shape[3])
     tuning.record(
-        kernel="flash_attention", seq=seq, head_dim=q.shape[3],
+        kernel="flash_attention", seq=seq, head_dim=head_dim,
         gqa_group=group, dtype=jnp.dtype(q.dtype).name, causal=causal,
         block_q=bq, block_k=bk, window=window,
-        # v's width where it is not q and k's (latent attention)
-        **({} if v.shape[3] == q.shape[3] else {"v_head_dim": v.shape[3]}),
+        # v's width where it is not q and k's (latent attention), and
+        # how many of q and k's columns came as rotated parts
+        **({} if v.shape[3] == head_dim else {"v_head_dim": v.shape[3]}),
+        **({} if q_rope is None else {"rope_head_dim": q_rope.shape[3]}),
     )
     return flash_attention_tpu(
         q, k, v, causal=causal, scale=scale, block_q=bq, block_k=bk,
-        window=window,
+        window=window, q_rope=q_rope, k_rope=k_rope,
     )
 
 
@@ -160,19 +191,28 @@ def make_sharded_attention(mesh, q_spec, kv_spec, causal: bool = True):
     from dlrover_tpu.parallel.compat import shard_map
     from dlrover_tpu.parallel.sharding import fit_spec
 
-    def attn_fn(q, k, v, window=None):
+    def attn_fn(q, k, v, window=None, q_rope=None, k_rope=None):
         qp = list(fit_spec(q_spec, q.shape, mesh))
         kp = list(fit_spec(kv_spec, k.shape, mesh))
         if qp[2] is None or kp[2] is None:
             qp[2] = kp[2] = None  # heads split together or not at all
         kp[0] = qp[0]
         qs, ks = P(*qp), P(*kp)
+        operands, specs = (q, k, v), (qs, ks, ks)
+        if q_rope is not None:
+            # the one rotated key is whole wherever its heads are
+            operands += (q_rope, k_rope)
+            specs += (qs, P(kp[0], kp[1], None, None))
+
+        def attend(q, k, v, q_rope=None, k_rope=None):
+            return flash_attention(
+                q, k, v, causal=causal, window=window,
+                q_rope=q_rope, k_rope=k_rope,
+            )
+
         return shard_map(
-            functools.partial(
-                flash_attention, causal=causal, window=window
-            ),
-            mesh=mesh, in_specs=(qs, ks, ks), out_specs=qs,
+            attend, mesh=mesh, in_specs=specs, out_specs=qs,
             check_vma=False,
-        )(q, k, v)
+        )(*operands)
 
     return attn_fn

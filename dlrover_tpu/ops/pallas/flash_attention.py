@@ -64,6 +64,22 @@ reads the wider of the two; a head's resident dQ of 8,192 x 192 is
 past the default scoped limit's room, and that call states what it
 takes as the grouped one does.
 
+q and k may each come in two parts (latent attention: a head's 128
+un-rotated and 64 rotated columns, the products that make them being
+apart, and the rotated key one [batch, seq, 64] for every head). The
+parts are further refs of the same kernels: a ``pallas_call`` takes
+its block specs as trees and hands the body a tuple of refs where the
+caller handed a tuple of arrays. The rotated key's spec maps a head's
+grid index to its batch row, so its block is fetched where a head's
+copy would be and no copy is made; a grid step puts the [block, 192]
+tiles of q and k together in VMEM (``_read``) and from there the body
+is the one it is for whole operands, product for product; dQ and dK
+are summed 192 wide in VMEM and leave in the parts they came in
+(``_write``), a head's part of the rotated key's gradient summed over
+the heads outside the kernel. A caller that hands whole q and k gets
+the kernels it always got: the helpers below are the plain read and
+the plain write for a ref that is no tuple.
+
 A window (``window``, static: query i sees key j iff ``j <= i`` and
 ``i - j < window``) is a second edge, under the diagonal. A grid block
 wholly under it is skipped as one above the diagonal is: not computed
@@ -120,13 +136,60 @@ def _band_mask(q_start, k_start, g, rows, cols, window):
     )
 
 
+def _each(x):
+    """An operand's parts: q or k handed whole is its own one part, in
+    parts (a tuple; latent attention's un-rotated and rotated columns)
+    those. Arrays, refs, or what ``_parts_of`` made of them."""
+    return x if isinstance(x, tuple) else (x,)
+
+
+def _parts_of(fn, x):
+    """``fn`` of an operand handed whole, or the tuple of ``fn`` of
+    each of its parts: a ``pallas_call`` takes block specs and shapes
+    as trees and hands the kernel its refs in the same trees."""
+    return tuple(fn(p) for p in x) if isinstance(x, tuple) else fn(x)
+
+
+def _width(x):
+    """An operand's last dimension, over its parts."""
+    return sum(p.shape[-1] for p in _each(x))
+
+
+def _dtype(x):
+    """An operand's dtype, which its parts share."""
+    return _each(x)[0].dtype
+
+
+def _read(ref, index):
+    """``ref[index]``; of an operand in parts, its parts' side by side
+    on the lanes: the [block, d] tile the products below read, put
+    together in VMEM and nowhere else."""
+    if isinstance(ref, tuple):
+        return jax.lax.concatenate([r[index] for r in ref], 1)
+    return ref[index]
+
+
+def _write(ref, index, value):
+    """``ref[index] = value``; to an operand's gradient in parts, each
+    part its own columns of ``value``."""
+    if not isinstance(ref, tuple):
+        ref[index] = value
+        return
+    start = 0
+    for r in ref:
+        r[index] = value[:, start:start + r.shape[-1]]
+        start += r.shape[-1]
+
+
 def _stack_groups(ref, g, rows=slice(None)):
     """[1, g, block, d] ref -> [g*rows, d] value, via per-group slices
     stacked on sublanes (the relayout Mosaic supports; a direct 4-D
     reshape hits "unsupported shape cast")."""
     if g == 1:
-        return ref[0, 0, rows]
-    return jnp.concatenate([ref[0, gi, rows] for gi in range(g)], axis=0)
+        return _read(ref, (0, 0, rows))
+    return jnp.concatenate(
+        [_read(ref, (0, gi, rows)) for gi in range(g)], axis=0
+    )
 
 
 def _stack_cols(ref, g, rows=slice(None)):
@@ -344,8 +407,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     def compute(r0, size, cols, diagonal):
         rows = _rows_of(r0, size, block_q)
         s = _scores(
-            _stack_groups(q_ref, g, rows), k_ref[0, cols], scale, g,
-            diagonal, window,
+            _stack_groups(q_ref, g, rows), _read(k_ref, (0, cols)), scale,
+            g, diagonal, window,
         )
         m_prev = m_scr[rows, :1]  # [g*size, 1]
         m_new = jax.lax.max(m_prev, _row_reduce(jax.lax.reduce_max, s))
@@ -392,11 +455,22 @@ def _check_blocks(seq, block_q, block_k):
         raise ValueError(f"block_q must be a power of two, got {block_q}")
 
 
-def _kernel(body, name, seq, causal, g, block_q, block_k, head_dim, scale,
+def _kernel(body, name, seq, causal, g, block_q, block_k, q, scale,
             window=None):
     """``body`` ("fwd", "dq", "dkv", "dqkv" or "dq_dkv" by ``name``)
-    with its static arguments; building a causal one sets the census gauges.
-    A windowed one takes whole blocks (``_walk``)."""
+    with its static arguments, for ``q`` whole or in parts; building
+    one sets the gauge of the parts, a causal one the census gauges. A
+    windowed one takes whole blocks (``_walk``)."""
+    from dlrover_tpu.telemetry.registry import gauge
+
+    gauge(
+        "attn_operand_parts",
+        "arrays in which an attention kernel reads q and k each, at "
+        "the last one built: 1 (whole) or 2 (a head's un-rotated and "
+        "rotated columns apart, the rotated key one for every head)",
+        labelnames=("kernel",),
+    ).labels(kernel=name).set(len(_each(q)))
+    head_dim = _width(q)
     sub = None
     if causal:
         if window is None:
@@ -435,19 +509,48 @@ def _kv_index(causal, block_q, block_k, window=None):
     return index
 
 
+def _specs(x, rows, index):
+    """Block specs of ``rows`` of a head of q or k, or of their
+    gradients, by ``index``: each part its own width."""
+    return _parts_of(
+        lambda part: pl.BlockSpec((1, *rows, part.shape[-1]), index), x
+    )
+
+
+def _k_specs(k, bkh, block_k, index):
+    """``_specs`` of k's key positions. A part with fewer rows than
+    the grid has kv heads (latent attention's rotated key, [batch,
+    seq, r]) is one that ``bkh // rows`` heads in a row share: each
+    reads its batch row's block and none holds a copy."""
+
+    def spec(part):
+        shared = bkh // part.shape[0]
+
+        def of_its_row(b, i, j):
+            _, *rest = index(b, i, j)
+            return (b // shared, *rest)
+
+        return _specs(
+            part, (block_k,), index if shared == 1 else of_its_row
+        )
+
+    return _parts_of(spec, k)
+
+
 def _fwd(q, k, v, scale, causal, block_q, block_k, window=None):
     """q: [bk_h, g, seq, d]; k,v: [bk_h, seq, d] ->
     (o [bk_h, g, seq, dv], lse [bk_h, g, 1, seq] f32). ``v`` may be
     narrower or wider than q and k (``dv``): the scores contract over
-    ``d``, the result and its accumulator are ``dv`` wide."""
-    bkh, g, seq, d = q.shape
+    ``d``, the result and its accumulator are ``dv`` wide. q and k may
+    each come in parts (tuples: ``_each``), ``d`` wide together."""
+    bkh, g, seq, _ = _each(q)[0].shape
     dv = v.shape[-1]
     block_q = min(block_q, seq)
     block_k = min(block_k, seq)
     _check_blocks(seq, block_q, block_k)
     grid = (bkh, seq // block_q, seq // block_k)
     kernel = _kernel(
-        _fwd_kernel, "fwd", seq, causal, g, block_q, block_k, d, scale,
+        _fwd_kernel, "fwd", seq, causal, g, block_q, block_k, q, scale,
         window,
     )
     kv_idx = _kv_index(causal, block_q, block_k, window)
@@ -455,8 +558,8 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, window=None):
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, g, block_q, d), lambda b, i, j: (b, 0, i, 0)),
-            pl.BlockSpec((1, block_k, d), kv_idx),
+            _specs(q, (g, block_q), lambda b, i, j: (b, 0, i, 0)),
+            _k_specs(k, bkh, block_k, kv_idx),
             pl.BlockSpec((1, block_k, dv), kv_idx),
         ],
         out_specs=[
@@ -466,7 +569,7 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, window=None):
             pl.BlockSpec((1, g, 1, block_q), lambda b, i, j: (b, 0, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bkh, g, seq, dv), q.dtype),
+            jax.ShapeDtypeStruct((bkh, g, seq, dv), _dtype(q)),
             jax.ShapeDtypeStruct((bkh, g, 1, seq), jnp.float32),
         ],
         scratch_shapes=[
@@ -489,6 +592,16 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, window=None):
 # The kernels that walk sub-tiles are the other way about in dK/dV
 # alone: dS first reads 62.3 ms at gpt2-xl's shape, dV first 75.1
 # (PERF.md section 6, PR 31).
+
+def _cols_of(k_ref, cols):
+    """A read of k's key positions ``cols``, made where a product
+    wants it: of k handed whole from the ref each time, in the order
+    above; of k in parts the tile put together once a call."""
+    if isinstance(k_ref, tuple):
+        k = _read(k_ref, (0, cols))
+        return lambda: k
+    return lambda: k_ref[0, cols]
+
 
 def _q_side(q_ref, do_ref, lse_ref, delta_ref, g, rows):
     """What the backward kernels read of the query positions ``rows``:
@@ -537,12 +650,13 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         q, do, lse, delta = _q_side(
             q_ref, do_ref, lse_ref, delta_ref, g, rows
         )
-        p = _p(q, lse, k_ref[0, cols], scale, g, diagonal, window)
+        k = _cols_of(k_ref, cols)
+        p = _p(q, lse, k(), scale, g, diagonal, window)
         ds = jax.lax.convert_element_type(
-            _ds(p, do, v_ref[0, cols], delta), k_ref.dtype
+            _ds(p, do, v_ref[0, cols], delta), q.dtype
         )
         acc_scr[rows] += jax.lax.dot_general(
-            ds, k_ref[0, cols], (((1,), (0,)), ((), ())),
+            ds, k(), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         if add_dkv is not None:
@@ -553,17 +667,18 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     @pl.when(j == nk - 1)
     def _finalize():
-        dq = (acc_scr[:] * scale).astype(dq_ref.dtype)
+        dq = (acc_scr[:] * scale).astype(_dtype(dq_ref))
         for gi in range(g):
-            dq_ref[0, gi] = dq[gi * block_q:(gi + 1) * block_q]
+            _write(dq_ref, (0, gi), dq[gi * block_q:(gi + 1) * block_q])
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr,
                 *, scale, causal, g, block_q, block_k, sub, add_dq=None,
                 window=None):
-    """``add_dq(r0, size, cols, ds)``, where given, takes each dS the
-    walk forms (cast for the products) on to dQ: ``_dqkv_kernel``."""
+    """``add_dq(r0, size, k, ds)``, where given, takes each dS the
+    walk forms (cast for the products) and the read of its key
+    positions (``_cols_of``) on to dQ: ``_dqkv_kernel``."""
     j = pl.program_id(1)  # k block (major)
     i = pl.program_id(2)  # q block (minor: accumulates)
     nq = pl.num_programs(2)
@@ -581,7 +696,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         q, do, lse, delta = _q_side(
             q_ref, do_ref, lse_ref, delta_ref, g, rows
         )
-        p = _p(q, lse, k_ref[0, cols], scale, g, diagonal, window)
+        k = _cols_of(k_ref, cols)
+        p = _p(q, lse, k(), scale, g, diagonal, window)
         if sub is not None:  # dS first where sub-tiles are walked (above)
             ds = _ds(p, do, v_ref[0, cols], delta)
         # dV += P^T @ dO — contracting over the g*size rows also sums
@@ -603,14 +719,15 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32,
         )
         if add_dq is not None:
-            add_dq(r0, size, cols, ds)
+            add_dq(r0, size, k, ds)
 
     _walk(causal, block_q, block_k, sub, q_start, k_start, compute,
           window)
 
     @pl.when(i == nq - 1)
     def _finalize():
-        dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
+        _write(dk_ref, (0,),
+               (dk_scr[:] * scale).astype(_dtype(dk_ref)))
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
@@ -628,13 +745,13 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    def add_dq(r0, size, cols, ds):
+    def add_dq(r0, size, k, ds):
         if block_q == seq:  # one block a head: static offsets
             rows = _rows_of(r0, size, seq)
         else:
             rows = pl.ds(pl.multiple_of(i * block_q + r0, size), size)
         dq_scr[rows] += jax.lax.dot_general(
-            ds, k_ref[0, cols], (((1,), (0,)), ((), ())),
+            ds, k(), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
@@ -648,7 +765,8 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         j == pl.num_programs(1) - 1, i == pl.num_programs(2) - 1
     ))
     def _finalize():
-        dq_ref[0, 0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
+        _write(dq_ref, (0, 0),
+               (dq_scr[:] * scale).astype(_dtype(dq_ref)))
 
 
 def _dq_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -691,7 +809,8 @@ def _dq_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         i == pl.num_programs(1) - 1, j == pl.num_programs(2) - 1
     ))
     def _finalize():
-        dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
+        _write(dk_ref, (0,),
+               (dk_scr[:] * scale).astype(_dtype(dk_ref)))
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
@@ -768,8 +887,10 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
          window=None):
     from dlrover_tpu.telemetry.registry import gauge
 
-    bkh, g, seq, d = q.shape
-    dv = v.shape[-1]  # v, o and dO's width; q, k and dQ are d wide
+    bkh, g, seq, _ = _each(q)[0].shape
+    # v, o and dO's width; q, k, dQ and dK are d wide, over their parts
+    d, dv = _width(q), v.shape[-1]
+    itemsize = _dtype(q).itemsize
     block_q = min(block_q, seq)
     block_k = min(block_k, seq)
     _check_blocks(seq, block_q, block_k)
@@ -790,8 +911,16 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
 
     def build(body, name):
         return _kernel(
-            body, name, seq, causal, g, block_q, block_k, d, scale, window
+            body, name, seq, causal, g, block_q, block_k, q, scale, window
         )
+
+    def shapes(x, *rows):
+        """dQ's or dK's, ``rows`` a head; of k in parts each part a kv
+        head's own, the part that the heads share too
+        (``_flash_bwd_rule`` sums it)."""
+        return _parts_of(lambda part: jax.ShapeDtypeStruct(
+            (bkh, *rows, part.shape[-1]), part.dtype
+        ), x)
 
     def by_query_blocks(resident):
         """The dq kernel's grid, (b, i, j): dq, and where the kv head's
@@ -812,21 +941,19 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
                     else (_dq_kernel, "dq"))),
             grid=(bkh, seq // block_q, seq // block_k),
             in_specs=[
-                pl.BlockSpec((1, g, block_q, d), q_idx),
-                pl.BlockSpec((1, block_k, d), kv_idx),  # k
+                _specs(q, (g, block_q), q_idx),
+                _k_specs(k, bkh, block_k, kv_idx),
                 pl.BlockSpec((1, block_k, dv), kv_idx),  # v
                 pl.BlockSpec((1, g, block_q, dv), q_idx),
                 pl.BlockSpec((1, g, 1, block_q), lse_idx),
                 pl.BlockSpec((1, g, 1, block_q), lse_idx),
             ],
-            out_specs=[pl.BlockSpec((1, g, block_q, d), q_idx)] + resident * [
-                pl.BlockSpec((1, seq, d), lambda b, i, j: (b, 0, 0)),
+            out_specs=[_specs(q, (g, block_q), q_idx)] + resident * [
+                _specs(k, (seq,), lambda b, i, j: (b, 0, 0)),
                 pl.BlockSpec((1, seq, dv), lambda b, i, j: (b, 0, 0)),
             ],
-            out_shape=[
-                jax.ShapeDtypeStruct((bkh, g, seq, d), q.dtype),
-            ] + resident * [
-                jax.ShapeDtypeStruct((bkh, seq, d), k.dtype),
+            out_shape=[shapes(q, g, seq)] + resident * [
+                shapes(k, seq),
                 jax.ShapeDtypeStruct((bkh, seq, dv), v.dtype),
             ],
             scratch_shapes=[
@@ -837,7 +964,7 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
             ],
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=_dkv_resident_vmem_bytes(
-                    seq, max(d, dv), q.dtype.itemsize)
+                    seq, max(d, dv), itemsize)
             ) if resident else None,
             interpret=_interpret(),
         )(q, k, v, do, lse, delta)
@@ -870,23 +997,21 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
                     else (_dkv_kernel, "dkv"))),
             grid=(bkh, seq // block_k, seq // block_q),
             in_specs=[
-                pl.BlockSpec((1, g, block_q, d), q_side_idx(True)),
-                pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),  # k
+                _specs(q, (g, block_q), q_side_idx(True)),
+                _k_specs(k, bkh, block_k, lambda b, j, i: (b, j, 0)),
                 pl.BlockSpec((1, block_k, dv), lambda b, j, i: (b, j, 0)),  # v
                 pl.BlockSpec((1, g, block_q, dv), q_side_idx(True)),
                 pl.BlockSpec((1, g, 1, block_q), q_side_idx(False)),
                 pl.BlockSpec((1, g, 1, block_q), q_side_idx(False)),
             ],
             out_specs=resident * [
-                pl.BlockSpec((1, 1, seq, d), lambda b, j, i: (b, 0, 0, 0)),
+                _specs(q, (1, seq), lambda b, j, i: (b, 0, 0, 0)),
             ] + [
-                pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+                _specs(k, (block_k,), lambda b, j, i: (b, j, 0)),
                 pl.BlockSpec((1, block_k, dv), lambda b, j, i: (b, j, 0)),
             ],
-            out_shape=resident * [
-                jax.ShapeDtypeStruct((bkh, 1, seq, d), q.dtype),
-            ] + [
-                jax.ShapeDtypeStruct((bkh, seq, d), k.dtype),
+            out_shape=resident * [shapes(q, 1, seq)] + [
+                shapes(k, seq),
                 jax.ShapeDtypeStruct((bkh, seq, dv), v.dtype),
             ],
             scratch_shapes=resident * [pltpu.VMEM((seq, d), jnp.float32)] + [
@@ -895,7 +1020,7 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
             ],
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=_dq_resident_vmem_bytes(
-                    seq, d, q.dtype.itemsize)
+                    seq, d, itemsize)
             ) if resident and seq * d * 4 > DQ_UNSTATED_BYTES else None,
             interpret=_interpret(),
         )(q, k, v, do, lse, delta)
@@ -926,7 +1051,21 @@ def _flash_bwd_rule(scale, causal, block_q, block_k, window, res, do):
     dq, dk, dv = _bwd(
         q, k, v, o, lse, do, scale, causal, block_q, block_k, window
     )
-    return dq, dk, dv
+    return dq, jax.tree.map(_summed_over_its_heads, dk, k), dv
+
+
+def _summed_over_its_heads(dk, k):
+    """Of k's gradient as the kernels write it, a kv head's own, the
+    gradient of a part that several heads read ([rows, seq, r]: latent
+    attention's rotated key): the heads' sum, in float32 from the
+    kernels' parts, as a broadcast's transpose is. Any other part's
+    as it came."""
+    if dk.shape == k.shape:
+        return dk
+    rows, seq, r = k.shape
+    return jnp.sum(
+        dk.reshape(rows, -1, seq, r), axis=1, dtype=jnp.float32
+    ).astype(k.dtype)
 
 
 _flash_gqa.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -941,14 +1080,31 @@ def flash_attention_tpu(
     block_q: int = 512,
     block_k: int = 512,
     window: Optional[int] = None,
+    q_rope: Optional[jax.Array] = None,  # [batch, seq, heads, rope_dim]
+    k_rope: Optional[jax.Array] = None,  # [batch, seq, 1, rope_dim]
 ) -> jax.Array:
     """Flash attention in the models' [batch, seq, heads, head_dim]
     layout; GQA folded into the kernels' matmul rows (no KV repeat).
     ``window``: query i sees key j iff ``j <= i`` and ``i - j <
     window`` (causal only); one that reaches every key is no window.
     ``v`` may have a width of its own, which is the result's; the
-    default ``scale`` is q and k's ``head_dim ** -0.5``."""
+    default ``scale`` is q and k's ``head_dim ** -0.5``.
+
+    With ``q_rope`` and ``k_rope`` (latent attention) a head's q and k
+    are ``q | q_rope`` and ``k | k_rope``, ``k_rope`` one key for
+    every head: the kernels read the parts and put a block's tile
+    together in VMEM, so neither the whole q and k nor a copy of
+    ``k_rope`` a head is ever in memory, and the gradients come back
+    in the same parts. ``head_dim`` is then the two widths' sum."""
     b, s, h, d = q.shape
+    if (q_rope is None) != (k_rope is None):
+        raise ValueError("q_rope and k_rope come together or not at all")
+    if q_rope is not None:
+        if k_rope.shape[2] != 1:
+            raise ValueError(
+                f"k_rope {k_rope.shape}: one rotated key for every head"
+            )
+        d += q_rope.shape[3]
     if window is not None:
         if not causal or window < 1:
             raise ValueError(
@@ -961,14 +1117,17 @@ def flash_attention_tpu(
     g = h // kvh
     scale = scale if scale is not None else d ** -0.5
     # [b, s, h, d] -> [b*kvh, g, s, d]: query head i = (i // g, i % g)
-    qg = q.transpose(0, 2, 1, 3).reshape(b * kvh, g, s, d)
+    def q_layout(x):
+        return x.transpose(0, 2, 1, 3).reshape(b * kvh, g, s, x.shape[3])
 
     def kv_layout(x):
         return x.transpose(0, 2, 1, 3).reshape(b * kvh, s, x.shape[3])
 
+    qg, kg = q_layout(q), kv_layout(k)
+    if q_rope is not None:
+        qg, kg = (qg, q_layout(q_rope)), (kg, k_rope[:, :, 0])
     o = _flash_gqa(
-        qg, kv_layout(k), kv_layout(v), scale, causal, block_q, block_k,
-        window,
+        qg, kg, kv_layout(v), scale, causal, block_q, block_k, window,
     )
     return o.reshape(b, h, s, v.shape[3]).transpose(0, 2, 1, 3)
 

@@ -29,7 +29,9 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 from dlrover_tpu.parallel.compat import shard_map
 
-from dlrover_tpu.ops.attention import NEG_INF, mha_reference
+from dlrover_tpu.ops.attention import (
+    NEG_INF, mha_reference, whole_q_and_k,
+)
 from dlrover_tpu.parallel.mesh import SEQ_AXIS, batch_axes
 
 
@@ -187,13 +189,13 @@ def ulysses_attention(
 
 def make_context_parallel_attn(mesh: Mesh, kind: str = "ring",
                                axis_name: str = SEQ_AXIS):
-    """Build an ``attn_fn`` for models.llama.forward."""
-    if kind == "ring":
-        return lambda q, k, v: ring_attention(
-            q, k, v, mesh, causal=True, axis_name=axis_name
-        )
-    if kind == "ulysses":
-        return lambda q, k, v: ulysses_attention(
-            q, k, v, mesh, causal=True, axis_name=axis_name
-        )
-    raise ValueError(f"unknown context-parallel kind {kind!r}")
+    """Build an ``attn_fn`` for models.llama.forward. Latent
+    attention's rotated parts (``q_rope``, ``k_rope``) are put beside
+    q and k before the sequence is cut: the chunks rotate whole."""
+    attend = {"ring": ring_attention, "ulysses": ulysses_attention}.get(kind)
+    if attend is None:
+        raise ValueError(f"unknown context-parallel kind {kind!r}")
+    return lambda q, k, v, q_rope=None, k_rope=None: attend(
+        *whole_q_and_k(q, k, q_rope, k_rope), v, mesh, causal=True,
+        axis_name=axis_name,
+    )

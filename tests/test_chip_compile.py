@@ -16,6 +16,7 @@ every test file.
 
 import base64
 import dataclasses
+import hashlib
 import json
 import math
 import os
@@ -290,14 +291,18 @@ CELL_ATTENTION = {
     "mistral": (3, 4096, 32, 8, 128),
     "lfm2": (4, 8192, 32, 8, 64),
     "smallthinker": (1, 16384, 28, 4, 128),
+    # latent attention handed whole, as until PR 43 (v is 128 wide:
+    # ``CELL_V_WIDTH``); the cell hands parts
+    "joyai": (4, 8192, 32, 32, 192),
 }
+CELL_V_WIDTH = {"joyai": 128}
 #: the backward is one kernel at each: a head's float32 dQ stays in
 #: VMEM without a group (256 KB, 2 MB), a kv head's float32 dK and dV
 #: with one (4, 8 and 16 MB)
 CELL_BACKWARD_FORM = {
     "gpt2-xl": "dq_resident", "olmoe": "dq_resident",
     "mistral": "dkv_resident", "lfm2": "dkv_resident",
-    "smallthinker": "dkv_resident",
+    "smallthinker": "dkv_resident", "joyai": "dq_resident",
 }
 #: what the grouped backward call asks of a v5e core's 128 MiB of VMEM
 #: (``jax/_src/pallas/mosaic/tpu_info.py``): the kv head's float32 dK
@@ -395,23 +400,35 @@ def test_windowed_kernels_compile_at_smallthinkers_shape(
         re.findall(r'custom_call_target="tpu_custom_call"', text)) == 2
 
 
-def test_latent_kernels_compile_at_joyais_shape(topo, on_tpu_path):
+@pytest.mark.parametrize("operands", ["parts", "whole"])
+def test_latent_kernels_compile_at_joyais_shape(topo, on_tpu_path, operands):
     """``joyai-llm-flash-ep8.steady``'s attention: four sequences of
     8,192, 32 heads, q and k 192 wide (a lane and a half), v 128: the
     rule's (1024, 1024) blocks, the forward kernel and the one
     backward kernel with a head's float32 dQ resident (6 MiB, 8 in
     whole lanes), which states what it takes of VMEM, through the
-    dispatch a TPU process takes."""
-    q, v = (
-        jax.ShapeDtypeStruct(
-            (4, 8192, 32, d), jnp.bfloat16,
+    dispatch a TPU process takes. In the parts the cell hands over (q
+    and k 128 wide, their rotated 64 columns apart, the key's one for
+    every head) a grid step puts the 192-wide tiles together in VMEM
+    and the gradients leave in the same parts; whole, as before."""
+    def shaped(heads, d):
+        return jax.ShapeDtypeStruct(
+            (4, 8192, heads, d), jnp.bfloat16,
             sharding=SingleDeviceSharding(topo.devices[0]),
-        ) for d in (192, 128)
-    )
+        )
+
     assert tuning.heuristic_blocks(8192, 1) == (1024, 1024)
     assert fa._one_backward_kernel(1, 8192, 192)
-    compiled = jax.jit(_sum_grad(attention.flash_attention)).lower(
-        q, q, v).compile()
+    if operands == "parts":
+        compiled = jax.jit(jax.grad(
+            lambda q, k, v, q_rope, k_rope: attention.flash_attention(
+                q, k, v, q_rope=q_rope, k_rope=k_rope,
+            ).astype(jnp.float32).sum(), argnums=(0, 1, 2, 3, 4),
+        )).lower(shaped(32, 128), shaped(32, 128), shaped(32, 128),
+                 shaped(32, 64), shaped(1, 64)).compile()
+    else:
+        compiled = jax.jit(_sum_grad(attention.flash_attention)).lower(
+            shaped(32, 192), shaped(32, 192), shaped(32, 128)).compile()
     kernels = re.findall(
         r"%([\w.\-]+) = ([^\n]*?) custom-call\([^\n]*"
         r"custom_call_target=\"tpu_custom_call\"", compiled.as_text())
@@ -420,10 +437,31 @@ def test_latent_kernels_compile_at_joyais_shape(topo, on_tpu_path):
         r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"',
         compiled.as_text())]
     assert max(asked) == 16 * 2 ** 20 + fa.OTHER_VMEM_BYTES
-    # o and dv 128 wide, dq and dk 192
+    # o and dv 128 wide; dq and dk 192, or each in parts of 128 and
+    # 64 (the rotated key's a head's own, summed outside the kernel)
     assert sorted(re.findall(r"8192,(\d+)\]", " ".join(
-        result for _, result in kernels))) == ["128", "128", "192", "192"]
-    assert tuning.last_selection()["v_head_dim"] == 128
+        result for _, result in kernels))) == (
+            ["128", "128", "128", "128", "64", "64"]
+            if operands == "parts" else ["128", "128", "192", "192"])
+    selection = tuning.last_selection()
+    assert (selection["head_dim"], selection["v_head_dim"]) == (192, 128)
+    assert selection.get("rope_head_dim") == (
+        64 if operands == "parts" else None)
+
+
+#: sha256 (first 16 digits) of the forward and the backward kernel's
+#: Mosaic module, without source locations, that a call with whole q,
+#: k and v lowers to at each cell's shape: what commit 6e20e70 (PR 42)
+#: lowers. A PR that changes what such a call runs reads the cells
+#: again and then writes its own here (the test prints them)
+WHOLE_KERNELS = {
+    "gpt2-xl": ("fc758e9c4fb635a1", "163ae216a736d229"),
+    "olmoe": ("1c7c62ea288cfef3", "19453fc863f47018"),
+    "mistral": ("7f3bc98ec0816dc5", "fffbbf467e2e1ea4"),
+    "lfm2": ("3b21acb8a143e85c", "761086b50c01458f"),
+    "smallthinker": ("f2c890076821e395", "515ba026a486cd3e"),
+    "joyai": ("ffb0b59e8f1d4260", "a29cfc45da995f91"),
+}
 
 
 def _lowered_kernels(fn, *args):
@@ -472,21 +510,26 @@ def test_dispatch_lowers_the_rules_kernels_whatever_the_environment(
     # as a chip's process sees it
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     one_chip = SingleDeviceSharding(topo.devices[0])
-    q, kv = (
+    q, k, v = (
         jax.ShapeDtypeStruct(
-            (batch, seq, h, d), jnp.bfloat16, sharding=one_chip
-        ) for h in (heads, kv_heads)
+            (batch, seq, h, width), jnp.bfloat16, sharding=one_chip
+        ) for h, width in ((heads, d), (kv_heads, d),
+                           (kv_heads, CELL_V_WIDTH.get(cell, d)))
     )
     want = _lowered_kernels(_sum_grad(
         lambda q, k, v: fa.flash_attention_tpu(
             q, k, v, causal=True, block_q=bq, block_k=bk)
-    ), q, kv, kv)
+    ), q, k, v)
     # a Mosaic module for the forward and one for the backward kernel
     backward = {"dq_resident": "_dqkv_kernel",
                 "dkv_resident": "_dq_dkv_kernel"}
     assert re.findall(r'kernel_name = "(\w+)"', want[0]) == [
         "_fwd_kernel", backward[CELL_BACKWARD_FORM[cell]]]
     assert len(want[1]) == 2, len(want[1])
+    digests = tuple(
+        hashlib.sha256(module.encode()).hexdigest()[:16]
+        for module in want[1])
+    assert digests == WHOLE_KERNELS[cell], digests
 
     def refuse(*args, **kwargs):
         raise AssertionError("a thread or jax.clear_caches()")
@@ -496,7 +539,7 @@ def test_dispatch_lowers_the_rules_kernels_whatever_the_environment(
     # the undecorated function: inlined like the kernel's own wrapper,
     # and traced whatever this process has traced before
     got = _lowered_kernels(
-        _sum_grad(attention.flash_attention.__wrapped__), q, kv, kv
+        _sum_grad(attention.flash_attention.__wrapped__), q, k, v
     )
     assert got == want
     assert tuning.last_selection()["source"] == "static"
@@ -950,6 +993,75 @@ def test_lfm2_step_holds_the_convolutions_kernels(
         and "conv." not in line and "fusion(" in line
     ]
     assert not unscoped
+
+
+#: ``peak_memory_in_bytes`` of ``joyai-llm-flash-ep8.steady``'s step as
+#: this file compiles it (4 x 8,192, six layers and the module, remat
+#: ``minimal``, the least effort; PERF.md, PR 43): 7.4 GB of it the
+#: state. The step with q and k built whole outside the kernels (PR
+#: 42) read 15,958,852,096 here: by this statistic the parts plan 55
+#: MB more (at the default effort 46), by the buffer assignment's
+#: total 119 MB less, and on the chip the same (PERF.md section 6)
+JOYAI_STEP_BYTES = 16_013_520_896
+
+
+def test_joyai_step_holds_no_whole_q_or_k(topo, on_tpu_path, monkeypatch):
+    """``joyai-llm-flash-ep8.steady``'s step: it fits and plans no
+    more than was read when the kernels took the parts; no
+    instruction's result, in a fusion or out of one, is a head's
+    whole 192-wide q or k in the model's or the kernels' order (the
+    parts reach the kernels as their products make them: a compiled
+    step's instruction names and shapes are a device trace's); the
+    three blocks' kernels (the forward, the forward again under
+    ``minimal``, the one backward kernel) are named as the
+    benchmark's ``attn_kernel_ms`` tells them and carry
+    ``attn.latent``; and the record says which form ran."""
+    from dlrover_tpu.ops import grouped_matmul as gm
+    from yardstick import cells, worker
+    from yardstick.layer_metrics import attn_kernel_ms
+
+    monkeypatch.setattr(gm, "_use_pallas", lambda lhs, rhs: True)
+    monkeypatch.setattr(gm, "_interpret", lambda: False)
+    monkeypatch.setattr(gm, "_add_on_mxu", lambda out, rows: True)
+    _, config, traffic = cells.load_cell("joyai-llm-flash-ep8.steady")
+    cfg = worker.program_config(config, traffic)
+    assert (cfg.remat, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim) == (
+        "minimal", 128, 64)
+    mesh = Mesh(
+        np.array(topo.devices[:1]).reshape(1, 1), ("data", "fsdp"))
+    trainer = make_trainer_for_llama(
+        cfg, mesh, strategy=traffic["strategy"],
+        optimizer=optax.adamw(traffic["optimizer"]["learning_rate"]),
+    )
+    compiled = trainer.train_step.lower(*_abstract_step_args(
+        trainer, traffic["global_batch"], traffic["seq"])
+    ).compile(LEAST_EFFORT)
+    assert compiled.memory_analysis().peak_memory_in_bytes <= (
+        JOYAI_STEP_BYTES)
+    text = compiled.as_text()
+    whole = re.findall(
+        r"bf16\[(?:4,8192,32|4,32,8192|128,1,8192|128,8192),192\]", text)
+    assert not whole, len(whole)
+    assert "bf16[4,8192,32,128]" in text and "bf16[4,8192,1,64]" in text
+    kernels = re.findall(
+        r"%([\w.\-]+) = ([^\n]*?) custom-call\([^\n]*"
+        r"custom_call_target=\"tpu_custom_call\"[^\n]*"
+        r"op_name=\"([^\"]*)\"", text)
+    latent = [name for name, _, op in kernels if "attn.latent" in op]
+    # the leading layer, the scanned layers' body and the module's
+    # block, each the forward, the forward again and the backward
+    assert len(latent) == 3 * 3, latent
+    assert all(attn_kernel_ms.KERNEL.search(name) for name in latent)
+    assert not any(
+        attn_kernel_ms.KERNEL.search(name)
+        for name, _, op in kernels if "attn.latent" not in op)
+    # a backward kernel's results: dq and dk in their parts, dv
+    backward = [result for name, result, _ in kernels
+                if name in latent and result.count("bf16[") == 5]
+    assert len(backward) == 3
+    assert all(sorted(re.findall(r"8192,(\d+)\]", result)) == [
+        "128", "128", "128", "64", "64"] for result in backward)
+    assert tuning.last_selection()["rope_head_dim"] == 64
 
 
 def test_fsdp_step_lowers_over_four_chips(topo, on_tpu_path):

@@ -451,3 +451,207 @@ def test_a_long_head_at_latent_widths_keeps_its_dq_resident():
     assert 4096 * 128 * 4 <= flash_attention.DQ_UNSTATED_BYTES  # OLMoE
     assert flash_attention._dq_resident_vmem_bytes(8192, 192, 2) == (
         8192 * 256 * 8 + flash_attention.OTHER_VMEM_BYTES)
+
+
+# -- q and k in the parts their products make (latent attention) -------------
+
+# [seq, block_q, block_k, causal, g, window, backward]: a head's q and
+# k 128 + 64 wide, the rotated key one for every head, v 128. The
+# cell's form first (no group, the one backward kernel with the head's
+# dQ resident) over several blocks each way, one block walked in
+# sub-tiles, unequal blocks and no mask; then the forms no cell runs
+# in parts and a caller may: the dq and dk/dv pair, and with a group
+# the kv head's dK and dV resident, with a window too
+IN_PARTS = [
+    (256, 128, 128, True, 1, None, "_dqkv_kernel"),
+    (512, 512, 512, True, 1, None, "_dqkv_kernel"),
+    (256, 64, 128, True, 1, None, "_dqkv_kernel"),
+    (256, 128, 128, False, 1, None, "_dqkv_kernel"),
+    (256, 128, 128, True, 1, None, "pair"),
+    (256, 64, 128, True, 4, None, "_dq_dkv_kernel"),
+    (256, 64, 128, True, 2, 60, "_dq_dkv_kernel"),
+]
+PARTS = ("q", "q_rope", "k", "k_rope", "v")
+
+
+def _rand_parts(key, b, s, h, kvh, dtype=jnp.float32):
+    shapes = dict(q=(b, s, h, 128), q_rope=(b, s, h, 64),
+                  k=(b, s, kvh, 128), k_rope=(b, s, 1, 64),
+                  v=(b, s, kvh, 128))
+    return {name: jax.random.normal(k, shapes[name], dtype)
+            for name, k in zip(PARTS, jax.random.split(key, 5))}
+
+
+def _whole(attn):
+    """``attn(q, k, v)`` on the parts put side by side outside it."""
+    from dlrover_tpu.ops.attention import whole_q_and_k
+
+    def on_parts(q, q_rope, k, k_rope, v):
+        return attn(*whole_q_and_k(q, k, q_rope, k_rope), v)
+
+    return on_parts
+
+
+def _forward_and_grads(fn, parts):
+    """o, and the gradient of each of the five operands; jitted, which
+    interpret mode repays twice over (a fresh trace a call: what a
+    caller patches is read while it is traced)."""
+    def both(*operands):
+        return fn(*operands), jax.grad(
+            lambda *operands: jnp.sum(fn(*operands) ** 2), argnums=range(5)
+        )(*operands)
+
+    o, grads = jax.jit(both)(*parts.values())
+    return dict(zip(PARTS, grads), o=o)
+
+
+@pytest.fixture(scope="module", params=IN_PARTS, ids=str)
+def in_parts(request):
+    """o and the five gradients of the kernels on the parts, of the
+    same kernels on whole q and k, and of the reference, once a
+    case."""
+    seq, block_q, block_k, causal, g, window, backward = request.param
+    parts = _rand_parts(jax.random.key(13), 2, seq, 2 * g, 2)
+    kw = dict(causal=causal, window=window)
+
+    def attn(q, k, v, **rope):
+        return flash_attention_tpu(
+            q, k, v, block_q=block_q, block_k=block_k, **kw, **rope)
+
+    def on_parts(q, q_rope, k, k_rope, v):
+        return attn(q, k, v, q_rope=q_rope, k_rope=k_rope)
+
+    want = ["_dq_kernel", "_dkv_kernel"] if backward == "pair" else [backward]
+    with pytest.MonkeyPatch.context() as patch:
+        if backward == "pair":
+            patch.setattr(flash_attention, "_one_backward_kernel",
+                          lambda g, seq, d: False)
+        assert _kernels_of_grads(
+            lambda q, k, v: on_parts(
+                q, parts["q_rope"], k, parts["k_rope"], v),
+            parts["q"], parts["k"], parts["v"],
+        ) == ["_fwd_kernel", *want]
+        got = _forward_and_grads(on_parts, parts)
+        whole = _forward_and_grads(_whole(attn), parts)
+    ref = _forward_and_grads(
+        _whole(functools.partial(mha_reference, **kw)), parts)
+    return got, whole, ref
+
+
+@pytest.mark.parametrize("what", ("o",) + PARTS)
+def test_kernels_on_parts_are_the_kernels_on_whole_q_and_k(in_parts, what):
+    got, whole, ref = in_parts
+    assert got[what].shape == ref[what].shape
+    np.testing.assert_allclose(
+        got[what], ref[what], rtol=5e-3, atol=5e-3,
+        err_msg=f"{what} against the reference")
+    if what == "k_rope":
+        # whole, the heads' parts are summed in the concatenation's
+        # transpose: another order of the same float32 terms
+        np.testing.assert_allclose(
+            got[what], whole[what], rtol=1e-5, atol=1e-5, err_msg=what)
+    else:
+        # a block's tile is put together in VMEM and the body is the
+        # one it was: the same products in the same order
+        np.testing.assert_array_equal(got[what], whole[what], err_msg=what)
+
+
+@pytest.fixture(scope="module")
+def bf16_parts():
+    parts = _rand_parts(jax.random.key(14), 1, 256, 2, 2, jnp.bfloat16)
+
+    def attn(q, k, v, **rope):
+        return flash_attention_tpu(
+            q, k, v, block_q=128, block_k=128, **rope).astype(jnp.float32)
+
+    got = _forward_and_grads(
+        lambda q, q_rope, k, k_rope, v: attn(
+            q, k, v, q_rope=q_rope, k_rope=k_rope), parts)
+    return got, _forward_and_grads(_whole(attn), parts)
+
+
+@pytest.mark.parametrize("what", ("o",) + PARTS)
+def test_bf16_parts_are_within_a_step_of_whole_q_and_k(bf16_parts, what):
+    got, whole = bf16_parts
+    assert got[what].dtype == whole[what].dtype
+    scale = float(jnp.abs(whole[what].astype(jnp.float32)).max())
+    np.testing.assert_allclose(
+        got[what].astype(np.float32), whole[what].astype(np.float32),
+        rtol=0, atol=2 ** -7 * scale, err_msg=what)
+
+
+@pytest.mark.parametrize("window", [None, 40])
+@pytest.mark.parametrize("what", ("o",) + PARTS + ("lse",))
+def test_the_reference_on_parts_is_the_reference_on_their_concatenation(
+        what, window):
+    parts = _rand_parts(jax.random.key(15), 2, 64, 4, 2)
+    kw = dict(causal=True, window=window)
+    if what == "lse":
+        q, q_rope, k, k_rope, v = parts.values()
+        from dlrover_tpu.ops.attention import whole_q_and_k
+
+        got = mha_reference(
+            q, k, v, return_lse=True, q_rope=q_rope, k_rope=k_rope, **kw)
+        want = mha_reference(
+            *whole_q_and_k(q, k, q_rope, k_rope), v, return_lse=True, **kw)
+        np.testing.assert_array_equal(got[1], want[1])
+        return
+    got = _forward_and_grads(
+        lambda q, q_rope, k, k_rope, v: mha_reference(
+            q, k, v, q_rope=q_rope, k_rope=k_rope, **kw), parts)
+    want = _forward_and_grads(
+        _whole(functools.partial(mha_reference, **kw)), parts)
+    np.testing.assert_array_equal(got[what], want[what])
+
+
+def test_the_parts_decide_the_kernels_and_the_record_says_which(monkeypatch):
+    """What a caller hands decides: the dispatch under ``jax.jit``
+    passes the parts on and records ``rope_head_dim``, the gauge reads
+    2 for each kernel built on parts and 1 for one built on whole q
+    and k, and the default scale is the whole width's."""
+    from dlrover_tpu.ops import attention, tuning
+    from dlrover_tpu.telemetry.registry import default_registry
+
+    def parts_gauge(kernel):
+        return default_registry().get(
+            "attn_operand_parts").labels(kernel=kernel).value
+
+    monkeypatch.setattr(attention, "_use_pallas", lambda q, k: True)
+    parts = _rand_parts(jax.random.key(16), 1, 256, 2, 2)
+    q, q_rope, k, k_rope, v = parts.values()
+    # the undecorated function: traced whatever this process has
+    # traced before
+    dispatch = attention.flash_attention.__wrapped__
+    got = _forward_and_grads(
+        lambda q, q_rope, k, k_rope, v: dispatch(
+            q, k, v, q_rope=q_rope, k_rope=k_rope), parts)
+    record = tuning.last_selection()
+    assert (record["head_dim"], record["rope_head_dim"]) == (192, 64)
+    assert "v_head_dim" in record and record["source"] == "static"
+    assert parts_gauge("fwd") == parts_gauge("dqkv") == 2
+    want = _forward_and_grads(_whole(functools.partial(
+        mha_reference, scale=192 ** -0.5)), parts)
+    for what in ("o",) + PARTS:
+        np.testing.assert_allclose(
+            got[what], want[what], rtol=5e-3, atol=5e-3, err_msg=what)
+    _forward_and_grads(_whole(dispatch), parts)
+    assert "rope_head_dim" not in tuning.last_selection()
+    assert parts_gauge("fwd") == parts_gauge("dqkv") == 1
+    # off the TPU the same call is the reference's
+    monkeypatch.undo()
+    np.testing.assert_array_equal(
+        attention.flash_attention(q, k, v, q_rope=q_rope, k_rope=k_rope),
+        mha_reference(q, k, v, q_rope=q_rope, k_rope=k_rope))
+
+
+@pytest.mark.parametrize("change,sentence", [
+    (dict(k_rope=None), "come together"),
+    (dict(q_rope=None), "come together"),
+    (dict(k_rope=jnp.zeros((1, 128, 2, 64))), "one rotated key"),
+])
+def test_parts_that_do_not_fit_are_refused(change, sentence):
+    parts = _rand_parts(jax.random.key(17), 1, 128, 2, 2)
+    q, k, v = parts["q"], parts["k"], parts["v"]
+    rope = {**dict(q_rope=parts["q_rope"], k_rope=parts["k_rope"]), **change}
+    with pytest.raises(ValueError, match=sentence):
+        flash_attention_tpu(q, k, v, block_q=128, block_k=128, **rope)

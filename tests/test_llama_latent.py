@@ -16,7 +16,7 @@ import optax
 import pytest
 
 from dlrover_tpu.models import llama
-from dlrover_tpu.ops.attention import mha_reference
+from dlrover_tpu.ops.attention import mha_reference, whole_q_and_k
 from dlrover_tpu.parallel import moe
 from dlrover_tpu.parallel.mesh import create_mesh
 from dlrover_tpu.trainer.sharded import make_trainer_for_llama
@@ -149,14 +149,91 @@ def test_latent_attention_is_the_equations():
     )
     y = jax.random.normal(keys[2], (2, 32, 64))
     cos, sin = llama.rope_tables(32, cfg.rope_dim, cfg.rope_theta)
-    q, k, v = llama._latent_qkv(cfg, y, p, cos, sin)
-    assert q.shape == k.shape == (2, 32, 4, 24) and v.shape == (2, 32, 4, 16)
+    q, k, v, q_rope, k_rope = llama._latent_qkv(cfg, y, p, cos, sin)
+    assert q.shape == k.shape == v.shape == (2, 32, 4, 16)
     # one rotated key for every head
-    for n in range(1, 4):
-        np.testing.assert_array_equal(k[:, :, n, 16:], k[:, :, 0, 16:])
-    out = llama._operator_out(y, mha_reference(q, k, v), p, cfg.layer_plan()[0][0])
+    assert q_rope.shape == (2, 32, 4, 8) and k_rope.shape == (2, 32, 1, 8)
+    out = llama._operator_out(
+        y, mha_reference(q, k, v, q_rope=q_rope, k_rope=k_rope), p,
+        cfg.layer_plan()[0][0])
     np.testing.assert_allclose(
         out, _latent_attention_by_hand(cfg, y, p), rtol=1e-4, atol=1e-5)
+
+
+def _whole_qkv(cfg, y, p, cos, sin, constrain=None):
+    """``_latent_qkv`` as it was while attention took a head's q and k
+    whole: two products, the activations split, rotated in
+    neighbouring pairs, the rotated key copied to every head, and
+    concatenated. Handed on with no rotated parts."""
+    b, s, _ = y.shape
+    nh, nope, rope = (cfg.num_heads, cfg.qk_nope_head_dim,
+                      cfg.qk_rope_head_dim)
+    c_q = llama.rms_norm(y @ p["wq_a"], p["q_a_norm"], cfg.norm_eps)
+    c_kv, k_rope = jnp.split(y @ p["wkv_a"], [cfg.kv_lora_rank], axis=-1)
+    c_kv = llama.rms_norm(c_kv, p["kv_a_norm"], cfg.norm_eps)
+    q = (c_q @ p["wq_b"]).reshape(b, s, nh, -1)
+    kv = (c_kv @ p["wkv_b"]).reshape(b, s, nh, -1)
+    q_nope, q_rope = jnp.split(q, [nope], axis=-1)
+    k_nope, v = jnp.split(kv, [nope], axis=-1)
+    q_rope = llama.apply_rope(q_rope, cos, sin, cfg.rope_interleave)
+    k_rope = llama.apply_rope(
+        k_rope[:, :, None, :], cos, sin, cfg.rope_interleave)
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_rope, (b, s, nh, rope))], axis=-1)
+    return q, k, v, None, None
+
+
+@pytest.mark.parametrize("interleave", [True, False])
+@pytest.mark.parametrize("what", ["q", "k", "v"])
+def test_the_parts_side_by_side_are_the_whole_q_and_k(what, interleave):
+    """Rotated in neighbouring pairs, the whole q and k left their
+    rotated columns in (evens, odds) order: the parts' are in the same
+    one, taken on the weights."""
+    cfg = _latent(rope_interleave=interleave)
+    p = _init(cfg)["lead"][0]
+    y = jax.random.normal(jax.random.key(5), (2, 32, 64))
+    cos, sin = llama.rope_tables(32, cfg.rope_dim, cfg.rope_theta)
+    q, k, v, q_rope, k_rope = llama._latent_qkv(cfg, y, p, cos, sin)
+    got = dict(zip("qk", whole_q_and_k(q, k, q_rope, k_rope)), v=v)
+    want = dict(zip("qkv", _whole_qkv(cfg, y, p, cos, sin)))
+    assert got[what].shape == want[what].shape
+    np.testing.assert_allclose(got[what], want[what], rtol=1e-6, atol=1e-6)
+    if interleave and what != "v":
+        # and not the order of the halves' form on the same weights
+        other = dict(zip("qkv", _whole_qkv(
+            dataclasses.replace(cfg, rope_interleave=False), y, p, cos, sin)))
+        assert float(jnp.abs(other[what] - want[what]).max()) > 0.01
+
+
+@pytest.mark.parametrize("remat", ["off", "minimal"])
+def test_the_loss_and_every_gradient_are_the_whole_q_and_ks(
+        remat, monkeypatch):
+    """Every leaf's gradient in the leaf's own shape and column order:
+    the slices' and the (evens, odds) order's transposes put each
+    column's back where the leaf keeps it."""
+    cfg = _latent(remat=remat)
+    params, batch = _init(cfg), _batch(cfg)
+
+    def loss_and_grads():
+        # a function of its own a call: traced anew, with what is
+        # patched by then
+        return jax.jit(lambda params, batch: jax.value_and_grad(
+            llama.next_token_loss)(params, batch, cfg))(params, batch)
+
+    got, got_g = loss_and_grads()
+    monkeypatch.setattr(llama, "_latent_qkv", _whole_qkv)
+    want, want_g = loss_and_grads()
+    assert abs(float(got) - float(want)) < 1e-6
+    flat, _ = jax.tree_util.tree_flatten_with_path(got_g)
+    for (path, a), b in zip(flat, jax.tree.leaves(want_g)):
+        assert a.shape == b.shape, path
+        np.testing.assert_allclose(
+            a, b, rtol=2e-4, atol=2e-6, err_msg=str(path))
+    layer = got_g["period"][0]
+    for name in ("wq_b", "wkv_b", "wkv_a"):
+        assert layer[name].shape == params["period"][0][name].shape
+        assert float(jnp.abs(layer[name]).max()) > 0
 
 
 def test_the_routing_weights_take_the_factor_and_the_sources_eps():
@@ -218,7 +295,9 @@ def _loss_by_hand(params, batch, cfg):
         for i in range(cfg.num_layers - 1)]
     for layer_kind, p in layers:
         x, aux = llama._block(
-            cfg, x, p, cos, sin, mha_reference, kind=layer_kind)
+            cfg, x, p, cos, sin,
+            llama._operator_of(cfg, mha_reference, layer_kind),
+            kind=layer_kind)
         aux_sum = aux_sum + aux
     head = params["lm_head"]
 
@@ -236,7 +315,8 @@ def _loss_by_hand(params, batch, cfg):
         llama.rms_norm(x, m["hidden_norm"], cfg.norm_eps),
     ], axis=-1) @ m["eh_proj"]
     y, aux = llama._block(
-        cfg, merged, m["block"], cos, sin, mha_reference, kind=kind)
+        cfg, merged, m["block"], cos, sin,
+        llama._operator_of(cfg, mha_reference, kind), kind=kind)
     further = jnp.concatenate(
         [targets[:, 1:], jnp.full_like(targets[:, :1], -1)], axis=1)
     return (main + cfg.mtp_loss_weight * ce(y, m["final_norm"], further)
