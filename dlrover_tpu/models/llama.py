@@ -27,14 +27,16 @@ import jax
 import jax.numpy as jnp
 
 from dlrover_tpu.ops.attention import flash_attention
-from dlrover_tpu.ops.short_conv import gated_short_conv
+from dlrover_tpu.ops.delta_rule import gated_delta_rule
+from dlrover_tpu.ops.short_conv import causal_taps, gated_short_conv
 
 
 class LayerKind(NamedTuple):
     """What a layer is made of: its operator (``"full_attention"``,
     ``"latent_attention"``, whose q, k and v come through low-rank
-    projections, or ``"conv"``, the gated short convolution), for
-    attention the window (None: every earlier key) and whether q and
+    projections, ``"conv"``, the gated short convolution, or
+    ``"linear_attention"``, the gated delta rule), for attention the
+    window (None: every earlier key) and whether q and
     k are rotated, and its feed-forward (``"dense"`` or
     ``"experts"``)."""
     operator: str = "full_attention"
@@ -187,6 +189,26 @@ class LlamaConfig:
     # built.
     mtp_layers: int = 0
     mtp_loss_weight: float = 0.3
+    # linear attention, in the source's keys (``linear_attn_config``
+    # and ``kda_*`` of a delta-rule/full-attention hybrid): where
+    # ``layer_types[l]`` is "linear_attention" the operator is the
+    # gated delta rule with a decay for every key channel
+    # (ops/delta_rule.py) over ``linear_num_heads`` heads of
+    # ``linear_head_dim`` keys and as many values. q, k and v each pass
+    # a causal depthwise convolution of ``linear_conv_size`` taps and
+    # ``silu``, q and k an l2 norm a head; the log decay is ``-exp(A_log)
+    # softplus(f_b f_a y + dt_bias)`` through ``linear_gate_rank``
+    # columns (``kda_use_full_proj`` false), the step size ``sigmoid(y
+    # w_beta)``, doubled with ``linear_allow_neg_eigval``; the result
+    # passes an RMSNorm a head and a sigmoid gate of the same low rank.
+    linear_num_heads: int = 0
+    linear_head_dim: int = 128
+    linear_conv_size: int = 4
+    linear_gate_rank: int = 128
+    linear_allow_neg_eigval: bool = False
+    # a sigmoid gate on full attention's result, elementwise, from the
+    # layer's normed input through ``wg`` (``use_gqa_gate``)
+    attn_out_gate: bool = False
 
     def __post_init__(self):
         if self.remat not in ("off", "dots", "dots_attn_out",
@@ -236,11 +258,19 @@ class LlamaConfig:
                     f"layer_types has {len(self.layer_types)} entries "
                     f"for {self.num_layers} layers"
                 )
-            unknown = set(self.layer_types) - {"conv", "full_attention"}
+            unknown = set(self.layer_types) - {
+                "conv", "full_attention", "linear_attention"}
             if unknown:
                 raise ValueError(
                     f"layer_types names {sorted(unknown)}: the "
-                    "operators here are 'conv' and 'full_attention'"
+                    "operators here are 'conv', 'full_attention' and "
+                    "'linear_attention'"
+                )
+            if ("linear_attention" in self.layer_types
+                    and not self.linear_num_heads):
+                raise ValueError(
+                    "layer_types names 'linear_attention' and "
+                    "linear_num_heads gives it no head"
                 )
         if self.num_dense_layers and not (
                 self.num_experts > 0
@@ -316,7 +346,8 @@ class LlamaConfig:
         kinds = tuple(
             LayerKind(
                 operator, *(
-                    (None, False) if operator == "conv"
+                    (None, False)
+                    if operator in ("conv", "linear_attention")
                     else (windows[i], ropes[i])
                 ),
                 "experts" if self.num_experts > 0
@@ -396,6 +427,23 @@ def llama_latent_tiny(**kw) -> LlamaConfig:
     ), **kw})
 
 
+def llama_linear_tiny(**kw) -> LlamaConfig:
+    """Test-sized delta-rule/full-attention hybrid: a period of one
+    gated full-attention layer without positions and three layers of
+    the gated delta rule (4 heads of 16 behind four-tap convolutions),
+    8 experts by sigmoid score with a selection bias and a shared
+    one."""
+    return llama_tiny(**{**dict(
+        num_layers=4, layer_types=("full_attention",)
+        + ("linear_attention",) * 3, rope_layout=(0,) * 4,
+        attn_out_gate=True, linear_num_heads=4, linear_head_dim=16,
+        linear_gate_rank=16, linear_allow_neg_eigval=True,
+        num_experts=8, moe_top_k=2, moe_intermediate_size=32,
+        moe_gate="sigmoid", use_expert_bias=True,
+        moe_topk_norm_eps=1e-20, moe_shared_experts=1,
+    ), **kw})
+
+
 def llama_tiny(**kw) -> LlamaConfig:
     """Test-sized config that still exercises GQA + scan + remat."""
     kw.setdefault("vocab_size", 256)
@@ -425,6 +473,22 @@ def _leaves(cfg: LlamaConfig, kind: LayerKind) -> Dict:
             "conv_in": ((h, 3 * h), ("embed", "mlp")),
             "conv_out": ((h, h), ("mlp", "embed")),
         }
+    elif kind.operator == "linear_attention":
+        lh, ld, rank = (cfg.linear_num_heads, cfg.linear_head_dim,
+                        cfg.linear_gate_rank)
+        matrices = {
+            "wq": ((h, lh * ld), ("embed", "heads")),
+            "wk": ((h, lh * ld), ("embed", "heads")),
+            "wv": ((h, lh * ld), ("embed", "heads")),
+            "wo": ((lh * ld, h), ("heads", "embed")),
+            # the decay's and the output gate's low ranks
+            "f_a": ((h, rank), ("embed", None)),
+            "f_b": ((rank, lh * ld), (None, "heads")),
+            "g_a": ((h, rank), ("embed", None)),
+            "g_b": ((rank, lh * ld), (None, "heads")),
+            "w_beta": ((h, lh), ("embed", None)),
+        }
+        norms["o_norm"] = ld
     elif kind.operator == "latent_attention":
         rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
         nope, rope, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
@@ -446,6 +510,8 @@ def _leaves(cfg: LlamaConfig, kind: LayerKind) -> Dict:
             "wv": ((h, nkv * hd), ("embed", "kv_heads")),
             "wo": ((nh * hd, h), ("heads", "embed")),
         }
+        if cfg.attn_out_gate:
+            matrices["wg"] = ((h, nh * hd), ("embed", "heads"))
         if cfg.qk_norm:
             norms.update(q_norm=nh * hd, k_norm=nkv * hd)
         elif cfg.qk_head_norm:
@@ -482,6 +548,14 @@ def _leaves(cfg: LlamaConfig, kind: LayerKind) -> Dict:
     if kind.operator == "conv":
         taps = cfg.conv_L_cache
         leaves["conv_w"] = ((h, taps), ("mlp", None), taps ** -0.5)
+    if kind.operator == "linear_attention":
+        wide, taps = lh * ld, cfg.linear_conv_size
+        for name in ("conv_q", "conv_k", "conv_v"):
+            leaves[name] = ((wide, taps), ("heads", None), taps ** -0.5)
+        leaves["g_bias"] = ((wide,), ("norm",), 0)
+        # float32 vectors with draws of their own (``_DECAY_DRAWS``)
+        leaves["A_log"] = ((lh,), ("norm",), "A_log")
+        leaves["dt_bias"] = ((wide,), ("norm",), "dt_bias")
     if kind.ffn == "experts" and cfg.use_expert_bias:
         leaves["expert_bias"] = ((cfg.num_experts,), (None,), 0)
     return leaves
@@ -492,7 +566,27 @@ def _leaves(cfg: LlamaConfig, kind: LayerKind) -> Dict:
 _DRAW = {"wq": 0, "wk": 1, "wv": 2, "wo": 3, "w_gate": 4, "w_up": 5,
          "w_down": 6, "router": 7, "conv_in": 0, "conv_w": 1,
          "conv_out": 3, "wq_a": 8, "wq_b": 9, "wkv_a": 10, "wkv_b": 11,
-         "ws_gate": 12, "ws_up": 13, "ws_down": 14}
+         "ws_gate": 12, "ws_up": 13, "ws_down": 14, "conv_q": 15,
+         "conv_k": 16, "conv_v": 17, "f_a": 18, "f_b": 19, "g_a": 20,
+         "g_b": 21, "w_beta": 22, "A_log": 23, "dt_bias": 24, "wg": 25}
+
+
+def _draw_A_log(key, shape):
+    """``log`` of a head's decay rate, the rate uniform in [1, 16)."""
+    return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
+
+
+def _draw_dt_bias(key, shape):
+    """What ``softplus`` takes to a step ``dt`` drawn log-uniform in
+    [0.001, 0.1]: ``dt + log(1 - exp(-dt))``."""
+    dt = jnp.exp(jax.random.uniform(
+        key, shape, jnp.float32, math.log(1e-3), math.log(1e-1)
+    ))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+#: the decay's two vectors, kept in float32: how each is drawn
+_DECAY_DRAWS = {"A_log": _draw_A_log, "dt_bias": _draw_dt_bias}
 
 
 def _init_layers(key, cfg: LlamaConfig, kind: LayerKind, stack=()):
@@ -508,11 +602,12 @@ def _init_layers(key, cfg: LlamaConfig, kind: LayerKind, stack=()):
             )
             continue
         draw = _DRAW[name]
+        draw = ks[draw] if draw < 8 else jax.random.fold_in(key, draw)
+        if std in _DECAY_DRAWS:
+            layers[name] = _DECAY_DRAWS[std](draw, stack + shape)
+            continue
         layers[name] = (
-            jax.random.normal(
-                ks[draw] if draw < 8 else jax.random.fold_in(key, draw),
-                stack + shape, jnp.float32,
-            ) * std
+            jax.random.normal(draw, stack + shape, jnp.float32) * std
         ).astype(cfg.dtype)
     return layers
 
@@ -729,6 +824,8 @@ def _pre_attn(cfg: LlamaConfig, x, layer_params, cos, sin,
         return (bcu, p["conv_w"]), logits()
     if kind.operator == "latent_attention":
         return _latent_qkv(cfg, y, p, cos, sin, constrain), logits()
+    if kind.operator == "linear_attention":
+        return _delta_rule_operands(cfg, y, p, constrain), logits()
     q, k = y @ p["wq"], y @ p["wk"]
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"], cfg.norm_eps)
@@ -741,7 +838,62 @@ def _pre_attn(cfg: LlamaConfig, x, layer_params, cos, sin,
     v = constrain((y @ p["wv"]).reshape(b, s, nkv, hd), _KV)
     if kind.rope:
         q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+    if cfg.attn_out_gate:
+        with jax.named_scope("attn.gate"):
+            return (q, k, v, y @ p["wg"]), logits()
     return (q, k, v), logits()
+
+
+def _causal_conv_silu(x, w):
+    """``silu`` of a causal depthwise convolution along the sequence:
+    ``x`` [batch, seq, channels], ``w`` [channels, taps], a channel's
+    taps oldest first, nothing before a sequence's first position.
+    Shifted multiply-adds in float32, as ``gated_short_conv_plain``
+    has them."""
+    return jax.nn.silu(causal_taps(x.astype(jnp.float32), w))
+
+
+#: added to a head's sum of squares before the root, in ``_l2norm``
+L2_NORM_EPS = 1e-6
+
+
+def _l2norm(x):
+    """``x`` [..., d] float32 over its last axis's length."""
+    return x * jax.lax.rsqrt(
+        jnp.sum(x * x, axis=-1, keepdims=True) + L2_NORM_EPS
+    )
+
+
+def _delta_rule_operands(cfg: LlamaConfig, y, p, constrain=_free):
+    """The gated delta rule's operands from the normed stream ``y``:
+    ``(q, k, v [b, s, heads, d], g [b, s, heads, d] float32, beta [b,
+    s, heads] float32, the output gate's pre-activation [b, s, heads x
+    d])``. The scopes name every op: ``kda.proj`` the three
+    projections, the two low ranks and the step size's; ``kda.conv``
+    the convolutions with ``silu`` and the l2 norms; ``kda.decay`` the
+    log decay and the step size."""
+    b, s, _ = y.shape
+    heads, d = cfg.linear_num_heads, cfg.linear_head_dim
+    with jax.named_scope("kda.proj"):
+        q, k, v = (constrain(y @ p[w], _MLP) for w in ("wq", "wk", "wv"))
+        decay = (y @ p["f_a"]) @ p["f_b"]
+        gate = (y @ p["g_a"]) @ p["g_b"]
+        step = y @ p["w_beta"]
+    with jax.named_scope("kda.conv"):
+        q, k, v = (
+            _causal_conv_silu(x, p[w]).reshape(b, s, heads, d)
+            for x, w in ((q, "conv_q"), (k, "conv_k"), (v, "conv_v"))
+        )
+        q, k = _l2norm(q).astype(y.dtype), _l2norm(k).astype(y.dtype)
+        v = v.astype(y.dtype)
+    with jax.named_scope("kda.decay"):
+        g = -jnp.exp(p["A_log"])[:, None] * jax.nn.softplus(
+            decay.astype(jnp.float32) + p["dt_bias"]
+        ).reshape(b, s, heads, d)
+        beta = jax.nn.sigmoid(step.astype(jnp.float32))
+        if cfg.linear_allow_neg_eigval:
+            beta = 2.0 * beta
+    return q, k, v, g, beta, gate
 
 
 def _evens_then_odds(w):
@@ -853,13 +1005,32 @@ def _expert_mlp(cfg: LlamaConfig, expert_parallel: bool):
     )
 
 
-def _operator_out(x, out, layer_params, kind: LayerKind):
-    """The operator's result through its output projection."""
+def _operator_out(x, out, layer_params, kind: LayerKind,
+                  norm_eps: float = 1e-5):
+    """The operator's result through its output projection; with
+    the gate's logits beside it (``_operator_of``), through its gate
+    first, and linear attention's through the heads' norm at
+    ``norm_eps``."""
     b, s, _ = x.shape
+    p = layer_params
     if kind.operator == "conv":
         with jax.named_scope("conv.out_proj"):
-            return out @ layer_params["conv_out"]
-    return out.reshape(b, s, -1) @ layer_params["wo"]
+            return out @ p["conv_out"]
+    if kind.operator == "linear_attention":
+        # an RMSNorm a head with one learned scale, a sigmoid gate
+        with jax.named_scope("kda.out"):
+            o, gate = out
+            gate = jax.nn.sigmoid(
+                gate.astype(jnp.float32) + p["g_bias"]
+            ).astype(o.dtype)
+            o = rms_norm(o, p["o_norm"], norm_eps).reshape(b, s, -1)
+            return (o * gate) @ p["wo"]
+    if isinstance(out, tuple):  # full attention and its gate's logits
+        with jax.named_scope("attn.gate"):
+            out, gate = out
+            out = out.reshape(b, s, -1) * jax.nn.sigmoid(
+                gate.astype(jnp.float32)).astype(out.dtype)
+    return out.reshape(b, s, -1) @ p["wo"]
 
 
 def _post_attn(cfg: LlamaConfig, x, out, layer_params,
@@ -869,7 +1040,9 @@ def _post_attn(cfg: LlamaConfig, x, out, layer_params,
     projection + residual + MLP. ``router_logits``: ``_pre_attn``'s,
     where the router reads the block's input."""
     p = layer_params
-    x = constrain(x + _operator_out(x, out, p, kind), _RESIDUAL)
+    x = constrain(
+        x + _operator_out(x, out, p, kind, cfg.norm_eps), _RESIDUAL
+    )
     y = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
     if kind.ffn == "experts":
         mlp = _expert_mlp(cfg, expert_parallel)
@@ -915,7 +1088,10 @@ def _operator_of(cfg: LlamaConfig, attn_fn, kind: LayerKind):
     windowed layer's window to ``attn_fn`` (which has to take it);
     latent attention's call is ``attn.latent``, and hands ``attn_fn``
     the rotated parts as ``q_rope`` and ``k_rope`` (which it has to
-    take, as ``flash_attention`` and ``mha_reference`` do)."""
+    take, as ``flash_attention`` and ``mha_reference`` do). Linear
+    attention's call is ``kda.scan``, the gated delta rule; it hands
+    the output gate's logits on beside its result, as attention does
+    with ``attn_out_gate``, for ``_operator_out``."""
     if kind.operator == "conv":
 
         def mix(bcu, w):
@@ -923,6 +1099,13 @@ def _operator_of(cfg: LlamaConfig, attn_fn, kind: LayerKind):
                 return gated_short_conv(bcu, w)
 
         return mix
+    if kind.operator == "linear_attention":
+
+        def scan(q, k, v, g, beta, gate):
+            with jax.named_scope("kda.scan"):
+                return gated_delta_rule(q, k, v, g, beta), gate
+
+        return scan
     if kind.operator == "latent_attention":
 
         def attend_latent(q, k, v, q_rope, k_rope):
@@ -930,16 +1113,18 @@ def _operator_of(cfg: LlamaConfig, attn_fn, kind: LayerKind):
                 return attn_fn(q, k, v, q_rope=q_rope, k_rope=k_rope)
 
         return attend_latent
-    if cfg.sliding_window_layout is None and cfg.layer_types is None:
-        return attn_fn
-
-    def attend(q, k, v):
+    def scoped(q, k, v):
         if kind.window is None:
             with jax.named_scope("attn.full"):
                 return attn_fn(q, k, v)
         with jax.named_scope("attn.window"):
             return attn_fn(q, k, v, window=kind.window)
 
+    attend = scoped
+    if cfg.sliding_window_layout is None and cfg.layer_types is None:
+        attend = attn_fn
+    if cfg.attn_out_gate:
+        return lambda q, k, v, gate: (attend(q, k, v), gate)
     return attend
 
 
@@ -1278,15 +1463,14 @@ def set_mtp_loss_gauge(value) -> float:
     return value
 
 
-def _routed(params, tokens, cfg: LlamaConfig, attn_fn, stat):
-    """``stat(logits, layer_params)`` of every expert layer, stacked
-    [expert layers, ...], for ``tokens`` [batch, seq]: a forward pass
-    that also records, a layer, what the router saw (its logits for
-    the hidden states it really reads; jit-able)."""
-    if cfg.num_experts == 0:
-        raise ValueError("routing_stats: a dense config has no router")
-    from dlrover_tpu.parallel.moe import router_logits
-
+def _seen_in_layers(params, tokens, cfg: LlamaConfig, attn_fn, see):
+    """``see(kind, x, layer_params, operands, out, logits)`` of every
+    scanned layer, stacked [layers, ...], for ``tokens`` [batch, seq]:
+    a forward pass that also records, a layer, something of what the
+    layer made on the way (jit-able). ``operands`` and ``logits`` are
+    ``_pre_attn``'s, ``out`` the operator's result; what ``see``
+    returns in ``logits``' place, if anything, goes on to
+    ``_post_attn``."""
     if attn_fn is None:
         attn_fn = partial(flash_attention, causal=True)
     cos, sin = rope_tables(tokens.shape[1], cfg.rope_dim, cfg.rope_theta)
@@ -1297,14 +1481,7 @@ def _routed(params, tokens, cfg: LlamaConfig, attn_fn, stat):
         def body(x, p):
             operands, logits = _pre_attn(cfg, x, p, cos, sin, kind=kind)
             out = operate(*operands)
-            seen = None
-            if kind.ffn == "experts":
-                if logits is None:
-                    logits = router_logits(rms_norm(
-                        x + _operator_out(x, out, p, kind),
-                        p["mlp_norm"], cfg.norm_eps,
-                    ), p["router"])
-                seen = stat(logits, p)
+            seen, logits = see(kind, x, p, operands, out, logits)
             x, _ = _post_attn(cfg, x, out, p, logits, kind=kind)
             return x, seen
 
@@ -1313,6 +1490,27 @@ def _routed(params, tokens, cfg: LlamaConfig, attn_fn, stat):
     return _through_layers(
         cfg, layer_of, params["embed"][tokens], params
     )[1]
+
+
+def _routed(params, tokens, cfg: LlamaConfig, attn_fn, stat):
+    """``stat(logits, layer_params)`` of every expert layer, stacked
+    [expert layers, ...], for ``tokens`` [batch, seq]: what the router
+    saw (its logits for the hidden states it really reads)."""
+    if cfg.num_experts == 0:
+        raise ValueError("routing_stats: a dense config has no router")
+    from dlrover_tpu.parallel.moe import router_logits
+
+    def see(kind, x, p, operands, out, logits):
+        if kind.ffn != "experts":
+            return None, logits
+        if logits is None:
+            logits = router_logits(rms_norm(
+                x + _operator_out(x, out, p, kind, cfg.norm_eps),
+                p["mlp_norm"], cfg.norm_eps,
+            ), p["router"])
+        return stat(logits, p), logits
+
+    return _seen_in_layers(params, tokens, cfg, attn_fn, see)
 
 
 def _selection(cfg: LlamaConfig, layer_params) -> Dict:
@@ -1354,10 +1552,43 @@ def bias_changed_stats(params: Dict, tokens: jax.Array,
     )
 
 
+def decay_min(params: Dict, tokens: jax.Array, cfg: LlamaConfig,
+              attn_fn=None) -> jax.Array:
+    """The least ``alpha = exp(g)`` that a channel of each scanned
+    layer's gated delta rule takes on ``tokens`` [batch, seq], float32
+    [layers]; 1 for a layer of another kind. A forward pass
+    (jit-able), before the scan's floor: ``gated_delta_rule`` takes
+    a step under ``exp(-10)`` as ``exp(-10)`` (ops/delta_rule.py
+    ``G_FLOOR``), and this is the number that says whether a run's
+    channels get there."""
+    def see(kind, x, p, operands, out, logits):
+        if kind.operator != "linear_attention":
+            return jnp.ones((), jnp.float32), logits
+        return jnp.exp(jnp.min(operands[3])), logits
+
+    return _seen_in_layers(params, tokens, cfg, attn_fn, see)
+
+
+def set_decay_min_gauge(least) -> float:
+    """Set the gauge ``kda_decay_min`` (``GET /metrics``) to the
+    least of ``decay_min``'s values at an evaluation."""
+    from dlrover_tpu.telemetry.registry import gauge
+
+    value = float(jnp.min(least))
+    gauge(
+        "kda_decay_min",
+        "least decay alpha = exp(g) of a key channel of the gated "
+        "delta rule, over the layers, at the last evaluation",
+    ).set(value)
+    return value
+
+
 def flops_per_token(cfg: LlamaConfig, seq_len: int) -> float:
     """Approximate training FLOPs per token (6N_active + attention
     quadratic, at ``num_heads x head_dim`` and by each layer's kind;
-    the convolution's taps are not counted), a prediction module's
+    the convolution's taps are not counted, and of the gated delta
+    rule its projections and low ranks but not the recurrence), a
+    prediction module's
     block and second pass through the head included. For MoE, only
     the top-k routed experts execute per token, so N counts k experts
     — not all E."""
@@ -1378,7 +1609,8 @@ def flops_per_token(cfg: LlamaConfig, seq_len: int) -> float:
     # lets it see, causality not counted: the sequence, or the window
     keys = sum(
         count * min(kind.window or seq_len, seq_len)
-        for kind, count in kinds if kind.operator != "conv"
+        for kind, count in kinds
+        if kind.operator not in ("conv", "linear_attention")
     )
     # a head's scores contract over q and k's width, its weighted
     # values are v's wide

@@ -1,0 +1,136 @@
+"""The gated delta rule with a decay of its own for every key channel
+(Kimi Delta Attention): a linear-attention layer's recurrence, a head
+at a time. With ``alpha_t = exp(g_t)`` in (0, 1]^d and a step size
+``beta_t``, the state ``S`` [d keys, d values] starts at zero and::
+
+    S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T
+    o_t = S_t^T q_t / sqrt(d)
+
+The state forgets channel by channel, then the rank-one update takes
+out what it holds along ``k_t`` and writes ``v_t`` there.
+
+Computed in chunks of ``CHUNK`` positions. With ``G_t`` the log decay
+cumulated from the chunk's start and ``w_t = beta_t (v_t - S_{t-1}^T
+(alpha_t * k_t))`` what position t really writes::
+
+    A[t, i] = sum_c k_t[c] k_i[c] exp(G_t[c] - G_i[c])      i < t
+    B[t, i] = sum_c q_t[c] k_i[c] exp(G_t[c] - G_i[c])      i <= t
+    (I + Diag(beta) A) W = beta * (V - (K * exp(G)) S_0)
+    O = ((Q * exp(G)) S_0 + B W) / sqrt(d)
+    S_C = Diag(exp(G_C)) S_0 + (K * exp(G_C - G))^T W
+
+so the sequential part is one unit lower-triangular system a chunk
+and a [d, d] state handed from chunk to chunk.
+
+On the TPU, where the shapes tile, the Pallas kernels of
+ops/pallas/delta_rule.py (forward; backward over the chunks' entry
+states that the forward keeps when it is differentiated), which are
+exact while no step forgets faster than ``G_FLOOR``: the entry sees
+to that. Elsewhere
+``gated_delta_rule_plain``: the equations above under a ``lax.scan``
+over chunks, every ``exp(G_t - G_i)`` taken pair by pair (exact at any
+decay: no exponent is positive), the system solved by substitution,
+differentiated by JAX. float32 inside both, whatever the operands'
+dtype.
+"""
+
+import jax
+import jax.numpy as jnp
+
+#: positions of a chunk, in the plain path and in the kernels
+CHUNK = 64
+#: the least log decay a step that ``gated_delta_rule`` takes: a faster
+#: one is taken as this, ``alpha`` of 4.5e-5 where it was smaller yet.
+#: It bounds what a block of sixteen positions spans, which is what
+#: lets the kernels factor a pair's decay into a row's and a column's
+#: part exactly (ops/pallas/delta_rule.py)
+G_FLOOR = -10.0
+
+
+def _use_pallas(q: jax.Array) -> bool:
+    if jax.default_backend() != "tpu":
+        return False
+    from dlrover_tpu.ops.pallas.delta_rule import tiles_the_kernel
+
+    return tiles_the_kernel(q.shape)
+
+
+def gated_delta_rule_plain(q, k, v, g, beta, chunk: int = CHUNK):
+    """The chunked equations as they stand, in float32, rounded once.
+    A sequence that is no whole number of chunks is padded with
+    positions that leave the state as it is (``k`` 0, ``g`` 0, ``beta``
+    0)."""
+    b, s, h, d = q.shape
+    c = min(chunk, s)
+    pad = -s % c
+    f32 = jnp.float32
+
+    def chunks(x):
+        x = x.astype(f32)
+        if pad:
+            x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+        # [chunks, batch, heads, c, ...]
+        x = x.reshape(b, -1, c, *x.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(x, 1, 0), 2, 3)
+
+    lower = jnp.tril(jnp.ones((c, c), bool))
+    strictly = jnp.tril(jnp.ones((c, c), bool), -1)
+    eye = jnp.eye(c, dtype=f32)
+
+    @jax.checkpoint
+    def step(state, x):  # state [b, h, keys, values]
+        q, k, v, g, beta = x
+        beta = beta[..., None]
+        gc = jnp.cumsum(g, axis=-2)
+        # exp(G_t - G_i) [b, h, t, i, c]: no positive exponent where
+        # i <= t, and none taken where i > t (masked below)
+        decay = jnp.exp(jnp.where(
+            lower[:, :, None],
+            gc[..., :, None, :] - gc[..., None, :, :], 0.0,
+        ))
+        a = jnp.where(strictly, jnp.einsum(
+            "bhtc,bhic,bhtic->bhti", k, k, decay), 0.0)
+        bb = jnp.where(lower, jnp.einsum(
+            "bhtc,bhic,bhtic->bhti", q, k, decay), 0.0)
+        gamma = jnp.exp(gc)
+        rhs = beta * (v - jnp.einsum("bhtc,bhcv->bhtv", k * gamma, state))
+        w = jax.scipy.linalg.solve_triangular(
+            eye + beta * a, rhs, lower=True, unit_diagonal=True
+        )
+        o = (jnp.einsum("bhtc,bhcv->bhtv", q * gamma, state)
+             + jnp.einsum("bhti,bhiv->bhtv", bb, w))
+        last = gc[..., -1:, :]
+        state = (
+            jnp.swapaxes(jnp.exp(last), -1, -2) * state
+            + jnp.einsum("bhtc,bhtv->bhcv", k * jnp.exp(last - gc), w)
+        )
+        return state, o
+
+    xs = tuple(chunks(x) for x in (q, k, v, g, beta))
+    _, o = jax.lax.scan(step, jnp.zeros((b, h, d, v.shape[-1]), f32), xs)
+    # [chunks, b, h, c, values] -> [b, s, h, values]
+    o = jnp.moveaxis(jnp.moveaxis(o, 3, 2), 0, 1).reshape(b, s + pad, h, -1)
+    return (o[:, :s] * d ** -0.5).astype(v.dtype)
+
+
+def gated_delta_rule(q, k, v, g, beta):
+    """``q, k`` [batch, seq, heads, d], ``v`` [batch, seq, heads, d],
+    ``g`` the same shape as ``k`` (the log of the decay, float32, at
+    most 0), ``beta`` [batch, seq, heads] to ``o`` [batch, seq, heads,
+    d] in ``v``'s dtype. Differentiable in all five. A sequence is a
+    row of the batch: the state starts at zero at its first
+    position. A log decay under ``G_FLOOR`` is taken as ``G_FLOOR``,
+    on either path: what a channel keeps of its state over such a step
+    is then 4.5e-5 and not less, and ``g`` there gets no gradient."""
+    if not (q.shape == k.shape == g.shape and v.shape[:3] == q.shape[:3]
+            and beta.shape == q.shape[:3]):
+        raise ValueError(
+            f"gated_delta_rule: q {q.shape}, k {k.shape}, v {v.shape}, "
+            f"g {g.shape}, beta {beta.shape}"
+        )
+    g = jnp.maximum(g, G_FLOOR)
+    if _use_pallas(q):
+        from dlrover_tpu.ops.pallas.delta_rule import delta_rule_tpu
+
+        return delta_rule_tpu(q, k, v, g, beta)
+    return gated_delta_rule_plain(q, k, v, g, beta)

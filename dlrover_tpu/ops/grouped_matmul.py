@@ -72,11 +72,13 @@ def tiles(rows: int, contraction: int, columns: int, most: int = None):
     """(rows, contraction, columns) of one tile: in each dimension the
     largest multiple of 128 that divides it within ``TILE_CAPS``
     ((512, 1024, 1024) at 2048 x 1024, (512, 640, 768) at 2560 x
-    768, (512, 1024, 896) at 2048 x 1792); None where a dimension has
-    no such divisor. ``most``: the most elements of the [contraction,
-    columns] face, where the caller's result is wider than bf16; the
-    largest face within it, the longer contraction among equals
-    ((512, 512, 896) at 2048 x 1792, and 2560 x 768's as above)."""
+    768, (512, 1024, 896) at 2048 x 1792, (512, 1024, 640) at 4096 x
+    1280 and (512, 640, 1024) at its reverse); None where a dimension
+    has no such divisor. ``most``: the most elements of the
+    [contraction, columns] face, where the caller's result is wider
+    than bf16; the largest face within it, the longer contraction
+    among equals ((512, 512, 896) at 2048 x 1792, (512, 512, 640) at
+    4096 x 1280, and 2560 x 768's as above)."""
     fits = [
         [t for t in range(LANES, cap + 1, LANES) if size % t == 0]
         for size, cap in zip((rows, contraction, columns), TILE_CAPS)

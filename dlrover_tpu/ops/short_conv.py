@@ -30,11 +30,11 @@ def _use_pallas(bcu: jax.Array, w: jax.Array) -> bool:
     return tiles_the_kernel(bcu.shape, w.shape)
 
 
-def gated_short_conv_plain(bcu: jax.Array, w: jax.Array) -> jax.Array:
-    """The equations above as they stand, in float32, rounded once."""
-    b, c, u = jnp.split(bcu.astype(jnp.float32), 3, axis=-1)
-    seq, taps = bcu.shape[1], w.shape[1]
-    v = b * u
+def causal_taps(v: jax.Array, w: jax.Array) -> jax.Array:
+    """The depthwise causal convolution alone: ``v`` [batch, seq,
+    channels] float32 against ``w`` [channels, taps], a channel's
+    taps oldest first, as ``taps`` shifted multiply-adds."""
+    seq, taps = v.shape[1], w.shape[1]
     wf = w.astype(jnp.float32)
     acc = jnp.zeros_like(v)
     for j in range(taps):
@@ -42,7 +42,13 @@ def gated_short_conv_plain(bcu: jax.Array, w: jax.Array) -> jax.Array:
         acc = acc + wf[:, j] * jnp.pad(
             v, ((0, 0), (back, 0), (0, 0))
         )[:, :seq]
-    return (c * acc).astype(bcu.dtype)
+    return acc
+
+
+def gated_short_conv_plain(bcu: jax.Array, w: jax.Array) -> jax.Array:
+    """The equations above as they stand, in float32, rounded once."""
+    b, c, u = jnp.split(bcu.astype(jnp.float32), 3, axis=-1)
+    return (c * causal_taps(b * u, w)).astype(bcu.dtype)
 
 
 def gated_short_conv(bcu: jax.Array, w: jax.Array) -> jax.Array:

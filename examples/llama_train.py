@@ -51,6 +51,7 @@ MODELS = {
     # 4 experts, top-2: dropless on one device (parallel/moe.py)
     "llama_moe_tiny": llama.llama_moe_tiny,
     "llama_latent_tiny": llama.llama_latent_tiny,
+    "llama_linear_tiny": llama.llama_linear_tiny,
 }
 
 
@@ -222,6 +223,9 @@ def main():
     mtp_loss = None
     if cfg.mtp_layers:
         mtp_loss = jax.jit(functools.partial(llama.mtp_loss, cfg=cfg))
+    decay_min = None
+    if "linear_attention" in (cfg.layer_types or ()):
+        decay_min = jax.jit(functools.partial(llama.decay_min, cfg=cfg))
 
     device = devices[0]
     step, loss, losses = start_step, None, []
@@ -308,6 +312,14 @@ def main():
                     )
                     print(f"MTP_LOSS step={step} loss={float(loss):.4f} "
                           f"mtp_loss={term:.4f}", flush=True)
+                if decay_min is not None:
+                    # how fast the delta rule's fastest channel
+                    # forgets on this batch (GET /metrics)
+                    least = llama.set_decay_min_gauge(
+                        decay_min(params, mb[0][0])
+                    )
+                    print(f"KDA_DECAY step={step} min_alpha={least:.3e}",
+                          flush=True)
                 ckpt.save(
                     step,
                     {"params": params, "opt_state": opt_state,

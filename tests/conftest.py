@@ -112,8 +112,11 @@ MODULE_BUDGET_OVERRIDES = {
     # since PR 36 lfm2's thirteen-layer step too, 150s of its own;
     # since PR 39 OLMoE's step traced and lowered, 20s of its own;
     # since PR 43 joyai's step at the least effort, 70s of its own,
-    # and latent attention's kernels on its parts: 394s alone
-    "test_chip_compile": 760.0,
+    # and latent attention's kernels on its parts: 394s alone; since
+    # PR 44 solar's four-layer step at the least effort, 55-75s of
+    # its own, which is all the budget gains: 562s beside five other
+    # workers
+    "test_chip_compile": 830.0,
     # Pallas kernels in interpret mode, since PR 39 the in-place sum
     # against megablox's on seven pieces: 47s alone, 71s beside five
     # other workers
@@ -167,6 +170,21 @@ MODULE_BUDGET_OVERRIDES = {
     # of them holding a step for a second: 35 s alone, 84 s beside five
     # other workers (PR 38)
     "test_yardstick_host_stall": 150.0,
+    # the delta rule's kernels in interpret mode and the recurrence
+    # walked token by token, each jitted forward and backward for
+    # nine sets of operands: 85 s alone, 98 s beside five other
+    # workers (PR 44)
+    "test_delta_rule": 200.0,
+    # eight-layer delta-rule hybrids jitted forward and backward under
+    # each remat policy, the kernels in interpret mode inside a model,
+    # a trainer over eight CPU devices: 145 s alone, 199 s beside five
+    # other workers (PR 44)
+    "test_llama_linear": 400.0,
+    # fourteen changed references jitted at the tiny size on two
+    # batches, each walking the recurrence token by token, the program
+    # under three remat policies: 75 s alone, 206 s beside five other
+    # workers (PR 44)
+    "test_yardstick_solar": 400.0,
     "test_context_parallel": 180.0,
     # since PR 42 the kernels at latent attention's (192, 128) too,
     # one backward kernel and the pair: 195 s alone, 272 s beside five

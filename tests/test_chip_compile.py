@@ -1064,6 +1064,94 @@ def test_joyai_step_holds_no_whole_q_or_k(topo, on_tpu_path, monkeypatch):
     assert tuning.last_selection()["rope_head_dim"] == 64
 
 
+#: ``peak_memory_in_bytes`` of ``solar-open2-250b-ep32.steady``'s step
+#: as this file compiles it (1 x 8,192, four layers, remat ``minimal``,
+#: the least effort; PERF.md, PR 44): 8.5 GB of it the state. At the
+#: default effort it plans 15,769,854,464
+SOLAR_STEP_BYTES = 16_240_236_032
+
+
+def test_solar_step_holds_the_delta_rules_kernels(
+    topo, on_tpu_path, monkeypatch
+):
+    """``solar-open2-250b-ep32.steady``'s step: it fits and plans no
+    more than was read when the cell was built; a linear-attention
+    layer's Pallas calls (the forward, the forward again under
+    ``minimal``, and the backward over the entry states it kept) are
+    named as the benchmark's ``delta_rule_ms`` tells them, and as
+    neither the attention's, the experts' nor the convolution's
+    readers do (a compiled step's instruction names are a device
+    trace's), and carry ``kda.scan``; the 4096 x 1280 experts' products
+    and in-place float32 sums take the tiles the rule gives them; and
+    every fusion on a ``[1, 8192, 8192]`` array says whose op it is."""
+    from dlrover_tpu.ops import delta_rule, grouped_matmul as gm
+    from dlrover_tpu.ops.pallas import delta_rule as scan_kernels
+    from yardstick import cells, worker
+    from yardstick.layer_metrics import (
+        attn_kernel_ms, delta_rule_ms, moe_expert_ms, short_conv_ms,
+    )
+
+    monkeypatch.setattr(gm, "_use_pallas", lambda lhs, rhs: True)
+    monkeypatch.setattr(gm, "_interpret", lambda: False)
+    monkeypatch.setattr(gm, "_add_on_mxu", lambda out, rows: True)
+    monkeypatch.setattr(delta_rule, "_use_pallas", lambda q: True)
+    monkeypatch.setattr(scan_kernels, "_interpret", lambda: False)
+    _, config, traffic = cells.load_cell("solar-open2-250b-ep32.steady")
+    cfg = worker.program_config(config, traffic)
+    assert (cfg.remat, cfg.loss_chunk) == ("minimal", 0)
+    assert gm.tiles(5120, 4096, 1280) == (512, 1024, 640)
+    assert gm.tiles(5120, 1280, 4096) == (512, 640, 1024)
+    assert gm.tiles(5120, 4096, 1280, most=gm.IN_PLACE_TILE) == (
+        512, 512, 640)
+    mesh = Mesh(
+        np.array(topo.devices[:1]).reshape(1, 1), ("data", "fsdp"))
+    trainer = make_trainer_for_llama(
+        cfg, mesh, strategy=traffic["strategy"],
+        optimizer=optax.adamw(traffic["optimizer"]["learning_rate"]),
+    )
+    compiled = trainer.train_step.lower(*_abstract_step_args(
+        trainer, traffic["global_batch"], traffic["seq"])
+    ).compile(LEAST_EFFORT)
+    planned = compiled.memory_analysis().peak_memory_in_bytes
+    print("solar step plans", planned)
+    assert planned <= SOLAR_STEP_BYTES
+    text = compiled.as_text()
+    kernels = re.findall(
+        r"%([\w.\-]+) = ([^\n]*?) custom-call\([^\n]*"
+        r"custom_call_target=\"tpu_custom_call\"[^\n]*"
+        r"op_name=\"([^\"]*)\"", text)
+    scan = [(name, op) for name, _, op in kernels
+            if delta_rule_ms.KERNEL.search(name)]
+    # three linear positions of the period, each the forward, the
+    # forward again and the backward
+    assert len(scan) == 3 * 3, [name for name, _ in scan]
+    assert all("kda.scan" in op for _, op in scan)
+    others = [name for name, _, op in kernels if "kda.scan" not in op]
+    assert others and not any(
+        delta_rule_ms.KERNEL.search(name) for name in others)
+    assert not any(
+        attn_kernel_ms.KERNEL.search(name)
+        or moe_expert_ms.KERNEL.search(name)
+        or short_conv_ms.KERNEL.search(name) for name, _ in scan)
+    # the period's attention layer: the forward, the forward again and
+    # the one backward kernel (a group of 8: a kv head's dK and dV
+    # resident)
+    assert sum(bool(attn_kernel_ms.KERNEL.search(n)) for n in others) == 3
+    assert fa._one_backward_kernel(8, 8192, 128)
+    assert sum(bool(moe_expert_ms.KERNEL.search(n)) for n in others) >= 9
+    assert not any(short_conv_ms.KERNEL.search(n) for n in others)
+    # the backward's entry states: [batch, heads, chunks, 128, 128]
+    assert "f32[1,64,128,128,128]" in text
+    wide = re.compile(r"= \w+\[1,8192,8192\]")
+    unscoped = [
+        line[:160] for line in text.splitlines()
+        if wide.search(line) and "op_name=" in line and "fusion(" in line
+        and "kda." not in line and "attn." not in line
+    ]
+    assert not unscoped
+    assert tuning.last_selection()["gqa_group"] == 8
+
+
 def test_fsdp_step_lowers_over_four_chips(topo, on_tpu_path):
     """Full widths, depth cut to two layers, the mesh
     examples/llama_train.py builds on a four-chip host."""

@@ -1,0 +1,277 @@
+"""The gated delta rule (ops/delta_rule.py): the plain chunked path
+and the Pallas kernels in interpret mode against the recurrence walked
+token by token, ``o`` and all five gradients."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from dlrover_tpu.ops import delta_rule
+from dlrover_tpu.ops.pallas import delta_rule as kernels
+
+D = kernels.HEAD
+
+
+def recurrence(q, k, v, g, beta):
+    """``S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t
+    k_t v_t^T``, ``o_t = S_t^T q_t / sqrt(d)``, a position at a time,
+    float32."""
+    b, s, h, d = q.shape
+
+    def step(state, x):  # [b, h, keys, values]
+        q_t, k_t, v_t, g_t, beta_t = x
+        state = state * jnp.exp(g_t)[..., None]
+        held = jnp.einsum("bhc,bhcv->bhv", k_t, state)
+        state = state + (
+            (beta_t[..., None] * k_t)[..., None]
+            * (v_t - held)[..., None, :]
+        )
+        return state, jnp.einsum("bhc,bhcv->bhv", q_t, state)
+
+    xs = tuple(
+        jnp.moveaxis(x.astype(jnp.float32), 1, 0)
+        for x in (q, k, v, g, beta)
+    )
+    _, o = jax.lax.scan(
+        step, jnp.zeros((b, h, d, v.shape[-1]), jnp.float32), xs)
+    return jnp.moveaxis(o, 0, 1) * d ** -0.5
+
+
+def operands(seed, batch, seq, heads, decay, beta=None, dtype=jnp.float32,
+             d=D):
+    """q and k of unit length, v normal, ``g`` uniform in ``-decay x
+    [0.2, 1]`` (or ``-decay`` at every position and channel, where
+    ``decay`` is a tuple of one), ``beta`` 2 sigmoid(normal) or
+    ``beta`` everywhere."""
+    keys = jax.random.split(jax.random.key(seed), 5)
+    shape = (batch, seq, heads, d)
+    q, k, v = (jax.random.normal(key, shape) for key in keys[:3])
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True)
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    if isinstance(decay, tuple):
+        g = jnp.full(shape, -decay[0], jnp.float32)
+    else:
+        g = -decay * jax.random.uniform(keys[3], shape, minval=0.2)
+    if beta is None:
+        step = 2 * jax.nn.sigmoid(jax.random.normal(keys[4], shape[:3]))
+    else:
+        step = jnp.full(shape[:3], beta, jnp.float32)
+    return (q.astype(dtype), k.astype(dtype), v.astype(dtype), g, step)
+
+
+PATHS = {
+    "plain": delta_rule.gated_delta_rule_plain,
+    "kernels": kernels.delta_rule_tpu,
+}
+NAMES = ("q", "k", "v", "g", "beta")
+
+
+def with_gradients(fn, args, cotangent):
+    return jax.jit(jax.value_and_grad(
+        lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * cotangent),
+        argnums=(0, 1, 2, 3, 4),
+    ))(*args)
+
+
+def relative(got, want):
+    scale = float(jnp.abs(want).max())
+    return float(jnp.abs(got.astype(jnp.float32) - want).max()) / scale
+
+
+#: (sequence, decay, beta): a chunk and a bit, many chunks, one chunk;
+#: a decay of 5 a step at every channel and position, and up to 5; a
+#: step size near 0 and near 2
+CASES = {
+    "many chunks": (320, 0.3, None),
+    "one chunk": (64, 0.3, None),
+    "g of -5 a step": (192, (5.0,), None),
+    "g down to -5": (192, 5.0, None),
+    "beta near 0": (128, 0.3, 0.02),
+    "beta near 2": (128, 0.3, 1.98),
+    "no decay": (128, (0.0,), None),
+}
+
+
+@pytest.mark.parametrize("case", list(CASES))
+@pytest.mark.parametrize("path", list(PATHS))
+def test_path_agrees_with_the_recurrence(path, case):
+    """float32: ``o`` and the five gradients to 1e-4 of each one's
+    largest entry, no NaN, no overflow."""
+    seq, decay, beta = CASES[case]
+    args = operands(3, 2, seq, 2, decay, beta)
+    want_o = recurrence(*args)
+    cotangent = jax.random.normal(jax.random.key(9), want_o.shape)
+    got_o = PATHS[path](*args)
+    assert bool(jnp.isfinite(got_o).all())
+    assert relative(got_o, want_o) < 1e-4
+    _, want = with_gradients(recurrence, args, cotangent)
+    _, got = with_gradients(PATHS[path], args, cotangent)
+    for name, a, b in zip(NAMES, got, want):
+        assert bool(jnp.isfinite(a).all()), name
+        assert relative(a, b) < 1e-4, (name, relative(a, b))
+
+
+@pytest.mark.parametrize("path", list(PATHS))
+def test_a_chunks_edge_is_no_edge(path):
+    """Positions 63 and 64 (a chunk's last, the next one's first) and
+    a sub-block's edge read as the recurrence does; what a later
+    position holds moves no earlier result, and its cotangent reaches
+    every earlier operand."""
+    args = operands(5, 1, 192, 1, 1.0)
+    want = recurrence(*args)
+    got = PATHS[path](*args)
+    for at in (15, 16, 63, 64, 127, 128):
+        assert relative(got[:, at], want[:, at]) < 1e-4, at
+    later = tuple(x.at[:, 100:].set(x[:, 100:] * 0.5) for x in args)
+    assert relative(PATHS[path](*later)[:, :100], got[:, :100]) < 1e-5
+    args = operands(5, 1, 192, 1, 0.01)  # what forgets little
+    only_last = jnp.zeros_like(want).at[:, -1].set(1.0)
+    _, grads = with_gradients(PATHS[path], args, only_last)
+    _, wanted = with_gradients(recurrence, args, only_last)
+    for name, a, b in zip(NAMES, grads, wanted):
+        # the first position's q meets only its own result
+        assert name == "q" or float(jnp.abs(a[:, 0]).max()) > 0, name
+        assert relative(a, b) < 1e-4, name
+
+
+@pytest.mark.parametrize("path", list(PATHS))
+def test_batch_rows_and_heads_are_independent(path):
+    args = operands(7, 3, 128, 2, 0.5)
+    whole = PATHS[path](*args)
+    for row in range(3):
+        alone = PATHS[path](*(x[row:row + 1] for x in args))
+        assert relative(alone, whole[row:row + 1]) < 1e-5
+    swapped = PATHS[path](*(x[:, :, ::-1] for x in args))
+    assert relative(swapped[:, :, ::-1], whole) < 1e-5
+
+
+def test_kernels_in_bfloat16_are_within_a_step_of_bfloat16():
+    """bfloat16 operands, the products' operands rounded to bfloat16
+    inside: ``o`` and the gradients within 2 ** -7 of each one's
+    largest entry (a bfloat16's last place at that size) of the
+    float32 recurrence on the same rounded operands."""
+    args = operands(11, 1, 256, 2, 1.0, dtype=jnp.bfloat16)
+    want_o = recurrence(*args)
+    cotangent = jax.random.normal(jax.random.key(9), want_o.shape)
+    got_o = kernels.delta_rule_tpu(*args)
+    assert got_o.dtype == jnp.bfloat16
+    assert relative(got_o, want_o) < 2 ** -7
+    _, want = with_gradients(recurrence, args, cotangent)
+    _, got = with_gradients(kernels.delta_rule_tpu, args, cotangent)
+    for name, a, b in zip(NAMES, got, want):
+        assert a.dtype == (jnp.bfloat16 if name in "qkv" else jnp.float32)
+        assert relative(a, b) < 2 ** -7, (name, relative(a, b))
+
+
+def test_plain_path_takes_any_length_and_width():
+    """A sequence that is no whole number of chunks and heads that are
+    no lane tile: padded positions leave the state as it is."""
+    args = operands(13, 2, 100, 3, 0.5, d=16)
+    want = recurrence(*args)
+    assert relative(delta_rule.gated_delta_rule_plain(*args), want) < 1e-4
+    assert relative(
+        delta_rule.gated_delta_rule_plain(*args, chunk=32), want) < 1e-4
+    # the entry's dispatch off the TPU is the plain path
+    assert relative(delta_rule.gated_delta_rule(*args), want) < 1e-4
+    assert not kernels.tiles_the_kernel(args[0].shape)
+    assert kernels.tiles_the_kernel((1, 8192, 64, 128))
+    assert not kernels.tiles_the_kernel((1, 8200, 64, 128))
+
+
+def _one_fast_step(args, at=(70, 75)):
+    """``args`` with a log decay of -100 at two positions of one
+    sub-block, in every channel: each step is far inside what an
+    ``exp`` holds, the block's range of 200 is past what ``CLIP``
+    holds."""
+    q, k, v, g, beta = args
+    return q, k, v, g.at[:, jnp.array(at)].set(-100.0), beta
+
+
+def _fast_channels(args, step=-12.0):
+    """``args`` with eight channels of every head forgetting by
+    ``step`` at every position: 192 over a block of sixteen."""
+    q, k, v, g, beta = args
+    return q, k, v, g.at[..., :8].set(step), beta
+
+
+PAST = {
+    "12 a step in eight channels": lambda: _fast_channels(
+        operands(17, 1, 128, 2, 0.3)),
+    "two fast steps in a block": lambda: _one_fast_step(
+        operands(19, 1, 128, 2, 0.3)),
+}
+
+
+@pytest.fixture
+def kernels_at_the_entry(monkeypatch):
+    """The dispatch a TPU process takes, the kernels in interpret
+    mode."""
+    monkeypatch.setattr(delta_rule, "_use_pallas", lambda q: True)
+
+
+@pytest.mark.parametrize("case", list(PAST))
+@pytest.mark.parametrize("path", list(PATHS))
+def test_the_entry_holds_a_fast_decay_to_its_floor(
+    path, case, kernels_at_the_entry, monkeypatch
+):
+    """A channel that forgets by more than 150 within a block of
+    ``SUB`` rows is past what the kernels' factored exponents hold
+    exactly (ops/pallas/delta_rule.py). The entry takes no step under
+    ``G_FLOOR``, on either path, and what that costs is under the
+    tests' own tolerance: ``o`` and the five gradients are the
+    recurrence's on the operands as they came, ``alpha`` of
+    ``exp(-100)`` and all."""
+    if path == "plain":
+        monkeypatch.setattr(delta_rule, "_use_pallas", lambda q: False)
+    args = PAST[case]()
+    assert float(args[3].min()) < delta_rule.G_FLOOR
+    want_o = recurrence(*args)
+    cotangent = jax.random.normal(jax.random.key(9), want_o.shape)
+    assert relative(delta_rule.gated_delta_rule(*args), want_o) < 1e-4
+    _, want = with_gradients(recurrence, args, cotangent)
+    _, got = with_gradients(delta_rule.gated_delta_rule, args, cotangent)
+    for name, a, b in zip(NAMES, got, want):
+        assert bool(jnp.isfinite(a).all()), name
+        assert relative(a, b) < 1e-4, (name, relative(a, b))
+    # under the floor ``g`` decides nothing, and is told so
+    assert float(jnp.abs(
+        jnp.where(args[3] < delta_rule.G_FLOOR, got[3], 0.0)).max()) == 0
+
+
+def test_what_the_kernels_alone_read_past_the_floor():
+    """Why the floor is there: ``delta_rule_tpu`` by itself on two
+    steps of -100 in one block is finite and wrong by more than any
+    rounding, where the same operands with one such step (a block's
+    range of 100, inside what ``CLIP`` holds) read as the recurrence;
+    at the floor itself, at every position and channel, it is exact."""
+    inside = _one_fast_step(operands(19, 1, 128, 2, 0.3), at=(70,))
+    assert relative(kernels.delta_rule(*inside), recurrence(*inside)) < 1e-4
+    past = _one_fast_step(operands(19, 1, 128, 2, 0.3))
+    got = kernels.delta_rule(*past)
+    assert bool(jnp.isfinite(got).all())
+    assert relative(got, recurrence(*past)) > 1e-2
+    floor = operands(23, 1, 128, 2, (-delta_rule.G_FLOOR,))
+    assert relative(kernels.delta_rule(*floor), recurrence(*floor)) < 1e-4
+    assert -delta_rule.G_FLOOR * (kernels.SUB - 1) / 2 <= kernels.CLIP
+
+
+def test_entry_refuses_mismatched_operands():
+    q, k, v, g, beta = operands(1, 1, 64, 2, 0.5)
+    with pytest.raises(ValueError):
+        delta_rule.gated_delta_rule(q, k, v, g[:, :32], beta)
+    with pytest.raises(ValueError):
+        delta_rule.gated_delta_rule(q, k, v, g, beta[..., None])
+
+
+def test_dispatch_says_what_it_built():
+    """The gauges, set where the kernels are built."""
+    from dlrover_tpu.telemetry.registry import gauge
+
+    for name in ("delta_rule_chunk", "delta_rule_state_bytes",
+                 "delta_rule_backward_kernels"):
+        gauge(name, "").set(0)
+    args = operands(1, 1, 64, 1, 0.5)
+    jax.jit(kernels.delta_rule_tpu).lower(*args)  # traced, not run
+    assert gauge("delta_rule_chunk", "").value == 64
+    assert gauge("delta_rule_state_bytes", "").value == 128 * 128 * 4
+    assert gauge("delta_rule_backward_kernels", "").value == 1

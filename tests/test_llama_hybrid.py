@@ -388,8 +388,7 @@ def test_flops_per_token_counts_each_kind_of_layer():
 
 @pytest.mark.parametrize("change,sentence", [
     (dict(layer_types=TYPES[:8]), "8 entries for 9 layers"),
-    (dict(layer_types=("linear_attention",) + TYPES[1:]),
-     "linear_attention"),
+    (dict(layer_types=("state_space",) + TYPES[1:]), "state_space"),
     (dict(layer_types=TYPES + ("conv",)), "10 entries for 9 layers"),
     (dict(num_dense_layers=9), "num_dense_layers 9"),
     (dict(num_dense_layers=1, num_experts=0), "num_dense_layers 1"),
