@@ -4,11 +4,15 @@ chunked path's values (dev tool).
 ``ops/delta_rule.py gated_delta_rule`` runs, on the TPU, the Pallas
 kernels of ``ops/pallas/delta_rule.py``; elsewhere the chunked
 equations under a ``lax.scan``. This script times the kernels at
-``solar-open2-250b-ep32.steady``'s shape (``[1, 8192, 64, 128]`` in
-bf16, the log decay in float32): the forward, the forward that keeps
-the chunks' entry states with the backward over them, with the least
-time the memory allows beside each (``yardstick/families/solar.py
-delta_rule_step``'s bytes at 819 GB/s); and compares ``o`` and the
+``solar-open2-250b-ep32.steady``'s shape (64 heads of 128 at 8,192
+positions in bf16, the log decay in float32): the forward, the
+forward that keeps the chunks' entry states with the backward over
+them, with the least time the memory allows beside each
+(``yardstick/families/solar.py delta_rule_step``'s bytes at 819
+GB/s). Each on rows ``[1, 8192, 8192]``, which is what the step
+calls (``gated_delta_rule_rows``), and beside it through the 4-D
+entry on ``[1, 8192, 64, 128]``, whose fold to rows is a pass over
+every operand and result on the chip; and compares ``o`` and the
 five gradients with the plain path's on ``--check-heads`` of the
 heads (the plain path holds ``[heads, 64, 64, 128]`` float32 a
 chunk), at the decays ``--decay`` lists (``g`` uniform in ``-decay x
@@ -21,6 +25,7 @@ On no cell's path. Only a TPU run says anything:
 """
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -32,7 +37,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from dlrover_tpu.ops.delta_rule import (  # noqa: E402
-    gated_delta_rule, gated_delta_rule_plain,
+    gated_delta_rule, gated_delta_rule_plain, gated_delta_rule_rows,
 )
 from dlrover_tpu.ops.pallas import delta_rule as kernels  # noqa: E402
 
@@ -102,13 +107,19 @@ def main(argv=None):
            "chunk": kernels.CHUNK, "sub": kernels.SUB,
            **{"least_" + k: round(v, 4) for k, v in least.items()}}
     if args.n:
-        row["forward_ms"] = 1e3 * timed(
-            jax.jit(kernels.delta_rule_tpu), *ops, n=args.n)
+        flat = (*(x.reshape(*x.shape[:2], -1) for x in ops[:4]), ops[4])
+        on_rows = functools.partial(gated_delta_rule_rows, heads=args.heads)
+        row["forward_ms"] = 1e3 * timed(jax.jit(on_rows), *flat, n=args.n)
         row["forward_keeping_states_ms"] = 1e3 * timed(jax.jit(
             lambda *a: kernels.delta_rule(*a, keep_states=True)),
-            *ops, n=args.n)
+            *flat, n=args.n)
         row["forward_and_gradients_ms"] = 1e3 * timed(
-            gradients_of(kernels.delta_rule_tpu), ops, do, n=args.n)
+            gradients_of(on_rows), flat, do.reshape(flat[2].shape),
+            n=args.n)
+        row["forward_from_heads_ms"] = 1e3 * timed(
+            jax.jit(gated_delta_rule), *ops, n=args.n)
+        row["forward_and_gradients_from_heads_ms"] = 1e3 * timed(
+            gradients_of(gated_delta_rule), ops, do, n=args.n)
         rows.append(row)
     for dtype in (jnp.float32, jnp.bfloat16):
         for decay in args.decay:

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from dlrover_tpu.ops.attention import flash_attention
-from dlrover_tpu.ops.delta_rule import gated_delta_rule
+from dlrover_tpu.ops.delta_rule import gated_delta_rule_rows
 from dlrover_tpu.ops.short_conv import causal_taps, gated_short_conv
 
 
@@ -864,15 +864,37 @@ def _l2norm(x):
     )
 
 
+#: rows of a float32 tile on the TPU: ``[s, columns]`` lies in tiles of
+#: (8, 128)
+_TILE_ROWS = 8
+
+
+def _heads_apart(x, heads):
+    """Rows ``x`` [b, s, heads x d] with a head's columns an axis of
+    their own, for a reduction over one head: ``[b, s / 8, 8, heads,
+    d]`` (``[b, s, 1, heads, d]`` where 8 does not divide ``s``). The
+    eight positions are there for the TPU's sake: a float32 ``[s,
+    heads x 128]`` lies in tiles of (8 rows, 128 columns), which this
+    shape names axis by axis, so the compiler takes the reshape for
+    the same bytes and the reduction and the multiply by its result
+    join the fusions on either side. ``[b, s, heads, d]`` tiles
+    (heads, d): other bytes, a pass over the array each way and the
+    factor written out at full width between them (PERF.md, PR 45)."""
+    b, s, width = x.shape
+    rows = _TILE_ROWS if s % _TILE_ROWS == 0 else 1
+    return x.reshape(b, s // rows, rows, heads, width // heads)
+
+
 def _delta_rule_operands(cfg: LlamaConfig, y, p, constrain=_free):
-    """The gated delta rule's operands from the normed stream ``y``:
-    ``(q, k, v [b, s, heads, d], g [b, s, heads, d] float32, beta [b,
-    s, heads] float32, the output gate's pre-activation [b, s, heads x
-    d])``. The scopes name every op: ``kda.proj`` the three
-    projections, the two low ranks and the step size's; ``kda.conv``
-    the convolutions with ``silu`` and the l2 norms; ``kda.decay`` the
-    log decay and the step size."""
-    b, s, _ = y.shape
+    """The gated delta rule's operands from the normed stream ``y``,
+    in rows, as the projections write them and the scan's kernels
+    read them: ``(q, k, v [b, s, heads x d], g [b, s, heads x d]
+    float32, beta [b, s, heads] float32, the output gate's
+    pre-activation [b, s, heads x d])``. A head shows only inside
+    ``_heads_apart``, for q's and k's l2 norm. The scopes name every
+    op: ``kda.proj`` the three projections, the two low ranks and the
+    step size's; ``kda.conv`` the convolutions with ``silu`` and the
+    l2 norms; ``kda.decay`` the log decay and the step size."""
     heads, d = cfg.linear_num_heads, cfg.linear_head_dim
     with jax.named_scope("kda.proj"):
         q, k, v = (constrain(y @ p[w], _MLP) for w in ("wq", "wk", "wv"))
@@ -881,15 +903,20 @@ def _delta_rule_operands(cfg: LlamaConfig, y, p, constrain=_free):
         step = y @ p["w_beta"]
     with jax.named_scope("kda.conv"):
         q, k, v = (
-            _causal_conv_silu(x, p[w]).reshape(b, s, heads, d)
+            _causal_conv_silu(x, p[w])
             for x, w in ((q, "conv_q"), (k, "conv_k"), (v, "conv_v"))
         )
-        q, k = _l2norm(q).astype(y.dtype), _l2norm(k).astype(y.dtype)
+        q, k = (
+            _l2norm(_heads_apart(x, heads)).reshape(x.shape).astype(y.dtype)
+            for x in (q, k)
+        )
         v = v.astype(y.dtype)
     with jax.named_scope("kda.decay"):
-        g = -jnp.exp(p["A_log"])[:, None] * jax.nn.softplus(
+        # a head's rate at each of its d columns: of the weights' size
+        rate = jnp.repeat(jnp.exp(p["A_log"]), d)
+        g = -rate * jax.nn.softplus(
             decay.astype(jnp.float32) + p["dt_bias"]
-        ).reshape(b, s, heads, d)
+        )
         beta = jax.nn.sigmoid(step.astype(jnp.float32))
         if cfg.linear_allow_neg_eigval:
             beta = 2.0 * beta
@@ -1017,13 +1044,17 @@ def _operator_out(x, out, layer_params, kind: LayerKind,
         with jax.named_scope("conv.out_proj"):
             return out @ p["conv_out"]
     if kind.operator == "linear_attention":
-        # an RMSNorm a head with one learned scale, a sigmoid gate
+        # an RMSNorm a head with one learned scale, a sigmoid gate:
+        # rows in, rows to ``wo``
         with jax.named_scope("kda.out"):
             o, gate = out
             gate = jax.nn.sigmoid(
                 gate.astype(jnp.float32) + p["g_bias"]
             ).astype(o.dtype)
-            o = rms_norm(o, p["o_norm"], norm_eps).reshape(b, s, -1)
+            heads = o.shape[-1] // p["o_norm"].shape[-1]
+            o = rms_norm(
+                _heads_apart(o, heads), p["o_norm"], norm_eps
+            ).reshape(o.shape)
             return (o * gate) @ p["wo"]
     if isinstance(out, tuple):  # full attention and its gate's logits
         with jax.named_scope("attn.gate"):
@@ -1103,7 +1134,9 @@ def _operator_of(cfg: LlamaConfig, attn_fn, kind: LayerKind):
 
         def scan(q, k, v, g, beta, gate):
             with jax.named_scope("kda.scan"):
-                return gated_delta_rule(q, k, v, g, beta), gate
+                return gated_delta_rule_rows(
+                    q, k, v, g, beta, cfg.linear_num_heads
+                ), gate
 
         return scan
     if kind.operator == "latent_attention":
@@ -1557,8 +1590,8 @@ def decay_min(params: Dict, tokens: jax.Array, cfg: LlamaConfig,
     """The least ``alpha = exp(g)`` that a channel of each scanned
     layer's gated delta rule takes on ``tokens`` [batch, seq], float32
     [layers]; 1 for a layer of another kind. A forward pass
-    (jit-able), before the scan's floor: ``gated_delta_rule`` takes
-    a step under ``exp(-10)`` as ``exp(-10)`` (ops/delta_rule.py
+    (jit-able), before the scan's floor: ``gated_delta_rule_rows``
+    takes a step under ``exp(-10)`` as ``exp(-10)`` (ops/delta_rule.py
     ``G_FLOOR``), and this is the number that says whether a run's
     channels get there."""
     def see(kind, x, p, operands, out, logits):

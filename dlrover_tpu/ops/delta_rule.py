@@ -22,6 +22,9 @@ cumulated from the chunk's start and ``w_t = beta_t (v_t - S_{t-1}^T
 so the sequential part is one unit lower-triangular system a chunk
 and a [d, d] state handed from chunk to chunk.
 
+The entry is ``gated_delta_rule_rows``, on ``[batch, seq, heads x
+d]``; ``gated_delta_rule`` folds ``[batch, seq, heads, d]`` to it.
+
 On the TPU, where the shapes tile, the Pallas kernels of
 ops/pallas/delta_rule.py (forward; backward over the chunks' entry
 states that the forward keeps when it is differentiated), which are
@@ -47,12 +50,12 @@ CHUNK = 64
 G_FLOOR = -10.0
 
 
-def _use_pallas(q: jax.Array) -> bool:
+def _use_pallas(q: jax.Array, heads: int) -> bool:
     if jax.default_backend() != "tpu":
         return False
     from dlrover_tpu.ops.pallas.delta_rule import tiles_the_kernel
 
-    return tiles_the_kernel(q.shape)
+    return tiles_the_kernel(q.shape, heads)
 
 
 def gated_delta_rule_plain(q, k, v, g, beta, chunk: int = CHUNK):
@@ -113,24 +116,57 @@ def gated_delta_rule_plain(q, k, v, g, beta, chunk: int = CHUNK):
     return (o[:, :s] * d ** -0.5).astype(v.dtype)
 
 
+def gated_delta_rule_rows(q, k, v, g, beta, heads: int, folded=False):
+    """The entry, on rows: ``q, k`` [batch, seq, heads x d], ``v``
+    [batch, seq, heads x d], ``g`` the same shape as ``k`` (the log
+    of the decay, float32, at most 0), ``beta`` [batch, seq, heads] to
+    ``o`` in ``v``'s shape and dtype; a head is ``d`` columns of a
+    row, side by side, as a projection writes them and the kernels
+    read them. Differentiable in all five. A sequence is a row of the
+    batch: the state starts at zero at its first position. A log
+    decay under ``G_FLOOR`` is taken as ``G_FLOOR``, on either path:
+    what a channel keeps of its state over such a step is then 4.5e-5
+    and not less, and ``g`` there gets no gradient. ``folded`` is
+    ``gated_delta_rule``'s to say, for the kernels' record."""
+    if not (q.ndim == 3 and q.shape == k.shape == g.shape
+            and v.shape[:2] == q.shape[:2]
+            and beta.shape == (*q.shape[:2], heads)
+            and q.shape[2] % heads == v.shape[2] % heads == 0):
+        raise ValueError(
+            f"gated_delta_rule: q {q.shape}, k {k.shape}, v {v.shape}, "
+            f"g {g.shape}, beta {beta.shape} in rows of {heads} heads"
+        )
+    g = jnp.maximum(g, G_FLOOR)
+    if _use_pallas(q, heads):
+        from dlrover_tpu.ops.pallas.delta_rule import delta_rule_tpu
+
+        return delta_rule_tpu(q, k, v, g, beta, folded)
+
+    def apart(x):
+        return x.reshape(*x.shape[:2], heads, -1)
+
+    return gated_delta_rule_plain(
+        apart(q), apart(k), apart(v), apart(g), beta
+    ).reshape(v.shape)
+
+
 def gated_delta_rule(q, k, v, g, beta):
-    """``q, k`` [batch, seq, heads, d], ``v`` [batch, seq, heads, d],
-    ``g`` the same shape as ``k`` (the log of the decay, float32, at
-    most 0), ``beta`` [batch, seq, heads] to ``o`` [batch, seq, heads,
-    d] in ``v``'s dtype. Differentiable in all five. A sequence is a
-    row of the batch: the state starts at zero at its first
-    position. A log decay under ``G_FLOOR`` is taken as ``G_FLOOR``,
-    on either path: what a channel keeps of its state over such a step
-    is then 4.5e-5 and not less, and ``g`` there gets no gradient."""
-    if not (q.shape == k.shape == g.shape and v.shape[:3] == q.shape[:3]
-            and beta.shape == q.shape[:3]):
+    """``gated_delta_rule_rows`` for a caller that holds heads: ``q,
+    k, g`` [batch, seq, heads, d], ``v`` [batch, seq, heads, d],
+    ``beta`` [batch, seq, heads] to ``o`` [batch, seq, heads, d] in
+    ``v``'s dtype. Folded to rows here and nowhere else: on the chip
+    that is a pass over each operand and over ``o``, which the model
+    does not pay (it holds rows)."""
+    if not (q.ndim == 4 and q.shape == k.shape == g.shape
+            and v.shape[:3] == q.shape[:3]):
         raise ValueError(
             f"gated_delta_rule: q {q.shape}, k {k.shape}, v {v.shape}, "
             f"g {g.shape}, beta {beta.shape}"
         )
-    g = jnp.maximum(g, G_FLOOR)
-    if _use_pallas(q):
-        from dlrover_tpu.ops.pallas.delta_rule import delta_rule_tpu
 
-        return delta_rule_tpu(q, k, v, g, beta)
-    return gated_delta_rule_plain(q, k, v, g, beta)
+    def rows(x):
+        return x.reshape(*x.shape[:2], -1)
+
+    return gated_delta_rule_rows(
+        rows(q), rows(k), rows(v), rows(g), beta, q.shape[2], folded=True
+    ).reshape(v.shape)

@@ -38,6 +38,15 @@ What ``CLIP`` does bind on inside the range is columns after a block's
 own, whose ``exp(rho - G_i)`` would overflow and whose products are
 masked, by a select, after they are made.
 
+Operands, residuals and results are rows, ``[batch, seq, heads x
+128]`` (``beta`` ``[batch, seq, heads]``): what a projection writes,
+and what a grid step's block, a head's lane tile of 64 rows, is cut
+from. On the chip ``[seq, heads, 128]`` is other bytes than ``[seq,
+heads x 128]`` (tiles of (heads, 128), not of (8 rows, 128)), so a
+caller that holds heads pays a pass over each operand to get here:
+ops/delta_rule.py's 4-D entry is the one place that does, and the
+record says so.
+
 Both calls are made inside one jitted function, ``delta_rule``: a
 device trace names a Pallas call after the innermost jitted function
 that holds it, and the benchmark's ``delta_rule_ms`` tells the
@@ -71,10 +80,10 @@ _NT = (((1,), (1,)), ((), ()))
 _TN = (((0,), (0,)), ((), ()))
 
 
-def tiles_the_kernel(shape) -> bool:
-    """Whether the kernels take ``[batch, seq, heads, d]``: a head one
-    lane tile wide, the sequence whole chunks."""
-    return shape[3] == HEAD and shape[1] % CHUNK == 0
+def tiles_the_kernel(shape, heads) -> bool:
+    """Whether the kernels take rows ``[batch, seq, heads x d]``: a
+    head one lane tile wide, the sequence whole chunks."""
+    return shape[2] == heads * HEAD and shape[1] % CHUNK == 0
 
 
 def _interpret() -> bool:
@@ -327,12 +336,6 @@ def _backward(q, k, v, g, beta, states, do, heads):
     )(q, k, v, g, beta, states, do)
 
 
-def _folded(x):
-    """``[batch, seq, heads, d]`` as ``[batch, seq, heads x d]``: a
-    head's columns are a lane tile of the row, and no copy is made."""
-    return x.reshape(*x.shape[:2], -1)
-
-
 def _beta_by_head(beta):
     """``[batch, seq, heads]`` as ``[batch, heads, seq, 1]``: a chunk's
     step sizes down the sublanes."""
@@ -341,31 +344,29 @@ def _beta_by_head(beta):
 
 @functools.partial(jax.jit, static_argnames=("keep_states",))
 def delta_rule(q, k, v, g, beta, states=None, do=None, keep_states=False):
-    """The forward kernel's ``o`` (with ``keep_states`` also the
-    chunks' entry states), or with the states and the result's
-    cotangent ``do`` the backward kernel's five gradients. One jitted
-    name for both, which is what a device trace calls them."""
-    shape, heads = q.shape, q.shape[2]
-    beta_in = _beta_by_head(beta.astype(F32))
-    wide = [_folded(x) for x in (q, k, v, g.astype(F32))]
+    """On rows ``[batch, seq, heads x 128]`` and ``beta`` ``[batch,
+    seq, heads]``: the forward kernel's ``o`` (with ``keep_states``
+    also the chunks' entry states), or with the states and the
+    result's cotangent ``do`` the backward kernel's five gradients,
+    each in its operand's shape. One jitted name for both, which is
+    what a device trace calls them."""
+    heads = q.shape[2] // HEAD
+    wide = (q, k, v, g.astype(F32), _beta_by_head(beta.astype(F32)))
     if do is None:
-        out = _forward(*wide, beta_in, heads, keep_states)
-        if keep_states:
-            return out[0].reshape(v.shape), out[1]
-        return out.reshape(v.shape)
-    dq, dk, dv, dg, dbeta = _backward(
-        *wide, beta_in, states, _folded(do), heads)
+        out = _forward(*wide, heads, keep_states)
+        return tuple(out) if keep_states else out
+    dq, dk, dv, dg, dbeta = _backward(*wide, states, do, heads)
     return (
-        dq.reshape(shape), dk.reshape(shape), dv.reshape(v.shape),
-        dg.reshape(shape).astype(g.dtype),
+        dq, dk, dv, dg.astype(g.dtype),
         jnp.swapaxes(dbeta[..., 0], 1, 2).astype(beta.dtype),
     )
 
 
-def _record():
-    """Say what was built, at trace time: the gauges of
-    docs/TELEMETRY.md."""
-    from dlrover_tpu.telemetry.registry import gauge
+def _record(folded):
+    """Say what was built, at trace time: the gauges and counters of
+    docs/TELEMETRY.md. Every Pallas call a step holds is counted once,
+    by whether its caller held rows or heads that were ``folded``."""
+    from dlrover_tpu.telemetry.registry import counter, gauge
 
     gauge(
         "delta_rule_chunk",
@@ -381,21 +382,38 @@ def _record():
         "Pallas kernels of the gated delta rule's backward pass, beside "
         "the forward that keeps the chunks' entry states",
     ).set(BACKWARD_KERNELS)
+    if folded:
+        counter(
+            "delta_rule_folded_calls",
+            "Pallas calls of the gated delta rule traced on operands "
+            "that came as [batch, seq, heads, d] and were folded to "
+            "rows: a relayout of each on the chip",
+        ).inc()
+    else:
+        counter(
+            "delta_rule_rows_calls",
+            "Pallas calls of the gated delta rule traced on operands "
+            "that came as rows [batch, seq, heads x d], as the kernels "
+            "read them",
+        ).inc()
 
 
-@jax.custom_vjp
-def delta_rule_tpu(q, k, v, g, beta):
-    _record()
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def delta_rule_tpu(q, k, v, g, beta, folded=False):
+    """``delta_rule`` with its differentiation rule; ``folded`` says,
+    for the record alone, that the caller held heads."""
+    _record(folded)
     return delta_rule(q, k, v, g, beta)
 
 
-def _vjp_fwd(q, k, v, g, beta):
-    _record()
+def _vjp_fwd(q, k, v, g, beta, folded):
+    _record(folded)
     o, states = delta_rule(q, k, v, g, beta, keep_states=True)
     return o, (q, k, v, g, beta, states)
 
 
-def _vjp_bwd(saved, do):
+def _vjp_bwd(folded, saved, do):
+    _record(folded)
     *operands, states = saved
     return delta_rule(*operands, states=states, do=do)
 

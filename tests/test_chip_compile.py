@@ -1066,9 +1066,37 @@ def test_joyai_step_holds_no_whole_q_or_k(topo, on_tpu_path, monkeypatch):
 
 #: ``peak_memory_in_bytes`` of ``solar-open2-250b-ep32.steady``'s step
 #: as this file compiles it (1 x 8,192, four layers, remat ``minimal``,
-#: the least effort; PERF.md, PR 44): 8.5 GB of it the state. At the
-#: default effort it plans 15,769,854,464
-SOLAR_STEP_BYTES = 16_240_236_032
+#: the least effort; PERF.md, PR 45): 8.5 GB of it the state. With the
+#: delta-rule layers' heads an axis of their own between the
+#: projections and the kernels (PR 44) it read 16,240,236,032: the
+#: norms' factors at full width and the relayouts' copies
+SOLAR_STEP_BYTES = 15_315_505_664
+#: what a delta-rule layer's q, k, v, g or o is as rows, as heads, and
+#: as the tiles of rows that the compiler names ``[s / 8, 8, heads, d]``
+SOLAR_ROWS = re.compile(
+    r"\[(?:1,8192,8192|8192,8192|1,8192,64,128|8192,64,128"
+    r"|1024,8,64,128)\]")
+
+
+def _outside_fusions(text, ops):
+    """``(op, result, operands' results, op_name)`` of every
+    instruction of one of ``ops`` that is a device op of its own: in
+    no fusion's computation."""
+    comps, called_from, _, _ = _parse_hlo(text)
+    fused = {
+        callee for callee, (comp, site) in called_from.items()
+        if comps[comp][site][1] == "fusion"
+    }
+    for comp, instructions in comps.items():
+        if comp in fused:
+            continue
+        for result, op, operands, attrs in instructions.values():
+            if op in ops:
+                name = re.search(r'op_name="([^"]*)"', attrs)
+                yield (op, result, [
+                    instructions[o][0] for o in operands
+                    if o in instructions
+                ], name.group(1) if name else "")
 
 
 def test_solar_step_holds_the_delta_rules_kernels(
@@ -1082,9 +1110,16 @@ def test_solar_step_holds_the_delta_rules_kernels(
     neither the attention's, the experts' nor the convolution's
     readers do (a compiled step's instruction names are a device
     trace's), and carry ``kda.scan``; the 4096 x 1280 experts' products
-    and in-place float32 sums take the tiles the rule gives them; and
-    every fusion on a ``[1, 8192, 8192]`` array says whose op it is."""
+    and in-place float32 sums take the tiles the rule gives them;
+    every fusion on a ``[1, 8192, 8192]`` array says whose op it is;
+    and a delta-rule layer stays in rows from its projections to
+    ``W_o``: no reshape, copy, transpose or broadcast of a ``[1, 8192,
+    8192]`` array, in any of its shapes, is a device op of its own
+    under a ``kda.`` scope, no float32 one under no scope (the copies
+    a trace shows without an ``op_name``), and the nine calls were
+    handed rows."""
     from dlrover_tpu.ops import delta_rule, grouped_matmul as gm
+    from dlrover_tpu.telemetry.registry import counter
     from dlrover_tpu.ops.pallas import delta_rule as scan_kernels
     from yardstick import cells, worker
     from yardstick.layer_metrics import (
@@ -1094,8 +1129,11 @@ def test_solar_step_holds_the_delta_rules_kernels(
     monkeypatch.setattr(gm, "_use_pallas", lambda lhs, rhs: True)
     monkeypatch.setattr(gm, "_interpret", lambda: False)
     monkeypatch.setattr(gm, "_add_on_mxu", lambda out, rows: True)
-    monkeypatch.setattr(delta_rule, "_use_pallas", lambda q: True)
+    monkeypatch.setattr(delta_rule, "_use_pallas", lambda q, heads: True)
     monkeypatch.setattr(scan_kernels, "_interpret", lambda: False)
+    calls = [counter(f"delta_rule_{handed}_calls", "")
+             for handed in ("rows", "folded")]
+    before = [c.value for c in calls]
     _, config, traffic = cells.load_cell("solar-open2-250b-ep32.steady")
     cfg = worker.program_config(config, traffic)
     assert (cfg.remat, cfg.loss_chunk) == ("minimal", 0)
@@ -1149,6 +1187,15 @@ def test_solar_step_holds_the_delta_rules_kernels(
         and "kda." not in line and "attn." not in line
     ]
     assert not unscoped
+    assert [c.value - was for c, was in zip(calls, before)] == [9, 0]
+    moved = [
+        (op, result[:40], name[-60:])
+        for op, result, operands, name in _outside_fusions(
+            text, ("reshape", "copy", "transpose", "broadcast"))
+        if any(SOLAR_ROWS.search(r) for r in [result] + operands)
+        and ("kda." in name or (not name and result.startswith("f32")))
+    ]
+    assert not moved, moved
     assert tuning.last_selection()["gqa_group"] == 8
 
 

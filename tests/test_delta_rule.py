@@ -1,6 +1,7 @@
 """The gated delta rule (ops/delta_rule.py): the plain chunked path
 and the Pallas kernels in interpret mode against the recurrence walked
-token by token, ``o`` and all five gradients."""
+token by token, ``o`` and all five gradients; the entry on rows
+against the entry on heads."""
 
 import jax
 import jax.numpy as jnp
@@ -59,9 +60,23 @@ def operands(seed, batch, seq, heads, decay, beta=None, dtype=jnp.float32,
     return (q.astype(dtype), k.astype(dtype), v.astype(dtype), g, step)
 
 
+def rows(x):
+    """``[batch, seq, heads, d]`` as the rows the kernels take."""
+    return x.reshape(*x.shape[:2], -1)
+
+
+def on_heads(fn):
+    """``fn`` of rows, as a function of ``[batch, seq, heads, d]``
+    operands, which is what ``recurrence`` and the plain path take."""
+    def folded(q, k, v, g, beta):
+        return fn(rows(q), rows(k), rows(v), rows(g), beta).reshape(v.shape)
+
+    return folded
+
+
 PATHS = {
     "plain": delta_rule.gated_delta_rule_plain,
-    "kernels": kernels.delta_rule_tpu,
+    "kernels": on_heads(kernels.delta_rule_tpu),
 }
 NAMES = ("q", "k", "v", "g", "beta")
 
@@ -153,11 +168,11 @@ def test_kernels_in_bfloat16_are_within_a_step_of_bfloat16():
     args = operands(11, 1, 256, 2, 1.0, dtype=jnp.bfloat16)
     want_o = recurrence(*args)
     cotangent = jax.random.normal(jax.random.key(9), want_o.shape)
-    got_o = kernels.delta_rule_tpu(*args)
+    got_o = PATHS["kernels"](*args)
     assert got_o.dtype == jnp.bfloat16
     assert relative(got_o, want_o) < 2 ** -7
     _, want = with_gradients(recurrence, args, cotangent)
-    _, got = with_gradients(kernels.delta_rule_tpu, args, cotangent)
+    _, got = with_gradients(PATHS["kernels"], args, cotangent)
     for name, a, b in zip(NAMES, got, want):
         assert a.dtype == (jnp.bfloat16 if name in "qkv" else jnp.float32)
         assert relative(a, b) < 2 ** -7, (name, relative(a, b))
@@ -173,9 +188,10 @@ def test_plain_path_takes_any_length_and_width():
         delta_rule.gated_delta_rule_plain(*args, chunk=32), want) < 1e-4
     # the entry's dispatch off the TPU is the plain path
     assert relative(delta_rule.gated_delta_rule(*args), want) < 1e-4
-    assert not kernels.tiles_the_kernel(args[0].shape)
-    assert kernels.tiles_the_kernel((1, 8192, 64, 128))
-    assert not kernels.tiles_the_kernel((1, 8200, 64, 128))
+    assert not kernels.tiles_the_kernel(rows(args[0]).shape, 3)
+    assert kernels.tiles_the_kernel((1, 8192, 8192), 64)
+    assert not kernels.tiles_the_kernel((1, 8192, 8192), 32)
+    assert not kernels.tiles_the_kernel((1, 8200, 8192), 64)
 
 
 def _one_fast_step(args, at=(70, 75)):
@@ -206,7 +222,7 @@ PAST = {
 def kernels_at_the_entry(monkeypatch):
     """The dispatch a TPU process takes, the kernels in interpret
     mode."""
-    monkeypatch.setattr(delta_rule, "_use_pallas", lambda q: True)
+    monkeypatch.setattr(delta_rule, "_use_pallas", lambda q, heads: True)
 
 
 @pytest.mark.parametrize("case", list(PAST))
@@ -222,7 +238,8 @@ def test_the_entry_holds_a_fast_decay_to_its_floor(
     recurrence's on the operands as they came, ``alpha`` of
     ``exp(-100)`` and all."""
     if path == "plain":
-        monkeypatch.setattr(delta_rule, "_use_pallas", lambda q: False)
+        monkeypatch.setattr(
+            delta_rule, "_use_pallas", lambda q, heads: False)
     args = PAST[case]()
     assert float(args[3].min()) < delta_rule.G_FLOOR
     want_o = recurrence(*args)
@@ -244,15 +261,52 @@ def test_what_the_kernels_alone_read_past_the_floor():
     rounding, where the same operands with one such step (a block's
     range of 100, inside what ``CLIP`` holds) read as the recurrence;
     at the floor itself, at every position and channel, it is exact."""
+    alone = on_heads(kernels.delta_rule)
     inside = _one_fast_step(operands(19, 1, 128, 2, 0.3), at=(70,))
-    assert relative(kernels.delta_rule(*inside), recurrence(*inside)) < 1e-4
+    assert relative(alone(*inside), recurrence(*inside)) < 1e-4
     past = _one_fast_step(operands(19, 1, 128, 2, 0.3))
-    got = kernels.delta_rule(*past)
+    got = alone(*past)
     assert bool(jnp.isfinite(got).all())
     assert relative(got, recurrence(*past)) > 1e-2
     floor = operands(23, 1, 128, 2, (-delta_rule.G_FLOOR,))
-    assert relative(kernels.delta_rule(*floor), recurrence(*floor)) < 1e-4
+    assert relative(alone(*floor), recurrence(*floor)) < 1e-4
     assert -delta_rule.G_FLOOR * (kernels.SUB - 1) / 2 <= kernels.CLIP
+
+
+#: (dtype, what ``o`` and the gradients may differ by, of each one's
+#: largest entry): the two entries run the same program on the same
+#: numbers, so float32 is held to its rounding and bfloat16 to the
+#: limit the kernels have against the recurrence
+ENTRY_LIMITS = {"float32": 1e-5, "bfloat16": 2 ** -7}
+
+
+@pytest.mark.parametrize("dtype", list(ENTRY_LIMITS))
+@pytest.mark.parametrize("path", list(PATHS))
+def test_the_rows_entry_is_the_heads_entry(path, dtype, monkeypatch):
+    """``gated_delta_rule_rows`` on ``[batch, seq, heads x d]`` against
+    ``gated_delta_rule`` on the same numbers as ``[batch, seq, heads,
+    d]``: ``o`` and the five gradients, each in its operand's shape,
+    on the plain path and on the kernels."""
+    monkeypatch.setattr(
+        delta_rule, "_use_pallas", lambda q, heads: path == "kernels")
+    args = operands(29, 2, 128, 3, 1.0, dtype=jnp.dtype(dtype),
+                    d=D if path == "kernels" else 16)
+    flat = (*(rows(x) for x in args[:4]), args[4])
+    want_o = delta_rule.gated_delta_rule(*args)
+    got_o = delta_rule.gated_delta_rule_rows(*flat, heads=3)
+    assert got_o.shape == flat[2].shape and got_o.dtype == args[2].dtype
+    limit = ENTRY_LIMITS[dtype]
+    assert relative(got_o.reshape(want_o.shape),
+                    want_o.astype(jnp.float32)) < limit
+    cotangent = jax.random.normal(jax.random.key(9), want_o.shape)
+    _, want = with_gradients(delta_rule.gated_delta_rule, args, cotangent)
+    _, got = with_gradients(
+        lambda *a: delta_rule.gated_delta_rule_rows(*a, heads=3), flat,
+        rows(cotangent))
+    for name, a, b, operand in zip(NAMES, got, want, flat):
+        assert a.shape == operand.shape and a.dtype == b.dtype, name
+        assert relative(a.reshape(b.shape), b.astype(jnp.float32)) < limit, (
+            name, relative(a.reshape(b.shape), b.astype(jnp.float32)))
 
 
 def test_entry_refuses_mismatched_operands():
@@ -261,17 +315,61 @@ def test_entry_refuses_mismatched_operands():
         delta_rule.gated_delta_rule(q, k, v, g[:, :32], beta)
     with pytest.raises(ValueError):
         delta_rule.gated_delta_rule(q, k, v, g, beta[..., None])
+    # heads that fold to rows of one width are still other heads
+    with pytest.raises(ValueError):
+        delta_rule.gated_delta_rule(
+            q, k.reshape(1, 64, 4, D // 2), v, g, beta)
 
 
-def test_dispatch_says_what_it_built():
-    """The gauges, set where the kernels are built."""
+@pytest.mark.parametrize("wrong", ["heads", "beta", "g", "4-D"])
+def test_rows_entry_refuses_mismatched_operands(wrong):
+    q, k, v, g, beta = operands(1, 1, 64, 2, 0.5)
+    flat = [rows(q), rows(k), rows(v), rows(g), beta]
+    heads = 2
+    if wrong == "heads":
+        heads = 3  # beta has two, and 3 does not divide a row
+    elif wrong == "beta":
+        flat[4] = beta[..., None]
+    elif wrong == "g":
+        flat[3] = flat[3][:, :32]
+    else:
+        flat[0] = q
+    with pytest.raises(ValueError):
+        delta_rule.gated_delta_rule_rows(*flat, heads=heads)
+
+
+def _calls():
+    from dlrover_tpu.telemetry.registry import counter
+
+    return (counter("delta_rule_rows_calls", "").value,
+            counter("delta_rule_folded_calls", "").value)
+
+
+def test_dispatch_says_what_it_built(kernels_at_the_entry):
+    """The gauges, set where the kernels are built, and the counters
+    of the calls built on rows and on heads that were folded: one for
+    a forward, two more for its gradients (the forward that keeps the
+    entry states, the backward)."""
     from dlrover_tpu.telemetry.registry import gauge
 
     for name in ("delta_rule_chunk", "delta_rule_state_bytes",
                  "delta_rule_backward_kernels"):
         gauge(name, "").set(0)
     args = operands(1, 1, 64, 1, 0.5)
-    jax.jit(kernels.delta_rule_tpu).lower(*args)  # traced, not run
+    flat = (*(rows(x) for x in args[:4]), args[4])
+    before = _calls()
+    jax.jit(kernels.delta_rule_tpu).lower(*flat)  # traced, not run
     assert gauge("delta_rule_chunk", "").value == 64
     assert gauge("delta_rule_state_bytes", "").value == 128 * 128 * 4
     assert gauge("delta_rule_backward_kernels", "").value == 1
+    assert _calls() == (before[0] + 1, before[1])
+    jax.jit(lambda *a: delta_rule.gated_delta_rule_rows(
+        *a, heads=1)).lower(*flat)
+    assert _calls() == (before[0] + 2, before[1])
+    # the 4-D entry folds, and says so
+    jax.jit(delta_rule.gated_delta_rule).lower(*args)
+    assert _calls() == (before[0] + 2, before[1] + 1)
+    jax.jit(jax.grad(
+        lambda *a: delta_rule.gated_delta_rule(*a).sum(), argnums=(0, 3)
+    )).lower(*args)
+    assert _calls() == (before[0] + 2, before[1] + 3)

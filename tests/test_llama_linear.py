@@ -300,12 +300,91 @@ def test_the_model_runs_the_kernels_where_they_tile(monkeypatch):
     want, want_g = step(params, batch, cfg)
     calls = []
     monkeypatch.setattr(
-        delta_rule, "_use_pallas", lambda q: calls.append(q.shape) or True)
+        delta_rule, "_use_pallas",
+        lambda q, heads: calls.append((q.shape, heads)) or True)
     got, got_g = step(params, batch, cfg)
-    assert calls and set(calls) == {(1, 128, 2, 128)}
+    # rows, as the projections wrote them
+    assert calls and set(calls) == {((1, 128, 256), 2)}
     assert abs(float(got) - float(want)) < 1e-5
     for a, b in zip(jax.tree.leaves(got_g), jax.tree.leaves(want_g)):
         np.testing.assert_allclose(a, b, rtol=3e-4, atol=3e-6)
+
+
+def _calls():
+    from dlrover_tpu.telemetry.registry import counter
+
+    return (counter("delta_rule_rows_calls", "").value,
+            counter("delta_rule_folded_calls", "").value)
+
+
+def test_the_steps_scans_are_handed_rows(monkeypatch):
+    """The cell's step in small (one period: a full-attention layer
+    and three delta-rule layers, heads of 128, the kernels where a
+    TPU process takes them): under ``minimal`` each delta-rule layer
+    builds the forward, the forward again that keeps the entry states
+    and the backward, nine Pallas calls, every one on rows and none on
+    heads that were folded; and no value of the step's program, as
+    traced, has a head of the delta rule as an axis of its own outside
+    the norms' view ``[b, s / 8, 8, heads, d]``."""
+    cfg = _linear(num_layers=4, layer_types=PERIOD, rope_layout=(0,) * 4,
+                  linear_num_heads=2, linear_head_dim=128, max_seq_len=128,
+                  remat="minimal")
+    monkeypatch.setattr(delta_rule, "_use_pallas", lambda q, heads: True)
+    before = _calls()
+    text = str(jax.make_jaxpr(
+        jax.grad(llama.next_token_loss), static_argnums=2
+    )(_init(cfg), _batch(cfg, shape=(1, 128)), cfg))
+    assert _calls() == (before[0] + 9, before[1])
+    assert "[1,16,8,2,128]" in text
+    assert "[1,128,2,128]" not in text
+
+
+@pytest.mark.parametrize("seq", [32, 30], ids=["tiles", "no tiles"])
+@pytest.mark.parametrize("norm", ["l2", "rms"])
+def test_a_heads_norm_in_rows_is_the_norm_on_heads(norm, seq):
+    """``_heads_apart``'s view under ``_l2norm`` and ``rms_norm``
+    against the same on ``[b, s, heads, d]``, the result and the
+    gradients to 1e-6; a sequence that 8 does not divide is viewed
+    position by position."""
+    heads, d = 4, 16
+    x = jax.random.normal(jax.random.key(3), (2, seq, heads * d))
+    scale = jax.random.uniform(jax.random.key(4), (d,), minval=0.5,
+                               maxval=1.5)
+    cotangent = jax.random.normal(jax.random.key(5), x.shape)
+    assert llama._heads_apart(x, heads).shape == (
+        (2, 4, 8, heads, d) if seq == 32 else (2, 30, 1, heads, d))
+
+    def normed(view):
+        def fn(x, scale):
+            y = view(x)
+            y = (llama._l2norm(y) if norm == "l2"
+                 else llama.rms_norm(y, scale, 1e-5))
+            return jnp.sum(y.reshape(x.shape) * cotangent)
+
+        return jax.value_and_grad(fn, argnums=(0, 1))(x, scale)
+
+    want, want_g = normed(lambda x: x.reshape(2, seq, heads, d))
+    got, got_g = normed(lambda x: llama._heads_apart(x, heads))
+    assert abs(float(got) - float(want)) < 1e-6 * seq
+    for a, b in zip(got_g, want_g):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
+
+
+def test_the_operators_operands_are_rows():
+    cfg = _linear()
+    p = jax.tree.map(lambda a: a[1], _init(cfg)["period"][2])
+    y = jax.random.normal(jax.random.key(3), (2, 32, 64))
+    kind = cfg.layer_plan()[1][2]
+    (q, k, v, g, beta, gate), _ = llama._pre_attn(
+        cfg, y, dict(p, attn_norm=jnp.ones(64)), None, None, kind=kind)
+    for x in (q, k, v, g, gate):
+        assert x.shape == (2, 32, 4 * 16)
+    assert beta.shape == (2, 32, 4) and g.dtype == jnp.float32
+    # a head's 16 columns of q and of k are of unit length
+    np.testing.assert_allclose(
+        jnp.sum(q.reshape(2, 32, 4, 16) ** 2, -1), 1.0, atol=1e-4)
+    o, passed = llama._operator_of(cfg, None, kind)(q, k, v, g, beta, gate)
+    assert o.shape == (2, 32, 64) and passed is gate
 
 
 def test_every_new_op_carries_its_scope():
