@@ -20,6 +20,15 @@ chunk), at the decays ``--decay`` lists (``g`` uniform in ``-decay x
 ``G_FLOOR`` (-10): a decay of 20 is compared through the entry, which
 is what the kernels' exactness rests on there.
 
+``--heads-per-step 1 2 4 8`` times the three kernels alone at each of
+those numbers of heads a grid step, whatever the kernels' own rule
+(``heads_a_step``) takes for the shape, prints the microseconds a
+chunk of one head costs beside the milliseconds a call, and holds each
+one's ``o`` and gradients to the first's bit for bit. ``--floors``
+adds two readings at each: a grid step whose body is empty (the
+blocks' DMAs and the step's own cost) and one whose ``_inverse``
+makes no product (the body without its longest chain).
+
 On no cell's path. Only a TPU run says anything:
 ``chiprun -- python3 benchmarks/profile_delta_rule.py``.
 """
@@ -43,6 +52,7 @@ from dlrover_tpu.ops.pallas import delta_rule as kernels  # noqa: E402
 
 HBM_BYTES_PER_S = 819e9  # yardstick/peaks.json, "TPU v5 lite"
 NAMES = ("q", "k", "v", "g", "beta")
+RULE = kernels.HEADS_A_STEP
 
 
 def timed(fn, *args, n=10):
@@ -68,6 +78,72 @@ def operands(batch, seq, heads, decay, dtype, seed=0):
     return (q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta), do
 
 
+def at_heads_a_step(together):
+    """Have the kernels built from here on take ``together`` heads a
+    grid step (``None``: what their rule takes), whatever was traced
+    before."""
+    kernels.HEADS_A_STEP = RULE if together is None else (together,)
+    jax.clear_caches()
+
+
+def _empty_forward(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
+                   **_):
+    o_ref[...] = v_ref[...]
+
+
+def _empty_backward(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref,
+                    do_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
+                    dstate, **_):
+    dq_ref[...] = q_ref[...]
+    dk_ref[...] = k_ref[...]
+    dv_ref[...] = do_ref[...]
+    dg_ref[...] = g_ref[...]
+    dbeta_ref[...] = beta_ref[...]
+
+
+def _no_inverse(n):
+    return n
+    yield
+
+
+#: what a grid step costs with less in it: the blocks' DMAs and the
+#: step's own cost alone, and the body without the inverse's chain of
+#: ten float32 products (the results are then no delta rule's)
+FLOORS = {
+    "empty_body": dict(_fwd_kernel=_empty_forward,
+                       _bwd_kernel=_empty_backward),
+    "no_inverse": dict(_inverse=_no_inverse),
+}
+
+
+def kernel_times(flat, do, n):
+    """Milliseconds a call of the forward kernel, of the forward that
+    keeps the chunks' entry states and of the backward kernel over
+    them, as built now."""
+    keep = jax.jit(functools.partial(kernels.delta_rule, keep_states=True))
+    _, states = keep(*flat)
+    return {
+        "forward_ms": 1e3 * timed(jax.jit(kernels.delta_rule), *flat, n=n),
+        "forward_keeping_states_ms": 1e3 * timed(keep, *flat, n=n),
+        "backward_ms": 1e3 * timed(
+            jax.jit(lambda *a: kernels.delta_rule(
+                *a[:5], states=a[5], do=a[6])), *flat, states, do, n=n),
+    }
+
+
+def a_heads_chunk(times, shape):
+    """``times`` with, beside each call's milliseconds, the
+    microseconds it takes a chunk of one head."""
+    batch, seq, width = shape
+    chunks = batch * (width // kernels.HEAD) * (seq // kernels.CHUNK)
+    out = {}
+    for name, ms in times.items():
+        out[name] = round(ms, 4)
+        out[name.replace("_ms", "_us_a_heads_chunk")] = round(
+            1e3 * ms / chunks, 4)
+    return out
+
+
 def gradients_of(fn):
     def gradients(args, do):
         _, back = jax.vjp(fn, *args)
@@ -87,6 +163,15 @@ def main(argv=None):
                     default=[0.3, 5.0, 20.0])
     ap.add_argument("--n", type=int, default=10,
                     help="calls timed; 0 skips the timing")
+    ap.add_argument("--heads-per-step", type=int, nargs="+", default=[],
+                    help="time the kernels alone at each of these heads "
+                         "a grid step (each divides --heads), whatever "
+                         "their rule takes, and hold each one's results "
+                         "to the first's, bit for bit")
+    ap.add_argument("--floors", action="store_true",
+                    help="at each of --heads-per-step also time a grid "
+                         "step with an empty body and one whose "
+                         "_inverse makes no product")
     ap.add_argument("--out", default="chiprun_out/delta_rule.jsonl")
     args = ap.parse_args(argv)
     if jax.default_backend() != "tpu":
@@ -103,24 +188,53 @@ def main(argv=None):
     }
     rows = []
     ops, do = operands(args.batch, args.seq, args.heads, 0.3, jnp.bfloat16)
+    flat = (*(x.reshape(*x.shape[:2], -1) for x in ops[:4]), ops[4])
+    flat_do = do.reshape(flat[2].shape)
+    first = None
+    for together in args.heads_per_step if args.n else []:
+        at_heads_a_step(together)
+        row = {"what": "kernels alone", "shape": list(flat[0].shape),
+               "heads_a_step": kernels.heads_a_step(args.heads),
+               **a_heads_chunk(kernel_times(flat, flat_do, args.n),
+                               flat[0].shape)}
+        got = (kernels.delta_rule(*flat),
+               *gradients_of(kernels.delta_rule_tpu)(flat, flat_do))
+        first = first or got
+        row["same_bits_as_first"] = all(
+            bool((a == b).all()) for a, b in zip(got, first))
+        rows.append(row)
+        print(json.dumps(row), flush=True)
+        for name, stubs in FLOORS.items() if args.floors else ():
+            real = {k: getattr(kernels, k) for k in stubs}
+            for k, stub in stubs.items():
+                setattr(kernels, k, stub)
+            at_heads_a_step(together)
+            rows.append({
+                "what": name, "heads_a_step": together,
+                **a_heads_chunk(kernel_times(flat, flat_do, args.n),
+                                flat[0].shape)})
+            print(json.dumps(rows[-1]), flush=True)
+            for k, fn in real.items():
+                setattr(kernels, k, fn)
+    at_heads_a_step(None)
     row = {"what": "kernels timed", "shape": list(ops[0].shape),
            "chunk": kernels.CHUNK, "sub": kernels.SUB,
+           "heads_a_step": kernels.heads_a_step(args.heads),
            **{"least_" + k: round(v, 4) for k, v in least.items()}}
     if args.n:
-        flat = (*(x.reshape(*x.shape[:2], -1) for x in ops[:4]), ops[4])
         on_rows = functools.partial(gated_delta_rule_rows, heads=args.heads)
         row["forward_ms"] = 1e3 * timed(jax.jit(on_rows), *flat, n=args.n)
         row["forward_keeping_states_ms"] = 1e3 * timed(jax.jit(
             lambda *a: kernels.delta_rule(*a, keep_states=True)),
             *flat, n=args.n)
         row["forward_and_gradients_ms"] = 1e3 * timed(
-            gradients_of(on_rows), flat, do.reshape(flat[2].shape),
-            n=args.n)
+            gradients_of(on_rows), flat, flat_do, n=args.n)
         row["forward_from_heads_ms"] = 1e3 * timed(
             jax.jit(gated_delta_rule), *ops, n=args.n)
         row["forward_and_gradients_from_heads_ms"] = 1e3 * timed(
             gradients_of(gated_delta_rule), ops, do, n=args.n)
         rows.append(row)
+        print(json.dumps(row), flush=True)
     for dtype in (jnp.float32, jnp.bfloat16):
         for decay in args.decay:
             ops, do = operands(
@@ -153,9 +267,9 @@ def main(argv=None):
                 bool(jnp.isfinite(x.astype(jnp.float32)).all())
                 for x in (got_o, *got))
             rows.append(row)
+            print(json.dumps(row), flush=True)
     with open(args.out, "a") as f:
         for row in rows:
-            print(json.dumps(row), flush=True)
             f.write(json.dumps(row) + "\n")
     return 0
 

@@ -1,9 +1,11 @@
 """Pallas TPU kernels of the gated delta rule with per-channel decay
 (ops/delta_rule.py has the recurrence and its chunked form).
 
-A grid step is one chunk of ``CHUNK`` positions of one head of one
-sequence; a head's chunks are walked in order, with its [values,
-keys] float32 state resident in VMEM (64 KB). The forward kernel goes
+A grid step is one chunk of ``CHUNK`` positions of several adjacent
+heads of one sequence (``heads_a_step``: the most of ``HEADS_A_STEP``
+that divides the operands' heads, one head where none does); the
+heads' chunks are walked in order, with each head's [values, keys]
+float32 state resident in VMEM (64 KB a head). The forward kernel goes
 up the sequence. Differentiated, it also writes each chunk's entry
 state, and the backward kernel goes down the sequence over them with
 the state's cotangent resident, making a chunk's ``A``, ``B``, ``W``
@@ -11,6 +13,23 @@ again from its operands: one forward kernel that keeps ``[seq / 64,
 heads, 128, 128]`` float32 (256 MB a layer at 8,192 positions of 64
 heads, alive for that layer's backward pass only) and one backward
 kernel, not a second forward walk inside the backward.
+
+Why several heads. A head's chunk is a chain of small products, each
+waiting for the one before (the inverse below alone is seven deep, on
+[64, 64] operands), and one head offers the compiler nothing to put
+in the waits: alone, a head's chunk takes 2.3 us forward and 3.0
+backward, 1.5 us of either the inverse (PERF.md, PR 46). The heads
+are independent and adjacent lanes of the same rows, so a grid step
+takes a block of rows ``[CHUNK, heads_a_step x 128]`` and makes the
+heads' chunks side by side. The arithmetic of a head is what it is
+alone, product for product and bit for bit; what changes is the order
+of the kernel's text, which is the order the compiler issues from:
+the heads' parts are generators taken in turn (``_in_turn``), so that
+the same product of every head stands together, and the inverse is
+taken on two heads at once as one block-diagonal [128, 128] matrix,
+which fills the MXU's face where one head's [64, 64] fills a quarter.
+The stages between two turns are jitted (``_stage``), so that a kernel
+is traced in the time of one head's, however many it holds.
 
 Within a chunk the rank-one updates are folded: ``(I + Diag(beta)
 A)^-1`` of the strictly lower ``N = Diag(beta) A`` is a product of
@@ -40,8 +59,8 @@ masked, by a select, after they are made.
 
 Operands, residuals and results are rows, ``[batch, seq, heads x
 128]`` (``beta`` ``[batch, seq, heads]``): what a projection writes,
-and what a grid step's block, a head's lane tile of 64 rows, is cut
-from. On the chip ``[seq, heads, 128]`` is other bytes than ``[seq,
+and what a grid step's block, its heads' lane tiles of 64 rows, is
+cut from. On the chip ``[seq, heads, 128]`` is other bytes than ``[seq,
 heads x 128]`` (tiles of (heads, 128), not of (8 rows, 128)), so a
 caller that holds heads pays a pass over each operand to get here:
 ops/delta_rule.py's 4-D entry is the one place that does, and the
@@ -73,6 +92,10 @@ HEAD = 128
 #: kernels the backward pass runs (beside the forward that keeps the
 #: chunks' entry states)
 BACKWARD_KERNELS = 1
+#: heads that may share a grid step, the most first. Eight read 6%
+#: under four in the kernels and 0.6% in the step, for 2.5 s more of
+#: every start spent tracing them (PERF.md, PR 46)
+HEADS_A_STEP = (4, 2)
 
 F32 = jnp.float32
 _NN = (((1,), (0,)), ((), ()))
@@ -86,8 +109,43 @@ def tiles_the_kernel(shape, heads) -> bool:
     return shape[2] == heads * HEAD and shape[1] % CHUNK == 0
 
 
+def heads_a_step(heads) -> int:
+    """Heads of one grid step: the most of ``HEADS_A_STEP`` that
+    divides the operands' ``heads``, one where none does."""
+    return next((h for h in HEADS_A_STEP if heads % h == 0), 1)
+
+
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def _in_turn(bodies):
+    """Run generators a step each in turn until each has returned,
+    and give what they returned. A head's part of a grid step is
+    written as a generator that yields where its next line waits for
+    products: taken in turn, the heads' products of one stage stand
+    next to each other in the kernel's text, which is the order the
+    compiler issues them in (it moves little of one head's chain up
+    beside another's by itself: PERF.md, PR 46). One body is run
+    straight through."""
+    bodies = list(bodies)
+    results = [None] * len(bodies)
+    live = dict(enumerate(bodies))
+    while live:
+        for j, body in list(live.items()):
+            try:
+                next(body)
+            except StopIteration as stop:
+                results[j] = stop.value
+                del live[j]
+    return results
+
+
+#: a stage of a head's part, traced once for all the heads of a grid
+#: step and all three kernels (the trace of a kernel is otherwise as
+#: many times a head's as the step has heads: PERF.md, PR 46); the
+#: kernel's text holds every call's operations in place
+_stage = functools.partial(jax.jit, static_argnames=("dtype",))
 
 
 def _dot(a, b, dims, dtype=F32):
@@ -100,9 +158,14 @@ def _dot(a, b, dims, dtype=F32):
     )
 
 
+def _places(shape):
+    """Every entry's row and its column."""
+    return (jax.lax.broadcasted_iota(jnp.int32, shape, 0),
+            jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+
+
 def _triangle(strict, upper=False):
-    row = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 1)
+    row, col = _places((CHUNK, CHUNK))
     if upper:
         row, col = col, row
     return row > col if strict else row >= col
@@ -112,82 +175,205 @@ def _ones(mask):
     return jnp.where(mask, 1.0, 0.0).astype(F32)
 
 
+def _eye(shape):
+    row, col = _places(shape)
+    return _ones(row == col)
+
+
+def _rows(block):
+    """The rows of a chunk's ``block``-th block of ``SUB``."""
+    return slice(block * SUB, (block + 1) * SUB)
+
+
+@jax.jit
+def _cumulated(g):
+    """A chunk's log decay cumulated down its rows."""
+    return _dot(_ones(_triangle(False)), g, _NN)
+
+
+@jax.jit
 def _factors(gc):
-    """``[(rows, exp(G_t - rho) [SUB, d], exp(rho - G_i) [CHUNK, d])]``
-    for each block of ``SUB`` rows of the chunk's cumulated log decay
+    """``[(exp(G_t - rho) [SUB, d], exp(rho - G_i) [CHUNK, d])]`` for
+    each block of ``SUB`` rows of the chunk's cumulated log decay
     ``gc``."""
     out = []
-    for at in range(0, CHUNK, SUB):
-        rows = slice(at, at + SUB)
-        block = gc[rows]
+    for at in range(CHUNK // SUB):
+        block = gc[_rows(at)]
         rho = 0.5 * (block[:1] + block[SUB - 1:])
         out.append((
-            rows, jnp.exp(jnp.clip(block - rho, -CLIP, CLIP)),
+            jnp.exp(jnp.clip(block - rho, -CLIP, CLIP)),
             jnp.exp(jnp.minimum(rho - gc, CLIP)),
         ))
     return out
 
 
-def _inverse(n):
-    """``(I + n)^-1`` of a strictly lower-triangular ``n`` [CHUNK,
-    CHUNK], in float32 products. A nilpotent ``m`` has ``(I + m)^-1 =
-    (I - m)(I + m^2)(I + m^4)...``; taken on all of ``n`` at once the
-    high powers of keys that resemble each other grow large before
-    they cancel. So first the blocks of ``SUB`` on the diagonal, whose
-    sixteenth power is zero (``near``), then what is left of ``I +
-    n`` once they are divided out: ``I + near n_off``, whose blocks
-    lie strictly below the diagonal and whose ``CHUNK / SUB``-th
-    power is zero."""
-    row = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 1)
-    eye = _ones(row == col)
-    diagonal = row // SUB == col // SUB
-
-    def inverse(m, order):
-        inv, power = eye - m, m
-        for _ in range(order.bit_length() - 2):
-            power = _dot(power, power, _NN)
-            inv = _dot(inv, eye + power, _NN)
-        return inv
-
-    near = inverse(jnp.where(diagonal, n, 0.0), SUB)
-    far = _dot(near, jnp.where(diagonal, 0.0, n), _NN)
-    return _dot(inverse(far, CHUNK // SUB), near, _NN)
-
-
-def _chunk(q, k, v, g, beta, state, dtype):
-    """What both kernels make of a chunk's operands (float32) and its
-    entry state [values, keys]: a dict of the chunked form's parts."""
-    gc = _dot(_ones(_triangle(False)), g, _NN)  # cumulated down the rows
-    factors = _factors(gc)
+@_stage
+def _decayed(q, k, factors, dtype):
+    """``A`` (strictly lower) and ``B`` (lower) of a chunk, a block
+    of rows at a time."""
     a_rows, b_rows = [], []
-    for rows, e_row, e_col in factors:
+    for at, (e_row, e_col) in enumerate(factors):
+        rows = _rows(at)
         both = _dot(
             jnp.concatenate([k[rows] * e_row, q[rows] * e_row], axis=0),
             k * e_col, _NT, dtype,
         )
         a_rows.append(both[:SUB])
         b_rows.append(both[SUB:])
-    a = jnp.where(_triangle(True), jnp.concatenate(a_rows, axis=0), 0.0)
-    b = jnp.where(_triangle(False), jnp.concatenate(b_rows, axis=0), 0.0)
-    inv = _inverse(beta * a)
-    gamma = jnp.exp(gc)
-    kg, qg = k * gamma, q * gamma
-    held = _dot(kg, state, _NT, dtype)  # what the state holds along k
-    w = _dot(inv, beta * (v - held), _NN)
-    last = gc[CHUNK - 1:]
-    return dict(
-        gc=gc, factors=factors, a=a, b=b, inv=inv, gamma=gamma, kg=kg,
-        qg=qg, held=held, w=w, last=last, kd=k * jnp.exp(last - gc),
+    return (
+        jnp.where(_triangle(True), jnp.concatenate(a_rows, axis=0), 0.0),
+        jnp.where(_triangle(False), jnp.concatenate(b_rows, axis=0), 0.0),
     )
 
 
-def _next_state(c, state, dtype):
-    return state * jnp.exp(c["last"]) + _dot(c["w"], c["kd"], _TN, dtype)
+@_stage
+def _against_state(q, k, gc, state, dtype):
+    gamma = jnp.exp(gc)
+    kg, qg = k * gamma, q * gamma
+    last = gc[CHUNK - 1:]
+    return dict(
+        gamma=gamma, kg=kg, qg=qg, last=last, kd=k * jnp.exp(last - gc),
+        held=_dot(kg, state, _NT, dtype),  # what the state holds along k
+    )
 
 
-def _load(refs):
-    return [ref[...].astype(F32) for ref in refs]
+def _before(q, k, v, g, beta, state, dtype):
+    """What both kernels make of a head's chunk, its operands in
+    float32 and its entry state [values, keys], ahead of the inverse:
+    a dict of the chunked form's parts; a generator (``_in_turn``)."""
+    gc = _cumulated(g)
+    yield
+    factors = _factors(gc)
+    yield
+    a, b = _decayed(q, k, factors, dtype)
+    yield
+    return dict(
+        q=q, k=k, v=v, beta=beta, state=state, gc=gc, factors=factors,
+        a=a, b=b, **_against_state(q, k, gc, state, dtype),
+    )
+
+
+@jax.jit
+def _split(n):
+    """A strictly lower ``n`` as ``I`` less its blocks of ``SUB`` on
+    the diagonal, those blocks, and the rest."""
+    row, col = _places(n.shape)
+    diagonal = row // SUB == col // SUB
+    near = jnp.where(diagonal, n, 0.0)
+    return _eye(n.shape) - near, near, jnp.where(diagonal, 0.0, n)
+
+
+@jax.jit
+def _times(a, b):
+    return _dot(a, b, _NN)
+
+
+@jax.jit
+def _times_one_plus(a, b):
+    """``a (I + b)``."""
+    return _dot(a, _eye(b.shape) + b, _NN)
+
+
+@jax.jit
+def _one_minus(m):
+    return _eye(m.shape) - m
+
+
+def _inverse(n):
+    """``(I + n)^-1`` of a strictly lower-triangular ``n``, in float32
+    products; a generator (``_in_turn``). ``n`` is one head's [CHUNK,
+    CHUNK] or two heads' on the diagonal of one matrix, whose inverse
+    has the two heads' on its diagonal: a product of such matrices is
+    such a matrix, and the zero blocks add exact zeros, so a head's
+    numbers are what they are by itself. A nilpotent ``m`` has ``(I +
+    m)^-1 = (I - m)(I + m^2)(I + m^4)...``; taken on all of ``n`` at
+    once the high powers of keys that resemble each other grow large
+    before they cancel. So first the blocks of ``SUB`` on the diagonal,
+    whose sixteenth power is zero (``near``), then what is left of ``I
+    + n`` once they are divided out: ``I + near n_off``, whose blocks
+    lie strictly below the diagonal and whose ``CHUNK / SUB``-th
+    power is zero."""
+    def inverse(inv, power, order):
+        for _ in range(order.bit_length() - 2):
+            power = _times(power, power)
+            yield
+            inv = _times_one_plus(inv, power)
+            yield
+        return inv
+
+    inv, on_diagonal, off_diagonal = _split(n)
+    near = yield from inverse(inv, on_diagonal, SUB)
+    far = _times(near, off_diagonal)
+    yield
+    inv = yield from inverse(_one_minus(far), far, CHUNK // SUB)
+    return _times(inv, near)
+
+
+def _diagonal(blocks):
+    """One or two square blocks as the blocks on the diagonal of one
+    matrix, zeros beside them."""
+    if len(blocks) == 1:
+        return blocks[0]
+    one, two = blocks
+    zero = jnp.zeros_like(one)
+    return jnp.concatenate([
+        jnp.concatenate([one, zero], axis=1),
+        jnp.concatenate([zero, two], axis=1),
+    ], axis=0)
+
+
+def _pairs(xs):
+    """``xs`` two at a time; one at a time where they are an odd
+    number."""
+    size = 1 if len(xs) % 2 else 2
+    return [xs[at:at + size] for at in range(0, len(xs), size)]
+
+
+def _across(invs, rights, dims):
+    """The float32 product of each pair's matrix of ``invs`` with its
+    heads' ``rights`` [CHUNK, d], one under the other; a list as
+    ``rights`` is."""
+    out = []
+    for inv, pair in zip(invs, _pairs(rights)):
+        both = _dot(inv, jnp.concatenate(pair, axis=0), dims)
+        out += [both[at:at + CHUNK] for at in range(0, both.shape[0], CHUNK)]
+    return out
+
+
+def _lanes(j):
+    """Head ``j``'s columns of a grid step's block of rows."""
+    return slice(j * HEAD, (j + 1) * HEAD)
+
+
+def _chunks(refs, beta_ref, states, dtype):
+    """For each head of a grid step the chunked form's parts, ``w``
+    among them, and for each pair of heads its inverse. ``refs`` are
+    the blocks of ``q, k, v, g``, a head a lane tile of each, and
+    ``states`` holds the heads' entry states."""
+    cs = _in_turn(
+        _before(*(ref[:, _lanes(j)].astype(F32) for ref in refs),
+                beta_ref[j], states[j], dtype)
+        for j in range(states.shape[0])
+    )
+    invs = _in_turn(
+        _inverse(_diagonal([c["beta"] * c["a"] for c in pair]))
+        for pair in _pairs(cs)
+    )
+    ws = _across(
+        invs, [c["beta"] * (c["v"] - c["held"]) for c in cs], _NN)
+    for c, w in zip(cs, ws):
+        c["w"] = w
+    return cs, invs
+
+
+@_stage
+def _result(c, dtype):
+    """A chunk's ``o`` ahead of its scale, and the state it leaves."""
+    entry = c["state"]
+    return (
+        _dot(c["qg"], entry, _NT, dtype) + _dot(c["b"], c["w"], _NN, dtype),
+        entry * jnp.exp(c["last"]) + _dot(c["w"], c["kd"], _TN, dtype),
+    )
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
@@ -198,57 +384,68 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
     def _():
         state[...] = jnp.zeros_like(state)
 
-    q, k, v, g, beta = _load((q_ref, k_ref, v_ref, g_ref, beta_ref))
-    entry = state[...]
-    if states_ref is not None:
-        states_ref[...] = entry
-    c = _chunk(q, k, v, g, beta, entry, dtype)
-    o_ref[...] = (scale * (
-        _dot(c["qg"], entry, _NT, dtype) + _dot(c["b"], c["w"], _NN, dtype)
-    )).astype(o_ref.dtype)
-    state[...] = _next_state(c, entry, dtype)
+    cs, _ = _chunks((q_ref, k_ref, v_ref, g_ref), beta_ref, state, dtype)
+    for j, c in enumerate(cs):
+        if states_ref is not None:
+            states_ref[j] = c["state"]
+        o, state[j] = _result(c, dtype)
+        o_ref[:, _lanes(j)] = (scale * o).astype(o_ref.dtype)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
-                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dstate, *,
-                scale, dtype):
-    @pl.when(pl.program_id(2) == 0)
-    def _():
-        dstate[...] = jnp.zeros_like(dstate)
-
-    q, k, v, g, beta = _load((q_ref, k_ref, v_ref, g_ref, beta_ref))
-    entry, after = states_ref[...], dstate[...]
-    do = scale * do_ref[...].astype(F32)
-    c = _chunk(q, k, v, g, beta, entry, dtype)
-    w, kd, kg, qg, gamma = c["w"], c["kd"], c["kg"], c["qg"], c["gamma"]
-
-    dw = _dot(c["b"], do, _TN, dtype) + _dot(kd, after, _NT, dtype)
-    db = jnp.where(_triangle(False), _dot(do, w, _NT, dtype), 0.0)
-    dqg = _dot(do, entry, _NN, dtype)
-    dkd = _dot(w, after, _NN, dtype)
-    dr = _dot(c["inv"], dw, _TN)
-    dn = jnp.where(_triangle(True), -_dot(dr, w, _NT), 0.0)
-    da = beta * dn
-    dbeta_ref[...] = (
-        jnp.sum(dn * c["a"], axis=1, keepdims=True)
-        + jnp.sum(dr * (v - c["held"]), axis=1, keepdims=True)
+@_stage
+def _cotangents(c, do, after, dtype):
+    """What ``do`` and the cotangent ``after`` of the state a chunk
+    leaves hand to ``w`` and to the parts that ``w`` does not pass
+    through."""
+    return dict(
+        dw=_dot(c["b"], do, _TN, dtype) + _dot(c["kd"], after, _NT, dtype),
+        db=jnp.where(
+            _triangle(False), _dot(do, c["w"], _NT, dtype), 0.0),
+        dqg=_dot(do, c["state"], _NN, dtype),
+        dkd=_dot(c["w"], after, _NN, dtype),
     )
-    dv = beta * dr
-    dv_ref[...] = dv.astype(dv_ref.dtype)
-    dkg = -_dot(dv, entry, _NN, dtype)
-    dstate[...] = (
-        _dot(do, qg, _TN, dtype) + after * jnp.exp(c["last"])
-        - _dot(dv, kg, _TN, dtype)
+
+
+@_stage
+def _through_the_inverse(c, dr, dtype):
+    """From ``dr``, the cotangent of the solved system's right side:
+    ``dn`` of ``Diag(beta) A``, ``dbeta``, ``dv`` and ``dkg``."""
+    dn = jnp.where(_triangle(True), -_dot(dr, c["w"], _NT), 0.0)
+    dv = c["beta"] * dr
+    return dict(
+        dn=dn, dv=dv, dkg=-_dot(dv, c["state"], _NN, dtype),
+        dbeta=jnp.sum(dn * c["a"], axis=1, keepdims=True)
+        + jnp.sum(dr * (c["v"] - c["held"]), axis=1, keepdims=True),
     )
-    dq = dqg * gamma
-    dk = dkg * gamma + dkd * jnp.exp(c["last"] - c["gc"])
-    dgc = dqg * qg + dkg * kg - dkd * kd
+
+
+@_stage
+def _before_state(c, d, do, after, dtype):
+    """The cotangent of a chunk's entry state."""
+    return (
+        _dot(do, c["qg"], _TN, dtype) + after * jnp.exp(c["last"])
+        - _dot(d["dv"], c["kg"], _TN, dtype)
+    )
+
+
+@_stage
+def _to_operands(c, d, after, dtype):
+    """``dq``, ``dk`` and ``dg`` of a chunk from the cotangents of its
+    parts."""
+    q, k, kd = c["q"], c["k"], c["kd"]
+    da, db = c["beta"] * d["dn"], d["db"]
+    dqg, dkg, dkd = d["dqg"], d["dkg"], d["dkd"]
+    dq = dqg * c["gamma"]
+    dk = dkg * c["gamma"] + dkd * jnp.exp(c["last"] - c["gc"])
+    dgc = dqg * c["qg"] + dkg * c["kg"] - dkd * kd
     dlast = (
-        jnp.sum(entry * after, axis=0, keepdims=True) * jnp.exp(c["last"])
+        jnp.sum(c["state"] * after, axis=0, keepdims=True)
+        * jnp.exp(c["last"])
         + jnp.sum(dkd * kd, axis=0, keepdims=True)
     )
     dq_rows, dk_rows, dgc_rows = [], [], []
-    for rows, e_row, e_col in c["factors"]:
+    for at, (e_row, e_col) in enumerate(c["factors"]):
+        rows = _rows(at)
         grads = jnp.concatenate([da[rows], db[rows]], axis=0)
         along = _dot(grads, k * e_col, _NN, dtype)
         to_k, to_q = along[:SUB] * e_row, along[SUB:] * e_row
@@ -262,29 +459,63 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
         )
         dk = dk + down
         dgc = dgc - k * down
-    dq_ref[...] = (dq + jnp.concatenate(dq_rows, axis=0)).astype(dq_ref.dtype)
-    dk_ref[...] = (dk + jnp.concatenate(dk_rows, axis=0)).astype(dk_ref.dtype)
     dgc = dgc + jnp.concatenate(dgc_rows, axis=0)
     # a position's log decay is in every later row's cumulated one,
     # and the chunk's last row's in the state that leaves it
-    dg_ref[...] = (
-        _dot(_ones(_triangle(False, upper=True)), dgc, _NN) + dlast
-    ).astype(dg_ref.dtype)
+    return (
+        dq + jnp.concatenate(dq_rows, axis=0),
+        dk + jnp.concatenate(dk_rows, axis=0),
+        _dot(_ones(_triangle(False, upper=True)), dgc, _NN) + dlast,
+    )
 
 
-def _specs(chunks, reverse):
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
+                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dstate, *,
+                scale, dtype):
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dstate[...] = jnp.zeros_like(dstate)
+
+    heads = range(dstate.shape[0])
+    cs, invs = _chunks(
+        (q_ref, k_ref, v_ref, g_ref), beta_ref, states_ref, dtype)
+    dos = [scale * do_ref[:, _lanes(j)].astype(F32) for j in heads]
+    afters = [dstate[j] for j in heads]
+    ds = [_cotangents(c, do, after, dtype)
+          for c, do, after in zip(cs, dos, afters)]
+    drs = _across(invs, [d["dw"] for d in ds], _TN)
+
+    def rest_of(j, c, d, do, after, dr):
+        d.update(_through_the_inverse(c, dr, dtype))
+        yield
+        dbeta_ref[j] = d["dbeta"]
+        dv_ref[:, _lanes(j)] = d["dv"].astype(dv_ref.dtype)
+        dstate[j] = _before_state(c, d, do, after, dtype)
+        yield
+        dq, dk, dg = _to_operands(c, d, after, dtype)
+        dq_ref[:, _lanes(j)] = dq.astype(dq_ref.dtype)
+        dk_ref[:, _lanes(j)] = dk.astype(dk_ref.dtype)
+        dg_ref[:, _lanes(j)] = dg.astype(dg_ref.dtype)
+
+    _in_turn(rest_of(*of) for of in zip(heads, cs, ds, dos, afters, drs))
+
+
+def _specs(chunks, together, reverse):
     """Block specs of a ``[batch, seq, heads x d]`` operand, of
     ``beta`` as ``[batch, heads, seq, 1]`` and of the entry states
-    ``[batch, heads, chunks, d, d]``, for the grid ``(batch, head,
-    chunk)``; ``reverse`` walks the chunks from the last."""
+    ``[batch, heads, chunks, d, d]``, for the grid ``(batch, heads /
+    together, chunk)``: a block is ``together`` adjacent heads' part
+    of each; ``reverse`` walks the chunks from the last."""
     def at(n):
         return chunks - 1 - n if reverse else n
 
-    wide = pl.BlockSpec((None, CHUNK, HEAD), lambda b, h, n: (b, at(n), h))
+    wide = pl.BlockSpec(
+        (None, CHUNK, together * HEAD), lambda b, h, n: (b, at(n), h))
     beta = pl.BlockSpec(
-        (None, None, CHUNK, 1), lambda b, h, n: (b, h, at(n), 0))
+        (None, together, CHUNK, 1), lambda b, h, n: (b, h, at(n), 0))
     states = pl.BlockSpec(
-        (None, None, None, HEAD, HEAD), lambda b, h, n: (b, h, at(n), 0, 0))
+        (None, together, None, HEAD, HEAD),
+        lambda b, h, n: (b, h, at(n), 0, 0))
     return wide, beta, states
 
 
@@ -297,7 +528,8 @@ def _params():
 def _forward(q, k, v, g, beta, heads, keep_states):
     batch, seq, _ = q.shape
     chunks = seq // CHUNK
-    wide, beta_spec, states_spec = _specs(chunks, False)
+    together = heads_a_step(heads)
+    wide, beta_spec, states_spec = _specs(chunks, together, False)
     out_specs = [wide]
     out_shape = [jax.ShapeDtypeStruct(v.shape, v.dtype)]
     if keep_states:
@@ -306,10 +538,10 @@ def _forward(q, k, v, g, beta, heads, keep_states):
             (batch, heads, chunks, HEAD, HEAD), F32))
     out = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=HEAD ** -0.5, dtype=q.dtype),
-        grid=(batch, heads, chunks),
+        grid=(batch, heads // together, chunks),
         in_specs=[wide, wide, wide, wide, beta_spec],
         out_specs=out_specs, out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((HEAD, HEAD), F32)],
+        scratch_shapes=[pltpu.VMEM((together, HEAD, HEAD), F32)],
         compiler_params=_params(), interpret=_interpret(),
     )(q, k, v, g, beta)
     return out if keep_states else out[0]
@@ -318,10 +550,11 @@ def _forward(q, k, v, g, beta, heads, keep_states):
 def _backward(q, k, v, g, beta, states, do, heads):
     batch, seq, _ = q.shape
     chunks = seq // CHUNK
-    wide, beta_spec, states_spec = _specs(chunks, True)
+    together = heads_a_step(heads)
+    wide, beta_spec, states_spec = _specs(chunks, together, True)
     return pl.pallas_call(
         functools.partial(_bwd_kernel, scale=HEAD ** -0.5, dtype=q.dtype),
-        grid=(batch, heads, chunks),
+        grid=(batch, heads // together, chunks),
         in_specs=[wide, wide, wide, wide, beta_spec, states_spec, wide],
         out_specs=[wide, wide, wide, wide, beta_spec],
         out_shape=[
@@ -331,7 +564,7 @@ def _backward(q, k, v, g, beta, states, do, heads):
             jax.ShapeDtypeStruct(g.shape, g.dtype),
             jax.ShapeDtypeStruct(beta.shape, beta.dtype),
         ],
-        scratch_shapes=[pltpu.VMEM((HEAD, HEAD), F32)],
+        scratch_shapes=[pltpu.VMEM((together, HEAD, HEAD), F32)],
         compiler_params=_params(), interpret=_interpret(),
     )(q, k, v, g, beta, states, do)
 
@@ -362,21 +595,28 @@ def delta_rule(q, k, v, g, beta, states=None, do=None, keep_states=False):
     )
 
 
-def _record(folded):
-    """Say what was built, at trace time: the gauges and counters of
-    docs/TELEMETRY.md. Every Pallas call a step holds is counted once,
-    by whether its caller held rows or heads that were ``folded``."""
+def _record(folded, heads):
+    """Say what was built, at trace time, for operands of ``heads``
+    heads: the gauges and counters of docs/TELEMETRY.md. Every Pallas
+    call a step holds is counted once, by whether its caller held rows
+    or heads that were ``folded``."""
     from dlrover_tpu.telemetry.registry import counter, gauge
 
+    together = heads_a_step(heads)
     gauge(
         "delta_rule_chunk",
         "positions of one chunk of the gated delta rule's scan",
     ).set(CHUNK)
     gauge(
+        "delta_rule_heads_per_step",
+        "adjacent heads whose chunk one grid step of the gated delta "
+        "rule's kernels takes",
+    ).set(together)
+    gauge(
         "delta_rule_state_bytes",
-        "bytes of a head's state resident in VMEM through the gated "
-        "delta rule's scan",
-    ).set(HEAD * HEAD * 4)
+        "bytes of the states, a grid step's heads', resident in VMEM "
+        "through the gated delta rule's scan",
+    ).set(together * HEAD * HEAD * 4)
     gauge(
         "delta_rule_backward_kernels",
         "Pallas kernels of the gated delta rule's backward pass, beside "
@@ -402,19 +642,19 @@ def _record(folded):
 def delta_rule_tpu(q, k, v, g, beta, folded=False):
     """``delta_rule`` with its differentiation rule; ``folded`` says,
     for the record alone, that the caller held heads."""
-    _record(folded)
+    _record(folded, beta.shape[2])
     return delta_rule(q, k, v, g, beta)
 
 
 def _vjp_fwd(q, k, v, g, beta, folded):
-    _record(folded)
+    _record(folded, beta.shape[2])
     o, states = delta_rule(q, k, v, g, beta, keep_states=True)
     return o, (q, k, v, g, beta, states)
 
 
 def _vjp_bwd(folded, saved, do):
-    _record(folded)
     *operands, states = saved
+    _record(folded, operands[4].shape[2])
     return delta_rule(*operands, states=states, do=do)
 
 

@@ -1117,9 +1117,10 @@ def test_solar_step_holds_the_delta_rules_kernels(
     8192]`` array, in any of its shapes, is a device op of its own
     under a ``kda.`` scope, no float32 one under no scope (the copies
     a trace shows without an ``op_name``), and the nine calls were
-    handed rows."""
+    handed rows and built with the most heads a grid step that the
+    kernels' rule has, which divides the cell's 64."""
     from dlrover_tpu.ops import delta_rule, grouped_matmul as gm
-    from dlrover_tpu.telemetry.registry import counter
+    from dlrover_tpu.telemetry.registry import counter, gauge
     from dlrover_tpu.ops.pallas import delta_rule as scan_kernels
     from yardstick import cells, worker
     from yardstick.layer_metrics import (
@@ -1134,6 +1135,7 @@ def test_solar_step_holds_the_delta_rules_kernels(
     calls = [counter(f"delta_rule_{handed}_calls", "")
              for handed in ("rows", "folded")]
     before = [c.value for c in calls]
+    gauge("delta_rule_heads_per_step", "").set(0)
     _, config, traffic = cells.load_cell("solar-open2-250b-ep32.steady")
     cfg = worker.program_config(config, traffic)
     assert (cfg.remat, cfg.loss_chunk) == ("minimal", 0)
@@ -1188,6 +1190,10 @@ def test_solar_step_holds_the_delta_rules_kernels(
     ]
     assert not unscoped
     assert [c.value - was for c, was in zip(calls, before)] == [9, 0]
+    together = gauge("delta_rule_heads_per_step", "").value
+    assert together == max(scan_kernels.HEADS_A_STEP) > 1
+    assert gauge(
+        "delta_rule_state_bytes", "").value == together * 128 * 128 * 4
     moved = [
         (op, result[:40], name[-60:])
         for op, result, operands, name in _outside_fusions(

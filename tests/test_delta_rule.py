@@ -107,13 +107,31 @@ CASES = {
 }
 
 
+#: heads of the operands -> heads that a grid step of the kernels takes
+#: of them: every number the kernels' rule can choose, and one head
+#: where nothing else divides the count
+HEADS_A_STEP = {3: 1, 2: 2, 4: 4, 6: 2}
+#: (path, heads of the operands): the kernels at each number of heads a
+#: grid step, which only the head count decides
+PATHS_AND_HEADS = [("plain", 2)] + [("kernels", h) for h in (3, 2, 4)]
+
+
+def test_heads_a_grid_step_come_from_the_head_count():
+    assert set(HEADS_A_STEP.values()) == {1, *kernels.HEADS_A_STEP}
+    for heads, together in HEADS_A_STEP.items():
+        assert kernels.heads_a_step(heads) == together
+    assert kernels.heads_a_step(64) == max(kernels.HEADS_A_STEP)
+    assert kernels.heads_a_step(1) == 1
+
+
 @pytest.mark.parametrize("case", list(CASES))
-@pytest.mark.parametrize("path", list(PATHS))
-def test_path_agrees_with_the_recurrence(path, case):
+@pytest.mark.parametrize("path,heads", PATHS_AND_HEADS)
+def test_path_agrees_with_the_recurrence(path, heads, case):
     """float32: ``o`` and the five gradients to 1e-4 of each one's
-    largest entry, no NaN, no overflow."""
+    largest entry, no NaN, no overflow; the kernels with one, two and
+    four heads' chunks a grid step."""
     seq, decay, beta = CASES[case]
-    args = operands(3, 2, seq, 2, decay, beta)
+    args = operands(3, 2, seq, heads, decay, beta)
     want_o = recurrence(*args)
     cotangent = jax.random.normal(jax.random.key(9), want_o.shape)
     got_o = PATHS[path](*args)
@@ -149,15 +167,41 @@ def test_a_chunks_edge_is_no_edge(path):
         assert relative(a, b) < 1e-4, name
 
 
-@pytest.mark.parametrize("path", list(PATHS))
-def test_batch_rows_and_heads_are_independent(path):
-    args = operands(7, 3, 128, 2, 0.5)
+@pytest.mark.parametrize("path,heads", PATHS_AND_HEADS + [("kernels", 6)])
+def test_batch_rows_and_heads_are_independent(path, heads):
+    """A row of the batch alone and the heads in another order read
+    the same. In the kernels, where heads share a grid step, a head's
+    ``o`` and gradients are the same to the last bit whoever its
+    neighbours are: by itself (a grid step of one head), and beside
+    heads whose operands were changed."""
+    args = operands(7, 3, 128, heads, 0.5)
     whole = PATHS[path](*args)
     for row in range(3):
         alone = PATHS[path](*(x[row:row + 1] for x in args))
         assert relative(alone, whole[row:row + 1]) < 1e-5
     swapped = PATHS[path](*(x[:, :, ::-1] for x in args))
     assert relative(swapped[:, :, ::-1], whole) < 1e-5
+    if path != "kernels":
+        return
+    assert kernels.heads_a_step(heads) == HEADS_A_STEP[heads]
+    args = tuple(x[:1] for x in args)
+    cotangent = jax.random.normal(jax.random.key(9), args[2].shape)
+    last = heads - 1  # the last head: the others share its grid step
+    others = tuple(
+        x.at[:, :, :last].set(x[:, :, :last] * 0.5) for x in args)
+    alone = tuple(x[:, :, last:] for x in args)
+    _, grads = with_gradients(PATHS[path], args, cotangent)
+    _, beside_others = with_gradients(PATHS[path], others, cotangent)
+    _, by_itself = with_gradients(
+        PATHS[path], alone, cotangent[:, :, last:])
+    for name, a, b, c in zip(
+        ("o", *NAMES),
+        (whole[:1], *grads),
+        (PATHS[path](*others), *beside_others),
+        (PATHS[path](*alone), *by_itself),
+    ):
+        assert bool((a[:, :, last:] == b[:, :, last:]).all()), name
+        assert bool((a[:, :, last:] == c).all()), name
 
 
 def test_kernels_in_bfloat16_are_within_a_step_of_bfloat16():
@@ -353,13 +397,14 @@ def test_dispatch_says_what_it_built(kernels_at_the_entry):
     from dlrover_tpu.telemetry.registry import gauge
 
     for name in ("delta_rule_chunk", "delta_rule_state_bytes",
-                 "delta_rule_backward_kernels"):
+                 "delta_rule_backward_kernels", "delta_rule_heads_per_step"):
         gauge(name, "").set(0)
     args = operands(1, 1, 64, 1, 0.5)
     flat = (*(rows(x) for x in args[:4]), args[4])
     before = _calls()
     jax.jit(kernels.delta_rule_tpu).lower(*flat)  # traced, not run
     assert gauge("delta_rule_chunk", "").value == 64
+    assert gauge("delta_rule_heads_per_step", "").value == 1
     assert gauge("delta_rule_state_bytes", "").value == 128 * 128 * 4
     assert gauge("delta_rule_backward_kernels", "").value == 1
     assert _calls() == (before[0] + 1, before[1])
@@ -373,3 +418,10 @@ def test_dispatch_says_what_it_built(kernels_at_the_entry):
         lambda *a: delta_rule.gated_delta_rule(*a).sum(), argnums=(0, 3)
     )).lower(*args)
     assert _calls() == (before[0] + 2, before[1] + 3)
+    # as many heads' states resident as a grid step takes heads
+    for heads, together in HEADS_A_STEP.items():
+        args = operands(1, 1, 64, heads, 0.5)
+        jax.jit(delta_rule.gated_delta_rule).lower(*args)
+        assert gauge("delta_rule_heads_per_step", "").value == together
+        assert gauge(
+            "delta_rule_state_bytes", "").value == together * 128 * 128 * 4
