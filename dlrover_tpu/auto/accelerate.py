@@ -189,8 +189,8 @@ def auto_accelerate(
     hbm = hbm_bytes or _device_hbm_bytes(devices[0])
     candidates = strategies or enumerate_strategies(
         len(devices), global_batch,
-        # past the measured single-chip envelope (LONGCTX artifact,
-        # strategy.SINGLE_CHIP_MAX_SEQ) no per-chip layout can hold
+        # past the measured single-chip envelope
+        # (strategy.SINGLE_CHIP_MAX_SEQ) no per-chip layout can hold
         # the sequence — sequence-parallel candidates join the search
         # and the analytic memory model (which divides activation
         # tokens by the seq axis) does the rest

@@ -7,8 +7,8 @@ acceleration engine).
 TPU shape: one cached snapshot of the accelerator fleet (platform,
 chip generation, per-chip HBM and peak bf16 FLOP/s from the device
 kind) plus host resources — the single source the strategy ranker
-(auto/accelerate.py), the planner, and bench.py share instead of each
-keeping its own chip table.
+(auto/accelerate.py) and the planner share instead of each keeping
+its own chip table.
 """
 
 import dataclasses

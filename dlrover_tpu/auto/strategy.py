@@ -18,11 +18,12 @@ from typing import List, Optional, Tuple
 REMAT_POLICIES = ("off", "dots", "dots_attn_out", "minimal")
 PRECISIONS = ("bf16", "fp32")
 
-#: longest sequence the flagship fits on ONE chip (LONGCTX_r04.json
-#: and an earlier chip run, not reproduced: batch 1 x seq 8192 trains
-#: at 47.7% MFU on the 15.75 GB v5e; 16384 does not fit with
-#: params+adam+dots-remat activations). Past this, sequence-parallel candidates enter the
-#: search — the auto layer's gate for choosing ring/Ulysses attention.
+#: longest sequence the flagship fits on ONE chip (``git show
+#: 6a8d87c:LONGCTX_r04.json``, an earlier chip run, not reproduced:
+#: batch 1 x seq 8192 trains on the 15.75 GB v5e; 16384 does not fit
+#: with params+adam+dots-remat activations). Past this,
+#: sequence-parallel candidates enter the search — the auto layer's
+#: gate for choosing ring/Ulysses attention.
 SINGLE_CHIP_MAX_SEQ = 8192
 #: the flagship's per-token activation-cost proxy (hidden x layers of
 #: llama_1b, the model the envelope was MEASURED on) — smaller models

@@ -389,15 +389,6 @@ def llama2_13b(**kw) -> LlamaConfig:
     )
 
 
-def llama2_70b(**kw) -> LlamaConfig:
-    """Llama-2-70B shape (GQA 64q/8kv) — BASELINE.json config #5's
-    elastic v5p-64 target."""
-    return LlamaConfig(
-        hidden_size=8192, intermediate_size=28672, num_layers=80,
-        num_heads=64, num_kv_heads=8, **kw,
-    )
-
-
 def llama_1b(**kw) -> LlamaConfig:
     """A ~1.1B config (TinyLlama shape) for single-chip benchmarking."""
     return LlamaConfig(

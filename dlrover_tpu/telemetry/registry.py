@@ -10,8 +10,7 @@ two ways:
   * ``to_prometheus_text()`` — the Prometheus text exposition format
     (v0.0.4), served by :mod:`dlrover_tpu.telemetry.http` so a scraper
     pointed at the master/agent ``/metrics`` endpoint just works;
-  * ``to_dict()`` — plain JSON for tests, ``bench.py`` detail fields,
-    and offline dumps.
+  * ``to_dict()`` — plain JSON for tests and offline dumps.
 
 No prometheus_client dependency: the container must not grow deps, and
 the subset needed here (three instrument kinds, labels, exposition) is

@@ -512,8 +512,7 @@ def summarize(names: Optional[Iterable[str]] = None,
               ) -> Dict[str, Dict[str, float]]:
     """Aggregate span durations by name:
     ``{name: {count, mean_ms, max_ms, total_ms}}``. ``names`` filters;
-    ``records`` defaults to the whole ring (bench.py's per-phase
-    breakdown reads this)."""
+    ``records`` defaults to the whole ring."""
     if records is None:
         records = tail(len(_ring) if _ring.maxlen is None else _ring.maxlen)
     wanted = set(names) if names is not None else None

@@ -8,14 +8,11 @@ numbers from the compiler instead of a module walk: ``jit(fn).lower(...)
 scheduled (including remat recompute — hardware flops, the HFU
 numerator), and ``memory_analysis()`` the buffer footprint.
 
-Two consumers:
- - ``ElasticTrainer``/bench report the profile to the master over the
-   ``report_model_info`` RPC -> JobMetricCollector -> LocalStatsReporter
-   (master/stats), closing the loop for the resource optimizer;
- - ``measure_step_time`` gives the wall-clock side for MFU/HFU.
+``ElasticTrainer`` reports the profile to the master over the
+``report_model_info`` RPC -> JobMetricCollector -> LocalStatsReporter
+(master/stats), closing the loop for the resource optimizer.
 """
 
-import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -107,34 +104,6 @@ def profile_step(step_fn: Callable, *args,
         (prof.variable_count, prof.param_count,
          prof.max_variable_size) = _tensor_stats(params)
     return prof
-
-
-def measure_step_time(run_once: Callable[[], Any], steps: int = 10,
-                      warmup: int = 2) -> float:
-    """Mean wall-clock seconds per step of ``run_once`` (which returns
-    jax arrays)."""
-    out = None
-    for _ in range(warmup):
-        out = run_once()
-    jax.block_until_ready(out)
-    t0 = time.perf_counter()
-    for _ in range(steps):
-        out = run_once()
-    jax.block_until_ready(out)
-    return (time.perf_counter() - t0) / steps
-
-
-def utilization(flops_per_step: float, step_time_s: float,
-                peak_flops: float) -> float:
-    """Percent of peak: ``100 * (flops/step / step_time) / peak``.
-
-    Feed it analytic model flops for MFU, or the XLA-counted hardware
-    flops from :class:`StepProfile` (remat recompute included) for HFU
-    — same wall-clock denominator, so the two are directly comparable
-    in the bench JSON."""
-    if step_time_s <= 0 or peak_flops <= 0:
-        return 0.0
-    return 100.0 * (flops_per_step / step_time_s) / peak_flops
 
 
 def report_profile(master_client, prof: StepProfile,

@@ -242,7 +242,7 @@ class ShardedTrainer:
     def microbatch_sharding(self) -> NamedSharding:
         """Sharding of a [accum, batch, ...] microbatched array — the
         single source of truth for shard_batch and external loaders
-        (DevicePrefetch, bench --data shm)."""
+        (DevicePrefetch)."""
         return NamedSharding(
             self.mesh, P(None, *self.batch_sharding.spec)
         )

@@ -46,7 +46,7 @@ from dlrover_tpu.trainer.sharded import make_trainer_for_llama
 
 MODELS = {
     "llama_tiny": llama.llama_tiny,
-    # sized as bench.py sizes it for a 16 GB chip
+    # sized for a 16 GB chip at 3 x 2048 (chip_smoke.py)
     "llama_1b": functools.partial(llama.llama_1b, remat="dots_attn_out"),
     # 4 experts, top-2: dropless on one device (parallel/moe.py)
     "llama_moe_tiny": llama.llama_moe_tiny,

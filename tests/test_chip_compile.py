@@ -38,7 +38,7 @@ from dlrover_tpu.ops.pallas import flash_attention as fa
 from dlrover_tpu.parallel import moe
 from dlrover_tpu.trainer.sharded import make_trainer_for_llama
 
-BATCH, SEQ = 3, 2048  # bench.py's one-chip size for llama_1b
+BATCH, SEQ = 3, 2048  # chip_smoke.py's one-chip size for llama_1b
 #: the whole-step compiles ask the compiler for the least optimization:
 #: a quarter of the CPU time (18 s, not 67 s, each), and what they
 #: guard does not depend on it — whether the step lowers, and whether

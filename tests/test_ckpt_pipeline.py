@@ -60,6 +60,7 @@ class SlowStore(ckpt_store.LocalFsStore):
         self.active = 0
         self.max_active = 0
         self.puts = 0
+        self.started = threading.Event()  # an upload has begun
         self._lock = threading.Lock()
 
     def _track(self):
@@ -69,6 +70,7 @@ class SlowStore(ckpt_store.LocalFsStore):
                     self.active += 1
                     self.max_active = max(self.max_active, self.active)
                     self.puts += 1
+                self.started.set()
                 time.sleep(self.delay)
                 return ctx
 
@@ -471,6 +473,9 @@ def test_ram_gc_spares_files_pinned_by_pending_persist(tmp_path):
     )
     state = _state()
     ckpt.save(1, state)  # persist of step 1 starts (slow)
+    # ... and has started: still queued when 2 and 3 arrive, step 1 is
+    # the oldest entry and the bounded queue drops it (newest wins)
+    assert store.started.wait(30)
     for s in (2, 3):
         ckpt.save(s, state)  # gc would love to remove step-1's file
     ckpt.wait()

@@ -89,6 +89,18 @@ def test_every_rule_has_expected_anchor_entry():
     assert {c.id for c in ALL_RULES} == set(EXPECTED_ANCHORS)
 
 
+def test_what_is_linted_exists():
+    """A deleted file cannot stay wired in: every path the default run
+    collects is a file, and every rule's ``targets`` names a directory
+    or a file of this tree."""
+    assert [p for p in default_files() if not p.is_file()] == []
+    for cls in ALL_RULES:
+        for target in cls.targets:
+            path = REPO_ROOT / target
+            found = path.is_dir() if target.endswith("/") else path.is_file()
+            assert found, f"{cls.id} targets {target}, which is not there"
+
+
 # ------------------------------------------------------------------- gate
 
 

@@ -132,6 +132,10 @@ def test_rowwise_training_learns_on_mesh():
         params, opt_state, loss = trainer.train_step(
             params, opt_state, batch
         )
+        # one step in flight: with the next ones dispatched behind it
+        # the eight device threads of a loaded host can each wait in a
+        # different step's collective, and XLA CPU aborts at 40 s
+        loss.block_until_ready()
         if first is None:
             first = float(loss)
     assert float(loss) < 0.8 * first, (first, float(loss))

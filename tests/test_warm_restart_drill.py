@@ -13,8 +13,7 @@ process-start -> first-step time beat the first's because its jit was
 a disk read (the cache directory the agent wired into the worker env).
 
 The on-chip measurement (1.1B flagship, cold vs warm, real compile
-times) is ``benchmarks/failover_warm.py`` (an earlier chip run, not
-reproduced) and ``chip_smoke.py``'s resume phase; this drill keeps the
+times) is ``chip_smoke.py``'s resume phase; this drill keeps the
 mechanism honest in CI on the CPU backend.
 """
 

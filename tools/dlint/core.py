@@ -139,14 +139,12 @@ class LintResult:
 
 
 def default_files() -> List[Path]:
-    """The production surface the contracts cover: the package plus the
-    bench harness (tests enforce their own contracts on themselves)."""
-    files = sorted(
+    """The production surface the contracts cover: the package (tests
+    enforce their own contracts on themselves)."""
+    return sorted(
         p for p in (REPO_ROOT / "dlrover_tpu").rglob("*.py")
         if "__pycache__" not in p.parts
     )
-    files.append(REPO_ROOT / "bench.py")
-    return files
 
 
 def _assign_fingerprints(findings: List[Finding]) -> None:

@@ -23,8 +23,7 @@ from typing import List, Tuple
 from tools.dlint.core import FileContext, Rule
 
 _EVENT_NAME = re.compile(r"^[a-z0-9_]+(\.[a-z0-9_]+)+$")
-#: span names allow a single undotted segment ("data", "dispatch" —
-#: the bench's train-thread phases predate the dotted convention)
+#: span names allow a single undotted segment
 _SPAN_NAME = re.compile(r"^[a-z0-9_]+(\.[a-z0-9_]+)*$")
 _FRAGMENT = re.compile(r"^[a-z0-9_.]*$")
 
@@ -278,7 +277,7 @@ class SpanNameRule(_LiteralCollector):
     id = "span-names"
     title = "tracing span names are canonical (ISSUE 8)"
     call_name = "span"
-    targets = ("dlrover_tpu/", "bench.py")
+    targets = ("dlrover_tpu/",)
 
     def finalize(self, full_run: bool) -> None:
         for relpath, line, value, kind in self.literals:

@@ -5,8 +5,8 @@ surface — and the easiest thing to let drift. A knob read without a
 default crashes (or silently changes behavior) on a bare environment; a
 knob no doc mentions is a support ticket. This rule:
 
-  * inventories every ``DLROVER_TPU_*`` env read in the package +
-    bench.py (``os.getenv`` / ``os.environ.get`` / ``os.environ[...]``,
+  * inventories every ``DLROVER_TPU_*`` env read in the package
+    (``os.getenv`` / ``os.environ.get`` / ``os.environ[...]``,
     including reads through string constants like
     ``NodeEnv.COORDINATOR_ADDR``);
   * flags reads with no default (justified required-vars go in the
@@ -89,7 +89,7 @@ class KnobRegistryRule(Rule):
     id = "knob-registry"
     title = "every env knob has a default and a documented home"
     interest = (ast.Call, ast.Subscript, ast.Assign)
-    targets = ("dlrover_tpu/", "bench.py")
+    targets = ("dlrover_tpu/",)
 
     def __init__(self):
         super().__init__()
@@ -247,7 +247,7 @@ def render_knobs_md(reads: List[_Read],
         "# Environment knobs",
         "",
         "<!-- GENERATED by `python -m tools.dlint --write-knobs` from",
-        "     the env reads in dlrover_tpu/ + bench.py. Do not edit by",
+        "     the env reads in dlrover_tpu/. Do not edit by",
         "     hand: the `knob-registry` dlint rule diffs this file",
         "     against the code on every tier-1 run. -->",
         "",

@@ -10,7 +10,7 @@ rule:
 
   * inventories every ``counter(...)`` / ``gauge(...)`` /
     ``histogram(...)`` construction whose name literal starts with
-    ``dlrover_`` in the package + bench.py;
+    ``dlrover_`` in the package;
   * flags names that break the ``dlrover_<snake_case>`` shape
     (Prometheus rejects them at scrape time, which is the worst
     possible moment to find out);
@@ -68,7 +68,7 @@ class MetricRegistryRule(Rule):
     id = "metric-registry"
     title = "every dlrover_* metric has a docs/TELEMETRY.md row"
     interest = (ast.Call,)
-    targets = ("dlrover_tpu/", "bench.py")
+    targets = ("dlrover_tpu/",)
 
     def __init__(self):
         super().__init__()
@@ -132,7 +132,7 @@ class MetricRegistryRule(Rule):
             self.report(
                 "docs/TELEMETRY.md", docs[name][1],
                 f"documented metric {name} has no emitter in "
-                "dlrover_tpu/ or bench.py — a renamed or deleted "
+                "dlrover_tpu/ — a renamed or deleted "
                 "metric leaves a dashboard panel that flatlines "
                 "forever; delete the row or restore the emitter",
                 anchor=f"ghost:{name}",

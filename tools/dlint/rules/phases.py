@@ -15,7 +15,7 @@ class GoodputPhaseRule(Rule):
     id = "goodput-phases"
     title = "goodput phase labels are canonical Phase members (PR 7)"
     interest = (ast.Call, ast.Attribute)
-    targets = ("dlrover_tpu/", "bench.py")
+    targets = ("dlrover_tpu/",)
 
     def __init__(self):
         super().__init__()

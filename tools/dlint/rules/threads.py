@@ -15,7 +15,7 @@ class ThreadNameRule(Rule):
     id = "thread-name"
     title = "threading.Thread(...) requires name="
     interest = (ast.Call,)
-    targets = ("dlrover_tpu/", "bench.py")
+    targets = ("dlrover_tpu/",)
 
     def visit(self, node: ast.AST, ctx: FileContext) -> None:
         assert isinstance(node, ast.Call)
