@@ -9,14 +9,17 @@ stdout and in ``chiprun_out/<family>_controls.jsonl``.
 
     python benchmarks/controls.py --cell solar-open2-250b-ep32.steady \
         --seeds 4400000101 4400000102 \
-        [--embed-std 0.02] [--only "no shared expert" ...]
+        [--embed-std 0.02] [--head-std 0.25] \
+        [--only "no shared expert" ...]
 
 With ``--probe N`` instead, for a family with experts: the trainer's
 own step on the cell's weights for N steps, ``routing_stats`` before
 each step (a layer's rows on the held experts, the most loaded expert
 over the mean), with delta-rule layers the least ``alpha`` a layer
-(``kda_decay_min``), each step's seconds and loss: whether the routers
-keep their balance while they train.
+(``kda_decay_min``), with a selection bias its largest magnitude a
+layer (what ``moe_bias_abs_max`` is the most of: 0 unless a rule moves
+it), each step's seconds and loss: whether the routers keep their
+balance while they train.
 
 A number from here is a chip's or it is nothing: the program's loss
 runs the Pallas kernels, and off the TPU the script refuses.
@@ -69,7 +72,9 @@ def probe(args, config, traffic, cfg, platform):
         optimizer=optax.adamw(traffic["optimizer"]["learning_rate"]))
     stats = jax.jit(lambda p, t: (
         llama.routing_stats(p, t, cfg),
-        llama.decay_min(p, t, cfg) if decays else None))
+        llama.decay_min(p, t, cfg) if decays else None,
+        llama.expert_bias_abs_max(p, cfg) if cfg.use_expert_bias
+        else None))
     held = slice(cfg.moe_first_expert_held,
                  cfg.moe_first_expert_held + cfg.moe_experts_held)
     for seed in args.seeds:
@@ -82,7 +87,8 @@ def probe(args, config, traffic, cfg, platform):
             n = traffic["global_batch"]
             tokens, targets = batch_fn(step * n, (step + 1) * n)
             with mesh:
-                counts, least = stats(params, jax.device_put(tokens))
+                counts, least, bias = stats(
+                    params, jax.device_put(tokens))
                 counts = np.asarray(counts)
                 mb = trainer.microbatch((tokens, targets))
                 t0 = time.perf_counter()
@@ -98,11 +104,15 @@ def probe(args, config, traffic, cfg, platform):
             }
             if decays:
                 row["decay_min"] = np.asarray(least).tolist()
+            if bias is not None:
+                row["bias_abs_max"] = np.asarray(bias).tolist()
             rows.append(row)
         write(args, json.dumps({
             "probe": seed, "platform": platform,
             "rehearse": args.rehearse,
-            "embed_std": cfg.embed_init_std, "rows": rows}))
+            "embed_std": cfg.embed_init_std,
+            "head_std": cfg.head_init_std,
+            "rows": rows}))
 
 
 def main():
@@ -110,6 +120,7 @@ def main():
     ap.add_argument("--cell", required=True)
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
     ap.add_argument("--embed-std", type=float, default=None)
+    ap.add_argument("--head-std", type=float, default=None)
     ap.add_argument("--only", nargs="*", default=None)
     ap.add_argument("--probe", type=int, default=0)
     ap.add_argument("--out", default=None,
@@ -132,6 +143,8 @@ def main():
     family = config["family"]
     if args.embed_std is not None:
         config["assumed"]["embed_init_std"] = args.embed_std
+    if args.head_std is not None:
+        config["assumed"]["head_init_std"] = args.head_std
     cfg = worker.program_config(config, traffic)
     if not args.rehearse:
         args.out = args.out or f"chiprun_out/{family}_controls.jsonl"
@@ -173,6 +186,7 @@ def main():
         row = {"control": name, "platform": platform,
                "rehearse": args.rehearse,
                "embed_std": cfg.embed_init_std,
+               "head_std": cfg.head_init_std,
                "tolerance": worker.REFERENCE_TOLERANCE, "readings": {}}
         for seed, params, batch, program in cases:
             if exchanged and "place" in name:

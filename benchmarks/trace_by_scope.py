@@ -41,8 +41,9 @@ SCOPES = (
     "mla.q_down", "mla.kv_down", "mla.up", "attn.latent", "attn.gate",
     "attn.full", "attn.window", "conv.in_proj", "conv.mix",
     "conv.out_proj", "moe.shared", "moe.route", "moe.dispatch",
-    "moe.combine", "moe.experts", "mtp.merge", "mtp.block", "mtp.head",
-    "optimizer", "loss",
+    "moe.combine", "moe.experts", "moe.bias_update", "mtp.merge",
+    "mtp.block", "mtp.head", "norm.post_attn", "norm.post_mlp",
+    "embed.mup", "optimizer", "loss",
 )
 
 

@@ -125,6 +125,14 @@ class LlamaConfig:
     # it, and every token starts on the same k experts (PERF.md
     # section 6, PR 34).
     embed_init_std: float = 0.02
+    # the untied head's, drawn by ``init_params``: None is ``hidden_size
+    # ** -0.5``, logits of unit deviation from a normed stream. A loss
+    # over targets that the input does not predict differs between two
+    # computations by the logits' deviation times how far the streams
+    # lie apart over the root of the tokens: a larger head is what a
+    # comparison of such losses sees more with (PERF.md section 6,
+    # PR 49)
+    head_init_std: Optional[float] = None
     # a stack of two kinds of operator, in the source's keys
     # (``Lfm2MoeConfig``): ``layer_types[l]`` is "full_attention" or
     # "conv", the gated short convolution of ``conv_L_cache`` taps
@@ -209,6 +217,22 @@ class LlamaConfig:
     # a sigmoid gate on full attention's result, elementwise, from the
     # layer's normed input through ``wg`` (``use_gqa_gate``)
     attn_out_gate: bool = False
+    # four norms a block, in the source's keys (``AfmoeDecoderLayer``):
+    # an RMSNorm on each branch's result ahead of the residual sum,
+    # ``x + RMSNorm(attn(RMSNorm(x)))`` and ``x + RMSNorm(ffn(RMSNorm(
+    # x)))``; the leaves ``post_attn_norm`` and ``post_mlp_norm``
+    post_norms: bool = False
+    # the embedding's rows times ``sqrt(hidden_size)`` as they enter
+    # the stream (``mup_enabled``)
+    mup_enabled: bool = False
+    # the rule that moves the selection bias, every step, by the sign
+    # of the load's error (auxiliary-loss-free balancing,
+    # arXiv:2408.15664; ``load_balance_coeff``): 0 leaves the buffer
+    # as it is. ``loss_and_expert_counts`` hands the step's
+    # assignments out beside the loss, ``moved_expert_bias`` applies
+    # the rule to them, and the trainer calls both
+    # (``trainer/sharded.py``)
+    moe_bias_update_rate: float = 0.0
 
     def __post_init__(self):
         if self.remat not in ("off", "dots", "dots_attn_out",
@@ -296,6 +320,13 @@ class LlamaConfig:
                 "qk_nope_head_dim, qk_rope_head_dim and v_head_dim, "
                 "as many kv heads as heads, and no layer pattern or "
                 "norm of whole q and k beside it"
+            )
+        if self.moe_bias_update_rate and not (
+                self.num_experts > 0 and self.use_expert_bias):
+            raise ValueError(
+                f"moe_bias_update_rate {self.moe_bias_update_rate}: the "
+                "rule moves an expert layer's selection bias, which "
+                "use_expert_bias keeps"
             )
         if self.mtp_layers not in (0, 1):
             raise ValueError(
@@ -435,6 +466,27 @@ def llama_linear_tiny(**kw) -> LlamaConfig:
     ), **kw})
 
 
+def llama_sandwich_tiny(**kw) -> LlamaConfig:
+    """Test-sized config with four norms a block and the embedding
+    times ``sqrt(hidden_size)``: a leading dense layer, then a period
+    of gated windowed attention with the rotary embedding and one
+    gated full-attention layer without positions, the heads' norms on
+    q and k, a shared expert beside 8 routed ones by sigmoid score,
+    and the selection bias moved every step by the load's error."""
+    return llama_tiny(**{**dict(
+        num_layers=5, num_dense_layers=1, num_kv_heads=1,
+        sliding_window_size=32, sliding_window_layout=(1, 1, 0, 1, 1),
+        rope_layout=(1, 1, 0, 1, 1), qk_head_norm=True,
+        attn_out_gate=True, post_norms=True, mup_enabled=True,
+        embed_init_std=0.125, num_experts=8, moe_top_k=2,
+        moe_intermediate_size=32, moe_gate="sigmoid",
+        use_expert_bias=True, moe_topk_norm_eps=1e-20,
+        moe_routed_scaling=2.826, moe_shared_experts=1,
+        moe_capacity_factor=0.0, router_aux_loss_coef=0.0,
+        router_z_loss_coef=0.0, moe_bias_update_rate=1e-3,
+    ), **kw})
+
+
 def llama_tiny(**kw) -> LlamaConfig:
     """Test-sized config that still exercises GQA + scan + remat."""
     kw.setdefault("vocab_size", 256)
@@ -459,6 +511,8 @@ def _leaves(cfg: LlamaConfig, kind: LayerKind) -> Dict:
     h, hd = cfg.hidden_size, cfg.head_dim
     nh, nkv = cfg.num_heads, cfg.num_kv_heads
     norms = {"attn_norm": h, "mlp_norm": h}
+    if cfg.post_norms:
+        norms.update(post_attn_norm=h, post_mlp_norm=h)
     if kind.operator == "conv":
         matrices = {
             "conv_in": ((h, 3 * h), ("embed", "mlp")),
@@ -648,7 +702,7 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Dict:
     if not cfg.tie_word_embeddings:
         params["lm_head"] = (
             jax.random.normal(k_out, (h, cfg.vocab_size), jnp.float32)
-            * h ** -0.5
+            * (cfg.head_init_std or h ** -0.5)
         ).astype(cfg.dtype)
     if cfg.mtp_layers:
         k_merge, k_block = jax.random.split(jax.random.fold_in(rng, 3))
@@ -1055,17 +1109,34 @@ def _operator_out(x, out, layer_params, kind: LayerKind,
     return out.reshape(b, s, -1) @ p["wo"]
 
 
+def _past_operator(cfg: LlamaConfig, x, out, layer_params,
+                   kind: LayerKind):
+    """The residual stream past the operator: ``x`` plus the
+    operator's result ``out`` through its output projection and, with
+    ``post_norms``, an RMSNorm (scope ``norm.post_attn``)."""
+    branch = _operator_out(x, out, layer_params, kind, cfg.norm_eps)
+    if cfg.post_norms:
+        with jax.named_scope("norm.post_attn"):
+            branch = rms_norm(
+                branch, layer_params["post_attn_norm"], cfg.norm_eps
+            )
+    return x + branch
+
+
 def _post_attn(cfg: LlamaConfig, x, out, layer_params,
                router_logits=None, constrain=_free, expert_parallel=False,
                kind=LayerKind()):
     """Block segment 2, from the operator's result ``out``: output
-    projection + residual + MLP. ``router_logits``: ``_pre_attn``'s,
-    where the router reads the block's input."""
+    projection + residual + MLP, ``(x, aux, counts)``.
+    ``router_logits``: ``_pre_attn``'s, where the router reads the
+    block's input. ``counts``: where a rule moves the selection bias
+    (``moe_bias_update_rate``) the assignments each of the router's
+    experts received, int32 [experts] (``dropless_moe_mlp``); None
+    without one and for a dense MLP."""
     p = layer_params
-    x = constrain(
-        x + _operator_out(x, out, p, kind, cfg.norm_eps), _RESIDUAL
-    )
+    x = constrain(_past_operator(cfg, x, out, p, kind), _RESIDUAL)
     y = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
+    counts = None
     if kind.ffn == "experts":
         mlp = _expert_mlp(cfg, expert_parallel)
         if router_logits is not None:
@@ -1076,22 +1147,31 @@ def _post_attn(cfg: LlamaConfig, x, out, layer_params,
             mlp = partial(
                 mlp, shared=(p["ws_gate"], p["ws_up"], p["ws_down"])
             )
-        out, aux = mlp(
-            y, p["router"], p["w_gate"], p["w_up"], p["w_down"]
-        )
-        return constrain(x + out, _RESIDUAL), aux
-    gate = jax.nn.silu(constrain(y @ p["w_gate"], _MLP))
-    up = constrain(y @ p["w_up"], _MLP)
-    x = constrain(x + (gate * up) @ p["w_down"], _RESIDUAL)
-    return x, jnp.zeros((), jnp.float32)
+        if cfg.moe_bias_update_rate:
+            out, aux, counts = mlp(
+                y, p["router"], p["w_gate"], p["w_up"], p["w_down"],
+                count=True,
+            )
+        else:
+            out, aux = mlp(
+                y, p["router"], p["w_gate"], p["w_up"], p["w_down"]
+            )
+    else:
+        gate = jax.nn.silu(constrain(y @ p["w_gate"], _MLP))
+        up = constrain(y @ p["w_up"], _MLP)
+        out, aux = (gate * up) @ p["w_down"], jnp.zeros((), jnp.float32)
+    if cfg.post_norms:
+        with jax.named_scope("norm.post_mlp"):
+            out = rms_norm(out, p["post_mlp_norm"], cfg.norm_eps)
+    return constrain(x + out, _RESIDUAL), aux, counts
 
 
 def _block(cfg: LlamaConfig, x, layer_params, cos, sin, operate,
            constrain=_free, expert_parallel=False, kind=LayerKind()):
     """One decoder block of ``kind`` around its operator's call
     ``operate`` (``_operator_of``). x: [batch, seq, hidden]. Returns
-    (x, aux_loss) where aux_loss is the MoE balance loss (0 for
-    dense)."""
+    (x, aux_loss, counts) where aux_loss is the MoE balance loss (0
+    for dense) and counts ``_post_attn``'s."""
     operands, logits = _pre_attn(
         cfg, x, layer_params, cos, sin, constrain, kind
     )
@@ -1224,13 +1304,28 @@ def _dots_policy(cfg: LlamaConfig):
     )
 
 
+def _embed(params, tokens, cfg: LlamaConfig):
+    """The embedding's rows of ``tokens``; with ``mup_enabled`` times
+    ``sqrt(hidden_size)``, in their own dtype (scope ``embed.mup``)."""
+    x = params["embed"][tokens]
+    if cfg.mup_enabled:
+        with jax.named_scope("embed.mup"):
+            x = x * jnp.asarray(math.sqrt(cfg.hidden_size), x.dtype)
+    return x
+
+
 def _run_stack(params, tokens, cfg: LlamaConfig, attn_fn=None,
                constrain=None, expert_parallel: bool = False):
     """``(the residual stream out of the last layer, before the final
     norm; the MoE aux loss; layer_of)``: ``layer_of(kind)`` makes one
     more layer of ``kind`` under the config's remat policy, for a
     prediction module past the stack. The arguments are
-    ``hidden_states``'.
+    ``hidden_states``'. Last, where a rule moves the selection bias
+    (``moe_bias_update_rate``; else None), the assignments each
+    expert received in each scanned layer, int32 [layers, experts],
+    as this one forward pass selected: a layer's body hands them out
+    beside its carry, so a remat's second forward counts nothing
+    again.
 
     ``constrain(x, logical_axes) -> x`` pins the layout of the
     activations between the matmuls (the residual stream, q/k/v, the
@@ -1244,7 +1339,7 @@ def _run_stack(params, tokens, cfg: LlamaConfig, attn_fn=None,
         constrain = _free
     s = tokens.shape[1]
     cos, sin = rope_tables(s, cfg.rope_dim, cfg.rope_theta)
-    x = constrain(params["embed"][tokens], _RESIDUAL)
+    x = constrain(_embed(params, tokens, cfg), _RESIDUAL)
 
     def layer_of(kind):
         """One layer of ``kind`` under the config's remat policy."""
@@ -1252,11 +1347,11 @@ def _run_stack(params, tokens, cfg: LlamaConfig, attn_fn=None,
 
         def body(carry, layer_params):
             x, aux_sum = carry
-            x, aux = _block(
+            x, aux, counts = _block(
                 cfg, x, layer_params, cos, sin, operate, constrain,
                 expert_parallel, kind,
             )
-            return (x, aux_sum + aux), None
+            return (x, aux_sum + aux), counts
 
         if cfg.remat == "dots_attn_out":
             # "dots" remat on the segments AROUND the operator, with
@@ -1281,8 +1376,8 @@ def _run_stack(params, tokens, cfg: LlamaConfig, attn_fn=None,
                 x, aux_sum = carry
                 operands, logits = pre(x, layer_params, cos, sin)
                 out = operate(*operands)
-                x, aux = post(x, out, layer_params, logits)
-                return (x, aux_sum + aux), None
+                x, aux, counts = post(x, out, layer_params, logits)
+                return (x, aux_sum + aux), counts
 
         elif cfg.remat == "dots":
             body = jax.checkpoint(body, policy=_dots_policy(cfg))
@@ -1292,10 +1387,10 @@ def _run_stack(params, tokens, cfg: LlamaConfig, attn_fn=None,
             )
         return body
 
-    (x, aux), _ = _through_layers(
+    (x, aux), counts = _through_layers(
         cfg, layer_of, (x, jnp.zeros((), jnp.float32)), params
     )
-    return constrain(x, _RESIDUAL), aux, layer_of
+    return constrain(x, _RESIDUAL), aux, layer_of, counts
 
 
 def hidden_states(
@@ -1309,7 +1404,7 @@ def hidden_states(
     """Final-norm hidden states [batch, seq, hidden] + MoE aux loss.
     ``expert_parallel``: the experts are sharded over an ``expert``
     mesh axis (the trainer says so from its mesh)."""
-    x, aux, _ = _run_stack(
+    x, aux, _, _ = _run_stack(
         params, tokens, cfg, attn_fn, constrain, expert_parallel
     )
     return rms_norm(x, params["final_norm"], cfg.norm_eps), aux
@@ -1397,28 +1492,33 @@ def _mean_ce(x, head, targets, chunk: int) -> jax.Array:
 
 
 def _mtp_states(cfg: LlamaConfig, params, module, x, ahead, layer_of):
-    """``(normed hidden states, aux)`` of the prediction module
-    ``module``: position i's state ``x[i]`` out of the stack (before
-    the final norm) and the embedding of the token after it,
+    """``(normed hidden states, aux, counts)`` of the prediction
+    module ``module``: position i's state ``x[i]`` out of the stack
+    (before the final norm) and the embedding of the token after it,
     ``ahead[i]``, each normed, side by side through ``eh_proj``; one
-    block of the stack's last kind; the module's own final norm."""
+    block of the stack's last kind; the module's own final norm.
+    ``counts``: its block's, as ``_run_stack``'s."""
     with jax.named_scope("mtp.merge"):
         merged = jnp.concatenate([
-            rms_norm(params["embed"][ahead], module["embed_norm"],
+            rms_norm(_embed(params, ahead, cfg), module["embed_norm"],
                      cfg.norm_eps),
             rms_norm(x, module["hidden_norm"], cfg.norm_eps),
         ], axis=-1) @ module["eh_proj"]
     with jax.named_scope("mtp.block"):
-        (x, aux), _ = layer_of(cfg.layer_plan()[1][-1])(
+        (x, aux), counts = layer_of(cfg.layer_plan()[1][-1])(
             (merged, jnp.zeros((), jnp.float32)), module["block"]
         )
-    return rms_norm(x, module["final_norm"], cfg.norm_eps), aux
+    return rms_norm(x, module["final_norm"], cfg.norm_eps), aux, counts
 
 
-def _losses(params, batch, cfg: LlamaConfig, attn_fn=None,
-            constrain=None, expert_parallel: bool = False):
-    """``(the main head's mean cross entropy, the prediction module's
-    (0 without one), the scaled aux losses of every expert layer)``.
+def _losses_and_counts(params, batch, cfg: LlamaConfig, attn_fn=None,
+                       constrain=None, expert_parallel: bool = False):
+    """``((the main head's mean cross entropy, the prediction module's
+    (0 without one), the scaled aux losses of every expert layer),
+    counts)``. ``counts``: None, or where a rule moves the selection
+    bias the assignments an expert, ``{"stack": [scanned layers,
+    experts], "mtp": [experts]}`` (``_run_stack``; ``mtp`` with a
+    prediction module).
 
     The prediction module reads position i's state and token i + 1
     and is scored, through the model's own head, on token i + 2
@@ -1426,25 +1526,38 @@ def _losses(params, batch, cfg: LlamaConfig, attn_fn=None,
     after it (the roll hands it the first, and its target masks it
     out) and its last two no target."""
     tokens, targets = batch
-    x, aux, layer_of = _run_stack(
+    x, aux, layer_of, counts = _run_stack(
         params, tokens, cfg, attn_fn, constrain, expert_parallel
     )
+    if counts is not None:
+        counts = {"stack": counts}
     normed = rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = _head(params, cfg)
     ce = _mean_ce(normed, head, targets, cfg.loss_chunk)
     if not cfg.mtp_layers:
-        return ce, jnp.zeros((), jnp.float32), aux
+        return (ce, jnp.zeros((), jnp.float32), aux), counts
     (module,) = params["mtp"]
-    y, mtp_aux = _mtp_states(
+    y, mtp_aux, mtp_counts = _mtp_states(
         cfg, params, module, x, jnp.roll(tokens, -1, axis=1), layer_of
     )
+    if counts is not None:
+        counts["mtp"] = mtp_counts
     with jax.named_scope("mtp.head"):
         mtp_ce = _mean_ce(
             y, head,
             jnp.pad(targets[:, 1:], ((0, 0), (0, 1)), constant_values=-1),
             cfg.loss_chunk,
         )
-    return ce, mtp_ce, aux + mtp_aux
+    return (ce, mtp_ce, aux + mtp_aux), counts
+
+
+def _losses(params, batch, cfg: LlamaConfig, attn_fn=None,
+            constrain=None, expert_parallel: bool = False):
+    """``_losses_and_counts``' three losses, one by one (``mtp_loss``
+    and the tests read them apart)."""
+    return _losses_and_counts(
+        params, batch, cfg, attn_fn, constrain, expert_parallel
+    )[0]
 
 
 def next_token_loss(
@@ -1457,12 +1570,75 @@ def next_token_loss(
     = (tokens, targets), both int32 [batch, seq]; target < 0 masks
     the position out. ``constrain``, ``expert_parallel``: see
     ``hidden_states``."""
-    ce, mtp_ce, aux = _losses(
+    return loss_and_expert_counts(
+        params, batch, cfg, attn_fn, constrain, expert_parallel
+    )[0]
+
+
+def loss_and_expert_counts(
+    params: Dict, batch: Tuple[jax.Array, jax.Array], cfg: LlamaConfig,
+    attn_fn=None, constrain=None, expert_parallel: bool = False,
+) -> Tuple[jax.Array, Dict]:
+    """``(next_token_loss, counts)``: beside the loss, where a rule
+    moves the selection bias (``moe_bias_update_rate``; else None),
+    the assignments each expert received in each expert layer, from
+    the same forward pass (``_losses_and_counts``), for
+    ``moved_expert_bias``. What a trainer differentiates with
+    ``has_aux``."""
+    (ce, mtp_ce, aux), counts = _losses_and_counts(
         params, batch, cfg, attn_fn, constrain, expert_parallel
     )
     if cfg.mtp_layers:
         ce = ce + cfg.mtp_loss_weight * mtp_ce
-    return ce + aux  # aux arrives scaled (the config's coefficients)
+    # aux arrives scaled (the config's coefficients)
+    return ce + aux, counts
+
+
+def moved_expert_bias(params: Dict, counts: Dict,
+                      cfg: LlamaConfig) -> Dict:
+    """``params`` with every expert layer's selection bias moved by
+    the rule (``parallel/moe.py moved_bias``, at
+    ``moe_bias_update_rate``) on the step's ``counts``
+    (``loss_and_expert_counts``', summed over the step's
+    microbatches). Scope ``moe.bias_update``."""
+    from dlrover_tpu.parallel.moe import moved_bias
+
+    def moved(layers, counts):
+        return {**layers, "expert_bias": moved_bias(
+            layers["expert_bias"], counts, cfg.moe_bias_update_rate
+        )}
+
+    params = dict(params)
+    with jax.named_scope("moe.bias_update"):
+        stack = counts["stack"]
+        if cfg.by_position:
+            # [periods, positions, experts]: a stack a position
+            per_period = stack.reshape(
+                -1, len(params["period"]), stack.shape[-1]
+            )
+            params["period"] = [
+                moved(layers, per_period[:, i])
+                for i, layers in enumerate(params["period"])
+            ]
+        else:
+            params["blocks"] = moved(params["blocks"], stack)
+        if cfg.mtp_layers:
+            params["mtp"] = [
+                {**module, "block": moved(module["block"], counts["mtp"])}
+                for module in params["mtp"]
+            ]
+    return params
+
+
+def expert_bias_abs_max(params: Dict, cfg: LlamaConfig) -> jax.Array:
+    """The largest magnitude of each scanned expert layer's selection
+    bias, float32 [layers]: how far the rule has moved it."""
+    if cfg.by_position:
+        return jnp.stack([
+            jnp.max(jnp.abs(layers["expert_bias"]), axis=-1)
+            for layers in params["period"]
+        ], axis=1).reshape(-1)
+    return jnp.max(jnp.abs(params["blocks"]["expert_bias"]), axis=-1)
 
 
 def mtp_loss(params: Dict, batch, cfg: LlamaConfig, attn_fn=None):
@@ -1506,13 +1682,13 @@ def _seen_in_layers(params, tokens, cfg: LlamaConfig, attn_fn, see):
             operands, logits = _pre_attn(cfg, x, p, cos, sin, kind=kind)
             out = operate(*operands)
             seen, logits = see(kind, x, p, operands, out, logits)
-            x, _ = _post_attn(cfg, x, out, p, logits, kind=kind)
+            x, _, _ = _post_attn(cfg, x, out, p, logits, kind=kind)
             return x, seen
 
         return body
 
     return _through_layers(
-        cfg, layer_of, params["embed"][tokens], params
+        cfg, layer_of, _embed(params, tokens, cfg), params
     )[1]
 
 
@@ -1529,7 +1705,7 @@ def _routed(params, tokens, cfg: LlamaConfig, attn_fn, stat):
             return None, logits
         if logits is None:
             logits = router_logits(rms_norm(
-                x + _operator_out(x, out, p, kind, cfg.norm_eps),
+                _past_operator(cfg, x, out, p, kind),
                 p["mlp_norm"], cfg.norm_eps,
             ), p["router"])
         return stat(logits, p), logits

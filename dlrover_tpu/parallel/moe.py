@@ -278,7 +278,8 @@ def route_logits(
     the k experts are the top-k of score plus bias and the weights
     the scores there, without it; the bias is a buffer that no
     gradient reaches (a balance kept by moving it is the trainer's
-    to keep, and nothing here moves it). The balance term reads the
+    to keep, by ``moved_bias`` on the step's ``expert_counts``). The
+    balance term reads the
     scores normalised to sum to one over the experts, which a
     softmax's are."""
     probs = GATES[gate](logits)
@@ -305,6 +306,21 @@ def route_logits(
     if scaling != 1.0:
         weights = weights * scaling
     return weights, experts, aux
+
+
+def moved_bias(bias: jax.Array, counts: jax.Array,
+               rate: float) -> jax.Array:
+    """The selection bias after one step of the rule that balances
+    without an auxiliary loss (arXiv:2408.15664, as torchtitan's
+    ``MoEArgs.load_balance_coeff`` applies it ahead of an optimizer
+    step): ``bias + delta``, ``delta = rate x sign(mean(c) - c)``
+    less its own mean, ``c`` the assignments each expert received in
+    the step. ``bias`` float32 [..., E], ``counts`` int [..., E]: a
+    layer a row. An expert under the mean load is raised by ``rate``,
+    one over it lowered, and the bias keeps its mean."""
+    c = counts.astype(jnp.float32)
+    delta = rate * jnp.sign(jnp.mean(c, axis=-1, keepdims=True) - c)
+    return bias + (delta - jnp.mean(delta, axis=-1, keepdims=True))
 
 
 #: the gate's activation in an expert, by the model file's name for it
@@ -577,6 +593,7 @@ def dropless_moe_mlp(
     act: str = "silu",
     first_held: int = 0,
     shared: Tuple[jax.Array, jax.Array, jax.Array] = None,
+    count: bool = False,
     **routing,  # ``route_logits``' gate, bias, norm_eps and scaling
 ) -> Tuple[jax.Array, jax.Array]:
     """MoE gated block (``act(gate) * up``, then down) in which every
@@ -589,7 +606,10 @@ def dropless_moe_mlp(
     token takes, unweighted and whole on every device, added to
     ``out`` (scope ``moe.shared``): of the devices that share a layer
     each computes it for its own tokens, so over the shares it counts
-    once.
+    once. ``count``: ``(out, aux, counts)``, with the assignments
+    each of the router's experts received, held here or not, int32
+    [experts] (``expert_counts`` of the selection): what a rule that
+    moves the selection bias reads (``moved_bias``).
 
     The four scopes name every device op's ``op_name``: ``moe.route``
     (router, softmax, top-k, aux losses), ``moe.dispatch`` (stable
@@ -635,20 +655,24 @@ def dropless_moe_mlp(
             logits.reshape(n, e), k, norm_topk_prob, balance_coef,
             z_coef, **routing,
         )
-    def with_shared(out):
-        if shared is None:
-            return out
-        with jax.named_scope("moe.shared"):
-            ws_gate, ws_up, ws_down = shared
-            return out + (
-                ACTIVATIONS[act](x @ ws_gate) * (x @ ws_up)
-            ) @ ws_down
+        counts = (expert_counts(experts, e),) if count else ()
+
+    def result(out):
+        """``out`` with the shared expert's term, ``aux`` and, asked
+        for, the counts."""
+        if shared is not None:
+            with jax.named_scope("moe.shared"):
+                ws_gate, ws_up, ws_down = shared
+                out = out + (
+                    ACTIVATIONS[act](x @ ws_gate) * (x @ ws_up)
+                ) @ ws_down
+        return (out, aux, *counts)
 
     if held < e:
         out = _share(
             flat, weights, experts, w_gate, w_up, w_down, act, first_held
         )
-        return with_shared(out.reshape(b, s, h)), aux
+        return result(out.reshape(b, s, h))
     with jax.named_scope("moe.dispatch"):
         assigned = experts.reshape(n * k)
         order = jnp.argsort(assigned, stable=True).astype(jnp.int32)
@@ -675,7 +699,7 @@ def dropless_moe_mlp(
     with jax.named_scope("moe.combine"):
         mine = _to_token_order(rows, order, inverse).reshape(n, k, h)
         out = jnp.sum(mine.astype(jnp.float32), axis=1).astype(x.dtype)
-    return with_shared(out.reshape(b, s, h)), aux
+    return result(out.reshape(b, s, h))
 
 
 def tokens_per_expert(
@@ -855,3 +879,23 @@ def set_bias_changed_gauge(changed, assignments: int) -> float:
         "tokens x k, at the last evaluation",
     ).set(share)
     return share
+
+
+def set_bias_abs_max_gauge(magnitudes) -> float:
+    """From the largest magnitude of each expert layer's selection
+    bias (``models.llama.expert_bias_abs_max``) set the gauge
+    ``moe_bias_abs_max``: the most over the layers. 0 while the bias
+    is the zeros it starts at; how far a rule that moves it
+    (``moved_bias``) has taken it, against scores that lie in (0, 1)
+    under a sigmoid gate."""
+    import numpy as np
+
+    from dlrover_tpu.telemetry.registry import gauge
+
+    most = float(np.max(np.asarray(magnitudes, dtype=np.float64)))
+    gauge(
+        "moe_bias_abs_max",
+        "largest magnitude of the router's selection bias over the "
+        "expert layers, at the last evaluation",
+    ).set(most)
+    return most

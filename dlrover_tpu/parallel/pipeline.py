@@ -372,9 +372,10 @@ def pipeline_llama_forward(
         )
 
     def block_fn(x, layer_params):
+        # (x, aux): a stage moves no selection bias, the counts go unread
         return llama._block(
             cfg, x, layer_params, cos, sin, attn_fn, kind=kind
-        )
+        )[:2]
 
     # honor the config's activation-checkpointing policy per block, same
     # as the un-pipelined llama.forward. "dots_attn_out" maps to "dots"

@@ -43,6 +43,13 @@ class ShardedTrainer:
         the optimizer leaves as they are: its update there is
         dropped, weight decay with it (a buffer kept among the
         parameters, such as a router's selection bias).
+      move_buffers: ``(loss_and_seen, move)``, a rule of the model's
+        own for such buffers (a selection bias moved by the step's
+        load). ``loss_and_seen(params, batch) -> (loss, seen)`` is
+        differentiated in ``loss_fn``'s place, ``seen`` summed over
+        the step's microbatches, and ``move(params, seen) -> params``
+        applied with the optimizer's update. Not beside a
+        ``value_and_grad`` of the caller's own.
     """
 
     def __init__(
@@ -57,7 +64,14 @@ class ShardedTrainer:
         batch_extra_axes: Tuple[Optional[str], ...] = ("seq",),
         value_and_grad: Optional[Callable] = None,
         frozen: Any = None,
+        move_buffers: Optional[Tuple[Callable, Callable]] = None,
     ):
+        if move_buffers is not None and value_and_grad is not None:
+            raise ValueError(
+                "move_buffers brings the function that is "
+                "differentiated (loss_and_seen): give no "
+                "value_and_grad beside it"
+            )
         self.mesh = mesh
         self.rules = shd.get_rules(strategy)
         self.strategy = strategy
@@ -69,6 +83,7 @@ class ShardedTrainer:
         # sharpness-aware double evaluation
         self._value_and_grad = value_and_grad
         self._frozen = frozen
+        self._move_buffers = move_buffers
         self.param_shardings = shd.tree_shardings(
             axes_tree, mesh, self.rules
         )
@@ -155,11 +170,21 @@ class ShardedTrainer:
         if self._jit_step is not None:
             return self._jit_step
 
-        grad_fn = self._value_and_grad or jax.value_and_grad(
-            self._loss_fn
-        )
         accum = self.accum_steps
         gshard = self._grad_shardings
+        if self._move_buffers is not None:
+            loss_and_seen, move = self._move_buffers
+            grad_fn = jax.value_and_grad(loss_and_seen, has_aux=True)
+        else:
+            # one form for both: nothing seen beside the loss
+            move = None
+            plain_grad_fn = self._value_and_grad or jax.value_and_grad(
+                self._loss_fn
+            )
+
+            def grad_fn(params, mb):
+                loss, grads = plain_grad_fn(params, mb)
+                return (loss, None), grads
 
         def constrain_grads(grads):
             if gshard is None:
@@ -184,7 +209,7 @@ class ShardedTrainer:
             # update and its application
             if accum == 1:
                 with jax.named_scope("loss"):
-                    loss, grads = grad_fn(
+                    (loss, seen), grads = grad_fn(
                         params, jax.tree.map(lambda x: x[0], batch)
                     )
                 grads = constrain_grads(grads)
@@ -193,19 +218,21 @@ class ShardedTrainer:
                 def micro(carry, mb):
                     loss_sum, grads_sum = carry
                     with jax.named_scope("loss"):
-                        loss, grads = grad_fn(params, mb)
+                        (loss, seen), grads = grad_fn(params, mb)
                     grads = constrain_grads(grads)
                     return (
                         loss_sum + loss,
                         jax.tree.map(jnp.add, grads_sum, grads),
-                    ), None
+                    ), seen
 
                 zeros = constrain_grads(jax.tree.map(
                     lambda p: jnp.zeros(p.shape, jnp.float32), params
                 ))
-                (loss_sum, grads_sum), _ = jax.lax.scan(
+                (loss_sum, grads_sum), seen = jax.lax.scan(
                     micro, (jnp.zeros(()), zeros), batch
                 )
+                # a microbatch a row (None where nothing is seen)
+                seen = jax.tree.map(lambda a: jnp.sum(a, axis=0), seen)
                 loss = loss_sum / accum
                 # summed in f32, handed on in the params' dtype: the
                 # optimizer then sees what it sees without accumulation
@@ -226,6 +253,8 @@ class ShardedTrainer:
                         updates, self._frozen,
                     )
                 params = optax.apply_updates(params, updates)
+                if move is not None:
+                    params = move(params, seen)
             return params, opt_state, loss
 
         self._jit_step = jax.jit(
@@ -319,10 +348,25 @@ def make_trainer_for_llama(
         # a dropless config on such a mesh is refused here, before
         # anything is traced
         llama._expert_mlp(cfg, expert_parallel)
-    loss = lambda params, batch: llama.next_token_loss(  # noqa: E731
-        params, batch, cfg, attn_fn=attn_fn, constrain=constrain,
+    model = dict(
+        cfg=cfg, attn_fn=attn_fn, constrain=constrain,
         expert_parallel=expert_parallel,
     )
+    loss = lambda params, batch: llama.next_token_loss(  # noqa: E731
+        params, batch, **model
+    )
+    move_buffers = None
+    if cfg.moe_bias_update_rate:
+        # the rule that moves the selection bias reads the assignments
+        # of the step's own forward pass: they leave beside the loss
+        move_buffers = (
+            lambda params, batch: llama.loss_and_expert_counts(
+                params, batch, **model
+            ),
+            lambda params, counts: llama.moved_expert_bias(
+                params, counts, cfg
+            ),
+        )
     init = lambda rng: llama.init_params(rng, cfg)  # noqa: E731
     logger.info(
         "ShardedTrainer: %s params=%.1fM mesh=%s strategy=%s accum=%d",
@@ -332,5 +376,5 @@ def make_trainer_for_llama(
     return ShardedTrainer(
         loss, init, llama.param_axes(cfg), mesh, strategy=strategy,
         optimizer=optimizer, accum_steps=accum_steps,
-        frozen=llama.frozen_params(cfg),
+        frozen=llama.frozen_params(cfg), move_buffers=move_buffers,
     )

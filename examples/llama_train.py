@@ -35,7 +35,8 @@ from dlrover_tpu.models import llama
 from dlrover_tpu.ops import tuning
 from dlrover_tpu.parallel.mesh import create_mesh
 from dlrover_tpu.parallel.moe import (
-    set_bias_changed_gauge, set_chunks_walked_gauge,
+    set_bias_abs_max_gauge, set_bias_changed_gauge,
+    set_chunks_walked_gauge,
     set_expert_load_gauges, set_rows_held_gauge, set_sums_visited_gauge,
 )
 from dlrover_tpu.trainer.checkpoint import FlashCheckpointer
@@ -52,6 +53,7 @@ MODELS = {
     "llama_moe_tiny": llama.llama_moe_tiny,
     "llama_latent_tiny": llama.llama_latent_tiny,
     "llama_linear_tiny": llama.llama_linear_tiny,
+    "llama_sandwich_tiny": llama.llama_sandwich_tiny,
 }
 
 
@@ -302,6 +304,10 @@ def main():
                             mb[0][0].size * cfg.moe_top_k,
                         )
                         line += f" bias_changed={changed:.3f}"
+                        moved = set_bias_abs_max_gauge(
+                            llama.expert_bias_abs_max(params, cfg)
+                        )
+                        line += f" bias_abs_max={moved:.4f}"
                     print(line, flush=True)
                 if mtp_loss is not None:
                     # the prediction module's own term of the loss

@@ -115,8 +115,9 @@ MODULE_BUDGET_OVERRIDES = {
     # and latent attention's kernels on its parts: 394s alone; since
     # PR 44 solar's four-layer step at the least effort, 55-75s of
     # its own, which is all the budget gains: 562s beside five other
-    # workers
-    "test_chip_compile": 830.0,
+    # workers; since PR 49 trinity's nine-layer step at the least
+    # effort, 70s of its own
+    "test_chip_compile": 930.0,
     # Pallas kernels in interpret mode, since PR 39 the in-place sum
     # against megablox's on seven pieces: 47s alone, 71s beside five
     # other workers
@@ -189,6 +190,16 @@ MODULE_BUDGET_OVERRIDES = {
     # under three remat policies: 75 s alone, 206 s beside five other
     # workers (PR 44)
     "test_yardstick_solar": 400.0,
+    # fourteen changed references jitted at the tiny size on two
+    # batches, nine layers each, the program under three remat
+    # policies: 75 s alone (PR 49)
+    "test_yardstick_trinity": 300.0,
+    # nine-layer trainers jitted and stepped, the reference's counts
+    # beside each step: 60 s alone (PR 49)
+    "test_moe_bias_rule": 200.0,
+    # five tiny families' training steps lowered, three of them run
+    # once: 50 s alone (PR 49)
+    "test_llama_static_path": 150.0,
     "test_context_parallel": 180.0,
     # since PR 42 the kernels at latent attention's (192, 128) too,
     # one backward kernel and the pair: 195 s alone, 272 s beside five

@@ -1205,6 +1205,78 @@ def test_solar_step_holds_the_delta_rules_kernels(
     assert tuning.last_selection()["gqa_group"] == 8
 
 
+#: ``peak_memory_in_bytes`` of ``trinity-mini-ep8.steady``'s step as
+#: this file compiles it (1 x 16,384, nine layers, remat ``minimal``,
+#: the least effort; PERF.md, PR 49): 7.46 GB of it the state. At the
+#: default effort it read 15,546,647,040
+TRINITY_STEP_BYTES = 15_488_046_080
+
+
+def test_trinity_step_fits_and_moves_the_bias(
+    topo, on_tpu_path, monkeypatch
+):
+    """``trinity-mini-ep8.steady``'s step (1 x 16,384, a leading dense
+    layer and two periods of [window, full, window, window] with 16 of
+    128 experts held, remat ``minimal``): it fits the chip's 16.91 GB
+    and plans no more than was read when the cell was built; every
+    layer's attention is the Pallas kernels, as the benchmark's
+    ``attn_kernel_ms`` tells them by name, a period's four positions
+    the forward, the forward again and the one backward kernel of a
+    group of eight each, under ``attn.window`` or ``attn.full``; the
+    held share is walked in chunks of 10,240 rows by the kernels
+    ``moe_expert_ms`` knows; and the four scopes this configuration
+    brought are in the compiled step's ``op_name``s, the bias rule
+    inside the one program."""
+    from dlrover_tpu.ops import grouped_matmul as gm
+    from yardstick import cells, worker
+    from yardstick.layer_metrics import attn_kernel_ms, moe_expert_ms
+
+    monkeypatch.setattr(gm, "_use_pallas", lambda lhs, rhs: True)
+    monkeypatch.setattr(gm, "_interpret", lambda: False)
+    monkeypatch.setattr(gm, "_add_on_mxu", lambda out, rows: True)
+    _, config, traffic = cells.load_cell("trinity-mini-ep8.steady")
+    cfg = worker.program_config(config, traffic)
+    assert (cfg.remat, cfg.loss_chunk) == ("minimal", 0)
+    assert (cfg.moe_experts_held, cfg.num_experts) == (16, 128)
+    assert cfg.moe_bias_update_rate == 0.001
+    mesh = Mesh(
+        np.array(topo.devices[:1]).reshape(1, 1), ("data", "fsdp"))
+    trainer = make_trainer_for_llama(
+        cfg, mesh, strategy=traffic["strategy"],
+        optimizer=optax.adamw(traffic["optimizer"]["learning_rate"]),
+    )
+    compiled = trainer.train_step.lower(*_abstract_step_args(
+        trainer, traffic["global_batch"], traffic["seq"])
+    ).compile(LEAST_EFFORT)
+    planned = compiled.memory_analysis().peak_memory_in_bytes
+    print("trinity step plans", planned)
+    assert planned <= TRINITY_STEP_BYTES < 16.91e9
+    text = compiled.as_text()
+    kernels = re.findall(
+        r"%([\w.\-]+) = ([^\n]*?) custom-call\([^\n]*"
+        r"custom_call_target=\"tpu_custom_call\"[^\n]*"
+        r"op_name=\"([^\"]*)\"", text)
+    attn = [(name, op) for name, _, op in kernels
+            if attn_kernel_ms.KERNEL.search(name)]
+    # the leading layer and the period's four positions, each the
+    # forward, the forward again and the one backward kernel
+    assert fa._one_backward_kernel(8, 16384, 128)
+    assert len(attn) == 5 * 3, [name for name, _ in attn]
+    windowed = [op for _, op in attn if "attn.window" in op]
+    full = [op for _, op in attn if "attn.full" in op]
+    assert (len(windowed), len(full)) == (4 * 3, 3)
+    experts = [name for name, result, _ in kernels
+               if moe_expert_ms.KERNEL.search(name)]
+    assert len(experts) >= 4 * 9
+    rows, _ = moe.walk_chunks(
+        traffic["seq"] * cfg.moe_top_k, cfg.hidden_size)
+    assert rows == 10240 and f"bf16[{rows},1024]" in text
+    for scope in ("embed.mup", "norm.post_attn", "norm.post_mlp",
+                  "moe.bias_update", "attn.gate", "moe.shared"):
+        assert f"{scope}" in text, scope
+    assert tuning.last_selection()["gqa_group"] == 8
+
+
 def test_fsdp_step_lowers_over_four_chips(topo, on_tpu_path):
     """Full widths, depth cut to two layers, the mesh
     examples/llama_train.py builds on a four-chip host."""

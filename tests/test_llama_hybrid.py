@@ -305,7 +305,7 @@ def _loop_over_layers(params, batch, cfg):
             "dense" if i < cfg.num_dense_layers else "experts")
         operate = (short_conv.gated_short_conv if operator == "conv"
                    else mha_reference)
-        x, aux = llama._block(cfg, x, p, cos, sin, operate, kind=kind)
+        x, aux, _ = llama._block(cfg, x, p, cos, sin, operate, kind=kind)
         aux_sum = aux_sum + aux
     x = llama.rms_norm(x, params["final_norm"], cfg.norm_eps)
     nll, count = llama._masked_nll(

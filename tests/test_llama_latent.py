@@ -294,7 +294,7 @@ def _loss_by_hand(params, batch, cfg):
         (kind, jax.tree.map(lambda a: a[i], params["period"][0]))
         for i in range(cfg.num_layers - 1)]
     for layer_kind, p in layers:
-        x, aux = llama._block(
+        x, aux, _ = llama._block(
             cfg, x, p, cos, sin,
             llama._operator_of(cfg, mha_reference, layer_kind),
             kind=layer_kind)
@@ -314,7 +314,7 @@ def _loss_by_hand(params, batch, cfg):
         llama.rms_norm(params["embed"][ahead], m["embed_norm"], cfg.norm_eps),
         llama.rms_norm(x, m["hidden_norm"], cfg.norm_eps),
     ], axis=-1) @ m["eh_proj"]
-    y, aux = llama._block(
+    y, aux, _ = llama._block(
         cfg, merged, m["block"], cos, sin,
         llama._operator_of(cfg, mha_reference, kind), kind=kind)
     further = jnp.concatenate(
@@ -358,7 +358,7 @@ def test_the_module_is_scored_one_token_further_on():
     # the first's embedding) scores the same on every counted one:
     # attention is causal and the routing dropless
     (module,) = params["mtp"]
-    x, _, layer_of = llama._run_stack(params, tokens, cfg)
+    x, _, layer_of, _ = llama._run_stack(params, tokens, cfg)
     ahead = jnp.roll(tokens, -1, axis=1)
     states = [
         llama._mtp_states(cfg, params, module, x, fed, layer_of)[0]

@@ -252,7 +252,7 @@ def _loop_over_layers(params, batch, cfg):
         kind = period[l % len(period)]
         p = jax.tree.map(
             lambda a: a[l // len(period)], params["period"][l % len(period)])
-        x, layer_aux = llama._block(
+        x, layer_aux, _ = llama._block(
             cfg, x, p, None, None, llama._operator_of(cfg, attn, kind),
             kind=kind)
         aux = aux + layer_aux
@@ -449,7 +449,7 @@ def test_decay_min_reads_the_least_alpha_a_layer():
     x = params["embed"][tokens]
     kind = cfg.layer_plan()[1][0]
     attn = lambda q, k, v: mha_reference(q, k, v, causal=True)  # noqa: E731
-    x, _ = llama._block(
+    x, _, _ = llama._block(
         cfg, x, jax.tree.map(lambda a: a[0], params["period"][0]), None,
         None, llama._operator_of(cfg, attn, kind), kind=kind)
     y = llama.rms_norm(x, p["attn_norm"], cfg.norm_eps)

@@ -148,7 +148,7 @@ def _loop_over_layers(params, batch, cfg):
         p = jax.tree.map(lambda a: a[i], params["blocks"])
         window = (cfg.sliding_window_size
                   if cfg.sliding_window_layout[i] else None)
-        x, aux = llama._block(
+        x, aux, _ = llama._block(
             cfg, x, p, cos, sin,
             lambda q, k, v: mha_reference(q, k, v, window=window),
             kind=llama.LayerKind(
