@@ -28,7 +28,8 @@ import jax.numpy as jnp
 
 from dlrover_tpu.ops.attention import flash_attention
 from dlrover_tpu.ops.delta_rule import gated_delta_rule_rows
-from dlrover_tpu.ops.short_conv import causal_taps, gated_short_conv
+from dlrover_tpu.ops.kda_conv import conv_silu_norm, heads_apart
+from dlrover_tpu.ops.short_conv import gated_short_conv
 
 
 class LayerKind(NamedTuple):
@@ -889,57 +890,17 @@ def _pre_attn(cfg: LlamaConfig, x, layer_params, cos, sin,
     return (q, k, v), logits()
 
 
-def _causal_conv_silu(x, w):
-    """``silu`` of a causal depthwise convolution along the sequence:
-    ``x`` [batch, seq, channels], ``w`` [channels, taps], a channel's
-    taps oldest first, nothing before a sequence's first position.
-    Shifted multiply-adds in float32, as ``gated_short_conv_plain``
-    has them."""
-    return jax.nn.silu(causal_taps(x.astype(jnp.float32), w))
-
-
-#: added to a head's sum of squares before the root, in ``_l2norm``
-L2_NORM_EPS = 1e-6
-
-
-def _l2norm(x):
-    """``x`` [..., d] float32 over its last axis's length."""
-    return x * jax.lax.rsqrt(
-        jnp.sum(x * x, axis=-1, keepdims=True) + L2_NORM_EPS
-    )
-
-
-#: rows of a float32 tile on the TPU: ``[s, columns]`` lies in tiles of
-#: (8, 128)
-_TILE_ROWS = 8
-
-
-def _heads_apart(x, heads):
-    """Rows ``x`` [b, s, heads x d] with a head's columns an axis of
-    their own, for a reduction over one head: ``[b, s / 8, 8, heads,
-    d]`` (``[b, s, 1, heads, d]`` where 8 does not divide ``s``). The
-    eight positions are there for the TPU's sake: a float32 ``[s,
-    heads x 128]`` lies in tiles of (8 rows, 128 columns), which this
-    shape names axis by axis, so the compiler takes the reshape for
-    the same bytes and the reduction and the multiply by its result
-    join the fusions on either side. ``[b, s, heads, d]`` tiles
-    (heads, d): other bytes, a pass over the array each way and the
-    factor written out at full width between them (PERF.md, PR 45)."""
-    b, s, width = x.shape
-    rows = _TILE_ROWS if s % _TILE_ROWS == 0 else 1
-    return x.reshape(b, s // rows, rows, heads, width // heads)
-
-
 def _delta_rule_operands(cfg: LlamaConfig, y, p, constrain=_free):
     """The gated delta rule's operands from the normed stream ``y``,
     in rows, as the projections write them and the scan's kernels
     read them: ``(q, k, v [b, s, heads x d], g [b, s, heads x d]
     float32, beta [b, s, heads] float32, the output gate's
     pre-activation [b, s, heads x d])``. A head shows only inside
-    ``_heads_apart``, for q's and k's l2 norm. The scopes name every
-    op: ``kda.proj`` the three projections, the two low ranks and the
-    step size's; ``kda.conv`` the convolutions with ``silu`` and the
-    l2 norms; ``kda.decay`` the log decay and the step size."""
+    ``ops/kda_conv.py conv_silu_norm``, for q's and k's l2 norm. The
+    scopes name every op: ``kda.proj`` the three projections, the two
+    low ranks and the step size's; ``kda.conv`` the convolutions with
+    ``silu`` and the l2 norms (one operator, on the TPU one Pallas
+    pass each way); ``kda.decay`` the log decay and the step size."""
     heads, d = cfg.linear_num_heads, cfg.linear_head_dim
     with jax.named_scope("kda.proj"):
         q, k, v = (constrain(y @ p[w], _MLP) for w in ("wq", "wk", "wv"))
@@ -947,15 +908,11 @@ def _delta_rule_operands(cfg: LlamaConfig, y, p, constrain=_free):
         gate = (y @ p["g_a"]) @ p["g_b"]
         step = y @ p["w_beta"]
     with jax.named_scope("kda.conv"):
-        q, k, v = (
-            _causal_conv_silu(x, p[w])
-            for x, w in ((q, "conv_q"), (k, "conv_k"), (v, "conv_v"))
-        )
         q, k = (
-            _l2norm(_heads_apart(x, heads)).reshape(x.shape).astype(y.dtype)
-            for x in (q, k)
+            conv_silu_norm(x, p[w], l2_heads=heads)
+            for x, w in ((q, "conv_q"), (k, "conv_k"))
         )
-        v = v.astype(y.dtype)
+        v = conv_silu_norm(v, p["conv_v"])
     with jax.named_scope("kda.decay"):
         # a head's rate at each of its d columns: of the weights' size
         rate = jnp.repeat(jnp.exp(p["A_log"]), d)
@@ -1098,7 +1055,7 @@ def _operator_out(x, out, layer_params, kind: LayerKind,
             ).astype(o.dtype)
             heads = o.shape[-1] // p["o_norm"].shape[-1]
             o = rms_norm(
-                _heads_apart(o, heads), p["o_norm"], norm_eps
+                heads_apart(o, heads), p["o_norm"], norm_eps
             ).reshape(o.shape)
             return (o * gate) @ p["wo"]
     if isinstance(out, tuple):  # full attention and its gate's logits

@@ -21,6 +21,8 @@ import json
 import math
 import os
 import re
+import subprocess
+import sys
 import threading
 
 import numpy as np
@@ -757,11 +759,16 @@ def test_olmoe_step_keeps_megabloxs_tgmm(topo, on_tpu_path, monkeypatch):
     the chip (not compiled): with every expert held the layer is one
     pass, the matrices' gradients are megablox's ``tgmm`` writing
     every group once (no ``existing_out``), three a scanned layer,
-    and the in-place kernel of a share's walk is not on its path."""
+    and the in-place kernel of a share's walk is not on its path; nor
+    is a line of a delta-rule layer's convolutions (PERF.md, PR 51):
+    the lowering loads no module of theirs and counts no call."""
     import importlib
 
     from dlrover_tpu.ops import grouped_matmul as gm
     from yardstick import cells, worker
+
+    monkeypatch.delitem(sys.modules, KDA_CONV_KERNELS, raising=False)
+    convs = _kda_conv_calls()
 
     megablox = importlib.import_module(
         "jax.experimental.pallas.ops.tpu.megablox.gmm")
@@ -788,6 +795,77 @@ def test_olmoe_step_keeps_megabloxs_tgmm(topo, on_tpu_path, monkeypatch):
         trainer, traffic["global_batch"], traffic["seq"])).as_text()
     assert "tpu_custom_call" in text
     assert len(sums) == 3 and all(s is None for s in sums), sums
+    assert KDA_CONV_KERNELS not in sys.modules
+    assert _kda_conv_calls() == convs
+
+
+#: the module that holds a delta-rule layer's convolutions' kernels,
+#: which only the branch that takes them imports
+#: (``ops/kda_conv.py conv_silu_norm``)
+KDA_CONV_KERNELS = "dlrover_tpu.ops.pallas.kda_conv"
+
+
+def _kda_conv_calls():
+    from dlrover_tpu.telemetry.registry import counter
+
+    return [counter(f"kda_conv_{path}_calls", "").value
+            for path in ("kernel", "plain")]
+
+
+#: a fresh process, as a cell's worker is: the tiny configuration's
+#: step built and lowered through the trainer, then what was loaded
+#: and counted
+_LOWER_A_TINY_STEP = """
+import json, os, sys
+import jax, optax
+from dlrover_tpu.parallel.mesh import create_mesh
+from dlrover_tpu.telemetry.registry import counter
+from dlrover_tpu.trainer.sharded import make_trainer_for_llama
+from yardstick import cells, worker
+name, traffic = sys.argv[1], json.loads(sys.argv[2])
+with open(os.path.join(cells.HERE, "configs", name + ".json")) as f:
+    cfg = worker.program_config(json.load(f), traffic)
+trainer = make_trainer_for_llama(
+    cfg, create_mesh([("data", 1), ("fsdp", 1)], devices=jax.devices()[:1]),
+    optimizer=optax.adamw(1e-3))
+state = trainer.init(jax.random.key(0))
+tokens = worker.SeededTokens(3, traffic["seq"], cfg.vocab_size)(0, 2)
+text = trainer.train_step.lower(*state, trainer.microbatch(tokens)).as_text()
+print(json.dumps({
+    "linear": "linear_attention" in (cfg.layer_types or ()),
+    "loaded": sorted(m for m in sys.modules if m.endswith("kda_conv")),
+    "calls": [counter(f"kda_conv_{path}_calls", "").value
+              for path in ("kernel", "plain")],
+    "text": len(text)}))
+"""
+
+
+@pytest.mark.parametrize("name,linear", [
+    ("tiny-olmoe", False), ("tiny-llama", False), ("tiny-lfm2", False),
+    ("tiny-solar", True),
+])
+def test_only_a_delta_rule_layer_reaches_its_convolutions(name, linear):
+    """A configuration without a linear-attention layer builds and
+    lowers its training step, in a process of its own, without
+    loading the convolutions' kernels' module and without moving
+    either of the entry's counters: no cell but ``solar``'s runs a
+    line of what PR 51 adds beside ``ops/kda_conv.py``'s import, which
+    is three plain functions (``lfm2``'s gated convolution is another
+    module's). ``tiny-solar`` is the control: it counts its calls, on
+    the plain path off the TPU, and still loads no kernel."""
+    from .test_moe_bias_rule import TRAFFIC
+
+    out = subprocess.run(
+        [sys.executable, "-c", _LOWER_A_TINY_STEP, name,
+         json.dumps(TRAFFIC)],
+        capture_output=True, text=True, timeout=600, check=False,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert out.returncode == 0, out.stderr[-2000:]
+    said = json.loads(out.stdout.splitlines()[-1])
+    assert said["linear"] is linear and said["text"] > 0
+    assert said["loaded"] == ["dlrover_tpu.ops.kda_conv"]
+    assert (said["calls"][1] > 0) is linear and said["calls"][0] == 0
 
 
 def test_llama_1b_step_fits_one_chip_at_batch_3(topo, on_tpu_path):
@@ -1066,11 +1144,12 @@ def test_joyai_step_holds_no_whole_q_or_k(topo, on_tpu_path, monkeypatch):
 
 #: ``peak_memory_in_bytes`` of ``solar-open2-250b-ep32.steady``'s step
 #: as this file compiles it (1 x 8,192, four layers, remat ``minimal``,
-#: the least effort; PERF.md, PR 45): 8.5 GB of it the state. With the
+#: the least effort; PERF.md, PR 51): 8.5 GB of it the state. With the
 #: delta-rule layers' heads an axis of their own between the
 #: projections and the kernels (PR 44) it read 16,240,236,032: the
-#: norms' factors at full width and the relayouts' copies
-SOLAR_STEP_BYTES = 15_315_505_664
+#: norms' factors at full width and the relayouts' copies; in rows with
+#: the convolutions as plain float32 ops (PR 45) 15,315,505,664
+SOLAR_STEP_BYTES = 14_462_113_280
 #: what a delta-rule layer's q, k, v, g or o is as rows, as heads, and
 #: as the tiles of rows that the compiler names ``[s / 8, 8, heads, d]``
 SOLAR_ROWS = re.compile(
@@ -1109,7 +1188,12 @@ def test_solar_step_holds_the_delta_rules_kernels(
     named as the benchmark's ``delta_rule_ms`` tells them, and as
     neither the attention's, the experts' nor the convolution's
     readers do (a compiled step's instruction names are a device
-    trace's), and carry ``kda.scan``; the 4096 x 1280 experts' products
+    trace's), and carry ``kda.scan``; the convolutions, ``silu`` and
+    l2 norms of q, k and v are twenty-seven Pallas calls under
+    ``kda.conv`` by a jitted name of their own, ``kda_conv``, which no
+    reader's pattern matches, every call of the entry took the kernels,
+    and no fusion on a ``[1, 8192, 8192]`` array is left under that
+    scope; the 4096 x 1280 experts' products
     and in-place float32 sums take the tiles the rule gives them;
     every fusion on a ``[1, 8192, 8192]`` array says whose op it is;
     and a delta-rule layer stays in rows from its projections to
@@ -1119,9 +1203,10 @@ def test_solar_step_holds_the_delta_rules_kernels(
     a trace shows without an ``op_name``), and the nine calls were
     handed rows and built with the most heads a grid step that the
     kernels' rule has, which divides the cell's 64."""
-    from dlrover_tpu.ops import delta_rule, grouped_matmul as gm
+    from dlrover_tpu.ops import delta_rule, grouped_matmul as gm, kda_conv
     from dlrover_tpu.telemetry.registry import counter, gauge
     from dlrover_tpu.ops.pallas import delta_rule as scan_kernels
+    from dlrover_tpu.ops.pallas import kda_conv as conv_kernels
     from yardstick import cells, worker
     from yardstick.layer_metrics import (
         attn_kernel_ms, delta_rule_ms, moe_expert_ms, short_conv_ms,
@@ -1132,8 +1217,14 @@ def test_solar_step_holds_the_delta_rules_kernels(
     monkeypatch.setattr(gm, "_add_on_mxu", lambda out, rows: True)
     monkeypatch.setattr(delta_rule, "_use_pallas", lambda q, heads: True)
     monkeypatch.setattr(scan_kernels, "_interpret", lambda: False)
+    monkeypatch.setattr(
+        kda_conv, "_use_pallas", lambda x, w, l2_heads: (
+            conv_kernels.tiles_the_kernel(x.shape, w.shape, l2_heads)))
+    monkeypatch.setattr(conv_kernels, "_interpret", lambda: False)
     calls = [counter(f"delta_rule_{handed}_calls", "")
              for handed in ("rows", "folded")]
+    calls += [counter(f"kda_conv_{path}_calls", "")
+              for path in ("kernel", "plain")]
     before = [c.value for c in calls]
     gauge("delta_rule_heads_per_step", "").set(0)
     _, config, traffic = cells.load_cell("solar-open2-250b-ep32.steady")
@@ -1180,6 +1271,15 @@ def test_solar_step_holds_the_delta_rules_kernels(
     assert fa._one_backward_kernel(8, 8192, 128)
     assert sum(bool(moe_expert_ms.KERNEL.search(n)) for n in others) >= 9
     assert not any(short_conv_ms.KERNEL.search(n) for n in others)
+    # q, k and v at three linear positions of the period, each the
+    # forward, the forward again and the backward: under ``kda.conv``,
+    # by the jitted name no reader goes by
+    conv = [name for name, _, op in kernels if "kda.conv" in op]
+    assert len(conv) == 3 * 3 * 3, conv
+    assert all(name.startswith("kda_conv") for name in conv)
+    assert not any(
+        reader.KERNEL.search(name) for name in conv for reader in (
+            attn_kernel_ms, delta_rule_ms, moe_expert_ms, short_conv_ms))
     # the backward's entry states: [batch, heads, chunks, 128, 128]
     assert "f32[1,64,128,128,128]" in text
     wide = re.compile(r"= \w+\[1,8192,8192\]")
@@ -1189,7 +1289,18 @@ def test_solar_step_holds_the_delta_rules_kernels(
         and "kda." not in line and "attn." not in line
     ]
     assert not unscoped
-    assert [c.value - was for c, was in zip(calls, before)] == [9, 0]
+    # nothing is left at full width under ``kda.conv`` beside the kernels
+    # (36 fusions when the convolutions were plain ops; the eighteen still
+    # there turn the taps and their gradients, ``f32[4, 8192]``)
+    left = [
+        line.split(" = ")[0].strip() for line in text.splitlines()
+        if SOLAR_ROWS.search(line.split("fusion(")[0])
+        and "fusion(" in line and "kda.conv" in line
+    ]
+    assert not left, left
+    # nine scans on rows and none folded; nine calls of the
+    # convolutions' entry (q, k, v a linear position), none plain
+    assert [c.value - was for c, was in zip(calls, before)] == [9, 0, 9, 0]
     together = gauge("delta_rule_heads_per_step", "").value
     assert together == max(scan_kernels.HEADS_A_STEP) > 1
     assert gauge(

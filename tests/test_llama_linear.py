@@ -16,11 +16,13 @@ import optax
 import pytest
 
 from dlrover_tpu.models import llama
-from dlrover_tpu.ops import delta_rule
+from dlrover_tpu.ops import delta_rule, kda_conv
 from dlrover_tpu.ops.attention import mha_reference
 from dlrover_tpu.ops.pallas import delta_rule as kernels
 from dlrover_tpu.parallel.mesh import create_mesh
 from dlrover_tpu.trainer.sharded import make_trainer_for_llama
+
+from .test_kda_conv import _calls as _conv_calls
 
 REMATS = ("off", "dots", "dots_attn_out", "minimal")
 PERIOD = ("full_attention",) + ("linear_attention",) * 3
@@ -217,6 +219,26 @@ def test_the_operator_is_the_equations():
     # the step size reaches past one, and every log decay is negative
     assert 1.0 < float(operands[4].max()) < 2.0
     assert float(operands[3].max()) < 0
+    # the convolutions' entry alone: four taps a position at a time,
+    # silu, and with heads each head's 16 columns over their length
+    x, w = normed @ p["wk"], np.asarray(p["conv_k"])
+    a = np.zeros(x.shape, np.float32)
+    for pos in range(32):
+        for j in range(4):
+            if pos - 3 + j >= 0:
+                a[:, pos] += w[:, j] * np.asarray(x)[:, pos - 3 + j]
+    s = a / (1 + np.exp(-a))
+    np.testing.assert_allclose(
+        kda_conv.conv_silu_norm(x, p["conv_k"]), s, rtol=1e-5, atol=1e-6)
+    heads = s.reshape(2, 32, 4, 16)
+    unit = heads / np.sqrt((heads * heads).sum(-1, keepdims=True) + 1e-6)
+    np.testing.assert_allclose(
+        kda_conv.conv_silu_norm(x, p["conv_k"], l2_heads=4),
+        unit.reshape(2, 32, 64), rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(operands[1], unit.reshape(2, 32, 64),
+                               rtol=1e-5, atol=1e-6)
+    with pytest.raises(ValueError, match="taps of 64 channels"):
+        kda_conv.conv_silu_norm(x[..., :32], p["conv_k"])
 
 
 def test_the_gate_on_attention_is_elementwise_from_the_normed_input():
@@ -298,13 +320,24 @@ def test_the_model_runs_the_kernels_where_they_tile(monkeypatch):
     batch = _batch(cfg, shape=(1, 128))
     step = jax.value_and_grad(llama.next_token_loss)
     want, want_g = step(params, batch, cfg)
-    calls = []
+    calls, convs = [], []
     monkeypatch.setattr(
         delta_rule, "_use_pallas",
         lambda q, heads: calls.append((q.shape, heads)) or True)
+    monkeypatch.setattr(
+        kda_conv, "_use_pallas",
+        lambda x, w, l2_heads: convs.append(
+            (x.shape, w.shape, l2_heads)) or True)
+    before = _conv_calls()
     got, got_g = step(params, batch, cfg)
     # rows, as the projections wrote them
     assert calls and set(calls) == {((1, 128, 256), 2)}
+    # q and k with their heads, v without, at each of three layers;
+    # every one on the kernels' path
+    assert sorted(convs, key=str) == sorted(
+        3 * [((1, 128, 256), (256, 4), 2)] * 2
+        + 3 * [((1, 128, 256), (256, 4), None)], key=str)
+    assert _conv_calls() == (before[0] + 9, before[1])
     assert abs(float(got) - float(want)) < 1e-5
     for a, b in zip(jax.tree.leaves(got_g), jax.tree.leaves(want_g)):
         np.testing.assert_allclose(a, b, rtol=3e-4, atol=3e-6)
@@ -342,7 +375,7 @@ def test_the_steps_scans_are_handed_rows(monkeypatch):
 @pytest.mark.parametrize("seq", [32, 30], ids=["tiles", "no tiles"])
 @pytest.mark.parametrize("norm", ["l2", "rms"])
 def test_a_heads_norm_in_rows_is_the_norm_on_heads(norm, seq):
-    """``_heads_apart``'s view under ``_l2norm`` and ``rms_norm``
+    """``heads_apart``'s view under ``l2norm`` and ``rms_norm``
     against the same on ``[b, s, heads, d]``, the result and the
     gradients to 1e-6; a sequence that 8 does not divide is viewed
     position by position."""
@@ -351,20 +384,20 @@ def test_a_heads_norm_in_rows_is_the_norm_on_heads(norm, seq):
     scale = jax.random.uniform(jax.random.key(4), (d,), minval=0.5,
                                maxval=1.5)
     cotangent = jax.random.normal(jax.random.key(5), x.shape)
-    assert llama._heads_apart(x, heads).shape == (
+    assert kda_conv.heads_apart(x, heads).shape == (
         (2, 4, 8, heads, d) if seq == 32 else (2, 30, 1, heads, d))
 
     def normed(view):
         def fn(x, scale):
             y = view(x)
-            y = (llama._l2norm(y) if norm == "l2"
+            y = (kda_conv.l2norm(y) if norm == "l2"
                  else llama.rms_norm(y, scale, 1e-5))
             return jnp.sum(y.reshape(x.shape) * cotangent)
 
         return jax.value_and_grad(fn, argnums=(0, 1))(x, scale)
 
     want, want_g = normed(lambda x: x.reshape(2, seq, heads, d))
-    got, got_g = normed(lambda x: llama._heads_apart(x, heads))
+    got, got_g = normed(lambda x: kda_conv.heads_apart(x, heads))
     assert abs(float(got) - float(want)) < 1e-6 * seq
     for a, b in zip(got_g, want_g):
         np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
@@ -387,7 +420,7 @@ def test_the_operators_operands_are_rows():
     assert o.shape == (2, 32, 64) and passed is gate
 
 
-def test_every_new_op_carries_its_scope():
+def test_every_new_op_carries_its_scope(monkeypatch):
     cfg = _linear()
     text = jax.jit(llama.next_token_loss, static_argnums=2).lower(
         _init(cfg), _batch(cfg), cfg).as_text(debug_info=True)
@@ -398,6 +431,18 @@ def test_every_new_op_carries_its_scope():
         _init(plain), _batch(plain), plain).as_text(debug_info=True)
     for scope in SCOPES[:-1]:
         assert scope not in text, scope
+    # where the kernels take the convolutions, the jitted name that a
+    # device trace calls them by stands under ``kda.conv`` wherever
+    # the lowered step names it, as the plain ops did
+    tiled = _linear(num_layers=4, layer_types=PERIOD, rope_layout=(0,) * 4,
+                    linear_num_heads=2, linear_head_dim=128,
+                    max_seq_len=128)
+    monkeypatch.setattr(kda_conv, "_use_pallas", lambda x, w, h: True)
+    text = jax.jit(jax.grad(llama.next_token_loss), static_argnums=2).lower(
+        _init(tiled), _batch(tiled, shape=(1, 128)), tiled
+    ).as_text(debug_info=True)
+    named = re.findall(r'loc\("([^"]*jit\(kda_conv\)[^"]*)"', text)
+    assert named and all("kda.conv" in name for name in named), named
 
 
 def test_routing_stats_walk_both_kinds_of_layer():
