@@ -17,10 +17,19 @@ And the rule's kernels with the backward the other way than
 in VMEM a head's dQ without a group, a kv head's dK and dV with one:
 how that rule's edges were read.
 A shape with a window (``smallthinker``: 16,384 positions, 28 query
-heads on 4, the window 4096) is timed at the rule's blocks, both
-backwards, with the window and without it: what the blocks skipped
-under the band's lower edge save (``"window"`` in the row). Its batch
-of one sequence runs ``--layers`` calls like the others.
+heads on 4, the window 4096; ``trinity-mini``: 32 on 4, 2048) is timed
+at the rule's blocks with the rule's backward: without the window,
+and with it at every edge of ``--tiles`` for the column tiles in which
+the forward and the backward kernels take a block that an edge of the
+band crosses (``flash_attention._window_tile``; ``whole`` is the
+block: the band's compact grid and nothing else; ``rule`` the file's
+own edge for each kernel), at ``block_k = 512`` whole blocks (the
+control that adds no body), and once with the other backward at the
+rule's tiles (``"window"`` and ``"window_tile"`` in the row). A tree
+from before the band's grid has no such rule: run it with ``--tiles
+whole`` and its rows are its own kernels (``--label`` names the tree
+in every row). Its batch of one sequence runs ``--layers`` calls like
+the others.
 Each timed call runs ``--layers`` attention calls in one ``lax.scan``
 so that the host's clock times tens of milliseconds. Only a TPU run
 says anything: ``chiprun -- python3 benchmarks/profile_attn_subtiles.py``.
@@ -53,9 +62,10 @@ SHAPES = {
     "mistral": (3, 4096, 32, 8, 128),
     "smallthinker": (1, 16384, 28, 4, 128),
     "lfm2": (4, 8192, 32, 8, 64),
+    "trinity-mini": (1, 16384, 32, 4, 128),
 }
 #: the window of a shape's windowed layers
-WINDOWS = {"smallthinker": 4096}
+WINDOWS = {"smallthinker": 4096, "trinity-mini": 2048}
 
 
 def timeit(fn, *args, n=10, warmup=2):
@@ -94,6 +104,8 @@ def main(argv=None):
     ap.add_argument("--shapes", default=",".join(SHAPES))
     ap.add_argument("--subs", default="128,256,512")
     ap.add_argument("--blocks", default="512x512,256x256")
+    ap.add_argument("--tiles", default="whole,rule,512,256,128")
+    ap.add_argument("--label", default=None)
     ap.add_argument("--layers", type=int, default=8)
     ap.add_argument("--n", type=int, default=10)
     ap.add_argument("--out", default="chiprun_out/attn_subtiles.jsonl")
@@ -109,6 +121,9 @@ def main(argv=None):
     os.makedirs(os.path.dirname(args.out), exist_ok=True)
     rng = np.random.default_rng(0)
     rule, one_kernel = fa._sub_tiles, fa._one_backward_kernel
+    tile_rule = getattr(fa, "_window_tile", None)
+    tiles = [None if t == "whole" else t if t == "rule" else int(t)
+             for t in args.tiles.split(",") if t]
     whole = {"fwd": None, "dq": None, "dkv": None}
     both = ("fwd_ms", "fwd_bwd_ms")
     for name in args.shapes.split(","):
@@ -136,12 +151,19 @@ def main(argv=None):
             for kernel in whole for sub in subs
             if sub < max(blocks) and group == 1
         ]
-        windows = [None]
-        if name in WINDOWS:  # the rule's kernels, without and with it
-            settings, windows = settings[1:3], [None, WINDOWS[name]]
-        for ((block_q, block_k), edges, kernels, keys), window in (
-            (setting, window) for setting in settings for window in windows
-        ):
+        # (setting, window, the windowed tile's edge: None the block,
+        # "rule" the file's own)
+        runs = [(setting, None, "rule") for setting in settings]
+        if name in WINDOWS:
+            window = WINDOWS[name]
+            ruled_blocks = (blocks, None, ruled, both)
+            runs = [(ruled_blocks, None, "rule")] + [
+                (ruled_blocks, window, tile) for tile in tiles
+            ] + [
+                (((blocks[0], 512), None, ruled, both), window, None),
+                ((blocks, None, 3 - ruled, both[1:]), window, "rule"),
+            ]
+        for ((block_q, block_k), edges, kernels, keys), window, tile in runs:
             fa._sub_tiles = rule if edges is None else (
                 lambda kernel, bq, bk, g, d: fa._fits(  # noqa: B023
                     edges[kernel], g, bq, bk
@@ -150,6 +172,11 @@ def main(argv=None):
             fa._one_backward_kernel = (
                 lambda g, seq, d: kernels == 1  # noqa: B023
             )
+            if tile_rule is not None:
+                fa._window_tile = tile_rule if tile == "rule" else (
+                    lambda kernel, bk: (  # noqa: B023
+                        tile if tile and bk > tile else bk)  # noqa: B023
+                )
             fns = dict(zip(
                 both, _stack(args.layers, block_q, block_k, window)
             ))
@@ -157,7 +184,9 @@ def main(argv=None):
                 "shape": name, "blocks": [block_q, block_k],
                 **(edges or {"rule": True}),
                 "backward_kernels": kernels,
-                **({"window": window} if name in WINDOWS else {}),
+                **({"window": window, "window_tile": tile}
+                   if name in WINDOWS else {}),
+                **({"tree": args.label} if args.label else {}),
             }
             try:
                 for key in keys:

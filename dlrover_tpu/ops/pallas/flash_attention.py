@@ -81,18 +81,32 @@ the kernels it always got: the helpers below are the plain read and
 the plain write for a ref that is no tuple.
 
 A window (``window``, static: query i sees key j iff ``j <= i`` and
-``i - j < window``) is a second edge, under the diagonal. A grid block
-wholly under it is skipped as one above the diagonal is: not computed
-(``pl.when``) and not fetched (the index maps are clamped at both ends
-of a row's or column's live blocks). A block that either edge crosses
-takes the mask of both; one between them is one product without a
-mask. A windowed call takes whole blocks: the sub-tile walk above was
-settled without a group and without a window, and no cell runs a
-window without a group.
+``i - j < window``) is a second edge, under the diagonal, and a
+windowed call computes the band and nothing else (``_Band``, the one
+statement of its geometry). Its grid's minor dimension counts the
+blocks of the band, not of the sequence: step n of a query block is
+the n-th key block from the first that holds a key it sees (3 steps
+at a window of 2048 in (128, 1024) blocks and 5 at 4096, where 16
+blocks tile 16,384 positions), the index maps and the kernels reading
+the block from the same expression; the few short rows at the
+sequence's start re-reference the block on the diagonal and compute
+nothing there. The grid by key blocks is the mirror image. A block
+between the edges is one product without a mask. A block that the
+diagonal or the window's edge crosses is one masked product over its
+live column tiles (``_window_tile``: 512 wide in the backward
+kernels, the whole block in the forward), the leading ones of a block
+on the diagonal and the trailing ones of a block on the window's
+edge, at a static width: a kernel holds one body for each run of
+tiles that the call's geometry has (``_Band.crossed_tiles``: three at
+the two shapes above) and a step takes its own. Rows are not cut, so a
+group folds as ever. As read on a v5e (PERF.md section 6, PR 52), a
+call of 32 query heads on 4 with the window 2048, forward and backward:
+22.81 ms with the sequence's grid and whole blocks, 20.20 with the
+band's grid, 18.50 with the backward's tiles as well.
 """
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -249,6 +263,123 @@ def _band(q0, rows, k0, cols, window):
     return live, whole
 
 
+# ``min`` and ``max`` of block arithmetic that is Python's at trace
+# time (the grid, the census) and traced in an index map or a kernel
+
+def _least(a, b):
+    return min(a, b) if isinstance(a, int) else jnp.minimum(a, b)
+
+
+def _most(a, b):
+    return max(a, b) if isinstance(a, int) else jnp.maximum(a, b)
+
+
+def _window_tile(kernel, block_k):
+    """The edge of the column tiles in which a windowed ``kernel``
+    ("fwd", or "bwd": each of the backward's) takes a key block that
+    an edge of the band crosses; the block where it takes it whole, as
+    one masked product.
+
+    As read on a v5e at (128, 1024) blocks, groups of 8 and 7 and
+    windows of 2048 and 4096 of 16,384 positions
+    (``benchmarks/profile_attn_subtiles.py`` and the two cells' traced
+    steps; PERF.md section 6, PR 52). The forward reads level at
+    every edge (6.68 ms a call whole, 6.74 at 512, 6.71 at 256: its
+    step is bound by what its 1,024 rows cost, the reductions, the
+    rescale and the state's traffic, not by its columns), so it keeps
+    the one masked body. The backward has no such cost a row and
+    gains with the columns it leaves out: 13.7 ms a call -> 9.8 at 512
+    in ``trinity-mini``'s step and 16.7 -> 13.7 in ``smallthinker``'s.
+    At 256 the kernel alone reads a further 0.5 to 0.9 ms less, but in
+    both cells' steps 1.7 to 2.0 ms MORE than at 512 (11.6 and 15.7),
+    and at 128 (fifteen masked bodies) 6 times the whole block's
+    time: past some size of the kernel's program every body more
+    costs, and a body is as long as its columns. So the widest edge
+    that tiles a block in two."""
+    edge = None if kernel == "fwd" else 512
+    if edge is None or block_k % edge or block_k <= edge:
+        return block_k
+    return edge
+
+
+class _Band(NamedTuple):
+    """A windowed call's grid and what a grid step computes, from
+    ``(seq, block_q, block_k, window)`` and the column tile's edge
+    alone: the one statement of both, for the index maps, the kernels
+    and the census. Block indices are Python ints or traced values.
+
+    The grid's minor dimension counts the blocks of the band, not of
+    the sequence: step ``n`` of query block ``i`` is key block
+    ``first_key_block(i) + n`` (of key block ``j``, query block
+    ``first_query_block(j) + n``), and a step past the row's (the
+    column's) last live block computes nothing and fetches nothing new
+    (the index maps clamp it to the last)."""
+
+    seq: int
+    block_q: int
+    block_k: int
+    window: int
+    tile: int
+
+    def first_key_block(self, i):
+        """The block of the first key that query block ``i`` sees."""
+        return _most(i * self.block_q - self.window + 1, 0) // self.block_k
+
+    def last_key_block(self, i):
+        """The block on the diagonal."""
+        return (i * self.block_q + self.block_q - 1) // self.block_k
+
+    def first_query_block(self, j):
+        return (j * self.block_k) // self.block_q
+
+    def last_query_block(self, j):
+        """The block of the last query that sees key block ``j``'s
+        last key, or the sequence's last."""
+        return _least(
+            (j * self.block_k + self.block_k + self.window - 2)
+            // self.block_q,
+            self.seq // self.block_q - 1,
+        )
+
+    def key_steps(self):
+        """The most key blocks a query block's band meets."""
+        return max(
+            self.last_key_block(i) - self.first_key_block(i) + 1
+            for i in range(self.seq // self.block_q)
+        )
+
+    def query_steps(self):
+        """The most query blocks a key block's band meets."""
+        return max(
+            self.last_query_block(j) - self.first_query_block(j) + 1
+            for j in range(self.seq // self.block_k)
+        )
+
+    def tiles(self, q_start, k_start):
+        """``(first, end)``: the column tiles of the key block at
+        ``k_start`` in which some query of the block at ``q_start``
+        sees a key (of a live block)."""
+        first = _most(q_start - self.window + 1 - k_start, 0) // self.tile
+        end = (_least(q_start + self.block_q - k_start, self.block_k)
+               + self.tile - 1) // self.tile
+        return first, end
+
+    def crossed_tiles(self):
+        """``tiles`` of every live block that an edge of the band
+        crosses, each once: the static widths a kernel holds a body
+        of."""
+        found = set()
+        for i in range(self.seq // self.block_q):
+            q_start = i * self.block_q
+            for j in range(self.first_key_block(i),
+                           self.last_key_block(i) + 1):
+                k_start = j * self.block_k
+                if not _band(q_start, self.block_q, k_start,
+                             self.block_k, self.window)[1]:
+                    found.add(self.tiles(q_start, k_start))
+        return sorted(found)
+
+
 def causal_tile_census(seq, block_q, block_k, sub_q, sub_k, window=None):
     """Over one head's causal [seq, seq] scores, in (sub_q, sub_k)
     sub-tiles: (sub-tiles the live grid blocks cover, which is what a
@@ -270,14 +401,16 @@ def causal_tile_census(seq, block_q, block_k, sub_q, sub_k, window=None):
     return covered, computed, masked
 
 
-def _set_census_gauges(kernel, seq, block_q, block_k, sub, window):
+def _set_census_gauges(kernel, seq, block_q, block_k, sub_q, sub_k, window,
+                       grid_steps):
     """Where a causal kernel is built (trace time): what share of the
-    sub-tiles its live blocks cover it computes, and how many of them
-    an edge of the band crosses."""
+    (sub_q, sub_k) sub-tiles its live blocks cover it computes, how
+    many of them an edge of the band crosses, and what share of a
+    head's ``grid_steps`` meet a live block."""
     from dlrover_tpu.telemetry.registry import gauge
 
     covered, computed, masked = causal_tile_census(
-        seq, block_q, block_k, sub or block_q, sub or block_k, window
+        seq, block_q, block_k, sub_q, sub_k, window
     )
     labels = dict(kernel=kernel, window=str(window or "none"))
     gauge(
@@ -292,6 +425,13 @@ def _set_census_gauges(kernel, seq, block_q, block_k, sub, window):
         "over the same",
         labelnames=("kernel", "window"),
     ).labels(**labels).set(masked / covered)
+    live_blocks = covered // ((block_q // sub_q) * (block_k // sub_k))
+    gauge(
+        "attn_grid_steps_live_share",
+        "grid steps of a head that meet a live block over all of its "
+        "grid steps, at the last causal attention kernel built",
+        labelnames=("kernel", "window"),
+    ).labels(**labels).set(live_blocks / grid_steps)
 
 
 def _rows_of(r0, size, block_q):
@@ -330,12 +470,12 @@ def _row_reduce(reduce, x):
 
 
 def _walk(causal, block_q, block_k, sub, q_start, k_start, compute,
-          window=None):
+          band=None):
     """What one grid step computes, as calls of
     ``compute(r0, size, cols, diagonal)``: the ``size`` query positions
-    of the block from ``r0`` on against the block's leading key
-    positions ``cols``, in one product; ``diagonal`` is None, or the
-    product's first (query, key) positions where it takes the mask.
+    of the block from ``r0`` on against the block's key positions
+    ``cols``, in one product; ``diagonal`` is None, or the product's
+    first (query, key) positions where it takes the mask.
 
     Without ``causal``, one call over the block. With it, a block
     wholly above the diagonal is skipped. Where ``sub`` is None every
@@ -344,10 +484,15 @@ def _walk(causal, block_q, block_k, sub, q_start, k_start, compute,
     mask, and the one on it is one call a row of sub-tiles of edge
     ``sub``, over the row's live column tiles: n + 1 in row n.
 
-    With a ``window`` (whole blocks only): a block wholly outside the
-    band is skipped, one wholly inside it is one call without the
-    mask, and one that the diagonal or the window's edge crosses is
-    one call with it."""
+    With a ``band`` (a window): a block wholly outside the band is
+    skipped, one wholly inside it is one call without the mask, and
+    one that the diagonal or the window's edge crosses is one masked
+    call over its live column tiles (``_Band.tiles``: the leading ones
+    of a block on the diagonal, the trailing ones of a block on the
+    window's edge), at a static width: one body for each run of tiles
+    that the call's geometry holds (``_Band.crossed_tiles``), picked
+    by the step's own run. A width from ``program_id`` or a rolled
+    loop over the tiles reads 1.3 to 4 times slower (PR 31)."""
 
     def whole(masked):
         compute(0, block_q, slice(None),
@@ -355,11 +500,32 @@ def _walk(causal, block_q, block_k, sub, q_start, k_start, compute,
 
     if not causal:
         return whole(False)
-    if window is not None:
-        live, inside = _band(q_start, block_q, k_start, block_k, window)
-        pl.when(inside)(lambda: whole(False))
-        crossed = jnp.logical_and(live, jnp.logical_not(inside))
-        return pl.when(crossed)(lambda: whole(True))
+    if band is not None:
+        live, inside = _band(
+            q_start, block_q, k_start, block_k, band.window
+        )
+        # a step past a key block's last query block (the grid by key
+        # blocks, at the sequence's end) is no block at all
+        within = q_start < band.seq
+        pl.when(jnp.logical_and(inside, within))(lambda: whole(False))
+        crossed = jnp.logical_and(
+            jnp.logical_and(live, jnp.logical_not(inside)), within
+        )
+        runs = band.crossed_tiles()
+        tiles = block_k // band.tile
+        if runs == [(0, tiles)]:
+            return pl.when(crossed)(lambda: whole(True))
+        first, end = band.tiles(q_start, k_start)
+        for a, b in runs:
+            cols = slice(None) if (a, b) == (0, tiles) else slice(
+                a * band.tile, b * band.tile)
+            pl.when(jnp.logical_and(
+                crossed, jnp.logical_and(first == a, end == b)
+            ))(functools.partial(
+                compute, 0, block_q, cols,
+                (q_start, k_start + a * band.tile),
+            ))
+        return None
     live = q_start + block_q - 1 >= k_start
     if sub is None:
         return pl.when(live)(lambda: whole(True))
@@ -385,14 +551,28 @@ def _walk(causal, block_q, block_k, sub, q_start, k_start, compute,
         jax.lax.fori_loop(0, n_tiles, row_tile, None, unroll=True)
 
 
+def _key_block(band, i, j):
+    """The key block of step ``j`` of query block ``i``'s row of the
+    grid: the j-th of the sequence, with a band the j-th of the row's
+    band."""
+    return j if band is None else band.first_key_block(i) + j
+
+
+def _query_block(band, j, i):
+    """The query block of step ``i`` of key block ``j``'s row."""
+    return i if band is None else band.first_query_block(j) + i
+
+
 # ---------------------------------------------------------------------------
 # forward
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, scale, causal, g,
-                block_q, block_k, sub, window=None):
+                block_q, block_k, sub, band=None):
     i = pl.program_id(1)  # q block
-    j = pl.program_id(2)  # k block (minor: sequential, scratch persists)
+    # k block (minor: sequential, scratch persists); with a band, the
+    # row's j-th live one
+    j = pl.program_id(2)
     nk = pl.num_programs(2)
 
     @pl.when(j == 0)
@@ -402,13 +582,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     q_start = i * block_q
-    k_start = j * block_k
+    k_start = _key_block(band, i, j) * block_k
 
     def compute(r0, size, cols, diagonal):
         rows = _rows_of(r0, size, block_q)
         s = _scores(
             _stack_groups(q_ref, g, rows), _read(k_ref, (0, cols)), scale,
-            g, diagonal, window,
+            g, diagonal, band and band.window,
         )
         m_prev = m_scr[rows, :1]  # [g*size, 1]
         m_new = jax.lax.max(m_prev, _row_reduce(jax.lax.reduce_max, s))
@@ -431,7 +611,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_scr[rows] = jnp.broadcast_to(l_new, lanes)
 
     _walk(causal, block_q, block_k, sub, q_start, k_start, compute,
-          window)
+          band)
 
     @pl.when(j == nk - 1)
     def _finalize():
@@ -455,12 +635,33 @@ def _check_blocks(seq, block_q, block_k):
         raise ValueError(f"block_q must be a power of two, got {block_q}")
 
 
+def _band_of(kernel, seq, block_q, block_k, window):
+    """The geometry of a windowed call of ``kernel`` ("fwd" or "bwd");
+    None without a window."""
+    if window is None:
+        return None
+    return _Band(
+        seq, block_q, block_k, window, _window_tile(kernel, block_k)
+    )
+
+
+def _grid(seq, block_q, block_k, band, by_key_blocks=False):
+    """A head's grid: its query blocks by the key blocks each meets
+    (all of them, or a band's), or the other way about."""
+    nq, nk = seq // block_q, seq // block_k
+    if by_key_blocks:
+        return nk, (nq if band is None else band.query_steps())
+    return nq, (nk if band is None else band.key_steps())
+
+
 def _kernel(body, name, seq, causal, g, block_q, block_k, q, scale,
-            window=None):
-    """``body`` ("fwd", "dq", "dkv", "dqkv" or "dq_dkv" by ``name``)
-    with its static arguments, for ``q`` whole or in parts; building
-    one sets the gauge of the parts, a causal one the census gauges. A
-    windowed one takes whole blocks (``_walk``)."""
+            band=None):
+    """``(kernel, grid)``: ``body`` ("fwd", "dq", "dkv", "dqkv" or
+    "dq_dkv" by ``name``) with its static arguments, for ``q`` whole
+    or in parts, and a head's grid; building one sets the gauge of the
+    parts, a causal one the census gauges. A windowed one walks its
+    band in column tiles (``_walk``), another the diagonal in
+    ``_sub_tiles``."""
     from dlrover_tpu.telemetry.registry import gauge
 
     gauge(
@@ -471,24 +672,31 @@ def _kernel(body, name, seq, causal, g, block_q, block_k, q, scale,
         labelnames=("kernel",),
     ).labels(kernel=name).set(len(_each(q)))
     head_dim = _width(q)
+    grid = _grid(seq, block_q, block_k, band, name in ("dkv", "dqkv"))
     sub = None
     if causal:
-        if window is None:
+        if band is None:
             sub = _sub_tiles(name, block_q, block_k, g, head_dim)
-        _set_census_gauges(name, seq, block_q, block_k, sub, window)
+            tile = (sub or block_q, sub or block_k)
+        else:
+            tile = (block_q, band.tile)
+        _set_census_gauges(
+            name, seq, block_q, block_k, *tile, band and band.window,
+            grid[0] * grid[1],
+        )
     static = dict(
         scale=scale, causal=causal, g=g,
         block_q=block_q, block_k=block_k, sub=sub,
     )
-    if window is not None:
-        static["window"] = window
-    return functools.partial(body, **static)
+    if band is not None:
+        static["band"] = band
+    return functools.partial(body, **static), grid
 
 
-def _kv_index(causal, block_q, block_k, window=None):
+def _kv_index(causal, block_q, block_k, band=None):
     """K/V block index for grid step (b, i, j), clamped to the
-    diagonal and, with a window, up to the first block that holds a
-    key the q block's first query sees.
+    diagonal; with a band, step j is the j-th block from the first
+    that holds a key the q block's first query sees.
 
     A causally SKIPPED (j, i) step computes nothing (pl.when), but the
     pipeline would still stream its K/V block from HBM — dead traffic
@@ -498,12 +706,10 @@ def _kv_index(causal, block_q, block_k, window=None):
     change, so skipped steps cost no bandwidth."""
 
     def index(b, i, j):
+        j = _key_block(band, i, j)
         if causal:
             diag = (i * block_q + block_q - 1) // block_k
             j = jnp.minimum(j, diag)
-        if window is not None:
-            first = jnp.maximum(i * block_q - window + 1, 0) // block_k
-            j = jnp.maximum(j, first)
         return (b, j, 0)
 
     return index
@@ -548,15 +754,15 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, window=None):
     block_q = min(block_q, seq)
     block_k = min(block_k, seq)
     _check_blocks(seq, block_q, block_k)
-    grid = (bkh, seq // block_q, seq // block_k)
-    kernel = _kernel(
+    band = _band_of("fwd", seq, block_q, block_k, window)
+    kernel, grid = _kernel(
         _fwd_kernel, "fwd", seq, causal, g, block_q, block_k, q, scale,
-        window,
+        band,
     )
-    kv_idx = _kv_index(causal, block_q, block_k, window)
+    kv_idx = _kv_index(causal, block_q, block_k, band)
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(bkh, *grid),
         in_specs=[
             _specs(q, (g, block_q), lambda b, i, j: (b, 0, i, 0)),
             _k_specs(k, bkh, block_k, kv_idx),
@@ -630,10 +836,10 @@ def _ds(p, do, v, delta):
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                acc_scr, *, scale, causal, g, block_q, block_k, sub,
-               add_dkv=None, window=None):
-    """``add_dkv(p, ds, q, do)``, where given, takes the softmax and
-    each dS the walk forms (cast for the products) on to dV and dK:
-    ``_dq_dkv_kernel``."""
+               add_dkv=None, band=None):
+    """``add_dkv(cols, p, ds, q, do)``, where given, takes the softmax
+    and each dS the walk forms (cast for the products) of the block's
+    key positions ``cols`` on to dV and dK: ``_dq_dkv_kernel``."""
     i = pl.program_id(1)
     j = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -643,7 +849,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     q_start = i * block_q
-    k_start = j * block_k
+    k_start = _key_block(band, i, j) * block_k
 
     def compute(r0, size, cols, diagonal):
         rows = _rows_of(r0, size, block_q)
@@ -651,7 +857,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             q_ref, do_ref, lse_ref, delta_ref, g, rows
         )
         k = _cols_of(k_ref, cols)
-        p = _p(q, lse, k(), scale, g, diagonal, window)
+        p = _p(q, lse, k(), scale, g, diagonal, band and band.window)
         ds = jax.lax.convert_element_type(
             _ds(p, do, v_ref[0, cols], delta), q.dtype
         )
@@ -660,10 +866,10 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             preferred_element_type=jnp.float32,
         )
         if add_dkv is not None:
-            add_dkv(p, ds, q, do)
+            add_dkv(cols, p, ds, q, do)
 
     _walk(causal, block_q, block_k, sub, q_start, k_start, compute,
-          window)
+          band)
 
     @pl.when(j == nk - 1)
     def _finalize():
@@ -675,12 +881,14 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr,
                 *, scale, causal, g, block_q, block_k, sub, add_dq=None,
-                window=None):
+                band=None):
     """``add_dq(r0, size, k, ds)``, where given, takes each dS the
     walk forms (cast for the products) and the read of its key
     positions (``_cols_of``) on to dQ: ``_dqkv_kernel``."""
     j = pl.program_id(1)  # k block (major)
-    i = pl.program_id(2)  # q block (minor: accumulates)
+    # q block (minor: accumulates); with a band, the column's i-th
+    # live one
+    i = pl.program_id(2)
     nq = pl.num_programs(2)
 
     @pl.when(i == 0)
@@ -688,7 +896,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    q_start = i * block_q
+    q_start = _query_block(band, j, i) * block_q
     k_start = j * block_k
 
     def compute(r0, size, cols, diagonal):
@@ -697,7 +905,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             q_ref, do_ref, lse_ref, delta_ref, g, rows
         )
         k = _cols_of(k_ref, cols)
-        p = _p(q, lse, k(), scale, g, diagonal, window)
+        p = _p(q, lse, k(), scale, g, diagonal, band and band.window)
         if sub is not None:  # dS first where sub-tiles are walked (above)
             ds = _ds(p, do, v_ref[0, cols], delta)
         # dV += P^T @ dO — contracting over the g*size rows also sums
@@ -722,7 +930,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             add_dq(r0, size, k, ds)
 
     _walk(causal, block_q, block_k, sub, q_start, k_start, compute,
-          window)
+          band)
 
     @pl.when(i == nq - 1)
     def _finalize():
@@ -733,7 +941,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                  dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
-                 *, scale, block_q, **static):
+                 *, scale, block_q, band=None, **static):
     """The dK/dV kernel, and dQ of the whole head (no group) summed in
     ``dq_scr`` [seq, d] over the grid's (j, i) steps of one head: its
     output block does not move with them. A query block meets its key
@@ -749,7 +957,8 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if block_q == seq:  # one block a head: static offsets
             rows = _rows_of(r0, size, seq)
         else:
-            rows = pl.ds(pl.multiple_of(i * block_q + r0, size), size)
+            rows = pl.ds(pl.multiple_of(
+                _query_block(band, j, i) * block_q + r0, size), size)
         dq_scr[rows] += jax.lax.dot_general(
             ds, k(), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -758,7 +967,7 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     _dkv_kernel(
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_ref, dv_ref, dk_scr, dv_scr,
-        scale=scale, block_q=block_q, add_dq=add_dq, **static,
+        scale=scale, block_q=block_q, add_dq=add_dq, band=band, **static,
     )
 
     @pl.when(jnp.logical_and(
@@ -771,7 +980,7 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _dq_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
-                   *, scale, block_k, **static):
+                   *, scale, block_k, band=None, **static):
     """The dQ kernel, and dK and dV of the whole kv head summed in
     ``dk_scr`` and ``dv_scr`` [seq, d] over the grid's (i, j) steps of
     one head: their output blocks do not move with them. The dual of
@@ -785,8 +994,17 @@ def _dq_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def add_dkv(p, ds, q, do):
-        cols = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+    def add_dkv(cols, p, ds, q, do):
+        # the head's key positions of the block's ``cols``: the whole
+        # block, or with a band a run of its column tiles
+        start = _key_block(band, i, j) * block_k
+        if cols == slice(None):
+            cols = pl.ds(pl.multiple_of(start, block_k), block_k)
+        else:
+            cols = pl.ds(
+                pl.multiple_of(start + cols.start, band.tile),
+                cols.stop - cols.start,
+            )
         # contracting over the g*block_q rows also sums the group; dK
         # ahead of dV reads 2 to 3% faster than after it at every
         # grouped cell's shape (PERF.md section 6, PR 37)
@@ -802,7 +1020,7 @@ def _dq_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     _dq_kernel(
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr,
-        scale=scale, block_k=block_k, add_dkv=add_dkv, **static,
+        scale=scale, block_k=block_k, add_dkv=add_dkv, band=band, **static,
     )
 
     @pl.when(jnp.logical_and(
@@ -909,9 +1127,11 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
         labelnames=("form",),
     ).labels(form=form).set(2 if form == "pair" else 1)
 
+    band = _band_of("bwd", seq, block_q, block_k, window)
+
     def build(body, name):
         return _kernel(
-            body, name, seq, causal, g, block_q, block_k, q, scale, window
+            body, name, seq, causal, g, block_q, block_k, q, scale, band
         )
 
     def shapes(x, *rows):
@@ -928,7 +1148,7 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
         their blocks the same at every (i, j), written back once a
         head. That call says what VMEM it takes (the default scoped
         limit is 16 MiB of a v5e core's 128)."""
-        kv_idx = _kv_index(causal, block_q, block_k, window)
+        kv_idx = _kv_index(causal, block_q, block_k, band)
 
         def q_idx(b, i, j):
             return (b, 0, i, 0)
@@ -936,10 +1156,11 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
         def lse_idx(b, i, j):
             return (b, 0, 0, i)
 
+        kernel, grid = build(*((_dq_dkv_kernel, "dq_dkv") if resident
+                               else (_dq_kernel, "dq")))
         return pl.pallas_call(
-            build(*((_dq_dkv_kernel, "dq_dkv") if resident
-                    else (_dq_kernel, "dq"))),
-            grid=(bkh, seq // block_q, seq // block_k),
+            kernel,
+            grid=(bkh, *grid),
             in_specs=[
                 _specs(q, (g, block_q), q_idx),
                 _k_specs(k, bkh, block_k, kv_idx),
@@ -974,15 +1195,17 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
         UP to the first causally-live q block of k-block j — skipped
         steps (q entirely above the diagonal) re-reference the block
         the first live step fetches, so they cost no bandwidth (same
-        trick as _kv_index) — and, with a window, DOWN to the block of
-        the last query that sees the k block's last key."""
+        trick as _kv_index); with a band, step i is the i-th block
+        from that one, clamped DOWN to the block of the last query
+        that sees the k block's last key."""
 
         def index(b, j, i):
-            if causal:
+            if band is not None:
+                i = jnp.minimum(
+                    _query_block(band, j, i), band.last_query_block(j)
+                )
+            elif causal:
                 i = jnp.maximum(i, (j * block_k) // block_q)
-            if window is not None:
-                last = (j * block_k + block_k + window - 2) // block_q
-                i = jnp.minimum(i, jnp.minimum(last, seq // block_q - 1))
             return (b, 0, i, 0) if sublane else (b, 0, 0, i)
 
         return index
@@ -992,10 +1215,11 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
         them where the head's dQ is ``resident`` that too: its whole
         [seq, d], its block the same at every (j, i), written back once
         a head."""
+        kernel, grid = build(*((_dqkv_kernel, "dqkv") if resident
+                               else (_dkv_kernel, "dkv")))
         return pl.pallas_call(
-            build(*((_dqkv_kernel, "dqkv") if resident
-                    else (_dkv_kernel, "dkv"))),
-            grid=(bkh, seq // block_k, seq // block_q),
+            kernel,
+            grid=(bkh, *grid),
             in_specs=[
                 _specs(q, (g, block_q), q_side_idx(True)),
                 _k_specs(k, bkh, block_k, lambda b, j, i: (b, j, 0)),

@@ -15,6 +15,19 @@ walk of the diagonal block in ``ops/pallas/flash_attention.py
 _sub_tiles`` was settled on the chip at these blocks and engages only
 with equal blocks and no group, so the rule and the kernel file move
 together or not at all.
+
+The rule does not read a window, and needs not: a windowed call's
+grid counts the key blocks of a query block's band, and its backward
+kernel takes a block that an edge of the band crosses in 512-wide
+column tiles of its own (``flash_attention._Band``,
+``_window_tile``), so a 1024-wide key block costs a windowed layer's
+backward no column outside the band's tiles. The narrower key block
+that would leave out the same columns with no body more reads slower,
+as without a window: whole (128, 512) blocks took 10.64 ms forward
+and 22.96 forward and backward at a window of 2048 (32 query heads on
+4 at 16,384 positions) where (128, 1024) take 6.68 and 18.50, and
+15.32 and 31.77 against 8.73 and 23.66 at 4096 (PERF.md section 6,
+PR 52).
 """
 
 from typing import Dict, Optional, Tuple

@@ -374,32 +374,70 @@ def test_sub_tiled_kernels_compile_at_the_cells_shapes(
                      else [])
 
 
-@pytest.mark.parametrize("window", [None, 4096], ids=["full", "window"])
-def test_windowed_kernels_compile_at_smallthinkers_shape(
-    topo, on_tpu_path, window
+#: the windowed cells' attention: 28 and 32 query heads on 4 of 128
+#: at 16,384 positions, windows of 4096 and 2048
+WINDOWED_CELLS = {"smallthinker": (28, 4096), "trinity-mini": (32, 2048)}
+
+
+@pytest.mark.parametrize("window", [False, True], ids=["full", "window"])
+@pytest.mark.parametrize("cell", WINDOWED_CELLS)
+def test_windowed_kernels_compile_at_the_cells_shapes(
+    topo, on_tpu_path, cell, window
 ):
-    """One 16,384-token sequence, 28 query heads on 4 of 128: the
-    rule's (128, 1024) blocks fold a group of 7 into 896 rows, no
-    power of two; the forward kernel and the one backward kernel (a kv
-    head's float32 dK and dV, 16 MB, resident) with the window's masks
-    and both-ended index clamps, and without."""
-    batch, seq, heads, kv_heads, d = CELL_ATTENTION["smallthinker"]
+    """One 16,384-token sequence, 28 or 32 query heads on 4 of 128:
+    the rule's (128, 1024) blocks fold a group of 7 into 896 rows, no
+    power of two, one of 8 into 1,024; the forward kernel and the one
+    backward kernel (a kv head's float32 dK and dV, 16 MB, resident).
+    With the window the grid counts the band's key blocks, and the
+    backward kernel holds a body for every run of 512-wide column
+    tiles of a block that an edge of the band crosses (the forward
+    takes such a block whole: its step is bound by its rows); without
+    it the kernels are the whole-block ones."""
+    heads, width = WINDOWED_CELLS[cell]
+    seq, kv_heads, d = 16384, 4, 128
+    assert CELL_ATTENTION.get(cell, (1, seq, heads, kv_heads, d)) == (
+        1, seq, heads, kv_heads, d)
     assert tuning.heuristic_blocks(seq, heads // kv_heads) == (128, 1024)
 
     def attn(q, k, v):
         return fa.flash_attention_tpu(
-            q, k, v, causal=True, block_q=128, block_k=1024, window=window
+            q, k, v, causal=True, block_q=128, block_k=1024,
+            window=width if window else None,
         )
 
     one_chip = SingleDeviceSharding(topo.devices[0])
     q, kv = (
         jax.ShapeDtypeStruct(
-            (batch, seq, h, d), jnp.bfloat16, sharding=one_chip
+            (1, seq, h, d), jnp.bfloat16, sharding=one_chip
         ) for h in (heads, kv_heads)
     )
-    text = jax.jit(_sum_grad(attn)).lower(q, kv, kv).compile().as_text()
+    fn = _sum_grad(attn)
+    text = jax.jit(fn).lower(q, kv, kv).compile().as_text()
     assert len(
         re.findall(r'custom_call_target="tpu_custom_call"', text)) == 2
+    # what the band's grid and the tiles show in the traced call: the
+    # grid's minor dimension, and a body (a ``cond``) for the unmasked
+    # block and for the masked one, in the backward for each of the
+    # three runs of tiles, where the whole-block kernel has the one
+    # test of whether a block is live
+    grids, conds = [], []
+
+    def find(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grids.append(tuple(eqn.params["grid_mapping"].grid))
+                conds.append(sum(
+                    e.primitive.name == "cond"
+                    for e in eqn.params["jaxpr"].eqns))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                find(sub)
+
+    find(jax.make_jaxpr(fn)(q, kv, kv).jaxpr)
+    steps = {"smallthinker": 5, "trinity-mini": 3}[cell] if window else 16
+    assert grids == [(4, 128, steps)] * 2, grids
+    # beside the bodies: init and finalize, the backward's resident
+    # dK and dV their own
+    assert conds == ([2 + 1 + 1, 4 + 1 + 3] if window else [2 + 1, 4 + 1]), conds
 
 
 @pytest.mark.parametrize("operands", ["parts", "whole"])
