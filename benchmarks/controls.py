@@ -16,7 +16,8 @@ With ``--probe N`` instead, for a family with experts: the trainer's
 own step on the cell's weights for N steps, ``routing_stats`` before
 each step (a layer's rows on the held experts, the most loaded expert
 over the mean), with delta-rule layers the least ``alpha`` a layer
-(``kda_decay_min``), with a selection bias its largest magnitude a
+(``kda_decay_min``) and with state-space layers the least ``a`` a
+head takes (``ssm_decay_min``), with a selection bias its largest magnitude a
 layer (what ``moe_bias_abs_max`` is the most of: 0 unless a rule moves
 it), each step's seconds and loss: whether the routers keep their
 balance while they train.
@@ -64,7 +65,8 @@ def probe(args, config, traffic, cfg, platform):
 
     if not cfg.num_experts:
         sys.exit(f"{config['family']}: no experts, no router to probe")
-    decays = "linear_attention" in (cfg.layer_types or ())
+    decays = "linear_attention" in (cfg.layer_types or ()) or (
+        "M" in (cfg.hybrid_override_pattern or ""))
     mesh = create_mesh(
         list(traffic["mesh"].items()), devices=jax.devices()[:1])
     trainer = make_trainer_for_llama(

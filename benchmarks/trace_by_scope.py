@@ -37,7 +37,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 #: ``op_name`` holds wins; ``loss`` and ``optimizer`` (trainer/sharded.py)
 #: hold every op of theirs that no inner scope names
 SCOPES = (
-    "kda.scan", "kda.proj", "kda.conv", "kda.out", "kda.decay",
+    "ssm.scan", "ssm.in_proj", "ssm.conv", "ssm.dt", "ssm.gate_norm",
+    "ssm.out_proj", "moe.latent_down", "moe.latent_up", "kda.scan", "kda.proj", "kda.conv", "kda.out", "kda.decay",
     "mla.q_down", "mla.kv_down", "mla.up", "attn.latent", "attn.gate",
     "attn.full", "attn.window", "conv.in_proj", "conv.mix",
     "conv.out_proj", "moe.shared", "moe.route", "moe.dispatch",

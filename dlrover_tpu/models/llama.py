@@ -30,16 +30,19 @@ from dlrover_tpu.ops.attention import flash_attention
 from dlrover_tpu.ops.delta_rule import gated_delta_rule_rows
 from dlrover_tpu.ops.kda_conv import conv_silu_norm, heads_apart
 from dlrover_tpu.ops.short_conv import gated_short_conv
+from dlrover_tpu.ops.ssd import ssd_scan
 
 
 class LayerKind(NamedTuple):
     """What a layer is made of: its operator (``"full_attention"``,
     ``"latent_attention"``, whose q, k and v come through low-rank
     projections, ``"conv"``, the gated short convolution, or
-    ``"linear_attention"``, the gated delta rule), for attention the
+    ``"linear_attention"``, the gated delta rule, ``"state_space"``,
+    a Mamba-2 mixer, or ``"none"``), for attention the
     window (None: every earlier key) and whether q and
-    k are rotated, and its feed-forward (``"dense"`` or
-    ``"experts"``)."""
+    k are rotated, and its feed-forward (``"dense"``,
+    ``"experts"`` or ``"none"``). A block of one branch (``x +
+    branch(RMSNorm(x))``) is a kind with one of the two ``"none"``."""
     operator: str = "full_attention"
     window: Optional[int] = None
     rope: bool = True
@@ -234,6 +237,41 @@ class LlamaConfig:
     # the rule to them, and the trainer calls both
     # (``trainer/sharded.py``)
     moe_bias_update_rate: float = 0.0
+    # a stack of blocks of one branch, in the source's keys
+    # (``NemotronHConfig``): layer l is ``x + branch(RMSNorm(x))``
+    # with the branch ``hybrid_override_pattern[l]`` names: "M" a
+    # Mamba-2 mixer, "*" attention, "E" experts. With the key the
+    # parameters are kept by position. The mixer: ``mamba_num_heads``
+    # heads of ``mamba_head_dim`` in ``n_groups`` groups that share
+    # their B and C of ``ssm_state_size``, behind one input projection
+    # ``[z | x | B | C | dt]`` and a causal depthwise convolution of
+    # ``conv_kernel`` taps over ``[x | B | C]`` with a bias a channel
+    # (``use_conv_bias``); the scan is ops/ssd.py's, whose plain path
+    # walks chunks of ``chunk_size``; its result times ``silu(z)``,
+    # then an RMSNorm over each group's columns, then the output
+    # projection.
+    hybrid_override_pattern: Optional[str] = None
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 64
+    n_groups: int = 1
+    ssm_state_size: int = 128
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    use_conv_bias: bool = True
+    # experts without a gate matrix, ``W_down act(W_up u)`` (False;
+    # the shared expert alike), and experts in a latent: the routed
+    # ones read and write ``moe_latent_size`` columns, between a
+    # projection down from the stream and one back up, while router
+    # and shared expert read the stream itself. The shared expert's
+    # width where the source states it apart from
+    # ``moe_shared_experts x moe_intermediate_size``
+    moe_expert_gated: bool = True
+    moe_latent_size: Optional[int] = None
+    moe_shared_expert_intermediate_size: Optional[int] = None
+    # the prediction module's own sublayers, as
+    # ``hybrid_override_pattern`` names the stack's (None: one block
+    # of the stack's last kind)
+    mtp_hybrid_override_pattern: Optional[str] = None
 
     def __post_init__(self):
         if self.remat not in ("off", "dots", "dots_attn_out",
@@ -266,7 +304,7 @@ class LlamaConfig:
             raise ValueError(
                 f"unknown moe_router_input {self.moe_router_input!r}"
             )
-        if self.moe_expert_act not in ("silu", "relu"):
+        if self.moe_expert_act not in ("silu", "relu", "relu2"):
             raise ValueError(
                 f"unknown moe_expert_act {self.moe_expert_act!r}"
             )
@@ -329,6 +367,45 @@ class LlamaConfig:
                 "rule moves an expert layer's selection bias, which "
                 "use_expert_bias keeps"
             )
+        for name in ("hybrid_override_pattern",
+                     "mtp_hybrid_override_pattern"):
+            unknown = set(getattr(self, name) or "") - set("M*E")
+            if unknown:
+                raise ValueError(
+                    f"{name} names {sorted(unknown)}: the branches "
+                    "here are 'M' (Mamba-2), '*' (attention) and 'E' "
+                    "(experts)"
+                )
+        if self.hybrid_override_pattern is not None:
+            pattern = self.hybrid_override_pattern
+            if (len(pattern) != self.num_layers or self.layer_types
+                    or self.num_dense_layers or self.latent
+                    or self.post_norms or self.moe_bias_update_rate):
+                raise ValueError(
+                    f"hybrid_override_pattern {pattern!r} for "
+                    f"{self.num_layers} layers: a character a layer, "
+                    "and no layer_types, leading dense layers, latent "
+                    "attention, norms on a branch's result or rule on "
+                    "the selection bias beside it"
+                )
+            if "M" in pattern and not (
+                    self.mamba_num_heads > 0 and self.n_groups > 0
+                    and self.mamba_num_heads % self.n_groups == 0):
+                raise ValueError(
+                    f"hybrid_override_pattern names 'M' and "
+                    f"mamba_num_heads {self.mamba_num_heads} in "
+                    f"n_groups {self.n_groups} gives it no heads"
+                )
+            if "E" in pattern and self.num_experts == 0:
+                raise ValueError(
+                    "hybrid_override_pattern names 'E' and num_experts "
+                    "gives it no expert"
+                )
+        elif self.mtp_hybrid_override_pattern is not None:
+            raise ValueError(
+                "mtp_hybrid_override_pattern names the sublayers of a "
+                "module past a stack that hybrid_override_pattern names"
+            )
         if self.mtp_layers not in (0, 1):
             raise ValueError(
                 f"mtp_layers {self.mtp_layers}: one prediction module "
@@ -361,7 +438,29 @@ class LlamaConfig:
         """Whether the parameters are kept by position (``lead`` and
         ``period``) and not as one stack of like layers
         (``blocks``)."""
-        return self.layer_types is not None or self.num_dense_layers > 0
+        return (self.layer_types is not None or self.num_dense_layers > 0
+                or self.hybrid_override_pattern is not None)
+
+    def _branch(self, character: str, rope: bool = False) -> LayerKind:
+        """The kind of the one-branch block a pattern's ``character``
+        names."""
+        if character == "M":
+            return LayerKind("state_space", None, False, "none")
+        if character == "*":
+            return LayerKind("full_attention", None, rope, "none")
+        return LayerKind("none", None, False, "experts")
+
+    def mtp_kinds(self) -> Tuple[LayerKind, ...]:
+        """The kinds of a prediction module's sublayers: those
+        ``mtp_hybrid_override_pattern`` names (attention as the
+        stack's first attention layer has it), or one block of the
+        stack's last kind."""
+        if self.mtp_hybrid_override_pattern is None:
+            return self.layer_plan()[1][-1:]
+        rope = bool((self.rope_layout or (1,))[0])
+        return tuple(
+            self._branch(c, rope) for c in self.mtp_hybrid_override_pattern
+        )
 
     def layer_plan(self) -> Tuple[Tuple[LayerKind, ...],
                                   Tuple[LayerKind, ...]]:
@@ -375,7 +474,12 @@ class LlamaConfig:
             for on in self.sliding_window_layout or (0,) * n
         ]
         ropes = [bool(on) for on in self.rope_layout or (1,) * n]
+        # the one-branch blocks a pattern names, or (without one) a
+        # block of operator and feed-forward a layer
         kinds = tuple(
+            self._branch(c, ropes[i])
+            for i, c in enumerate(self.hybrid_override_pattern or "")
+        ) or tuple(
             LayerKind(
                 operator, *(
                     (None, False)
@@ -488,6 +592,29 @@ def llama_sandwich_tiny(**kw) -> LlamaConfig:
     ), **kw})
 
 
+def llama_mamba_tiny(**kw) -> LlamaConfig:
+    """Test-sized stack of one-branch blocks: Mamba-2 mixers (8 heads
+    of 16 in 4 groups of 16 states behind a four-tap convolution with
+    a bias), one attention layer without positions and experts without
+    a gate (``relu2``) in a 32-wide latent, 4 of 16 held by sigmoid
+    score with a selection bias and a factor of 5, beside a shared
+    expert on the stream; a prediction module of two sublayers."""
+    pattern = "MEMEMEM*EME"
+    return llama_tiny(**{**dict(
+        num_layers=len(pattern), hybrid_override_pattern=pattern,
+        rope_layout=(0,) * len(pattern), mamba_num_heads=8,
+        mamba_head_dim=16, n_groups=4, ssm_state_size=16, chunk_size=32,
+        num_experts=16, moe_top_k=4, moe_experts_held=4,
+        moe_intermediate_size=24, moe_gate="sigmoid",
+        use_expert_bias=True, moe_topk_norm_eps=1e-20,
+        moe_routed_scaling=5.0, moe_shared_experts=1,
+        moe_shared_expert_intermediate_size=48, moe_expert_act="relu2",
+        moe_expert_gated=False, moe_latent_size=32,
+        moe_capacity_factor=0.0, mtp_layers=1,
+        mtp_hybrid_override_pattern="*E",
+    ), **kw})
+
+
 def llama_tiny(**kw) -> LlamaConfig:
     """Test-sized config that still exercises GQA + scan + remat."""
     kw.setdefault("vocab_size", 256)
@@ -511,10 +638,26 @@ def _leaves(cfg: LlamaConfig, kind: LayerKind) -> Dict:
     (deviation 0: zeros)."""
     h, hd = cfg.hidden_size, cfg.head_dim
     nh, nkv = cfg.num_heads, cfg.num_kv_heads
-    norms = {"attn_norm": h, "mlp_norm": h}
+    # a branch's norm: a block of one branch has the one
+    norms = {
+        name: h for name, branch in (
+            ("attn_norm", kind.operator), ("mlp_norm", kind.ffn))
+        if branch != "none"
+    }
     if cfg.post_norms:
         norms.update(post_attn_norm=h, post_mlp_norm=h)
-    if kind.operator == "conv":
+    if kind.operator == "none":
+        matrices = {}
+    elif kind.operator == "state_space":
+        inner, conv = _ssm_widths(cfg)
+        matrices = {
+            # [z | x | B | C | dt]
+            "ssm_in": ((h, inner + conv + cfg.mamba_num_heads),
+                       ("embed", "mlp")),
+            "ssm_out": ((inner, h), ("mlp", "embed")),
+        }
+        norms["ssm_norm"] = inner
+    elif kind.operator == "conv":
         matrices = {
             "conv_in": ((h, 3 * h), ("embed", "mlp")),
             "conv_out": ((h, h), ("mlp", "embed")),
@@ -564,20 +707,32 @@ def _leaves(cfg: LlamaConfig, kind: LayerKind) -> Dict:
             norms.update(q_norm=hd, k_norm=hd)
     if kind.ffn == "experts":
         held, m = cfg.moe_experts_held, cfg.moe_intermediate_size
+        # what a routed expert reads and writes: the stream, or the
+        # latent between the two projections
+        wide = cfg.moe_latent_size or h
         matrices.update({
             "router": ((h, cfg.num_experts), ("embed", None)),
-            "w_gate": ((held, h, m), ("expert", "embed", "mlp")),
-            "w_up": ((held, h, m), ("expert", "embed", "mlp")),
-            "w_down": ((held, m, h), ("expert", "mlp", "embed")),
+            "w_gate": ((held, wide, m), ("expert", "embed", "mlp")),
+            "w_up": ((held, wide, m), ("expert", "embed", "mlp")),
+            "w_down": ((held, m, wide), ("expert", "mlp", "embed")),
         })
+        if cfg.moe_latent_size:
+            matrices.update({
+                "w_latent_down": ((h, wide), ("embed", None)),
+                "w_latent_up": ((wide, h), (None, "embed")),
+            })
         if cfg.moe_shared_experts:
-            ms = cfg.moe_shared_experts * m
+            ms = (cfg.moe_shared_expert_intermediate_size
+                  or cfg.moe_shared_experts * m)
             matrices.update({
                 "ws_gate": ((h, ms), ("embed", "mlp")),
                 "ws_up": ((h, ms), ("embed", "mlp")),
                 "ws_down": ((ms, h), ("mlp", "embed")),
             })
-    else:
+        if not cfg.moe_expert_gated:
+            del matrices["w_gate"]
+            matrices.pop("ws_gate", None)
+    elif kind.ffn == "dense":
         m = cfg.intermediate_size
         matrices.update({
             "w_gate": ((h, m), ("embed", "mlp")),
@@ -602,9 +757,26 @@ def _leaves(cfg: LlamaConfig, kind: LayerKind) -> Dict:
         # float32 vectors with draws of their own (``_DECAY_DRAWS``)
         leaves["A_log"] = ((lh,), ("norm",), "A_log")
         leaves["dt_bias"] = ((wide,), ("norm",), "dt_bias")
+    if kind.operator == "state_space":
+        taps, heads = cfg.conv_kernel, cfg.mamba_num_heads
+        leaves["ssm_conv_w"] = ((conv, taps), ("mlp", None), taps ** -0.5)
+        if cfg.use_conv_bias:
+            leaves["ssm_conv_b"] = ((conv,), ("norm",), 0)
+        # float32 vectors a head: the decay's two by draws of their
+        # own (``_DECAY_DRAWS``), the skip ``D`` at one
+        leaves["A_log"] = ((heads,), ("norm",), "A_log")
+        leaves["dt_bias"] = ((heads,), ("norm",), "dt_bias")
+        leaves["D"] = ((heads,), ("norm",), None)
     if kind.ffn == "experts" and cfg.use_expert_bias:
         leaves["expert_bias"] = ((cfg.num_experts,), (None,), 0)
     return leaves
+
+
+def _ssm_widths(cfg: LlamaConfig) -> Tuple[int, int]:
+    """``(the mixer's inner columns, heads x head_dim; the columns
+    [x | B | C] that pass its convolution)``."""
+    inner = cfg.mamba_num_heads * cfg.mamba_head_dim
+    return inner, inner + 2 * cfg.n_groups * cfg.ssm_state_size
 
 
 #: which of ``jax.random.split(key, 8)`` draws a leaf; from 8 on the
@@ -614,7 +786,9 @@ _DRAW = {"wq": 0, "wk": 1, "wv": 2, "wo": 3, "w_gate": 4, "w_up": 5,
          "conv_out": 3, "wq_a": 8, "wq_b": 9, "wkv_a": 10, "wkv_b": 11,
          "ws_gate": 12, "ws_up": 13, "ws_down": 14, "conv_q": 15,
          "conv_k": 16, "conv_v": 17, "f_a": 18, "f_b": 19, "g_a": 20,
-         "g_b": 21, "w_beta": 22, "A_log": 23, "dt_bias": 24, "wg": 25}
+         "g_b": 21, "w_beta": 22, "A_log": 23, "dt_bias": 24, "wg": 25,
+         "ssm_in": 26, "ssm_out": 27, "ssm_conv_w": 28,
+         "w_latent_down": 29, "w_latent_up": 30}
 
 
 def _draw_A_log(key, shape):
@@ -642,7 +816,7 @@ def _init_layers(key, cfg: LlamaConfig, kind: LayerKind, stack=()):
     ks = jax.random.split(key, 8)
     layers = {}
     for name, (shape, _, std) in _leaves(cfg, kind).items():
-        if not std:  # a norm's scale at one, the selection bias at zero
+        if not std:  # a scale at one, a bias at zero
             layers[name] = jnp.full(
                 stack + shape, 1.0 if std is None else 0.0, jnp.float32
             )
@@ -674,7 +848,8 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Dict:
     the scanned period. A tied head has no ``lm_head``. ``mtp`` holds
     the prediction modules past the stack: the norms of the two
     inputs, the merge ``eh_proj`` [2 hidden, hidden], a block of the
-    last layer's kind and a final norm of its own."""
+    last layer's kind (with ``mtp_hybrid_override_pattern`` a list, a
+    block a sublayer) and a final norm of its own."""
     h = cfg.hidden_size
     k_embed, k_blocks, k_out = jax.random.split(rng, 3)
     lead, period = cfg.layer_plan()
@@ -714,10 +889,23 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Dict:
                 jax.random.normal(k_merge, (2 * h, h), jnp.float32)
                 * (2 * h) ** -0.5
             ).astype(cfg.dtype),
-            "block": _init_layers(k_block, cfg, period[-1]),
+            "block": _mtp_block(
+                cfg, lambda i, kind: _init_layers(
+                    jax.random.fold_in(k_block, i) if i else k_block,
+                    cfg, kind)),
             "final_norm": jnp.ones((h,), jnp.float32),
         }]
     return params
+
+
+def _mtp_block(cfg: LlamaConfig, of):
+    """A prediction module's ``block``: ``of(i, kind)`` of its one
+    block, or with ``mtp_hybrid_override_pattern`` the list of it
+    over the module's sublayers."""
+    kinds = cfg.mtp_kinds()
+    if cfg.mtp_hybrid_override_pattern is None:
+        return of(0, kinds[0])
+    return [of(i, kind) for i, kind in enumerate(kinds)]
 
 
 def param_axes(cfg: LlamaConfig) -> Dict:
@@ -737,7 +925,8 @@ def param_axes(cfg: LlamaConfig) -> Dict:
         axes["mtp"] = [{
             "embed_norm": ("norm",), "hidden_norm": ("norm",),
             "eh_proj": ("mlp", "embed"),
-            "block": _layer_axes(cfg, period[-1]),
+            "block": _mtp_block(
+                cfg, lambda _, kind: _layer_axes(cfg, kind)),
             "final_norm": ("norm",),
         }]
     return axes
@@ -762,7 +951,7 @@ def _layers_of_each_kind(cfg: LlamaConfig):
     periods = (cfg.num_layers - len(lead)) // len(period)
     return [(kind, 1) for kind in lead] + [
         (kind, periods) for kind in period
-    ] + cfg.mtp_layers * [(period[-1], 1)]
+    ] + cfg.mtp_layers * [(kind, 1) for kind in cfg.mtp_kinds()]
 
 
 def param_count(cfg: LlamaConfig) -> int:
@@ -854,7 +1043,6 @@ def _pre_attn(cfg: LlamaConfig, x, layer_params, cos, sin,
     b, s, h = x.shape
     nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     p = layer_params
-    y = rms_norm(constrain(x, _RESIDUAL), p["attn_norm"], cfg.norm_eps)
 
     def logits():
         if (kind.ffn != "experts"
@@ -864,6 +1052,11 @@ def _pre_attn(cfg: LlamaConfig, x, layer_params, cos, sin,
 
         return router_logits(x, p["router"])
 
+    if kind.operator == "none":  # a block of the feed-forward alone
+        return (), logits()
+    y = rms_norm(constrain(x, _RESIDUAL), p["attn_norm"], cfg.norm_eps)
+    if kind.operator == "state_space":
+        return _ssm_operands(cfg, y, p, constrain), logits()
     if kind.operator == "conv":
         with jax.named_scope("conv.in_proj"):
             bcu = constrain(y @ p["conv_in"], _MLP)
@@ -888,6 +1081,31 @@ def _pre_attn(cfg: LlamaConfig, x, layer_params, cos, sin,
         with jax.named_scope("attn.gate"):
             return (q, k, v, y @ p["wg"]), logits()
     return (q, k, v), logits()
+
+
+def _ssm_operands(cfg: LlamaConfig, y, p, constrain=_free):
+    """A Mamba-2 mixer's operands from the normed stream ``y``, in
+    rows: ``([x | B | C] [b, s, heads x d + 2 groups x n] past its
+    convolution, the step Delta [b, s, heads] and the rate A [heads]
+    (negative) in float32, the skip D [heads], the gate's
+    pre-activation z [b, s, heads x d], the grouped norm's scale)``.
+    The scopes name every op:
+    ``ssm.in_proj`` the one projection ``[z | x | B | C | dt]`` and
+    its three slices, ``ssm.conv`` the convolution with its bias and
+    ``silu`` (``ops/kda_conv.py``: on the TPU one Pallas pass each
+    way), ``ssm.dt`` the step's softplus and the rate."""
+    inner, conv = _ssm_widths(cfg)
+    with jax.named_scope("ssm.in_proj"):
+        z, xbc, dt = jnp.split(
+            constrain(y @ p["ssm_in"], _MLP), [inner, inner + conv], axis=-1
+        )
+    with jax.named_scope("ssm.conv"):
+        xbc = conv_silu_norm(
+            xbc, p["ssm_conv_w"], bias=p.get("ssm_conv_b"))
+    with jax.named_scope("ssm.dt"):
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
+        rate = -jnp.exp(p["A_log"])
+    return xbc, dt, rate, p["D"], z, p["ssm_norm"]
 
 
 def _delta_rule_operands(cfg: LlamaConfig, y, p, constrain=_free):
@@ -1012,13 +1230,17 @@ def _expert_mlp(cfg: LlamaConfig, expert_parallel: bool):
             or cfg.moe_expert_act != "silu"
             or cfg.moe_gate != "softmax" or cfg.use_expert_bias
             or cfg.moe_topk_norm_eps is not None
-            or cfg.moe_routed_scaling != 1.0 or cfg.moe_shared_experts):
+            or cfg.moe_routed_scaling != 1.0 or cfg.moe_shared_experts
+            or not cfg.moe_expert_gated or cfg.moe_latent_size
+            or cfg.hybrid_override_pattern is not None):
         raise ValueError(
             "a share of the experts held on one device "
             "(moe_experts_held), a router on the block's input, a "
             "relu gate, a sigmoid router and its selection bias, a "
-            "factor on the routing weights and a shared expert are "
-            "the dropless path's, on one device: over an 'expert' "
+            "factor on the routing weights, a shared expert, experts "
+            "without a gate or in a latent, and a stack of one-branch "
+            "blocks (whose state-space scan takes a whole sequence) "
+            "are the dropless path's, on one device: over an 'expert' "
             "mesh axis larger than one they are refused (experts "
             "over chips: ROADMAP B9)"
         )
@@ -1042,6 +1264,9 @@ def _operator_out(x, out, layer_params, kind: LayerKind,
     ``norm_eps``."""
     b, s, _ = x.shape
     p = layer_params
+    if kind.operator == "state_space":
+        with jax.named_scope("ssm.out_proj"):
+            return out @ p["ssm_out"]
     if kind.operator == "conv":
         with jax.named_scope("conv.out_proj"):
             return out @ p["conv_out"]
@@ -1070,7 +1295,10 @@ def _past_operator(cfg: LlamaConfig, x, out, layer_params,
                    kind: LayerKind):
     """The residual stream past the operator: ``x`` plus the
     operator's result ``out`` through its output projection and, with
-    ``post_norms``, an RMSNorm (scope ``norm.post_attn``)."""
+    ``post_norms``, an RMSNorm (scope ``norm.post_attn``); ``x``
+    itself past no operator."""
+    if kind.operator == "none":
+        return x
     branch = _operator_out(x, out, layer_params, kind, cfg.norm_eps)
     if cfg.post_norms:
         with jax.named_scope("norm.post_attn"):
@@ -1092,8 +1320,10 @@ def _post_attn(cfg: LlamaConfig, x, out, layer_params,
     without one and for a dense MLP."""
     p = layer_params
     x = constrain(_past_operator(cfg, x, out, p, kind), _RESIDUAL)
-    y = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
     counts = None
+    if kind.ffn == "none":  # a block of the operator alone
+        return x, jnp.zeros((), jnp.float32), counts
+    y = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
     if kind.ffn == "experts":
         mlp = _expert_mlp(cfg, expert_parallel)
         if router_logits is not None:
@@ -1102,17 +1332,18 @@ def _post_attn(cfg: LlamaConfig, x, out, layer_params,
             mlp = partial(mlp, bias=p["expert_bias"])
         if cfg.moe_shared_experts:
             mlp = partial(
-                mlp, shared=(p["ws_gate"], p["ws_up"], p["ws_down"])
+                mlp, shared=(p.get("ws_gate"), p["ws_up"], p["ws_down"])
             )
+        if cfg.moe_latent_size:
+            mlp = partial(
+                mlp, latent=(p["w_latent_down"], p["w_latent_up"])
+            )
+        # experts without a gate matrix have no ``w_gate``
+        experts = (p.get("w_gate"), p["w_up"], p["w_down"])
         if cfg.moe_bias_update_rate:
-            out, aux, counts = mlp(
-                y, p["router"], p["w_gate"], p["w_up"], p["w_down"],
-                count=True,
-            )
+            out, aux, counts = mlp(y, p["router"], *experts, count=True)
         else:
-            out, aux = mlp(
-                y, p["router"], p["w_gate"], p["w_up"], p["w_down"]
-            )
+            out, aux = mlp(y, p["router"], *experts)
     else:
         gate = jax.nn.silu(constrain(y @ p["w_gate"], _MLP))
         up = constrain(y @ p["w_up"], _MLP)
@@ -1150,7 +1381,35 @@ def _operator_of(cfg: LlamaConfig, attn_fn, kind: LayerKind):
     take, as ``flash_attention`` and ``mha_reference`` do). Linear
     attention's call is ``kda.scan``, the gated delta rule; it hands
     the output gate's logits on beside its result, as attention does
-    with ``attn_out_gate``, for ``_operator_out``."""
+    with ``attn_out_gate``, for ``_operator_out``. A Mamba-2 mixer's
+    is ``ssm.scan`` and, on its result, ``ssm.gate_norm``; a block
+    of the feed-forward alone calls nothing."""
+    if kind.operator == "none":
+        return lambda: None
+    if kind.operator == "state_space":
+        inner, _ = _ssm_widths(cfg)
+        groups = cfg.n_groups
+
+        def scan(xbc, dt, rate, skip, z, scale):
+            with jax.named_scope("ssm.scan"):
+                x, b, c = jnp.split(
+                    xbc, [inner, inner + groups * cfg.ssm_state_size],
+                    axis=-1)
+                o = ssd_scan(
+                    x, b, c, dt, rate, skip, cfg.mamba_num_heads, groups,
+                    cfg.chunk_size,
+                )
+            # the gate, then an RMSNorm over each group's columns
+            with jax.named_scope("ssm.gate_norm"):
+                gated = heads_apart(
+                    o.astype(jnp.float32)
+                    * jax.nn.silu(z.astype(jnp.float32)), groups)
+                normed = gated * jax.lax.rsqrt(
+                    jnp.mean(gated * gated, axis=-1, keepdims=True)
+                    + cfg.norm_eps)
+                return (normed.reshape(o.shape) * scale).astype(o.dtype)
+
+        return scan
     if kind.operator == "conv":
 
         def mix(bcu, w):
@@ -1182,7 +1441,8 @@ def _operator_of(cfg: LlamaConfig, attn_fn, kind: LayerKind):
             return attn_fn(q, k, v, window=kind.window)
 
     attend = scoped
-    if cfg.sliding_window_layout is None and cfg.layer_types is None:
+    if (cfg.sliding_window_layout is None and cfg.layer_types is None
+            and cfg.hybrid_override_pattern is None):
         attend = attn_fn
     if cfg.attn_out_gate:
         return lambda q, k, v, gate: (attend(q, k, v), gate)
@@ -1219,7 +1479,10 @@ def _scan_layers(layers, carry, blocks):
         for i, layer in enumerate(layers):
             carry, out = layer(carry, position(period_params, i))
             outs.append(out)
-        if outs[0] is None:
+        # a layer with nothing to hand out (a block of another
+        # branch) is left out of the stack
+        outs = [out for out in outs if out is not None]
+        if not outs:
             return carry, None
         return carry, jax.tree.map(lambda *o: jnp.stack(o), *outs)
 
@@ -1453,18 +1716,23 @@ def _mtp_states(cfg: LlamaConfig, params, module, x, ahead, layer_of):
     module ``module``: position i's state ``x[i]`` out of the stack
     (before the final norm) and the embedding of the token after it,
     ``ahead[i]``, each normed, side by side through ``eh_proj``; one
-    block of the stack's last kind; the module's own final norm.
-    ``counts``: its block's, as ``_run_stack``'s."""
+    block of the stack's last kind, or the module's own sublayers
+    (``mtp_kinds``); the module's own final norm. ``counts``: its
+    block's, as ``_run_stack``'s."""
     with jax.named_scope("mtp.merge"):
         merged = jnp.concatenate([
             rms_norm(_embed(params, ahead, cfg), module["embed_norm"],
                      cfg.norm_eps),
             rms_norm(x, module["hidden_norm"], cfg.norm_eps),
         ], axis=-1) @ module["eh_proj"]
+    blocks = module["block"]
+    if cfg.mtp_hybrid_override_pattern is None:
+        blocks = [blocks]
     with jax.named_scope("mtp.block"):
-        (x, aux), counts = layer_of(cfg.layer_plan()[1][-1])(
-            (merged, jnp.zeros((), jnp.float32)), module["block"]
-        )
+        carry = (merged, jnp.zeros((), jnp.float32))
+        for kind, block in zip(cfg.mtp_kinds(), blocks):
+            carry, counts = layer_of(kind)(carry, block)
+    x, aux = carry
     return rms_norm(x, module["final_norm"], cfg.norm_eps), aux, counts
 
 
@@ -1589,11 +1857,11 @@ def moved_expert_bias(params: Dict, counts: Dict,
 
 def expert_bias_abs_max(params: Dict, cfg: LlamaConfig) -> jax.Array:
     """The largest magnitude of each scanned expert layer's selection
-    bias, float32 [layers]: how far the rule has moved it."""
+    bias, float32 [expert layers]: how far the rule has moved it."""
     if cfg.by_position:
         return jnp.stack([
             jnp.max(jnp.abs(layers["expert_bias"]), axis=-1)
-            for layers in params["period"]
+            for layers in params["period"] if "expert_bias" in layers
         ], axis=1).reshape(-1)
     return jnp.max(jnp.abs(params["blocks"]["expert_bias"]), axis=-1)
 
@@ -1717,8 +1985,12 @@ def decay_min(params: Dict, tokens: jax.Array, cfg: LlamaConfig,
     (jit-able), before the scan's floor: ``gated_delta_rule_rows``
     takes a step under ``exp(-10)`` as ``exp(-10)`` (ops/delta_rule.py
     ``G_FLOOR``), and this is the number that says whether a run's
-    channels get there."""
+    channels get there. A state-space layer's is the least ``a =
+    exp(A Delta)`` of a head (ops/ssd.py has no floor)."""
     def see(kind, x, p, operands, out, logits):
+        if kind.operator == "state_space":
+            _, dt, rate = operands[:3]  # a head's a = exp(A Delta)
+            return jnp.exp(jnp.min(dt * rate)), logits
         if kind.operator != "linear_attention":
             return jnp.ones((), jnp.float32), logits
         return jnp.exp(jnp.min(operands[3])), logits
@@ -1726,16 +1998,18 @@ def decay_min(params: Dict, tokens: jax.Array, cfg: LlamaConfig,
     return _seen_in_layers(params, tokens, cfg, attn_fn, see)
 
 
-def set_decay_min_gauge(least) -> float:
-    """Set the gauge ``kda_decay_min`` (``GET /metrics``) to the
-    least of ``decay_min``'s values at an evaluation."""
+def set_decay_min_gauge(least, name: str = "kda_decay_min") -> float:
+    """Set the gauge ``kda_decay_min`` (``GET /metrics``), or for a
+    stack of state-space layers ``ssm_decay_min``, to the least of
+    ``decay_min``'s values at an evaluation."""
     from dlrover_tpu.telemetry.registry import gauge
 
     value = float(jnp.min(least))
     gauge(
-        "kda_decay_min",
-        "least decay alpha = exp(g) of a key channel of the gated "
-        "delta rule, over the layers, at the last evaluation",
+        name,
+        "least decay of a step (alpha = exp(g) of a key channel of the "
+        "gated delta rule; a = exp(A Delta) of a head of the "
+        "state-space scan), over the layers, at the last evaluation",
     ).set(value)
     return value
 
@@ -1744,7 +2018,8 @@ def flops_per_token(cfg: LlamaConfig, seq_len: int) -> float:
     """Approximate training FLOPs per token (6N_active + attention
     quadratic, at ``num_heads x head_dim`` and by each layer's kind;
     the convolution's taps are not counted, and of the gated delta
-    rule its projections and low ranks but not the recurrence), a
+    rule and of a state-space mixer the projections and low ranks
+    but not the recurrence), a
     prediction module's
     block and second pass through the head included. For MoE, only
     the top-k routed experts execute per token, so N counts k experts
@@ -1755,11 +2030,14 @@ def flops_per_token(cfg: LlamaConfig, seq_len: int) -> float:
     n += cfg.mtp_layers * cfg.vocab_size * cfg.hidden_size
     kinds = _layers_of_each_kind(cfg)
     if cfg.num_experts > 0:
-        h, m = cfg.hidden_size, cfg.moe_intermediate_size
+        # an expert's two or three matrices on what it reads
+        expert = (3 if cfg.moe_expert_gated else 2) * (
+            cfg.moe_latent_size or cfg.hidden_size
+        ) * cfg.moe_intermediate_size
         # of the experts held here a token meets its k's share
         met = (min(cfg.moe_top_k, cfg.num_experts)
                * cfg.moe_experts_held / cfg.num_experts)
-        n -= 3 * h * m * (cfg.moe_experts_held - met) * sum(
+        n -= expert * (cfg.moe_experts_held - met) * sum(
             count for kind, count in kinds if kind.ffn == "experts"
         )
     # scores and weighted values against every key a query's layer
@@ -1767,7 +2045,7 @@ def flops_per_token(cfg: LlamaConfig, seq_len: int) -> float:
     keys = sum(
         count * min(kind.window or seq_len, seq_len)
         for kind, count in kinds
-        if kind.operator not in ("conv", "linear_attention")
+        if kind.operator in ("full_attention", "latent_attention")
     )
     # a head's scores contract over q and k's width, its weighted
     # values are v's wide

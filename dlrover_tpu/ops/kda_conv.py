@@ -7,12 +7,15 @@ and for q and k the l2 norm of each head.
 [heads x d, taps] a channel's taps, oldest first::
 
     a[t, c] = sum_j w[c, j] x[t - (taps - 1) + j, c]   # x = 0 before t = 0
+    a[t, c] += bias[c]                                 # where one is given
     s = silu(a) = a * sigmoid(a)
     n = s * rsqrt(sum over a head's d columns of s^2 + L2_NORM_EPS)
 
 in float32, rounded once to ``x.dtype``: ``n`` where ``l2_heads``
 (the number of heads) is given, ``s`` where it is not. A sequence is
-a row of the batch, so nothing crosses a sequence's start. On the TPU
+a row of the batch, so nothing crosses a sequence's start. The bias
+(a Mamba-2 layer's ``use_conv_bias``; a delta-rule layer has none) is
+a float32 number a channel, added before ``silu``. On the TPU
 one Pallas pass forward and one backward (ops/pallas/kda_conv.py);
 elsewhere, and as the tests' other side, the shifted multiply-adds of
 ``ops/short_conv.py causal_taps`` and the norm through a view that
@@ -63,9 +66,13 @@ def _use_pallas(x: jax.Array, w: jax.Array, l2_heads) -> bool:
     return tiles_the_kernel(x.shape, w.shape, l2_heads)
 
 
-def conv_silu_norm_plain(x: jax.Array, w: jax.Array, l2_heads=None):
+def conv_silu_norm_plain(x: jax.Array, w: jax.Array, l2_heads=None,
+                         bias=None):
     """The equations above as they stand, in float32, rounded once."""
-    s = jax.nn.silu(causal_taps(x.astype(jnp.float32), w))
+    a = causal_taps(x.astype(jnp.float32), w)
+    if bias is not None:
+        a = a + bias.astype(jnp.float32)
+    s = jax.nn.silu(a)
     if l2_heads:
         s = l2norm(heads_apart(s, l2_heads)).reshape(x.shape)
     return s.astype(x.dtype)
@@ -83,19 +90,26 @@ def _count(path: str):
     ).inc()
 
 
-def conv_silu_norm(x: jax.Array, w: jax.Array, l2_heads=None):
+def conv_silu_norm(x: jax.Array, w: jax.Array, l2_heads=None, bias=None):
     """``[batch, seq, heads x d]`` and ``[heads x d, taps]`` to
     ``[batch, seq, heads x d]``; with ``l2_heads`` heads, each head's
-    columns of unit length."""
-    if w.shape[0] != x.shape[-1] or (l2_heads and w.shape[0] % l2_heads):
+    columns of unit length; with ``bias`` [heads x d], a channel's
+    number added ahead of ``silu``."""
+    if w.shape[0] != x.shape[-1] or (l2_heads and w.shape[0] % l2_heads) or (
+            bias is not None and bias.shape != w.shape[:1]):
         raise ValueError(
             f"taps of {w.shape[0]} channels for rows of {x.shape[-1]} "
             f"in {l2_heads or 'no'} heads"
+            + ("" if bias is None else f", a bias of {bias.shape}")
         )
     if _use_pallas(x, w, l2_heads):
-        from dlrover_tpu.ops.pallas.kda_conv import kda_conv_tpu
+        from dlrover_tpu.ops.pallas.kda_conv import (
+            kda_conv_bias_tpu, kda_conv_tpu,
+        )
 
         _count("kernel")
-        return kda_conv_tpu(x, w, l2_heads)
+        if bias is None:
+            return kda_conv_tpu(x, w, l2_heads)
+        return kda_conv_bias_tpu(x, w, bias, l2_heads)
     _count("plain")
-    return conv_silu_norm_plain(x, w, l2_heads)
+    return conv_silu_norm_plain(x, w, l2_heads, bias)

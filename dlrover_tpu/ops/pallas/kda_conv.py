@@ -13,6 +13,11 @@ again::
     da = ds sigmoid(a) (1 + a (1 - sigmoid(a)))
     dx[t] = sum_j w[j] da[t + (taps - 1) - j]
     dw[j] = sum_t da[t] x[t - (taps - 1) + j]
+    db = sum_t da[t]                          # with a bias a channel
+
+(``a`` the taps' sum plus the bias, where a Mamba-2 layer's call gives
+one: one more float32 row beside the taps, and one more beside ``dw``;
+a call without one builds the kernels it built before the bias).
 
 A grid step is a block of time steps of one sequence at a block of
 whole heads on the lanes, walked a head (or, without the norm, a lane
@@ -103,12 +108,14 @@ def _tile_after(x, after, taps):
     ]
 
 
-def _silu_norm(shifted, w_ref, at, l2):
+def _silu_norm(shifted, w_ref, at, l2, b_ref=None):
     """``(a, sigmoid(a), s, r)`` of one head's lanes ``at``: ``r`` the
     norm's factor [rows, 1], None without the norm."""
     a = jnp.zeros_like(shifted[0])
     for j, rows in enumerate(shifted):
         a += w_ref[j:j + 1, at] * rows
+    if b_ref is not None:
+        a += b_ref[:, at]
     gate = jax.nn.sigmoid(a)
     s = a * gate
     r = jax.lax.rsqrt(
@@ -117,10 +124,10 @@ def _silu_norm(shifted, w_ref, at, l2):
     return a, gate, s, r
 
 
-def _da(shifted, dy, w_ref, at, l2):
+def _da(shifted, dy, w_ref, at, l2, b_ref=None):
     """The cotangent of ``a`` from the result's, everything between
     made again."""
-    a, gate, s, r = _silu_norm(shifted, w_ref, at, l2)
+    a, gate, s, r = _silu_norm(shifted, w_ref, at, l2, b_ref)
     ds = dy
     if l2:
         ds = r * dy - s * (r * r * r) * jnp.sum(
@@ -141,21 +148,25 @@ def _each_head(lanes, head, body):
     jax.lax.fori_loop(0, lanes // head, step, 0)
 
 
-def _fwd_kernel(x_ref, before_ref, w_ref, y_ref, *, taps, head, l2):
+def _fwd_kernel(x_ref, before_ref, w_ref, *rest, taps, head, l2):
+    b_ref, y_ref = rest if len(rest) == 2 else (None, *rest)
     first = pl.program_id(2) == 0
     edge = slice(HALO - TILE, HALO)
 
     def one(at):
         before = jnp.where(first, 0.0, before_ref[edge, at].astype(F32))
         _, _, s, r = _silu_norm(
-            _shifted(x_ref[:, at].astype(F32), before, taps), w_ref, at, l2)
+            _shifted(x_ref[:, at].astype(F32), before, taps), w_ref, at, l2,
+            b_ref)
         y_ref[:, at] = (s * r if l2 else s).astype(y_ref.dtype)
 
     _each_head(x_ref.shape[1], head, one)
 
 
 def _bwd_kernel(x_ref, before_ref, after_ref, dy_ref, dy_after_ref, w_ref,
-                dx_ref, dw_ref, *, taps, head, l2):
+                *rest, taps, head, l2):
+    b_ref, dx_ref, dw_ref, db_ref = (
+        rest if len(rest) == 4 else (None, *rest, None))
     start = (pl.program_id(1) == 0) & (pl.program_id(2) == 0)
     first = pl.program_id(2) == 0
     last = pl.program_id(2) == pl.num_programs(2) - 1
@@ -164,21 +175,25 @@ def _bwd_kernel(x_ref, before_ref, after_ref, dy_ref, dy_after_ref, w_ref,
     @pl.when(start)
     def _():
         dw_ref[...] = jnp.zeros_like(dw_ref)
+        if db_ref is not None:
+            db_ref[...] = jnp.zeros_like(db_ref)
 
     def one(at):
         x = x_ref[:, at].astype(F32)
         before = jnp.where(first, 0.0, before_ref[edge, at].astype(F32))
         shifted = _shifted(x, before, taps)
-        da = _da(shifted, dy_ref[:, at].astype(F32), w_ref, at, l2)
+        da = _da(shifted, dy_ref[:, at].astype(F32), w_ref, at, l2, b_ref)
         da_after = jnp.where(last, 0.0, _da(
             _tile_after(x, after_ref[near, at].astype(F32), taps),
-            dy_after_ref[near, at].astype(F32), w_ref, at, l2))
+            dy_after_ref[near, at].astype(F32), w_ref, at, l2, b_ref))
         dx = jnp.zeros_like(x)
         for j in range(taps):
             dx += w_ref[j:j + 1, at] * _later(da, da_after, taps - 1 - j)
             dw_ref[j:j + 1, at] += jnp.sum(
                 da * shifted[j], axis=0, keepdims=True)
         dx_ref[:, at] = dx.astype(dx_ref.dtype)
+        if db_ref is not None:
+            db_ref[:, at] += jnp.sum(da, axis=0, keepdims=True)
 
     _each_head(x_ref.shape[1], head, one)
 
@@ -209,9 +224,18 @@ def _blocks(x, l2_heads, rows, lanes):
     return head, rows, lanes, (width // lanes, x.shape[0], seq // rows)
 
 
-def _forward(x, taps_first, l2_heads, rows, lanes):
+def _with_bias(bias, lanes):
+    """``(the bias's spec, the bias as a row)`` for either pass: none
+    of either where a call has no bias."""
+    if bias is None:
+        return [], []
+    return [pl.BlockSpec((1, lanes), _taps)], [bias.astype(F32)[None]]
+
+
+def _forward(x, taps_first, bias, l2_heads, rows, lanes):
     taps = taps_first.shape[0]
     head, rows, lanes, grid = _blocks(x, l2_heads, rows, lanes)
+    bias_spec, bias_row = _with_bias(bias, lanes)
     return pl.pallas_call(
         functools.partial(
             _fwd_kernel, taps=taps, head=head, l2=bool(l2_heads)),
@@ -220,17 +244,19 @@ def _forward(x, taps_first, l2_heads, rows, lanes):
             pl.BlockSpec((None, rows, lanes), _whole),
             pl.BlockSpec((None, HALO, lanes), _at_lanes(_halo_before(rows))),
             pl.BlockSpec((taps, lanes), _taps),
+            *bias_spec,
         ],
         out_specs=pl.BlockSpec((None, rows, lanes), _whole),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         interpret=_interpret(),
-    )(x, x, taps_first)
+    )(x, x, taps_first, *bias_row)
 
 
-def _backward(x, taps_first, dy, l2_heads, rows, lanes):
+def _backward(x, taps_first, bias, dy, l2_heads, rows, lanes):
     taps, width = taps_first.shape
     head, rows, lanes, grid = _blocks(x, l2_heads, rows, lanes)
     after = _at_lanes(_halo_after(rows, x.shape[1]))
+    bias_spec, bias_row = _with_bias(bias, lanes)
     return pl.pallas_call(
         functools.partial(
             _bwd_kernel, taps=taps, head=head, l2=bool(l2_heads)),
@@ -242,36 +268,41 @@ def _backward(x, taps_first, dy, l2_heads, rows, lanes):
             pl.BlockSpec((None, rows, lanes), _whole),
             pl.BlockSpec((None, HALO, lanes), after),
             pl.BlockSpec((taps, lanes), _taps),
+            *bias_spec,
         ],
         out_specs=[
             pl.BlockSpec((None, rows, lanes), _whole),
             # one block a lane block, through the batch and the
-            # sequence: the taps' gradient, summed
+            # sequence: the taps' gradient, summed (and the bias's)
             pl.BlockSpec((taps, lanes), _taps),
+            *bias_spec,
         ],
         out_shape=[
             jax.ShapeDtypeStruct(x.shape, x.dtype),
             jax.ShapeDtypeStruct((taps, width), F32),
+            *(jax.ShapeDtypeStruct((1, width), F32) for _ in bias_row),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
         ),
         interpret=_interpret(),
-    )(x, x, x, dy, dy, taps_first)
+    )(x, x, x, dy, dy, taps_first, *bias_row)
 
 
 @functools.partial(jax.jit, static_argnames=("l2_heads", "rows", "lanes"))
-def kda_conv(x, w, dy=None, l2_heads=None, rows=None, lanes=None):
+def kda_conv(x, w, dy=None, l2_heads=None, rows=None, lanes=None,
+             bias=None):
     """The forward kernel's result, or with its cotangent ``dy`` the
-    backward kernel's ``(dx, dw)``. One jitted name for both, which is
-    what a device trace calls them. ``rows`` and ``lanes`` cap a
-    block's time steps and lanes (``BLOCK_ROWS``, ``BLOCK_LANES``
-    where None)."""
+    backward kernel's ``(dx, dw)``, with a ``bias`` ``(dx, dw, db)``.
+    One jitted name for both, which is what a device trace calls
+    them. ``rows`` and ``lanes`` cap a block's time steps and lanes
+    (``BLOCK_ROWS``, ``BLOCK_LANES`` where None)."""
     taps_first = w.astype(F32).T  # [taps, heads x d]: lanes
     if dy is None:
-        return _forward(x, taps_first, l2_heads, rows, lanes)
-    dx, dw = _backward(x, taps_first, dy, l2_heads, rows, lanes)
-    return dx, dw.T.astype(w.dtype)
+        return _forward(x, taps_first, bias, l2_heads, rows, lanes)
+    dx, dw, *db = _backward(x, taps_first, bias, dy, l2_heads, rows, lanes)
+    return (dx, dw.T.astype(w.dtype),
+            *(d[0].astype(bias.dtype) for d in db))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -288,3 +319,21 @@ def _vjp_bwd(l2_heads, saved, dy):
 
 
 kda_conv_tpu.defvjp(_vjp_fwd, _vjp_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def kda_conv_bias_tpu(x, w, bias, l2_heads=None):
+    """``kda_conv_tpu`` with a bias a channel ahead of ``silu``."""
+    return kda_conv(x, w, l2_heads=l2_heads, bias=bias)
+
+
+def _bias_vjp_fwd(x, w, bias, l2_heads):
+    return kda_conv(x, w, l2_heads=l2_heads, bias=bias), (x, w, bias)
+
+
+def _bias_vjp_bwd(l2_heads, saved, dy):
+    x, w, bias = saved
+    return kda_conv(x, w, dy, l2_heads=l2_heads, bias=bias)
+
+
+kda_conv_bias_tpu.defvjp(_bias_vjp_fwd, _bias_vjp_bwd)

@@ -323,8 +323,12 @@ def moved_bias(bias: jax.Array, counts: jax.Array,
     return bias + (delta - jnp.mean(delta, axis=-1, keepdims=True))
 
 
-#: the gate's activation in an expert, by the model file's name for it
-ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+#: the activation in an expert, by the model file's name for it: the
+#: gate's, or in an expert without a gate matrix the one product's
+ACTIVATIONS = {
+    "silu": jax.nn.silu, "relu": jax.nn.relu,
+    "relu2": lambda x: jnp.square(jax.nn.relu(x)),
+}
 
 #: rows of one chunk of a share's walk at rows ``CHUNK_WIDTH`` wide: a
 #: multiple of 512, so that ``grouped_matmul.tiles`` keeps its row
@@ -397,15 +401,26 @@ def _products(rows, matrices, sizes):
         )
 
 
-def _gated(gate, up, scale, act):
-    """``act(gate) * up``, each row times its routing weight
-    ``scale`` [C] float32, in the products' dtype: the down
-    projection's input."""
+def _first_matrices(w_gate, w_up):
+    """The matrices an expert's input meets: the gate's and ``w_up``,
+    or ``w_up`` alone in an expert without a gate (``w_gate`` None)."""
+    return (w_up,) if w_gate is None else (w_gate, w_up)
+
+
+def _hidden(products, act):
+    """An expert's hidden rows from its first products: ``act(gate) *
+    up`` of ``(gate, up)``, ``act(up)`` of ``(up,)`` alone."""
+    *gate, up = products
+    return ACTIVATIONS[act](gate[0]) * up if gate else ACTIVATIONS[act](up)
+
+
+def _gated(products, scale, act):
+    """``_hidden``, each row times its routing weight ``scale`` [C]
+    float32, in the products' dtype: the down projection's input."""
     with jax.named_scope("moe.experts"):
         return (
-            (ACTIVATIONS[act](gate) * up).astype(jnp.float32)
-            * scale[:, None]
-        ).astype(gate.dtype)
+            _hidden(products, act).astype(jnp.float32) * scale[:, None]
+        ).astype(products[0].dtype)
 
 
 def _over_live_chunks(live, carry, order, group_sizes, rows):
@@ -442,6 +457,7 @@ def _walk(act, flat, weights, w_gate, w_up, w_down, order, group_sizes):
     three grouped matmuls on its own group sizes and adds its C
     results to their tokens in float32 (a token may occur more than
     once in a chunk). With every assignment held every chunk is live.
+    ``w_gate`` None: experts without a gate matrix, two products.
 
     Differentiated as ``_walk_held_rows``, whose backward pass is
     the same walk written out (``_walk_bwd``)."""
@@ -456,9 +472,9 @@ def _walk(act, flat, weights, w_gate, w_up, w_down, order, group_sizes):
         )
         with jax.named_scope("moe.dispatch"):
             mine = flat[tokens]
-        gate, up = _products(mine, (w_gate, w_up), sizes)
+        first = _products(mine, _first_matrices(w_gate, w_up), sizes)
         (mine,) = _products(
-            _gated(gate, up, scales[chosen], act), (w_down,), sizes
+            _gated(first, scales[chosen], act), (w_down,), sizes
         )
         with jax.named_scope("moe.combine"):
             return add_rows(out, tokens, mine)
@@ -498,37 +514,41 @@ def _walk_bwd(act, args, g):
     flat, weights, w_gate, w_up, w_down, order, group_sizes = args
     rows, _ = walk_chunks(weights.size, flat.shape[1])
     scales = _scales(weights, order)
-    matrices = (w_gate, w_up, w_down)
+    firsts = _first_matrices(w_gate, w_up)
+    matrices = (*firsts, w_down)
 
     def live(grads, start):
-        d_flat, d_scales, d_w_gate, d_w_up, d_w_down = grads
+        d_flat, d_scales, *d_firsts, d_w_down = grads
         chosen, tokens, sizes = _chunk(
             order, group_sizes, start, rows, weights
         )
         with jax.named_scope("moe.dispatch"):
             mine = flat[tokens]
-        (gate, up), to_rows = jax.vjp(
-            lambda r: _products(r, (w_gate, w_up), sizes), mine
+        first, to_rows = jax.vjp(
+            lambda r: _products(r, firsts, sizes), mine
         )
         # ``g``'s rows are gathered once both products of ``mine`` are
         # made: the two gathers depend on nothing of each other, and the
         # chip's scheduler, left the choice, has put ``g``'s between
         # the two products, a chunk's rows more alive at the step's
         # planned peak (PERF.md section 6, PR 37)
-        g_then, gate, up = jax.lax.optimization_barrier((g, gate, up))
+        g_then, *first = jax.lax.optimization_barrier((g, *first))
         with jax.named_scope("moe.combine"):
             cotangent = g_then[tokens]
         hidden, to_products = jax.vjp(
-            functools.partial(_gated, act=act), gate, up, scales[chosen]
+            functools.partial(_gated, act=act), tuple(first),
+            scales[chosen],
         )
         _, to_hidden = jax.vjp(
             lambda h: _products(h, (w_down,), sizes), hidden
         )
-        d_gate, d_up, d_scale = to_products(*to_hidden((cotangent,)))
-        (d_mine,) = to_rows((d_gate, d_up))
+        d_first, d_scale = to_products(*to_hidden((cotangent,)))
+        (d_mine,) = to_rows(d_first)
         with jax.named_scope("moe.experts"):
-            d_w_gate = add_rhs_gradient(d_w_gate, mine, d_gate, sizes)
-            d_w_up = add_rhs_gradient(d_w_up, mine, d_up, sizes)
+            d_firsts = [
+                add_rhs_gradient(d_w, mine, d, sizes)
+                for d_w, d in zip(d_firsts, d_first)
+            ]
             d_w_down = add_rhs_gradient(
                 d_w_down, hidden, cotangent, sizes
             )
@@ -536,7 +556,7 @@ def _walk_bwd(act, args, g):
             return (
                 add_rows(d_flat, tokens, d_mine),
                 d_scales.at[chosen].set(d_scale, unique_indices=True),
-                d_w_gate, d_w_up, d_w_down,
+                *d_firsts, d_w_down,
             )
 
     d_flat, d_scales, *d_matrices = _over_live_chunks(
@@ -545,11 +565,13 @@ def _walk_bwd(act, args, g):
          *(jnp.zeros(w.shape, jnp.float32) for w in matrices)),
         order, group_sizes, rows,
     )
+    d_matrices = [d.astype(w.dtype) for d, w in zip(d_matrices, matrices)]
+    if w_gate is None:
+        d_matrices = [None] + d_matrices
     return (
         d_flat.astype(flat.dtype),
         d_scales[:weights.size].reshape(weights.shape),
-        *(d.astype(w.dtype) for d, w in zip(d_matrices, matrices)),
-        None, None,
+        *d_matrices, None, None,
     )
 
 
@@ -560,7 +582,7 @@ def _share(flat, weights, experts, w_gate, w_up, w_down, act, first_held):
     """``dropless_moe_mlp``'s result [N, H] where the device holds
     the ``w_gate.shape[0]`` experts from ``first_held`` of the more
     that ``experts`` [N, k] chooses among."""
-    held, nk = w_gate.shape[0], experts.size
+    held, nk = w_up.shape[0], experts.size
     rows, chunks = walk_chunks(nk, flat.shape[1])
     with jax.named_scope("moe.dispatch"):
         # held experts by their place here, every absent one last
@@ -594,19 +616,29 @@ def dropless_moe_mlp(
     first_held: int = 0,
     shared: Tuple[jax.Array, jax.Array, jax.Array] = None,
     count: bool = False,
+    latent: Tuple[jax.Array, jax.Array] = None,
     **routing,  # ``route_logits``' gate, bias, norm_eps and scaling
 ) -> Tuple[jax.Array, jax.Array]:
     """MoE gated block (``act(gate) * up``, then down) in which every
     one of the ``N x k`` assignments to an expert on this device is
     computed: ``(out [batch, seq, hidden], aux)``, ``aux`` scaled as
-    ``moe_mlp``'s. ``logits``: the router's, where the model computes
+    ``moe_mlp``'s. ``w_gate`` None: experts without a gate matrix,
+    ``act(up)`` then down (``act`` "relu2": the relu's square).
+    ``logits``: the router's, where the model computes
     them elsewhere than from ``x`` (``router_logits``); else from
-    ``x`` here. ``shared``: the gate, up and down matrices ([hidden,
+    ``x`` here. ``shared``: the gate (or None, as ``w_gate``), up and
+    down matrices ([hidden,
     mlp'], [hidden, mlp'], [mlp', hidden]) of an expert that every
     token takes, unweighted and whole on every device, added to
     ``out`` (scope ``moe.shared``): of the devices that share a layer
     each computes it for its own tokens, so over the shares it counts
-    once. ``count``: ``(out, aux, counts)``, with the assignments
+    once. ``latent``: ``(down [hidden, latent], up [latent,
+    hidden])``, whole on every device: the routed experts read and
+    write rows ``latent`` wide, ``x`` through ``down`` (scope
+    ``moe.latent_down``), and their weighted sum goes through ``up``
+    (``moe.latent_up``; linear, so the shares' partial sums add up
+    past it as before it); router and shared expert read ``x``
+    itself. ``count``: ``(out, aux, counts)``, with the assignments
     each of the router's experts received, held here or not, int32
     [experts] (``expert_counts`` of the selection): what a rule that
     moves the selection bias reads (``moved_bias``).
@@ -641,7 +673,7 @@ def dropless_moe_mlp(
     from dlrover_tpu.ops.grouped_matmul import grouped_matmul
 
     b, s, h = x.shape
-    e, held = gate_w.shape[-1], w_gate.shape[0]
+    e, held = gate_w.shape[-1], w_up.shape[0]
     if not 0 <= first_held <= e - held:
         raise ValueError(
             f"experts {first_held}..{first_held + held - 1} of {e}"
@@ -658,21 +690,27 @@ def dropless_moe_mlp(
         counts = (expert_counts(experts, e),) if count else ()
 
     def result(out):
-        """``out`` with the shared expert's term, ``aux`` and, asked
-        for, the counts."""
+        """``out`` [N, .] through the latent's way up and with the
+        shared expert's term, ``aux`` and, asked for, the counts."""
+        if latent is not None:
+            with jax.named_scope("moe.latent_up"):
+                out = out @ latent[1]
+        out = out.reshape(b, s, h)
         if shared is not None:
             with jax.named_scope("moe.shared"):
-                ws_gate, ws_up, ws_down = shared
-                out = out + (
-                    ACTIVATIONS[act](x @ ws_gate) * (x @ ws_up)
-                ) @ ws_down
+                *ws_first, ws_down = shared
+                out = out + _hidden(tuple(
+                    x @ w for w in _first_matrices(*ws_first)
+                ), act) @ ws_down
         return (out, aux, *counts)
 
+    if latent is not None:
+        with jax.named_scope("moe.latent_down"):
+            flat = flat @ latent[0]
     if held < e:
-        out = _share(
+        return result(_share(
             flat, weights, experts, w_gate, w_up, w_down, act, first_held
-        )
-        return result(out.reshape(b, s, h))
+        ))
     with jax.named_scope("moe.dispatch"):
         assigned = experts.reshape(n * k)
         order = jnp.argsort(assigned, stable=True).astype(jnp.int32)
@@ -685,21 +723,23 @@ def dropless_moe_mlp(
         grouped = functools.partial(
             grouped_matmul, group_sizes=group_sizes
         )
-        gate = checkpoint_name(grouped(rows, w_gate), "moe_gate")
-        up = checkpoint_name(grouped(rows, w_up), "moe_up")
+        first = () if w_gate is None else (
+            checkpoint_name(grouped(rows, w_gate), "moe_gate"),)
+        first += (checkpoint_name(grouped(rows, w_up), "moe_up"),)
         # the routing weight goes onto the down product's input: the
         # product is linear in it, and the weight's gradient then
         # needs that input (made again from the two kept products)
         # and not the down product's result, which nothing keeps
         hidden = (
-            (ACTIVATIONS[act](gate) * up).astype(jnp.float32)
+            _hidden(first, act).astype(jnp.float32)
             * weights.reshape(n * k)[order][:, None]
         ).astype(x.dtype)
         rows = grouped(hidden, w_down)
     with jax.named_scope("moe.combine"):
-        mine = _to_token_order(rows, order, inverse).reshape(n, k, h)
+        mine = _to_token_order(rows, order, inverse).reshape(
+            n, k, rows.shape[-1])
         out = jnp.sum(mine.astype(jnp.float32), axis=1).astype(x.dtype)
-    return result(out.reshape(b, s, h))
+    return result(out)
 
 
 def tokens_per_expert(

@@ -54,6 +54,7 @@ MODELS = {
     "llama_latent_tiny": llama.llama_latent_tiny,
     "llama_linear_tiny": llama.llama_linear_tiny,
     "llama_sandwich_tiny": llama.llama_sandwich_tiny,
+    "llama_mamba_tiny": llama.llama_mamba_tiny,
 }
 
 
@@ -226,7 +227,8 @@ def main():
     if cfg.mtp_layers:
         mtp_loss = jax.jit(functools.partial(llama.mtp_loss, cfg=cfg))
     decay_min = None
-    if "linear_attention" in (cfg.layer_types or ()):
+    state_space = "M" in (cfg.hybrid_override_pattern or "")
+    if "linear_attention" in (cfg.layer_types or ()) or state_space:
         decay_min = jax.jit(functools.partial(llama.decay_min, cfg=cfg))
 
     device = devices[0]
@@ -319,13 +321,18 @@ def main():
                     print(f"MTP_LOSS step={step} loss={float(loss):.4f} "
                           f"mtp_loss={term:.4f}", flush=True)
                 if decay_min is not None:
-                    # how fast the delta rule's fastest channel
-                    # forgets on this batch (GET /metrics)
+                    # how fast the delta rule's fastest channel, or
+                    # the state-space scan's fastest head, forgets on
+                    # this batch (GET /metrics)
                     least = llama.set_decay_min_gauge(
-                        decay_min(params, mb[0][0])
+                        decay_min(params, mb[0][0]),
+                        "ssm_decay_min" if state_space else "kda_decay_min",
                     )
-                    print(f"KDA_DECAY step={step} min_alpha={least:.3e}",
-                          flush=True)
+                    print(
+                        f"SSM_DECAY step={step} min_a={least:.3e}"
+                        if state_space else
+                        f"KDA_DECAY step={step} min_alpha={least:.3e}",
+                        flush=True)
                 ckpt.save(
                     step,
                     {"params": params, "opt_state": opt_state,

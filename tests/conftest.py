@@ -118,8 +118,30 @@ MODULE_BUDGET_OVERRIDES = {
     # workers; since PR 49 trinity's nine-layer step at the least
     # effort, 70s of its own; since PR 52 the windowed kernels at
     # trinity's shape as well, two compiles of 12s: 965s beside five
-    # other workers
-    "test_chip_compile": 1000.0,
+    # other workers; since PR 54 nemotron's eleven-layer step at the
+    # least effort, 50-60s of its own, and the scan's kernels alone
+    "test_chip_compile": 1200.0,
+    # the state-space scan's Pallas kernels in interpret mode, forward
+    # and backward on seven shapes (PR 54): 40s alone, 85s beside
+    # three other workers
+    "test_ssd": 150.0,
+    # the convolution's kernels in interpret mode, since PR 54 with a
+    # bias as well (nine more cases): 63s beside three other workers
+    "test_kda_conv": 100.0,
+    # the walk's written-out backward jitted for experts without a
+    # gate, in a latent and not, against a dense loop (PR 54): 69s
+    # beside three other workers
+    "test_moe_ungated": 120.0,
+    # eleven-layer one-branch models jitted forward and backward, and
+    # a three-layer one under each remat policy (PR 54): 110s beside
+    # three other workers
+    "test_llama_state_space": 200.0,
+    # seventeen edited references on two batches of an eleven-layer
+    # model with a position-by-position scan (PR 54): 150s beside
+    # three other workers
+    "test_yardstick_nemotron": 240.0,
+    # two whole rehearsals of the new cell, launcher to last line: 69s
+    "test_yardstick_nemotron_rehearsal": 150.0,
     # Pallas kernels in interpret mode, since PR 39 the in-place sum
     # against megablox's on seven pieces: 47s alone, 71s beside five
     # other workers

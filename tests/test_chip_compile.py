@@ -1354,6 +1354,145 @@ def test_solar_step_holds_the_delta_rules_kernels(
     assert tuning.last_selection()["gqa_group"] == 8
 
 
+#: ``peak_memory_in_bytes`` of ``nemotron-3-super-120b-a12b-ep64
+#: .steady``'s step as this file compiles it (1 x 8,192, eleven layers
+#: and the module, remat ``minimal``, the least effort; PERF.md, PR
+#: 54): 8.27 GB of it the state. At the default effort it read
+#: 11,458,404,352
+NEMOTRON_STEP_BYTES = 11_462_598_656
+
+
+def test_ssd_kernels_compile_at_the_cells_shape(topo, monkeypatch):
+    """The state-space scan's forward and backward kernels at the
+    cell's shape (one sequence of 8,192, 128 heads of 64 in 8 groups of
+    128 states): a grid step a group's sixteen heads, 1,024 lanes, and
+    the entry states kept for the backward pass."""
+    from dlrover_tpu.ops import ssd
+    from dlrover_tpu.ops.pallas import ssd as kernels
+
+    monkeypatch.setattr(
+        ssd, "_use_pallas", lambda x, B, heads, groups: True)
+    monkeypatch.setattr(kernels, "_interpret", lambda: False)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def of(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (
+        of((1, 8192, 8192), jnp.bfloat16), of((1, 8192, 1024), jnp.bfloat16),
+        of((1, 8192, 1024), jnp.bfloat16), of((1, 8192, 128), jnp.float32),
+        of((128,), jnp.float32), of((128,), jnp.float32),
+    )
+
+    def loss(*operands):
+        return ssd.ssd_scan(*operands, 128, 8).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+        *args).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert "f32[1,8,64,128,1024]" in text  # the chunks' entry states
+
+
+def test_nemotron_step_holds_the_scans_kernels(topo, on_tpu_path, monkeypatch):
+    """``nemotron-3-super-120b-a12b-ep64.steady``'s step: it fits and
+    plans no more than was read when the cell was built; a mixer's
+    Pallas calls (the forward, the forward again under ``minimal``,
+    and the backward over the entry states it kept) are named as the
+    benchmark's ``ssd_ms`` tells them, and as no other reader does,
+    and carry ``ssm.scan``; the convolution with its bias is fifteen
+    Pallas calls under ``ssm.conv`` by the jitted name ``kda_conv``;
+    every call of either entry took the kernels; the two attention
+    layers (the stack's and the module's) run the one backward kernel
+    at a group of 16; and the expert layers' 1024 x 2688 products take
+    the tiles the rule gives them."""
+    from dlrover_tpu.ops import grouped_matmul as gm, kda_conv, ssd
+    from dlrover_tpu.ops.pallas import kda_conv as conv_kernels
+    from dlrover_tpu.ops.pallas import ssd as scan_kernels
+    from dlrover_tpu.telemetry.registry import counter, gauge
+    from yardstick import cells, worker
+    from yardstick.layer_metrics import (
+        attn_kernel_ms, delta_rule_ms, moe_expert_ms, short_conv_ms, ssd_ms,
+    )
+
+    monkeypatch.setattr(gm, "_use_pallas", lambda lhs, rhs: True)
+    monkeypatch.setattr(gm, "_interpret", lambda: False)
+    monkeypatch.setattr(gm, "_add_on_mxu", lambda out, rows: True)
+    monkeypatch.setattr(
+        ssd, "_use_pallas", lambda x, B, heads, groups: (
+            scan_kernels.tiles_the_kernel(x.shape, B.shape, heads, groups)))
+    monkeypatch.setattr(scan_kernels, "_interpret", lambda: False)
+    monkeypatch.setattr(
+        kda_conv, "_use_pallas", lambda x, w, l2_heads: (
+            conv_kernels.tiles_the_kernel(x.shape, w.shape, l2_heads)))
+    monkeypatch.setattr(conv_kernels, "_interpret", lambda: False)
+    calls = [counter(f"ssd_{path}_calls", "") for path in ("kernel", "plain")]
+    calls += [counter(f"kda_conv_{path}_calls", "")
+              for path in ("kernel", "plain")]
+    before = [c.value for c in calls]
+    gauge("ssd_heads_per_step", "").set(0)
+    _, config, traffic = cells.load_cell(
+        "nemotron-3-super-120b-a12b-ep64.steady")
+    cfg = worker.program_config(config, traffic)
+    assert (cfg.remat, cfg.loss_chunk) == ("minimal", 0)
+    assert gm.tiles(20480, 1024, 2688) == (512, 1024, 896)
+    assert gm.tiles(20480, 2688, 1024) == (512, 896, 1024)
+    assert moe.walk_chunks(8192 * 22, 1024) == (20480, 9)
+    mesh = Mesh(
+        np.array(topo.devices[:1]).reshape(1, 1), ("data", "fsdp"))
+    trainer = make_trainer_for_llama(
+        cfg, mesh, strategy=traffic["strategy"],
+        optimizer=optax.adamw(traffic["optimizer"]["learning_rate"]),
+    )
+    compiled = trainer.train_step.lower(*_abstract_step_args(
+        trainer, traffic["global_batch"], traffic["seq"])
+    ).compile(LEAST_EFFORT)
+    planned = compiled.memory_analysis().peak_memory_in_bytes
+    print("nemotron step plans", planned)
+    assert planned <= NEMOTRON_STEP_BYTES < 15.75 * 2 ** 30
+    text = compiled.as_text()
+    kernels = re.findall(
+        r"%([\w.\-]+) = ([^\n]*?) custom-call\([^\n]*"
+        r"custom_call_target=\"tpu_custom_call\"[^\n]*"
+        r"op_name=\"([^\"]*)\"", text)
+    scan = [(name, op) for name, _, op in kernels
+            if ssd_ms.KERNEL.search(name)]
+    # five mixers, each the forward, the forward again and the backward
+    assert len(scan) == 5 * 3, [name for name, _ in scan]
+    assert all("ssm.scan" in op for _, op in scan)
+    others = [name for name, _, op in kernels if "ssm.scan" not in op]
+    assert others and not any(ssd_ms.KERNEL.search(n) for n in others)
+    assert not any(
+        reader.KERNEL.search(name) for name, _ in scan for reader in (
+            attn_kernel_ms, delta_rule_ms, moe_expert_ms, short_conv_ms))
+    # the chunks' entry states: [batch, groups, chunks, 128, 16 x 64]
+    assert "f32[1,8,64,128,1024]" in text
+    conv = [name for name, _, op in kernels if "ssm.conv" in op]
+    assert len(conv) == 5 * 3, conv
+    assert all(name.startswith("kda_conv") for name in conv)
+    assert not any(
+        reader.KERNEL.search(name) for name in conv for reader in (
+            attn_kernel_ms, delta_rule_ms, moe_expert_ms, short_conv_ms,
+            ssd_ms))
+    # the stack's attention layer and the module's: the forward, the
+    # forward again and the one backward kernel each (a group of 16: a
+    # kv head's dK and dV resident)
+    assert sum(bool(attn_kernel_ms.KERNEL.search(n)) for n in others) == 6
+    assert fa._one_backward_kernel(16, 8192, 128)
+    assert tuning.last_selection()["gqa_group"] == 16
+    # six expert layers' walks: grouped products, none of them read as
+    # another operator's
+    assert sum(bool(moe_expert_ms.KERNEL.search(n)) for n in others) >= 18
+    # every mixer's call of either entry took the kernels (a call a
+    # layer: the remat's second forward reuses its trace)
+    assert [c.value - was for c, was in zip(calls, before)] == [5, 0, 5, 0]
+    assert gauge("ssd_heads_per_step", "").value == 16
+    assert gauge("ssd_state_bytes", "").value == 16 * 64 * 128 * 4
+    for scope in ("ssm.in_proj", "ssm.dt", "ssm.gate_norm", "ssm.out_proj",
+                  "moe.latent_down", "moe.latent_up", "moe.shared",
+                  "mtp.block", "attn.full"):
+        assert scope in text, scope
+
+
 #: ``peak_memory_in_bytes`` of ``trinity-mini-ep8.steady``'s step as
 #: this file compiles it (1 x 16,384, nine layers, remat ``minimal``,
 #: the least effort; PERF.md, PR 49): 7.46 GB of it the state. At the

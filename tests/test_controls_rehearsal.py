@@ -22,6 +22,7 @@ CHEAP_COMPILES = {
     "XLA_FLAGS": "--xla_backend_optimization_level=0",
 }
 JOYAI, SOLAR = "joyai-llm-flash-ep8.steady", "solar-open2-250b-ep32.steady"
+NEMOTRON = "nemotron-3-super-120b-a12b-ep64.steady"
 FLOAT8 = "the reference in float8"
 #: what the float32 reference and the bfloat16 program differ by at
 #: these widths and seeds, at most (the solar rehearsal's bound)
@@ -40,6 +41,8 @@ RUNS = {
        for tiny, (cell, seed, controls) in CASES.items()},
     "probe": ("--cell", SOLAR, "--rehearse", "tiny-solar",
               "--seeds", "2", "--probe", "2"),
+    "probe-nemotron": ("--cell", NEMOTRON, "--rehearse", "tiny-nemotron",
+                       "--seeds", "2", "--probe", "2"),
 }
 
 
@@ -85,17 +88,27 @@ def test_rehearsal_runs_the_familys_controls(tiny, finished):
         assert off[control] > AGREES, off
 
 
-def test_rehearsal_probes_the_routers_and_the_decay(finished):
-    rc, rows, err = finished("probe")
+@pytest.mark.parametrize("run,tiny,expert_layers,decays", [
+    ("probe", "tiny-solar", 4, 4), ("probe-nemotron", "tiny-nemotron", 5, 11),
+])
+def test_rehearsal_probes_the_routers_and_the_decay(
+        run, tiny, expert_layers, decays, finished):
+    rc, rows, err = finished(run)
     assert rc == 0, err[-2000:]
     (row,) = rows
-    assert (row["probe"], row["rehearse"]) == (2, "tiny-solar")
+    assert (row["probe"], row["rehearse"]) == (2, tiny)
     assert [r["step"] for r in row["rows"]] == [0, 1]
     for r in row["rows"]:
-        # a row an expert layer; a delta-rule layer's alpha under 1
-        assert len(r["held_rows"]) == len(r["max_over_mean"]) > 0
+        # a row an expert layer (of a stack of one-branch blocks the
+        # expert layers alone); a delta-rule layer's alpha, or a
+        # state-space layer's a, under 1 and every other layer's at 1
+        assert len(r["held_rows"]) == len(r["max_over_mean"]) == (
+            expert_layers)
         assert min(r["max_over_mean"]) >= 1.0
+        assert len(r["decay_min"]) == decays
         assert 0.0 < min(r["decay_min"]) < 1.0
+        assert len(r.get("bias_abs_max", [0] * expert_layers)) == (
+            expert_layers)
         assert r["loss"] > 0 and r["seconds"] > 0
 
 

@@ -173,3 +173,95 @@ def test_off_the_tpu_the_entry_takes_the_plain_path():
         kda_conv.conv_silu_norm(x[..., :128], w)
     with pytest.raises(ValueError, match="in 3 heads"):
         kda_conv.conv_silu_norm(x, w, l2_heads=3)
+
+
+# -- a bias a channel (a Mamba-2 layer's ``use_conv_bias``) -------------------
+
+def _bias(width, dtype=F32):
+    return jax.random.normal(jax.random.key(7), (width,)).astype(dtype)
+
+
+def test_the_plain_path_adds_the_bias_ahead_of_silu():
+    x, w, _ = _case(F32, seq=8, heads=2, d=4)
+    bias = _bias(8)
+    a = np.asarray(kda_conv.causal_taps(x, w)) + np.asarray(bias)
+    np.testing.assert_allclose(
+        kda_conv.conv_silu_norm(x, w, bias=bias), a / (1 + np.exp(-a)),
+        rtol=1e-5, atol=1e-6)
+    with pytest.raises(ValueError, match="bias"):
+        kda_conv.conv_silu_norm(x, w, bias=bias[:4])
+
+
+@pytest.mark.parametrize("l2_heads", [None, 2], ids=["no norm", "l2"])
+@pytest.mark.parametrize("dtype,rows,lanes", [
+    (F32, 16, 128), (F32, None, None), (jnp.bfloat16, 16, 128),
+], ids=["f32 16x128", "f32 whole", "bf16 16x128"])
+def test_the_kernels_with_a_bias_agree_with_the_plain_path(
+        dtype, rows, lanes, l2_heads):
+    """Forward and the three gradients, the bias's summed in float32
+    over the batch and the sequence beside ``dw``'s."""
+    x, w, dy = _case(dtype)
+    bias = _bias(w.shape[0])
+
+    def loss(x, w, bias):
+        out = kda_conv.conv_silu_norm_plain(x, w, l2_heads, bias)
+        return jnp.sum(out.astype(F32) * dy.astype(F32))
+
+    want = (kda_conv.conv_silu_norm_plain(x, w, l2_heads, bias),
+            *jax.grad(loss, (0, 1, 2))(x, w, bias))
+    got = (
+        kernels.kda_conv(x, w, l2_heads=l2_heads, rows=rows, lanes=lanes,
+                         bias=bias),
+        *kernels.kda_conv(x, w, dy, l2_heads=l2_heads, rows=rows,
+                          lanes=lanes, bias=bias),
+    )
+    assert len(got) == 4 and got[3].dtype == F32
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        a, b = a.astype(F32), b.astype(F32)
+        if dtype == F32:
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=2e-5)
+        else:
+            assert float(jnp.max(
+                jnp.abs(a - b) - 2.0 ** -8 * jnp.maximum(
+                    jnp.abs(a), jnp.abs(b)))) <= 2.0 ** -8
+
+
+def test_a_call_without_a_bias_builds_what_it_built():
+    """The bias is an operand of its own kernels: a call without one
+    traces the Pallas calls it traced before the bias, with the
+    operands and results they had."""
+    x, w, dy = _case(F32)
+
+    def calls(f, *args):
+        return [
+            (len(e.invars), len(e.outvars))
+            for e in jax.make_jaxpr(f)(*args).jaxpr.eqns[0].params[
+                "jaxpr"].eqns if e.primitive.name == "pallas_call"
+        ]
+
+    assert calls(lambda x, w: kernels.kda_conv(x, w), x, w) == [(3, 1)]
+    assert calls(
+        lambda x, w, dy: kernels.kda_conv(x, w, dy), x, w, dy) == [(6, 2)]
+    bias = _bias(w.shape[0])
+    assert calls(
+        lambda x, w, b: kernels.kda_conv(x, w, bias=b), x, w, bias
+    ) == [(4, 1)]
+    assert calls(
+        lambda x, w, dy, b: kernels.kda_conv(x, w, dy, bias=b),
+        x, w, dy, bias) == [(7, 3)]
+
+
+def test_the_custom_rule_hands_the_bias_its_gradient():
+    x, w, dy = _case(F32)
+    bias = _bias(w.shape[0])
+
+    def through(f):
+        return jax.grad(
+            lambda x, w, b: jnp.sum(f(x, w, b) * dy), (0, 1, 2))(x, w, bias)
+
+    for a, b in zip(
+            through(lambda x, w, b: kernels.kda_conv_bias_tpu(x, w, b, None)),
+            through(lambda x, w, b: kda_conv.conv_silu_norm_plain(
+                x, w, None, b))):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=2e-5)

@@ -1,0 +1,36 @@
+"""The state-space scan kernels' share of their roofline."""
+
+from yardstick import cells, counts
+from yardstick.layer_metrics import ssd_ms
+
+NAME, UNIT = "ssd_roofline_pct", "%"
+LAYER = "state-space scan"
+MOVES, SOURCE = "tokens_per_s", "device_trace"
+
+
+def least_seconds(run):
+    """``(seconds, bound)``: the least time one chip could take for
+    its tokens' scans in a step (the family's ``ssd_step``: forward
+    once and backward once, the recurrence's own operations and the
+    least bytes), and whether operations or bytes set it; None for a
+    family without the operator."""
+    count = getattr(cells.family_module(run["config"]), "ssd_step", None)
+    if count is None:
+        return None
+    traffic = run["traffic"]
+    tokens = (
+        traffic["global_batch"] * traffic["seq"] // run["cell"]["chips"]
+    )
+    return counts.roofline_seconds(
+        *count(run["config"], tokens), run["peak"]
+    )
+
+
+def read(run):
+    if run["trace"] is None or run["peak"] is None:
+        return None
+    took = ssd_ms.kernel_seconds_per_step(run["trace"])
+    least = least_seconds(run)
+    if took is None or least is None:
+        return None
+    return 100.0 * least[0] / took
