@@ -233,6 +233,21 @@ GATES = {
 }
 
 
+def _scores_at(probs: jax.Array, experts: jax.Array) -> jax.Array:
+    """``probs[n, experts[n, j]]``, [N, k], read by comparing each
+    chosen expert with the expert index and summing over the experts:
+    a sum of one score and zeros is that score, and ``experts``' k a
+    token are distinct, so the gradient's sum over k has one term a
+    place too. The same bits as ``jnp.take_along_axis`` and its
+    scatter-add, which the chip runs as a scalar gather at 10 ns an
+    element and, backward, a sort and a scatter of the ``N x k``
+    assignments (PERF.md, PR 55)."""
+    hit = experts[..., None] == jnp.arange(
+        probs.shape[-1], dtype=experts.dtype
+    )
+    return jnp.sum(jnp.where(hit, probs[..., None, :], 0.0), axis=-1)
+
+
 def route(
     flat: jax.Array,  # [N, H]
     gate_w: jax.Array,  # [H, E]
@@ -289,7 +304,7 @@ def route_logits(
         _, experts = jax.lax.top_k(
             probs + jax.lax.stop_gradient(bias), k
         )
-        weights = jnp.take_along_axis(probs, experts, axis=-1)
+        weights = _scores_at(probs, experts)
     shares = probs
     if gate != "softmax":
         shares = probs / jnp.sum(probs, axis=-1, keepdims=True)

@@ -792,6 +792,117 @@ def test_grouped_matmul_kernels_compile_at_the_cells_shapes(
     assert "ragged-dot" not in text
 
 
+def _compiled_route(one_chip, tokens, experts, k, biased, **routing):
+    """``route_logits`` and its gradient at a cell's shape, compiled
+    for the described chip under the expert layer's scope: the
+    weights against a cotangent plus ``aux``, as a layer's loss
+    reaches them."""
+    def reached(logits, bias, cot):
+        with jax.named_scope("moe.route"):
+            weights, chosen, aux = moe.route_logits(
+                logits, k, True, bias=bias if biased else None, **routing)
+        return jnp.sum(weights * cot) + aux, chosen
+
+    shapes = [
+        jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+        for shape in ((tokens, experts), (experts,), (tokens, k))
+    ]
+    return jax.jit(jax.value_and_grad(reached, has_aux=True)).lower(
+        *shapes).compile().as_text()
+
+
+def _dims(result):
+    """The shapes in an instruction's result, as lists of ints."""
+    return [[int(d) for d in dims.split(",") if d]
+            for dims in re.findall(r"\w\[([\d,]*)\]", result)]
+
+
+def _scalar_moves(text, tokens, k, scope=""):
+    """What a compiled program holds of the instructions the chip
+    runs an element at a time (PERF.md, PR 55: 10 ns an element):
+    every ``gather``, ``scatter`` and packing of their indices, and
+    every sort of the ``tokens x k`` assignments; with a ``scope``,
+    those whose ``op_name`` holds it (in a whole step the transpose
+    of a gather carries none)."""
+    found = []
+    for comp, instructions in _parse_hlo(text)[0].items():
+        for name, (result, op, _, attrs) in instructions.items():
+            if scope not in attrs:
+                continue
+            if op in ("gather", "scatter") or (
+                op == "custom-call" and "GatherScatter" in attrs
+            ) or (
+                op == "sort" and [tokens * k] in _dims(result)
+            ):
+                found.append((comp, name, op, result))
+    return found
+
+
+def _routers_compare(text, traffic, cfg):
+    """In a cell's whole step the routers read their k scores by
+    comparison (PERF.md, PR 55): nothing under ``moe.route`` moves a
+    scalar at a time."""
+    assert _scalar_moves(
+        text, traffic["global_batch"] * traffic["seq"], cfg.moe_top_k,
+        scope="moe.route") == []
+
+
+@pytest.mark.parametrize("tokens,experts,k", [
+    (8192, 512, 22), (16384, 128, 8),
+], ids=["nemotron", "trinity"])
+def test_biased_router_compiles_to_no_gather(topo, tokens, experts, k):
+    """A router with a selection bias at Nemotron's and
+    ``trinity-mini``'s shapes, with its gradient: the k scores are
+    read by a select and a sum over the experts, so the program holds
+    no gather or scatter, no sort but ``top_k``'s over the experts,
+    and nothing of shape [tokens, k, experts] between its fusions."""
+    text = _compiled_route(
+        SingleDeviceSharding(topo.devices[0]), tokens, experts, k, True,
+        gate="sigmoid")
+    assert _scalar_moves(text, tokens, k) == []
+    comps, _, _, entry = _parse_hlo(text)
+    sorts = [result for result, op, _, _ in comps[entry].values()
+             if op == "sort"]
+    assert len(sorts) == 1 and _dims(sorts[0])[0] == [tokens, experts]
+    held = [name for name, (result, _, _, _) in comps[entry].items()
+            if any(sorted(d) == sorted([tokens, k, experts])
+                   for d in _dims(result))]
+    assert held == []
+
+
+#: sha256 (first 16 digits) of ``_compiled_route``'s instructions,
+#: without their metadata, for a router that has no bias, at OLMoE's
+#: and SmallThinker's shapes: what commit e8a7988 (PR 54) compiles.
+#: A PR that changes what such a router runs reads those cells again
+#: and then writes its own here (the test prints them)
+ROUTE_WITHOUT_BIAS = {
+    "olmoe": "53bcedc15f5242cb",
+    "smallthinker": "2978ee9ab3f86c39",
+}
+
+
+@pytest.mark.parametrize("cell,tokens,experts,k", [
+    ("olmoe", 3 * 4096, 64, 8), ("smallthinker", 16384, 64, 6),
+])
+def test_router_without_a_bias_compiles_as_it_did(
+    topo, cell, tokens, experts, k
+):
+    """``top_k``'s own values: the branch a biased router's change
+    leaves alone, so the cells that take it compile the program they
+    did."""
+    text = _compiled_route(
+        SingleDeviceSharding(topo.devices[0]), tokens, experts, k, False)
+    instructions = [
+        line for line in re.sub(
+            r", metadata=\{[^}]*\}", "", text).splitlines()
+        if re.match(r"\s+(ROOT )?%|%|ENTRY ", line)
+    ]
+    digest = hashlib.sha256(
+        "\n".join(instructions).encode()).hexdigest()[:16]
+    print("route without a bias", cell, digest, len(instructions))
+    assert digest == ROUTE_WITHOUT_BIAS[cell], digest
+
+
 def test_olmoe_step_keeps_megabloxs_tgmm(topo, on_tpu_path, monkeypatch):
     """``olmoe-1b-7b-1chip.steady``'s step, traced and lowered for
     the chip (not compiled): with every expert held the layer is one
@@ -1075,6 +1186,7 @@ def test_lfm2_step_holds_the_convolutions_kernels(
     assert compiled.memory_analysis().peak_memory_in_bytes <= (
         LFM2_STEP_BYTES)
     text = compiled.as_text()
+    _routers_compare(text, traffic, cfg)
     kernels = re.findall(
         r"%([\w.\-]+) = ([^\n]*?) custom-call\([^\n]*"
         r"custom_call_target=\"tpu_custom_call\"[^\n]*"
@@ -1117,8 +1229,10 @@ def test_lfm2_step_holds_the_convolutions_kernels(
 #: state. The step with q and k built whole outside the kernels (PR
 #: 42) read 15,958,852,096 here: by this statistic the parts plan 55
 #: MB more (at the default effort 46), by the buffer assignment's
-#: total 119 MB less, and on the chip the same (PERF.md section 6)
-JOYAI_STEP_BYTES = 16_013_520_896
+#: total 119 MB less, and on the chip the same (PERF.md section 6).
+#: 16,013,520,896 until the routers read their k scores by comparison
+#: (PR 55): a quarter of a megabyte more at this effort
+JOYAI_STEP_BYTES = 16_013_783_040
 
 
 def test_joyai_step_holds_no_whole_q_or_k(topo, on_tpu_path, monkeypatch):
@@ -1155,6 +1269,7 @@ def test_joyai_step_holds_no_whole_q_or_k(topo, on_tpu_path, monkeypatch):
     assert compiled.memory_analysis().peak_memory_in_bytes <= (
         JOYAI_STEP_BYTES)
     text = compiled.as_text()
+    _routers_compare(text, traffic, cfg)
     whole = re.findall(
         r"bf16\[(?:4,8192,32|4,32,8192|128,1,8192|128,8192),192\]", text)
     assert not whole, len(whole)
@@ -1285,6 +1400,7 @@ def test_solar_step_holds_the_delta_rules_kernels(
     print("solar step plans", planned)
     assert planned <= SOLAR_STEP_BYTES
     text = compiled.as_text()
+    _routers_compare(text, traffic, cfg)
     kernels = re.findall(
         r"%([\w.\-]+) = ([^\n]*?) custom-call\([^\n]*"
         r"custom_call_target=\"tpu_custom_call\"[^\n]*"
@@ -1450,6 +1566,7 @@ def test_nemotron_step_holds_the_scans_kernels(topo, on_tpu_path, monkeypatch):
     print("nemotron step plans", planned)
     assert planned <= NEMOTRON_STEP_BYTES < 15.75 * 2 ** 30
     text = compiled.as_text()
+    _routers_compare(text, traffic, cfg)
     kernels = re.findall(
         r"%([\w.\-]+) = ([^\n]*?) custom-call\([^\n]*"
         r"custom_call_target=\"tpu_custom_call\"[^\n]*"
@@ -1495,9 +1612,14 @@ def test_nemotron_step_holds_the_scans_kernels(topo, on_tpu_path, monkeypatch):
 
 #: ``peak_memory_in_bytes`` of ``trinity-mini-ep8.steady``'s step as
 #: this file compiles it (1 x 16,384, nine layers, remat ``minimal``,
-#: the least effort; PERF.md, PR 49): 7.46 GB of it the state. At the
-#: default effort it read 15,546,647,040
-TRINITY_STEP_BYTES = 15_488_046_080
+#: the least effort; PERF.md, PR 49): 7.46 GB of it the state. It
+#: read 15,488,046,080 here and 15,546,647,040 at the default effort
+#: while the routers gathered their k scores; since they compare (PR
+#: 55) the default effort, which the chip compiles at, plans
+#: 15,524,612,608, and the least effort 108 MB more than it did: it
+#: alone lifts the select's zeros, a float32 [16384, 8, 128], out of
+#: the layer loop and carries them through it
+TRINITY_STEP_BYTES = 15_596_441_600
 
 
 def test_trinity_step_fits_and_moves_the_bias(
@@ -1540,6 +1662,7 @@ def test_trinity_step_fits_and_moves_the_bias(
     print("trinity step plans", planned)
     assert planned <= TRINITY_STEP_BYTES < 16.91e9
     text = compiled.as_text()
+    _routers_compare(text, traffic, cfg)
     kernels = re.findall(
         r"%([\w.\-]+) = ([^\n]*?) custom-call\([^\n]*"
         r"custom_call_target=\"tpu_custom_call\"[^\n]*"

@@ -184,3 +184,76 @@ def test_capacity_path_follows_the_config_too():
             ref, ref_aux = moe.dropless_moe_mlp(*args, 2, norm)
             np.testing.assert_allclose(out, ref, atol=2e-5)
             np.testing.assert_allclose(aux, ref_aux, rtol=1e-6)
+
+
+_GATES = {"sigmoid": jax.nn.sigmoid,
+          "softmax": lambda logits: jax.nn.softmax(logits, axis=-1)}
+
+
+def _bits(x):
+    return np.asarray(x).view(np.uint32)
+
+
+def _tied_logits(seed, e, tokens=48):
+    """Logits in steps of a quarter (scores tie all over a row), the
+    first two experts the same column, and a bias drawn in steps of an
+    eighth (score plus bias ties too)."""
+    ks = jax.random.split(jax.random.key(seed), 3)
+    logits = jnp.round(4.0 * jax.random.normal(ks[0], (tokens, e))) / 4.0
+    logits = logits.at[:, 1].set(logits[:, 0])
+    bias = jnp.round(8.0 * 0.3 * jax.random.normal(ks[1], (e,))) / 8.0
+    return logits, bias, ks[2]
+
+
+@pytest.mark.parametrize("e,k,gate", [
+    (32, 4, "sigmoid"), (128, 8, "sigmoid"), (256, 8, "sigmoid"),
+    (320, 8, "sigmoid"), (512, 22, "sigmoid"), (64, 8, "softmax"),
+])
+def test_biased_weights_are_take_along_axis_bit_for_bit(e, k, gate):
+    """A biased router's weights and ``d weights / d logits`` against
+    ``jnp.take_along_axis`` and its scatter-add, written here: the
+    same float32 bits, with scores that tie and a drawn bias, whether
+    the route is run op by op or as one jitted program."""
+    logits, bias, key = _tied_logits(e + k, e)
+    cot = jax.random.normal(key, (logits.shape[0], k))
+
+    def reference(logits):
+        probs = _GATES[gate](logits)
+        _, experts = jax.lax.top_k(probs + bias, k)
+        return jnp.take_along_axis(probs, experts, axis=-1), experts
+
+    def routed(logits):
+        weights, experts, _ = moe.route_logits(
+            logits, k, False, gate=gate, bias=bias)
+        return weights, experts
+
+    for wrap in (lambda f: f, jax.jit):
+        want, want_vjp, want_experts = jax.vjp(
+            wrap(reference), logits, has_aux=True)
+        got, got_vjp, got_experts = jax.vjp(
+            wrap(routed), logits, has_aux=True)
+        np.testing.assert_array_equal(got_experts, want_experts)
+        assert got.dtype == jnp.float32
+        np.testing.assert_array_equal(_bits(got), _bits(want))
+        np.testing.assert_array_equal(
+            _bits(got_vjp(cot)[0]), _bits(want_vjp(cot)[0]))
+    # the ties are there: some token's k-th score plus bias is its
+    # next one's
+    ranked = -np.sort(-np.asarray(_GATES[gate](logits) + bias), axis=-1)
+    assert (ranked[:, k - 1] == ranked[:, k]).any()
+    # renormalised and scaled, the weights still are the reference's
+    normed, _, _ = moe.route_logits(
+        logits, k, True, gate=gate, bias=bias, scaling=2.5)
+    total = jnp.sum(want, axis=-1, keepdims=True)
+    if gate == "sigmoid":
+        total = total + 1e-6
+    np.testing.assert_array_equal(normed, want / total * 2.5)
+
+
+@pytest.mark.parametrize("gate", ["softmax", "sigmoid"])
+def test_a_router_without_a_bias_returns_top_ks_own_values(gate):
+    logits, _, _ = _tied_logits(11, 64)
+    weights, experts, _ = moe.route_logits(logits, 8, False, gate=gate)
+    values, indices = jax.lax.top_k(_GATES[gate](logits), 8)
+    np.testing.assert_array_equal(experts, indices)
+    np.testing.assert_array_equal(_bits(weights), _bits(values))
