@@ -5,7 +5,7 @@ Prints ONE JSON line::
     {"metric": "trace_overhead_disabled_ns", "value": N, "unit": "ns",
      "disabled_ns": N, "enabled_ring_ns": N, "enabled_file_ns": N,
      "gc_hook_ns": N, "gc_span_file_ns": N, "watchdog_tick_ns": N,
-     "pass_lt_1us_disabled": true, ...}
+     "wait_staged_idle_ns": N, "pass_lt_1us_disabled": true, ...}
 
 The budget that matters is the DISABLED path: span sites stay wired
 into the train step, RPC handler, and checkpoint lanes permanently, so
@@ -20,9 +20,15 @@ clock readings, a profiler annotation where jax is imported: not
 here); ``gc_span_file_ns``, what it adds to one that does (generation
 1, written through); and ``watchdog_tick_ns``, one check of the hang
 detector's watchdog while no step is late (it wakes one to four times
-a step, on its own thread, whether tracing is on or off).
+a step, on its own thread, whether tracing is on or off). And what
+ISSUE 56 added: ``wait_staged_idle_ns``, one
+``FlashCheckpointer.wait_staged()`` with nothing in flight (the last
+save's copies long on the host), which a loop whose step donates its
+state calls before every dispatch: no span, no histogram, tracing on
+or off.
 
-No jax import — this measures pure-Python overhead.
+Pure-Python overhead: jax is imported only for that last figure (the
+checkpointer's module needs it), after the others are taken.
 """
 
 import gc
@@ -102,6 +108,34 @@ def _watchdog_tick_ns(n: int) -> float:
     return _per_call_ns(n, spin)
 
 
+def _wait_staged_idle_ns(n: int) -> float:
+    """One ``wait_staged()`` after a save whose snapshot is on the
+    host: the site a donating loop crosses every step."""
+    import numpy as np
+
+    from dlrover_tpu.trainer.checkpoint import FlashCheckpointer
+
+    tmp = tempfile.mkdtemp(prefix="trace_overhead_ckpt_")
+    ckpt = FlashCheckpointer(
+        os.path.join(tmp, "persist"), ram_dir=os.path.join(tmp, "ram"),
+        persist_interval=0, use_orbax=False,
+    )
+    try:
+        ckpt.save(1, {"w": np.zeros(8, np.float32)})
+        ckpt.wait()
+
+        def spin(k: int):
+            wait = ckpt.wait_staged
+            for _ in range(k):
+                wait()
+
+        spin(10_000)
+        return _per_call_ns(n, spin)
+    finally:
+        ckpt.close()
+        shutil.rmtree(tmp, ignore_errors=True)
+
+
 def main() -> int:
     # warm the function paths before any measurement
     tracing.disable()
@@ -128,6 +162,11 @@ def main() -> int:
     finally:
         shutil.rmtree(tmp, ignore_errors=True)
 
+    gc_hook_ns = _gc_hook_ns(0, 100_000)
+    watchdog_tick_ns = _watchdog_tick_ns(100_000)
+    # last: it imports jax, and the figures above are without it
+    wait_staged_idle_ns = _wait_staged_idle_ns(1_000_000)
+
     print(json.dumps({
         "metric": "trace_overhead_disabled_ns",
         "value": round(disabled_ns, 1),
@@ -135,9 +174,10 @@ def main() -> int:
         "disabled_ns": round(disabled_ns, 1),
         "enabled_ring_ns": round(ring_ns, 1),
         "enabled_file_ns": round(file_ns, 1),
-        "gc_hook_ns": round(_gc_hook_ns(0, 100_000), 1),
+        "gc_hook_ns": round(gc_hook_ns, 1),
         "gc_span_file_ns": round(gc_span_file_ns, 1),
-        "watchdog_tick_ns": round(_watchdog_tick_ns(100_000), 1),
+        "watchdog_tick_ns": round(watchdog_tick_ns, 1),
+        "wait_staged_idle_ns": round(wait_staged_idle_ns, 1),
         "pass_lt_1us_disabled": disabled_ns < 1000.0,
         "span_files_written": len(span_files),
         "python": sys.version.split()[0],

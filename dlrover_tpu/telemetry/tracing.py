@@ -48,6 +48,13 @@ stay wired into the hot paths permanently:
     session runs the span sits on the ``/host:CPU`` plane of the same
     ``.xplane.pb`` as the chip's operations. The launcher and the
     agent never import jax for this. Still behind ``_enabled``;
+  * **who held a core**: a site may ask for ``cpu=True`` and its
+    record gains ``cpu_s``, the thread's own CPU time over the block
+    (``time.thread_time()``, read only while tracing is on): the
+    checkpoint lanes' passes do, so that a pass that computed (and,
+    where it is Python or a numpy call that keeps it, held the
+    interpreter lock the step loop needs) is told from one that waited
+    on a copy or on the file system. No per-step site asks;
   * **the collector's pauses**: while tracing is on one
     ``gc.callbacks`` hook writes ``gc.collect`` {generation,
     collected} for every collection of generation 1 or 2, and any of
@@ -254,12 +261,14 @@ class _Span:
     id and becomes the context for its body — children and outbound
     RPCs parent under it."""
 
-    __slots__ = ("_name", "_attrs", "_ts", "_t0", "_ann",
+    __slots__ = ("_name", "_attrs", "_ts", "_t0", "_ann", "_cpu", "_c0",
                  "trace_id", "span_id", "_parent", "_tok")
 
-    def __init__(self, name: str, attrs: Optional[Dict[str, Any]]):
+    def __init__(self, name: str, attrs: Optional[Dict[str, Any]],
+                 cpu: bool = False):
         self._name = name
         self._attrs = attrs
+        self._cpu = cpu  # the site asked for the thread's CPU time
 
     def __enter__(self):
         ctx = _context.get()
@@ -274,11 +283,19 @@ class _Span:
         self._tok = _context.set((self.trace_id, self.span_id))
         self._ann = _annotation(self._name)
         self._ts = time.time()
+        if self._cpu:
+            self._c0 = time.thread_time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         dur = time.perf_counter() - self._t0
+        if self._cpu:
+            # a dict of its own: sites hand one ``attrs`` to several
+            self._attrs = dict(
+                self._attrs or (),
+                cpu_s=time.thread_time() - self._c0,
+            )
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
         try:
@@ -292,17 +309,24 @@ class _Span:
         return False
 
 
-def span(name: str, attrs: Optional[Dict[str, Any]] = None):
+def span(name: str, attrs: Optional[Dict[str, Any]] = None,
+         cpu: bool = False):
     """Context manager timing a block. When tracing is disabled this
     returns a shared no-op object — sub-microsecond and allocation-free,
     safe to leave in a train loop permanently. ``attrs`` (a plain dict,
     deliberately not ``**kwargs`` — a kwargs catch-all would allocate
     even on the disabled path) lands in the record and the Chrome
     ``args`` pane; a site that passes one builds it with tracing off
-    too, so a site on a per-step path passes none."""
+    too, so a site on a per-step path passes none.
+
+    ``cpu=True`` adds ``cpu_s`` to the record: the thread's own CPU
+    time over the block (``time.thread_time()`` at both ends, read
+    only while tracing is on). Near ``dur``, the block held a core;
+    near zero, it waited (a copy, the file system, a lock). For the
+    passes of a background lane, never for a per-step site."""
     if not _enabled:
         return _NOOP
-    return _Span(name, attrs)
+    return _Span(name, attrs, cpu)
 
 
 def add_span(name: str, start_ts: float, duration_s: float,

@@ -77,8 +77,9 @@ _CKPT_BUCKETS = (
     30.0, 60.0, 300.0,
 )
 
-#: the zero-stall budget: staging dispatch is expected in the
-#: sub-millisecond buckets; anything above ~25ms means back-pressure
+#: the staging budget: dispatch is expected in the lowest buckets (12
+#: ms for 6.8 GB in 38 shards on a v5e chip); far above ~25ms means
+#: back-pressure. The wait for the copies is wait_staged's histogram
 _STALL_BUCKETS = (
     0.0002, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05,
     0.1, 0.25, 0.5, 1.0, 5.0, 30.0,
@@ -228,6 +229,19 @@ def _materialize_staged(staged):
     return jax.tree.map(mat, staged, is_leaf=_is_snap_leaf)
 
 
+def _shard_sizes(tree) -> Tuple[int, int]:
+    """``(bytes, shards)`` of a staged or materialized snapshot: what
+    the spans say of a save's size. Called under ``tracing.enabled()``
+    only."""
+    sizes = [
+        d.nbytes
+        for leaf in jax.tree.leaves(tree, is_leaf=_is_snap_leaf)
+        if _is_snap_leaf(leaf)
+        for _, d in leaf["shards"]
+    ]
+    return sum(sizes), len(sizes)
+
+
 def _local_shards(pytree):
     """Blocking snapshot of process-local shard data + index metadata
     (stage + materialize in one call; the synchronous baseline and the
@@ -361,8 +375,13 @@ class _SerializerLane:
 
     def submit(self, job) -> None:
         with self._cond:
-            while self._pending is not None and not self._closed:
-                self._cond.wait()
+            if self._pending is not None and not self._closed:
+                # back-pressure, apart from dispatch in ``ckpt.stage``
+                with tracing.span("ckpt.submit_wait", {
+                    "step": job.step, "behind": self._pending.step,
+                }):
+                    while self._pending is not None and not self._closed:
+                        self._cond.wait()
             if self._closed:
                 raise RuntimeError("checkpointer is closed")
             self._pending = job
@@ -520,11 +539,13 @@ class _PersistQueue:
 
 
 class FlashCheckpointer:
-    """Two-tier async checkpointer with a zero-stall save path.
+    """Two-tier async checkpointer.
 
     save(step, state): stages the device->host snapshot (copy dispatch
-    only — the stall is microseconds, independent of serialization and
-    near-independent of state size) and returns; the serializer lane
+    only, independent of serialization and near-independent of state
+    size; a loop whose step donates the state then waits for the
+    copies in :meth:`wait_staged`, docs/CHECKPOINT.md "Stall budget")
+    and returns; the serializer lane
     materializes the staged shards and streams the archive to the RAM
     tier (tmpfs), then hands the persistent save to a bounded persist
     worker when ``step % persist_interval == 0`` (or force_persist).
@@ -687,11 +708,11 @@ class FlashCheckpointer:
         process — ``os._exit``, SIGKILL). That is the pre-pipeline
         cost profile: use it only where a drill/caller needs
         crash-durability at a specific step; a normal step loop keeps
-        the zero-stall default and accepts a serialize-window of
+        the async default and accepts a serialize-window of
         durability lag (docs/CHECKPOINT.md). The returned stall covers
         the full durable drain, but the stall histogram keeps
         recording staging dispatch only — durable saves must not skew
-        the zero-stall budget it alerts on."""
+        the staging budget it alerts on."""
         t0 = time.perf_counter()
         ts_wall = time.time()
         staged = _stage_local_shards(
@@ -730,9 +751,11 @@ class FlashCheckpointer:
         ).observe(stall_s)
         # the train-thread slice of the save on the trace timeline;
         # serialize/persist appear as their own lanes' spans
-        tracing.add_span(
-            "ckpt.stage", ts_wall, stall_s, attrs={"step": step}
-        )
+        if tracing.enabled():
+            nbytes, shards = _shard_sizes(staged)
+            tracing.add_span("ckpt.stage", ts_wall, stall_s, attrs={
+                "step": step, "bytes": nbytes, "shards": shards,
+            })
         if durable:
             self._serializer.drain()
             total_s = time.perf_counter() - t0
@@ -752,9 +775,24 @@ class FlashCheckpointer:
         materialized on the host. THE DONATION SYNC POINT: a train
         loop whose step donates the state buffers must call this
         before dispatching the step that invalidates them (or
-        construct the checkpointer with ``stage="sync"``)."""
+        construct the checkpointer with ``stage="sync"``). Where it
+        waits, the wait is the span ``ckpt.wait_staged`` on the
+        caller's thread and one observation of
+        ``dlrover_checkpoint_wait_staged_seconds``; with nothing in
+        flight it reads one attribute and an event's flag."""
         job = self._last_save
-        return job.staged_evt.wait(timeout) if job is not None else True
+        if job is None or job.staged_evt.is_set():
+            return True  # nothing in flight: nothing written
+        t0 = time.perf_counter()
+        with tracing.span("ckpt.wait_staged", {"step": job.step}):
+            staged = job.staged_evt.wait(timeout)
+        histogram(
+            "dlrover_checkpoint_wait_staged_seconds",
+            "Train-thread wait for a save's device-to-host copies "
+            "(the stall save_stall_seconds leaves out)",
+            buckets=_CKPT_BUCKETS,
+        ).observe(time.perf_counter() - t0)
+        return staged
 
     def _ensure_workers(self) -> None:
         if self._serializer is not None:
@@ -790,23 +828,18 @@ class FlashCheckpointer:
         staging failure truly loses the save, and that loss is counted
         (``persist_skipped{reason="stage_failed"}``) so failover
         drills can detect it."""
-        with tracing.span("ckpt.serialize", {"step": job.step}):
+        with tracing.span("ckpt.serialize", {"step": job.step},
+                          cpu=True):
             self._serialize_job_inner(job)
 
     def _serialize_job_inner(self, job: _SaveJob) -> None:
         t0 = time.perf_counter()
         try:
             size = {}
-            with tracing.span("ckpt.write.materialize", size):
+            with tracing.span("ckpt.write.materialize", size, cpu=True):
                 snapshot = _materialize_staged(job.staged)
                 if tracing.enabled():
-                    size["bytes"] = sum(
-                        d.nbytes
-                        for leaf in jax.tree.leaves(
-                            snapshot, is_leaf=_is_snap_leaf
-                        ) if _is_snap_leaf(leaf)
-                        for _, d in leaf["shards"]
-                    )
+                    size["bytes"] = _shard_sizes(snapshot)[0]
             job.staged = None  # drop device handles promptly
             job.staged_evt.set()
         except Exception as e:
@@ -1014,7 +1047,8 @@ class FlashCheckpointer:
 
     def _run_persist(self, job: _PersistJob) -> None:
         with tracing.span(
-            "ckpt.persist", {"step": job.step, "kind": job.payload[0]}
+            "ckpt.persist", {"step": job.step, "kind": job.payload[0]},
+            cpu=True,
         ):
             self._run_persist_inner(job)
 

@@ -80,21 +80,24 @@ class DigestMismatchError(ArchiveError):
 class _HashingWriter:
     """Tee writes into a hash while streaming a member into the zip —
     the digest costs no extra pass over the data at save time.
-    ``digest_secs`` is what the hash took of it, for
-    ``ckpt.write.digest``: read off the clock only with tracing on."""
+    ``digest_secs`` is what the hash took of it and ``digest_cpu_s``
+    what of that the thread held a core, for ``ckpt.write.digest``:
+    read off the clocks only with tracing on."""
 
     def __init__(self, inner: BinaryIO, digest):
         self._inner = inner
         self._digest = digest
         self._timed = tracing.enabled()
         self.digest_secs = 0.0
+        self.digest_cpu_s = 0.0
 
     def write(self, data):
         if not self._timed:
             self._digest.update(data)
             return self._inner.write(data)
-        t0 = time.perf_counter()
+        t0, c0 = time.perf_counter(), time.thread_time()
         self._digest.update(data)
+        self.digest_cpu_s += time.thread_time() - c0
         self.digest_secs += time.perf_counter() - t0
         return self._inner.write(data)
 
@@ -235,7 +238,7 @@ def snapshot_to_file(snapshot: Any, step: int, fileobj: BinaryIO,
             counter[0] += 1
             arr = np.asarray(arr)
             size = {"bytes": arr.nbytes}
-            with tracing.span("ckpt.write.encode", size):
+            with tracing.span("ckpt.write.encode", size, cpu=True):
                 if (
                     arr.dtype.kind == "V"
                     or arr.dtype.name not in np.sctypeDict
@@ -253,7 +256,7 @@ def snapshot_to_file(snapshot: Any, step: int, fileobj: BinaryIO,
             digest = hashlib.sha256()
             # numpy's chunking, the zip member's crc32 and the write
             # to the medium; the hash's share of it is the child
-            with tracing.span("ckpt.write.io", size), zf.open(
+            with tracing.span("ckpt.write.io", size, cpu=True), zf.open(
                 name + ".npy", "w", force_zip64=True
             ) as m:
                 t0 = time.time()
@@ -261,9 +264,11 @@ def snapshot_to_file(snapshot: Any, step: int, fileobj: BinaryIO,
                 np.lib.format.write_array(
                     writer, arr, allow_pickle=False
                 )
-                tracing.add_span(
-                    "ckpt.write.digest", t0, writer.digest_secs, size
-                )
+                if tracing.enabled():
+                    tracing.add_span(
+                        "ckpt.write.digest", t0, writer.digest_secs,
+                        {**size, "cpu_s": writer.digest_cpu_s},
+                    )
             manifest["digests"][name + ".npy"] = digest.hexdigest()
             return name
 

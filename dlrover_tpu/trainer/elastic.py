@@ -261,8 +261,7 @@ class ElasticTrainer:
                 if ckpt is not None:
                     wait = getattr(ckpt, "wait_staged", None)
                     if wait is not None:
-                        with tracing.span("train.wait_staged"):
-                            wait()
+                        wait()  # where it waits: ``ckpt.wait_staged``
                 with tracing.span("train.dispatch"):
                     return jitted(params, opt_state, batches)
 

@@ -339,7 +339,12 @@ def main():
                      "step": jax.numpy.array(step)},
                     # durable: the failover drills hard-kill (os._exit)
                     # shortly after a cadence step — the archive must
-                    # already be on tmpfs, not in the async serializer
+                    # already be on tmpfs, not in the async serializer.
+                    # What each mode costs this loop at 6.8 GB on a
+                    # v5e chip (PERF.md section 5): durable 26.7-27.6 s
+                    # a save (PR 26), async 6.7-12.6 s (wait_staged,
+                    # before the next donating dispatch; PR 56) with
+                    # the other 20 s on the lane, beside the steps
                     durable=True,
                 )
             if step >= args.steps:

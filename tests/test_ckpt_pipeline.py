@@ -666,3 +666,272 @@ def test_elastic_train_step_blocks_until_staging_materializes(
         np.asarray(restored[0]["w"]), expect
     )
     ckpt.close()
+
+
+# ------------------------------------- what a save costs the step loop
+
+
+@pytest.fixture
+def traced():
+    """The span sites on, the ring empty; off and empty afterwards."""
+    from dlrover_tpu.telemetry import tracing
+
+    tracing.disable()
+    tracing.clear()
+    tracing.enable()
+    yield tracing
+    tracing.disable()
+    tracing.clear()
+
+
+def _spans(tracing, name):
+    return [r for r in tracing.tail(4096) if r["name"] == name]
+
+
+def _gate(monkeypatch, module, name):
+    """Hold every call of ``module.name`` until ``release`` is set;
+    ``entered`` says that one stands there."""
+    entered, release = threading.Event(), threading.Event()
+    real = getattr(module, name)
+
+    def gated(*args, **kw):
+        entered.set()
+        assert release.wait(10.0), "test deadlock"
+        return real(*args, **kw)
+
+    monkeypatch.setattr(module, name, gated)
+    return entered, release
+
+
+def _wait_staged_histogram():
+    hist = T.default_registry().get(
+        "dlrover_checkpoint_wait_staged_seconds"
+    )
+    return None if hist is None else hist._default_child()
+
+
+def test_wait_staged_writes_nothing_when_nothing_is_in_flight(
+        tmp_path, traced):
+    ckpt = _ckpt(tmp_path, persist_interval=0)
+    assert ckpt.wait_staged() is True  # no save yet
+    ckpt.save(3, _state())
+    ckpt.wait()  # the copies are long on the host
+    for _ in range(3):  # a loop that asks before every dispatch
+        assert ckpt.wait_staged() is True
+    ckpt.close()
+    assert _spans(traced, "ckpt.stage")  # the sites are on
+    assert _spans(traced, "ckpt.wait_staged") == []
+    assert _wait_staged_histogram() is None
+
+
+@pytest.mark.parametrize("on", [True, False], ids=["on", "off"])
+def test_wait_staged_that_waits_is_one_span_on_the_callers_thread(
+        tmp_path, monkeypatch, traced, on):
+    import dlrover_tpu.trainer.checkpoint as ckpt_mod
+
+    if not on:
+        traced.disable()
+    entered, release = _gate(monkeypatch, ckpt_mod, "_materialize_staged")
+    ckpt = _ckpt(tmp_path, persist_interval=0)
+    ckpt.save(11, _state())
+    assert entered.wait(5.0)
+    got = []
+    loop = threading.Thread(
+        target=lambda: got.append(ckpt.wait_staged(10.0)), name="loop"
+    )
+    loop.start()
+    time.sleep(0.05)
+    assert loop.is_alive()  # it stands in the wait
+    release.set()
+    loop.join(10.0)
+    assert got == [True]
+    ckpt.wait()
+    ckpt.close()
+    waits = _spans(traced, "ckpt.wait_staged")
+    if on:
+        (wait,) = waits
+        assert wait["attrs"] == {"step": 11}
+        assert wait["thread"] == "loop"
+        assert wait["dur"] >= 0.04
+        # the lane's materialize ends where the wait does
+        (mat,) = _spans(traced, "ckpt.write.materialize")
+        assert mat["thread"] == "ckpt-serialize"
+        assert abs(mat["ts"] + mat["dur"]
+                   - wait["ts"] - wait["dur"]) < 0.05
+    else:
+        assert waits == []
+    # the histogram is an operator's, tracing or not: one wait, and
+    # the staging-only stall beside it left as it was
+    child = _wait_staged_histogram()
+    assert child.count == 1 and child.sum >= 0.04
+    stall = T.default_registry().get(
+        "dlrover_checkpoint_save_stall_seconds"
+    )._default_child()
+    assert stall.count == 1 and stall.sum < 0.04
+
+
+def test_wait_staged_that_times_out_says_so_and_is_a_span(
+        tmp_path, monkeypatch, traced):
+    import dlrover_tpu.trainer.checkpoint as ckpt_mod
+
+    entered, release = _gate(monkeypatch, ckpt_mod, "_materialize_staged")
+    ckpt = _ckpt(tmp_path, persist_interval=0)
+    ckpt.save(12, _state())
+    assert entered.wait(5.0)
+    assert ckpt.wait_staged(0.02) is False
+    release.set()
+    ckpt.wait()
+    ckpt.close()
+    (wait,) = _spans(traced, "ckpt.wait_staged")
+    assert wait["attrs"] == {"step": 12} and wait["dur"] >= 0.02
+
+
+def test_submit_wait_only_under_back_pressure(tmp_path, monkeypatch,
+                                              traced):
+    """``ckpt.stage`` is dispatch; where the lane's one pending slot
+    was taken, the wait for it is ``ckpt.submit_wait`` inside it."""
+    entered, release = _gate(monkeypatch, ckpt_store, "snapshot_to_file")
+    ckpt = _ckpt(tmp_path, persist_interval=0)
+    ckpt.save(1, _state())
+    assert entered.wait(5.0)  # the lane is busy with step 1
+    ckpt.save(2, _state())  # takes the pending slot: no wait
+    assert _spans(traced, "ckpt.submit_wait") == []
+    third = threading.Thread(
+        target=lambda: ckpt.save(3, _state()), name="loop"
+    )
+    third.start()
+    time.sleep(0.05)
+    assert third.is_alive()  # behind step 2
+    release.set()
+    third.join(10.0)
+    assert not third.is_alive()
+    ckpt.wait()
+    ckpt.close()
+    (wait,) = _spans(traced, "ckpt.submit_wait")
+    assert wait["attrs"] == {"step": 3, "behind": 2}
+    assert wait["thread"] == "loop" and wait["dur"] >= 0.04
+    stages = {r["attrs"]["step"]: r for r in _spans(traced, "ckpt.stage")}
+    assert sorted(stages) == [1, 2, 3]
+    assert stages[3]["ts"] <= wait["ts"]
+    assert stages[3]["dur"] >= wait["dur"]
+    assert stages[1]["dur"] < 0.04 and stages[2]["dur"] < 0.04
+
+
+def test_stage_span_says_how_much_was_staged(tmp_path, traced):
+    ckpt = _ckpt(tmp_path, persist_interval=0)
+    state = _state()
+    ckpt.save(4, state)
+    ckpt.wait()
+    ckpt.close()
+    (stage,) = _spans(traced, "ckpt.stage")
+    assert stage["attrs"] == {
+        "step": 4, "shards": 3,
+        "bytes": sum(x.nbytes for x in jax.tree.leaves(state)),
+    }
+    (mat,) = _spans(traced, "ckpt.write.materialize")
+    assert mat["attrs"]["bytes"] == stage["attrs"]["bytes"]
+
+
+LANE_SPANS = ("ckpt.serialize", "ckpt.write.materialize",
+              "ckpt.write.encode", "ckpt.write.io", "ckpt.write.digest",
+              "ckpt.persist")
+
+
+@pytest.mark.parametrize("name", LANE_SPANS)
+def test_lane_span_carries_its_threads_cpu_time(tmp_path, traced, name):
+    ckpt = _ckpt(tmp_path, persist_interval=1)
+    ckpt.save(1, _state())
+    ckpt.wait()
+    ckpt.close()
+    found = _spans(traced, name)
+    assert found
+    for rec in found:
+        assert rec["thread"] in ("ckpt-serialize", "ckpt-persist")
+        # the thread's own clock: never more than the wall's, but for
+        # the two clocks' grain
+        assert 0.0 <= rec["attrs"]["cpu_s"] <= rec["dur"] + 0.02
+    # the train thread's slice asks for none
+    assert "cpu_s" not in _spans(traced, "ckpt.stage")[0]["attrs"]
+
+
+def test_a_lane_that_waits_reads_little_cpu(tmp_path, monkeypatch,
+                                            traced):
+    """``cpu_s`` near zero where the pass waited: here on a gate, on
+    the chip on the device-to-host copies."""
+    import dlrover_tpu.trainer.checkpoint as ckpt_mod
+
+    real = ckpt_mod._materialize_staged
+
+    def slow(staged):
+        time.sleep(0.2)
+        return real(staged)
+
+    monkeypatch.setattr(ckpt_mod, "_materialize_staged", slow)
+    ckpt = _ckpt(tmp_path, persist_interval=0)
+    ckpt.save(1, _state())
+    ckpt.wait()
+    ckpt.close()
+    (mat,) = _spans(traced, "ckpt.write.materialize")
+    assert mat["dur"] >= 0.2 and mat["attrs"]["cpu_s"] < 0.1
+    (whole,) = _spans(traced, "ckpt.serialize")
+    assert whole["attrs"]["cpu_s"] < whole["dur"] - 0.1
+
+
+def test_no_thread_clock_is_read_with_tracing_off(tmp_path,
+                                                  monkeypatch):
+    from dlrover_tpu.telemetry import tracing
+
+    assert not tracing.enabled()
+    calls = []
+    monkeypatch.setattr(
+        time, "thread_time", lambda: calls.append(1) or 0.0
+    )
+    ckpt = _ckpt(tmp_path, persist_interval=1)
+    ckpt.save(1, _state())
+    ckpt.wait_staged()
+    ckpt.wait()
+    restored, step = ckpt.restore(target=_state())
+    ckpt.close()
+    assert step == 1 and calls == []
+
+
+def test_elastic_train_steps_wait_is_the_checkpointers_span(
+        tmp_path, monkeypatch, traced):
+    """One wait, one name, wherever it is called from."""
+    import optax
+
+    import dlrover_tpu.trainer.checkpoint as ckpt_mod
+    from dlrover_tpu.trainer.elastic import ElasticTrainer
+
+    optimizer = optax.sgd(0.1)
+    trainer = ElasticTrainer(
+        lambda p, b: jnp.mean((b[0] @ p["w"] - b[1]) ** 2),
+        optimizer, max_nodes=1, cur_nodes=1,
+    )
+    params = {"w": jnp.ones((3, 1))}
+    opt_state = optimizer.init(params)
+    batches = (jnp.ones((1, 4, 3)), jnp.zeros((1, 4, 1)))
+    params, opt_state, _ = trainer.train_step(params, opt_state, batches)
+    real = ckpt_mod._materialize_staged
+
+    def slow(staged):
+        time.sleep(0.1)
+        return real(staged)
+
+    monkeypatch.setattr(ckpt_mod, "_materialize_staged", slow)
+    ckpt = _ckpt(tmp_path, persist_interval=0)
+    trainer.attach_checkpointer(ckpt, save_interval=1)
+    trainer.report_step()
+    assert trainer.maybe_checkpoint((params, opt_state)) is not None
+    for _ in range(2):  # the first waits, the second finds it done
+        params, opt_state, _ = trainer.train_step(
+            params, opt_state, batches
+        )
+    ckpt.wait()
+    ckpt.close()
+    (wait,) = _spans(traced, "ckpt.wait_staged")
+    assert wait["thread"] == threading.current_thread().name
+    assert 0.05 <= wait["dur"]
+    # the warm-up's and the two after the save
+    assert len(_spans(traced, "train.dispatch")) == 3
+    assert _spans(traced, "train.wait_staged") == []

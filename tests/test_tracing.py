@@ -125,6 +125,85 @@ def test_add_span_retroactive_and_disabled_noop():
     assert rec["attrs"]["round"] == 3
 
 
+def _burn(seconds):
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
+def test_cpu_s_is_an_opt_in_of_the_site():
+    """``cpu=True``: the thread's own CPU time over the block, near
+    ``dur`` where it held a core and near zero where it waited."""
+    tracing.enable()
+    with tracing.span("plain"):
+        _burn(0.01)
+    with tracing.span("held", cpu=True):
+        _burn(0.1)
+    with tracing.span("waited", {"bytes": 7}, cpu=True):
+        time.sleep(0.1)
+    plain, held, waited = tracing.tail(3)
+    assert "attrs" not in plain
+    assert set(held["attrs"]) == {"cpu_s"}
+    assert 0.05 < held["attrs"]["cpu_s"] <= held["dur"] + 0.02
+    assert waited["attrs"]["bytes"] == 7
+    assert waited["attrs"]["cpu_s"] < 0.05 <= waited["dur"]
+
+
+def test_cpu_s_leaves_the_sites_own_attrs_alone():
+    """A site hands one dict to several spans (the checkpoint's
+    ``size``): each record gets a ``cpu_s`` of its own."""
+    tracing.enable()
+    size = {"bytes": 3}
+    with tracing.span("first", size, cpu=True):
+        _burn(0.02)
+    with tracing.span("second", size, cpu=True):
+        size["late"] = True  # filled in inside the block, as sites do
+    first, second = tracing.tail(2)
+    assert size == {"bytes": 3, "late": True}
+    assert first["attrs"]["cpu_s"] >= 0.01 > second["attrs"]["cpu_s"]
+    assert second["attrs"]["late"] is True and "late" not in first["attrs"]
+
+
+@pytest.mark.parametrize("on, cpu, reads", [
+    (False, True, 0), (True, False, 0), (True, True, 2),
+], ids=["off", "on-not-asked", "on-asked"])
+def test_thread_clock_is_read_only_where_asked_and_on(
+        monkeypatch, on, cpu, reads):
+    calls = []
+    monkeypatch.setattr(
+        time, "thread_time", lambda: calls.append(1) or 0.0
+    )
+    if on:
+        tracing.enable()
+    with tracing.span("x", None, cpu=cpu):
+        pass
+    assert len(calls) == reads
+    assert len(tracing.tail(5)) == (1 if on else 0)
+
+
+def test_cpu_s_reaches_the_chrome_args_pane():
+    tracing.enable()
+    with tracing.span("ckpt.serialize", {"step": 4}, cpu=True):
+        pass
+    (event,) = [e for e in tracing.chrome_trace()["traceEvents"]
+                if e["ph"] == "X"]
+    assert event["args"]["step"] == 4 and "cpu_s" in event["args"]
+
+
+@pytest.mark.parametrize("where", ["dlrover_tpu", "docs", "examples",
+                                   "benchmarks"])
+def test_the_wait_for_staging_has_one_name(where):
+    """``ckpt.wait_staged``, inside ``FlashCheckpointer.wait_staged``
+    wherever it is called from; the wrapper span
+    ``train.wait_staged`` is gone from code and documents."""
+    hits = [
+        str(p) for p in (REPO_ROOT / where).rglob("*")
+        if p.suffix in (".py", ".md") and "train.wait_staged"
+        in p.read_text(errors="replace")
+    ]
+    assert hits == []
+
+
 def test_summarize_aggregates_by_name():
     tracing.enable()
     for ms in (10, 20, 30):
