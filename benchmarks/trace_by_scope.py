@@ -49,7 +49,8 @@ SCOPES = (
 
 
 def write_step(cell, out):
-    """The cell's step as this process's backend compiles it."""
+    """The cell's step as this process's backend compiles it, and
+    how often each operator's entry took its kernels."""
     import jax
     import jax.numpy as jnp
     import optax
@@ -76,6 +77,15 @@ def write_step(cell, out):
     with open(out, "w") as f:
         f.write(compiled.as_text())
     print("planned", compiled.memory_analysis().peak_memory_in_bytes)
+    # which path each operator's entry took while the step was traced
+    # (``<operator>_<path>_calls``, docs/TELEMETRY.md)
+    from dlrover_tpu.telemetry.registry import default_registry
+
+    print("calls", {
+        name: sum(family["series"].values())
+        for name, family in default_registry().to_dict().items()
+        if name.endswith("_calls")
+    })
 
 
 def leaves(events):

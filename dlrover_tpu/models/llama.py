@@ -28,6 +28,7 @@ import jax.numpy as jnp
 
 from dlrover_tpu.ops.attention import flash_attention
 from dlrover_tpu.ops.delta_rule import gated_delta_rule_rows
+from dlrover_tpu.ops.gated_norm import gated_group_norm
 from dlrover_tpu.ops.kda_conv import conv_silu_norm, heads_apart
 from dlrover_tpu.ops.short_conv import gated_short_conv
 from dlrover_tpu.ops.ssd import ssd_scan
@@ -1401,13 +1402,7 @@ def _operator_of(cfg: LlamaConfig, attn_fn, kind: LayerKind):
                 )
             # the gate, then an RMSNorm over each group's columns
             with jax.named_scope("ssm.gate_norm"):
-                gated = heads_apart(
-                    o.astype(jnp.float32)
-                    * jax.nn.silu(z.astype(jnp.float32)), groups)
-                normed = gated * jax.lax.rsqrt(
-                    jnp.mean(gated * gated, axis=-1, keepdims=True)
-                    + cfg.norm_eps)
-                return (normed.reshape(o.shape) * scale).astype(o.dtype)
+                return gated_group_norm(o, z, scale, groups, cfg.norm_eps)
 
         return scan
     if kind.operator == "conv":

@@ -1474,8 +1474,11 @@ def test_solar_step_holds_the_delta_rules_kernels(
 #: .steady``'s step as this file compiles it (1 x 8,192, eleven layers
 #: and the module, remat ``minimal``, the least effort; PERF.md, PR
 #: 54): 8.27 GB of it the state. At the default effort it read
-#: 11,458,404,352
-NEMOTRON_STEP_BYTES = 11_462_598_656
+#: 11,458,404,352. PR 54 read 11,462,598,656 here; the figure below is
+#: what the tree plans since PR 56, with a mixer's gate and norm as
+#: plain passes and as the kernels alike (PR 57): the step's peak is
+#: not in a mixer
+NEMOTRON_STEP_BYTES = 11_461_876_736
 
 
 def test_ssd_kernels_compile_at_the_cells_shape(topo, monkeypatch):
@@ -1509,6 +1512,35 @@ def test_ssd_kernels_compile_at_the_cells_shape(topo, monkeypatch):
     assert "f32[1,8,64,128,1024]" in text  # the chunks' entry states
 
 
+def test_gated_norm_kernels_compile_at_the_cells_shape(topo, monkeypatch):
+    """A mixer's gate and grouped norm at the cell's shape (one
+    sequence of 8,192, 8 groups of 1,024 columns): a forward and a
+    backward Pallas call, the cotangent read in bf16 and the scale's
+    gradient summed in float32."""
+    from dlrover_tpu.ops.pallas import gated_norm as kernels
+
+    monkeypatch.setattr(kernels, "_interpret", lambda: False)
+    assert kernels.tiles_the_kernel((1, 8192, 8192), 8)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    rows = jax.ShapeDtypeStruct(
+        (1, 8192, 8192), jnp.bfloat16, sharding=one_chip)
+    scale = jax.ShapeDtypeStruct((8192,), jnp.float32, sharding=one_chip)
+
+    def gradients(o, z, scale, dy):
+        y, back = jax.vjp(
+            lambda *a: kernels.gated_norm_tpu(*a, 8, 1e-5), o, z, scale)
+        return (y, *back(dy))
+
+    compiled = jax.jit(gradients).lower(rows, rows, scale, rows).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert [(o.dtype, o.shape) for o in jax.tree.leaves(
+        compiled.out_info)] == 3 * [(jnp.bfloat16, (1, 8192, 8192))] + [
+            (jnp.float32, (8192,))]
+    # nothing of the operator's is left to XLA at full width
+    assert not re.search(r"= f32\[1,8192,8192\]", text)
+
+
 def test_nemotron_step_holds_the_scans_kernels(topo, on_tpu_path, monkeypatch):
     """``nemotron-3-super-120b-a12b-ep64.steady``'s step: it fits and
     plans no more than was read when the cell was built; a mixer's
@@ -1516,12 +1548,18 @@ def test_nemotron_step_holds_the_scans_kernels(topo, on_tpu_path, monkeypatch):
     and the backward over the entry states it kept) are named as the
     benchmark's ``ssd_ms`` tells them, and as no other reader does,
     and carry ``ssm.scan``; the convolution with its bias is fifteen
-    Pallas calls under ``ssm.conv`` by the jitted name ``kda_conv``;
-    every call of either entry took the kernels; the two attention
+    Pallas calls under ``ssm.conv`` by the jitted name ``kda_conv``,
+    and the gate with the grouped norm fifteen under ``ssm.gate_norm``
+    by the jitted name ``gated_norm``, which no reader's pattern
+    matches, so nothing of them is left to XLA: no norm's factor
+    written out at a group's width, no float32 cotangent from
+    ``ssm_out``'s input-gradient product (PERF.md, PR 57);
+    every call of the three entries took the kernels; the two attention
     layers (the stack's and the module's) run the one backward kernel
     at a group of 16; and the expert layers' 1024 x 2688 products take
     the tiles the rule gives them."""
-    from dlrover_tpu.ops import grouped_matmul as gm, kda_conv, ssd
+    from dlrover_tpu.ops import gated_norm, grouped_matmul as gm, kda_conv, ssd
+    from dlrover_tpu.ops.pallas import gated_norm as norm_kernels
     from dlrover_tpu.ops.pallas import kda_conv as conv_kernels
     from dlrover_tpu.ops.pallas import ssd as scan_kernels
     from dlrover_tpu.telemetry.registry import counter, gauge
@@ -1541,9 +1579,13 @@ def test_nemotron_step_holds_the_scans_kernels(topo, on_tpu_path, monkeypatch):
         kda_conv, "_use_pallas", lambda x, w, l2_heads: (
             conv_kernels.tiles_the_kernel(x.shape, w.shape, l2_heads)))
     monkeypatch.setattr(conv_kernels, "_interpret", lambda: False)
-    calls = [counter(f"ssd_{path}_calls", "") for path in ("kernel", "plain")]
-    calls += [counter(f"kda_conv_{path}_calls", "")
-              for path in ("kernel", "plain")]
+    monkeypatch.setattr(
+        gated_norm, "_use_pallas", lambda o, groups: (
+            norm_kernels.tiles_the_kernel(o.shape, groups)))
+    monkeypatch.setattr(norm_kernels, "_interpret", lambda: False)
+    calls = [counter(f"{entry}_{path}_calls", "")
+             for entry in ("ssd", "kda_conv", "gated_norm")
+             for path in ("kernel", "plain")]
     before = [c.value for c in calls]
     gauge("ssd_heads_per_step", "").set(0)
     _, config, traffic = cells.load_cell(
@@ -1583,13 +1625,23 @@ def test_nemotron_step_holds_the_scans_kernels(topo, on_tpu_path, monkeypatch):
             attn_kernel_ms, delta_rule_ms, moe_expert_ms, short_conv_ms))
     # the chunks' entry states: [batch, groups, chunks, 128, 16 x 64]
     assert "f32[1,8,64,128,1024]" in text
-    conv = [name for name, _, op in kernels if "ssm.conv" in op]
-    assert len(conv) == 5 * 3, conv
-    assert all(name.startswith("kda_conv") for name in conv)
-    assert not any(
-        reader.KERNEL.search(name) for name in conv for reader in (
-            attn_kernel_ms, delta_rule_ms, moe_expert_ms, short_conv_ms,
-            ssd_ms))
+    # beside the scan, a mixer's convolution and its gate with the
+    # grouped norm: each the forward, the forward again and the
+    # backward, by a jitted name that no reader goes by
+    for scope, jitted in (("ssm.conv", "kda_conv"),
+                          ("ssm.gate_norm", "gated_norm")):
+        beside = [name for name, _, op in kernels if scope in op]
+        assert len(beside) == 5 * 3, beside
+        assert all(name.startswith(jitted) for name in beside)
+        assert not any(
+            reader.KERNEL.search(name) for name in beside for reader in (
+                attn_kernel_ms, delta_rule_ms, moe_expert_ms, short_conv_ms,
+                ssd_ms))
+    # what the plain passes wrote out is gone: the norm's factor at a
+    # group's width (two a layer, 268 MB each) and a float32 result of
+    # ``ssm_out``'s input-gradient product (268 MB where bf16 is 134)
+    assert not re.search(r"= f32\[1024,8,8,1024\]\S* broadcast\(", text)
+    assert not re.search(r"= f32\[8192,8192\]", text)
     # the stack's attention layer and the module's: the forward, the
     # forward again and the one backward kernel each (a group of 16: a
     # kv head's dK and dV resident)
@@ -1599,9 +1651,9 @@ def test_nemotron_step_holds_the_scans_kernels(topo, on_tpu_path, monkeypatch):
     # six expert layers' walks: grouped products, none of them read as
     # another operator's
     assert sum(bool(moe_expert_ms.KERNEL.search(n)) for n in others) >= 18
-    # every mixer's call of either entry took the kernels (a call a
-    # layer: the remat's second forward reuses its trace)
-    assert [c.value - was for c, was in zip(calls, before)] == [5, 0, 5, 0]
+    # every mixer's call of each of the three entries took the kernels
+    # (a call a layer: the remat's second forward reuses its trace)
+    assert [c.value - was for c, was in zip(calls, before)] == 3 * [5, 0]
     assert gauge("ssd_heads_per_step", "").value == 16
     assert gauge("ssd_state_bytes", "").value == 16 * 64 * 128 * 4
     for scope in ("ssm.in_proj", "ssm.dt", "ssm.gate_norm", "ssm.out_proj",
