@@ -35,7 +35,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 #: the scopes of models/llama.py and parallel/moe.py, the first that an
 #: ``op_name`` holds wins; ``loss`` and ``optimizer`` (trainer/sharded.py)
-#: hold every op of theirs that no inner scope names
+#: hold every op of theirs that no inner scope names, as ``loop.pass``
+#: (a looped stack's walk) holds a pass's
 SCOPES = (
     "ssm.scan", "ssm.in_proj", "ssm.conv", "ssm.dt", "ssm.gate_norm",
     "ssm.out_proj", "moe.latent_down", "moe.latent_up", "kda.scan", "kda.proj", "kda.conv", "kda.out", "kda.decay",
@@ -44,7 +45,8 @@ SCOPES = (
     "conv.out_proj", "moe.shared", "moe.route", "moe.dispatch",
     "moe.combine", "moe.experts", "moe.bias_update", "mtp.merge",
     "mtp.block", "mtp.head", "norm.post_attn", "norm.post_mlp",
-    "embed.mup", "optimizer", "loss",
+    "embed.mup", "loop.exit_gate", "loop.exit_loss", "loop.pass",
+    "optimizer", "loss",
 )
 
 

@@ -273,6 +273,19 @@ class LlamaConfig:
     # ``hybrid_override_pattern`` names the stack's (None: one block
     # of the stack's last kind)
     mtp_hybrid_override_pattern: Optional[str] = None
+    # a looped stack, in the source's keys (``OuroConfig``): the whole
+    # stack is walked ``total_ut_steps`` times a step with its one set
+    # of weights, the final norm inside the loop (a pass reads the
+    # normed state of the pass before, the first the embedding), the
+    # positions the same in every pass. A gate ``exit_gate`` reads each
+    # pass's normed state, ``lambda_t = sigmoid(w . h_t + b)`` a
+    # position, and the loss is every pass's cross entropy weighted
+    # position by position by the exit distribution ``p_t = lambda_t
+    # prod_{j<t} (1 - lambda_j)`` (the last pass takes what is left),
+    # less ``exit_entropy_weight`` times that distribution's entropy
+    # (``_losses_and_counts``). 1: no loop, no gate, the plain loss.
+    total_ut_steps: int = 1
+    exit_entropy_weight: float = 0.1
 
     def __post_init__(self):
         if self.remat not in ("off", "dots", "dots_attn_out",
@@ -406,6 +419,23 @@ class LlamaConfig:
             raise ValueError(
                 "mtp_hybrid_override_pattern names the sublayers of a "
                 "module past a stack that hybrid_override_pattern names"
+            )
+        if self.total_ut_steps < 1:
+            raise ValueError(
+                f"total_ut_steps {self.total_ut_steps}: the stack is "
+                "walked at least once"
+            )
+        if self.total_ut_steps > 1 and (
+                self.num_experts > 0 or self.mtp_layers
+                or self.by_position):
+            raise ValueError(
+                f"total_ut_steps {self.total_ut_steps}: the loop that "
+                "is built walks one stack of like dense layers; "
+                "experts (whose balance losses and bias rule's counts "
+                "would be summed over the passes), a prediction module "
+                "and a stack kept by position (layer_types, leading "
+                "dense layers, hybrid_override_pattern) beside it are "
+                "refused, not guessed"
             )
         if self.mtp_layers not in (0, 1):
             raise ValueError(
@@ -614,6 +644,14 @@ def llama_mamba_tiny(**kw) -> LlamaConfig:
         moe_capacity_factor=0.0, mtp_layers=1,
         mtp_hybrid_override_pattern="*E",
     ), **kw})
+
+
+def llama_loop_tiny(**kw) -> LlamaConfig:
+    """Test-sized looped stack: two dense layers of four norms a block
+    walked four times with their one set of weights, an exit gate a
+    position and the loss that weights every pass's cross entropy by
+    it."""
+    return llama_tiny(**{**dict(post_norms=True, total_ut_steps=4), **kw})
 
 
 def llama_tiny(**kw) -> LlamaConfig:
@@ -850,7 +888,10 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Dict:
     the prediction modules past the stack: the norms of the two
     inputs, the merge ``eh_proj`` [2 hidden, hidden], a block of the
     last layer's kind (with ``mtp_hybrid_override_pattern`` a list, a
-    block a sublayer) and a final norm of its own."""
+    block a sublayer) and a final norm of its own. A looped stack
+    (``total_ut_steps`` above 1) has ``exit_gate``: the gate's weight
+    ``w`` [hidden], drawn fan-in normal, and its bias ``b`` [1],
+    float32 zeros, one pair for all passes."""
     h = cfg.hidden_size
     k_embed, k_blocks, k_out = jax.random.split(rng, 3)
     lead, period = cfg.layer_plan()
@@ -896,6 +937,15 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Dict:
                     cfg, kind)),
             "final_norm": jnp.ones((h,), jnp.float32),
         }]
+    if cfg.total_ut_steps > 1:
+        params["exit_gate"] = {
+            "w": (
+                jax.random.normal(
+                    jax.random.fold_in(rng, 4), (h,), jnp.float32
+                ) * h ** -0.5
+            ).astype(cfg.dtype),
+            "b": jnp.zeros((1,), jnp.float32),
+        }
     return params
 
 
@@ -930,6 +980,8 @@ def param_axes(cfg: LlamaConfig) -> Dict:
                 cfg, lambda _, kind: _layer_axes(cfg, kind)),
             "final_norm": ("norm",),
         }]
+    if cfg.total_ut_steps > 1:
+        axes["exit_gate"] = {"w": ("norm",), "b": (None,)}
     return axes
 
 
@@ -967,6 +1019,7 @@ def param_count(cfg: LlamaConfig) -> int:
         cfg.vocab_size * h * embeddings + h
         + sum(n * layer(kind) for kind, n in _layers_of_each_kind(cfg))
         + cfg.mtp_layers * (2 * h * h + 3 * h)
+        + (h + 1 if cfg.total_ut_steps > 1 else 0)  # the exit gate
     )
 
 
@@ -1548,6 +1601,20 @@ def _run_stack(params, tokens, cfg: LlamaConfig, attn_fn=None,
     partitioner faced with ``x[batch/n, seq, embed] @ w[embed/n, mlp]``
     gathers the weight and leaves the activation where it is. None
     leaves every layout to the compiler."""
+    x, layer_of, constrain = _stack_entry(
+        params, tokens, cfg, attn_fn, constrain, expert_parallel
+    )
+    (x, aux), counts = _through_layers(
+        cfg, layer_of, (x, jnp.zeros((), jnp.float32)), params
+    )
+    return constrain(x, _RESIDUAL), aux, layer_of, counts
+
+
+def _stack_entry(params, tokens, cfg: LlamaConfig, attn_fn, constrain,
+                 expert_parallel):
+    """``(the embedded tokens, layer_of, constrain)``: what enters the
+    stack and what makes its layers (``_run_stack``), with
+    ``constrain`` as it is run (``_free`` for None)."""
     if attn_fn is None:
         attn_fn = partial(flash_attention, causal=True)
     if constrain is None:
@@ -1602,10 +1669,59 @@ def _run_stack(params, tokens, cfg: LlamaConfig, attn_fn=None,
             )
         return body
 
-    (x, aux), counts = _through_layers(
-        cfg, layer_of, (x, jnp.zeros((), jnp.float32)), params
+    return x, layer_of, constrain
+
+
+def _run_loop(params, tokens, cfg: LlamaConfig, attn_fn=None,
+              constrain=None):
+    """A looped stack's ``(states, gate logits)``: the normed state
+    out of each of the ``total_ut_steps`` passes, [passes, batch, seq,
+    hidden], and from them the exit gate's logits, float32 [passes,
+    batch, seq]. A pass (scope ``loop.pass``) is the whole stack and
+    then the final norm, on the normed state of the pass before (the
+    first on the embedding), with the one set of weights.
+
+    The passes are calls in a Python loop, not a ``lax.scan`` around
+    ``_through_layers``: on the chip at 16 layers x 4 passes of the
+    2048-wide model the unrolled step took 2.2555 s against the
+    scan's 2.2996 (1.9%), for 26.7 s against 18.9 s of compiling and
+    14.87 against 14.12 GB planned (PERF.md section 6, PR 58). The
+    step's program holds a layer body a pass; under remat ``minimal``
+    what is kept for the backward is a layer's input a layer and pass
+    either way.
+
+    A weight's gradient is a sum over the passes, which jax adds in
+    the cotangents' dtype, the weight's own: bfloat16 in the cells. A
+    float32 accumulator would stand beside every layer's weights for
+    the length of the backward (4 bytes a parameter where the gradient
+    itself is 2: 3.3 GB at 16 layers of 51.4 M, two layers' worth of
+    depth), to save three roundings of 2 ** -9 ahead of the one the
+    gradient gets anyway when it is handed to the optimizer in
+    bfloat16; ``tests/yardstick/test_yardstick_ouro.py`` holds the sum
+    against the float32 reference's gradient, leaf by leaf, in float32
+    and in bfloat16."""
+    x, layer_of, constrain = _stack_entry(
+        params, tokens, cfg, attn_fn, constrain, False
     )
-    return constrain(x, _RESIDUAL), aux, layer_of, counts
+    states = []
+    for _ in range(cfg.total_ut_steps):
+        with jax.named_scope("loop.pass"):
+            (x, _), _ = _through_layers(
+                cfg, layer_of, (x, jnp.zeros((), jnp.float32)), params
+            )
+            x = rms_norm(
+                constrain(x, _RESIDUAL), params["final_norm"],
+                cfg.norm_eps,
+            )
+        states.append(x)
+    states = jnp.stack(states)
+    with jax.named_scope("loop.exit_gate"):
+        gate = params["exit_gate"]
+        logits = jnp.einsum(
+            "tbsh,h->tbs", states, gate["w"],
+            preferred_element_type=jnp.float32,
+        ) + gate["b"]
+    return states, logits
 
 
 def hidden_states(
@@ -1618,7 +1734,11 @@ def hidden_states(
 ) -> Tuple[jax.Array, jax.Array]:
     """Final-norm hidden states [batch, seq, hidden] + MoE aux loss.
     ``expert_parallel``: the experts are sharded over an ``expert``
-    mesh axis (the trainer says so from its mesh)."""
+    mesh axis (the trainer says so from its mesh). A looped stack's
+    are its last pass's (no early exit: there is no decode path)."""
+    if cfg.total_ut_steps > 1:
+        states, _ = _run_loop(params, tokens, cfg, attn_fn, constrain)
+        return states[-1], jnp.zeros((), jnp.float32)
     x, aux, _, _ = _run_stack(
         params, tokens, cfg, attn_fn, constrain, expert_parallel
     )
@@ -1650,24 +1770,29 @@ def forward(
     return logits
 
 
-def _masked_nll(logits: jax.Array, targets: jax.Array) -> Tuple[
+def _position_nll(logits: jax.Array, targets: jax.Array) -> Tuple[
         jax.Array, jax.Array]:
-    """(sum of masked nll, mask count). targets < 0 mask positions out."""
+    """(nll a position, times the mask; the mask), both float32 in
+    ``targets``' shape. targets < 0 mask positions out."""
     mask = (targets >= 0).astype(jnp.float32)
     safe_targets = jnp.maximum(targets, 0)
     logp = jax.nn.log_softmax(logits, axis=-1)
     nll = -jnp.take_along_axis(
         logp, safe_targets[..., None], axis=-1
     )[..., 0]
-    return jnp.sum(nll * mask), jnp.sum(mask)
+    return nll * mask, mask
 
 
-def _chunked_ce(x: jax.Array, lm_head: jax.Array, targets: jax.Array,
-                chunk: int) -> Tuple[jax.Array, jax.Array]:
-    """Cross entropy without materializing full [tokens, vocab] logits:
-    a rematerialized scan over token chunks — each chunk's logits and
-    log-softmax are recomputed in the backward pass, so peak memory is
-    one [chunk, vocab] block instead of [batch*seq, vocab]."""
+def _masked_nll(logits: jax.Array, targets: jax.Array) -> Tuple[
+        jax.Array, jax.Array]:
+    """(sum of masked nll, mask count)."""
+    nll, mask = _position_nll(logits, targets)
+    return jnp.sum(nll), jnp.sum(mask)
+
+
+def _in_chunks(x: jax.Array, targets: jax.Array, chunk: int):
+    """``x`` [..., h] and ``targets`` as rows of ``chunk`` tokens,
+    [chunks, chunk, h] and [chunks, chunk]."""
     h = x.shape[-1]
     xf = x.reshape(-1, h)
     tf = targets.reshape(-1)
@@ -1679,8 +1804,16 @@ def _chunked_ce(x: jax.Array, lm_head: jax.Array, targets: jax.Array,
         xf = jnp.concatenate([xf, jnp.zeros((pad, h), xf.dtype)])
         tf = jnp.concatenate([tf, jnp.full((pad,), -1, tf.dtype)])
         n += pad
-    xc = xf.reshape(n // chunk, chunk, h)
-    tc = tf.reshape(n // chunk, chunk)
+    return xf.reshape(n // chunk, chunk, h), tf.reshape(n // chunk, chunk)
+
+
+def _chunked_ce(x: jax.Array, lm_head: jax.Array, targets: jax.Array,
+                chunk: int) -> Tuple[jax.Array, jax.Array]:
+    """Cross entropy without materializing full [tokens, vocab] logits:
+    a rematerialized scan over token chunks — each chunk's logits and
+    log-softmax are recomputed in the backward pass, so peak memory is
+    one [chunk, vocab] block instead of [batch*seq, vocab]."""
+    xc, tc = _in_chunks(x, targets, chunk)
 
     def body(carry, inp):
         nll_sum, cnt = carry
@@ -1704,6 +1837,124 @@ def _mean_ce(x, head, targets, chunk: int) -> jax.Array:
         logits = (x @ head).astype(jnp.float32)
         nll_sum, cnt = _masked_nll(logits, targets)
     return nll_sum / jnp.maximum(cnt, 1.0)
+
+
+def _ce_by_position(x, head, targets, chunk: int) -> jax.Array:
+    """Cross entropy of the normed states ``x`` through ``head`` a
+    position, float32 in ``targets``' shape, 0 where the target is
+    < 0; with ``chunk`` in ``_chunked_ce``'s rematerialized chunks."""
+    def nll(xs, ts):
+        return _position_nll((xs @ head).astype(jnp.float32), ts)[0]
+
+    if chunk <= 0:
+        return nll(x, targets)
+    _, chunks = jax.lax.scan(
+        jax.checkpoint(lambda _, inp: (None, nll(*inp))), None,
+        _in_chunks(x, targets, chunk),
+    )
+    return chunks.reshape(-1)[:targets.size].reshape(targets.shape)
+
+
+def _exit_distribution(gate_logits: jax.Array) -> Tuple[
+        jax.Array, jax.Array]:
+    """``(p, log p)`` over the passes, float32 [passes, ...], from the
+    exit gate's logits [passes, ...]: ``p_t = lambda_t prod_{j<t} (1 -
+    lambda_j)`` with ``lambda = sigmoid(logit)``, and the last pass
+    what is left, ``prod_{j<T} (1 - lambda_j)`` (its own logit is not
+    read), so the ``p_t`` sum to one. In logarithms: the entropy term
+    reads ``log p`` and no gate is so sure that it is not finite."""
+    logits = gate_logits[:-1].astype(jnp.float32)
+    stayed = jnp.cumsum(jax.nn.log_sigmoid(-logits), axis=0)
+    before = jnp.concatenate(
+        [jnp.zeros_like(gate_logits[:1], jnp.float32), stayed[:-1]]
+    )
+    log_p = jnp.concatenate(
+        [before + jax.nn.log_sigmoid(logits), stayed[-1:]]
+    )
+    return jnp.exp(log_p), log_p
+
+
+def _exit_terms(params, batch, cfg: LlamaConfig, attn_fn=None,
+                constrain=None):
+    """A looped stack's ``(nll, p, log p, mask)``: every pass's cross
+    entropy a position (0 where there is no target), the exit
+    distribution and its logarithm, all float32 [passes, batch, seq],
+    and the positions that have a target, float32 [batch, seq]. Each
+    pass goes through the head under a checkpoint of its own, so its
+    logits are made again in the backward and none is kept from the
+    forward: four unchunked would be 6.4 GB at 8,192 x 49,152, and
+    the cell's step plans 14.87 GB with them (the calls are unrolled
+    like the passes: a ``lax.map`` over them, which holds them to one
+    at a time by construction, planned 14.59 GB beside the unrolled
+    passes and ran 3% slower, PERF.md section 6, PR 58). Scope
+    ``loop.exit_loss``."""
+    tokens, targets = batch
+    states, gate_logits = _run_loop(
+        params, tokens, cfg, attn_fn, constrain
+    )
+    head = _head(params, cfg)
+    with jax.named_scope("loop.exit_loss"):
+        ce = jax.checkpoint(
+            lambda x: _ce_by_position(x, head, targets, cfg.loss_chunk)
+        )
+        nll = jnp.stack([ce(x) for x in states])
+        p, log_p = _exit_distribution(gate_logits)
+    return nll, p, log_p, (targets >= 0).astype(jnp.float32)
+
+
+def _exit_loss(params, batch, cfg: LlamaConfig, attn_fn=None,
+               constrain=None) -> jax.Array:
+    """A looped stack's loss: the mean over the positions with a
+    target of ``sum_t p_t nll_t - exit_entropy_weight H(p)``, ``H(p)
+    = -sum_t p_t log p_t``; gradients reach the gate and the trunk
+    through ``p``."""
+    nll, p, log_p, mask = _exit_terms(
+        params, batch, cfg, attn_fn, constrain
+    )
+    with jax.named_scope("loop.exit_loss"):
+        by_position = jnp.sum(
+            p * (nll + cfg.exit_entropy_weight * log_p), axis=0
+        )
+        return jnp.sum(by_position * mask) / jnp.maximum(
+            jnp.sum(mask), 1.0
+        )
+
+
+def loop_stats(params: Dict, batch, cfg: LlamaConfig,
+               attn_fn=None) -> Tuple[jax.Array, jax.Array]:
+    """``(each pass's mean cross entropy, each pass's mean exit
+    probability)`` on ``batch``, float32 [passes] each, over the
+    positions with a target: a forward pass (jit-able). The second
+    sums to one; a gate that has collapsed puts it all on one pass."""
+    nll, p, _, mask = _exit_terms(params, batch, cfg, attn_fn)
+    count = jnp.maximum(jnp.sum(mask), 1.0)
+    return (jnp.sum(nll, axis=(1, 2)) / count,
+            jnp.sum(p * mask, axis=(1, 2)) / count)
+
+
+def set_loop_gauges(pass_loss, exit_share) -> Tuple[list, list]:
+    """Set the gauges ``loop_pass_loss{pass}`` and
+    ``loop_exit_share{pass}`` (``GET /metrics``) to ``loop_stats``'
+    values at an evaluation, passes counted from 1."""
+    from dlrover_tpu.telemetry.registry import gauge
+
+    pass_loss = [float(v) for v in pass_loss]
+    exit_share = [float(v) for v in exit_share]
+    losses = gauge(
+        "loop_pass_loss",
+        "mean cross entropy of a looped stack's pass through the "
+        "head, unweighted, at the last evaluation", ("pass",),
+    )
+    shares = gauge(
+        "loop_exit_share",
+        "mean probability that the exit gate gives a pass of a looped "
+        "stack, at the last evaluation", ("pass",),
+    )
+    for t, (loss, share) in enumerate(zip(pass_loss, exit_share), 1):
+        # ``pass`` is a keyword: the label goes in as a mapping
+        losses.labels(**{"pass": str(t)}).set(loss)
+        shares.labels(**{"pass": str(t)}).set(share)
+    return pass_loss, exit_share
 
 
 def _mtp_states(cfg: LlamaConfig, params, module, x, ahead, layer_of):
@@ -1745,6 +1996,12 @@ def _losses_and_counts(params, batch, cfg: LlamaConfig, attn_fn=None,
     (``targets[i + 1]``): a sequence's last position has no token
     after it (the roll hands it the first, and its target masks it
     out) and its last two no target."""
+    if cfg.total_ut_steps > 1:
+        # the weighted sum over the passes stands where the one cross
+        # entropy does; no module, no experts beside it
+        nothing = jnp.zeros((), jnp.float32)
+        loss = _exit_loss(params, batch, cfg, attn_fn, constrain)
+        return (loss, nothing, nothing), None
     tokens, targets = batch
     x, aux, layer_of, counts = _run_stack(
         params, tokens, cfg, attn_fn, constrain, expert_parallel
@@ -2018,12 +2275,17 @@ def flops_per_token(cfg: LlamaConfig, seq_len: int) -> float:
     prediction module's
     block and second pass through the head included. For MoE, only
     the top-k routed experts execute per token, so N counts k experts
-    — not all E."""
+    — not all E. A looped stack meets every weight but the embedding's
+    once a pass."""
     n = param_count(cfg)
     if not cfg.tie_word_embeddings:
         n -= cfg.vocab_size * cfg.hidden_size  # tied-ish
     n += cfg.mtp_layers * cfg.vocab_size * cfg.hidden_size
-    kinds = _layers_of_each_kind(cfg)
+    n *= cfg.total_ut_steps
+    kinds = [
+        (kind, count * cfg.total_ut_steps)
+        for kind, count in _layers_of_each_kind(cfg)
+    ]
     if cfg.num_experts > 0:
         # an expert's two or three matrices on what it reads
         expert = (3 if cfg.moe_expert_gated else 2) * (

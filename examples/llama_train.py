@@ -55,6 +55,8 @@ MODELS = {
     "llama_linear_tiny": llama.llama_linear_tiny,
     "llama_sandwich_tiny": llama.llama_sandwich_tiny,
     "llama_mamba_tiny": llama.llama_mamba_tiny,
+    # two layers walked four times a step, an exit gate a position
+    "llama_loop_tiny": llama.llama_loop_tiny,
 }
 
 
@@ -226,6 +228,9 @@ def main():
     mtp_loss = None
     if cfg.mtp_layers:
         mtp_loss = jax.jit(functools.partial(llama.mtp_loss, cfg=cfg))
+    loop_stats = None
+    if cfg.total_ut_steps > 1:
+        loop_stats = jax.jit(functools.partial(llama.loop_stats, cfg=cfg))
     decay_min = None
     state_space = "M" in (cfg.hybrid_override_pattern or "")
     if "linear_attention" in (cfg.layer_types or ()) or state_space:
@@ -320,6 +325,20 @@ def main():
                     )
                     print(f"MTP_LOSS step={step} loss={float(loss):.4f} "
                           f"mtp_loss={term:.4f}", flush=True)
+                if loop_stats is not None:
+                    # each pass's own cross entropy and the share of
+                    # the exit distribution it holds, on this step's
+                    # first microbatch (GET /metrics)
+                    per_pass, shares = llama.set_loop_gauges(
+                        *loop_stats(params, (mb[0][0], mb[1][0]))
+                    )
+                    print(
+                        f"LOOP_EXIT step={step} loss={float(loss):.4f} "
+                        "pass_loss="
+                        + ",".join(f"{v:.4f}" for v in per_pass)
+                        + " exit_share="
+                        + ",".join(f"{v:.3f}" for v in shares),
+                        flush=True)
                 if decay_min is not None:
                     # how fast the delta rule's fastest channel, or
                     # the state-space scan's fastest head, forgets on

@@ -140,6 +140,14 @@ MODULE_BUDGET_OVERRIDES = {
     # model with a position-by-position scan (PR 54): 150s beside
     # three other workers
     "test_yardstick_nemotron": 240.0,
+    # a two-layer stack walked four times, jitted forward and backward
+    # under each remat policy and chunking (PR 58): 41s alone, up to
+    # 87s beside three other workers
+    "test_llama_loop": 150.0,
+    # the same loop against the float32 reference and its gradients,
+    # eight edited references on two batches (PR 58): 35s alone, 62s
+    # beside three other workers
+    "test_yardstick_ouro": 120.0,
     # two whole rehearsals of the new cell, launcher to last line: 69s
     "test_yardstick_nemotron_rehearsal": 150.0,
     # Pallas kernels in interpret mode, since PR 39 the in-place sum

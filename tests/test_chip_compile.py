@@ -296,22 +296,30 @@ CELL_ATTENTION = {
     # latent attention handed whole, as until PR 43 (v is 128 wide:
     # ``CELL_V_WIDTH``); the cell hands parts
     "joyai": (4, 8192, 32, 32, 192),
+    # the looped stack's: 16 ungrouped heads of 128 at 8,192 whole
+    "ouro": (1, 8192, 16, 16, 128),
 }
 CELL_V_WIDTH = {"joyai": 128}
 #: the backward is one kernel at each: a head's float32 dQ stays in
-#: VMEM without a group (256 KB, 2 MB), a kv head's float32 dK and dV
-#: with one (4, 8 and 16 MB)
+#: VMEM without a group (256 KB, 2 MB; 4 MiB of ``DQ_RESIDENT_BYTES``'
+#: 6 at ``ouro``'s 8,192 x 128), a kv head's float32 dK and dV with
+#: one (4, 8 and 16 MB)
 CELL_BACKWARD_FORM = {
     "gpt2-xl": "dq_resident", "olmoe": "dq_resident",
     "mistral": "dkv_resident", "lfm2": "dkv_resident",
     "smallthinker": "dkv_resident", "joyai": "dq_resident",
+    "ouro": "dq_resident",
 }
 #: what the grouped backward call asks of a v5e core's 128 MiB of VMEM
 #: (``jax/_src/pallas/mosaic/tpu_info.py``): the kv head's float32 dK
 #: and dV and two buffers of each one's output block, 8, 16 and 32
 #: MiB, and ``OTHER_VMEM_BYTES``, 28, for everything else
 CELL_BACKWARD_VMEM = {"mistral": 36 * 2 ** 20, "lfm2": 44 * 2 ** 20,
-                      "smallthinker": 60 * 2 ** 20}
+                      "smallthinker": 60 * 2 ** 20,
+                      # without a group, a head's float32 dQ at 8,192
+                      # x 128 (4 MiB) and two buffers of its output
+                      # block (2 MiB each) pass the default too
+                      "ouro": 36 * 2 ** 20}
 
 
 def _sum_grad(attn):
@@ -326,7 +334,7 @@ def _sum_grad(attn):
 #: backward compile holds as well
 @pytest.mark.parametrize("cell,grad", [
     ("gpt2-xl", False), ("gpt2-xl", True), ("olmoe", True), ("mistral", True),
-    ("lfm2", True), ("smallthinker", True),
+    ("lfm2", True), ("smallthinker", True), ("ouro", False), ("ouro", True),
 ], ids=lambda v: v if isinstance(v, str) else ("fwd", "fwd_bwd")[v])
 def test_sub_tiled_kernels_compile_at_the_cells_shapes(
     topo, on_tpu_path, cell, grad
@@ -370,8 +378,8 @@ def test_sub_tiled_kernels_compile_at_the_cells_shapes(
                  r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', line)]
     default = 16 * 2 ** 20  # written out beside a call that asks
     asked = [n for n in asked if n != default]
-    assert asked == ([CELL_BACKWARD_VMEM[cell]] if grad and group > 1
-                     else [])
+    assert asked == ([CELL_BACKWARD_VMEM[cell]]
+                     if grad and cell in CELL_BACKWARD_VMEM else [])
 
 
 #: the windowed cells' attention: 28 and 32 query heads on 4 of 128
@@ -501,6 +509,7 @@ WHOLE_KERNELS = {
     "lfm2": ("3b21acb8a143e85c", "761086b50c01458f"),
     "smallthinker": ("f2c890076821e395", "515ba026a486cd3e"),
     "joyai": ("ffb0b59e8f1d4260", "a29cfc45da995f91"),
+    "ouro": ("e4726d9dbf8af8e7", "6be7937b54eaa797"),
 }
 
 
@@ -1671,6 +1680,59 @@ def test_nemotron_step_holds_the_scans_kernels(topo, on_tpu_path, monkeypatch):
 #: 15,524,612,608, and the least effort 108 MB more than it did: it
 #: alone lifts the select's zeros, a float32 [16384, 8, 128], out of
 #: the layer loop and carries them through it
+OURO_STEP_BYTES = 14_867_043_328
+
+
+def test_ouro_step_fits_and_holds_a_layer_body_a_pass(topo, on_tpu_path):
+    """``ouro-2.6b-1chip.steady``'s step (1 x 8,192, 16 layers walked
+    four times, remat ``minimal``, the loss unchunked): it fits the
+    chip's 16.91 GB and plans no more than was read when the cell was
+    built, the four passes' float32 logits (6.4 GB) not among it; the
+    passes are unrolled, so the program holds a layer body a pass, and
+    in each the Pallas kernels as the benchmark's ``attn_kernel_ms``
+    tells them by name: the forward, the forward again under
+    ``minimal`` and ONE backward kernel with a head's dQ resident, not
+    the dq and dk/dv pair; the three scopes the loop brought are in
+    the compiled step's ``op_name``s beside the block's two."""
+    from yardstick import cells, worker
+    from yardstick.layer_metrics import attn_kernel_ms
+
+    _, config, traffic = cells.load_cell("ouro-2.6b-1chip.steady")
+    cfg = worker.program_config(config, traffic)
+    assert (cfg.remat, cfg.loss_chunk, cfg.total_ut_steps) == (
+        "minimal", 0, 4)
+    mesh = Mesh(
+        np.array(topo.devices[:1]).reshape(1, 1), ("data", "fsdp"))
+    trainer = make_trainer_for_llama(
+        cfg, mesh, strategy=traffic["strategy"],
+        optimizer=optax.adamw(traffic["optimizer"]["learning_rate"]),
+    )
+    compiled = trainer.train_step.lower(*_abstract_step_args(
+        trainer, traffic["global_batch"], traffic["seq"])
+    ).compile(LEAST_EFFORT)
+    planned = compiled.memory_analysis().peak_memory_in_bytes
+    print("ouro step plans", planned)
+    assert planned <= OURO_STEP_BYTES < 16.91e9
+    text = compiled.as_text()
+    kernels = re.findall(
+        r"%([\w.\-]+) = ([^\n]*?) custom-call\([^\n]*"
+        r"custom_call_target=\"tpu_custom_call\"[^\n]*"
+        r"op_name=\"([^\"]*)\"", text)
+    attn = [(name, op) for name, _, op in kernels
+            if attn_kernel_ms.KERNEL.search(name)]
+    assert fa._one_backward_kernel(1, 8192, 128)
+    assert len(kernels) == len(attn) == 4 * 3, [name for name, _ in attn]
+    assert all("loop.pass" in op for _, op in attn)
+    again = [op for _, op in attn if "rematted_computation" in op]
+    backward = [op for _, op in attn
+                if "transpose(jvp" in op and op not in again]
+    assert (len(again), len(backward)) == (4, 4), attn
+    for scope in ("loop.pass", "loop.exit_gate", "loop.exit_loss",
+                  "norm.post_attn", "norm.post_mlp"):
+        assert scope in text, scope
+    assert tuning.last_selection()["gqa_group"] == 1
+
+
 TRINITY_STEP_BYTES = 15_596_441_600
 
 
