@@ -27,6 +27,7 @@ On no cell's path.
 import argparse
 import collections
 import json
+import math
 import os
 import re
 import sys
@@ -63,7 +64,8 @@ def write_step(cell, out):
 
     _, config, traffic = cells.load_cell(cell)
     mesh = create_mesh(
-        list(traffic["mesh"].items()), devices=jax.devices()[:1])
+        list(traffic["mesh"].items()),
+        devices=jax.devices()[:math.prod(traffic["mesh"].values())])
     trainer = make_trainer_for(
         worker.program_config(config, traffic), mesh,
         strategy=traffic["strategy"],

@@ -1085,6 +1085,41 @@ def _free(x, logical_axes):
     return x
 
 
+def _tie(constrain, held, weights, axes):
+    """``(held, weights)`` as they came: nothing forward. In the
+    backward pass the ``weights``' gradients, laid out as the weights
+    are (``constrain`` to ``axes``, their logical axes), are complete
+    before ``held``'s cotangent goes on: one ``optimization_barrier``
+    around both.
+
+    For a step that gathers its weights (ZeRO-3) that layout is the
+    gradient's reduce-scatter, which the TPU's compiler runs as a ring
+    of ``collective-permute``s through the weight's partial products,
+    and whose last hop it leaves for the end of the layer loop's body,
+    where nothing asks for the result sooner: all seven rings' last
+    hops, 107 MB at Mistral-7B's widths, then stand in a row behind
+    the body's last product (2.7 ms a layer of ``mistral-7b-l16.fsdp4``
+    with no compute beside them, PERF.md section 6, PR 59). Tied to an
+    activation's cotangent that the backward makes later, a ring has
+    a deadline with a matmul group's compute before it. ``held`` is
+    what a later part of the forward made (so an earlier part of the
+    backward does not wait for it), the weights those used after
+    it."""
+
+    @jax.custom_vjp
+    def tied(held, weights):
+        return held, weights
+
+    def backward(_, cotangents):
+        held_ct, weights_ct = cotangents
+        return jax.lax.optimization_barrier(
+            (held_ct, jax.tree.map(constrain, weights_ct, axes))
+        )
+
+    tied.defvjp(lambda held, weights: ((held, weights), None), backward)
+    return tied(held, weights)
+
+
 def _pre_attn(cfg: LlamaConfig, x, layer_params, cos, sin,
               constrain=_free, kind=LayerKind()):
     """Block segment 1, up to the operator's call: the norm and, for
@@ -1364,14 +1399,16 @@ def _past_operator(cfg: LlamaConfig, x, out, layer_params,
 
 def _post_attn(cfg: LlamaConfig, x, out, layer_params,
                router_logits=None, constrain=_free, expert_parallel=False,
-               kind=LayerKind()):
+               kind=LayerKind(), tie=None):
     """Block segment 2, from the operator's result ``out``: output
     projection + residual + MLP, ``(x, aux, counts)``.
     ``router_logits``: ``_pre_attn``'s, where the router reads the
     block's input. ``counts``: where a rule moves the selection bias
     (``moe_bias_update_rate``) the assignments each of the router's
     experts received, int32 [experts] (``dropless_moe_mlp``); None
-    without one and for a dense MLP."""
+    without one and for a dense MLP. ``tie``: ``_block``'s; here a
+    dense MLP's ``w_down``, whose gradient is the backward's first,
+    is due when the gate's and the up's backward are through."""
     p = layer_params
     x = constrain(_past_operator(cfg, x, out, p, kind), _RESIDUAL)
     counts = None
@@ -1399,9 +1436,14 @@ def _post_attn(cfg: LlamaConfig, x, out, layer_params,
         else:
             out, aux = mlp(y, p["router"], *experts)
     else:
+        w_down = p["w_down"]
+        if tie is not None:
+            y, w_down = tie(
+                y, w_down, _layer_axes(cfg, kind)["w_down"]
+            )
         gate = jax.nn.silu(constrain(y @ p["w_gate"], _MLP))
         up = constrain(y @ p["w_up"], _MLP)
-        out, aux = (gate * up) @ p["w_down"], jnp.zeros((), jnp.float32)
+        out, aux = (gate * up) @ w_down, jnp.zeros((), jnp.float32)
     if cfg.post_norms:
         with jax.named_scope("norm.post_mlp"):
             out = rms_norm(out, p["post_mlp_norm"], cfg.norm_eps)
@@ -1409,17 +1451,26 @@ def _post_attn(cfg: LlamaConfig, x, out, layer_params,
 
 
 def _block(cfg: LlamaConfig, x, layer_params, cos, sin, operate,
-           constrain=_free, expert_parallel=False, kind=LayerKind()):
+           constrain=_free, expert_parallel=False, kind=LayerKind(),
+           tie=None):
     """One decoder block of ``kind`` around its operator's call
     ``operate`` (``_operator_of``). x: [batch, seq, hidden]. Returns
     (x, aux_loss, counts) where aux_loss is the MoE balance loss (0
-    for dense) and counts ``_post_attn``'s."""
+    for dense) and counts ``_post_attn``'s. ``tie(held, weights,
+    axes)``: ``_tie`` where the step gathers its weights (None
+    elsewhere, and nothing more is traced): the gradients of what
+    ``_post_attn`` reads are due when the operator's backward is
+    through."""
     operands, logits = _pre_attn(
         cfg, x, layer_params, cos, sin, constrain, kind
     )
+    if tie is not None:
+        operands, layer_params = tie(
+            operands, layer_params, _layer_axes(cfg, kind)
+        )
     return _post_attn(
         cfg, x, operate(*operands), layer_params, logits, constrain,
-        expert_parallel, kind,
+        expert_parallel, kind, tie,
     )
 
 
@@ -1583,7 +1634,8 @@ def _embed(params, tokens, cfg: LlamaConfig):
 
 
 def _run_stack(params, tokens, cfg: LlamaConfig, attn_fn=None,
-               constrain=None, expert_parallel: bool = False):
+               constrain=None, expert_parallel: bool = False,
+               gathered_weights: bool = False):
     """``(the residual stream out of the last layer, before the final
     norm; the MoE aux loss; layer_of)``: ``layer_of(kind)`` makes one
     more layer of ``kind`` under the config's remat policy, for a
@@ -1600,9 +1652,11 @@ def _run_stack(params, tokens, cfg: LlamaConfig, attn_fn=None,
     two MLP products) to the strategy's rule table, so that a
     partitioner faced with ``x[batch/n, seq, embed] @ w[embed/n, mlp]``
     gathers the weight and leaves the activation where it is. None
-    leaves every layout to the compiler."""
+    leaves every layout to the compiler. ``gathered_weights``:
+    ``next_token_loss``'."""
     x, layer_of, constrain = _stack_entry(
-        params, tokens, cfg, attn_fn, constrain, expert_parallel
+        params, tokens, cfg, attn_fn, constrain, expert_parallel,
+        gathered_weights,
     )
     (x, aux), counts = _through_layers(
         cfg, layer_of, (x, jnp.zeros((), jnp.float32)), params
@@ -1611,14 +1665,17 @@ def _run_stack(params, tokens, cfg: LlamaConfig, attn_fn=None,
 
 
 def _stack_entry(params, tokens, cfg: LlamaConfig, attn_fn, constrain,
-                 expert_parallel):
+                 expert_parallel, gathered_weights=False):
     """``(the embedded tokens, layer_of, constrain)``: what enters the
     stack and what makes its layers (``_run_stack``), with
-    ``constrain`` as it is run (``_free`` for None)."""
+    ``constrain`` as it is run (``_free`` for None).
+    ``gathered_weights``: ``next_token_loss``'; the layers then hold
+    ``_tie``s."""
     if attn_fn is None:
         attn_fn = partial(flash_attention, causal=True)
     if constrain is None:
         constrain = _free
+    tie = partial(_tie, constrain) if gathered_weights else None
     s = tokens.shape[1]
     cos, sin = rope_tables(s, cfg.rope_dim, cfg.rope_theta)
     x = constrain(_embed(params, tokens, cfg), _RESIDUAL)
@@ -1631,7 +1688,7 @@ def _stack_entry(params, tokens, cfg: LlamaConfig, attn_fn, constrain,
             x, aux_sum = carry
             x, aux, counts = _block(
                 cfg, x, layer_params, cos, sin, operate, constrain,
-                expert_parallel, kind,
+                expert_parallel, kind, tie,
             )
             return (x, aux_sum + aux), counts
 
@@ -1650,13 +1707,18 @@ def _stack_entry(params, tokens, cfg: LlamaConfig, attn_fn, constrain,
             )
             post = jax.checkpoint(
                 partial(_post_attn, cfg, constrain=constrain,
-                        expert_parallel=expert_parallel, kind=kind),
+                        expert_parallel=expert_parallel, kind=kind,
+                        tie=tie),
                 policy=policy,
             )
 
             def body(carry, layer_params):  # noqa: F811
                 x, aux_sum = carry
                 operands, logits = pre(x, layer_params, cos, sin)
+                if tie is not None:
+                    operands, layer_params = tie(
+                        operands, layer_params, _layer_axes(cfg, kind)
+                    )
                 out = operate(*operands)
                 x, aux, counts = post(x, out, layer_params, logits)
                 return (x, aux_sum + aux), counts
@@ -1673,7 +1735,7 @@ def _stack_entry(params, tokens, cfg: LlamaConfig, attn_fn, constrain,
 
 
 def _run_loop(params, tokens, cfg: LlamaConfig, attn_fn=None,
-              constrain=None):
+              constrain=None, gathered_weights: bool = False):
     """A looped stack's ``(states, gate logits)``: the normed state
     out of each of the ``total_ut_steps`` passes, [passes, batch, seq,
     hidden], and from them the exit gate's logits, float32 [passes,
@@ -1701,7 +1763,7 @@ def _run_loop(params, tokens, cfg: LlamaConfig, attn_fn=None,
     against the float32 reference's gradient, leaf by leaf, in float32
     and in bfloat16."""
     x, layer_of, constrain = _stack_entry(
-        params, tokens, cfg, attn_fn, constrain, False
+        params, tokens, cfg, attn_fn, constrain, False, gathered_weights
     )
     states = []
     for _ in range(cfg.total_ut_steps):
@@ -1875,7 +1937,7 @@ def _exit_distribution(gate_logits: jax.Array) -> Tuple[
 
 
 def _exit_terms(params, batch, cfg: LlamaConfig, attn_fn=None,
-                constrain=None):
+                constrain=None, gathered_weights: bool = False):
     """A looped stack's ``(nll, p, log p, mask)``: every pass's cross
     entropy a position (0 where there is no target), the exit
     distribution and its logarithm, all float32 [passes, batch, seq],
@@ -1890,7 +1952,7 @@ def _exit_terms(params, batch, cfg: LlamaConfig, attn_fn=None,
     ``loop.exit_loss``."""
     tokens, targets = batch
     states, gate_logits = _run_loop(
-        params, tokens, cfg, attn_fn, constrain
+        params, tokens, cfg, attn_fn, constrain, gathered_weights
     )
     head = _head(params, cfg)
     with jax.named_scope("loop.exit_loss"):
@@ -1903,13 +1965,13 @@ def _exit_terms(params, batch, cfg: LlamaConfig, attn_fn=None,
 
 
 def _exit_loss(params, batch, cfg: LlamaConfig, attn_fn=None,
-               constrain=None) -> jax.Array:
+               constrain=None, gathered_weights: bool = False) -> jax.Array:
     """A looped stack's loss: the mean over the positions with a
     target of ``sum_t p_t nll_t - exit_entropy_weight H(p)``, ``H(p)
     = -sum_t p_t log p_t``; gradients reach the gate and the trunk
     through ``p``."""
     nll, p, log_p, mask = _exit_terms(
-        params, batch, cfg, attn_fn, constrain
+        params, batch, cfg, attn_fn, constrain, gathered_weights
     )
     with jax.named_scope("loop.exit_loss"):
         by_position = jnp.sum(
@@ -1983,7 +2045,8 @@ def _mtp_states(cfg: LlamaConfig, params, module, x, ahead, layer_of):
 
 
 def _losses_and_counts(params, batch, cfg: LlamaConfig, attn_fn=None,
-                       constrain=None, expert_parallel: bool = False):
+                       constrain=None, expert_parallel: bool = False,
+                       gathered_weights: bool = False):
     """``((the main head's mean cross entropy, the prediction module's
     (0 without one), the scaled aux losses of every expert layer),
     counts)``. ``counts``: None, or where a rule moves the selection
@@ -2000,11 +2063,14 @@ def _losses_and_counts(params, batch, cfg: LlamaConfig, attn_fn=None,
         # the weighted sum over the passes stands where the one cross
         # entropy does; no module, no experts beside it
         nothing = jnp.zeros((), jnp.float32)
-        loss = _exit_loss(params, batch, cfg, attn_fn, constrain)
+        loss = _exit_loss(
+            params, batch, cfg, attn_fn, constrain, gathered_weights
+        )
         return (loss, nothing, nothing), None
     tokens, targets = batch
     x, aux, layer_of, counts = _run_stack(
-        params, tokens, cfg, attn_fn, constrain, expert_parallel
+        params, tokens, cfg, attn_fn, constrain, expert_parallel,
+        gathered_weights,
     )
     if counts is not None:
         counts = {"stack": counts}
@@ -2040,21 +2106,29 @@ def _losses(params, batch, cfg: LlamaConfig, attn_fn=None,
 def next_token_loss(
     params: Dict, batch: Tuple[jax.Array, jax.Array], cfg: LlamaConfig,
     attn_fn=None, constrain=None, expert_parallel: bool = False,
+    gathered_weights: bool = False,
 ) -> jax.Array:
     """Mean next-token cross entropy (plus, for an expert config, the
     scaled balance and z losses of every layer, and with a prediction
     module its own mean cross entropy at ``mtp_loss_weight``). batch
     = (tokens, targets), both int32 [batch, seq]; target < 0 masks
     the position out. ``constrain``, ``expert_parallel``: see
-    ``hidden_states``."""
+    ``hidden_states``. ``gathered_weights``: the step gathers each
+    layer's weights before it uses them and reduce-scatters their
+    gradients (ZeRO-3; the trainer says so from its mesh and rule
+    table): the gradients' reductions then have deadlines inside the
+    layer (``_tie``). The loss and its gradient are the same either
+    way."""
     return loss_and_expert_counts(
-        params, batch, cfg, attn_fn, constrain, expert_parallel
+        params, batch, cfg, attn_fn, constrain, expert_parallel,
+        gathered_weights,
     )[0]
 
 
 def loss_and_expert_counts(
     params: Dict, batch: Tuple[jax.Array, jax.Array], cfg: LlamaConfig,
     attn_fn=None, constrain=None, expert_parallel: bool = False,
+    gathered_weights: bool = False,
 ) -> Tuple[jax.Array, Dict]:
     """``(next_token_loss, counts)``: beside the loss, where a rule
     moves the selection bias (``moe_bias_update_rate``; else None),
@@ -2063,7 +2137,8 @@ def loss_and_expert_counts(
     ``moved_expert_bias``. What a trainer differentiates with
     ``has_aux``."""
     (ce, mtp_ce, aux), counts = _losses_and_counts(
-        params, batch, cfg, attn_fn, constrain, expert_parallel
+        params, batch, cfg, attn_fn, constrain, expert_parallel,
+        gathered_weights,
     )
     if cfg.mtp_layers:
         ce = ce + cfg.mtp_loss_weight * mtp_ce

@@ -258,6 +258,26 @@ def tree_shardings(
     )
 
 
+def gathers_params(axes_tree: Any, mesh: Mesh, rules: Rules) -> bool:
+    """Whether a step under ``rules`` on ``mesh`` has to gather the
+    parameters of ``axes_tree`` before it uses them, and reduce-scatter
+    their gradients (ZeRO-3): some leaf is split over a mesh axis that
+    the batch is split over too, so a device holds a part of the
+    weight and rows that need all of it. False on one device, with
+    replicated parameters (``ddp``, ``zero1``), and where the weights
+    are split over axes of their own (``tp``: the matmul is split with
+    them and nothing is gathered)."""
+    batch_axes = set(
+        jax.tree.leaves(tuple(spec_for_axes(("batch",), rules, mesh)))
+    )
+    return any(
+        batch_axes.intersection(jax.tree.leaves(tuple(sharding.spec)))
+        for sharding in jax.tree.leaves(
+            tree_shardings(axes_tree, mesh, rules)
+        )
+    )
+
+
 def opt_state_shardings(
     abs_opt_state: Any,
     abs_params: Any,

@@ -23,6 +23,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.parallel import sharding as shd
 from dlrover_tpu.parallel.mesh import create_mesh
+from dlrover_tpu.telemetry.registry import gauge
 
 
 class ShardedTrainer:
@@ -348,9 +349,23 @@ def make_trainer_for_llama(
         # a dropless config on such a mesh is refused here, before
         # anything is traced
         llama._expert_mlp(cfg, expert_parallel)
+    # where the step gathers each layer's weights (ZeRO-3), the
+    # gradients' reduce-scatters get deadlines inside the layer, or the
+    # compiler leaves every ring's last hop for the end of the layer
+    # loop's body (models/llama.py _tie): the mesh and the rule table
+    # decide, no flag
+    param_axes = llama.param_axes(cfg)
+    gathered_weights = shd.gathers_params(
+        param_axes["period" if cfg.by_position else "blocks"], mesh, rules
+    )
+    gauge(
+        "dlrover_trainer_gradient_deadlines",
+        "1 where the layers of the trainer's step tie their weights' "
+        "gradients to the backward's own progress, else 0",
+    ).set(int(gathered_weights))
     model = dict(
         cfg=cfg, attn_fn=attn_fn, constrain=constrain,
-        expert_parallel=expert_parallel,
+        expert_parallel=expert_parallel, gathered_weights=gathered_weights,
     )
     loss = lambda params, batch: llama.next_token_loss(  # noqa: E731
         params, batch, **model
@@ -369,12 +384,13 @@ def make_trainer_for_llama(
         )
     init = lambda rng: llama.init_params(rng, cfg)  # noqa: E731
     logger.info(
-        "ShardedTrainer: %s params=%.1fM mesh=%s strategy=%s accum=%d",
+        "ShardedTrainer: %s params=%.1fM mesh=%s strategy=%s accum=%d "
+        "gradient_deadlines=%d",
         type(cfg).__name__, llama.param_count(cfg) / 1e6,
-        dict(mesh.shape), strategy, accum_steps,
+        dict(mesh.shape), strategy, accum_steps, gathered_weights,
     )
     return ShardedTrainer(
-        loss, init, llama.param_axes(cfg), mesh, strategy=strategy,
+        loss, init, param_axes, mesh, strategy=strategy,
         optimizer=optimizer, accum_steps=accum_steps,
         frozen=llama.frozen_params(cfg), move_buffers=move_buffers,
     )

@@ -1874,3 +1874,42 @@ def test_fsdp_step_gathers_weights_not_activations(topo, on_tpu_path):
         [], [((batch, seq, cfg.hidden_size), False)],
     ), large
     assert sum(c["scatter"] for c in census) >= 7, census
+
+
+FSDP4_STEP_BYTES = 15_047_397_376
+
+
+def test_fsdp4_step_fits_and_ties_its_gradients(topo, on_tpu_path):
+    """``mistral-7b-l16.fsdp4``'s step (16 layers, 4 x 4096 over four
+    chips, remat ``dots_attn_out``): it fits a chip's 16.91 GB and
+    plans no more than was read when the gradients' reductions got
+    their deadlines (``models/llama.py _tie``; 15,018,036,224 without
+    them; the real chips plan 15,463,208,960 and 15,412,876,800,
+    PERF.md section 6, PR 59); each of its two loops holds one layer's
+    seven weight gathers (the barriers are gone from a scheduled
+    program's text: tests/test_gradient_deadlines.py holds them in
+    the jaxpr). Two layers in a body (``lax.scan``'s ``unroll=2``)
+    planned 15,610,513,920 here and 15,832,355,840 on the chips, where
+    the step then took 1,005 ms for 811."""
+    from yardstick import cells, worker
+
+    _, config, traffic = cells.load_cell("mistral-7b-l16.fsdp4")
+    cfg = worker.program_config(config, traffic)
+    assert (cfg.num_layers, cfg.remat) == (16, "dots_attn_out")
+    mesh = Mesh(
+        np.array(topo.devices).reshape(1, 4), tuple(traffic["mesh"]))
+    trainer = make_trainer_for_llama(
+        cfg, mesh, strategy=traffic["strategy"],
+        optimizer=optax.adamw(traffic["optimizer"]["learning_rate"]),
+    )
+    compiled = trainer.train_step.lower(*_abstract_step_args(
+        trainer, traffic["global_batch"], traffic["seq"])
+    ).compile()
+    planned = compiled.memory_analysis().peak_memory_in_bytes
+    print("fsdp4 step plans", planned)
+    assert planned <= FSDP4_STEP_BYTES < 16.91e9
+    in_loop = [
+        c for c in collective_census(compiled.as_text())
+        if c["kind"] == "all-gather" and c["in_loop"]
+    ]
+    assert len(in_loop) == 2 * 7, in_loop
