@@ -34,10 +34,16 @@ from dlrover_tpu.ops.short_conv import gated_short_conv
 from dlrover_tpu.ops.ssd import ssd_scan
 
 
+#: the operators a layer's kind may name
+OPERATORS = ("full_attention", "latent_attention", "linear_attention",
+             "conv", "state_space", "none")
+
+
 class LayerKind(NamedTuple):
     """What a layer is made of: its operator (``"full_attention"``,
-    ``"latent_attention"``, whose q, k and v come through low-rank
-    projections, ``"conv"``, the gated short convolution, or
+    ``"latent_attention"``, whose k and v, and q unless one matrix
+    makes it, come through low-rank projections, ``"conv"``, the
+    gated short convolution, or
     ``"linear_attention"``, the gated delta rule, ``"state_space"``,
     a Mamba-2 mixer, or ``"none"``), for attention the
     window (None: every earlier key) and whether q and
@@ -178,14 +184,21 @@ class LlamaConfig:
     # unweighted (``n_shared_experts``): one gated MLP of that many
     # times ``moe_intermediate_size``, whole on every device
     moe_shared_experts: int = 0
-    # latent attention, in the source's keys (``DeepseekV3Config``):
-    # with ``kv_lora_rank`` every attention layer's operator is
-    # "latent_attention". q comes from a ``q_lora_rank``-wide
-    # projection through an RMSNorm; k's un-rotated part and v from a
-    # ``kv_lora_rank``-wide one through another; each head's q and k
-    # are ``qk_nope_head_dim`` such columns and ``qk_rope_head_dim``
-    # rotated ones, the rotated part of k one head's that all heads
-    # share; v is ``v_head_dim`` wide (``head_dim`` is not read).
+    # latent attention, in the source's keys (``DeepseekV3Config``,
+    # ``KimiLinearConfig``): with ``kv_lora_rank`` a layer's operator
+    # is "latent_attention": every layer's without ``layer_types``,
+    # with it the layers it names so, beside "linear_attention" and
+    # "conv" ones (attention with whole q, k and v in the same stack
+    # is refused). q comes from a ``q_lora_rank``-wide projection
+    # through an RMSNorm, or with ``q_lora_rank`` None from the stream
+    # by one matrix ``wq`` with no norm; k's first part and v from a
+    # ``kv_lora_rank``-wide projection through an RMSNorm; each head's
+    # q and k are ``qk_nope_head_dim`` such columns and
+    # ``qk_rope_head_dim`` further ones, that part of k one head's
+    # that all heads share; v is ``v_head_dim`` wide (``head_dim`` is
+    # not read). The further columns are rotated where the layer's
+    # ``rope_layout`` entry is 1 (None: everywhere) and left as the
+    # products made them where it is 0 (``mla_use_nope``).
     # ``rope_interleave``: the rotation pairs columns (2i, 2i + 1),
     # not (i, i + half).
     q_lora_rank: Optional[int] = None
@@ -336,12 +349,21 @@ class LlamaConfig:
                     f"for {self.num_layers} layers"
                 )
             unknown = set(self.layer_types) - {
-                "conv", "full_attention", "linear_attention"}
+                "conv", "full_attention", "latent_attention",
+                "linear_attention"}
             if unknown:
                 raise ValueError(
                     f"layer_types names {sorted(unknown)}: the "
-                    "operators here are 'conv', 'full_attention' and "
-                    "'linear_attention'"
+                    "operators here are 'conv', 'full_attention', "
+                    "'latent_attention' and 'linear_attention'"
+                )
+            if self.latent != ("latent_attention" in self.layer_types) or (
+                    self.latent and "full_attention" in self.layer_types):
+                raise ValueError(
+                    "layer_types names 'latent_attention' where "
+                    "kv_lora_rank sizes it, and then no "
+                    "'full_attention' beside it: one stack's attention "
+                    "layers are all of whole q, k and v or all latent"
                 )
             if ("linear_attention" in self.layer_types
                     and not self.linear_num_heads):
@@ -361,18 +383,20 @@ class LlamaConfig:
         if self.moe_gate not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown moe_gate {self.moe_gate!r}")
         if self.latent and not (
-                self.q_lora_rank and self.qk_nope_head_dim
-                and self.qk_rope_head_dim and self.v_head_dim
+                self.qk_nope_head_dim and self.qk_rope_head_dim
+                and self.v_head_dim
                 and self.num_kv_heads == self.num_heads
-                and self.layer_types is None
                 and self.sliding_window_layout is None
-                and self.rope_layout is None
-                and not (self.qk_norm or self.qk_head_norm)):
+                and not (self.qk_norm or self.qk_head_norm
+                         or self.attn_out_gate)):
             raise ValueError(
-                "latent attention (kv_lora_rank) takes q_lora_rank, "
-                "qk_nope_head_dim, qk_rope_head_dim and v_head_dim, "
-                "as many kv heads as heads, and no layer pattern or "
-                "norm of whole q and k beside it"
+                "latent attention (kv_lora_rank) takes "
+                "qk_nope_head_dim, qk_rope_head_dim and v_head_dim "
+                "and as many kv heads as heads; q through q_lora_rank "
+                "or, with None, by one matrix; layer_types may name "
+                "its layers beside 'linear_attention' and 'conv' ones "
+                "and rope_layout those that rotate; a window, a norm "
+                "of whole q and k or a gate on its result is not built"
             )
         if self.moe_bias_update_rate and not (
                 self.num_experts > 0 and self.use_expert_bias):
@@ -721,16 +745,23 @@ def _leaves(cfg: LlamaConfig, kind: LayerKind) -> Dict:
         rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
         nope, rope, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                           cfg.v_head_dim)
+        # q through its latent and a norm, or by one matrix
         matrices = {
             "wq_a": ((h, rq), ("embed", None)),
             "wq_b": ((rq, nh * (nope + rope)), (None, "heads")),
-            # [c_kv | the one rotated key]
+        } if rq else {
+            "wq": ((h, nh * (nope + rope)), ("embed", "heads")),
+        }
+        matrices.update({
+            # [c_kv | the one key of the further columns]
             "wkv_a": ((h, rkv + rope), ("embed", None)),
             # a head's [k_nope | v]
             "wkv_b": ((rkv, nh * (nope + vd)), (None, "heads")),
             "wo": ((nh * vd, h), ("heads", "embed")),
-        }
-        norms.update(q_a_norm=rq, kv_a_norm=rkv)
+        })
+        if rq:
+            norms["q_a_norm"] = rq
+        norms["kv_a_norm"] = rkv
     else:
         matrices = {
             "wq": ((h, nh * hd), ("embed", "heads")),
@@ -1007,6 +1038,16 @@ def _layers_of_each_kind(cfg: LlamaConfig):
     ] + cfg.mtp_layers * [(kind, 1) for kind in cfg.mtp_kinds()]
 
 
+def operator_layers(cfg: LlamaConfig) -> Dict[str, int]:
+    """``{operator: how many layers have it}`` over the stack and the
+    prediction modules' blocks, the operators in the order of their
+    first layer (a block of the feed-forward alone is ``"none"``)."""
+    layers = {}
+    for kind, n in _layers_of_each_kind(cfg):
+        layers[kind.operator] = layers.get(kind.operator, 0) + n
+    return layers
+
+
 def param_count(cfg: LlamaConfig) -> int:
     def layer(kind):
         return sum(
@@ -1042,6 +1083,14 @@ def rope_tables(
     t = jnp.arange(seq_len, dtype=jnp.float32)
     angles = jnp.outer(t, freqs)  # [seq, head_dim/2]
     return jnp.cos(angles), jnp.sin(angles)
+
+
+def _rope_tables_of(cfg: LlamaConfig, seq_len: int):
+    """``rope_tables`` at the config's width for the layers whose kind
+    rotates; ``(None, None)``, and no table built, where none does."""
+    if not any(kind.rope for kind, _ in _layers_of_each_kind(cfg)):
+        return None, None
+    return rope_tables(seq_len, cfg.rope_dim, cfg.rope_theta)
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
@@ -1151,7 +1200,9 @@ def _pre_attn(cfg: LlamaConfig, x, layer_params, cos, sin,
             bcu = constrain(y @ p["conv_in"], _MLP)
         return (bcu, p["conv_w"]), logits()
     if kind.operator == "latent_attention":
-        return _latent_qkv(cfg, y, p, cos, sin, constrain), logits()
+        return _latent_qkv(
+            cfg, y, p, cos, sin, constrain, kind.rope
+        ), logits()
     if kind.operator == "linear_attention":
         return _delta_rule_operands(cfg, y, p, constrain), logits()
     q, k = y @ p["wq"], y @ p["wk"]
@@ -1242,56 +1293,75 @@ def _evens_then_odds(w):
     return jnp.concatenate([w[..., 0::2], w[..., 1::2]], axis=-1)
 
 
-def _latent_qkv(cfg: LlamaConfig, y, p, cos, sin, constrain=_free):
+def _latent_qkv(cfg: LlamaConfig, y, p, cos, sin, constrain=_free,
+                rotate: bool = True):
     """Latent attention's operands from the normed stream ``y``, in
     the parts their products make and ``flash_attention`` takes
     (``_latent_up``): q through its low-rank projection and an
-    RMSNorm; the un-rotated part of every head's k, and v, through
-    another; the rotated part of k straight from ``y``, one head's,
-    which every head shares."""
+    RMSNorm, or with ``q_lora_rank`` None straight from ``y`` by the
+    one matrix ``wq``; the first part of every head's k, and v,
+    through another low-rank projection; the further part of k
+    straight from ``y``, one head's, which every head shares.
+    ``rotate``: whether the layer turns the further parts by position
+    (its kind's ``rope``)."""
     rank, wkv_a = cfg.kv_lora_rank, p["wkv_a"]
-    if cfg.rope_interleave:
+    if cfg.rope_interleave and rotate:
         wkv_a = jnp.concatenate(
             [wkv_a[:, :rank], _evens_then_odds(wkv_a[:, rank:])], axis=-1
         )
-    with jax.named_scope("mla.q_down"):
-        c_q = rms_norm(y @ p["wq_a"], p["q_a_norm"], cfg.norm_eps)
+    if cfg.q_lora_rank:
+        with jax.named_scope("mla.q_down"):
+            c_q = rms_norm(y @ p["wq_a"], p["q_a_norm"], cfg.norm_eps)
+    else:
+        c_q = y
     with jax.named_scope("mla.kv_down"):
         c_kv, k_rope = jnp.split(y @ wkv_a, [rank], axis=-1)
         c_kv = rms_norm(c_kv, p["kv_a_norm"], cfg.norm_eps)
-    return _latent_up(cfg, c_q, c_kv, k_rope, p, cos, sin, constrain)
+    return _latent_up(
+        cfg, c_q, c_kv, k_rope, p, cos, sin, constrain, rotate
+    )
 
 
 def _latent_up(cfg: LlamaConfig, c_q, c_kv, k_rope, p, cos, sin,
-               constrain=_free):
-    """``(q_nope, k_nope, v, q_rope, k_rope)`` from the normed latents
-    ``c_q`` and ``c_kv`` and the un-rotated one key ``k_rope`` [b, s,
-    rope]: a head's un-rotated q and k [b, s, heads, nope], v [b, s,
-    heads, v_head_dim], its rotated q [b, s, heads, rope] and the
-    rotated key that every head shares [b, s, 1, rope]. Each part is a
-    product of its own on static columns of ``wq_b`` and ``wkv_b``: no
-    [b, s, heads, nope + rope] array is built, split or shuffled, and
-    the one key is never copied to a head. The scores are over a
-    head's whole q and k, so attention's own default scale is ``(nope
-    + rope) ** -0.5``. With ``rope_interleave`` the rotated columns,
-    q's here and k's as they come, are in ``apply_rope``'s (evens,
-    odds) order, which scores as the source's order does."""
+               constrain=_free, rotate: bool = True):
+    """``(q_nope, k_nope, v, q_rope, k_rope)`` from what q is
+    multiplied out of (``c_q``: its normed latent, or with
+    ``q_lora_rank`` None the normed stream itself), the normed latent
+    ``c_kv`` and the one key ``k_rope`` [b, s, rope] as its product
+    made it: a head's first part of q and k [b, s, heads, nope], v [b,
+    s, heads, v_head_dim], the further part of its q [b, s, heads,
+    rope] and the key of that width that every head shares [b, s, 1,
+    rope], both rotated where ``rotate`` says so. Each part is a
+    product of its own on static columns of ``wq_b`` (``wq``) and
+    ``wkv_b``: no [b, s, heads, nope + rope] array is built, split or
+    shuffled, and the one key is never copied to a head. The scores
+    are over a head's whole q and k, so attention's own default scale
+    is ``(nope + rope) ** -0.5``. With ``rope_interleave`` the rotated
+    columns, q's here and k's as they come, are in ``apply_rope``'s
+    (evens, odds) order, which scores as the source's order does.
+    q's two products stand under ``mla.up`` beside k's and v's, or,
+    where one matrix makes q from the stream, under ``mla.q``."""
     nh, nope = cfg.num_heads, cfg.qk_nope_head_dim
-    wq_b = p["wq_b"].reshape(-1, nh, nope + cfg.qk_rope_head_dim)
+    wq = p["wq_b"] if cfg.q_lora_rank else p["wq"]
+    wq = wq.reshape(-1, nh, nope + cfg.qk_rope_head_dim)
     wkv_b = p["wkv_b"].reshape(-1, nh, nope + cfg.v_head_dim)
-    wq_rope = wq_b[..., nope:]
-    if cfg.rope_interleave:
+    wq_rope = wq[..., nope:]
+    if cfg.rope_interleave and rotate:
         wq_rope = _evens_then_odds(wq_rope)
+
+    def up(c, w, axes):
+        return constrain(jnp.einsum("bsr,rhd->bshd", c, w), axes)
+
+    def turned(x):
+        return apply_rope(x, cos, sin) if rotate else x
+
+    with jax.named_scope("mla.up" if cfg.q_lora_rank else "mla.q"):
+        q_nope = up(c_q, wq[..., :nope], _Q)
+        q_rope = turned(up(c_q, wq_rope, _Q))
     with jax.named_scope("mla.up"):
-
-        def up(c, w, axes):
-            return constrain(jnp.einsum("bsr,rhd->bshd", c, w), axes)
-
-        q_nope = up(c_q, wq_b[..., :nope], _Q)
-        q_rope = apply_rope(up(c_q, wq_rope, _Q), cos, sin)
         k_nope = up(c_kv, wkv_b[..., :nope], _KV)
         v = up(c_kv, wkv_b[..., nope:], _KV)
-        k_rope = apply_rope(k_rope[:, :, None, :], cos, sin)
+        k_rope = turned(k_rope[:, :, None, :])
     return q_nope, k_nope, v, q_rope, k_rope
 
 
@@ -1482,8 +1552,9 @@ def _operator_of(cfg: LlamaConfig, attn_fn, kind: LayerKind):
     ``conv.mix``, in their device ops' ``op_name``, and hands a
     windowed layer's window to ``attn_fn`` (which has to take it);
     latent attention's call is ``attn.latent``, and hands ``attn_fn``
-    the rotated parts as ``q_rope`` and ``k_rope`` (which it has to
-    take, as ``flash_attention`` and ``mha_reference`` do). Linear
+    the further parts (rotated where the layer rotates) as ``q_rope``
+    and ``k_rope`` (which it has to take, as ``flash_attention`` and
+    ``mha_reference`` do). Linear
     attention's call is ``kda.scan``, the gated delta rule; it hands
     the output gate's logits on beside its result, as attention does
     with ``attn_out_gate``, for ``_operator_out``. A Mamba-2 mixer's
@@ -1677,7 +1748,7 @@ def _stack_entry(params, tokens, cfg: LlamaConfig, attn_fn, constrain,
         constrain = _free
     tie = partial(_tie, constrain) if gathered_weights else None
     s = tokens.shape[1]
-    cos, sin = rope_tables(s, cfg.rope_dim, cfg.rope_theta)
+    cos, sin = _rope_tables_of(cfg, s)
     x = constrain(_embed(params, tokens, cfg), _RESIDUAL)
 
     def layer_of(kind):
@@ -2225,7 +2296,7 @@ def _seen_in_layers(params, tokens, cfg: LlamaConfig, attn_fn, see):
     ``_post_attn``."""
     if attn_fn is None:
         attn_fn = partial(flash_attention, causal=True)
-    cos, sin = rope_tables(tokens.shape[1], cfg.rope_dim, cfg.rope_theta)
+    cos, sin = _rope_tables_of(cfg, tokens.shape[1])
 
     def layer_of(kind):
         operate = _operator_of(cfg, attn_fn, kind)

@@ -363,6 +363,16 @@ def make_trainer_for_llama(
         "1 where the layers of the trainer's step tie their weights' "
         "gradients to the backward's own progress, else 0",
     ).set(int(gathered_weights))
+    operator_layers = llama.operator_layers(cfg)
+    layers_gauge = gauge(
+        "dlrover_model_operator_layers",
+        "layers of the trainer's model whose operator is `operator`, "
+        "prediction modules' blocks among them",
+        ("operator",),
+    )
+    for operator in llama.OPERATORS:  # 0 where the last model had some
+        layers_gauge.labels(operator=operator).set(
+            operator_layers.get(operator, 0))
     model = dict(
         cfg=cfg, attn_fn=attn_fn, constrain=constrain,
         expert_parallel=expert_parallel, gathered_weights=gathered_weights,
@@ -384,10 +394,11 @@ def make_trainer_for_llama(
         )
     init = lambda rng: llama.init_params(rng, cfg)  # noqa: E731
     logger.info(
-        "ShardedTrainer: %s params=%.1fM mesh=%s strategy=%s accum=%d "
-        "gradient_deadlines=%d",
+        "ShardedTrainer: %s params=%.1fM operator_layers=%s mesh=%s "
+        "strategy=%s accum=%d gradient_deadlines=%d",
         type(cfg).__name__, llama.param_count(cfg) / 1e6,
-        dict(mesh.shape), strategy, accum_steps, gathered_weights,
+        operator_layers, dict(mesh.shape), strategy, accum_steps,
+        gathered_weights,
     )
     return ShardedTrainer(
         loss, init, param_axes, mesh, strategy=strategy,

@@ -148,6 +148,15 @@ MODULE_BUDGET_OVERRIDES = {
     # eight edited references on two batches (PR 58): 35s alone, 62s
     # beside three other workers
     "test_yardstick_ouro": 120.0,
+    # a five-layer stack of two operators (four token-by-token scans
+    # in the reference) against the float32 reference and its
+    # gradients, seventeen edited references on two batches, sixteen
+    # shares (PR 60): 110 s alone
+    "test_yardstick_kimi": 300.0,
+    # five- and nine-layer stacks of the delta rule and latent
+    # attention jitted forward and backward under each remat policy,
+    # and a trainer stepped on eight CPU devices (PR 60): 71 s alone
+    "test_llama_latent_pattern": 180.0,
     # two whole rehearsals of the new cell, launcher to last line: 69s
     "test_yardstick_nemotron_rehearsal": 150.0,
     # Pallas kernels in interpret mode, since PR 39 the in-place sum

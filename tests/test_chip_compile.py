@@ -1479,6 +1479,154 @@ def test_solar_step_holds_the_delta_rules_kernels(
     assert tuning.last_selection()["gqa_group"] == 8
 
 
+#: ``peak_memory_in_bytes`` of ``kimi-linear-48b-a3b-ep16.steady``'s
+#: step as this file compiles it (1 x 16,384, five layers, remat
+#: ``minimal``, the least effort; PERF.md, PR 60): 4.97 GB of it the
+#: state. At the default effort it read 9,291,786,240, and with 32 of
+#: the 256 experts held 12,337,465,344 (the file's ``depth``)
+KIMI_STEP_BYTES = 9_407_342_592
+
+
+def test_kimi_step_holds_the_pair_backward_and_both_operators_kernels(
+    topo, on_tpu_path, monkeypatch
+):
+    """``kimi-linear-48b-a3b-ep16.steady``'s step under
+    ``steady-1x16384``: it fits under 15.75 GiB and plans no more than
+    was read when the cell was built; the one latent layer's attention
+    reaches the kernels in parts (no instruction's result is a head's
+    whole 192-wide q or k) and, a head's float32 dQ at 16,384
+    positions of 192 columns being 12.6 MB against
+    ``DQ_RESIDENT_BYTES``, its backward is the dq and dk/dv pair: four
+    kernels named as ``attn_kernel_ms`` tells them under
+    ``attn.latent``, and the gauges say 2 parts and the pair; the four
+    delta-rule layers' scans (the leading layer's outside the loop,
+    three in the period's body) are named as ``delta_rule_ms`` tells
+    them under ``kda.scan``, 32 heads in grid groups of the most the
+    rule has, the backward over ``[1, 32, 256, 128, 128]`` entry
+    states; their convolutions are ``kda_conv`` calls under
+    ``kda.conv``; q's one matrix stands under ``mla.q`` and nothing
+    under ``mla.q_down``; the 2304 x 1024 experts take the tiles the
+    rule gives them and a share's walk its chunk."""
+    from dlrover_tpu.ops import delta_rule, grouped_matmul as gm, kda_conv
+    from dlrover_tpu.telemetry.registry import counter, gauge
+    from dlrover_tpu.ops.pallas import delta_rule as scan_kernels
+    from dlrover_tpu.ops.pallas import kda_conv as conv_kernels
+    from yardstick import cells, worker
+    from yardstick.layer_metrics import (
+        attn_kernel_ms, delta_rule_ms, moe_expert_ms, short_conv_ms,
+    )
+
+    monkeypatch.setattr(gm, "_use_pallas", lambda lhs, rhs: True)
+    monkeypatch.setattr(gm, "_interpret", lambda: False)
+    monkeypatch.setattr(gm, "_add_on_mxu", lambda out, rows: True)
+    monkeypatch.setattr(delta_rule, "_use_pallas", lambda q, heads: True)
+    monkeypatch.setattr(scan_kernels, "_interpret", lambda: False)
+    monkeypatch.setattr(
+        kda_conv, "_use_pallas", lambda x, w, l2_heads: (
+            conv_kernels.tiles_the_kernel(x.shape, w.shape, l2_heads)))
+    monkeypatch.setattr(conv_kernels, "_interpret", lambda: False)
+    calls = [counter(f"delta_rule_{handed}_calls", "")
+             for handed in ("rows", "folded")]
+    calls += [counter(f"kda_conv_{path}_calls", "")
+              for path in ("kernel", "plain")]
+    before = [c.value for c in calls]
+    gauge("delta_rule_heads_per_step", "").set(0)
+    for kernel in ("fwd", "dq", "dkv"):
+        gauge("attn_operand_parts", "", ("kernel",)).labels(
+            kernel=kernel).set(0)
+    gauge("attn_backward_kernels", "", ("form",)).labels(form="pair").set(0)
+    _, config, traffic = cells.load_cell("kimi-linear-48b-a3b-ep16.steady")
+    assert (traffic["global_batch"], traffic["seq"]) == (1, 16384)
+    cfg = worker.program_config(config, traffic)
+    assert (cfg.remat, cfg.loss_chunk, cfg.q_lora_rank) == (
+        "minimal", 0, None)
+    # 2304 = 18 x 128: the largest 128-multiple divisor within a tile
+    assert gm.tiles(8192, 2304, 1024) == (512, 768, 1024)
+    assert gm.tiles(8192, 1024, 2304) == (512, 1024, 768)
+    assert gm.tiles(8192, 2304, 1024, most=gm.IN_PLACE_TILE) == (
+        512, 768, 512)
+    # a chunk of the walk from 2304-wide rows: 8,192 x 2560 / 2304 in
+    # whole tiles of 512; even routing's 8,192 held rows are one chunk
+    assert moe.walk_chunks(16384 * 8, 2304) == (8704, 16)
+    assert not fa._one_backward_kernel(1, 16384, 192)
+    assert fa._one_backward_kernel(1, 8192, 192)  # joyai's, on the limit
+    mesh = Mesh(
+        np.array(topo.devices[:1]).reshape(1, 1), ("data", "fsdp"))
+    trainer = make_trainer_for_llama(
+        cfg, mesh, strategy=traffic["strategy"],
+        optimizer=optax.adamw(traffic["optimizer"]["learning_rate"]),
+    )
+    layers = gauge("dlrover_model_operator_layers", "", ("operator",))
+    assert [layers.labels(operator=o).value for o in (
+        "linear_attention", "latent_attention", "full_attention")] == [
+            4, 1, 0]
+    compiled = trainer.train_step.lower(*_abstract_step_args(
+        trainer, traffic["global_batch"], traffic["seq"])
+    ).compile(LEAST_EFFORT)
+    planned = compiled.memory_analysis().peak_memory_in_bytes
+    print("kimi step plans", planned)
+    assert planned <= KIMI_STEP_BYTES < 15.75 * 2 ** 30
+    text = compiled.as_text()
+    _routers_compare(text, traffic, cfg)
+    whole = re.findall(
+        r"bf16\[(?:1,16384,32|1,32,16384|32,1,16384|32,16384),192\]", text)
+    assert not whole, len(whole)
+    # the parts as the kernels take them, a head's 128 and 64 columns
+    assert "bf16[32,1,16384,128]" in text and "bf16[32,1,16384,64]" in text
+    kernels = re.findall(
+        r"%([\w.\-]+) = ([^\n]*?) custom-call\([^\n]*"
+        r"custom_call_target=\"tpu_custom_call\"[^\n]*"
+        r"op_name=\"([^\"]*)\"", text)
+    latent = [(name, result) for name, result, op in kernels
+              if "attn.latent" in op]
+    # the forward, the forward again under ``minimal``, then dq and
+    # dk/dv: two kernels where joyai's 8,192 positions run one
+    assert len(latent) == 4, latent
+    assert all(attn_kernel_ms.KERNEL.search(name) for name, _ in latent)
+    assert not any(
+        attn_kernel_ms.KERNEL.search(name)
+        for name, _, op in kernels if "attn.latent" not in op)
+    # dq in its two parts; dk in its two and dv
+    widths = sorted(
+        sorted(re.findall(r"16384,(\d+)\]", result))
+        for _, result in latent if "f32[" not in result)
+    assert widths == [["128", "128", "64"], ["128", "64"]], widths
+    parts = gauge("attn_operand_parts", "", ("kernel",))
+    assert [parts.labels(kernel=k).value for k in ("fwd", "dq", "dkv")] == [
+        2, 2, 2]
+    assert gauge("attn_backward_kernels", "", ("form",)).labels(
+        form="pair").value == 2
+    assert tuning.last_selection()["rope_head_dim"] == 64
+    assert tuning.last_selection()["seq"] == 16384
+    scan = [(name, op) for name, _, op in kernels
+            if delta_rule_ms.KERNEL.search(name)]
+    # the leading layer and three positions of the period, each the
+    # forward, the forward again and the backward
+    assert len(scan) == 4 * 3, [name for name, _ in scan]
+    assert all("kda.scan" in op for _, op in scan)
+    assert sum("while" not in op for _, op in scan) == 3  # the lead's
+    assert "f32[1,32,256,128,128]" in text  # the chunks' entry states
+    others = [name for name, _, op in kernels if "kda.scan" not in op]
+    assert not any(delta_rule_ms.KERNEL.search(name) for name in others)
+    assert not any(short_conv_ms.KERNEL.search(name) for name in others)
+    assert sum(bool(moe_expert_ms.KERNEL.search(n)) for n in others) >= 9
+    conv = [name for name, _, op in kernels if "kda.conv" in op]
+    assert len(conv) == 4 * 3 * 3, conv
+    assert all(name.startswith("kda_conv") for name in conv)
+    assert not any(
+        reader.KERNEL.search(name) for name in conv for reader in (
+            attn_kernel_ms, delta_rule_ms, moe_expert_ms, short_conv_ms))
+    # twelve scans on rows and none folded; twelve calls of the
+    # convolutions' entry (q, k, v a delta-rule layer), none plain
+    assert [c.value - was for c, was in zip(calls, before)] == [
+        12, 0, 12, 0]
+    together = gauge("delta_rule_heads_per_step", "").value
+    assert together == max(scan_kernels.HEADS_A_STEP) and 32 % together == 0
+    # q's product is named, and no q latent's is
+    assert "mla.q/" in text and "mla.q_down" not in text
+    assert "mla.kv_down" in text and "mla.up" in text
+
+
 #: ``peak_memory_in_bytes`` of ``nemotron-3-super-120b-a12b-ep64
 #: .steady``'s step as this file compiles it (1 x 8,192, eleven layers
 #: and the module, remat ``minimal``, the least effort; PERF.md, PR

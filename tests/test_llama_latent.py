@@ -160,11 +160,12 @@ def test_latent_attention_is_the_equations():
         out, _latent_attention_by_hand(cfg, y, p), rtol=1e-4, atol=1e-5)
 
 
-def _whole_qkv(cfg, y, p, cos, sin, constrain=None):
+def _whole_qkv(cfg, y, p, cos, sin, constrain=None, rotate=True):
     """``_latent_qkv`` as it was while attention took a head's q and k
     whole: two products, the activations split, rotated in
     neighbouring pairs, the rotated key copied to every head, and
     concatenated. Handed on with no rotated parts."""
+    assert rotate  # every layer of these configs rotates
     b, s, _ = y.shape
     nh, nope, rope = (cfg.num_heads, cfg.qk_nope_head_dim,
                       cfg.qk_rope_head_dim)
@@ -434,7 +435,10 @@ def test_flops_per_token_counts_both_widths_and_the_module():
 
 
 @pytest.mark.parametrize("change,sentence", [
-    (dict(q_lora_rank=None), "latent attention"),
+    # q_lora_rank None is q by one matrix since PR 60
+    # (tests/test_llama_latent_pattern.py); a window still is refused
+    (dict(sliding_window_size=16, sliding_window_layout=(1, 0, 0)),
+     "latent attention"),
     (dict(num_kv_heads=2), "latent attention"),
     (dict(v_head_dim=0), "latent attention"),
     (dict(qk_norm=True), "latent attention"),
