@@ -24,16 +24,23 @@ is what the kernels' exactness rests on there.
 those numbers of heads a grid step, whatever the kernels' own rule
 (``heads_a_step``) takes for the shape, prints the microseconds a
 chunk of one head costs beside the milliseconds a call, and holds each
-one's ``o`` and gradients to the first's bit for bit. ``--floors``
-adds two readings at each: a grid step whose body is empty (the
-blocks' DMAs and the step's own cost) and one whose ``_inverse``
-makes no product (the body without its longest chain).
+one's ``o`` and gradients to the first's bit for bit. Beside each it
+times the backward as it was before it read what the forward solved
+(``solving_again``: it makes a chunk's inverse and ``w`` from its
+operands, the blocks of the kept ones fetched and not read) and holds
+the kernels' gradients to that one's, bit for bit. ``--floors`` adds
+two readings at each, and alone takes the rule's own heads a step: a
+grid step whose body is empty (the blocks' DMAs and the step's own
+cost) and one whose ``_inverse`` makes no product (the forward's body
+without its longest chain; the backward makes no inverse, so its
+reading there is its own).
 
 On no cell's path. Only a TPU run says anything:
 ``chiprun -- python3 benchmarks/profile_delta_rule.py``.
 """
 
 import argparse
+import contextlib
 import functools
 import json
 import os
@@ -92,8 +99,8 @@ def _empty_forward(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
 
 
 def _empty_backward(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref,
-                    do_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
-                    dstate, **_):
+                    inv_ref, w_ref, do_ref, dq_ref, dk_ref, dv_ref, dg_ref,
+                    dbeta_ref, dstate, **_):
     dq_ref[...] = q_ref[...]
     dk_ref[...] = k_ref[...]
     dv_ref[...] = do_ref[...]
@@ -106,6 +113,13 @@ def _no_inverse(n):
     yield
 
 
+def _solved_again(cs, inv_ref, w_ref):
+    return kernels._solved(cs)
+
+
+#: the backward before PR 61: a chunk's inverse and ``w`` made again
+#: from its operands, as the forward makes them
+SOLVING_AGAIN = dict(_kept=_solved_again)
 #: what a grid step costs with less in it: the blocks' DMAs and the
 #: step's own cost alone, and the body without the inverse's chain of
 #: ten float32 products (the results are then no delta rule's)
@@ -116,18 +130,34 @@ FLOORS = {
 }
 
 
+@contextlib.contextmanager
+def built_with(stubs, together):
+    """The kernels built inside with ``stubs`` for their parts, at
+    ``together`` heads a grid step."""
+    real = {name: getattr(kernels, name) for name in stubs}
+    for name, stub in stubs.items():
+        setattr(kernels, name, stub)
+    at_heads_a_step(together)
+    try:
+        yield
+    finally:
+        for name, fn in real.items():
+            setattr(kernels, name, fn)
+        at_heads_a_step(together)
+
+
 def kernel_times(flat, do, n):
     """Milliseconds a call of the forward kernel, of the forward that
-    keeps the chunks' entry states and of the backward kernel over
-    them, as built now."""
+    keeps what the backward reads and of the backward kernel over
+    that, as built now."""
     keep = jax.jit(functools.partial(kernels.delta_rule, keep_states=True))
-    _, states = keep(*flat)
+    _, kept = keep(*flat)
     return {
         "forward_ms": 1e3 * timed(jax.jit(kernels.delta_rule), *flat, n=n),
         "forward_keeping_states_ms": 1e3 * timed(keep, *flat, n=n),
         "backward_ms": 1e3 * timed(
             jax.jit(lambda *a: kernels.delta_rule(
-                *a[:5], states=a[5], do=a[6])), *flat, states, do, n=n),
+                *a[:5], kept=a[5], do=a[6])), *flat, kept, do, n=n),
     }
 
 
@@ -169,9 +199,10 @@ def main(argv=None):
                          "their rule takes, and hold each one's results "
                          "to the first's, bit for bit")
     ap.add_argument("--floors", action="store_true",
-                    help="at each of --heads-per-step also time a grid "
-                         "step with an empty body and one whose "
-                         "_inverse makes no product")
+                    help="at each of --heads-per-step (without it, at "
+                         "the heads a step the kernels' rule takes) also "
+                         "time a grid step with an empty body and one "
+                         "whose _inverse makes no product")
     ap.add_argument("--out", default="chiprun_out/delta_rule.jsonl")
     args = ap.parse_args(argv)
     if jax.default_backend() != "tpu":
@@ -191,6 +222,8 @@ def main(argv=None):
     flat = (*(x.reshape(*x.shape[:2], -1) for x in ops[:4]), ops[4])
     flat_do = do.reshape(flat[2].shape)
     first = None
+    if args.floors and not args.heads_per_step:
+        args.heads_per_step = [kernels.heads_a_step(args.heads)]
     for together in args.heads_per_step if args.n else []:
         at_heads_a_step(together)
         row = {"what": "kernels alone", "shape": list(flat[0].shape),
@@ -202,20 +235,23 @@ def main(argv=None):
         first = first or got
         row["same_bits_as_first"] = all(
             bool((a == b).all()) for a, b in zip(got, first))
+        with built_with(SOLVING_AGAIN, together):
+            row.update(a_heads_chunk({
+                "backward_solving_again_ms": kernel_times(
+                    flat, flat_do, args.n)["backward_ms"]}, flat[0].shape))
+            row["same_bits_as_solving_again"] = all(
+                bool((a == b).all()) for a, b in zip(
+                    got[1:],
+                    gradients_of(kernels.delta_rule_tpu)(flat, flat_do)))
         rows.append(row)
         print(json.dumps(row), flush=True)
         for name, stubs in FLOORS.items() if args.floors else ():
-            real = {k: getattr(kernels, k) for k in stubs}
-            for k, stub in stubs.items():
-                setattr(kernels, k, stub)
-            at_heads_a_step(together)
-            rows.append({
-                "what": name, "heads_a_step": together,
-                **a_heads_chunk(kernel_times(flat, flat_do, args.n),
-                                flat[0].shape)})
+            with built_with(stubs, together):
+                rows.append({
+                    "what": name, "heads_a_step": together,
+                    **a_heads_chunk(kernel_times(flat, flat_do, args.n),
+                                    flat[0].shape)})
             print(json.dumps(rows[-1]), flush=True)
-            for k, fn in real.items():
-                setattr(kernels, k, fn)
     at_heads_a_step(None)
     row = {"what": "kernels timed", "shape": list(ops[0].shape),
            "chunk": kernels.CHUNK, "sub": kernels.SUB,
@@ -225,7 +261,7 @@ def main(argv=None):
         on_rows = functools.partial(gated_delta_rule_rows, heads=args.heads)
         row["forward_ms"] = 1e3 * timed(jax.jit(on_rows), *flat, n=args.n)
         row["forward_keeping_states_ms"] = 1e3 * timed(jax.jit(
-            lambda *a: kernels.delta_rule(*a, keep_states=True)),
+            functools.partial(kernels.delta_rule, keep_states=True)),
             *flat, n=args.n)
         row["forward_and_gradients_ms"] = 1e3 * timed(
             gradients_of(on_rows), flat, flat_do, n=args.n)

@@ -6,13 +6,19 @@ heads of one sequence (``heads_a_step``: the most of ``HEADS_A_STEP``
 that divides the operands' heads, one head where none does); the
 heads' chunks are walked in order, with each head's [values, keys]
 float32 state resident in VMEM (64 KB a head). The forward kernel goes
-up the sequence. Differentiated, it also writes each chunk's entry
-state, and the backward kernel goes down the sequence over them with
-the state's cotangent resident, making a chunk's ``A``, ``B``, ``W``
-again from its operands: one forward kernel that keeps ``[seq / 64,
-heads, 128, 128]`` float32 (256 MB a layer at 8,192 positions of 64
-heads, alive for that layer's backward pass only) and one backward
-kernel, not a second forward walk inside the backward.
+up the sequence. Differentiated, it also writes, for each chunk, the
+head's entry state and what it solved there: the pair's inverse and
+the head's ``w``. The backward kernel goes down the sequence over
+them with the state's cotangent resident. It makes a chunk's ``A``,
+``B`` and decay factors again from its operands, and solves nothing:
+the inverse is the longest chain of either kernel, and the float32
+one it reads is the one it would make, so its gradients are the same
+bits. One forward kernel that keeps ``[heads, seq / 64, 128, 128]``
+float32 entry states, ``[heads / 2, seq / 64, 128, 128]`` inverses and
+``[seq, heads x 128]`` of ``w`` (64 + 32 + 32 KB a head's chunk: 537,
+268 and 268 MB a layer at 8,192 positions of 64 heads, alive for that
+layer's backward pass only) and one backward kernel, not a second
+forward walk inside the backward.
 
 Why several heads. A head's chunk is a chain of small products, each
 waiting for the one before (the inverse below alone is seven deep, on
@@ -92,6 +98,9 @@ HEAD = 128
 #: kernels the backward pass runs (beside the forward that keeps the
 #: chunks' entry states)
 BACKWARD_KERNELS = 1
+#: inverses a chunk's backward makes: it reads the one the forward kept
+#: (tests/test_delta_rule.py counts them in the kernel's trace)
+BACKWARD_INVERSES = 0
 #: heads that may share a grid step, the most first. Eight read 6%
 #: under four in the kernels and 0.6% in the step, for 2.5 s more of
 #: every start spent tracing them (PERF.md, PR 46)
@@ -322,10 +331,15 @@ def _diagonal(blocks):
     ], axis=0)
 
 
+def _paired(heads) -> int:
+    """Heads whose inverse is taken as one matrix, of a grid step's
+    ``heads``: two; one where they are an odd number."""
+    return 1 if heads % 2 else 2
+
+
 def _pairs(xs):
-    """``xs`` two at a time; one at a time where they are an odd
-    number."""
-    size = 1 if len(xs) % 2 else 2
+    """``xs``, a grid step's heads', ``_paired`` at a time."""
+    size = _paired(len(xs))
     return [xs[at:at + size] for at in range(0, len(xs), size)]
 
 
@@ -345,16 +359,21 @@ def _lanes(j):
     return slice(j * HEAD, (j + 1) * HEAD)
 
 
-def _chunks(refs, beta_ref, states, dtype):
-    """For each head of a grid step the chunked form's parts, ``w``
-    among them, and for each pair of heads its inverse. ``refs`` are
-    the blocks of ``q, k, v, g``, a head a lane tile of each, and
-    ``states`` holds the heads' entry states."""
-    cs = _in_turn(
+def _parts(refs, beta_ref, states, dtype):
+    """For each head of a grid step the chunked form's parts ahead of
+    the inverse. ``refs`` are the blocks of ``q, k, v, g``, a head a
+    lane tile of each, and ``states`` holds the heads' entry states."""
+    return _in_turn(
         _before(*(ref[:, _lanes(j)].astype(F32) for ref in refs),
                 beta_ref[j], states[j], dtype)
         for j in range(states.shape[0])
     )
+
+
+def _solved(cs):
+    """Each pair of heads' inverse, and into each head's parts its
+    ``w``: what the forward solves of a chunk, and keeps for the
+    backward."""
     invs = _in_turn(
         _inverse(_diagonal([c["beta"] * c["a"] for c in pair]))
         for pair in _pairs(cs)
@@ -363,7 +382,15 @@ def _chunks(refs, beta_ref, states, dtype):
         invs, [c["beta"] * (c["v"] - c["held"]) for c in cs], _NN)
     for c, w in zip(cs, ws):
         c["w"] = w
-    return cs, invs
+    return invs
+
+
+def _kept(cs, inv_ref, w_ref):
+    """``_solved`` as the backward has it: read from what the forward
+    kept."""
+    for j, c in enumerate(cs):
+        c["w"] = w_ref[:, _lanes(j)]
+    return [inv_ref[pair] for pair in range(inv_ref.shape[0])]
 
 
 @_stage
@@ -378,16 +405,22 @@ def _result(c, dtype):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
                 scale, dtype):
-    states_ref, state = (rest if len(rest) == 2 else (None, *rest))
+    *kept, state = rest  # nothing, or the states', inverses' and w's
 
     @pl.when(pl.program_id(2) == 0)
     def _():
         state[...] = jnp.zeros_like(state)
 
-    cs, _ = _chunks((q_ref, k_ref, v_ref, g_ref), beta_ref, state, dtype)
+    cs = _parts((q_ref, k_ref, v_ref, g_ref), beta_ref, state, dtype)
+    invs = _solved(cs)
+    if kept:
+        states_ref, inv_ref, w_ref = kept
+        for pair, inv in enumerate(invs):
+            inv_ref[pair] = inv
     for j, c in enumerate(cs):
-        if states_ref is not None:
+        if kept:
             states_ref[j] = c["state"]
+            w_ref[:, _lanes(j)] = c["w"]
         o, state[j] = _result(c, dtype)
         o_ref[:, _lanes(j)] = (scale * o).astype(o_ref.dtype)
 
@@ -469,16 +502,16 @@ def _to_operands(c, d, after, dtype):
     )
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
-                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dstate, *,
-                scale, dtype):
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, inv_ref,
+                w_ref, do_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
+                dstate, *, scale, dtype):
     @pl.when(pl.program_id(2) == 0)
     def _():
         dstate[...] = jnp.zeros_like(dstate)
 
     heads = range(dstate.shape[0])
-    cs, invs = _chunks(
-        (q_ref, k_ref, v_ref, g_ref), beta_ref, states_ref, dtype)
+    cs = _parts((q_ref, k_ref, v_ref, g_ref), beta_ref, states_ref, dtype)
+    invs = _kept(cs, inv_ref, w_ref)
     dos = [scale * do_ref[:, _lanes(j)].astype(F32) for j in heads]
     afters = [dstate[j] for j in heads]
     ds = [_cotangents(c, do, after, dtype)
@@ -502,13 +535,15 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
 
 def _specs(chunks, together, reverse):
     """Block specs of a ``[batch, seq, heads x d]`` operand, of
-    ``beta`` as ``[batch, heads, seq, 1]`` and of the entry states
-    ``[batch, heads, chunks, d, d]``, for the grid ``(batch, heads /
-    together, chunk)``: a block is ``together`` adjacent heads' part
-    of each; ``reverse`` walks the chunks from the last."""
+    ``beta`` as ``[batch, heads, seq, 1]``, of the entry states
+    ``[batch, heads, chunks, d, d]`` and of the pairs' inverses
+    (``_kept_shapes``), for the grid ``(batch, heads / together,
+    chunk)``: a block is ``together`` adjacent heads' part of each;
+    ``reverse`` walks the chunks from the last."""
     def at(n):
         return chunks - 1 - n if reverse else n
 
+    paired = _paired(together)
     wide = pl.BlockSpec(
         (None, CHUNK, together * HEAD), lambda b, h, n: (b, at(n), h))
     beta = pl.BlockSpec(
@@ -516,7 +551,27 @@ def _specs(chunks, together, reverse):
     states = pl.BlockSpec(
         (None, together, None, HEAD, HEAD),
         lambda b, h, n: (b, h, at(n), 0, 0))
-    return wide, beta, states
+    invs = pl.BlockSpec(
+        (None, together // paired, None, paired * CHUNK, paired * CHUNK),
+        lambda b, h, n: (b, h, at(n), 0, 0))
+    return wide, beta, states, invs
+
+
+def _kept_shapes(batch, seq, heads):
+    """What the forward keeps of every chunk for the backward, beside
+    ``o``: the heads' entry states, each pair's inverse as ``_inverse``
+    returns it (two heads' on the diagonal of one matrix; one head's
+    where a grid step takes one), and the heads' ``w`` as rows. All
+    float32: the backward's products see the bits they would make."""
+    chunks = seq // CHUNK
+    paired = _paired(heads_a_step(heads))
+    return [
+        jax.ShapeDtypeStruct((batch, heads, chunks, HEAD, HEAD), F32),
+        jax.ShapeDtypeStruct(
+            (batch, heads // paired, chunks, paired * CHUNK, paired * CHUNK),
+            F32),
+        jax.ShapeDtypeStruct((batch, seq, heads * HEAD), F32),
+    ]
 
 
 def _params():
@@ -529,13 +584,12 @@ def _forward(q, k, v, g, beta, heads, keep_states):
     batch, seq, _ = q.shape
     chunks = seq // CHUNK
     together = heads_a_step(heads)
-    wide, beta_spec, states_spec = _specs(chunks, together, False)
+    wide, beta_spec, states_spec, invs_spec = _specs(chunks, together, False)
     out_specs = [wide]
     out_shape = [jax.ShapeDtypeStruct(v.shape, v.dtype)]
     if keep_states:
-        out_specs.append(states_spec)
-        out_shape.append(jax.ShapeDtypeStruct(
-            (batch, heads, chunks, HEAD, HEAD), F32))
+        out_specs += [states_spec, invs_spec, wide]
+        out_shape += _kept_shapes(batch, seq, heads)
     out = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=HEAD ** -0.5, dtype=q.dtype),
         grid=(batch, heads // together, chunks),
@@ -547,15 +601,16 @@ def _forward(q, k, v, g, beta, heads, keep_states):
     return out if keep_states else out[0]
 
 
-def _backward(q, k, v, g, beta, states, do, heads):
+def _backward(q, k, v, g, beta, kept, do, heads):
     batch, seq, _ = q.shape
     chunks = seq // CHUNK
     together = heads_a_step(heads)
-    wide, beta_spec, states_spec = _specs(chunks, together, True)
+    wide, beta_spec, states_spec, invs_spec = _specs(chunks, together, True)
     return pl.pallas_call(
         functools.partial(_bwd_kernel, scale=HEAD ** -0.5, dtype=q.dtype),
         grid=(batch, heads // together, chunks),
-        in_specs=[wide, wide, wide, wide, beta_spec, states_spec, wide],
+        in_specs=[wide, wide, wide, wide, beta_spec,
+                  states_spec, invs_spec, wide, wide],
         out_specs=[wide, wide, wide, wide, beta_spec],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -566,7 +621,7 @@ def _backward(q, k, v, g, beta, states, do, heads):
         ],
         scratch_shapes=[pltpu.VMEM((together, HEAD, HEAD), F32)],
         compiler_params=_params(), interpret=_interpret(),
-    )(q, k, v, g, beta, states, do)
+    )(q, k, v, g, beta, *kept, do)
 
 
 def _beta_by_head(beta):
@@ -576,19 +631,20 @@ def _beta_by_head(beta):
 
 
 @functools.partial(jax.jit, static_argnames=("keep_states",))
-def delta_rule(q, k, v, g, beta, states=None, do=None, keep_states=False):
+def delta_rule(q, k, v, g, beta, kept=None, do=None, keep_states=False):
     """On rows ``[batch, seq, heads x 128]`` and ``beta`` ``[batch,
     seq, heads]``: the forward kernel's ``o`` (with ``keep_states``
-    also the chunks' entry states), or with the states and the
-    result's cotangent ``do`` the backward kernel's five gradients,
+    also what the backward reads: the chunks' entry states, inverses
+    and ``w``, ``_kept_shapes``), or with those three as ``kept`` and
+    the result's cotangent ``do`` the backward kernel's five gradients,
     each in its operand's shape. One jitted name for both, which is
     what a device trace calls them."""
     heads = q.shape[2] // HEAD
     wide = (q, k, v, g.astype(F32), _beta_by_head(beta.astype(F32)))
     if do is None:
         out = _forward(*wide, heads, keep_states)
-        return tuple(out) if keep_states else out
-    dq, dk, dv, dg, dbeta = _backward(*wide, states, do, heads)
+        return (out[0], tuple(out[1:])) if keep_states else out
+    dq, dk, dv, dg, dbeta = _backward(*wide, kept, do, heads)
     return (
         dq, dk, dv, dg.astype(g.dtype),
         jnp.swapaxes(dbeta[..., 0], 1, 2).astype(beta.dtype),
@@ -622,6 +678,19 @@ def _record(folded, heads):
         "Pallas kernels of the gated delta rule's backward pass, beside "
         "the forward that keeps the chunks' entry states",
     ).set(BACKWARD_KERNELS)
+    gauge(
+        "delta_rule_backward_inverses",
+        "inverses of a chunk's (I + Diag(beta) A) that the gated delta "
+        "rule's backward kernel makes: 0, it reads the forward's",
+    ).set(BACKWARD_INVERSES)
+    gauge(
+        "delta_rule_kept_bytes",
+        "bytes a head's chunk of the gated delta rule keeps from the "
+        "forward for its backward: the entry state, its share of the "
+        "inverse and w, float32",
+    ).set(sum(
+        array.size * array.dtype.itemsize
+        for array in _kept_shapes(1, CHUNK, together)) // together)
     if folded:
         counter(
             "delta_rule_folded_calls",
@@ -648,14 +717,14 @@ def delta_rule_tpu(q, k, v, g, beta, folded=False):
 
 def _vjp_fwd(q, k, v, g, beta, folded):
     _record(folded, beta.shape[2])
-    o, states = delta_rule(q, k, v, g, beta, keep_states=True)
-    return o, (q, k, v, g, beta, states)
+    o, kept = delta_rule(q, k, v, g, beta, keep_states=True)
+    return o, (q, k, v, g, beta, kept)
 
 
 def _vjp_bwd(folded, saved, do):
-    *operands, states = saved
+    *operands, kept = saved
     _record(folded, operands[4].shape[2])
-    return delta_rule(*operands, states=states, do=do)
+    return delta_rule(*operands, kept=kept, do=do)
 
 
 delta_rule_tpu.defvjp(_vjp_fwd, _vjp_bwd)

@@ -1310,8 +1310,10 @@ def test_joyai_step_holds_no_whole_q_or_k(topo, on_tpu_path, monkeypatch):
 #: delta-rule layers' heads an axis of their own between the
 #: projections and the kernels (PR 44) it read 16,240,236,032: the
 #: norms' factors at full width and the relayouts' copies; in rows with
-#: the convolutions as plain float32 ops (PR 45) 15,315,505,664
-SOLAR_STEP_BYTES = 14_462_113_280
+#: the convolutions as plain float32 ops (PR 45) 15,315,505,664.
+#: 14,462,113,280 until the scan's forward kept a layer's inverses and
+#: ``w`` for its backward (PR 61): 268 MB each, one layer's at a time
+SOLAR_STEP_BYTES = 14_998_671_872
 #: what a delta-rule layer's q, k, v, g or o is as rows, as heads, and
 #: as the tiles of rows that the compiler names ``[s / 8, 8, heads, d]``
 SOLAR_ROWS = re.compile(
@@ -1338,6 +1340,20 @@ def _outside_fusions(text, ops):
                     instructions[o][0] for o in operands
                     if o in instructions
                 ], name.group(1) if name else "")
+
+
+def _shapes_of_the_keeping_calls(kernels, named):
+    """The results' shapes of each custom call ``named`` whose results
+    are one bfloat16 array and float32 ones behind it: the scan's
+    forward that keeps what its backward reads (the plain forward has
+    ``o`` alone, the backward three bfloat16 gradients)."""
+    results = [re.findall(r"(\w+)(\[[\d,]+\])", result)
+               for name, result, _ in kernels if named.search(name)]
+    return [
+        [dtype + shape for dtype, shape in shapes] for shapes in results
+        if [dtype for dtype, _ in shapes] == ["bf16"] + ["f32"] * (
+            len(shapes) - 1) and len(shapes) > 1
+    ]
 
 
 def test_solar_step_holds_the_delta_rules_kernels(
@@ -1419,6 +1435,11 @@ def test_solar_step_holds_the_delta_rules_kernels(
     # three linear positions of the period, each the forward, the
     # forward again and the backward
     assert len(scan) == 3 * 3, [name for name, _ in scan]
+    # either forward keeps, beside ``o``, the chunks' entry states
+    # [batch, heads, chunks, 128, 128], the pairs' inverses and ``w``
+    assert _shapes_of_the_keeping_calls(kernels, delta_rule_ms.KERNEL) == [[
+        "bf16[1,8192,8192]", "f32[1,64,128,128,128]",
+        "f32[1,32,128,128,128]", "f32[1,8192,8192]"]] * 6
     assert all("kda.scan" in op for _, op in scan)
     others = [name for name, _, op in kernels if "kda.scan" not in op]
     assert others and not any(
@@ -1443,8 +1464,6 @@ def test_solar_step_holds_the_delta_rules_kernels(
     assert not any(
         reader.KERNEL.search(name) for name in conv for reader in (
             attn_kernel_ms, delta_rule_ms, moe_expert_ms, short_conv_ms))
-    # the backward's entry states: [batch, heads, chunks, 128, 128]
-    assert "f32[1,64,128,128,128]" in text
     wide = re.compile(r"= \w+\[1,8192,8192\]")
     unscoped = [
         line[:160] for line in text.splitlines()
@@ -1468,6 +1487,10 @@ def test_solar_step_holds_the_delta_rules_kernels(
     assert together == max(scan_kernels.HEADS_A_STEP) > 1
     assert gauge(
         "delta_rule_state_bytes", "").value == together * 128 * 128 * 4
+    assert gauge("delta_rule_backward_inverses", "").value == 0
+    # a head's chunk: its state, half a pair's inverse, its w
+    assert gauge("delta_rule_kept_bytes", "").value == 4 * (
+        128 * 128 + 64 * 128 + 64 * 128)
     moved = [
         (op, result[:40], name[-60:])
         for op, result, operands, name in _outside_fusions(
@@ -1483,8 +1506,11 @@ def test_solar_step_holds_the_delta_rules_kernels(
 #: step as this file compiles it (1 x 16,384, five layers, remat
 #: ``minimal``, the least effort; PERF.md, PR 60): 4.97 GB of it the
 #: state. At the default effort it read 9,291,786,240, and with 32 of
-#: the 256 experts held 12,337,465,344 (the file's ``depth``)
-KIMI_STEP_BYTES = 9_407_342_592
+#: the 256 experts held 12,337,465,344 (the file's ``depth``).
+#: 9,407,342,592 until the scan's forward kept a layer's inverses and
+#: ``w`` for its backward (PR 61): 268 MB each a layer, 215 MB of it
+#: over what the step's peak held beside them
+KIMI_STEP_BYTES = 9_622_743_040
 
 
 def test_kimi_step_holds_the_pair_backward_and_both_operators_kernels(
@@ -1605,7 +1631,10 @@ def test_kimi_step_holds_the_pair_backward_and_both_operators_kernels(
     assert len(scan) == 4 * 3, [name for name, _ in scan]
     assert all("kda.scan" in op for _, op in scan)
     assert sum("while" not in op for _, op in scan) == 3  # the lead's
-    assert "f32[1,32,256,128,128]" in text  # the chunks' entry states
+    # the chunks' entry states, the pairs' inverses and ``w``
+    assert _shapes_of_the_keeping_calls(kernels, delta_rule_ms.KERNEL) == [[
+        "bf16[1,16384,4096]", "f32[1,32,256,128,128]",
+        "f32[1,16,256,128,128]", "f32[1,16384,4096]"]] * 8
     others = [name for name, _, op in kernels if "kda.scan" not in op]
     assert not any(delta_rule_ms.KERNEL.search(name) for name in others)
     assert not any(short_conv_ms.KERNEL.search(name) for name in others)
@@ -1622,6 +1651,9 @@ def test_kimi_step_holds_the_pair_backward_and_both_operators_kernels(
         12, 0, 12, 0]
     together = gauge("delta_rule_heads_per_step", "").value
     assert together == max(scan_kernels.HEADS_A_STEP) and 32 % together == 0
+    assert gauge("delta_rule_backward_inverses", "").value == 0
+    assert gauge("delta_rule_kept_bytes", "").value == 4 * (
+        128 * 128 + 64 * 128 + 64 * 128)
     # q's product is named, and no q latent's is
     assert "mla.q/" in text and "mla.q_down" not in text
     assert "mla.kv_down" in text and "mla.up" in text

@@ -204,6 +204,81 @@ def test_batch_rows_and_heads_are_independent(path, heads):
         assert bool((a[:, :, last:] == c).all()), name
 
 
+def solved_again(cs, inv_ref, w_ref):
+    """What the backward kernel made of a grid step's chunks before it
+    read what the forward kept (``_chunks`` as it was, kept here as
+    the reference): each pair's inverse and each head's ``w`` again
+    from the operands, the kept blocks unread."""
+    invs = kernels._in_turn(
+        kernels._inverse(
+            kernels._diagonal([c["beta"] * c["a"] for c in pair]))
+        for pair in kernels._pairs(cs)
+    )
+    ws = kernels._across(
+        invs, [c["beta"] * (c["v"] - c["held"]) for c in cs], kernels._NN)
+    for c, w in zip(cs, ws):
+        c["w"] = w
+    return invs
+
+
+#: (sequence, decay, dtype): float32 over a chunk and a bit; every
+#: channel at the entry's floor at every position; the cells' bfloat16
+KEPT_CASES = {
+    "many chunks": (320, 0.3, jnp.float32),
+    "g at the floor": (128, (-delta_rule.G_FLOOR,), jnp.float32),
+    "bfloat16": (192, 1.0, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", list(KEPT_CASES))
+@pytest.mark.parametrize("heads", [3, 2, 4])
+def test_backward_reads_what_the_forward_solved(heads, case, monkeypatch):
+    """The forward that keeps the chunks' entry states keeps each
+    pair's inverse and each head's ``w`` beside them, float32; the
+    backward kernel over them makes no inverse, and its five gradients
+    are, bit for bit, those of a backward that solves each chunk
+    again: at one, two and four heads a grid step."""
+    seq, decay, dtype = KEPT_CASES[case]
+    args = operands(31, 2, seq, heads, decay, dtype=dtype)
+    flat = (*(rows(x) for x in args[:4]), args[4])
+    do = rows(jax.random.normal(
+        jax.random.key(9), args[2].shape)).astype(dtype)
+    o, kept = kernels.delta_rule(*flat, keep_states=True)
+    assert bool((o == kernels.delta_rule(*flat)).all())
+    paired = min(HEADS_A_STEP[heads], 2)  # heads whose inverse is one matrix
+    chunks = seq // kernels.CHUNK
+    assert [(x.shape, x.dtype) for x in kept] == [
+        ((2, heads, chunks, D, D), jnp.float32),
+        ((2, heads // paired, chunks, paired * 64, paired * 64),
+         jnp.float32),
+        ((2, seq, heads * D), jnp.float32),
+    ]
+    made = []
+
+    def inverse(n):
+        made.append(n.shape)
+        return (yield from kernels_inverse(n))
+
+    kernels_inverse = kernels._inverse
+    monkeypatch.setattr(kernels, "_inverse", inverse)
+
+    def backward():
+        # a trace of its own: the kernel's body is looked up as it is now
+        return jax.jit(lambda *a: kernels.delta_rule.__wrapped__(
+            *a[:5], kept=a[5:8], do=a[8]))(*flat, *kept, do)
+
+    got = backward()
+    assert len(made) == kernels.BACKWARD_INVERSES == 0
+    monkeypatch.setattr(kernels, "_kept", solved_again)
+    want = backward()
+    assert made == [(paired * 64, paired * 64)] * (
+        HEADS_A_STEP[heads] // paired)
+    for name, a, b in zip(NAMES, got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert bool(jnp.isfinite(a.astype(jnp.float32)).all()), name
+        assert bool((a == b).all()), name
+
+
 def test_kernels_in_bfloat16_are_within_a_step_of_bfloat16():
     """bfloat16 operands, the products' operands rounded to bfloat16
     inside: ``o`` and the gradients within 2 ** -7 of each one's
@@ -393,12 +468,14 @@ def test_dispatch_says_what_it_built(kernels_at_the_entry):
     """The gauges, set where the kernels are built, and the counters
     of the calls built on rows and on heads that were folded: one for
     a forward, two more for its gradients (the forward that keeps the
-    entry states, the backward)."""
+    entry states and what it solved, the backward that reads them)."""
     from dlrover_tpu.telemetry.registry import gauge
 
     for name in ("delta_rule_chunk", "delta_rule_state_bytes",
-                 "delta_rule_backward_kernels", "delta_rule_heads_per_step"):
+                 "delta_rule_backward_kernels", "delta_rule_heads_per_step",
+                 "delta_rule_kept_bytes"):
         gauge(name, "").set(0)
+    gauge("delta_rule_backward_inverses", "").set(1)
     args = operands(1, 1, 64, 1, 0.5)
     flat = (*(rows(x) for x in args[:4]), args[4])
     before = _calls()
@@ -407,6 +484,10 @@ def test_dispatch_says_what_it_built(kernels_at_the_entry):
     assert gauge("delta_rule_heads_per_step", "").value == 1
     assert gauge("delta_rule_state_bytes", "").value == 128 * 128 * 4
     assert gauge("delta_rule_backward_kernels", "").value == 1
+    assert gauge("delta_rule_backward_inverses", "").value == 0
+    # a head by itself: its state, its [64, 64] inverse and its w
+    assert gauge("delta_rule_kept_bytes", "").value == 4 * (
+        128 * 128 + 64 * 64 + 64 * 128)
     assert _calls() == (before[0] + 1, before[1])
     jax.jit(lambda *a: delta_rule.gated_delta_rule_rows(
         *a, heads=1)).lower(*flat)
@@ -425,3 +506,6 @@ def test_dispatch_says_what_it_built(kernels_at_the_entry):
         assert gauge("delta_rule_heads_per_step", "").value == together
         assert gauge(
             "delta_rule_state_bytes", "").value == together * 128 * 128 * 4
+        # a pair's inverse is one [128, 128] matrix, half of it a head's
+        assert gauge("delta_rule_kept_bytes", "").value == 4 * (
+            128 * 128 + (64 * 64 if together == 1 else 128 * 64) + 64 * 128)
