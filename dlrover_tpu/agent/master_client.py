@@ -47,9 +47,8 @@ ENV_RECONNECT_TIMEOUT = "DLROVER_TPU_MASTER_RECONNECT_TIMEOUT"
 DEFAULT_RECONNECT_TIMEOUT = 600.0
 
 #: decorrelated-jitter backoff bounds for the reconnect probe loop
-ENV_BACKOFF_CAP = "DLROVER_TPU_MASTER_RECONNECT_BACKOFF_MAX"
 BACKOFF_BASE = 0.25
-DEFAULT_BACKOFF_CAP = 15.0
+BACKOFF_CAP = 15.0
 
 #: relay-tier failover (ISSUE 16): when the client's primary address is
 #: an aggregator relay and it stays unreachable this long, the
@@ -114,9 +113,7 @@ class ConnectionSupervisor:
                 or DEFAULT_RECONNECT_TIMEOUT
             )
         self.reconnect_timeout = reconnect_timeout
-        self._backoff_cap = float(
-            os.getenv(ENV_BACKOFF_CAP, "") or DEFAULT_BACKOFF_CAP
-        )
+        self._backoff_cap = BACKOFF_CAP
         # relay -> direct-master failover: when set, an outage longer
         # than failover_after re-points the channel at fallback_addr
         # (once); the normal probe/re-hello machinery then reconnects

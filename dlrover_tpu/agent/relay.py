@@ -5,7 +5,7 @@ At 10k agents even a sharded, event-loop master is doing 10k RPC
 round-trips per interval. The 100k-GPU HSDP result (PAPERS.md) shows
 the scaling move: put an aggregation tier between agents and master so
 master load grows with RELAY count, not world size. One relay fronts K
-agents (``DLROVER_TPU_RELAY_FANOUT``):
+agents (the launcher's ``--relay_fanout``):
 
 * **downstream** it terminates its agents' ``report_node_status``
   deltas with the exact master-side bookkeeping
@@ -39,7 +39,6 @@ supervisor, invisible to agents.
 """
 
 import argparse
-import os
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
@@ -55,22 +54,14 @@ from dlrover_tpu.telemetry.http import start_metrics_server
 
 #: agents per relay — launchers and the swarm bench size the tier as
 #: ceil(agents / fanout)
-ENV_RELAY_FANOUT = "DLROVER_TPU_RELAY_FANOUT"
-DEFAULT_RELAY_FANOUT = 256
+RELAY_FANOUT = 256
 
 #: upstream forward cadence (seconds)
-ENV_RELAY_INTERVAL = "DLROVER_TPU_RELAY_INTERVAL"
-DEFAULT_RELAY_INTERVAL = 1.0
+RELAY_INTERVAL_S = 1.0
 
 #: where agents find their relay (set by the launcher); empty = no
 #: relay tier, agents report direct (agent/elastic/training.py)
 ENV_RELAY_ADDR = "DLROVER_TPU_RELAY_ADDR"
-
-
-def relay_fanout() -> int:
-    return int(
-        os.environ.get(ENV_RELAY_FANOUT, "0")
-    ) or DEFAULT_RELAY_FANOUT
 
 
 class _AgentSlot:
@@ -112,16 +103,12 @@ class AggregatorRelay:
     """One relay process/instance fronting up to K agents."""
 
     def __init__(self, master_addr: str, relay_id: int = 0,
-                 port: int = 0, interval: Optional[float] = None,
+                 port: int = 0, interval: float = RELAY_INTERVAL_S,
                  ledger_cap: Optional[int] = None,
                  rpc_timeout: float = 30.0):
         from dlrover_tpu.agent.master_client import MasterClient
 
         self.relay_id = relay_id
-        if interval is None:
-            interval = float(
-                os.environ.get(ENV_RELAY_INTERVAL, "0")
-            ) or DEFAULT_RELAY_INTERVAL
         self._interval = max(0.05, interval)
         self._lock = threading.Lock()
         self._slots: Dict[Tuple[str, int], _AgentSlot] = {}
@@ -533,12 +520,12 @@ class RelayTier:
     """
 
     def __init__(self, master_addr: str, n_agents: int,
-                 fanout: Optional[int] = None,
+                 fanout: int = RELAY_FANOUT,
                  check_interval: float = 1.0,
                  spawn_timeout: float = 30.0):
         self._master_addr = master_addr
         self._n_agents = max(1, int(n_agents))
-        self._fanout = max(1, int(fanout) if fanout else relay_fanout())
+        self._fanout = max(1, int(fanout))
         #: tier size: every agent fronted, no relay over fanout
         self.n_relays = -(-self._n_agents // self._fanout)
         self._check_interval = max(0.05, float(check_interval))
@@ -675,7 +662,8 @@ def main():
     parser.add_argument("--master_addr", required=True)
     parser.add_argument("--relay_id", type=int, default=0)
     parser.add_argument("--port", type=int, default=0)
-    parser.add_argument("--interval", type=float, default=None)
+    parser.add_argument("--interval", type=float,
+                        default=RELAY_INTERVAL_S)
     ns = parser.parse_args()
     relay = AggregatorRelay(
         ns.master_addr, relay_id=ns.relay_id, port=ns.port,

@@ -34,6 +34,12 @@ from dlrover_tpu.telemetry import counter, fleet, gauge, record
 #: depending on it instead of spinning forever.
 DEFAULT_WAIT_DEADLINE_SECS = 3600.0
 
+#: shards per ``get_tasks`` round-trip when the caller names none
+FETCH_BATCH = 1
+#: fetched-but-unconsumed shards kept buffered by a background thread
+#: when the caller names none (0 = no thread)
+LOOKAHEAD = 0
+
 #: sentinel for "the master answered WAIT" inside _request_tasks
 _WAIT = object()
 
@@ -83,13 +89,9 @@ class ShardingClient:
         )
         # ---- batched dispatch + lookahead window ---------------------
         if fetch_batch is None:
-            fetch_batch = int(
-                os.getenv("DLROVER_TPU_SHARD_FETCH_BATCH", "1") or 1
-            )
+            fetch_batch = FETCH_BATCH
         if lookahead is None:
-            lookahead = int(
-                os.getenv("DLROVER_TPU_SHARD_LOOKAHEAD", "0") or 0
-            )
+            lookahead = LOOKAHEAD
         self._fetch_batch = max(1, fetch_batch)
         self._lookahead = max(0, lookahead)
         #: shards fetched from the master but not yet handed to the

@@ -20,7 +20,7 @@ payload every time. This module folds them into ONE
   of the process (``report.rpc_fallback``), so mixed fleets keep
   working.
 
-The report interval is jittered ±20% (``DLROVER_TPU_REPORT_JITTER``)
+The report interval is jittered ±20% (``JITTER``)
 so a master restart doesn't get the whole fleet's re-hellos back in
 phase — 10k synchronized reports is a self-inflicted thundering herd.
 """
@@ -37,7 +37,7 @@ from dlrover_tpu.telemetry import fleet
 from dlrover_tpu.telemetry.journal import current_job_id, record
 
 #: fractional interval jitter (0.2 = ±20%)
-DEFAULT_JITTER = 0.2
+JITTER = 0.2
 #: resend goodput/resource at least every N intervals even if
 #: "unchanged" — bounds how stale a delta'd section can get when the
 #: change detector's thresholds hide slow drift
@@ -210,7 +210,6 @@ class StatusReporter:
                  resource_fn: Optional[
                      Callable[[], Optional[Tuple[float, int]]]] = None,
                  step_fn: Optional[Callable[[], Optional[int]]] = None,
-                 jitter: Optional[float] = None,
                  pid: int = 0,
                  serve_fn: Optional[Callable[[], Optional[Dict]]] = None):
         import os
@@ -222,15 +221,6 @@ class StatusReporter:
         self._step_fn = step_fn
         self._serve_fn = serve_fn
         self._pid = pid or os.getpid()
-        if jitter is None:
-            try:
-                jitter = float(
-                    os.environ.get("DLROVER_TPU_REPORT_JITTER",
-                                   str(DEFAULT_JITTER))
-                )
-            except ValueError:
-                jitter = DEFAULT_JITTER
-        self._jitter = min(0.9, max(0.0, jitter))
         self._tracker = DeltaTracker(
             incarnation=incarnation, job_id=current_job_id()
         )
@@ -250,7 +240,7 @@ class StatusReporter:
         record(
             "agent.report_interval",
             interval_s=self._interval,
-            jitter_pct=int(self._jitter * 100),
+            jitter_pct=int(JITTER * 100),
         )
         self._thread = threading.Thread(
             target=self._run, name="status-reporter", daemon=True
@@ -263,7 +253,7 @@ class StatusReporter:
             self._thread.join(timeout=2.0)
 
     def _sleep_interval(self):
-        lo, hi = 1.0 - self._jitter, 1.0 + self._jitter
+        lo, hi = 1.0 - JITTER, 1.0 + JITTER
         self._stopped.wait(self._interval * random.uniform(lo, hi))
 
     def _run(self):

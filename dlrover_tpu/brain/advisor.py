@@ -10,12 +10,12 @@ journals every conclusion as an *evidence chain*, so a human reading
 ``dump --kind brain`` can replay exactly why a plan was (or was not)
 proposed:
 
-  ``shrink_badput``      a job burning more than
-                         ``DLROVER_TPU_BRAIN_BADPUT_PCT`` percent of
-                         its wall clock in ckpt_stall + rendezvous is
+  ``shrink_badput``      a job burning more than ``BADPUT_PCT``
+                         percent of its wall clock in ckpt_stall +
+                         rendezvous is
                          over-provisioned for its I/O — fewer hosts
                          stall less; propose shrink by one node unit.
-  ``grow_scaling``       a job at/above ``DLROVER_TPU_BRAIN_GROW_PCT``
+  ``grow_scaling``       a job at/above ``GROW_PCT`` percent
                          goodput, straggler-free, whose per-worker
                          step rate has not degraded as workers joined
                          (the step-time curve still scales) earns one
@@ -37,9 +37,9 @@ entirely.
 
 The advisor owns no thread: the master's run loop calls
 ``maybe_step()`` each beat and the advisor rate-limits itself to
-``DLROVER_TPU_BRAIN_INTERVAL`` seconds, with a per-(job, action)
-cooldown (``DLROVER_TPU_BRAIN_COOLDOWN``) so a persistent condition
-journals one proposal, not one per beat.
+``INTERVAL_S`` seconds, with a per-(job, action) cooldown
+(``COOLDOWN_S``) so a persistent condition journals one proposal,
+not one per beat.
 """
 
 import os
@@ -51,10 +51,15 @@ from dlrover_tpu.telemetry import record
 from dlrover_tpu.telemetry.goodput import Phase
 
 ENV_BRAIN = "DLROVER_TPU_BRAIN"
-ENV_BRAIN_INTERVAL = "DLROVER_TPU_BRAIN_INTERVAL"
-ENV_BRAIN_BADPUT_PCT = "DLROVER_TPU_BRAIN_BADPUT_PCT"
-ENV_BRAIN_GROW_PCT = "DLROVER_TPU_BRAIN_GROW_PCT"
-ENV_BRAIN_COOLDOWN = "DLROVER_TPU_BRAIN_COOLDOWN"
+
+#: seconds between evaluation passes
+INTERVAL_S = 30.0
+#: stall % of wall clock that triggers ``shrink_badput``
+BADPUT_PCT = 25.0
+#: goodput % floor for ``grow_scaling``
+GROW_PCT = 90.0
+#: seconds between proposals per (job, action)
+COOLDOWN_S = 120.0
 
 MODE_OFF = "off"
 MODE_OBSERVE = "observe"
@@ -105,15 +110,7 @@ class ResourceAdvisor:
         self._node_unit = max(1, int(node_unit or 1))
         self.mode = mode if mode is not None else advisor_mode()
         self.interval = (
-            float(interval) if interval is not None
-            else float(os.getenv(ENV_BRAIN_INTERVAL, "30"))
-        )
-        self._badput_pct = float(
-            os.getenv(ENV_BRAIN_BADPUT_PCT, "25")
-        )
-        self._grow_pct = float(os.getenv(ENV_BRAIN_GROW_PCT, "90"))
-        self._cooldown = float(
-            os.getenv(ENV_BRAIN_COOLDOWN, "120")
+            float(interval) if interval is not None else INTERVAL_S
         )
         self._now = now_fn
         self._last_step = 0.0
@@ -133,7 +130,7 @@ class ResourceAdvisor:
         record(
             "brain.advisor_started",
             mode=self.mode, interval_s=self.interval,
-            badput_pct=self._badput_pct, grow_pct=self._grow_pct,
+            badput_pct=BADPUT_PCT, grow_pct=GROW_PCT,
             node_unit=self._node_unit, job=self._local_job,
         )
 
@@ -215,7 +212,7 @@ class ResourceAdvisor:
         ckpt_stall = float(badput.get(Phase.CKPT_STALL, 0.0))
         rendezvous = float(badput.get(Phase.RENDEZVOUS, 0.0))
         stall_pct = 100.0 * (ckpt_stall + rendezvous) / wall
-        if stall_pct <= self._badput_pct:
+        if stall_pct <= BADPUT_PCT:
             return None
         workers = self._workers_of(job, monitors, summary)
         return {
@@ -232,7 +229,7 @@ class ResourceAdvisor:
                 "ckpt_stall_s": round(ckpt_stall, 3),
                 "rendezvous_s": round(rendezvous, 3),
                 "stall_pct": round(stall_pct, 2),
-                "threshold_pct": self._badput_pct,
+                "threshold_pct": BADPUT_PCT,
                 "goodput_percent": summary.get("goodput_percent"),
                 "workers": workers,
             },
@@ -240,7 +237,7 @@ class ResourceAdvisor:
 
     def _rule_grow_scaling(self, job: str, summary: Dict, now: float):
         goodput_pct = float(summary.get("goodput_percent") or 0.0)
-        if not summary.get("procs") or goodput_pct < self._grow_pct:
+        if not summary.get("procs") or goodput_pct < GROW_PCT:
             return None
         # the fleet's straggler view lists every host (the lead reads
         # behind=0) — only hosts actually trailing the lead park a grow
@@ -279,7 +276,7 @@ class ResourceAdvisor:
                     hist[-1][0] - hist[0][0], 3
                 ),
                 "goodput_percent": goodput_pct,
-                "threshold_pct": self._grow_pct,
+                "threshold_pct": GROW_PCT,
                 "per_worker_rate": round(last_rate, 6),
                 "best_per_worker_rate": round(best_rate, 6),
                 "scaling_retention": round(retention, 4),
@@ -349,7 +346,7 @@ class ResourceAdvisor:
     def _propose(self, plan: Dict[str, Any], now: float) -> None:
         key = (plan["job"], plan["action"])
         last = self._last_proposed.get(key, 0.0)
-        if now - last < self._cooldown:
+        if now - last < COOLDOWN_S:
             return
         self._last_proposed[key] = now
         self._history.append(plan)

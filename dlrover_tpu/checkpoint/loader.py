@@ -65,8 +65,8 @@ def _nbytes(raw) -> int:
 
 
 def _decode_member(raw: bytes, enc: Optional[Dict[str, Any]]) -> np.ndarray:
-    """Member bytes -> array (same decode the v1 reader applies:
-    extension dtypes travel as uint8 + a recorded dtype/shape)."""
+    """Member bytes -> array (same decode the whole-archive reader
+    applies: extension dtypes travel as uint8 + a recorded dtype/shape)."""
     try:
         arr = np.lib.format.read_array(
             io.BytesIO(raw), allow_pickle=False
@@ -96,13 +96,11 @@ class StepCatalog:
 
     def __init__(self, step: int, leaves: List[Dict[str, Any]],
                  topology: Optional[Dict[str, Any]] = None,
-                 last_good: Optional[bool] = None,
-                 version: int = 2):
+                 last_good: Optional[bool] = None):
         self.step = int(step)
         self.leaves = leaves
         self.topology = topology
         self.last_good = last_good
-        self.version = version
         self.digests: Dict[str, str] = {}
         self.encodings: Dict[str, Dict[str, Any]] = {}
         self.locations: Dict[str, Tuple[int, str]] = {}
@@ -119,7 +117,6 @@ class StepCatalog:
             man.get("step", 0), leaves,
             topology=man.get("topology"),
             last_good=man.get("last_good"),
-            version=int(man.get("version", 2)),
         )
         cat.absorb(man)
         return cat

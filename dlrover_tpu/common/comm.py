@@ -840,7 +840,7 @@ class ServeStats(BaseMessage):
     # ISSUE 20: the sharded router plane
     shards: int = 1
     tenants: int = 0
-    #: delivered done-store entries GC'd after DLROVER_TPU_SERVE_DONE_TTL
+    #: delivered done-store entries GC'd after serving/router.py DONE_TTL_S
     done_evicted: int = 0
     #: replica-reported serve sections alive on the delta-report plane
     replicas_reporting: int = 0

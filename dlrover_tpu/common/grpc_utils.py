@@ -11,7 +11,6 @@ raises :class:`~dlrover_tpu.common.comm.WireError` instead of executing.
 
 import asyncio
 import json
-import os
 import socket
 import threading
 from concurrent import futures
@@ -104,37 +103,22 @@ def addr_connected(addr: str, timeout: float = 3.0) -> bool:
         return False
 
 
-#: default dispatch pool size; DLROVER_TPU_GRPC_MAX_WORKERS overrides
-#: for fleet-scale masters (the servicer's bounded admission keeps the
-#: batched report path from monopolizing whatever size is chosen).
-#: The value is CLAMPED to [MIN, MAX]: a zero/negative pool deadlocks
-#: every RPC and a four-digit one is 8 MB of stack per thread on a
-#: GIL'd core — both are misconfigurations, not choices.
-DEFAULT_MAX_WORKERS = 64
-MIN_MAX_WORKERS = 4
-MAX_MAX_WORKERS = 512
-
-
-def _resolve_max_workers(max_workers: Optional[int]) -> int:
-    if max_workers is None:
-        max_workers = int(
-            os.environ.get("DLROVER_TPU_GRPC_MAX_WORKERS", "0")
-        ) or DEFAULT_MAX_WORKERS
-    return min(MAX_MAX_WORKERS, max(MIN_MAX_WORKERS, max_workers))
+#: dispatch pool size (the servicer's bounded admission keeps the
+#: batched report path from monopolizing it)
+MAX_WORKERS = 64
 
 
 class GenericRpcServer:
     """gRPC server exposing one generic dispatch method."""
 
-    def __init__(self, handler: Callable[[str, object], object], port: int = 0,
-                 max_workers: Optional[int] = None):
-        max_workers = _resolve_max_workers(max_workers)
+    def __init__(self, handler: Callable[[str, object], object],
+                 port: int = 0):
         self._handler = handler
         # named threads: flight-recorder stack dumps must attribute
         # RPC work (a bare "ThreadPoolExecutor-0_3" frame is noise)
         self._server = grpc.server(
             futures.ThreadPoolExecutor(
-                max_workers=max_workers,
+                max_workers=MAX_WORKERS,
                 thread_name_prefix="grpc-worker",
             ),
             options=_GRPC_OPTIONS,
@@ -201,14 +185,13 @@ class AsyncRpcServer:
 
     def __init__(self, handler: Callable[[str, object], object],
                  port: int = 0,
-                 max_workers: Optional[int] = None,
                  hot_handlers: Optional[
                      Dict[str, Callable[[object], Awaitable[object]]]
                  ] = None):
         self._handler = handler
         self._hot = dict(hot_handlers or {})
         self._pool = futures.ThreadPoolExecutor(
-            max_workers=_resolve_max_workers(max_workers),
+            max_workers=MAX_WORKERS,
             thread_name_prefix="grpc-worker",
         )
         self._requested_port = port

@@ -80,8 +80,8 @@ class ElasticShmDataLoader:
         microbatch split) run on the prefetch thread, off the train
         loop.
       fetch_batch/lookahead (optional): per-producer shard dispatch
-        batching and lookahead window (see ShardingClient; None reads
-        DLROVER_TPU_SHARD_FETCH_BATCH / DLROVER_TPU_SHARD_LOOKAHEAD).
+        batching and lookahead window (see ShardingClient; None takes
+        its FETCH_BATCH / LOOKAHEAD).
     """
 
     def __init__(

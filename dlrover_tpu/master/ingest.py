@@ -24,7 +24,7 @@ sharded by node id into N independent :class:`IngestShard`\\ s:
 
 The ledger is also the master's per-reporter MEMORY — and before this
 PR it grew forever (satellite bugfix). Now it is bounded by
-``DLROVER_TPU_REPORT_LEDGER_CAP`` with the SpeedMonitor stale-first
+``LEDGER_CAP`` entries with the SpeedMonitor stale-first
 pattern: a ``final=True`` report (process exit) evicts its entry
 immediately, and at the cap the stalest incumbent is evicted to admit
 a newcomer. An evicted-but-alive reporter is not harmed: its next
@@ -47,13 +47,11 @@ from dlrover_tpu.telemetry import counter, record
 
 #: ingest shard count; each shard is an independent ledger slice +
 #: admission slice + (event-loop mode) single-thread apply executor
-ENV_INGEST_SHARDS = "DLROVER_TPU_INGEST_SHARDS"
-DEFAULT_INGEST_SHARDS = 4
+INGEST_SHARDS = 4
 
 #: per-reporter ledger entries the master retains across all shards;
 #: at the cap the stalest entry is evicted (resync heals a live one)
-ENV_LEDGER_CAP = "DLROVER_TPU_REPORT_LEDGER_CAP"
-DEFAULT_LEDGER_CAP = 16384
+LEDGER_CAP = 16384
 
 
 def _shed_counter():
@@ -83,7 +81,7 @@ class ReporterLedger:
     reports evict immediately. Thread-safe; shared by the master's
     ingest shards and the relay's downstream termination."""
 
-    def __init__(self, cap: int = DEFAULT_LEDGER_CAP):
+    def __init__(self, cap: int = LEDGER_CAP):
         self._lock = threading.Lock()
         self._entries: Dict[Tuple[str, int], Tuple[int, int, float]] = {}
         self._cap = max(2, cap)
@@ -220,14 +218,10 @@ class IngestPlane:
     managers); the plane owns everything per-reporter around it:
     admission, the acked-seq ledger, resync, and eviction."""
 
-    def __init__(self, shards: Optional[int] = None,
+    def __init__(self, shards: int = INGEST_SHARDS,
                  inflight_limit: Optional[int] = None,
                  retry_after: Optional[float] = None,
-                 ledger_cap: Optional[int] = None):
-        if shards is None:
-            shards = int(
-                os.environ.get(ENV_INGEST_SHARDS, "0")
-            ) or DEFAULT_INGEST_SHARDS
+                 ledger_cap: int = LEDGER_CAP):
         shards = max(1, shards)
         if inflight_limit is None:
             inflight_limit = int(
@@ -237,10 +231,6 @@ class IngestPlane:
             retry_after = float(
                 os.environ.get("DLROVER_TPU_REPORT_RETRY_AFTER", "0.5")
             )
-        if ledger_cap is None:
-            ledger_cap = int(
-                os.environ.get(ENV_LEDGER_CAP, "0")
-            ) or DEFAULT_LEDGER_CAP
         self.retry_after = retry_after
         self._inflight_limit = max(1, inflight_limit)
         # the admission budget splits across shards (no cross-shard

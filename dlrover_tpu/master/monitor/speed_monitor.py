@@ -19,7 +19,6 @@ thermal throttle), which is exactly the case the network-check probe
 cannot see once training started.
 """
 
-import os
 import time
 from collections import deque
 from dataclasses import dataclass
@@ -31,6 +30,19 @@ from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.telemetry import counter, gauge, histogram, record
 
 _context = Context.singleton_instance()
+
+#: a host is flagged when its rolling-median step duration runs
+#: > STRAGGLER_RATIO x the fleet median for STRAGGLER_WINDOW
+#: consecutive evaluations (persistence beats one slow sample)
+STRAGGLER_RATIO = 1.5
+STRAGGLER_WINDOW = 3
+#: min seconds between straggler re-scores once the fleet outgrows
+#: small sizes
+STRAGGLER_SCORE_INTERVAL_S = 0.5
+#: max per-host speed/straggler structures, and the staleness horizon
+#: past which an incumbent is evicted at the cap
+SPEED_HOST_CAP = 256
+SPEED_HOST_STALE_S = 60.0
 
 #: per-host step durations: millisecond steps up to multi-minute ones
 _STEP_BUCKETS = (
@@ -49,8 +61,8 @@ class GlobalStepRecord:
 class SpeedMonitor:
     """Sliding window of global-step records -> running speed (steps/s)."""
 
-    def __init__(self, straggler_ratio: Optional[float] = None,
-                 straggler_window: Optional[int] = None):
+    def __init__(self, straggler_ratio: float = STRAGGLER_RATIO,
+                 straggler_window: int = STRAGGLER_WINDOW):
         self._global_step_records: List[GlobalStepRecord] = []
         self._workers: Set[Tuple[str, int]] = set()
         self._max_record_count = _context.train_speed_record_num
@@ -63,17 +75,6 @@ class SpeedMonitor:
         self._has_step_reports = False
         self._batches_done = 0
         # ---- per-host straggler scoring state (ISSUE 4) ----
-        # a host is flagged when its rolling-median step duration runs
-        # > straggler_ratio x the fleet median for straggler_window
-        # consecutive evaluations (persistence beats one slow sample)
-        if straggler_ratio is None:
-            straggler_ratio = float(
-                os.getenv("DLROVER_TPU_STRAGGLER_RATIO", "1.5")
-            )
-        if straggler_window is None:
-            straggler_window = int(
-                os.getenv("DLROVER_TPU_STRAGGLER_WINDOW", "3")
-            )
         self._straggler_ratio = max(1.01, straggler_ratio)
         self._straggler_window = max(1, straggler_window)
         self._host_last: Dict[int, Tuple[int, float]] = {}
@@ -87,17 +88,8 @@ class SpeedMonitor:
         # O(hosts) scoring pass. Cap the tracked set (evict the
         # stalest reporter), cap the metric label space (first-come),
         # and rate-limit scoring once the fleet outgrows small sizes.
-        self._host_cap = max(2, int(
-            os.getenv("DLROVER_TPU_SPEED_HOST_CAP", "256")
-        ))
         self._labeled_nodes: Set[int] = set()
-        self._score_interval = float(
-            os.getenv("DLROVER_TPU_STRAGGLER_SCORE_INTERVAL", "0.5")
-        )
         self._last_score = 0.0
-        self._host_stale_s = float(
-            os.getenv("DLROVER_TPU_SPEED_HOST_STALE_S", "60")
-        )
         self._last_evict_scan = 0.0
         # master state journal hook: listener(step, batch_feed) fires
         # when the max step advances, throttled to one write per
@@ -234,7 +226,7 @@ class SpeedMonitor:
         re-score. Durations are per-host deltas between the host's OWN
         consecutive reports — cross-host clock skew cancels out."""
         last = self._host_last.get(node_id)
-        if last is None and len(self._host_last) >= self._host_cap:
+        if last is None and len(self._host_last) >= SPEED_HOST_CAP:
             # tracked set full: admit the newcomer only by evicting a
             # STALE incumbent (stopped reporting), found by a scan
             # rate-limited to 1/s — at 10k nodes an O(cap) scan per
@@ -251,7 +243,7 @@ class SpeedMonitor:
                     self._host_last, key=lambda n: self._host_last[n][1]
                 )
                 if timestamp - self._host_last[stalest][1] \
-                        > self._host_stale_s:
+                        > SPEED_HOST_STALE_S:
                     self._evict_host(stalest)
                     counter(
                         "dlrover_speed_monitor_hosts_evicted_total",
@@ -276,7 +268,7 @@ class SpeedMonitor:
         # churn across evictions would otherwise grow the registry's
         # series count with every node the job ever saw
         if (node_id in self._labeled_nodes
-                or len(self._labeled_nodes) < self._host_cap):
+                or len(self._labeled_nodes) < SPEED_HOST_CAP):
             self._labeled_nodes.add(node_id)
             histogram(
                 "dlrover_host_step_duration_seconds",
@@ -291,7 +283,7 @@ class SpeedMonitor:
         # tax at 10k — rate-limit once the fleet outgrows small sizes
         if len(self._host_durations) > 32:
             now = time.monotonic()
-            if now - self._last_score < self._score_interval:
+            if now - self._last_score < STRAGGLER_SCORE_INTERVAL_S:
                 return
             self._last_score = now
         self._score_stragglers()

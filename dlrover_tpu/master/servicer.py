@@ -20,7 +20,7 @@ from dlrover_tpu.common.constants import (
     TaskType,
     TrainingExceptionLevel,
 )
-from dlrover_tpu.common.grpc_utils import AsyncRpcServer, GenericRpcServer
+from dlrover_tpu.common.grpc_utils import AsyncRpcServer
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.master.elastic_training.kv_store_service import (
     KVStoreService,
@@ -28,11 +28,6 @@ from dlrover_tpu.master.elastic_training.kv_store_service import (
 from dlrover_tpu.master.ingest import IngestPlane
 from dlrover_tpu.master.shard.dataset_splitter import new_dataset_splitter
 from dlrover_tpu.telemetry import counter, histogram, record, tracing
-
-#: event-loop front end for the report lane (AsyncRpcServer); "0"
-#: falls back to the all-threaded GenericRpcServer — same wire, same
-#: semantics, one knob to bisect a regression
-ENV_ASYNC_INGEST = "DLROVER_TPU_ASYNC_INGEST"
 
 #: sub-millisecond KV polls up to multi-second shard waits
 _RPC_BUCKETS = (
@@ -1131,15 +1126,11 @@ def create_master_service(
         transition_coordinator=transition_coordinator,
         fleet_aggregator=fleet_aggregator,
     )
-    use_async = os.environ.get(ENV_ASYNC_INGEST, "1") != "0"
-    if use_async:
-        server = AsyncRpcServer(
-            servicer.handle, port=port,
-            hot_handlers={
-                "report_node_status": servicer.ingest_report_async,
-                "report_relay_batch": servicer.ingest_relay_batch_async,
-            },
-        )
-    else:
-        server = GenericRpcServer(servicer.handle, port=port)
+    server = AsyncRpcServer(
+        servicer.handle, port=port,
+        hot_handlers={
+            "report_node_status": servicer.ingest_report_async,
+            "report_relay_batch": servicer.ingest_relay_batch_async,
+        },
+    )
     return server, servicer

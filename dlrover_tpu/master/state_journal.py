@@ -30,7 +30,7 @@ disk, every step/goodput advance is another fsync. The journal now
 carries a write-behind commit lane (same shape as the shard dispatcher's
 group commit in ``shard/task_manager.py``): mutations are staged
 per-key (last writer wins) and flushed within
-``DLROVER_TPU_JOURNAL_FLUSH_WINDOW`` seconds as ONE FileStore
+``FLUSH_WINDOW_S`` seconds as ONE FileStore
 transaction (redo-log ``set_many``), so journal commits/sec is bounded
 by the window, not the report rate. Paths whose exactly-once argument
 requires commit-before-reply — the shard ledger — keep write-through
@@ -50,10 +50,9 @@ from dlrover_tpu.telemetry.registry import counter
 from dlrover_tpu.util.state_store import StateBackend, build_state_store
 
 ENV_STATE_DIR = "DLROVER_TPU_MASTER_STATE_DIR"
-#: write-behind coalescing window (seconds) for non-ledger state; 0
-#: disables the lane (pre-ISSUE-12 write-through behavior)
-ENV_FLUSH_WINDOW = "DLROVER_TPU_JOURNAL_FLUSH_WINDOW"
-DEFAULT_FLUSH_WINDOW_S = 0.05
+#: write-behind coalescing window (seconds) for non-ledger state; a
+#: ``commit_window`` of 0 disables the lane (write-through)
+FLUSH_WINDOW_S = 0.05
 
 
 def _safe_name(name: str) -> str:
@@ -319,27 +318,17 @@ class MasterStateJournal:
         return self._get(self._key("goodput"))
 
 
-def _flush_window() -> float:
-    raw = os.getenv(ENV_FLUSH_WINDOW, "")
-    if not raw:
-        return DEFAULT_FLUSH_WINDOW_S
-    try:
-        return max(0.0, float(raw))
-    except ValueError:
-        return DEFAULT_FLUSH_WINDOW_S
-
-
 def build_master_state_journal(
     job_name: str,
     state_dir: Optional[str] = None,
     fresh: bool = False,
-    commit_window: Optional[float] = None,
+    commit_window: float = FLUSH_WINDOW_S,
 ) -> Optional[MasterStateJournal]:
     """Build the journal when a state dir is configured; None otherwise.
 
     ``fresh=True`` wipes the job's prior state (deliberate restart from
-    scratch against a dirty state dir). ``commit_window`` overrides the
-    env-configured group-commit window (0 = write-through)."""
+    scratch against a dirty state dir). ``commit_window`` is the
+    group-commit window (0 = write-through)."""
     state_dir = state_dir or os.getenv(ENV_STATE_DIR, "")
     if not state_dir:
         return None
@@ -350,8 +339,9 @@ def build_master_state_journal(
         # state by the FileStore redo log — surface it for the drills
         record("control.journal_recovered", keys=len(recovered))
         store.recovered_txn_keys = []  # the singleton outlives us
-    window = _flush_window() if commit_window is None else commit_window
-    journal = MasterStateJournal(store, job_name, commit_window=window)
+    journal = MasterStateJournal(
+        store, job_name, commit_window=commit_window
+    )
     if fresh and journal.has_state():
         logger.info(
             "--fresh: discarding prior master state for job %r under %s",

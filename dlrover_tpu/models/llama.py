@@ -573,13 +573,6 @@ def llama2_7b(**kw) -> LlamaConfig:
     return LlamaConfig(**kw)
 
 
-def llama2_13b(**kw) -> LlamaConfig:
-    return LlamaConfig(
-        hidden_size=5120, intermediate_size=13824, num_layers=40,
-        num_heads=40, num_kv_heads=40, **kw,
-    )
-
-
 def llama_1b(**kw) -> LlamaConfig:
     """A ~1.1B config (TinyLlama shape) for single-chip benchmarking."""
     return LlamaConfig(

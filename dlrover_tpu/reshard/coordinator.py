@@ -14,10 +14,10 @@ Contract highlights (docs/ELASTICITY.md has the full state machine):
 
 * **one transition at a time** — a second failure while an order is
   open aborts the open order; overlapping remaps are undecidable.
-* **budget** — at most ``DLROVER_TPU_MAX_RESHARDS`` online
+* **budget** — at most ``MAX_RESHARDS`` online
   transitions per job; past it, failures take the restart path.
 * **abort watchdog** — survivors that do not complete within
-  ``DLROVER_TPU_RESHARD_ABORT_TIMEOUT`` seconds trigger an abort
+  ``ABORT_TIMEOUT_S`` seconds trigger an abort
   broadcast (``kind=abort``) and the fallback callback re-enables
   relaunch for the lost ranks.
 * **exactly-once ledger** — the lost rank's in-flight dataset tasks
@@ -41,6 +41,12 @@ from dlrover_tpu.reshard.order import (
     TransitionOrder,
 )
 from dlrover_tpu.telemetry import gauge, record, tracing
+
+#: online transitions attempted per job before degrading to
+#: restart-the-world
+MAX_RESHARDS = 8
+#: seconds an order may stay open before the coordinator aborts it
+ABORT_TIMEOUT_S = 120.0
 
 
 def reshard_enabled() -> bool:
@@ -69,22 +75,16 @@ class TransitionCoordinator:
         kv_store,
         task_manager=None,
         goodput=None,
-        max_transitions: Optional[int] = None,
-        abort_timeout: Optional[float] = None,
+        max_transitions: int = MAX_RESHARDS,
+        abort_timeout: float = ABORT_TIMEOUT_S,
         min_world: int = 1,
         fallback_fn: Optional[Callable[[TransitionOrder], None]] = None,
     ):
         self._kv = kv_store
         self._task_manager = task_manager
         self._goodput = goodput
-        self._max = int(
-            os.environ.get("DLROVER_TPU_MAX_RESHARDS", "8")
-            if max_transitions is None else max_transitions
-        )
-        self._abort_timeout = float(
-            os.environ.get("DLROVER_TPU_RESHARD_ABORT_TIMEOUT", "120")
-            if abort_timeout is None else abort_timeout
-        )
+        self._max = int(max_transitions)
+        self._abort_timeout = float(abort_timeout)
         self._min_world = max(1, int(min_world))
         self._fallback_fn = fallback_fn
         self._lock = threading.RLock()

@@ -18,27 +18,22 @@ depth and measured latency driving the training stack's scale plans —
 not a clever controller.
 """
 
-import os
 import threading
 from typing import Callable, Dict, Optional
 
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.telemetry import counter, record
 
-ENV_QUEUE_HIGH = "DLROVER_TPU_SERVE_QUEUE_HIGH"
-DEFAULT_QUEUE_HIGH = 16
-
-ENV_P99_HIGH_MS = "DLROVER_TPU_SERVE_P99_HIGH_MS"
-DEFAULT_P99_HIGH_MS = 2000.0
-
-ENV_COOLDOWN = "DLROVER_TPU_SERVE_SCALE_COOLDOWN"
-DEFAULT_COOLDOWN = 5.0
-
+#: queue depth that triggers +1 replica
+QUEUE_HIGH = 16
+#: p99 latency (ms) that triggers +1 replica (unless model-time-bound)
+P99_HIGH_MS = 2000.0
+#: seconds between scale decisions
+COOLDOWN_S = 5.0
 #: goodput-ledger serving-phase share below which the pool counts as
 #: idle for scale-down (the p99 window is sticky: a burst an hour ago
 #: must not pin an idle pool at max size)
-ENV_IDLE_SHARE = "DLROVER_TPU_SERVE_IDLE_SHARE"
-DEFAULT_IDLE_SHARE = 0.1
+IDLE_SHARE = 0.1
 
 
 class ServingAutoScaler:
@@ -58,10 +53,10 @@ class ServingAutoScaler:
         replicas_fn: Optional[Callable[[], int]] = None,
         min_replicas: int = 1,
         max_replicas: int = 4,
-        queue_high: Optional[int] = None,
-        p99_high_ms: Optional[float] = None,
+        queue_high: int = QUEUE_HIGH,
+        p99_high_ms: float = P99_HIGH_MS,
         interval: float = 1.0,
-        cooldown: Optional[float] = None,
+        cooldown: float = COOLDOWN_S,
         goodput_fn: Optional[Callable[[], Optional[float]]] = None,
     ):
         self._stats_fn = stats_fn
@@ -71,24 +66,12 @@ class ServingAutoScaler:
         #: how much of the pool's wall time was spent answering. None
         #: (no ledger wired) keeps the pre-SLO behavior exactly.
         self._goodput_fn = goodput_fn
-        self._idle_share = float(
-            os.getenv(ENV_IDLE_SHARE, "") or DEFAULT_IDLE_SHARE
-        )
         self._min = max(0, min_replicas)
         self._max = max(self._min, max_replicas)
-        self._queue_high = int(
-            queue_high if queue_high is not None
-            else os.getenv(ENV_QUEUE_HIGH, "") or DEFAULT_QUEUE_HIGH
-        )
-        self._p99_high_ms = float(
-            p99_high_ms if p99_high_ms is not None
-            else os.getenv(ENV_P99_HIGH_MS, "") or DEFAULT_P99_HIGH_MS
-        )
+        self._queue_high = int(queue_high)
+        self._p99_high_ms = float(p99_high_ms)
         self._interval = max(0.1, interval)
-        self._cooldown = float(
-            cooldown if cooldown is not None
-            else os.getenv(ENV_COOLDOWN, "") or DEFAULT_COOLDOWN
-        )
+        self._cooldown = float(cooldown)
         self._last_scale: Optional[float] = None
         self._thread: Optional[threading.Thread] = None
         self._stop = threading.Event()
@@ -162,7 +145,7 @@ class ServingAutoScaler:
         pool_idle = (
             queue_depth == 0 and not stats.get("in_flight")
             and serving_share is not None
-            and serving_share < self._idle_share
+            and serving_share < IDLE_SHARE
         )
         if pool_idle and current > self._min:
             target, reason = current - 1, "idle"
@@ -189,7 +172,7 @@ class ServingAutoScaler:
               and not stats.get("in_flight")
               and (p99_ms < self._p99_high_ms / 4
                    or (serving_share is not None
-                       and serving_share < self._idle_share))):
+                       and serving_share < IDLE_SHARE))):
             # the latency window is sticky — a burst long past must not
             # pin an idle pool at max size, so a near-zero serving
             # share from the goodput ledger also opens the down path

@@ -25,14 +25,14 @@ This module shards the plane (ISSUE 20):
   you can reach NOW").
 * **per-tenant fair queuing** — each shard's admission queue is a set
   of per-(priority, tenant) deques drained by deficit round-robin
-  (``DLROVER_TPU_SERVE_DRR_QUANTUM`` requests per tenant per visit).
+  (``DRR_QUANTUM`` requests per tenant per visit).
   Priority classes are strict (a higher class drains first); tenants
   within a class share by DRR, so one chatty tenant cannot starve the
   rest — a newly-arrived tenant is served within one drain cycle.
   ``tenant=`` / ``priority=`` ride ``serve_submit``; the default tenant
   keeps the old global-FIFO behavior exactly.
 * **done-store GC** — delivered responses older than
-  ``DLROVER_TPU_SERVE_DONE_TTL`` are evicted by the watchdog
+  ``DONE_TTL_S`` seconds are evicted by the watchdog
   (``dlrover_serve_done_evicted_total``); undelivered responses are
   kept forever (a poller may still come). Duplicate rejection holds for
   any retry inside the TTL; ``finished()`` is O(1) per shard via
@@ -73,8 +73,7 @@ DEFAULT_LEASE_TIMEOUT = 5.0
 
 #: bounded admission: submits past this TOTAL depth (split across
 #: shards) are rejected
-ENV_MAX_QUEUE = "DLROVER_TPU_SERVE_MAX_QUEUE"
-DEFAULT_MAX_QUEUE = 1024
+MAX_QUEUE = 1024
 
 #: router shard count: independent locks/queues/done-partitions
 ENV_ROUTER_SHARDS = "DLROVER_TPU_SERVE_ROUTER_SHARDS"
@@ -82,12 +81,10 @@ DEFAULT_ROUTER_SHARDS = 1
 
 #: delivered done-store entries older than this are GC'd (seconds);
 #: undelivered entries are kept until polled
-ENV_DONE_TTL = "DLROVER_TPU_SERVE_DONE_TTL"
-DEFAULT_DONE_TTL = 300.0
+DONE_TTL_S = 300.0
 
 #: deficit-round-robin quantum: requests granted per tenant per visit
-ENV_DRR_QUANTUM = "DLROVER_TPU_SERVE_DRR_QUANTUM"
-DEFAULT_DRR_QUANTUM = 4
+DRR_QUANTUM = 4
 
 #: sub-ms cache hits up to multi-second cold batches
 _LATENCY_BUCKETS = (
@@ -161,7 +158,7 @@ class RouterShard:
     guarantees a request id always routes to the same shard."""
 
     def __init__(self, index: int, max_queue: int,
-                 drr_quantum: int = DEFAULT_DRR_QUANTUM):
+                 drr_quantum: int = DRR_QUANTUM):
         self.index = index
         self._max_queue = max(1, max_queue)
         self._quantum = max(1, drr_quantum)
@@ -494,15 +491,11 @@ class RequestRouter:
     complete / poll / seal / relinquish / stats / finished — while the
     state lives in N independent shards."""
 
-    def __init__(self, max_queue: Optional[int] = None,
+    def __init__(self, max_queue: int = MAX_QUEUE,
                  lease_timeout: Optional[float] = None,
                  shards: Optional[int] = None,
-                 done_ttl: Optional[float] = None,
-                 drr_quantum: Optional[int] = None):
-        if max_queue is None:
-            max_queue = int(
-                os.getenv(ENV_MAX_QUEUE, "") or DEFAULT_MAX_QUEUE
-            )
+                 done_ttl: float = DONE_TTL_S,
+                 drr_quantum: int = DRR_QUANTUM):
         if lease_timeout is None:
             lease_timeout = float(
                 os.getenv(ENV_LEASE_TIMEOUT, "") or DEFAULT_LEASE_TIMEOUT
@@ -511,14 +504,6 @@ class RequestRouter:
             shards = int(
                 os.getenv(ENV_ROUTER_SHARDS, "")
                 or DEFAULT_ROUTER_SHARDS
-            )
-        if done_ttl is None:
-            done_ttl = float(
-                os.getenv(ENV_DONE_TTL, "") or DEFAULT_DONE_TTL
-            )
-        if drr_quantum is None:
-            drr_quantum = int(
-                os.getenv(ENV_DRR_QUANTUM, "") or DEFAULT_DRR_QUANTUM
             )
         self._max_queue = max(1, max_queue)
         self._lease_timeout = max(0.1, lease_timeout)

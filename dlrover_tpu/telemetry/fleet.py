@@ -23,7 +23,7 @@ plane the reports already use:
   (``RelayBatchReport.digest``).
 * **TimeSeriesStore** — bounded downsampling ring store in the master:
   raw per-ingest-interval points fold into 10 s buckets fold into 1 m
-  buckets, all three tiers capped (``DLROVER_TPU_FLEET_MEM_MB``), so a
+  buckets, all three tiers capped (``FLEET_MEM_MB``), so a
   week-long job cannot grow master memory.
 * **FleetAggregator** — hangs off the ingest plane: folds every relay
   digest (or direct per-agent digest) into the store, keeps per-host
@@ -59,7 +59,6 @@ __all__ = [
     "default_collector",
     "set_default_collector",
     "ENV_FLEET_DIGEST",
-    "ENV_FLEET_MEM_MB",
     "ENV_FLEET_TOPK",
     "ENV_SLO",
 ]
@@ -69,8 +68,7 @@ __all__ = [
 ENV_FLEET_DIGEST = "DLROVER_TPU_FLEET_DIGEST"
 
 #: hard cap (MiB) on the master's time-series store across all tiers
-ENV_FLEET_MEM_MB = "DLROVER_TPU_FLEET_MEM_MB"
-DEFAULT_FLEET_MEM_MB = 16
+FLEET_MEM_MB = 16
 
 #: declarative SLOs, ";"-separated ``name<=value`` / ``name>=value``
 ENV_SLO = "DLROVER_TPU_SLO"
@@ -380,15 +378,7 @@ class TimeSeriesStore:
     last (raw first — recent coarse history outlives old raw detail).
     Thread-safe."""
 
-    def __init__(self, max_mb: Optional[float] = None):
-        if max_mb is None:
-            try:
-                max_mb = float(
-                    os.environ.get(ENV_FLEET_MEM_MB, "")
-                    or DEFAULT_FLEET_MEM_MB
-                )
-            except ValueError:
-                max_mb = DEFAULT_FLEET_MEM_MB
+    def __init__(self, max_mb: float = FLEET_MEM_MB):
         self._max_bytes = int(max_mb * 1024 * 1024)
         self._lock = threading.Lock()
         self._series: Dict[str, Dict[str, _SeriesTier]] = {}

@@ -64,12 +64,11 @@ from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.telemetry import counter, gauge, histogram, record, tracing
 from dlrover_tpu.trainer import ckpt_store
 
-#: DLROVER_TPU_CKPT_QUEUE_DEPTH — max persist archives in flight
-#: (queued + running); DLROVER_TPU_CKPT_STAGE — "async" (default:
-#: background D2H materialization) or "sync" (Orbax-style blocking
-#: D2H on the train thread; serialization/persist still async).
-ENV_QUEUE_DEPTH = "DLROVER_TPU_CKPT_QUEUE_DEPTH"
-ENV_STAGE = "DLROVER_TPU_CKPT_STAGE"
+#: max persist archives in flight (queued + running)
+QUEUE_DEPTH = 2
+#: "async": background D2H materialization; "sync": Orbax-style
+#: blocking D2H on the train thread (serialization/persist still async)
+STAGE = "async"
 
 #: RAM-tier saves are milliseconds; persist commits can run minutes
 _CKPT_BUCKETS = (
@@ -565,8 +564,8 @@ class FlashCheckpointer:
         max_persist_keep: int = 3,
         use_orbax: bool = True,
         commit_timeout: float = 300.0,
-        queue_depth: Optional[int] = None,
-        stage: Optional[str] = None,
+        queue_depth: int = QUEUE_DEPTH,
+        stage: str = STAGE,
         process_index: Optional[int] = None,
         n_processes: Optional[int] = None,
         proc_of_device: Optional[Callable[[Any], int]] = None,
@@ -610,11 +609,7 @@ class FlashCheckpointer:
 
         self._attempt = os.getenv(NodeEnv.RDZV_ROUND, "0")
         os.makedirs(self.ram_dir, exist_ok=True)
-        if queue_depth is None:
-            queue_depth = int(os.getenv(ENV_QUEUE_DEPTH, "2") or 2)
         self.queue_depth = max(1, queue_depth)
-        if stage is None:
-            stage = os.getenv(ENV_STAGE, "async")
         if stage not in ("async", "sync"):
             raise ValueError(f"stage must be async|sync, got {stage!r}")
         self._stage_sync = stage == "sync"
@@ -1492,19 +1487,11 @@ class FlashCheckpointer:
                     tier, cand, t0, backend="store", requested_step=step,
                 )
                 return state, cand
-            # legacy monolithic path (format v1, or a v2 single-proc
-            # archive readable whole)
+            # monolithic path: a single-proc archive readable whole
             try:
                 with ckpt_store.open_step(
                     self._store, cand, self._process_index
                 ) as f:
-                    man = ckpt_store.read_manifest(f)
-                    if int(man.get("version", 1)) < 2:
-                        record(
-                            "checkpoint.legacy_format", step=cand,
-                            tier="persistent",
-                            version=int(man.get("version", 1)),
-                        )
                     snapshot, _ = ckpt_store.snapshot_from_file(
                         f, target
                     )
@@ -1551,22 +1538,13 @@ class FlashCheckpointer:
 
     def _restore_local_archive(self, f, man, step: int, target,
                                extra_sources=None):
-        """RAM-tier restore dispatch on the archive's format. v1
-        archives (and complete single-process v2 archives) go through
-        the monolithic reader; a multi-process v2 archive holds only
-        this host's addressable shards, so the v2 planner assembles
-        the rest from peers / the store."""
-        version = int(man.get("version", 1))
+        """RAM-tier restore dispatch on the archive's topology. A
+        complete single-process archive goes through the monolithic
+        reader; a multi-process archive holds only this host's
+        addressable shards, so the v2 planner assembles the rest from
+        peers / the store."""
         topo_n = int((man.get("topology") or {}).get("n_processes", 1))
-        if version < 2:
-            # pre-manifest monolithic archive: fully served by the
-            # legacy reader — existing saves and the warm-restart
-            # drill keep working, and the journal says so
-            record(
-                "checkpoint.legacy_format", step=step, tier="ram",
-                version=version,
-            )
-        if version < 2 or (topo_n <= 1 and not man.get("subset")):
+        if topo_n <= 1 and not man.get("subset"):
             snapshot, _ = ckpt_store.snapshot_from_file(f, target)
             return _restore_shards(snapshot, target)
         state, _ = self._restore_v2(

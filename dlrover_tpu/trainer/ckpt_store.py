@@ -115,11 +115,19 @@ _MANIFEST = "manifest.json"
 #: version 2 = the sharded checkpoint plane (docs/CHECKPOINT.md
 #: "Format v2"): normalized logical-shard domains, a global domain map
 #: with replica sets and elected owners in every entry, and optional
-#: owned-only subset archives. Version-1 archives (monolithic, welded
-#: to the saving topology) are still READ — restore auto-detects them
-#: and routes through the legacy path.
+#: owned-only subset archives. It is the only version read: an archive
+#: that states another (or none) is refused by name.
 _FORMAT_VERSION = 2
-_SUPPORTED_VERSIONS = (1, 2)
+
+
+def _check_version(manifest: Dict[str, Any], fileobj: BinaryIO) -> None:
+    version = manifest.get("version")
+    if version != _FORMAT_VERSION:
+        raise ArchiveError(
+            f"archive format version {version!r} is not readable (only "
+            f"version {_FORMAT_VERSION} is): "
+            f"{getattr(fileobj, 'name', '<archive>')}"
+        )
 
 
 def _path_components(path) -> List[Dict[str, Any]]:
@@ -224,7 +232,7 @@ def snapshot_to_file(snapshot: Any, step: int, fileobj: BinaryIO,
         }
     if owned_only:
         # a dedup subset is not independently restorable through the
-        # legacy reader (members for unowned shards are elsewhere);
+        # whole-archive reader (members for unowned shards are elsewhere);
         # the v2 loader assembles across process files instead
         manifest["subset"] = True
     counter = [0]
@@ -383,6 +391,7 @@ def _load_archive_file(fileobj: BinaryIO):
     try:
         with zipfile.ZipFile(fileobj) as zf:
             manifest = json.loads(zf.read(_MANIFEST).decode("utf-8"))
+            _check_version(manifest, fileobj)
             _verify_digests(zf, manifest)
         fileobj.seek(0)
         lazy = np.load(fileobj, allow_pickle=False)
@@ -399,10 +408,6 @@ def _load_archive_file(fileobj: BinaryIO):
         raise
     except Exception as e:
         raise ArchiveError(f"corrupt checkpoint archive: {e}")
-    if manifest.get("version") not in _SUPPORTED_VERSIONS:
-        raise ArchiveError(
-            f"unsupported archive version {manifest.get('version')!r}"
-        )
     for name, enc in manifest.get("encodings", {}).items():
         if name not in arrays:
             continue
@@ -520,10 +525,7 @@ def read_manifest(fileobj: BinaryIO) -> Dict[str, Any]:
         raise ArchiveError(f"unreadable archive manifest: {e}")
     if not isinstance(manifest, dict):
         raise ArchiveError("archive manifest malformed")
-    if manifest.get("version") not in _SUPPORTED_VERSIONS:
-        raise ArchiveError(
-            f"unsupported archive version {manifest.get('version')!r}"
-        )
+    _check_version(manifest, fileobj)
     return manifest
 
 
@@ -802,7 +804,7 @@ def index_key(step: int, process_index: int, attempt: str = "0") -> str:
     """One host's index piece (its archive manifest as standalone
     JSON): what rank 0 merges into the step manifest. The ``x`` prefix
     keeps it out of the ``proc-`` shard namespace the commit barrier
-    and legacy readers pattern-match on."""
+    pattern-matches on."""
     return f"step-{step}/xidx-{process_index}.a{attempt}.json"
 
 
@@ -938,9 +940,9 @@ def commit_step_sharded(store: ObjectStore, step: int, n_processes: int,
 
 
 def step_manifest(store: ObjectStore, step: int) -> Optional[Dict[str, Any]]:
-    """The merged v2 manifest of a COMMITTED step, or None for legacy
-    (format-1) steps. KeyError when the step is uncommitted or a v2
-    step lost its manifest object."""
+    """The merged v2 manifest of a COMMITTED step, or None for a step
+    one process wrote whole (no merged manifest). KeyError when the
+    step is uncommitted or a v2 step lost its manifest object."""
     doc = _commit_manifest(store, step)  # KeyError if uncommitted
     if doc.get("format") != 2:
         return None
@@ -1017,8 +1019,8 @@ def available_steps(store: ObjectStore, process_index: int) -> List[int]:
     assembles needed domains from whichever process files hold them —
     so availability means the step manifest exists, not a shard keyed
     by this process's index (which may not even be in the save
-    topology after a world resize). Legacy steps keep the per-process
-    shard check."""
+    topology after a world resize). Steps one process wrote whole keep
+    the per-process shard check."""
     out = []
     for s in committed_steps(store):
         try:

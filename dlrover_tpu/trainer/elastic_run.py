@@ -20,7 +20,7 @@ from dlrover_tpu.agent.elastic.training import (
     launch_agent,
 )
 from dlrover_tpu.agent.master_client import MasterClient
-from dlrover_tpu.agent.relay import ENV_RELAY_ADDR, ENV_RELAY_FANOUT, RelayTier
+from dlrover_tpu.agent.relay import ENV_RELAY_ADDR, RelayTier
 from dlrover_tpu.common.constants import NodeEnv
 from dlrover_tpu.common.grpc_utils import addr_connected
 from dlrover_tpu.common.log import default_logger as logger
@@ -54,8 +54,7 @@ def parse_args(argv=None):
                         help="self-host a local master subprocess")
     parser.add_argument("--master_addr", type=str,
                         default=os.getenv(NodeEnv.MASTER_ADDR, ""))
-    parser.add_argument("--relay_fanout", type=int,
-                        default=int(os.getenv(ENV_RELAY_FANOUT, "0") or 0),
+    parser.add_argument("--relay_fanout", type=int, default=0,
                         help="agents per aggregator relay; > 0 makes "
                              "node-rank-0's launcher run a relay tier "
                              "of ceil(max_nodes / fanout) local "

@@ -449,36 +449,52 @@ def test_digest_mismatch_refetches_from_next_tier(tmp_path):
     assert victim  # the corrupted member really existed
 
 
-# ----------------------------------------------------- legacy format v1
+# ------------------------------------------------- other format versions
 
 
-def test_legacy_v1_archive_read_and_journaled(tmp_path, monkeypatch):
-    """Pre-v2 monolithic archives are auto-detected and read through
-    the old path, with ``checkpoint.legacy_format`` journaled."""
-    monkeypatch.setattr(ckpt_store, "_FORMAT_VERSION", 1)
+def test_a_v1_archive_is_refused_by_name(tmp_path, monkeypatch):
+    """An archive that states format version 1 is input from outside
+    the program: it is refused with an error naming the file and the
+    version, and the walk-down restores the v2 step beside it."""
     state = {"w": np.arange(12, dtype=np.float32), "n": 3}
     c = FlashCheckpointer(
         persist_dir=str(tmp_path / "store"),
-        ram_dir=str(tmp_path / "ram-v1"),
+        ram_dir=str(tmp_path / "ram"),
         persist_interval=1, use_orbax=False,
     )
-    c.save(110, state, force_persist=True)
+    c.save(100, state, force_persist=True)
+    c.wait()
+    monkeypatch.setattr(ckpt_store, "_FORMAT_VERSION", 1)
+    c.save(110, {"w": state["w"] + 1, "n": 4}, force_persist=True)
     c.wait()
     c.close()
-    monkeypatch.setattr(ckpt_store, "_FORMAT_VERSION", 2)
+    monkeypatch.undo()
+
+    store = ckpt_store.get_store(str(tmp_path / "store"))
+    v1_key = ckpt_store.step_key(110, 0)
+    with store.open_read(v1_key) as f:
+        with pytest.raises(ckpt_store.ArchiveError) as err:
+            ckpt_store.read_manifest(f)
+    assert v1_key in str(err.value) and "version 1" in str(err.value)
+    with store.open_read(v1_key) as f:
+        with pytest.raises(ckpt_store.ArchiveError, match="version 1"):
+            ckpt_store.snapshot_from_file(f)
 
     r = FlashCheckpointer(
         persist_dir=str(tmp_path / "store"),
-        ram_dir=str(tmp_path / "ram-v1-new"),
+        ram_dir=str(tmp_path / "ram-new"),
         persist_interval=0, use_orbax=False,
     )
-    got, step = r.restore(step=110)
+    got, step = r.restore()
     r.close()
-    assert step == 110
+    assert step == 100
     assert np.array_equal(got["w"], state["w"])
-    ev = events("checkpoint.legacy_format")
-    assert ev and ev[-1]["data"]["version"] == 1
-    assert ev[-1]["data"]["tier"] == "persistent"
+    refused = [
+        e["data"] for e in events("checkpoint.restore_fallback")
+        if e["data"]["step"] == 110
+    ]
+    assert refused and refused[-1]["reason"] == "archive_error"
+    assert "version 1" in refused[-1]["error"]
 
 
 # ------------------------------------------------------------ bench smoke

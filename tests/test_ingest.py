@@ -275,6 +275,22 @@ def test_async_server_hot_and_cold_lanes():
         server.stop(grace=0.2)
 
 
+def test_the_master_server_is_the_async_one_whatever_the_env(monkeypatch):
+    """One server for the master: the variable that used to choose the
+    threaded one is read by nothing."""
+    from dlrover_tpu.common.grpc_utils import AsyncRpcServer
+
+    monkeypatch.setenv("DLROVER_TPU_ASYNC_INGEST", "0")
+    jm, speed = _job_manager(2)
+    server, _ = create_master_service(
+        0, job_manager=jm, speed_monitor=speed
+    )
+    try:
+        assert isinstance(server, AsyncRpcServer)
+    finally:
+        server.stop(grace=0.2)
+
+
 # ---------------------------------------------------------- relay tier
 
 

@@ -110,9 +110,6 @@ VOCABULARY = {
         "report.rpc_fallback",
     })),
     # PR 13: the sharded checkpoint plane (format v2).
-    # (legacy-archive detection journals "checkpoint.legacy_format",
-    # which lives in the checkpoint.* namespace with the other
-    # FlashCheckpointer lifecycle events, not here.)
     "ckpt": (("ckpt",), frozenset({
         "ckpt.manifest_committed",
         "ckpt.dedup",

@@ -60,8 +60,6 @@ KNOB_NOTES: Dict[str, str] = {
     "DLROVER_TPU_CKPT_DIR": "checkpoint root the evaluator reads",
     "DLROVER_TPU_DIST_HEARTBEAT_TIMEOUT":
         "jax.distributed heartbeat timeout seconds",
-    "DLROVER_TPU_STRAGGLER_SCORE_INTERVAL":
-        "min seconds between straggler re-scores",
 }
 
 
