@@ -37,7 +37,12 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 #: the scopes of models/llama.py and parallel/moe.py, the first that an
 #: ``op_name`` holds wins; ``loss`` and ``optimizer`` (trainer/sharded.py)
 #: hold every op of theirs that no inner scope names, as ``loop.pass``
-#: (a looped stack's walk) holds a pass's
+#: (a looped stack's walk) holds a pass's. ``loss.head`` (the head's
+#: products and its cross entropy, forward and backward, since PR 63)
+#: stands after ``mtp.head``, so a prediction module's head keeps its
+#: own name, and ahead of ``loop.exit_loss``: it is ``loss.head`` that
+#: names ``ouro``'s four passes through the head, and ``loop.exit_loss``
+#: holds what is left of it, the exit distribution and the weighted sum
 SCOPES = (
     "ssm.scan", "ssm.in_proj", "ssm.conv", "ssm.dt", "ssm.gate_norm",
     "ssm.out_proj", "moe.latent_down", "moe.latent_up", "kda.scan", "kda.proj", "kda.conv", "kda.out", "kda.decay",
@@ -48,8 +53,8 @@ SCOPES = (
     "conv.out_proj", "moe.shared", "moe.route", "moe.dispatch",
     "moe.combine", "moe.experts", "moe.bias_update", "mtp.merge",
     "mtp.block", "mtp.head", "norm.post_attn", "norm.post_mlp",
-    "embed.mup", "loop.exit_gate", "loop.exit_loss", "loop.pass",
-    "optimizer", "loss",
+    "embed.mup", "loop.exit_gate", "loss.head", "loop.exit_loss",
+    "loop.pass", "optimizer", "loss",
 )
 
 

@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from dlrover_tpu.ops.attention import flash_attention
-from dlrover_tpu.models.llama import _chunked_ce, _masked_nll
+from dlrover_tpu.models.llama import _mean_ce
 
 
 @dataclass(frozen=True)
@@ -255,13 +255,7 @@ def next_token_loss(
 ) -> jax.Array:
     tokens, targets = batch
     x = hidden_states(params, tokens, cfg, attn_fn=attn_fn)
-    head = _lm_head(params, cfg)
-    if cfg.loss_chunk > 0:
-        nll_sum, cnt = _chunked_ce(x, head, targets, cfg.loss_chunk)
-    else:
-        logits = (x @ head).astype(jnp.float32)
-        nll_sum, cnt = _masked_nll(logits, targets)
-    return nll_sum / jnp.maximum(cnt, 1.0)
+    return _mean_ce(x, _lm_head(params, cfg), targets, cfg.loss_chunk)
 
 
 def flops_per_token(cfg: GPTConfig, seq_len: int) -> float:

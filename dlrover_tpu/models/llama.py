@@ -1916,6 +1916,61 @@ def _masked_nll(logits: jax.Array, targets: jax.Array) -> Tuple[
     return jnp.sum(nll), jnp.sum(mask)
 
 
+@jax.custom_vjp
+def _head_nll(x: jax.Array, head: jax.Array, targets: jax.Array) -> Tuple[
+        jax.Array, jax.Array]:
+    """``_position_nll((x @ head).astype(float32), targets)`` of the
+    normed states ``x`` [..., hidden] through ``head`` [hidden,
+    vocab], with a backward rule of its own: the logits are kept
+    once, in the product's dtype (bfloat16 on the chip: the float32
+    copy held no bit that they do not), beside a float32 row ``lse``,
+    and the backward forms their gradient in one pass over them,
+    rounded to their dtype where the plain rule's transpose rounds it
+    (the chip's compiler makes that pass inside both gradient
+    products and writes it nowhere: PERF.md section 5, PR 63).
+    Nothing of [tokens, vocab] is float32 in memory, and the target's
+    logit is read, and its gradient placed, by a comparison: no
+    gather, no zeros, no scatter. Every loss goes through here;
+    ``forward()`` keeps the plain product for callers that want
+    logits. Scope ``loss.head``."""
+    return _head_nll_fwd(x, head, targets)[0]
+
+
+def _head_nll_fwd(x, head, targets):
+    with jax.named_scope("loss.head"):
+        logits = x @ head
+        wide = logits.astype(jnp.float32)  # inside the reductions only
+        top = jnp.max(wide, axis=-1)
+        lse = top + jnp.log(jnp.sum(jnp.exp(wide - top[..., None]), axis=-1))
+        hit = _is_target(logits.shape, targets)
+        mask = (targets >= 0).astype(jnp.float32)
+        nll = (lse - jnp.sum(jnp.where(hit, wide, 0.0), axis=-1)) * mask
+    return (nll, mask), (x, head, targets, logits, lse)
+
+
+def _head_nll_bwd(kept, cts):
+    x, head, targets, logits, lse = kept
+    with jax.named_scope("loss.head"):
+        scale = cts[0] * (targets >= 0)
+        p = jnp.exp(logits.astype(jnp.float32) - lse[..., None])
+        g = ((p - _is_target(logits.shape, targets)) * scale[..., None]
+             ).astype(logits.dtype)
+        dx = (g @ head.T).astype(x.dtype)
+        rows = tuple(range(x.ndim - 1))
+        dhead = jnp.tensordot(x, g, (rows, rows)).astype(head.dtype)
+    return dx, dhead, None
+
+
+_head_nll.defvjp(_head_nll_fwd, _head_nll_bwd)
+
+
+def _is_target(shape, targets: jax.Array) -> jax.Array:
+    """Bool ``shape`` [..., vocab]: the column that is the row's
+    target; none in a row whose target is < 0."""
+    columns = jax.lax.broadcasted_iota(targets.dtype, shape, len(shape) - 1)
+    return columns == targets[..., None]
+
+
 def _in_chunks(x: jax.Array, targets: jax.Array, chunk: int):
     """``x`` [..., h] and ``targets`` as rows of ``chunk`` tokens,
     [chunks, chunk, h] and [chunks, chunk]."""
@@ -1944,8 +1999,7 @@ def _chunked_ce(x: jax.Array, lm_head: jax.Array, targets: jax.Array,
     def body(carry, inp):
         nll_sum, cnt = carry
         xs, ts = inp
-        logits = (xs @ lm_head).astype(jnp.float32)
-        s, c = _masked_nll(logits, ts)
+        s, c = map(jnp.sum, _head_nll(xs, lm_head, ts))
         return (nll_sum + s, cnt + c), None
 
     (nll_sum, cnt), _ = jax.lax.scan(
@@ -1960,8 +2014,7 @@ def _mean_ce(x, head, targets, chunk: int) -> jax.Array:
     if chunk > 0:
         nll_sum, cnt = _chunked_ce(x, head, targets, chunk)
     else:
-        logits = (x @ head).astype(jnp.float32)
-        nll_sum, cnt = _masked_nll(logits, targets)
+        nll_sum, cnt = map(jnp.sum, _head_nll(x, head, targets))
     return nll_sum / jnp.maximum(cnt, 1.0)
 
 
@@ -1970,7 +2023,7 @@ def _ce_by_position(x, head, targets, chunk: int) -> jax.Array:
     position, float32 in ``targets``' shape, 0 where the target is
     < 0; with ``chunk`` in ``_chunked_ce``'s rematerialized chunks."""
     def nll(xs, ts):
-        return _position_nll((xs @ head).astype(jnp.float32), ts)[0]
+        return _head_nll(xs, head, ts)[0]
 
     if chunk <= 0:
         return nll(x, targets)
@@ -2008,8 +2061,9 @@ def _exit_terms(params, batch, cfg: LlamaConfig, attn_fn=None,
     and the positions that have a target, float32 [batch, seq]. Each
     pass goes through the head under a checkpoint of its own, so its
     logits are made again in the backward and none is kept from the
-    forward: four unchunked would be 6.4 GB at 8,192 x 49,152, and
-    the cell's step plans 14.87 GB with them (the calls are unrolled
+    forward: four passes' bfloat16 logits would be 3.2 GB at 8,192 x
+    49,152 beside a step that plans 14.39 GB (14.87 while they were
+    widened to float32, PERF.md section 6, PR 63; the calls are unrolled
     like the passes: a ``lax.map`` over them, which holds them to one
     at a time by construction, planned 14.59 GB beside the unrolled
     passes and ran 3% slower, PERF.md section 6, PR 58). Scope

@@ -1059,9 +1059,15 @@ def test_llama_1b_step_fits_one_chip_at_batch_3(topo, on_tpu_path):
 #: products in the last layer it walks once the attention backward
 #: was one call (its tie is broken by instruction names, and the
 #: walk's five grouped matmuls were then ``tpu_custom_call.98`` to
-#: ``.102``), and planned 16,298,763,776
+#: ``.102``), and planned 16,298,763,776. Since the head's cross
+#: entropy keeps its logits once, in bfloat16 (PR 63,
+#: ``models/llama.py _head_nll``), the step plans 15,213,382,656:
+#: 1,030,838,272 under the 16,244,220,928 it planned the commit
+#: before, and the ceiling came down by 1,043,438,080; the compiler's
+#: ``.remat`` twins of the head's fusions (fourteen names with
+#: ``fusion.1734.remat``) are no longer in the step's text
 SMALLTHINKER_STEP_BYTES = {"one pass": 15_542_064_128,
-                           "walk": 16_256_820_736}
+                           "walk": 15_213_382_656}
 
 
 def test_smallthinker_step_walks_its_share_in_chunks(
@@ -1098,6 +1104,7 @@ def test_smallthinker_step_walks_its_share_in_chunks(
     compiled = trainer.train_step.lower(*_abstract_step_args(
         trainer, traffic["global_batch"], traffic["seq"])).compile()
     planned = compiled.memory_analysis().peak_memory_in_bytes
+    print("smallthinker step plans", planned)
     assert planned <= SMALLTHINKER_STEP_BYTES["walk"]
     rows = traffic["seq"] * cfg.moe_top_k
     wide = re.compile(
@@ -1152,8 +1159,11 @@ def _in_place_sums_keep_their_names(text, held, token_sums):
 #: this file compiles it (4 x 8,192, thirteen layers, remat
 #: ``minimal``, the loss unchunked; PERF.md, PR 36): 8.0 GB of it the
 #: state. At nine layers it plans 11.64 GB, which is the room the
-#: third period took; ``loss_chunk`` 2048 is refused at thirteen
-LFM2_STEP_BYTES = 15_468_132_864
+#: third period took; ``loss_chunk`` 2048 is refused at thirteen.
+#: The ceiling came down by 8,669,184 in PR 63 to what the step plans
+#: with the head's own backward rule and planned the commit before it
+#: alike: the step's peak is not the head's
+LFM2_STEP_BYTES = 15_459_463_680
 
 
 def test_lfm2_step_holds_the_convolutions_kernels(
@@ -1192,8 +1202,9 @@ def test_lfm2_step_holds_the_convolutions_kernels(
     )
     compiled = trainer.train_step.lower(*_abstract_step_args(
         trainer, traffic["global_batch"], traffic["seq"])).compile()
-    assert compiled.memory_analysis().peak_memory_in_bytes <= (
-        LFM2_STEP_BYTES)
+    planned = compiled.memory_analysis().peak_memory_in_bytes
+    print("lfm2 step plans", planned)
+    assert planned <= LFM2_STEP_BYTES
     text = compiled.as_text()
     _routers_compare(text, traffic, cfg)
     kernels = re.findall(
@@ -1240,8 +1251,11 @@ def test_lfm2_step_holds_the_convolutions_kernels(
 #: MB more (at the default effort 46), by the buffer assignment's
 #: total 119 MB less, and on the chip the same (PERF.md section 6).
 #: 16,013,520,896 until the routers read their k scores by comparison
-#: (PR 55): a quarter of a megabyte more at this effort
-JOYAI_STEP_BYTES = 16_013_783_040
+#: (PR 55): a quarter of a megabyte more at this effort, 16,013,783,040.
+#: Down by 1,592,082,432 since the two heads keep their logits once,
+#: in bfloat16, and nothing float32 of [32768, 16160] (PR 63,
+#: ``models/llama.py _head_nll``)
+JOYAI_STEP_BYTES = 14_421_700_608
 
 
 def test_joyai_step_holds_no_whole_q_or_k(topo, on_tpu_path, monkeypatch):
@@ -1275,8 +1289,9 @@ def test_joyai_step_holds_no_whole_q_or_k(topo, on_tpu_path, monkeypatch):
     compiled = trainer.train_step.lower(*_abstract_step_args(
         trainer, traffic["global_batch"], traffic["seq"])
     ).compile(LEAST_EFFORT)
-    assert compiled.memory_analysis().peak_memory_in_bytes <= (
-        JOYAI_STEP_BYTES)
+    planned = compiled.memory_analysis().peak_memory_in_bytes
+    print("joyai step plans", planned)
+    assert planned <= JOYAI_STEP_BYTES
     text = compiled.as_text()
     _routers_compare(text, traffic, cfg)
     whole = re.findall(
@@ -1312,8 +1327,16 @@ def test_joyai_step_holds_no_whole_q_or_k(topo, on_tpu_path, monkeypatch):
 #: norms' factors at full width and the relayouts' copies; in rows with
 #: the convolutions as plain float32 ops (PR 45) 15,315,505,664.
 #: 14,462,113,280 until the scan's forward kept a layer's inverses and
-#: ``w`` for its backward (PR 61): 268 MB each, one layer's at a time
-SOLAR_STEP_BYTES = 14_998_671_872
+#: ``w`` for its backward (PR 61): 268 MB each, one layer's at a time,
+#: 14,998,671,872. UP by 113,664 bytes with the head's own backward
+#: rule (PR 63), at this effort and at the default alike, and the one
+#: ceiling that rose: the parent's live peak was the head's (two
+#: float32 [8192, 24576], 805 MB each, in the buffer assignment of
+#: the compiler's dump), the rule's is a delta-rule layer's backward
+#: (``delta_rule.15``'s results and three ``tgmm``s), and the heap,
+#: 6,478,791,168 and 6,478,922,240 bytes of temporaries around live
+#: peaks of 2.7 and 4.8 GB, is packed 128 KiB apart
+SOLAR_STEP_BYTES = 14_998_785_536
 #: what a delta-rule layer's q, k, v, g or o is as rows, as heads, and
 #: as the tiles of rows that the compiler names ``[s / 8, 8, heads, d]``
 SOLAR_ROWS = re.compile(
@@ -1509,8 +1532,10 @@ def test_solar_step_holds_the_delta_rules_kernels(
 #: the 256 experts held 12,337,465,344 (the file's ``depth``).
 #: 9,407,342,592 until the scan's forward kept a layer's inverses and
 #: ``w`` for its backward (PR 61): 268 MB each a layer, 215 MB of it
-#: over what the step's peak held beside them
-KIMI_STEP_BYTES = 9_622_743_040
+#: over what the step's peak held beside them, 9,622,743,040; 1,024
+#: bytes less with the head's own backward rule (PR 63): the peak is
+#: not the head's
+KIMI_STEP_BYTES = 9_622_742_016
 
 
 def test_kimi_step_holds_the_pair_backward_and_both_operators_kernels(
@@ -1666,8 +1691,10 @@ def test_kimi_step_holds_the_pair_backward_and_both_operators_kernels(
 #: 11,458,404,352. PR 54 read 11,462,598,656 here; the figure below is
 #: what the tree plans since PR 56, with a mixer's gate and norm as
 #: plain passes and as the kernels alike (PR 57): the step's peak is
-#: not in a mixer
-NEMOTRON_STEP_BYTES = 11_461_876_736
+#: not in a mixer, 11,461,876,736. It was the heads': down by
+#: 473,532,416 since the model's and the module's keep their logits
+#: once, in bfloat16 (PR 63, ``models/llama.py _head_nll``)
+NEMOTRON_STEP_BYTES = 10_988_344_320
 
 
 def test_ssd_kernels_compile_at_the_cells_shape(topo, monkeypatch):
@@ -1851,16 +1878,15 @@ def test_nemotron_step_holds_the_scans_kernels(topo, on_tpu_path, monkeypatch):
         assert scope in text, scope
 
 
-#: ``peak_memory_in_bytes`` of ``trinity-mini-ep8.steady``'s step as
-#: this file compiles it (1 x 16,384, nine layers, remat ``minimal``,
-#: the least effort; PERF.md, PR 49): 7.46 GB of it the state. It
-#: read 15,488,046,080 here and 15,546,647,040 at the default effort
-#: while the routers gathered their k scores; since they compare (PR
-#: 55) the default effort, which the chip compiles at, plans
-#: 15,524,612,608, and the least effort 108 MB more than it did: it
-#: alone lifts the select's zeros, a float32 [16384, 8, 128], out of
-#: the layer loop and carries them through it
-OURO_STEP_BYTES = 14_867_043_328
+#: ``peak_memory_in_bytes`` of ``ouro-2.6b-1chip.steady``'s step as
+#: this file compiles it (1 x 8,192, 16 layers walked four times,
+#: remat ``minimal``, the least effort; PERF.md, PR 58): 6.14 GB of it
+#: the state. 14,867,043,328 at either effort while a pass's logits
+#: were widened to float32 and their gradient scattered into zeros;
+#: down by 467,533,312 since a pass keeps them once, in bfloat16 (PR
+#: 63, ``models/llama.py _head_nll``; 14,391,121,408 at the default
+#: effort, which the chip compiles at)
+OURO_STEP_BYTES = 14_399_510_016
 
 
 def test_ouro_step_fits_and_holds_a_layer_body_a_pass(topo, on_tpu_path):
@@ -1913,7 +1939,18 @@ def test_ouro_step_fits_and_holds_a_layer_body_a_pass(topo, on_tpu_path):
     assert tuning.last_selection()["gqa_group"] == 1
 
 
-TRINITY_STEP_BYTES = 15_596_441_600
+#: ``peak_memory_in_bytes`` of ``trinity-mini-ep8.steady``'s step as
+#: this file compiles it (1 x 16,384, nine layers, remat ``minimal``,
+#: the least effort; PERF.md, PR 49): 7.46 GB of it the state. It
+#: read 15,488,046,080 here and 15,546,647,040 at the default effort
+#: while the routers gathered their k scores; since they compare (PR
+#: 55) the default effort, which the chip compiles at, plans
+#: 15,524,612,608, and the least effort 108 MB more than it did: it
+#: alone lifts the select's zeros, a float32 [16384, 8, 128], out of
+#: the layer loop and carries them through it,
+#: 15,596,441,600. Down by 616,621,056 since the head keeps its
+#: logits once, in bfloat16 (PR 63, ``models/llama.py _head_nll``)
+TRINITY_STEP_BYTES = 14_979_820_544
 
 
 def test_trinity_step_fits_and_moves_the_bias(
