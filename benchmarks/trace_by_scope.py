@@ -46,6 +46,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 SCOPES = (
     "ssm.scan", "ssm.in_proj", "ssm.conv", "ssm.dt", "ssm.gate_norm",
     "ssm.out_proj", "moe.latent_down", "moe.latent_up", "kda.scan", "kda.proj", "kda.conv", "kda.out", "kda.decay",
+    "sparse.compress", "sparse.select", "sparse.attn", "lightning.proj",
+    "lightning.scan", "lightning.out", "embed.scale", "branch.scale",
+    "head.scale",
     # "mla.q" (q by one matrix) after "mla.q_down", which holds it
     "mla.q_down", "mla.q", "mla.kv_down", "mla.up", "attn.latent",
     "attn.gate",

@@ -31,12 +31,22 @@ from dlrover_tpu.ops.delta_rule import gated_delta_rule_rows
 from dlrover_tpu.ops.gated_norm import gated_group_norm
 from dlrover_tpu.ops.kda_conv import conv_silu_norm, heads_apart
 from dlrover_tpu.ops.short_conv import gated_short_conv
+from dlrover_tpu.ops.sparse_attention import (
+    compress_keys, select_blocks, selected_attention,
+)
 from dlrover_tpu.ops.ssd import ssd_scan
 
 
 #: the operators a layer's kind may name
 OPERATORS = ("full_attention", "latent_attention", "linear_attention",
-             "conv", "state_space", "none")
+             "conv", "state_space", "sparse_attention",
+             "lightning_attention", "none")
+#: those whose kernels reach the step outside any ``shard_map`` (the
+#: selection's compressed keys and top-k are over a whole sequence,
+#: the scan has no state to hand to a neighbour, and the partitioner
+#: cannot cut a Pallas call): the trainer refuses them on every mesh
+#: of more than one device, a data-parallel one too (ROADMAP B13)
+ONE_DEVICE_OPERATORS = ("sparse_attention", "lightning_attention")
 
 
 class LayerKind(NamedTuple):
@@ -45,7 +55,10 @@ class LayerKind(NamedTuple):
     makes it, come through low-rank projections, ``"conv"``, the
     gated short convolution, or
     ``"linear_attention"``, the gated delta rule, ``"state_space"``,
-    a Mamba-2 mixer, or ``"none"``), for attention the
+    a Mamba-2 mixer, ``"sparse_attention"``, full attention's q, k
+    and v over the key blocks each query selects,
+    ``"lightning_attention"``, linear attention with a fixed decay a
+    head, or ``"none"``), for attention the
     window (None: every earlier key) and whether q and
     k are rotated, and its feed-forward (``"dense"``,
     ``"experts"`` or ``"none"``). A block of one branch (``x +
@@ -299,6 +312,50 @@ class LlamaConfig:
     # (``_losses_and_counts``). 1: no loop, no gate, the plain loss.
     total_ut_steps: int = 1
     exit_entropy_weight: float = 0.1
+    # three scalar factors, in the source's keys (``MiniCPMConfig``):
+    # the embedding's rows times ``scale_emb`` as they enter the
+    # stream; each branch's result times ``scale_depth / sqrt(
+    # scale_depth_layers)`` ahead of the residual sum,
+    # ``scale_depth_layers`` the depth the source divides by (its
+    # published ``num_hidden_layers``; None: ``num_layers``), and None
+    # for ``scale_depth`` no factor; the head's input, past the final
+    # norm, divided by ``hidden_size / dim_model_base`` (None: as it
+    # is). No initialisation rule reads them here.
+    scale_emb: float = 1.0
+    scale_depth: Optional[float] = None
+    scale_depth_layers: Optional[int] = None
+    dim_model_base: Optional[int] = None
+    # attention over selected key blocks (InfLLM-v2; the family's
+    # ``sparse_config``): where ``layer_types[l]`` is
+    # "sparse_attention" the layer's q, k and v are full attention's
+    # (with ``qk_head_norm`` and ``attn_out_gate`` as set) and each
+    # query of a kv head's heads sees the ``sparse_topk`` blocks of
+    # ``sparse_block_size`` keys that ops/sparse_attention.py selects:
+    # compressed keys of ``sparse_kernel_size`` every
+    # ``sparse_kernel_stride``, the first ``sparse_init_blocks`` and
+    # the ``sparse_window_size / sparse_block_size`` nearest blocks
+    # forced. A sequence of ``sparse_dense_len`` positions or fewer
+    # takes full attention.
+    sparse_block_size: int = 64
+    sparse_kernel_size: int = 32
+    sparse_kernel_stride: int = 16
+    sparse_topk: int = 64
+    sparse_window_size: int = 2048
+    sparse_init_blocks: int = 1
+    sparse_dense_len: int = 8192
+    # linear attention with a fixed decay a head (Lightning
+    # Attention-2): where ``layer_types[l]`` is "lightning_attention",
+    # ``lightning_num_heads`` heads of ``lightning_head_dim``, q, k
+    # and v by one matrix each, an RMSNorm on each head's q and k (one
+    # ``lightning_head_dim``-wide scale each), both rotated where the
+    # layer's ``rope_layout`` entry is 1; ``S_t = exp(-m_h) S_{t-1} +
+    # k_t v_t^T``, ``o_t = S_t^T q_t / sqrt(lightning_head_dim)``,
+    # ``m_h = 2 ** (-8 (h + 1) / heads)`` a constant (``lightning_decay``);
+    # the result through an RMSNorm a head, a sigmoid gate from the
+    # layer's normed input through ``wg``, and ``wo``. The scan is
+    # ops/ssd.py's with one head a group, a step of one and no skip.
+    lightning_num_heads: int = 0
+    lightning_head_dim: int = 128
 
     def __post_init__(self):
         if self.remat not in ("off", "dots", "dots_attn_out",
@@ -350,12 +407,14 @@ class LlamaConfig:
                 )
             unknown = set(self.layer_types) - {
                 "conv", "full_attention", "latent_attention",
-                "linear_attention"}
+                "linear_attention", "sparse_attention",
+                "lightning_attention"}
             if unknown:
                 raise ValueError(
                     f"layer_types names {sorted(unknown)}: the "
                     "operators here are 'conv', 'full_attention', "
-                    "'latent_attention' and 'linear_attention'"
+                    "'latent_attention', 'linear_attention', "
+                    "'sparse_attention' and 'lightning_attention'"
                 )
             if self.latent != ("latent_attention" in self.layer_types) or (
                     self.latent and "full_attention" in self.layer_types):
@@ -371,6 +430,7 @@ class LlamaConfig:
                     "layer_types names 'linear_attention' and "
                     "linear_num_heads gives it no head"
                 )
+        self._check_operators_and_factors()
         if self.num_dense_layers and not (
                 self.num_experts > 0
                 and self.num_dense_layers < self.num_layers):
@@ -479,9 +539,84 @@ class LlamaConfig:
                     f" of {self.num_experts}"
                 )
 
+    def _check_operators_and_factors(self):
+        """Refuse what of the two operators and the three factors is
+        not built, rather than run it wrong."""
+        types = self.layer_types or ()
+        sparse = "sparse_attention" in types
+        lightning = "lightning_attention" in types
+        if (sparse or lightning) and (
+                self.num_experts > 0 or self.latent or self.mtp_layers
+                or self.post_norms):
+            raise ValueError(
+                "'sparse_attention' and 'lightning_attention' layers "
+                "stand in a stack of dense two-branch blocks: experts, "
+                "latent attention, a prediction module or norms on a "
+                "branch's result beside them are not built"
+            )
+        if sparse:
+            block, stride = self.sparse_block_size, self.sparse_kernel_stride
+            if (block < 1 or block & (block - 1) or stride < 1
+                    or block % stride or self.sparse_kernel_size % stride
+                    or self.sparse_window_size % block
+                    or self.sparse_init_blocks
+                    + self.sparse_window_size // block > self.sparse_topk):
+                raise ValueError(
+                    f"sparse attention: blocks of {block} keys (a power "
+                    f"of two), compressed keys of "
+                    f"{self.sparse_kernel_size} every {stride} (both "
+                    f"whole strides), a window of "
+                    f"{self.sparse_window_size} (whole blocks) and "
+                    f"{self.sparse_init_blocks} first blocks, all "
+                    f"forced within the {self.sparse_topk} selected"
+                )
+        if lightning:
+            rotated = any(
+                on for on, t in zip(
+                    self.rope_layout or (1,) * len(types), types)
+                if t == "lightning_attention")
+            if self.lightning_num_heads < 1 or (
+                    rotated and self.lightning_head_dim != self.head_dim):
+                raise ValueError(
+                    f"layer_types names 'lightning_attention': "
+                    f"lightning_num_heads {self.lightning_num_heads} "
+                    f"heads of {self.lightning_head_dim}, which where "
+                    f"they are rotated is head_dim {self.head_dim} (the "
+                    "stack has one table of angles)"
+                )
+        factors = (self.scale_emb != 1.0 or self.scale_depth is not None
+                   or self.dim_model_base is not None)
+        if factors and (self.total_ut_steps > 1 or self.mtp_layers):
+            raise ValueError(
+                "scale_emb, scale_depth and dim_model_base are read by "
+                "the plain stack and its one head: a looped stack or a "
+                "prediction module beside them is not built"
+            )
+        if self.scale_depth_layers is not None and self.scale_depth is None:
+            raise ValueError(
+                f"scale_depth_layers {self.scale_depth_layers} divides "
+                "a scale_depth, and there is none"
+            )
+
     @property
     def latent(self) -> bool:
         return self.kv_lora_rank is not None
+
+    @property
+    def branch_scale(self) -> Optional[float]:
+        """What each branch's result is multiplied by ahead of the
+        residual sum (``scale_depth``); None: nothing."""
+        if self.scale_depth is None:
+            return None
+        return self.scale_depth / math.sqrt(
+            self.scale_depth_layers or self.num_layers)
+
+    def lightning_decay(self):
+        """A lightning layer's rate a head, float32 [heads]: ``m_h =
+        2 ** (-8 (h + 1) / heads)``, a constant that is no leaf."""
+        heads = self.lightning_num_heads
+        return jnp.exp2(
+            -8.0 * jnp.arange(1, heads + 1, dtype=jnp.float32) / heads)
 
     @property
     def rope_dim(self) -> int:
@@ -734,6 +869,16 @@ def _leaves(cfg: LlamaConfig, kind: LayerKind) -> Dict:
             "w_beta": ((h, lh), ("embed", None)),
         }
         norms["o_norm"] = ld
+    elif kind.operator == "lightning_attention":
+        lh, ld = cfg.lightning_num_heads, cfg.lightning_head_dim
+        matrices = {
+            "wq": ((h, lh * ld), ("embed", "heads")),
+            "wk": ((h, lh * ld), ("embed", "heads")),
+            "wv": ((h, lh * ld), ("embed", "heads")),
+            "wg": ((h, lh * ld), ("embed", "heads")),
+            "wo": ((lh * ld, h), ("heads", "embed")),
+        }
+        norms.update(q_norm=ld, k_norm=ld, o_norm=ld)
     elif kind.operator == "latent_attention":
         rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
         nope, rope, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
@@ -1198,6 +1343,10 @@ def _pre_attn(cfg: LlamaConfig, x, layer_params, cos, sin,
         ), logits()
     if kind.operator == "linear_attention":
         return _delta_rule_operands(cfg, y, p, constrain), logits()
+    if kind.operator == "lightning_attention":
+        return _lightning_operands(
+            cfg, y, p, cos, sin, constrain, kind.rope
+        ), logits()
     q, k = y @ p["wq"], y @ p["wk"]
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"], cfg.norm_eps)
@@ -1274,6 +1423,29 @@ def _delta_rule_operands(cfg: LlamaConfig, y, p, constrain=_free):
         if cfg.linear_allow_neg_eigval:
             beta = 2.0 * beta
     return q, k, v, g, beta, gate
+
+
+def _lightning_operands(cfg: LlamaConfig, y, p, cos, sin, constrain=_free,
+                        rotate: bool = True):
+    """A lightning layer's operands from the normed stream ``y``, in
+    rows [b, s, heads x d] as ``ops/ssd.py ssd_scan`` takes them:
+    ``(q, k, v, the output gate's pre-activation)``. q and k each
+    through an RMSNorm a head and, where the layer rotates, the rotary
+    embedding; the scores' ``d ** -0.5`` rides on q's norm's scale, in
+    float32, so that no rounding is its own. Scope
+    ``lightning.proj``."""
+    heads, d = cfg.lightning_num_heads, cfg.lightning_head_dim
+    b, s, _ = y.shape
+    with jax.named_scope("lightning.proj"):
+        q, k, v, gate = (
+            constrain(y @ p[w], _MLP) for w in ("wq", "wk", "wv", "wg"))
+        q = rms_norm(
+            q.reshape(b, s, heads, d), p["q_norm"] * d ** -0.5,
+            cfg.norm_eps)
+        k = rms_norm(k.reshape(b, s, heads, d), p["k_norm"], cfg.norm_eps)
+        if rotate:
+            q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+    return q.reshape(b, s, -1), k.reshape(b, s, -1), v, gate
 
 
 def _evens_then_odds(w):
@@ -1435,6 +1607,16 @@ def _operator_out(x, out, layer_params, kind: LayerKind,
                 heads_apart(o, heads), p["o_norm"], norm_eps
             ).reshape(o.shape)
             return (o * gate) @ p["wo"]
+    if kind.operator == "lightning_attention":
+        # an RMSNorm a head with one learned scale, a sigmoid gate
+        with jax.named_scope("lightning.out"):
+            o, gate = out
+            heads = o.shape[-1] // p["o_norm"].shape[-1]
+            o = rms_norm(
+                o.reshape(b, s, heads, -1), p["o_norm"], norm_eps
+            ).reshape(o.shape)
+            return (o * jax.nn.sigmoid(
+                gate.astype(jnp.float32)).astype(o.dtype)) @ p["wo"]
     if isinstance(out, tuple):  # full attention and its gate's logits
         with jax.named_scope("attn.gate"):
             out, gate = out
@@ -1457,7 +1639,17 @@ def _past_operator(cfg: LlamaConfig, x, out, layer_params,
             branch = rms_norm(
                 branch, layer_params["post_attn_norm"], cfg.norm_eps
             )
-    return x + branch
+    return x + _scaled_branch(cfg, branch)
+
+
+def _scaled_branch(cfg: LlamaConfig, branch):
+    """A branch's result as it joins the stream: times ``scale_depth
+    / sqrt(scale_depth_layers)`` where the config has the factor
+    (scope ``branch.scale``)."""
+    if cfg.branch_scale is None:
+        return branch
+    with jax.named_scope("branch.scale"):
+        return _times(branch, cfg.branch_scale)
 
 
 def _post_attn(cfg: LlamaConfig, x, out, layer_params,
@@ -1510,7 +1702,7 @@ def _post_attn(cfg: LlamaConfig, x, out, layer_params,
     if cfg.post_norms:
         with jax.named_scope("norm.post_mlp"):
             out = rms_norm(out, p["post_mlp_norm"], cfg.norm_eps)
-    return constrain(x + out, _RESIDUAL), aux, counts
+    return constrain(x + _scaled_branch(cfg, out), _RESIDUAL), aux, counts
 
 
 def _block(cfg: LlamaConfig, x, layer_params, cos, sin, operate,
@@ -1552,7 +1744,11 @@ def _operator_of(cfg: LlamaConfig, attn_fn, kind: LayerKind):
     the output gate's logits on beside its result, as attention does
     with ``attn_out_gate``, for ``_operator_out``. A Mamba-2 mixer's
     is ``ssm.scan`` and, on its result, ``ssm.gate_norm``; a block
-    of the feed-forward alone calls nothing."""
+    of the feed-forward alone calls nothing. Sparse attention's is
+    ``sparse.compress``, ``sparse.select`` and ``sparse.attn``
+    (ops/sparse_attention.py; ``attn_fn`` under ``attn.full`` on a
+    sequence within ``sparse_dense_len``), a lightning layer's
+    ``lightning.scan``, the state-space scan's own entry."""
     if kind.operator == "none":
         return lambda: None
     if kind.operator == "state_space":
@@ -1589,6 +1785,46 @@ def _operator_of(cfg: LlamaConfig, attn_fn, kind: LayerKind):
                 ), gate
 
         return scan
+    if kind.operator == "lightning_attention":
+        heads = cfg.lightning_num_heads
+
+        def lightning(q, k, v, gate):
+            # the state-space scan with one head a group: x = v, B =
+            # k, C = q (scaled), a step of one, the rate -m_h, no skip
+            with jax.named_scope("lightning.scan"):
+                return ssd_scan(
+                    v, k, q, jnp.ones((*v.shape[:2], heads), jnp.float32),
+                    -cfg.lightning_decay(), jnp.zeros((heads,), jnp.float32),
+                    heads, heads,
+                ), gate
+
+        return lightning
+    if kind.operator == "sparse_attention":
+
+        def selected_keys(q, k, v):
+            if q.shape[1] <= cfg.sparse_dense_len:
+                with jax.named_scope("attn.full"):
+                    return attn_fn(q, k, v)
+            kernel, stride = cfg.sparse_kernel_size, cfg.sparse_kernel_stride
+            with jax.named_scope("sparse.compress"):
+                compressed = compress_keys(k, kernel, stride)
+            with jax.named_scope("sparse.select"):
+                selected = select_blocks(
+                    q, compressed, block=cfg.sparse_block_size,
+                    kernel=kernel, stride=stride, topk=cfg.sparse_topk,
+                    window=cfg.sparse_window_size,
+                    init_blocks=cfg.sparse_init_blocks,
+                )
+            with jax.named_scope("sparse.attn"):
+                return selected_attention(q, k, v, selected)
+
+        def attend_selected(q, k, v, *gate):
+            # the gate's logits, where the config has the gate, go on
+            # beside the result (``_operator_out``)
+            out = selected_keys(q, k, v)
+            return (out, *gate) if gate else out
+
+        return attend_selected
     if kind.operator == "latent_attention":
 
         def attend_latent(q, k, v, q_rope, k_rope):
@@ -1694,6 +1930,27 @@ def _embed(params, tokens, cfg: LlamaConfig):
     if cfg.mup_enabled:
         with jax.named_scope("embed.mup"):
             x = x * jnp.asarray(math.sqrt(cfg.hidden_size), x.dtype)
+    if cfg.scale_emb != 1.0:
+        with jax.named_scope("embed.scale"):
+            x = _times(x, cfg.scale_emb)
+    return x
+
+
+def _times(x, factor: float):
+    """``x`` times a constant, the product in float32 and rounded
+    once: a factor that ``x``'s dtype does not hold (1.4 / sqrt(32))
+    is not rounded to it first."""
+    return (x.astype(jnp.float32) * factor).astype(x.dtype)
+
+
+def _head_input(x, params, cfg: LlamaConfig):
+    """What the head reads of the stream out of the last layer: its
+    final RMSNorm, with ``dim_model_base`` over ``hidden_size /
+    dim_model_base`` (scope ``head.scale``)."""
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    if cfg.dim_model_base is not None:
+        with jax.named_scope("head.scale"):
+            x = _times(x, cfg.dim_model_base / cfg.hidden_size)
     return x
 
 
@@ -1868,7 +2125,7 @@ def hidden_states(
     x, aux, _, _ = _run_stack(
         params, tokens, cfg, attn_fn, constrain, expert_parallel
     )
-    return rms_norm(x, params["final_norm"], cfg.norm_eps), aux
+    return _head_input(x, params, cfg), aux
 
 
 def _head(params: Dict, cfg: LlamaConfig) -> jax.Array:
@@ -2192,7 +2449,7 @@ def _losses_and_counts(params, batch, cfg: LlamaConfig, attn_fn=None,
     )
     if counts is not None:
         counts = {"stack": counts}
-    normed = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    normed = _head_input(x, params, cfg)
     head = _head(params, cfg)
     ce = _mean_ce(normed, head, targets, cfg.loss_chunk)
     if not cfg.mtp_layers:
@@ -2463,8 +2720,10 @@ def flops_per_token(cfg: LlamaConfig, seq_len: int) -> float:
     """Approximate training FLOPs per token (6N_active + attention
     quadratic, at ``num_heads x head_dim`` and by each layer's kind;
     the convolution's taps are not counted, and of the gated delta
-    rule and of a state-space mixer the projections and low ranks
-    but not the recurrence), a
+    rule, of a state-space mixer and of a lightning layer the
+    projections and low ranks but not the recurrence; sparse
+    attention at the keys of a query's ``sparse_topk`` blocks, the
+    selection's own scores not counted), a
     prediction module's
     block and second pass through the head included. For MoE, only
     the top-k routed experts execute per token, so N counts k experts
@@ -2496,6 +2755,12 @@ def flops_per_token(cfg: LlamaConfig, seq_len: int) -> float:
         count * min(kind.window or seq_len, seq_len)
         for kind, count in kinds
         if kind.operator in ("full_attention", "latent_attention")
+    )
+    # a query's selected blocks, on a sequence long enough to select
+    keys += sum(
+        count * (seq_len if seq_len <= cfg.sparse_dense_len else min(
+            cfg.sparse_topk * cfg.sparse_block_size, seq_len))
+        for kind, count in kinds if kind.operator == "sparse_attention"
     )
     # a head's scores contract over q and k's width, its weighted
     # values are v's wide

@@ -38,7 +38,9 @@ def mha_reference(
     v: jax.Array,  # [batch, kv_len, kv_heads, v_head_dim]
     causal: bool = True,
     scale: Optional[float] = None,
-    mask: Optional[jax.Array] = None,  # bool [q_len, kv_len], True=keep
+    # bool [q_len, kv_len], or a kv head's own [batch, kv_heads,
+    # q_len, kv_len]; True=keep
+    mask: Optional[jax.Array] = None,
     return_lse: bool = False,
     window: Optional[int] = None,
     q_rope: Optional[jax.Array] = None,  # [batch, q_len, heads, rope_dim]
@@ -76,14 +78,18 @@ def mha_reference(
         mask = tril if mask is None else (mask & tril)
     elif window is not None:
         raise ValueError("a window is a causal band: causal=False")
+    def over_heads(mask):
+        """``mask`` over [b, kv_heads, group, q_len, kv_len]."""
+        return mask[None, None, None] if mask.ndim == 2 else mask[:, :, None]
+
     if mask is not None:
-        scores = jnp.where(mask[None, None, None], scores, NEG_INF)
+        scores = jnp.where(over_heads(mask), scores, NEG_INF)
     # explicit online-softmax form; p hard-zeroed under the mask so a
     # fully-masked row yields zeros (not the mean of V)
     m = jnp.max(scores, axis=-1, keepdims=True)
     p = jnp.exp(scores - m)
     if mask is not None:
-        p = jnp.where(mask[None, None, None], p, 0.0)
+        p = jnp.where(over_heads(mask), p, 0.0)
     l = jnp.sum(p, axis=-1, keepdims=True)
     l_safe = jnp.where(l == 0.0, 1.0, l)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", p / l_safe, vf)
@@ -111,6 +117,7 @@ def flash_attention(
     window: Optional[int] = None,
     q_rope: Optional[jax.Array] = None,
     k_rope: Optional[jax.Array] = None,
+    selected: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Memory-efficient attention: Pallas kernel on TPU; off the TPU
     (CPU tests, rehearsals) the dense reference, which no TPU run
@@ -132,11 +139,22 @@ def flash_attention(
     the key's one for every head. What a caller hands decides what
     runs: with the parts the kernels read them as they are, with whole
     q and k the kernels are the ones they always were.
+
+    ``selected`` (bool [batch, kv_heads, seq, blocks], causal only;
+    ops/sparse_attention.py makes it and calls this entry): query t
+    of every head of a kv head sees key j iff ``j <= t`` and it
+    selected j's block of ``seq / blocks`` adjacent keys. One more
+    operand of the same kernels, which no gradient reaches: a trace
+    names them, as every call of this function's, ``flash_attention``.
     """
     if not _use_pallas(q, k):
+        mask = None
+        if selected is not None:
+            mask = jnp.repeat(
+                selected, q.shape[1] // selected.shape[-1], axis=-1)
         return mha_reference(
             q, k, v, causal=causal, scale=scale, window=window,
-            q_rope=q_rope, k_rope=k_rope,
+            q_rope=q_rope, k_rope=k_rope, mask=mask,
         )
     from dlrover_tpu.ops.pallas.flash_attention import (
         flash_attention_tpu,
@@ -164,7 +182,7 @@ def flash_attention(
     )
     return flash_attention_tpu(
         q, k, v, causal=causal, scale=scale, block_q=bq, block_k=bk,
-        window=window, q_rope=q_rope, k_rope=k_rope,
+        window=window, q_rope=q_rope, k_rope=k_rope, selected=selected,
     )
 
 

@@ -103,6 +103,21 @@ group folds as ever. As read on a v5e (PERF.md section 6, PR 52), a
 call of 32 query heads on 4 with the window 2048, forward and backward:
 22.81 ms with the sequence's grid and whole blocks, 20.20 with the
 band's grid, 18.50 with the backward's tiles as well.
+
+A selection (``selected``, bool [batch, kv_heads, seq, seq / block]:
+whether a query, for every head of its kv head, sees a block of
+``block`` adjacent keys; ops/sparse_attention.py makes it) is one more
+mask, beside the diagonal's, that differs query by query. It reaches a
+kernel as one further ref of int32 words, ``[batch x kv_heads, key
+blocks of the grid, 1, seq]``: bit c of a query's word for a key block
+of the grid says whether it selected the c-th ``block`` keys of it
+(``_selection_words``), so a grid step reads ``block_q`` words, a
+column like ``lse``'s, and spreads them over the scores' columns by a
+shift (``_selected_mask``). The result is exact for the selection:
+every query takes its own word, no union over a tile. The blocks are
+taken whole (no sub-tiles) and the grid is the causal one: with seeded
+weights a query's free picks are near uniform over the earlier blocks
+and no (128, 512) tile is without one (PERF.md section 6, PR 64).
 """
 
 import functools
@@ -447,9 +462,57 @@ def _rows_of(r0, size, block_q):
 # and a kernel that holds a body a width meets many. The jaxpr is the
 # same.
 
-def _scores(q, k, scale, g, diagonal, window=None):
+def _selection_words(selected, block_k):
+    """``selected`` [rows, seq, seq / block] bool as the kernels read
+    it: int32 [rows, seq / block_k, 1, seq], bit c of word [r, j, 0,
+    t] whether query t of row r selected the c-th block of key block
+    j of the grid. The queries lie along the lanes, as ``lse``'s."""
+    rows, seq, blocks = selected.shape
+    per = blocks * block_k // seq  # selection blocks a key block
+    if per < 1 or per > 32 or (seq // blocks) * per != block_k:
+        raise ValueError(
+            f"a selection over {blocks} blocks of {seq} keys and key "
+            f"blocks of {block_k}: 1 to 32 whole blocks to a key block"
+        )
+    bits = selected.reshape(rows, seq, -1, per).astype(jnp.int32)
+    words = jnp.sum(bits << jnp.arange(per, dtype=jnp.int32), axis=-1)
+    return words.transpose(0, 2, 1)[:, :, None, :]
+
+
+def _selected_mask(words, cols, block):
+    """[rows, cols] bool from the rows' words [rows, 1]: column c is
+    of the key block's ``c // block``-th block, a power of two wide."""
+    shape = (words.shape[0], cols)
+    which = jax.lax.shift_right_logical(
+        jax.lax.broadcasted_iota(jnp.int32, shape, 1),
+        jnp.int32(block.bit_length() - 1),
+    )
+    picked = jax.lax.shift_right_logical(
+        jax.lax.broadcast_in_dim(words, shape, (0, 1)), which)
+    return jax.lax.ne(
+        jax.lax.bitwise_and(picked, jnp.int32(1)), jnp.int32(0))
+
+
+def _words_of(sel_ref, g, rows=slice(None)):
+    """A grid step's selection words as the g-major rows' column,
+    [g*rows, 1]: every head of the group reads its kv head's."""
+    column = sel_ref[0, 0, 0, rows][:, None]
+    return column if g == 1 else jnp.concatenate([column] * g, axis=0)
+
+
+def _selection(sel_ref, block, g, rows):
+    """What ``_scores`` takes of a grid step's selection; None for a
+    call without one."""
+    if sel_ref is None:
+        return None
+    return _words_of(sel_ref, g, rows), block
+
+
+def _scores(q, k, scale, g, diagonal, window=None, selection=None):
     """Scaled scores of g-major rows ``q`` over columns ``k``, masked
-    where ``diagonal`` gives their first (query, key) positions."""
+    where ``diagonal`` gives their first (query, key) positions, and
+    by ``selection`` (the rows' words and the selection's block:
+    ``_selected_mask``) where a call has one."""
     # bf16 x bf16 -> fp32 accumulate: the MXU's native mode. Casting
     # inputs to fp32 first would fall off the fast path (~4x slower).
     s = jax.lax.mul(jax.lax.dot_general(
@@ -461,6 +524,12 @@ def _scores(q, k, scale, g, diagonal, window=None):
         mask = (_causal_mask(*edges) if window is None
                 else _band_mask(*edges, window))
         s = jax.lax.select(mask, s, jax.lax.full_like(s, NEG_INF))
+    if selection is not None:
+        words, block = selection
+        s = jax.lax.select(
+            _selected_mask(words, k.shape[0], block), s,
+            jax.lax.full_like(s, NEG_INF),
+        )
     return s
 
 
@@ -568,7 +637,8 @@ def _query_block(band, j, i):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, scale, causal, g,
-                block_q, block_k, sub, band=None):
+                block_q, block_k, sub, band=None, sel_ref=None,
+                sel_block=None):
     i = pl.program_id(1)  # q block
     # k block (minor: sequential, scratch persists); with a band, the
     # row's j-th live one
@@ -589,6 +659,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         s = _scores(
             _stack_groups(q_ref, g, rows), _read(k_ref, (0, cols)), scale,
             g, diagonal, band and band.window,
+            _selection(sel_ref, sel_block, g, rows),
         )
         m_prev = m_scr[rows, :1]  # [g*size, 1]
         m_new = jax.lax.max(m_prev, _row_reduce(jax.lax.reduce_max, s))
@@ -655,13 +726,14 @@ def _grid(seq, block_q, block_k, band, by_key_blocks=False):
 
 
 def _kernel(body, name, seq, causal, g, block_q, block_k, q, scale,
-            band=None):
+            band=None, sel_block=None):
     """``(kernel, grid)``: ``body`` ("fwd", "dq", "dkv", "dqkv" or
     "dq_dkv" by ``name``) with its static arguments, for ``q`` whole
     or in parts, and a head's grid; building one sets the gauge of the
     parts, a causal one the census gauges. A windowed one walks its
     band in column tiles (``_walk``), another the diagonal in
-    ``_sub_tiles``."""
+    ``_sub_tiles``, one with a selection (``sel_block``, the keys of
+    a block of it) takes its blocks whole."""
     from dlrover_tpu.telemetry.registry import gauge
 
     gauge(
@@ -676,7 +748,8 @@ def _kernel(body, name, seq, causal, g, block_q, block_k, q, scale,
     sub = None
     if causal:
         if band is None:
-            sub = _sub_tiles(name, block_q, block_k, g, head_dim)
+            if sel_block is None:
+                sub = _sub_tiles(name, block_q, block_k, g, head_dim)
             tile = (sub or block_q, sub or block_k)
         else:
             tile = (block_q, band.tile)
@@ -690,7 +763,30 @@ def _kernel(body, name, seq, causal, g, block_q, block_k, q, scale,
     )
     if band is not None:
         static["band"] = band
+    if sel_block is not None:
+        static["sel_block"] = sel_block
     return functools.partial(body, **static), grid
+
+
+def _selecting(kernel, operands):
+    """``kernel`` for a call whose ref after its ``operands`` others
+    is the selection's words: handed on as ``sel_ref``."""
+
+    def body(*refs):
+        return kernel(
+            *refs[:operands], *refs[operands + 1:], sel_ref=refs[operands]
+        )
+
+    return body
+
+
+def _selection_of(selected, seq, block_k):
+    """``(the words the kernels read, the keys of a selection's
+    block)`` of ``selected`` [rows, seq, blocks]; two Nones of None."""
+    if selected is None:
+        return None, None
+    return (_selection_words(selected, block_k),
+            seq // selected.shape[-1])
 
 
 def _kv_index(causal, block_q, block_k, band=None):
@@ -743,7 +839,8 @@ def _k_specs(k, bkh, block_k, index):
     return _parts_of(spec, k)
 
 
-def _fwd(q, k, v, scale, causal, block_q, block_k, window=None):
+def _fwd(q, k, v, scale, causal, block_q, block_k, window=None,
+         selected=None):
     """q: [bk_h, g, seq, d]; k,v: [bk_h, seq, d] ->
     (o [bk_h, g, seq, dv], lse [bk_h, g, 1, seq] f32). ``v`` may be
     narrower or wider than q and k (``dv``): the scores contract over
@@ -755,11 +852,15 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, window=None):
     block_k = min(block_k, seq)
     _check_blocks(seq, block_q, block_k)
     band = _band_of("fwd", seq, block_q, block_k, window)
+    words, sel_block = _selection_of(selected, seq, block_k)
     kernel, grid = _kernel(
         _fwd_kernel, "fwd", seq, causal, g, block_q, block_k, q, scale,
-        band,
+        band, sel_block,
     )
     kv_idx = _kv_index(causal, block_q, block_k, band)
+    selection = ()
+    if words is not None:
+        kernel, selection = _selecting(kernel, 3), (words,)
     return pl.pallas_call(
         kernel,
         grid=(bkh, *grid),
@@ -767,6 +868,11 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, window=None):
             _specs(q, (g, block_q), lambda b, i, j: (b, 0, i, 0)),
             _k_specs(k, bkh, block_k, kv_idx),
             pl.BlockSpec((1, block_k, dv), kv_idx),
+        ] + [
+            # the query block's words for the step's key block
+            pl.BlockSpec((1, 1, 1, block_q),
+                         lambda b, i, j: (b, kv_idx(b, i, j)[1], 0, i))
+            for _ in selection
         ],
         out_specs=[
             pl.BlockSpec((1, g, block_q, dv), lambda b, i, j: (b, 0, i, 0)),
@@ -784,7 +890,7 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, window=None):
             pltpu.VMEM((g * block_q, dv), jnp.float32),
         ],
         interpret=_interpret(),
-    )(q, k, v)
+    )(q, k, v, *selection)
 
 
 # ---------------------------------------------------------------------------
@@ -818,11 +924,11 @@ def _q_side(q_ref, do_ref, lse_ref, delta_ref, g, rows):
     )
 
 
-def _p(q, lse, k, scale, g, diagonal, window=None):
+def _p(q, lse, k, scale, g, diagonal, window=None, selection=None):
     """The softmax re-derived from the saved logsumexp: [g*rows, cols]."""
-    return jax.lax.exp(
-        jax.lax.sub(_scores(q, k, scale, g, diagonal, window), lse)
-    )
+    return jax.lax.exp(jax.lax.sub(
+        _scores(q, k, scale, g, diagonal, window, selection), lse
+    ))
 
 
 def _ds(p, do, v, delta):
@@ -836,7 +942,7 @@ def _ds(p, do, v, delta):
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                acc_scr, *, scale, causal, g, block_q, block_k, sub,
-               add_dkv=None, band=None):
+               add_dkv=None, band=None, sel_ref=None, sel_block=None):
     """``add_dkv(cols, p, ds, q, do)``, where given, takes the softmax
     and each dS the walk forms (cast for the products) of the block's
     key positions ``cols`` on to dV and dK: ``_dq_dkv_kernel``."""
@@ -857,7 +963,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             q_ref, do_ref, lse_ref, delta_ref, g, rows
         )
         k = _cols_of(k_ref, cols)
-        p = _p(q, lse, k(), scale, g, diagonal, band and band.window)
+        p = _p(q, lse, k(), scale, g, diagonal, band and band.window,
+               _selection(sel_ref, sel_block, g, rows))
         ds = jax.lax.convert_element_type(
             _ds(p, do, v_ref[0, cols], delta), q.dtype
         )
@@ -881,7 +988,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr,
                 *, scale, causal, g, block_q, block_k, sub, add_dq=None,
-                band=None):
+                band=None, sel_ref=None, sel_block=None):
     """``add_dq(r0, size, k, ds)``, where given, takes each dS the
     walk forms (cast for the products) and the read of its key
     positions (``_cols_of``) on to dQ: ``_dqkv_kernel``."""
@@ -905,7 +1012,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             q_ref, do_ref, lse_ref, delta_ref, g, rows
         )
         k = _cols_of(k_ref, cols)
-        p = _p(q, lse, k(), scale, g, diagonal, band and band.window)
+        p = _p(q, lse, k(), scale, g, diagonal, band and band.window,
+               _selection(sel_ref, sel_block, g, rows))
         if sub is not None:  # dS first where sub-tiles are walked (above)
             ds = _ds(p, do, v_ref[0, cols], delta)
         # dV += P^T @ dO — contracting over the g*size rows also sums
@@ -1102,7 +1210,7 @@ def _dq_resident_vmem_bytes(seq, head_dim, itemsize):
 
 
 def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
-         window=None):
+         window=None, selected=None):
     from dlrover_tpu.telemetry.registry import gauge
 
     bkh, g, seq, _ = _each(q)[0].shape
@@ -1128,11 +1236,15 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
     ).labels(form=form).set(2 if form == "pair" else 1)
 
     band = _band_of("bwd", seq, block_q, block_k, window)
+    words, sel_block = _selection_of(selected, seq, block_k)
+    selection = () if words is None else (words,)
 
     def build(body, name):
-        return _kernel(
-            body, name, seq, causal, g, block_q, block_k, q, scale, band
+        kernel, grid = _kernel(
+            body, name, seq, causal, g, block_q, block_k, q, scale, band,
+            sel_block,
         )
+        return (_selecting(kernel, 6) if selection else kernel), grid
 
     def shapes(x, *rows):
         """dQ's or dK's, ``rows`` a head; of k in parts each part a kv
@@ -1168,6 +1280,11 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
                 pl.BlockSpec((1, g, block_q, dv), q_idx),
                 pl.BlockSpec((1, g, 1, block_q), lse_idx),
                 pl.BlockSpec((1, g, 1, block_q), lse_idx),
+            ] + [
+                pl.BlockSpec(
+                    (1, 1, 1, block_q),
+                    lambda b, i, j: (b, kv_idx(b, i, j)[1], 0, i))
+                for _ in selection
             ],
             out_specs=[_specs(q, (g, block_q), q_idx)] + resident * [
                 _specs(k, (seq,), lambda b, i, j: (b, 0, 0)),
@@ -1188,7 +1305,7 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
                     seq, max(d, dv), itemsize)
             ) if resident else None,
             interpret=_interpret(),
-        )(q, k, v, do, lse, delta)
+        )(q, k, v, do, lse, delta, *selection)
 
     def q_side_idx(sublane):
         """Q/dO/lse/delta block index for dkv's (b, j, i) grid, clamped
@@ -1227,6 +1344,12 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
                 pl.BlockSpec((1, g, block_q, dv), q_side_idx(True)),
                 pl.BlockSpec((1, g, 1, block_q), q_side_idx(False)),
                 pl.BlockSpec((1, g, 1, block_q), q_side_idx(False)),
+            ] + [
+                pl.BlockSpec(
+                    (1, 1, 1, block_q),
+                    lambda b, j, i: (
+                        b, j, 0, q_side_idx(False)(b, j, i)[3]))
+                for _ in selection
             ],
             out_specs=resident * [
                 _specs(q, (1, seq), lambda b, j, i: (b, 0, 0, 0)),
@@ -1247,7 +1370,7 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
                     seq, d, itemsize)
             ) if resident and seq * d * 4 > DQ_UNSTATED_BYTES else None,
             interpret=_interpret(),
-        )(q, k, v, do, lse, delta)
+        )(q, k, v, do, lse, delta, *selection)
 
     if form == "dkv_resident":
         return by_query_blocks(True)
@@ -1259,23 +1382,29 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
 # ---------------------------------------------------------------------------
 # public wrapper with custom VJP
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_gqa(q, k, v, scale, causal, block_q, block_k, window=None):
-    o, _ = _fwd(q, k, v, scale, causal, block_q, block_k, window)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_gqa(q, k, v, selected, scale, causal, block_q, block_k,
+               window=None):
+    """``selected``: None, or the selection [rows, seq, blocks] bool,
+    an operand that no gradient reaches."""
+    o, _ = _fwd(q, k, v, scale, causal, block_q, block_k, window, selected)
     return o
 
 
-def _flash_fwd_rule(q, k, v, scale, causal, block_q, block_k, window):
-    o, lse = _fwd(q, k, v, scale, causal, block_q, block_k, window)
-    return o, (q, k, v, o, lse)
+def _flash_fwd_rule(q, k, v, selected, scale, causal, block_q, block_k,
+                    window):
+    o, lse = _fwd(
+        q, k, v, scale, causal, block_q, block_k, window, selected)
+    return o, (q, k, v, selected, o, lse)
 
 
 def _flash_bwd_rule(scale, causal, block_q, block_k, window, res, do):
-    q, k, v, o, lse = res
+    q, k, v, selected, o, lse = res
     dq, dk, dv = _bwd(
-        q, k, v, o, lse, do, scale, causal, block_q, block_k, window
+        q, k, v, o, lse, do, scale, causal, block_q, block_k, window,
+        selected,
     )
-    return dq, jax.tree.map(_summed_over_its_heads, dk, k), dv
+    return dq, jax.tree.map(_summed_over_its_heads, dk, k), dv, None
 
 
 def _summed_over_its_heads(dk, k):
@@ -1306,6 +1435,7 @@ def flash_attention_tpu(
     window: Optional[int] = None,
     q_rope: Optional[jax.Array] = None,  # [batch, seq, heads, rope_dim]
     k_rope: Optional[jax.Array] = None,  # [batch, seq, 1, rope_dim]
+    selected: Optional[jax.Array] = None,  # [batch, kv_heads, seq, blocks]
 ) -> jax.Array:
     """Flash attention in the models' [batch, seq, heads, head_dim]
     layout; GQA folded into the kernels' matmul rows (no KV repeat).
@@ -1319,7 +1449,13 @@ def flash_attention_tpu(
     every head: the kernels read the parts and put a block's tile
     together in VMEM, so neither the whole q and k nor a copy of
     ``k_rope`` a head is ever in memory, and the gradients come back
-    in the same parts. ``head_dim`` is then the two widths' sum."""
+    in the same parts. ``head_dim`` is then the two widths' sum.
+
+    ``selected`` (bool, causal only and without a window): query t
+    sees key j iff ``j <= t`` and ``selected[batch, kv head, t, j //
+    block]``, ``block = seq / blocks`` a power of two that divides
+    ``block_k``; every head of a kv head has its selection. No
+    gradient reaches it."""
     b, s, h, d = q.shape
     if (q_rope is None) != (k_rope is None):
         raise ValueError("q_rope and k_rope come together or not at all")
@@ -1337,6 +1473,17 @@ def flash_attention_tpu(
             )
         if window >= s:
             window = None
+    if selected is not None:
+        block = s // selected.shape[-1]
+        if (not causal or window is not None or block & (block - 1)
+                or selected.shape != (b, k.shape[2], s, s // block)):
+            raise ValueError(
+                f"selected {selected.shape} for q {q.shape}, k {k.shape}, "
+                f"causal={causal}, window={window}: a causal call "
+                "without a window, [batch, kv_heads, seq, blocks] of "
+                "a power of two keys each"
+            )
+        selected = selected.reshape(-1, *selected.shape[2:])
     kvh = k.shape[2]
     g = h // kvh
     scale = scale if scale is not None else d ** -0.5
@@ -1351,7 +1498,8 @@ def flash_attention_tpu(
     if q_rope is not None:
         qg, kg = (qg, q_layout(q_rope)), (kg, k_rope[:, :, 0])
     o = _flash_gqa(
-        qg, kg, kv_layout(v), scale, causal, block_q, block_k, window,
+        qg, kg, kv_layout(v), selected, scale, causal, block_q, block_k,
+        window,
     )
     return o.reshape(b, h, s, v.shape[3]).transpose(0, 2, 1, 3)
 

@@ -157,7 +157,13 @@ def _tile(refs, j, p):
     x = x_ref[:, lanes].astype(F32)
     dt = _spread([dt_ref[:, h:h + 1] for h in heads], p)
     cum = _spread([cum_ref[:, h:h + 1] for h in heads], p)
-    last = cum[CHUNK - 1:]
+    # the last row as a masked sum over the sublanes: where a tile is
+    # one head (a lightning layer's 128-wide heads) ``cum`` is one
+    # column spread over the lanes and its last row, sliced, one
+    # number, which the chip's compiler does not spread over sublanes
+    # and lanes at once
+    at_last = _places(cum.shape)[0] == CHUNK - 1
+    last = jnp.sum(jnp.where(at_last, cum, 0.0), axis=0, keepdims=True)
     return dict(
         lanes=lanes, heads=heads, x=x, dt=dt, cum=cum, u=dt * x,
         last=last, to_end=jnp.exp(last - cum),

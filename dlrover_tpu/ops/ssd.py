@@ -30,6 +30,17 @@ forward keeps when it is differentiated). Elsewhere ``ssd_plain``: the
 equations above under a ``lax.scan`` over chunks, differentiated by
 JAX. float32 inside both, whatever the operands' dtype. A sequence is
 a row of the batch: the state starts at zero at its first position.
+
+Linear attention with a fixed decay a head (a lightning layer of
+``models/llama.py``: ``S_t = exp(-m_h) S_{t-1} + k_t v_t^T``, ``o_t =
+S_t^T q_t / sqrt(d)``) is this scan in a regime of its own, and is
+called, not copied: ``x = v``, ``B = k``, ``C = q / sqrt(d)``,
+``Delta = 1``, ``A_h = -m_h``, ``D = 0``, one head a group (``heads =
+groups``), ``p = n = d``. The kernels take it where ``d`` is a lane
+tile's 128 (``tiles_the_kernel``; a grid step is then one head, its
+state [128, 128]); the constant step and the zero skip are read and
+multiplied like any other, which a leaner entry could spare (PERF.md
+section 7).
 """
 
 import jax

@@ -364,6 +364,14 @@ def make_trainer_for_llama(
         "gradients to the backward's own progress, else 0",
     ).set(int(gathered_weights))
     operator_layers = llama.operator_layers(cfg)
+    alone = set(llama.ONE_DEVICE_OPERATORS) & set(operator_layers)
+    if mesh.size > 1 and alone:
+        raise ValueError(
+            f"{sorted(alone)} layers take a device's whole sequences "
+            "through kernels that no shard_map wraps yet: a mesh of "
+            f"{dict(mesh.shape)}, were it over the batch alone, would "
+            "hand the partitioner a kernel, and is refused, not guessed"
+        )
     layers_gauge = gauge(
         "dlrover_model_operator_layers",
         "layers of the trainer's model whose operator is `operator`, "

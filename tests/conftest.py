@@ -119,8 +119,19 @@ MODULE_BUDGET_OVERRIDES = {
     # effort, 70s of its own; since PR 52 the windowed kernels at
     # trinity's shape as well, two compiles of 12s: 965s beside five
     # other workers; since PR 54 nemotron's eleven-layer step at the
-    # least effort, 50-60s of its own, and the scan's kernels alone
-    "test_chip_compile": 1200.0,
+    # least effort, 50-60s of its own, and the scan's kernels alone;
+    # since PR 64 sala's four-layer step at the least effort, 30s of
+    # its own: 1,265s beside five other workers on a loaded machine
+    "test_chip_compile": 1350.0,
+    # the sala family's program against its reference, whose
+    # selection walks whole score arrays and whose recurrence a
+    # position at a time, with seventeen edited references jitted
+    # anew (PR 64): 44s alone, 76s beside five other workers
+    "test_yardstick_sala": 120.0,
+    # a four-layer stack of the selection and the lightning layers
+    # jitted forward and backward, each factor, norm, gate and
+    # rotation changed in turn (PR 64): 73s beside five other workers
+    "test_llama_sala": 120.0,
     # the state-space scan's Pallas kernels in interpret mode, forward
     # and backward on seven shapes (PR 54): 40s alone, 85s beside
     # three other workers

@@ -594,6 +594,70 @@ def test_dispatch_lowers_the_rules_kernels_whatever_the_environment(
     assert tuning.last_selection()["source"] == "static"
 
 
+#: the same digests for the calls ``WHOLE_KERNELS`` does not make,
+#: a window and q and k in parts, each at a cell's shape ([batch, seq,
+#: heads, kv_heads, head_dim], then the window or the rotated part's
+#: width): what commit 2ce30bb (PR 63) lowers, read from a copy of it
+#: beside PR 64's tree, which hands every kernel one more operand
+#: where a caller has a selection and only there
+UNSELECTED_KERNELS = {
+    "smallthinker.window": (
+        (1, 16384, 28, 4, 128), {"window": 4096},
+        ("b44903697fa71161", "8c0685c0e24217c2")),
+    "trinity-mini.window": (
+        (1, 16384, 32, 4, 128), {"window": 2048},
+        ("44e4aa29da87ae3c", "920bd8b32af8dc47")),
+    # one backward kernel, a head's dQ resident
+    "joyai.parts": (
+        (4, 8192, 32, 32, 128), {"rope": 64},
+        ("4f74afc876050aca", "c830cd5e967c2955")),
+    # the dq and dk/dv pair
+    "kimi.parts": (
+        (1, 16384, 32, 32, 128), {"rope": 64},
+        ("1fbf08f00fce77f2", "ee597869148835bc", "37307a7630892ff4")),
+}
+
+
+@pytest.mark.parametrize("call", list(UNSELECTED_KERNELS))
+def test_a_call_without_a_selection_lowers_the_kernels_it_did(
+    topo, on_tpu_path, call
+):
+    """A windowed call and one with q and k in parts, ``selected``
+    None: the forward's and the backward's Mosaic modules are the
+    parent's, so the selection's operand costs the cells that have
+    none nothing."""
+    (batch, seq, heads, kv_heads, d), kind, want = UNSELECTED_KERNELS[call]
+    bq, bk = tuning.heuristic_blocks(seq, heads // kv_heads)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def shaped(h, width):
+        return jax.ShapeDtypeStruct(
+            (batch, seq, h, width), jnp.bfloat16, sharding=one_chip)
+
+    args = [shaped(heads, d), shaped(kv_heads, d), shaped(kv_heads, d)]
+    if "rope" in kind:
+        args += [shaped(heads, kind["rope"]), shaped(1, kind["rope"])]
+
+        def attn(q, k, v, q_rope, k_rope):
+            return fa.flash_attention_tpu(
+                q, k, v, causal=True, block_q=bq, block_k=bk,
+                q_rope=q_rope, k_rope=k_rope,
+                scale=(d + kind["rope"]) ** -0.5)
+    else:
+        def attn(q, k, v):
+            return fa.flash_attention_tpu(
+                q, k, v, causal=True, block_q=bq, block_k=bk,
+                window=kind["window"])
+
+    _, modules = _lowered_kernels(jax.grad(
+        lambda *operands: attn(*operands).astype(jnp.float32).sum(),
+        argnums=tuple(range(len(args)))), *args)
+    digests = tuple(
+        hashlib.sha256(module.encode()).hexdigest()[:16]
+        for module in modules)
+    assert digests == want, digests
+
+
 def _count_equations(jaxpr):
     """Equations of a jaxpr and of every jaxpr inside it (loop bodies,
     branches)."""
@@ -1875,6 +1939,103 @@ def test_nemotron_step_holds_the_scans_kernels(topo, on_tpu_path, monkeypatch):
     for scope in ("ssm.in_proj", "ssm.dt", "ssm.gate_norm", "ssm.out_proj",
                   "moe.latent_down", "moe.latent_up", "moe.shared",
                   "mtp.block", "attn.full"):
+        assert scope in text, scope
+
+
+#: ``peak_memory_in_bytes`` of ``minicpm-sala-9b-vp8.steady``'s step as
+#: this file compiles it (1 x 16,384, four layers, an eighth of the
+#: vocabulary, remat ``minimal``, the least effort; PERF.md, PR 64):
+#: 7.11 GB of it the state. 12,682,685,440 at the default effort,
+#: which the chip compiles at (the file's ``depth``); 12,989,689,856
+#: while the selection's ``top_k`` was the compiler's sort
+SALA_STEP_BYTES = 12_686_945_280
+
+
+def test_sala_step_holds_the_selections_and_the_scans_kernels(
+    topo, on_tpu_path, monkeypatch
+):
+    """``minicpm-sala-9b-vp8.steady``'s step: it fits under 15.75 GiB
+    and plans no more than was read when the cell was built; the one
+    selected-attention layer's kernels (the forward, the forward again
+    under ``minimal`` and the one backward kernel, a kv head's dK and
+    dV resident at a group of 16) are named as ``attn_kernel_ms``
+    tells them and carry ``sparse.attn``, with the selection's words
+    ``s32[2, 32, 1, 16384]`` among their operands; the three lightning
+    layers' scans (each the forward, the forward again and the
+    backward over ``[1, 32, 128, 128, 128]`` entry states) are named
+    as ``ssd_ms`` tells them, and as no other reader does, and carry
+    ``lightning.scan``; every call of the two entries took the
+    kernels; no array of the compressed scores is whole in the step
+    (``[32 or 16 heads, 16384, 1023]``), nor the sixteen chunks'
+    masks; and every scope of the two operators and the three factors
+    is in the text."""
+    from dlrover_tpu.ops import ssd
+    from dlrover_tpu.ops.pallas import ssd as scan_kernels
+    from dlrover_tpu.telemetry.registry import counter, gauge
+    from yardstick import cells, worker
+    from yardstick.layer_metrics import (
+        attn_kernel_ms, delta_rule_ms, moe_expert_ms, short_conv_ms, ssd_ms,
+    )
+
+    monkeypatch.setattr(
+        ssd, "_use_pallas", lambda x, B, heads, groups: (
+            scan_kernels.tiles_the_kernel(x.shape, B.shape, heads, groups)))
+    monkeypatch.setattr(scan_kernels, "_interpret", lambda: False)
+    calls = [counter(f"{entry}_calls", "") for entry in (
+        "sparse_attention_kernel", "sparse_attention_plain",
+        "ssd_kernel", "ssd_plain")]
+    before = [c.value for c in calls]
+    _, config, traffic = cells.load_cell("minicpm-sala-9b-vp8.steady")
+    cfg = worker.program_config(config, traffic)
+    assert (cfg.remat, cfg.loss_chunk) == ("minimal", 0)
+    assert tuning.heuristic_blocks(16384, 16) == (128, 512)
+    assert fa._one_backward_kernel(16, 16384, 128)
+    mesh = Mesh(
+        np.array(topo.devices[:1]).reshape(1, 1), ("data", "fsdp"))
+    trainer = make_trainer_for_llama(
+        cfg, mesh, strategy=traffic["strategy"],
+        optimizer=optax.adamw(traffic["optimizer"]["learning_rate"]),
+    )
+    compiled = trainer.train_step.lower(*_abstract_step_args(
+        trainer, traffic["global_batch"], traffic["seq"])
+    ).compile(LEAST_EFFORT)
+    planned = compiled.memory_analysis().peak_memory_in_bytes
+    print("sala step plans", planned)
+    assert planned <= SALA_STEP_BYTES < 15.75 * 2 ** 30
+    text = compiled.as_text()
+    kernels = re.findall(
+        r"%([\w.\-]+) = ([^\n]*?) custom-call\("
+        r"([^\n]*custom_call_target=\"tpu_custom_call\"[^\n]*)"
+        r"op_name=\"([^\"]*)\"", text)
+    attend = [(name, operands, op) for name, _, operands, op in kernels
+              if attn_kernel_ms.KERNEL.search(name)]
+    assert len(attend) == 3, [name for name, _, _ in attend]
+    assert all("sparse.attn" in op for _, _, op in attend)
+    assert all("s32[2,32,1,16384]" in operands for _, operands, _ in attend)
+    scan = [(name, op) for name, _, _, op in kernels
+            if ssd_ms.KERNEL.search(name)]
+    assert len(scan) == 3 * 3, [name for name, _ in scan]
+    assert all("lightning.scan" in op for _, op in scan)
+    assert len(kernels) == 12  # and no other kernel
+    for name, _, _, _ in kernels:
+        readers = [r for r in (attn_kernel_ms, delta_rule_ms, moe_expert_ms,
+                               short_conv_ms, ssd_ms)
+                   if r.KERNEL.search(name)]
+        assert len(readers) == 1, name
+    # the chunks' entry states: [batch, heads, chunks, 128, 128]
+    assert "f32[1,32,128,128,128]" in text
+    # nothing of the compressed scores whole, in any layout
+    assert not re.search(r"\[(\d+,)*(32|16),16384,1023\]", text)
+    assert not re.search(r"\[(\d+,)*16384,1023(,\d+)*\]", text)
+    assert not re.search(r"\[16,1,2,16,1024,1023\]", text)
+    assert re.search(r"f32\[1,2,16,1024,\d+\]", text)  # a chunk's are
+    assert [c.value - was for c, was in zip(calls, before)] == [
+        1, 0, 3, 0]
+    assert gauge("ssd_heads_per_step", "").value == 1
+    assert tuning.last_selection()["gqa_group"] == 16
+    for scope in ("sparse.compress", "sparse.select", "sparse.attn",
+                  "lightning.proj", "lightning.scan", "lightning.out",
+                  "embed.scale", "branch.scale", "head.scale", "attn.gate"):
         assert scope in text, scope
 
 

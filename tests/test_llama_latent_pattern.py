@@ -301,8 +301,11 @@ def test_the_trainer_steps_sets_the_gauge_and_every_operator_moves():
     trainer = make_trainer_for_llama(
         cfg, mesh, strategy="fsdp", optimizer=optax.adamw(1e-2))
     layers = gauge("dlrover_model_operator_layers", "", ("operator",))
-    assert [layers.labels(operator=o).value for o in llama.OPERATORS] == [
-        0, 1, 4, 0, 0, 0]
+    # by name, wherever an operator stands in the list
+    assert {o: layers.labels(operator=o).value
+            for o in llama.OPERATORS} == {
+        **dict.fromkeys(llama.OPERATORS, 0),
+        "latent_attention": 1, "linear_attention": 4}
     params, opt_state = trainer.init(jax.random.key(0))
     before = jax.tree.map(np.asarray, params)
     tokens, targets = _batch(cfg, shape=(8, 32))
@@ -328,8 +331,9 @@ def test_the_trainer_steps_sets_the_gauge_and_every_operator_moves():
     make_trainer_for_llama(
         llama.llama_tiny(), mesh, strategy="fsdp",
         optimizer=optax.adamw(1e-2))
-    assert [layers.labels(operator=o).value for o in llama.OPERATORS] == [
-        2, 0, 0, 0, 0, 0]
+    assert {o: layers.labels(operator=o).value
+            for o in llama.OPERATORS} == {
+        **dict.fromkeys(llama.OPERATORS, 0), "full_attention": 2}
 
 
 def test_flops_per_token_counts_the_latent_layers_scores_alone():

@@ -146,6 +146,30 @@ def test_the_kernels_take_one_and_four_heads_a_lane_tile(p):
         close(g, w, 1e-3, name)
 
 
+def test_the_kernels_take_a_lightning_layers_regime():
+    """One head a group, 128 values and 128 states a head, a step of
+    one, a constant rate a head and no skip: a lightning layer's call
+    (``models/llama.py``), through the kernels against the recurrence
+    walked a position at a time, forward and in q's, k's and v's
+    gradients."""
+    heads = 4
+    assert kernels.tiles_the_kernel(
+        (1, 16384, 32 * 128), (1, 16384, 32 * 128), 32, 32)
+    x, B, C, _, _, _ = operands(heads=heads, groups=heads, p=128, b=1)
+    dt = jnp.ones(x.shape[:3])
+    A = -jnp.exp2(-8.0 * jnp.arange(1, heads + 1) / heads)
+    ops = (x, B, C * 128 ** -0.5, dt, A, jnp.zeros((heads,)))
+    want = recurrence(*ops)
+    close(through_kernels(*ops), want, 2e-5, "o")
+    weights = jax.random.normal(jax.random.key(9), x.shape)
+    for name, g, w in list(zip(
+            NAMES, gradients(through_kernels, ops, weights),
+            gradients(recurrence, ops, weights)))[:3]:
+        close(g, w, 1e-3, name)
+    assert gauge("ssd_heads_per_step", "").value == 1
+    assert gauge("ssd_state_bytes", "").value == 128 * 128 * 4
+
+
 def test_the_kernels_in_bfloat16_round_once():
     """bfloat16 rows in, bfloat16 rows out, float32 inside: within
     bfloat16's own resolution of the float32 result."""
