@@ -129,7 +129,9 @@ def flash_attention(
     ``block_q``/``block_k`` cap the kernel block sizes; the GQA group
     folds into the kernel's matmul rows, so the effective q-block is
     ``group * block_q`` rows. The blocks are ``ops/tuning.py``'s static
-    rule on the shapes, and ``tuning.last_selection()`` names them.
+    rules on the shapes (the pair, and past a group of 8 a wider key
+    block for the forward kernel), and ``tuning.last_selection()``
+    names them and the form the backward takes.
     ``window`` (causal only, static in the kernel): query i sees key
     j iff ``j <= i`` and ``i - j < window``.
 
@@ -157,6 +159,7 @@ def flash_attention(
             q_rope=q_rope, k_rope=k_rope, mask=mask,
         )
     from dlrover_tpu.ops.pallas.flash_attention import (
+        backward_form,
         flash_attention_tpu,
     )
 
@@ -170,11 +173,18 @@ def flash_attention(
             f"block_q={block_q} block_k={block_k}"
         )
     bq, bk = blocks
+    fwd_bk = tuning.forward_key_block(
+        seq, group, blocks, block_k, window,
+        None if selected is None else seq // selected.shape[-1],
+    )
     head_dim = q.shape[3] + (0 if q_rope is None else q_rope.shape[3])
     tuning.record(
         kernel="flash_attention", seq=seq, head_dim=head_dim,
         gqa_group=group, dtype=jnp.dtype(q.dtype).name, causal=causal,
         block_q=bq, block_k=bk, window=window,
+        backward=backward_form(group, seq, max(head_dim, v.shape[3])),
+        # the forward kernel's key block where it has one of its own
+        **({} if fwd_bk == bk else {"fwd_block_k": fwd_bk}),
         # v's width where it is not q and k's (latent attention), and
         # how many of q and k's columns came as rotated parts
         **({} if v.shape[3] == head_dim else {"v_head_dim": v.shape[3]}),
@@ -183,6 +193,7 @@ def flash_attention(
     return flash_attention_tpu(
         q, k, v, causal=causal, scale=scale, block_q=bq, block_k=bk,
         window=window, q_rope=q_rope, k_rope=k_rope, selected=selected,
+        fwd_block_k=fwd_bk,
     )
 
 

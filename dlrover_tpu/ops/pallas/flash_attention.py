@@ -30,9 +30,20 @@ smaller one is a head's dQ: the dK/dV kernel with one product more,
 dQ += dS K on the dS it has formed (``_dqkv_kernel``). With a group dQ
 is g times a head's and the smaller ones are the kv head's dK and dV,
 [seq, head_dim] whatever g is: the dQ kernel with two products more,
-dK += dS^T Q and dV += P^T dO (``_dq_dkv_kernel``), which states the
-VMEM it needs (``_dkv_resident_vmem_bytes``). The pair is what a head
-too long for either budget keeps.
+dK += dS^T Q and dV += P^T dO (``_dq_dkv_kernel``). Either states the
+VMEM it needs (``_dq_resident_vmem_bytes``,
+``_dkv_resident_vmem_bytes``) and both live by one budget,
+``RESIDENT_BYTES`` of float32 in rows of whole lanes: 16,384
+positions of a 192-wide head's dQ, or of a 128-wide kv head's dK and
+dV. The pair is what a head too long for it keeps.
+
+The kernels of a call need not share a key block: the backward's run
+the pair of ``ops/tuning.py heuristic_blocks``, the forward
+``forward_key_block``, which past a group of 8 is wider than the
+pair's (its grid step is bound by its rows, and 128 rows a head of 16
+leave the pair 512 columns), and states what its scores take
+(``_fwd_vmem_bytes``). A windowed call's band and a selection's words
+are each kernel's own, made from its own key block.
 
 What a causally live grid step computes (``_walk``). Blocks wholly
 above the diagonal are skipped by the grid (``pl.when`` + the clamped
@@ -60,9 +71,9 @@ v may be narrower or wider than q and k (latent attention: q and k
 contract over q and k's width, the forward's accumulator, o, dO and
 dV are v's wide, dQ and dK q's. A block's last dimension is then the
 array's whole width, a lane and a half at 192. The one-kernel rule
-reads the wider of the two; a head's resident dQ of 8,192 x 192 is
-past the default scoped limit's room, and that call states what it
-takes as the grouped one does.
+reads the wider of the two, in whole lanes; a head's resident dQ of
+8,192 or 16,384 x 192 is past the default scoped limit's room, and
+that call states what it takes as the grouped one does.
 
 q and k may each come in two parts (latent attention: a head's 128
 un-rotated and 64 rotated columns, the products that make them being
@@ -127,6 +138,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from dlrover_tpu.ops import tuning
 
 NEG_INF = -1e30
 LANES = 128
@@ -469,7 +482,8 @@ def _selection_words(selected, block_k):
     j of the grid. The queries lie along the lanes, as ``lse``'s."""
     rows, seq, blocks = selected.shape
     per = blocks * block_k // seq  # selection blocks a key block
-    if per < 1 or per > 32 or (seq // blocks) * per != block_k:
+    if (per < 1 or per > tuning.WORD_BITS
+            or (seq // blocks) * per != block_k):
         raise ValueError(
             f"a selection over {blocks} blocks of {seq} keys and key "
             f"blocks of {block_k}: 1 to 32 whole blocks to a key block"
@@ -743,6 +757,14 @@ def _kernel(body, name, seq, causal, g, block_q, block_k, q, scale,
         "rotated columns apart, the rotated key one for every head)",
         labelnames=("kernel",),
     ).labels(kernel=name).set(len(_each(q)))
+    gauge(
+        "attn_key_block",
+        "key positions of a grid block of an attention kernel, at the "
+        "last one built: the forward's (fwd) beside the backward's, "
+        "whose kernels name the form it took (dqkv: a head's dq "
+        "resident; dq_dkv: a kv head's dk and dv; dq and dkv: the pair)",
+        labelnames=("kernel",),
+    ).labels(kernel=name).set(block_k)
     head_dim = _width(q)
     grid = _grid(seq, block_q, block_k, band, name in ("dkv", "dqkv"))
     sub = None
@@ -839,6 +861,34 @@ def _k_specs(k, bkh, block_k, index):
     return _parts_of(spec, k)
 
 
+#: the float32 scores and ``p`` of a grid step that fit beside the
+#: rest under the default scoped limit, 16 MiB of a v5e core's 128:
+#: ``ops/tuning.py ROWS_CAP`` rows by 1024 columns, which every pair of
+#: ``heuristic_blocks`` is within. A forward call within it is the one
+#: it always was; past it the call states what it needs
+UNSTATED_SCORE_BYTES = tuning.score_bytes(1, tuning.ROWS_CAP, 1024)
+
+
+def _fwd_vmem_bytes(rows, block_k, d, dv, itemsize):
+    """What the forward kernel asks of VMEM where it has to ask
+    (``ops/tuning.py forward_key_block``: a group of 16's [2048, 1024]
+    scores), None where it does not: a grid step's float32 scores and
+    ``p`` (``tuning.score_bytes``); the streamed blocks of q, k, v
+    and o, two buffers each, rows of whole lanes; and the rows'
+    state, ``m``, ``l`` and the accumulator. 22 MiB at [2048, 1024],
+    of which the chip's compiler allocates between 15 and 19; more
+    buys nothing (at [2048, 2048] a call read 5.31 ms stating 32 or
+    40 MiB and 5.41 stating 64 or 96: PERF.md section 6, PR 66)."""
+    scores = tuning.score_bytes(1, rows, block_k)
+    if scores <= UNSTATED_SCORE_BYTES:
+        return None
+    streamed = 2 * itemsize * (
+        rows * (_lanes(d) + _lanes(dv))
+        + block_k * (_lanes(d) + _lanes(dv)))
+    state = 4 * rows * (2 * LANES + _lanes(dv))
+    return scores + streamed + state
+
+
 def _fwd(q, k, v, scale, causal, block_q, block_k, window=None,
          selected=None):
     """q: [bk_h, g, seq, d]; k,v: [bk_h, seq, d] ->
@@ -861,6 +911,8 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, window=None,
     selection = ()
     if words is not None:
         kernel, selection = _selecting(kernel, 3), (words,)
+    stated = _fwd_vmem_bytes(
+        g * block_q, block_k, _width(q), dv, _dtype(q).itemsize)
     return pl.pallas_call(
         kernel,
         grid=(bkh, *grid),
@@ -889,6 +941,8 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, window=None,
             pltpu.VMEM((g * block_q, LANES), jnp.float32),
             pltpu.VMEM((g * block_q, dv), jnp.float32),
         ],
+        compiler_params=stated and pltpu.CompilerParams(
+            vmem_limit_bytes=stated),
         interpret=_interpret(),
     )(q, k, v, *selection)
 
@@ -1140,22 +1194,21 @@ def _dq_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-#: what a head's float32 dQ may hold of VMEM beside the dK/dV kernel's
-#: own blocks and scratch: the largest that was compiled and timed
-#: (8,192 positions of 192, latent attention's: in the cell 727 ms of
-#: ``attn_kernel_ms`` against the pair's 881, 18,579 tokens/s against
-#: 17,220; PERF.md section 6, PR 42)
-DQ_RESIDENT_BYTES = 6 * 1024 * 1024
+#: what the resident gradient may hold of VMEM in float32, rows padded
+#: to whole lanes, beside the other kernel's own blocks and scratch:
+#: a head's dQ without a group, a kv head's dK and dV with one. One
+#: budget for both, since both calls state what they take
+#: (``_dq_resident_vmem_bytes``, ``_dkv_resident_vmem_bytes``: 60 MiB
+#: of a v5e core's 128 at the budget's edge) and neither lives inside
+#: the 16 MiB default: the largest that was compiled and timed, 16,384
+#: positions of 128 columns' dK and dV (PR 37) and of 192 columns' dQ
+#: (two lanes a row; PERF.md section 6, PR 66)
+RESIDENT_BYTES = 16 * 1024 * 1024
 #: up to here the head's dQ fits beside the rest under the default
 #: scoped limit (4096 positions of 128) and the call is the one it
 #: always was; past it the call states what it needs
 #: (``_dq_resident_vmem_bytes``)
 DQ_UNSTATED_BYTES = 2 * 1024 * 1024
-#: what a kv head's float32 dK and dV may hold of VMEM beside the dQ
-#: kernel's own blocks and scratch, rows padded to whole lanes: the
-#: largest that was compiled and timed (16,384 positions of 128; the
-#: call states what it needs, ``_dkv_resident_vmem_bytes``)
-DKV_RESIDENT_BYTES = 16 * 1024 * 1024
 
 
 def _lanes(head_dim):
@@ -1166,21 +1219,33 @@ def _lanes(head_dim):
 def _one_backward_kernel(g, seq, head_dim):
     """Whether the backward is one kernel, which keeps the smaller
     gradient in VMEM over a head's grid steps and lets the larger
-    leave a block at a time. Without a group that is
-    ``_dqkv_kernel`` where the head's float32 dQ is within
-    ``DQ_RESIDENT_BYTES``; with one (dQ is g times a head's)
-    ``_dq_dkv_kernel`` where the kv head's float32 dK and dV are
-    within ``DKV_RESIDENT_BYTES``.
+    leave a block at a time: where that gradient, in float32 rows of
+    whole lanes, is within ``RESIDENT_BYTES``. Without a group it is
+    the head's dQ (``_dqkv_kernel``); with one (dQ is g times a
+    head's) the kv head's dK and dV (``_dq_dkv_kernel``).
 
     As read on a v5e (``benchmarks/profile_attn_subtiles.py``, forward
     and backward of a call; PERF.md section 6, PRs 33 and 37): 4.68 ms
     against the pair's 5.08 at one (1024, 1024) block a 64-wide head,
     6.46 against 7.83 at 4 x 4 such blocks of a 128-wide head, where
     dQ's rows are sliced at an offset from ``program_id``.
+    At 16,384 positions of 192 columns, 32 heads, q and k in parts:
+    77.9 ms a call forward and backward against the pair's 98.2, and
+    in ``kimi``'s step the one kernel 49.5 ms where the pair took 69.4
+    (PERF.md section 6, PR 66).
     ``head_dim``: the wider of q and k's and v's, where they differ."""
-    if g == 1:
-        return seq * head_dim * 4 <= DQ_RESIDENT_BYTES
-    return 2 * seq * _lanes(head_dim) * 4 <= DKV_RESIDENT_BYTES
+    held = seq * _lanes(head_dim) * 4
+    return (held if g == 1 else 2 * held) <= RESIDENT_BYTES
+
+
+def backward_form(g, seq, head_dim):
+    """The backward a call takes, by the name the gauge
+    ``attn_backward_kernels`` and ``ops/tuning.py last_selection``
+    give it: ``dq_resident`` or ``dkv_resident`` (one kernel,
+    ``_one_backward_kernel``), else ``pair``."""
+    if not _one_backward_kernel(g, seq, head_dim):
+        return "pair"
+    return "dq_resident" if g == 1 else "dkv_resident"
 
 
 #: what ``_dq_dkv_kernel`` asks of VMEM beside the resident dK and dV,
@@ -1224,9 +1289,7 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
         o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1
     )[:, :, None, :]  # [bkh, g, 1, seq] (4-D for TPU block tiling)
 
-    form = "pair"
-    if _one_backward_kernel(g, seq, max(d, dv)):
-        form = "dq_resident" if g == 1 else "dkv_resident"
+    form = backward_form(g, seq, max(d, dv))
     gauge(
         "attn_backward_kernels",
         "Pallas kernels of the attention backward at the last one "
@@ -1382,23 +1445,26 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
 # ---------------------------------------------------------------------------
 # public wrapper with custom VJP
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def _flash_gqa(q, k, v, selected, scale, causal, block_q, block_k,
-               window=None):
+               window=None, fwd_block_k=None):
     """``selected``: None, or the selection [rows, seq, blocks] bool,
-    an operand that no gradient reaches."""
-    o, _ = _fwd(q, k, v, scale, causal, block_q, block_k, window, selected)
+    an operand that no gradient reaches. ``fwd_block_k``: the forward
+    kernel's key block where it is not the backward's ``block_k``."""
+    o, _ = _fwd(q, k, v, scale, causal, block_q, fwd_block_k or block_k,
+                window, selected)
     return o
 
 
 def _flash_fwd_rule(q, k, v, selected, scale, causal, block_q, block_k,
-                    window):
-    o, lse = _fwd(
-        q, k, v, scale, causal, block_q, block_k, window, selected)
+                    window, fwd_block_k):
+    o, lse = _fwd(q, k, v, scale, causal, block_q, fwd_block_k or block_k,
+                  window, selected)
     return o, (q, k, v, selected, o, lse)
 
 
-def _flash_bwd_rule(scale, causal, block_q, block_k, window, res, do):
+def _flash_bwd_rule(scale, causal, block_q, block_k, window, fwd_block_k,
+                    res, do):
     q, k, v, selected, o, lse = res
     dq, dk, dv = _bwd(
         q, k, v, o, lse, do, scale, causal, block_q, block_k, window,
@@ -1436,9 +1502,12 @@ def flash_attention_tpu(
     q_rope: Optional[jax.Array] = None,  # [batch, seq, heads, rope_dim]
     k_rope: Optional[jax.Array] = None,  # [batch, seq, 1, rope_dim]
     selected: Optional[jax.Array] = None,  # [batch, kv_heads, seq, blocks]
+    fwd_block_k: Optional[int] = None,
 ) -> jax.Array:
     """Flash attention in the models' [batch, seq, heads, head_dim]
     layout; GQA folded into the kernels' matmul rows (no KV repeat).
+    ``fwd_block_k``: a key block of the forward kernel's own
+    (``ops/tuning.py forward_key_block``); None is ``block_k``.
     ``window``: query i sees key j iff ``j <= i`` and ``i - j <
     window`` (causal only); one that reaches every key is no window.
     ``v`` may have a width of its own, which is the result's; the
@@ -1499,7 +1568,7 @@ def flash_attention_tpu(
         qg, kg = (qg, q_layout(q_rope)), (kg, k_rope[:, :, 0])
     o = _flash_gqa(
         qg, kg, kv_layout(v), selected, scale, causal, block_q, block_k,
-        window,
+        window, fwd_block_k,
     )
     return o.reshape(b, h, s, v.shape[3]).transpose(0, 2, 1, 3)
 

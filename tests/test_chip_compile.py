@@ -301,9 +301,10 @@ CELL_ATTENTION = {
 }
 CELL_V_WIDTH = {"joyai": 128}
 #: the backward is one kernel at each: a head's float32 dQ stays in
-#: VMEM without a group (256 KB, 2 MB; 4 MiB of ``DQ_RESIDENT_BYTES``'
-#: 6 at ``ouro``'s 8,192 x 128), a kv head's float32 dK and dV with
-#: one (4, 8 and 16 MB)
+#: VMEM without a group (in whole lanes 512 KB, 2 MB; 4 MiB of
+#: ``RESIDENT_BYTES``' 16 at ``ouro``'s 8,192 x 128, 8 at ``joyai``'s
+#: 8,192 x 192), a kv head's float32 dK and dV with one (4, 8 and 16
+#: MB)
 CELL_BACKWARD_FORM = {
     "gpt2-xl": "dq_resident", "olmoe": "dq_resident",
     "mistral": "dkv_resident", "lfm2": "dkv_resident",
@@ -320,6 +321,16 @@ CELL_BACKWARD_VMEM = {"mistral": 36 * 2 ** 20, "lfm2": 44 * 2 ** 20,
                       # x 128 (4 MiB) and two buffers of its output
                       # block (2 MiB each) pass the default too
                       "ouro": 36 * 2 ** 20}
+
+
+def _asked_of_vmem(text):
+    """What each kernel call of a compiled step states of VMEM, in
+    MiB, the default 16 (written out beside a call that asks) left
+    out."""
+    asked = [int(n) for n in re.findall(
+        r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', text)]
+    assert all(n % 2 ** 10 == 0 for n in asked)
+    return [n / 2 ** 20 for n in asked if n != 16 * 2 ** 20]
 
 
 def _sum_grad(attn):
@@ -372,14 +383,9 @@ def test_sub_tiled_kernels_compile_at_the_cells_shapes(
     assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) == (
         2 if grad else 1
     )
-    asked = [int(n) for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line
-             for n in re.findall(
-                 r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', line)]
-    default = 16 * 2 ** 20  # written out beside a call that asks
-    asked = [n for n in asked if n != default]
-    assert asked == ([CELL_BACKWARD_VMEM[cell]]
-                     if grad and cell in CELL_BACKWARD_VMEM else [])
+    assert _asked_of_vmem(text) == (
+        [CELL_BACKWARD_VMEM[cell] / 2 ** 20]
+        if grad and cell in CELL_BACKWARD_VMEM else [])
 
 
 #: the windowed cells' attention: 28 and 32 query heads on 4 of 128
@@ -481,10 +487,8 @@ def test_latent_kernels_compile_at_joyais_shape(topo, on_tpu_path, operands):
         r"%([\w.\-]+) = ([^\n]*?) custom-call\([^\n]*"
         r"custom_call_target=\"tpu_custom_call\"", compiled.as_text())
     assert len(kernels) == 2
-    asked = [int(n) for n in re.findall(
-        r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"',
-        compiled.as_text())]
-    assert max(asked) == 16 * 2 ** 20 + fa.OTHER_VMEM_BYTES
+    assert _asked_of_vmem(compiled.as_text()) == [
+        16 + fa.OTHER_VMEM_BYTES / 2 ** 20]
     # o and dv 128 wide; dq and dk 192, or each in parts of 128 and
     # 64 (the rotated key's a head's own, summed outside the kernel)
     assert sorted(re.findall(r"8192,(\d+)\]", " ".join(
@@ -495,6 +499,82 @@ def test_latent_kernels_compile_at_joyais_shape(topo, on_tpu_path, operands):
     assert (selection["head_dim"], selection["v_head_dim"]) == (192, 128)
     assert selection.get("rope_head_dim") == (
         64 if operands == "parts" else None)
+
+
+@pytest.mark.parametrize("cell,seq,selection", [
+    ("nemotron", 8192, None), ("minicpm-sala", 16384, 64),
+])
+def test_a_group_of_16_forward_states_its_wider_key_block(
+    topo, on_tpu_path, cell, seq, selection
+):
+    """Nemotron's and ``minicpm-sala``'s attention, 32 query heads on
+    2 of 128 (the second with the selection's words), through the
+    dispatch a TPU process takes: the pair is (128, 512), which the
+    one backward kernel runs with its kv head's dK and dV resident;
+    the forward takes 1,024 columns and states what its [2048, 1024]
+    scores, ``p``, streamed blocks and state take, 22 MiB of a v5e
+    core's 128, and the chip's compiler takes it."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def shaped(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = [shaped(1, seq, 32, 128), shaped(1, seq, 2, 128),
+            shaped(1, seq, 2, 128)]
+    if selection:
+        args.append(shaped(1, 2, seq, seq // selection, dtype=jnp.bool_))
+    assert tuning.heuristic_blocks(seq, 16) == (128, 512)
+    assert tuning.forward_key_block(
+        seq, 16, (128, 512), selection_block=selection) == 1024
+    stated = fa._fwd_vmem_bytes(16 * 128, 1024, 128, 128, 2)
+    assert stated == 22 * 2 ** 20
+    assert fa._fwd_vmem_bytes(16 * 128, 512, 128, 128, 2) is None
+    compiled = jax.jit(jax.grad(
+        lambda q, k, v, *selected: attention.flash_attention.__wrapped__(
+            q, k, v, selected=selected[0] if selected else None,
+        ).astype(jnp.float32).sum(), argnums=(0, 1, 2),
+    )).lower(*args).compile()
+    text = compiled.as_text()
+    assert len(re.findall(
+        r'custom_call_target="tpu_custom_call"', text)) == 2
+    # the forward's, then the backward's resident dK and dV
+    assert _asked_of_vmem(text) == [
+        22, fa._dkv_resident_vmem_bytes(seq, 128, 2) / 2 ** 20]
+    assert max(_asked_of_vmem(text)) < 128
+    selection = tuning.last_selection()
+    assert (selection["block_q"], selection["block_k"],
+            selection["fwd_block_k"], selection["backward"]) == (
+                128, 512, 1024, "dkv_resident")
+
+
+def test_kimis_backward_is_one_kernel_with_the_heads_dq_resident(
+    topo, on_tpu_path
+):
+    """``kimi-linear-48b-a3b-ep16.steady``'s latent attention: 32
+    heads at 16,384 positions, q and k in parts of 128 and 64 columns
+    (the rotated key one for every head), v 128: a head's float32 dQ
+    is 16 MiB in rows of two lanes, the one budget's edge, so the
+    backward is ``_dqkv_kernel`` as ``joyai``'s is at 8,192, and the
+    call states 60 MiB, what the grouped backward states at 16,384
+    positions of 128."""
+    def shaped(heads, d):
+        return jax.ShapeDtypeStruct(
+            (1, 16384, heads, d), jnp.bfloat16,
+            sharding=SingleDeviceSharding(topo.devices[0]),
+        )
+
+    assert fa.backward_form(1, 16384, 192) == "dq_resident"
+    lowered = jax.jit(jax.grad(
+        lambda q, k, v, q_rope, k_rope: attention.flash_attention.__wrapped__(
+            q, k, v, q_rope=q_rope, k_rope=k_rope,
+        ).astype(jnp.float32).sum(), argnums=(0, 1, 2, 3, 4),
+    )).lower(shaped(32, 128), shaped(32, 128), shaped(32, 128),
+             shaped(32, 64), shaped(1, 64))
+    assert re.findall(r'kernel_name = "(\w+)"', lowered.as_text()) == [
+        "_fwd_kernel", "_dqkv_kernel"]
+    assert _asked_of_vmem(lowered.compile().as_text()) == [60]
+    assert tuning.last_selection()["backward"] == "dq_resident"
+    assert "fwd_block_k" not in tuning.last_selection()
 
 
 #: sha256 (first 16 digits) of the forward and the backward kernel's
@@ -599,7 +679,9 @@ def test_dispatch_lowers_the_rules_kernels_whatever_the_environment(
 #: heads, kv_heads, head_dim], then the window or the rotated part's
 #: width): what commit 2ce30bb (PR 63) lowers, read from a copy of it
 #: beside PR 64's tree, which hands every kernel one more operand
-#: where a caller has a selection and only there
+#: where a caller has a selection and only there. ``kimi.parts``: the
+#: forward's as then; the backward since PR 66 one kernel, where the
+#: dq and dk/dv pair was ("ee597869148835bc", "37307a7630892ff4")
 UNSELECTED_KERNELS = {
     "smallthinker.window": (
         (1, 16384, 28, 4, 128), {"window": 4096},
@@ -611,10 +693,10 @@ UNSELECTED_KERNELS = {
     "joyai.parts": (
         (4, 8192, 32, 32, 128), {"rope": 64},
         ("4f74afc876050aca", "c830cd5e967c2955")),
-    # the dq and dk/dv pair
+    # one backward kernel, a head's dQ resident, since PR 66
     "kimi.parts": (
         (1, 16384, 32, 32, 128), {"rope": 64},
-        ("1fbf08f00fce77f2", "ee597869148835bc", "37307a7630892ff4")),
+        ("1fbf08f00fce77f2", "a8c707f7762425e0")),
 }
 
 
@@ -1602,7 +1684,7 @@ def test_solar_step_holds_the_delta_rules_kernels(
 KIMI_STEP_BYTES = 9_622_742_016
 
 
-def test_kimi_step_holds_the_pair_backward_and_both_operators_kernels(
+def test_kimi_step_holds_the_one_backward_and_both_operators_kernels(
     topo, on_tpu_path, monkeypatch
 ):
     """``kimi-linear-48b-a3b-ep16.steady``'s step under
@@ -1610,10 +1692,11 @@ def test_kimi_step_holds_the_pair_backward_and_both_operators_kernels(
     was read when the cell was built; the one latent layer's attention
     reaches the kernels in parts (no instruction's result is a head's
     whole 192-wide q or k) and, a head's float32 dQ at 16,384
-    positions of 192 columns being 12.6 MB against
-    ``DQ_RESIDENT_BYTES``, its backward is the dq and dk/dv pair: four
-    kernels named as ``attn_kernel_ms`` tells them under
-    ``attn.latent``, and the gauges say 2 parts and the pair; the four
+    positions of 192 columns being the 16 MiB of ``RESIDENT_BYTES``
+    in rows of two lanes, its backward is the one kernel that
+    ``joyai``'s is (the dq and dk/dv pair until PR 66): three kernels
+    named as ``attn_kernel_ms`` tells them under ``attn.latent``, and
+    the gauges say 2 parts and a head's dQ resident; the four
     delta-rule layers' scans (the leading layer's outside the loop,
     three in the period's body) are named as ``delta_rule_ms`` tells
     them under ``kda.scan``, 32 heads in grid groups of the most the
@@ -1646,10 +1729,11 @@ def test_kimi_step_holds_the_pair_backward_and_both_operators_kernels(
               for path in ("kernel", "plain")]
     before = [c.value for c in calls]
     gauge("delta_rule_heads_per_step", "").set(0)
-    for kernel in ("fwd", "dq", "dkv"):
+    for kernel in ("fwd", "dqkv"):
         gauge("attn_operand_parts", "", ("kernel",)).labels(
             kernel=kernel).set(0)
-    gauge("attn_backward_kernels", "", ("form",)).labels(form="pair").set(0)
+    gauge("attn_backward_kernels", "", ("form",)).labels(
+        form="dq_resident").set(0)
     _, config, traffic = cells.load_cell("kimi-linear-48b-a3b-ep16.steady")
     assert (traffic["global_batch"], traffic["seq"]) == (1, 16384)
     cfg = worker.program_config(config, traffic)
@@ -1663,8 +1747,10 @@ def test_kimi_step_holds_the_pair_backward_and_both_operators_kernels(
     # a chunk of the walk from 2304-wide rows: 8,192 x 2560 / 2304 in
     # whole tiles of 512; even routing's 8,192 held rows are one chunk
     assert moe.walk_chunks(16384 * 8, 2304) == (8704, 16)
-    assert not fa._one_backward_kernel(1, 16384, 192)
-    assert fa._one_backward_kernel(1, 8192, 192)  # joyai's, on the limit
+    # 16 MiB in rows of two lanes: on the one budget's limit
+    assert fa._one_backward_kernel(1, 16384, 192)
+    assert not fa._one_backward_kernel(1, 32768, 192)
+    assert fa._one_backward_kernel(1, 8192, 192)  # joyai's: 8 MiB
     mesh = Mesh(
         np.array(topo.devices[:1]).reshape(1, 1), ("data", "fsdp"))
     trainer = make_trainer_for_llama(
@@ -1694,23 +1780,25 @@ def test_kimi_step_holds_the_pair_backward_and_both_operators_kernels(
         r"op_name=\"([^\"]*)\"", text)
     latent = [(name, result) for name, result, op in kernels
               if "attn.latent" in op]
-    # the forward, the forward again under ``minimal``, then dq and
-    # dk/dv: two kernels where joyai's 8,192 positions run one
-    assert len(latent) == 4, latent
+    # the forward, the forward again under ``minimal``, then the one
+    # backward kernel, as at joyai's 8,192 positions
+    assert len(latent) == 3, latent
     assert all(attn_kernel_ms.KERNEL.search(name) for name, _ in latent)
     assert not any(
         attn_kernel_ms.KERNEL.search(name)
         for name, _, op in kernels if "attn.latent" not in op)
-    # dq in its two parts; dk in its two and dv
+    # dq in its two parts, dk in its two and dv, of one kernel
     widths = sorted(
         sorted(re.findall(r"16384,(\d+)\]", result))
         for _, result in latent if "f32[" not in result)
-    assert widths == [["128", "128", "64"], ["128", "64"]], widths
+    assert widths == [["128", "128", "128", "64", "64"]], widths
     parts = gauge("attn_operand_parts", "", ("kernel",))
-    assert [parts.labels(kernel=k).value for k in ("fwd", "dq", "dkv")] == [
-        2, 2, 2]
+    assert [parts.labels(kernel=k).value for k in ("fwd", "dqkv")] == [2, 2]
     assert gauge("attn_backward_kernels", "", ("form",)).labels(
-        form="pair").value == 2
+        form="dq_resident").value == 1
+    # which states the VMEM the grouped backward states at 16,384
+    assert 60 in _asked_of_vmem(text)
+    assert tuning.last_selection()["backward"] == "dq_resident"
     assert tuning.last_selection()["rope_head_dim"] == 64
     assert tuning.last_selection()["seq"] == 16384
     scan = [(name, op) for name, _, op in kernels
@@ -1928,6 +2016,10 @@ def test_nemotron_step_holds_the_scans_kernels(topo, on_tpu_path, monkeypatch):
     assert sum(bool(attn_kernel_ms.KERNEL.search(n)) for n in others) == 6
     assert fa._one_backward_kernel(16, 8192, 128)
     assert tuning.last_selection()["gqa_group"] == 16
+    # the pair is the backward's; the forward states its own key block
+    assert (tuning.last_selection()["block_k"],
+            tuning.last_selection()["fwd_block_k"]) == (512, 1024)
+    assert sorted(set(_asked_of_vmem(text))) == [22, 44]
     # six expert layers' walks: grouped products, none of them read as
     # another operator's
     assert sum(bool(moe_expert_ms.KERNEL.search(n)) for n in others) >= 18
@@ -1960,7 +2052,9 @@ def test_sala_step_holds_the_selections_and_the_scans_kernels(
     under ``minimal`` and the one backward kernel, a kv head's dK and
     dV resident at a group of 16) are named as ``attn_kernel_ms``
     tells them and carry ``sparse.attn``, with the selection's words
-    ``s32[2, 32, 1, 16384]`` among their operands; the three lightning
+    among their operands, each kernel's from its own key block
+    (``s32[2, 16, 1, 16384]`` the forwards', ``s32[2, 32, 1, 16384]``
+    the backward's); the three lightning
     layers' scans (each the forward, the forward again and the
     backward over ``[1, 32, 128, 128, 128]`` entry states) are named
     as ``ssd_ms`` tells them, and as no other reader does, and carry
@@ -1988,7 +2082,11 @@ def test_sala_step_holds_the_selections_and_the_scans_kernels(
     _, config, traffic = cells.load_cell("minicpm-sala-9b-vp8.steady")
     cfg = worker.program_config(config, traffic)
     assert (cfg.remat, cfg.loss_chunk) == ("minimal", 0)
+    # the backward's pair; the forward's key block is as wide as the
+    # chip reads fastest, half of what a selection's word could hold
     assert tuning.heuristic_blocks(16384, 16) == (128, 512)
+    assert tuning.forward_key_block(
+        16384, 16, (128, 512), selection_block=64) == 1024
     assert fa._one_backward_kernel(16, 16384, 128)
     mesh = Mesh(
         np.array(topo.devices[:1]).reshape(1, 1), ("data", "fsdp"))
@@ -2011,7 +2109,12 @@ def test_sala_step_holds_the_selections_and_the_scans_kernels(
               if attn_kernel_ms.KERNEL.search(name)]
     assert len(attend) == 3, [name for name, _, _ in attend]
     assert all("sparse.attn" in op for _, _, op in attend)
-    assert all("s32[2,32,1,16384]" in operands for _, operands, _ in attend)
+    # each kernel makes its words from its own key block: the two
+    # forwards' 16 blocks of 1,024 keys, the backward's 32 of 512
+    assert sorted(
+        re.search(r"s32\[2,(\d+),1,16384\]", operands).group(1)
+        for _, operands, _ in attend) == ["16", "16", "32"]
+    assert sorted(set(_asked_of_vmem(text))) == [22, 60]
     scan = [(name, op) for name, _, _, op in kernels
             if ssd_ms.KERNEL.search(name)]
     assert len(scan) == 3 * 3, [name for name, _ in scan]
@@ -2033,6 +2136,7 @@ def test_sala_step_holds_the_selections_and_the_scans_kernels(
         1, 0, 3, 0]
     assert gauge("ssd_heads_per_step", "").value == 1
     assert tuning.last_selection()["gqa_group"] == 16
+    assert tuning.last_selection()["fwd_block_k"] == 1024
     for scope in ("sparse.compress", "sparse.select", "sparse.attn",
                   "lightning.proj", "lightning.scan", "lightning.out",
                   "embed.scale", "branch.scale", "head.scale", "attn.gate"):

@@ -328,27 +328,34 @@ def test_a_group_keeps_its_kv_heads_gradients_resident(g, kernel, form):
 
 @pytest.mark.parametrize("g", [1, 4])
 def test_a_head_too_long_for_vmem_keeps_two_backward_kernels(monkeypatch, g):
-    """The rule reads shapes: without a group the head's float32 dQ
-    against its budget; with one the kv head's float32 dK and dV, rows
-    of whole lanes, against theirs, whatever the group is."""
+    """The rule reads shapes, and one budget: without a group the
+    head's float32 dQ, with one the kv head's float32 dK and dV,
+    whatever the group is, either in rows of whole lanes."""
     rule = flash_attention._one_backward_kernel
-    budget = flash_attention.DQ_RESIDENT_BYTES
+    budget = flash_attention.RESIDENT_BYTES
     assert rule(1, 1024, 64) and rule(1, 4096, 128)  # gpt2-xl, OLMoE
     assert rule(1, budget // (4 * 128), 128)
     assert not rule(1, 2 * budget // (4 * 128), 128)
+    # a 192-wide row holds two lanes' rows, a 64-wide one a whole one
+    assert rule(1, budget // (4 * 256), 192)
+    assert not rule(1, 2 * budget // (4 * 256), 192)
+    assert not rule(1, 2 * budget // (4 * 128), 64)
     # Mistral, lfm2, smallthinker, chip_smoke.py's llama_1b
     assert rule(4, 4096, 128) and rule(4, 8192, 64)
     assert rule(7, 16384, 128) and rule(8, 2048, 64)
-    budget = flash_attention.DKV_RESIDENT_BYTES
     for group in (2, 7, 16):
         assert rule(group, budget // (2 * 4 * 128), 128)
         assert not rule(group, 2 * budget // (2 * 4 * 128), 128)
-        # a 64-wide row holds a whole lane row of VMEM
         assert rule(group, budget // (2 * 4 * 128), 64)
         assert not rule(group, 2 * budget // (2 * 4 * 128), 64)
-    monkeypatch.setattr(flash_attention, "DQ_RESIDENT_BYTES", 256 * 64 * 4)
+    assert [flash_attention.backward_form(*shape) for shape in (
+        (1, 16384, 192), (1, 32768, 192), (16, 16384, 128),
+        (16, 32768, 128))] == [
+            "dq_resident", "pair", "dkv_resident", "pair"]
+    # 256 positions of a lane's row, once without a group and twice
+    # with one
     monkeypatch.setattr(
-        flash_attention, "DKV_RESIDENT_BYTES", 2 * 256 * 128 * 4)
+        flash_attention, "RESIDENT_BYTES", min(g, 2) * 256 * 128 * 4)
     q, k, v = _rand_qkv(jax.random.key(9), 1, 512, g, 1, 64)
     assert _kernels_of_grads(
         functools.partial(flash_attention_tpu, block_q=128, block_k=128),
@@ -439,18 +446,24 @@ def test_the_default_scale_is_q_and_ks_width():
 
 
 def test_a_long_head_at_latent_widths_keeps_its_dq_resident():
-    """The cell's shape: 8,192 positions of 192 are 6 MiB of float32
-    dQ, the budget's edge; past what fits beside the rest under the
-    default scoped limit the call states its VMEM (rows of whole
-    lanes: the float32 sum and two buffers of the output block, 16
-    MiB, and ``OTHER_VMEM_BYTES``)."""
+    """The cells' shapes: 8,192 positions of 192 (``joyai``) are 8
+    MiB of float32 dQ in rows of two lanes, 16,384 (``kimi``) the
+    budget's 16; past what fits beside the rest under the default
+    scoped limit the call states its VMEM (rows of whole lanes: the
+    float32 sum and two buffers of the output block, and
+    ``OTHER_VMEM_BYTES``): at the edge what the grouped backward asks
+    at its own, 60 MiB."""
     rule = flash_attention._one_backward_kernel
-    assert rule(1, 8192, 192) and not rule(1, 16384, 192)
-    assert 8192 * 192 * 4 == flash_attention.DQ_RESIDENT_BYTES
+    assert rule(1, 8192, 192) and rule(1, 16384, 192)
+    assert not rule(1, 32768, 192)
+    assert 16384 * 256 * 4 == flash_attention.RESIDENT_BYTES
     assert 8192 * 192 * 4 > flash_attention.DQ_UNSTATED_BYTES
     assert 4096 * 128 * 4 <= flash_attention.DQ_UNSTATED_BYTES  # OLMoE
     assert flash_attention._dq_resident_vmem_bytes(8192, 192, 2) == (
         8192 * 256 * 8 + flash_attention.OTHER_VMEM_BYTES)
+    assert flash_attention._dq_resident_vmem_bytes(16384, 192, 2) == (
+        flash_attention._dkv_resident_vmem_bytes(16384, 128, 2)
+    ) == 60 * 2 ** 20
 
 
 # -- q and k in the parts their products make (latent attention) -------------
