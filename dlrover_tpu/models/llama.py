@@ -28,8 +28,8 @@ import jax.numpy as jnp
 
 from dlrover_tpu.ops.attention import flash_attention
 from dlrover_tpu.ops.delta_rule import gated_delta_rule_rows
-from dlrover_tpu.ops.gated_norm import gated_group_norm
-from dlrover_tpu.ops.kda_conv import conv_silu_norm, heads_apart
+from dlrover_tpu.ops.gated_norm import gated_group_norm, head_norm_gate
+from dlrover_tpu.ops.kda_conv import conv_silu_norm
 from dlrover_tpu.ops.short_conv import gated_short_conv
 from dlrover_tpu.ops.sparse_attention import (
     compress_keys, select_blocks, selected_attention,
@@ -1595,28 +1595,16 @@ def _operator_out(x, out, layer_params, kind: LayerKind,
         with jax.named_scope("conv.out_proj"):
             return out @ p["conv_out"]
     if kind.operator == "linear_attention":
-        # an RMSNorm a head with one learned scale, a sigmoid gate:
-        # rows in, rows to ``wo``
+        # an RMSNorm a head with one learned scale, a sigmoid gate with
+        # a learned bias: rows in, rows to ``wo``
         with jax.named_scope("kda.out"):
-            o, gate = out
-            gate = jax.nn.sigmoid(
-                gate.astype(jnp.float32) + p["g_bias"]
-            ).astype(o.dtype)
-            heads = o.shape[-1] // p["o_norm"].shape[-1]
-            o = rms_norm(
-                heads_apart(o, heads), p["o_norm"], norm_eps
-            ).reshape(o.shape)
-            return (o * gate) @ p["wo"]
+            return head_norm_gate(
+                *out, p["o_norm"], p["g_bias"], norm_eps) @ p["wo"]
     if kind.operator == "lightning_attention":
         # an RMSNorm a head with one learned scale, a sigmoid gate
         with jax.named_scope("lightning.out"):
-            o, gate = out
-            heads = o.shape[-1] // p["o_norm"].shape[-1]
-            o = rms_norm(
-                o.reshape(b, s, heads, -1), p["o_norm"], norm_eps
-            ).reshape(o.shape)
-            return (o * jax.nn.sigmoid(
-                gate.astype(jnp.float32)).astype(o.dtype)) @ p["wo"]
+            return head_norm_gate(
+                *out, p["o_norm"], None, norm_eps) @ p["wo"]
     if isinstance(out, tuple):  # full attention and its gate's logits
         with jax.named_scope("attn.gate"):
             out, gate = out

@@ -121,8 +121,14 @@ MODULE_BUDGET_OVERRIDES = {
     # other workers; since PR 54 nemotron's eleven-layer step at the
     # least effort, 50-60s of its own, and the scan's kernels alone;
     # since PR 64 sala's four-layer step at the least effort, 30s of
-    # its own: 1,265s beside five other workers on a loaded machine
-    "test_chip_compile": 1350.0,
+    # its own: 1,265s beside five other workers on a loaded machine;
+    # since PR 67 the gate-and-norm frame's kernels at three more
+    # cells' shapes, 5s: 1,508s in a whole run of 1,394s
+    "test_chip_compile": 1600.0,
+    # the gate-and-norm frame's two bodies (the heads' with a bias and
+    # without) in interpret mode, at widths of 4,096 and 8,192 too (PR
+    # 67): 78s alone
+    "test_gated_norm": 180.0,
     # the sala family's program against its reference, whose
     # selection walks whole score arrays and whose recurrence a
     # position at a time, with seventeen edited references jitted

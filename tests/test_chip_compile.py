@@ -1481,8 +1481,15 @@ def test_joyai_step_holds_no_whole_q_or_k(topo, on_tpu_path, monkeypatch):
 #: the compiler's dump), the rule's is a delta-rule layer's backward
 #: (``delta_rule.15``'s results and three ``tgmm``s), and the heap,
 #: 6,478,791,168 and 6,478,922,240 bytes of temporaries around live
-#: peaks of 2.7 and 4.8 GB, is packed 128 KiB apart
-SOLAR_STEP_BYTES = 14_998_785_536
+#: peaks of 2.7 and 4.8 GB, is packed 128 KiB apart. 14,998,785,536
+#: until the heads' norm and gate became Pallas calls (PR 67), and UP
+#: by 202,244,096: the plain passes' result was never an array (XLA
+#: made it inside the operand fusions of ``W_o``'s three products, a
+#: ``kOutput`` fusion that read the scan's ``o`` and the gate's
+#: logits), and the kernels' ``y`` is one, 134 MB of bf16 a layer from
+#: its forward-again to ``W_o``'s weight gradient; at this effort and
+#: at the default alike, 1.7 GB under the compiler's 16.91
+SOLAR_STEP_BYTES = 15_201_029_632
 #: what a delta-rule layer's q, k, v, g or o is as rows, as heads, and
 #: as the tiles of rows that the compiler names ``[s / 8, 8, heads, d]``
 SOLAR_ROWS = re.compile(
@@ -1525,6 +1532,26 @@ def _shapes_of_the_keeping_calls(kernels, named):
     ]
 
 
+def _heads_norms_calls(kernels, text, scope, layers, readers):
+    """The heads' norm and gate of ``layers`` linear-attention layers
+    under ``scope``: each the forward, the forward again under
+    ``minimal`` and the backward, Pallas calls by the jitted name
+    ``gated_norm``, which no reader's pattern matches, and none of the
+    scope's instructions a ``.remat`` twin of another (a second copy of
+    a fusion that the compiler makes to rematerialize it: PERF.md, PR
+    63)."""
+    calls = [name for name, *_, op in kernels if scope in op]
+    assert len(calls) == 3 * layers, calls
+    assert all(name.startswith("gated_norm") for name in calls)
+    assert not any(
+        reader.KERNEL.search(name) for name in calls for reader in readers)
+    twins = [
+        line.split(" = ")[0].strip() for line in text.splitlines()
+        if scope in line and ".remat" in line.split(" = ")[0]
+    ]
+    assert not twins, twins
+
+
 def test_solar_step_holds_the_delta_rules_kernels(
     topo, on_tpu_path, monkeypatch
 ):
@@ -1549,10 +1576,16 @@ def test_solar_step_holds_the_delta_rules_kernels(
     under a ``kda.`` scope, no float32 one under no scope (the copies
     a trace shows without an ``op_name``), and the nine calls were
     handed rows and built with the most heads a grid step that the
-    kernels' rule has, which divides the cell's 64."""
-    from dlrover_tpu.ops import delta_rule, grouped_matmul as gm, kda_conv
+    kernels' rule has, which divides the cell's 64; and the heads'
+    norm and gate between the scan and ``W_o`` are nine Pallas calls
+    under ``kda.out`` by the jitted name ``gated_norm`` (PR 67), every
+    call of their entry on the kernels' path."""
+    from dlrover_tpu.ops import (
+        delta_rule, gated_norm, grouped_matmul as gm, kda_conv,
+    )
     from dlrover_tpu.telemetry.registry import counter, gauge
     from dlrover_tpu.ops.pallas import delta_rule as scan_kernels
+    from dlrover_tpu.ops.pallas import gated_norm as norm_kernels
     from dlrover_tpu.ops.pallas import kda_conv as conv_kernels
     from yardstick import cells, worker
     from yardstick.layer_metrics import (
@@ -1568,9 +1601,14 @@ def test_solar_step_holds_the_delta_rules_kernels(
         kda_conv, "_use_pallas", lambda x, w, l2_heads: (
             conv_kernels.tiles_the_kernel(x.shape, w.shape, l2_heads)))
     monkeypatch.setattr(conv_kernels, "_interpret", lambda: False)
+    monkeypatch.setattr(
+        gated_norm, "_use_pallas", lambda o, groups: (
+            norm_kernels.tiles_the_kernel(o.shape, groups)))
+    monkeypatch.setattr(norm_kernels, "_interpret", lambda: False)
     calls = [counter(f"delta_rule_{handed}_calls", "")
              for handed in ("rows", "folded")]
-    calls += [counter(f"kda_conv_{path}_calls", "")
+    calls += [counter(f"{entry}_{path}_calls", "")
+              for entry in ("kda_conv", "head_norm_gate")
               for path in ("kernel", "plain")]
     before = [c.value for c in calls]
     gauge("delta_rule_heads_per_step", "").set(0)
@@ -1592,7 +1630,7 @@ def test_solar_step_holds_the_delta_rules_kernels(
     ).compile(LEAST_EFFORT)
     planned = compiled.memory_analysis().peak_memory_in_bytes
     print("solar step plans", planned)
-    assert planned <= SOLAR_STEP_BYTES
+    assert planned <= SOLAR_STEP_BYTES < 15.75 * 2 ** 30
     text = compiled.as_text()
     _routers_compare(text, traffic, cfg)
     kernels = re.findall(
@@ -1649,9 +1687,13 @@ def test_solar_step_holds_the_delta_rules_kernels(
         and "fusion(" in line and "kda.conv" in line
     ]
     assert not left, left
+    _heads_norms_calls(kernels, text, "kda.out", 3, (
+        attn_kernel_ms, delta_rule_ms, moe_expert_ms, short_conv_ms))
     # nine scans on rows and none folded; nine calls of the
-    # convolutions' entry (q, k, v a linear position), none plain
-    assert [c.value - was for c, was in zip(calls, before)] == [9, 0, 9, 0]
+    # convolutions' entry (q, k, v a linear position), none plain;
+    # three of the heads' norm and gate, none plain
+    assert [c.value - was for c, was in zip(calls, before)] == [
+        9, 0, 9, 0, 3, 0]
     together = gauge("delta_rule_heads_per_step", "").value
     assert together == max(scan_kernels.HEADS_A_STEP) > 1
     assert gauge(
@@ -1680,8 +1722,10 @@ def test_solar_step_holds_the_delta_rules_kernels(
 #: ``w`` for its backward (PR 61): 268 MB each a layer, 215 MB of it
 #: over what the step's peak held beside them, 9,622,743,040; 1,024
 #: bytes less with the head's own backward rule (PR 63): the peak is
-#: not the head's
-KIMI_STEP_BYTES = 9_622_742_016
+#: not the head's, 9,622,742,016. UP by 100,597,760 with the heads'
+#: norm and gate as Pallas calls (PR 67): their result an array of its
+#: own, as ``SOLAR_STEP_BYTES`` says
+KIMI_STEP_BYTES = 9_723_339_776
 
 
 def test_kimi_step_holds_the_one_backward_and_both_operators_kernels(
@@ -1704,10 +1748,16 @@ def test_kimi_step_holds_the_one_backward_and_both_operators_kernels(
     states; their convolutions are ``kda_conv`` calls under
     ``kda.conv``; q's one matrix stands under ``mla.q`` and nothing
     under ``mla.q_down``; the 2304 x 1024 experts take the tiles the
-    rule gives them and a share's walk its chunk."""
-    from dlrover_tpu.ops import delta_rule, grouped_matmul as gm, kda_conv
+    rule gives them and a share's walk its chunk; the heads' norm and
+    gate of the four delta-rule layers are twelve ``gated_norm`` calls
+    under ``kda.out`` (PR 67), every call of their entry on the
+    kernels' path."""
+    from dlrover_tpu.ops import (
+        delta_rule, gated_norm, grouped_matmul as gm, kda_conv,
+    )
     from dlrover_tpu.telemetry.registry import counter, gauge
     from dlrover_tpu.ops.pallas import delta_rule as scan_kernels
+    from dlrover_tpu.ops.pallas import gated_norm as norm_kernels
     from dlrover_tpu.ops.pallas import kda_conv as conv_kernels
     from yardstick import cells, worker
     from yardstick.layer_metrics import (
@@ -1723,9 +1773,14 @@ def test_kimi_step_holds_the_one_backward_and_both_operators_kernels(
         kda_conv, "_use_pallas", lambda x, w, l2_heads: (
             conv_kernels.tiles_the_kernel(x.shape, w.shape, l2_heads)))
     monkeypatch.setattr(conv_kernels, "_interpret", lambda: False)
+    monkeypatch.setattr(
+        gated_norm, "_use_pallas", lambda o, groups: (
+            norm_kernels.tiles_the_kernel(o.shape, groups)))
+    monkeypatch.setattr(norm_kernels, "_interpret", lambda: False)
     calls = [counter(f"delta_rule_{handed}_calls", "")
              for handed in ("rows", "folded")]
-    calls += [counter(f"kda_conv_{path}_calls", "")
+    calls += [counter(f"{entry}_{path}_calls", "")
+              for entry in ("kda_conv", "head_norm_gate")
               for path in ("kernel", "plain")]
     before = [c.value for c in calls]
     gauge("delta_rule_heads_per_step", "").set(0)
@@ -1822,10 +1877,13 @@ def test_kimi_step_holds_the_one_backward_and_both_operators_kernels(
     assert not any(
         reader.KERNEL.search(name) for name in conv for reader in (
             attn_kernel_ms, delta_rule_ms, moe_expert_ms, short_conv_ms))
+    _heads_norms_calls(kernels, text, "kda.out", 4, (
+        attn_kernel_ms, delta_rule_ms, moe_expert_ms, short_conv_ms))
     # twelve scans on rows and none folded; twelve calls of the
-    # convolutions' entry (q, k, v a delta-rule layer), none plain
+    # convolutions' entry (q, k, v a delta-rule layer), none plain;
+    # four of the heads' norm and gate, none plain
     assert [c.value - was for c, was in zip(calls, before)] == [
-        12, 0, 12, 0]
+        12, 0, 12, 0, 4, 0]
     together = gauge("delta_rule_heads_per_step", "").value
     assert together == max(scan_kernels.HEADS_A_STEP) and 32 % together == 0
     assert gauge("delta_rule_backward_inverses", "").value == 0
@@ -1880,33 +1938,64 @@ def test_ssd_kernels_compile_at_the_cells_shape(topo, monkeypatch):
     assert "f32[1,8,64,128,1024]" in text  # the chunks' entry states
 
 
-def test_gated_norm_kernels_compile_at_the_cells_shape(topo, monkeypatch):
-    """A mixer's gate and grouped norm at the cell's shape (one
-    sequence of 8,192, 8 groups of 1,024 columns): a forward and a
-    backward Pallas call, the cotangent read in bf16 and the scale's
-    gradient summed in float32."""
+#: the gate-and-norm frame's callers: ``[batch, seq, width]``, the
+#: groups (a mixer's) or heads, the body, and whether the gate has a
+#: bias
+GATED_NORM_CELLS = {
+    "nemotron": ((1, 8192, 8192), 8, "gate, norm", False),
+    "solar": ((1, 8192, 8192), 64, "norm, gate", True),
+    "kimi": ((1, 16384, 4096), 32, "norm, gate", True),
+    "minicpm-sala": ((1, 16384, 4096), 32, "norm, gate", False),
+}
+#: the mixer's forward and backward Mosaic modules as commit 7cf0d04
+#: (PR 66) lowers them, from the file that held that body alone: the
+#: frame the two bodies share since PR 67 hands it the same text
+NEMOTRON_GATED_NORM = ("0a8b711df9b7df4e", "adca66b2998e6cbe")
+
+
+@pytest.mark.parametrize("cell", list(GATED_NORM_CELLS))
+def test_gated_norm_kernels_compile_at_the_cells_shape(
+    topo, monkeypatch, cell
+):
+    """A mixer's gate and grouped norm at Nemotron's shape (one
+    sequence of 8,192, 8 groups of 1,024 columns) and the heads' norm
+    and gate at the three linear-attention cells' (64 heads of 128 at
+    8,192 positions; 32 at 16,384, with the gate's bias and without):
+    a forward and a backward Pallas call, the cotangent read in bf16
+    and the vectors' gradients summed in float32, the one scale's over
+    the heads too. The mixer's two modules are the parent's."""
     from dlrover_tpu.ops.pallas import gated_norm as kernels
 
+    shape, groups, body, biased = GATED_NORM_CELLS[cell]
     monkeypatch.setattr(kernels, "_interpret", lambda: False)
-    assert kernels.tiles_the_kernel((1, 8192, 8192), 8)
+    assert kernels.tiles_the_kernel(shape, groups)
     one_chip = SingleDeviceSharding(topo.devices[0])
-    rows = jax.ShapeDtypeStruct(
-        (1, 8192, 8192), jnp.bfloat16, sharding=one_chip)
-    scale = jax.ShapeDtypeStruct((8192,), jnp.float32, sharding=one_chip)
+    width = shape[-1]
+    rows = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    vectors = tuple(
+        jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
+        for n in ((width,) if body == "gate, norm"
+                  else (width // groups, width)[:1 + biased]))
 
-    def gradients(o, z, scale, dy):
+    def gradients(o, z, vectors, dy):
         y, back = jax.vjp(
-            lambda *a: kernels.gated_norm_tpu(*a, 8, 1e-5), o, z, scale)
+            lambda *a: kernels.gated_norm_tpu(*a, body, groups, 1e-5),
+            o, z, vectors)
         return (y, *back(dy))
 
-    compiled = jax.jit(gradients).lower(rows, rows, scale, rows).compile()
+    compiled = jax.jit(gradients).lower(rows, rows, vectors, rows).compile()
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 2
     assert [(o.dtype, o.shape) for o in jax.tree.leaves(
-        compiled.out_info)] == 3 * [(jnp.bfloat16, (1, 8192, 8192))] + [
-            (jnp.float32, (8192,))]
+        compiled.out_info)] == 3 * [(jnp.bfloat16, shape)] + [
+            (jnp.float32, v.shape) for v in vectors]
     # nothing of the operator's is left to XLA at full width
-    assert not re.search(r"= f32\[1,8192,8192\]", text)
+    assert not re.search(r"= f32\[%d,%d,%d\]" % shape, text)
+    if cell == "nemotron":
+        _, modules = _lowered_kernels(gradients, rows, rows, vectors, rows)
+        assert tuple(
+            hashlib.sha256(module.encode()).hexdigest()[:16]
+            for module in modules) == NEMOTRON_GATED_NORM
 
 
 def test_nemotron_step_holds_the_scans_kernels(topo, on_tpu_path, monkeypatch):
@@ -2039,8 +2128,11 @@ def test_nemotron_step_holds_the_scans_kernels(topo, on_tpu_path, monkeypatch):
 #: vocabulary, remat ``minimal``, the least effort; PERF.md, PR 64):
 #: 7.11 GB of it the state. 12,682,685,440 at the default effort,
 #: which the chip compiles at (the file's ``depth``); 12,989,689,856
-#: while the selection's ``top_k`` was the compiler's sort
-SALA_STEP_BYTES = 12_686_945_280
+#: while the selection's ``top_k`` was the compiler's sort;
+#: 12,686,945,280 until the lightning layers' heads' norm and gate
+#: became Pallas calls (PR 67): 404,585,472 less, where ``solar``'s and
+#: ``kimi``'s plans rose by the kernels' result
+SALA_STEP_BYTES = 12_282_359_808
 
 
 def test_sala_step_holds_the_selections_and_the_scans_kernels(
@@ -2058,12 +2150,15 @@ def test_sala_step_holds_the_selections_and_the_scans_kernels(
     layers' scans (each the forward, the forward again and the
     backward over ``[1, 32, 128, 128, 128]`` entry states) are named
     as ``ssd_ms`` tells them, and as no other reader does, and carry
-    ``lightning.scan``; every call of the two entries took the
+    ``lightning.scan``; the heads' norm and gate behind each scan are
+    nine ``gated_norm`` calls under ``lightning.out`` (PR 67), which no
+    reader's pattern matches; every call of the three entries took the
     kernels; no array of the compressed scores is whole in the step
     (``[32 or 16 heads, 16384, 1023]``), nor the sixteen chunks'
     masks; and every scope of the two operators and the three factors
     is in the text."""
-    from dlrover_tpu.ops import ssd
+    from dlrover_tpu.ops import gated_norm, ssd
+    from dlrover_tpu.ops.pallas import gated_norm as norm_kernels
     from dlrover_tpu.ops.pallas import ssd as scan_kernels
     from dlrover_tpu.telemetry.registry import counter, gauge
     from yardstick import cells, worker
@@ -2075,9 +2170,14 @@ def test_sala_step_holds_the_selections_and_the_scans_kernels(
         ssd, "_use_pallas", lambda x, B, heads, groups: (
             scan_kernels.tiles_the_kernel(x.shape, B.shape, heads, groups)))
     monkeypatch.setattr(scan_kernels, "_interpret", lambda: False)
+    monkeypatch.setattr(
+        gated_norm, "_use_pallas", lambda o, groups: (
+            norm_kernels.tiles_the_kernel(o.shape, groups)))
+    monkeypatch.setattr(norm_kernels, "_interpret", lambda: False)
     calls = [counter(f"{entry}_calls", "") for entry in (
         "sparse_attention_kernel", "sparse_attention_plain",
-        "ssd_kernel", "ssd_plain")]
+        "ssd_kernel", "ssd_plain",
+        "head_norm_gate_kernel", "head_norm_gate_plain")]
     before = [c.value for c in calls]
     _, config, traffic = cells.load_cell("minicpm-sala-9b-vp8.steady")
     cfg = worker.program_config(config, traffic)
@@ -2119,12 +2219,13 @@ def test_sala_step_holds_the_selections_and_the_scans_kernels(
             if ssd_ms.KERNEL.search(name)]
     assert len(scan) == 3 * 3, [name for name, _ in scan]
     assert all("lightning.scan" in op for _, op in scan)
-    assert len(kernels) == 12  # and no other kernel
-    for name, _, _, _ in kernels:
-        readers = [r for r in (attn_kernel_ms, delta_rule_ms, moe_expert_ms,
-                               short_conv_ms, ssd_ms)
-                   if r.KERNEL.search(name)]
-        assert len(readers) == 1, name
+    all_readers = (
+        attn_kernel_ms, delta_rule_ms, moe_expert_ms, short_conv_ms, ssd_ms)
+    _heads_norms_calls(kernels, text, "lightning.out", 3, all_readers)
+    assert len(kernels) == 12 + 9  # and no other kernel
+    for name, _, _, op in kernels:
+        readers = [r for r in all_readers if r.KERNEL.search(name)]
+        assert len(readers) == ("lightning.out" not in op), name
     # the chunks' entry states: [batch, heads, chunks, 128, 128]
     assert "f32[1,32,128,128,128]" in text
     # nothing of the compressed scores whole, in any layout
@@ -2133,7 +2234,7 @@ def test_sala_step_holds_the_selections_and_the_scans_kernels(
     assert not re.search(r"\[16,1,2,16,1024,1023\]", text)
     assert re.search(r"f32\[1,2,16,1024,\d+\]", text)  # a chunk's are
     assert [c.value - was for c, was in zip(calls, before)] == [
-        1, 0, 3, 0]
+        1, 0, 3, 0, 3, 0]
     assert gauge("ssd_heads_per_step", "").value == 1
     assert tuning.last_selection()["gqa_group"] == 16
     assert tuning.last_selection()["fwd_block_k"] == 1024
