@@ -47,8 +47,9 @@ SCOPES = (
     "ssm.scan", "ssm.in_proj", "ssm.conv", "ssm.dt", "ssm.gate_norm",
     "ssm.out_proj", "moe.latent_down", "moe.latent_up", "kda.scan", "kda.proj", "kda.conv", "kda.out", "kda.decay",
     "sparse.compress", "sparse.select", "sparse.attn", "lightning.proj",
-    "lightning.scan", "lightning.out", "embed.scale", "branch.scale",
-    "head.scale",
+    "lightning.scan", "lightning.out", "mamba.scan", "mamba.in_proj",
+    "mamba.conv", "mamba.x_proj", "mamba.dt", "mamba.gate",
+    "mamba.out_proj", "embed.scale", "branch.scale", "head.scale",
     # "mla.q" (q by one matrix) after "mla.q_down", which holds it
     "mla.q_down", "mla.q", "mla.kv_down", "mla.up", "attn.latent",
     "attn.gate",

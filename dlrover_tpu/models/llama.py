@@ -30,6 +30,7 @@ from dlrover_tpu.ops.attention import flash_attention
 from dlrover_tpu.ops.delta_rule import gated_delta_rule_rows
 from dlrover_tpu.ops.gated_norm import gated_group_norm, head_norm_gate
 from dlrover_tpu.ops.kda_conv import conv_silu_norm
+from dlrover_tpu.ops.selective_scan import selective_scan
 from dlrover_tpu.ops.short_conv import gated_short_conv
 from dlrover_tpu.ops.sparse_attention import (
     compress_keys, select_blocks, selected_attention,
@@ -40,13 +41,14 @@ from dlrover_tpu.ops.ssd import ssd_scan
 #: the operators a layer's kind may name
 OPERATORS = ("full_attention", "latent_attention", "linear_attention",
              "conv", "state_space", "sparse_attention",
-             "lightning_attention", "none")
+             "lightning_attention", "mamba", "none")
 #: those whose kernels reach the step outside any ``shard_map`` (the
 #: selection's compressed keys and top-k are over a whole sequence,
-#: the scan has no state to hand to a neighbour, and the partitioner
+#: the scans have no state to hand to a neighbour, and the partitioner
 #: cannot cut a Pallas call): the trainer refuses them on every mesh
-#: of more than one device, a data-parallel one too (ROADMAP B13)
-ONE_DEVICE_OPERATORS = ("sparse_attention", "lightning_attention")
+#: of more than one device, be its axis ``seq``, ``fsdp`` or ``data``
+#: (ROADMAP B13)
+ONE_DEVICE_OPERATORS = ("sparse_attention", "lightning_attention", "mamba")
 
 
 class LayerKind(NamedTuple):
@@ -58,7 +60,8 @@ class LayerKind(NamedTuple):
     a Mamba-2 mixer, ``"sparse_attention"``, full attention's q, k
     and v over the key blocks each query selects,
     ``"lightning_attention"``, linear attention with a fixed decay a
-    head, or ``"none"``), for attention the
+    head, ``"mamba"``, a Mamba-1 mixer (a selective scan whose decay
+    differs by channel and by state), or ``"none"``), for attention the
     window (None: every earlier key) and whether q and
     k are rotated, and its feed-forward (``"dense"``,
     ``"experts"`` or ``"none"``). A block of one branch (``x +
@@ -356,6 +359,23 @@ class LlamaConfig:
     # ops/ssd.py's with one head a group, a step of one and no skip.
     lightning_num_heads: int = 0
     lightning_head_dim: int = 128
+    # a Mamba-1 mixer, in the source's keys (``JambaConfig``): where
+    # ``layer_types[l]`` is "mamba", ``d = mamba_expand x hidden_size``
+    # channels of ``mamba_d_state`` states each: ``[x | z]`` by one
+    # projection without a bias, a causal depthwise convolution of
+    # ``mamba_d_conv`` taps over ``x`` with a bias a channel and
+    # ``silu``; ``[dt | B | C]`` from the convolved ``x`` by a second
+    # projection (``mamba_dt_rank`` + 2 x ``mamba_d_state`` columns; the
+    # rank is stated, nothing is derived from the width), an RMSNorm
+    # with a learned scale on each of the three;
+    # ``Delta = softplus(dt W_dt + b_dt)`` a channel, ``A =
+    # -exp(A_log)`` [d, states]; the scan is ops/selective_scan.py's;
+    # its result times ``silu(z)``, then the output projection. No norm
+    # past the gate, no head, no group.
+    mamba_expand: int = 2
+    mamba_d_state: int = 16
+    mamba_dt_rank: int = 0
+    mamba_d_conv: int = 4
 
     def __post_init__(self):
         if self.remat not in ("off", "dots", "dots_attn_out",
@@ -408,13 +428,14 @@ class LlamaConfig:
             unknown = set(self.layer_types) - {
                 "conv", "full_attention", "latent_attention",
                 "linear_attention", "sparse_attention",
-                "lightning_attention"}
+                "lightning_attention", "mamba"}
             if unknown:
                 raise ValueError(
                     f"layer_types names {sorted(unknown)}: the "
                     "operators here are 'conv', 'full_attention', "
                     "'latent_attention', 'linear_attention', "
-                    "'sparse_attention' and 'lightning_attention'"
+                    "'sparse_attention', 'lightning_attention' and "
+                    "'mamba'"
                 )
             if self.latent != ("latent_attention" in self.layer_types) or (
                     self.latent and "full_attention" in self.layer_types):
@@ -545,14 +566,21 @@ class LlamaConfig:
         types = self.layer_types or ()
         sparse = "sparse_attention" in types
         lightning = "lightning_attention" in types
-        if (sparse or lightning) and (
+        if (sparse or lightning or "mamba" in types) and (
                 self.num_experts > 0 or self.latent or self.mtp_layers
                 or self.post_norms):
             raise ValueError(
-                "'sparse_attention' and 'lightning_attention' layers "
+                "'sparse_attention', 'lightning_attention' and 'mamba' "
+                "layers "
                 "stand in a stack of dense two-branch blocks: experts, "
                 "latent attention, a prediction module or norms on a "
                 "branch's result beside them are not built"
+            )
+        if "mamba" in types and self.mamba_dt_rank < 1:
+            raise ValueError(
+                f"layer_types names 'mamba': mamba_dt_rank "
+                f"{self.mamba_dt_rank}, and the step's rank is the "
+                "source's stated one (nothing derives it)"
             )
         if sparse:
             block, stride = self.sparse_block_size, self.sparse_kernel_stride
@@ -619,6 +647,13 @@ class LlamaConfig:
             -8.0 * jnp.arange(1, heads + 1, dtype=jnp.float32) / heads)
 
     @property
+    def mamba_widths(self) -> Tuple[int, int, int]:
+        """A Mamba-1 mixer's ``(channels, states a channel, the step's
+        rank)``."""
+        return (self.mamba_expand * self.hidden_size, self.mamba_d_state,
+                self.mamba_dt_rank)
+
+    @property
     def rope_dim(self) -> int:
         """How many of a head's columns the rotary embedding turns."""
         return self.qk_rope_head_dim if self.latent else self.head_dim
@@ -673,7 +708,7 @@ class LlamaConfig:
             LayerKind(
                 operator, *(
                     (None, False)
-                    if operator in ("conv", "linear_attention")
+                    if operator in ("conv", "linear_attention", "mamba")
                     else (windows[i], ropes[i])
                 ),
                 "experts" if self.num_experts > 0
@@ -848,6 +883,15 @@ def _leaves(cfg: LlamaConfig, kind: LayerKind) -> Dict:
             "ssm_out": ((inner, h), ("mlp", "embed")),
         }
         norms["ssm_norm"] = inner
+    elif kind.operator == "mamba":
+        d, n, rank = cfg.mamba_widths
+        matrices = {
+            "mamba_in": ((h, 2 * d), ("embed", "mlp")),  # [x | z]
+            "mamba_x": ((d, rank + 2 * n), ("mlp", None)),  # [dt | B | C]
+            "mamba_dt": ((rank, d), (None, "mlp")),
+            "mamba_out": ((d, h), ("mlp", "embed")),
+        }
+        norms.update(mamba_dt_norm=rank, mamba_b_norm=n, mamba_c_norm=n)
     elif kind.operator == "conv":
         matrices = {
             "conv_in": ((h, 3 * h), ("embed", "mlp")),
@@ -975,6 +1019,16 @@ def _leaves(cfg: LlamaConfig, kind: LayerKind) -> Dict:
         leaves["A_log"] = ((heads,), ("norm",), "A_log")
         leaves["dt_bias"] = ((heads,), ("norm",), "dt_bias")
         leaves["D"] = ((heads,), ("norm",), None)
+    if kind.operator == "mamba":
+        taps = cfg.mamba_d_conv
+        leaves["mamba_conv_w"] = ((d, taps), ("mlp", None), taps ** -0.5)
+        leaves["mamba_conv_b"] = ((d,), ("norm",), 0)
+        # float32: the rates a channel and state at the family's
+        # S4D-real start, the step's bias by the draw of its own, the
+        # skip ``D`` at one
+        leaves["A_log"] = ((d, n), ("mlp", None), "A_log_s4d")
+        leaves["dt_bias"] = ((d,), ("norm",), "dt_bias")
+        leaves["D"] = ((d,), ("norm",), None)
     if kind.ffn == "experts" and cfg.use_expert_bias:
         leaves["expert_bias"] = ((cfg.num_experts,), (None,), 0)
     return leaves
@@ -996,7 +1050,8 @@ _DRAW = {"wq": 0, "wk": 1, "wv": 2, "wo": 3, "w_gate": 4, "w_up": 5,
          "conv_k": 16, "conv_v": 17, "f_a": 18, "f_b": 19, "g_a": 20,
          "g_b": 21, "w_beta": 22, "A_log": 23, "dt_bias": 24, "wg": 25,
          "ssm_in": 26, "ssm_out": 27, "ssm_conv_w": 28,
-         "w_latent_down": 29, "w_latent_up": 30}
+         "w_latent_down": 29, "w_latent_up": 30, "mamba_in": 31,
+         "mamba_x": 32, "mamba_dt": 33, "mamba_out": 34, "mamba_conv_w": 35}
 
 
 def _draw_A_log(key, shape):
@@ -1013,8 +1068,16 @@ def _draw_dt_bias(key, shape):
     return dt + jnp.log(-jnp.expm1(-dt))
 
 
-#: the decay's two vectors, kept in float32: how each is drawn
-_DECAY_DRAWS = {"A_log": _draw_A_log, "dt_bias": _draw_dt_bias}
+def _start_A_log(key, shape):
+    """``log`` of a Mamba-1 channel's rates at the S4D-real start:
+    state ``n`` of every channel decays at ``n + 1``. No draw."""
+    return jnp.broadcast_to(
+        jnp.log(jnp.arange(1, shape[-1] + 1, dtype=jnp.float32)), shape)
+
+
+#: the decay's leaves, kept in float32: how each is drawn
+_DECAY_DRAWS = {"A_log": _draw_A_log, "dt_bias": _draw_dt_bias,
+                "A_log_s4d": _start_A_log}
 
 
 def _init_layers(key, cfg: LlamaConfig, kind: LayerKind, stack=()):
@@ -1333,6 +1396,8 @@ def _pre_attn(cfg: LlamaConfig, x, layer_params, cos, sin,
     y = rms_norm(constrain(x, _RESIDUAL), p["attn_norm"], cfg.norm_eps)
     if kind.operator == "state_space":
         return _ssm_operands(cfg, y, p, constrain), logits()
+    if kind.operator == "mamba":
+        return _mamba_operands(cfg, y, p, constrain), logits()
     if kind.operator == "conv":
         with jax.named_scope("conv.in_proj"):
             bcu = constrain(y @ p["conv_in"], _MLP)
@@ -1388,6 +1453,39 @@ def _ssm_operands(cfg: LlamaConfig, y, p, constrain=_free):
         dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
         rate = -jnp.exp(p["A_log"])
     return xbc, dt, rate, p["D"], z, p["ssm_norm"]
+
+
+def _mamba_operands(cfg: LlamaConfig, y, p, constrain=_free):
+    """A Mamba-1 mixer's operands from the normed stream ``y``, in
+    rows as ``ops/selective_scan.py selective_scan`` takes them: ``(x
+    [b, s, d] past its convolution, the step Delta [b, s, d] in
+    float32, B and C [b, s, n] past their norms, the rates A [d, n]
+    (negative) in float32, the skip D [d], the gate's pre-activation z
+    [b, s, d])``. The scopes name every op: ``mamba.in_proj`` the one
+    projection ``[x | z]`` and its two slices, ``mamba.conv`` the
+    convolution with its bias and ``silu`` (``ops/kda_conv.py``: on the
+    TPU one Pallas pass each way), ``mamba.x_proj`` the second
+    projection ``[dt | B | C]`` off the convolved ``x`` with the three
+    norms, ``mamba.dt`` the low-rank step (its product kept in
+    float32), its bias and softplus, and the rates."""
+    d, n, rank = cfg.mamba_widths
+    with jax.named_scope("mamba.in_proj"):
+        x, z = jnp.split(constrain(y @ p["mamba_in"], _MLP), 2, axis=-1)
+    with jax.named_scope("mamba.conv"):
+        x = conv_silu_norm(
+            x, p["mamba_conv_w"], bias=p["mamba_conv_b"])
+    with jax.named_scope("mamba.x_proj"):
+        dt, B, C = (
+            rms_norm(a, p[f"mamba_{name}_norm"], cfg.norm_eps)
+            for name, a in zip(("dt", "b", "c"), jnp.split(
+                x @ p["mamba_x"], [rank, rank + n], axis=-1))
+        )
+    with jax.named_scope("mamba.dt"):
+        delta = jax.nn.softplus(jnp.matmul(
+            dt, p["mamba_dt"], preferred_element_type=jnp.float32,
+        ) + p["dt_bias"])
+        rates = -jnp.exp(p["A_log"])
+    return x, delta, B, C, rates, p["D"], z
 
 
 def _delta_rule_operands(cfg: LlamaConfig, y, p, constrain=_free):
@@ -1591,6 +1689,9 @@ def _operator_out(x, out, layer_params, kind: LayerKind,
     if kind.operator == "state_space":
         with jax.named_scope("ssm.out_proj"):
             return out @ p["ssm_out"]
+    if kind.operator == "mamba":
+        with jax.named_scope("mamba.out_proj"):
+            return out @ p["mamba_out"]
     if kind.operator == "conv":
         with jax.named_scope("conv.out_proj"):
             return out @ p["conv_out"]
@@ -1736,7 +1837,9 @@ def _operator_of(cfg: LlamaConfig, attn_fn, kind: LayerKind):
     ``sparse.compress``, ``sparse.select`` and ``sparse.attn``
     (ops/sparse_attention.py; ``attn_fn`` under ``attn.full`` on a
     sequence within ``sparse_dense_len``), a lightning layer's
-    ``lightning.scan``, the state-space scan's own entry."""
+    ``lightning.scan``, the state-space scan's own entry; a Mamba-1
+    mixer's ``mamba.scan`` (ops/selective_scan.py) and, on its result,
+    ``mamba.gate``."""
     if kind.operator == "none":
         return lambda: None
     if kind.operator == "state_space":
@@ -1757,6 +1860,17 @@ def _operator_of(cfg: LlamaConfig, attn_fn, kind: LayerKind):
                 return gated_group_norm(o, z, scale, groups, cfg.norm_eps)
 
         return scan
+    if kind.operator == "mamba":
+
+        def selective(x, delta, B, C, rates, skip, z):
+            with jax.named_scope("mamba.scan"):
+                o = selective_scan(x, delta, B, C, rates, skip)
+            # the gate, in float32 and rounded once; no norm follows
+            with jax.named_scope("mamba.gate"):
+                return (o.astype(jnp.float32) * jax.nn.silu(
+                    z.astype(jnp.float32))).astype(o.dtype)
+
+        return selective
     if kind.operator == "conv":
 
         def mix(bcu, w):
@@ -2676,8 +2790,17 @@ def decay_min(params: Dict, tokens: jax.Array, cfg: LlamaConfig,
     takes a step under ``exp(-10)`` as ``exp(-10)`` (ops/delta_rule.py
     ``G_FLOOR``), and this is the number that says whether a run's
     channels get there. A state-space layer's is the least ``a =
-    exp(A Delta)`` of a head (ops/ssd.py has no floor)."""
+    exp(A Delta)`` of a head (ops/ssd.py has no floor), a Mamba-1
+    mixer's the least ``exp(Delta_t[d] A[d, n])`` of a channel's
+    states (ops/selective_scan.py has none either)."""
     def see(kind, x, p, operands, out, logits):
+        if kind.operator == "mamba":
+            # the largest step of a channel against its fastest rate:
+            # no array of [seq, channels, states]
+            _, delta, _, _, rates = operands[:5]
+            return jnp.exp(jnp.min(
+                jnp.max(delta, axis=(0, 1)) * jnp.min(rates, axis=1)
+            )), logits
         if kind.operator == "state_space":
             _, dt, rate = operands[:3]  # a head's a = exp(A Delta)
             return jnp.exp(jnp.min(dt * rate)), logits
@@ -2708,7 +2831,8 @@ def flops_per_token(cfg: LlamaConfig, seq_len: int) -> float:
     """Approximate training FLOPs per token (6N_active + attention
     quadratic, at ``num_heads x head_dim`` and by each layer's kind;
     the convolution's taps are not counted, and of the gated delta
-    rule, of a state-space mixer and of a lightning layer the
+    rule, of a state-space mixer, of a lightning layer and of a
+    Mamba-1 mixer the
     projections and low ranks but not the recurrence; sparse
     attention at the keys of a query's ``sparse_topk`` blocks, the
     selection's own scores not counted), a

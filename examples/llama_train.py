@@ -232,7 +232,8 @@ def main():
     if cfg.total_ut_steps > 1:
         loop_stats = jax.jit(functools.partial(llama.loop_stats, cfg=cfg))
     decay_min = None
-    state_space = "M" in (cfg.hybrid_override_pattern or "")
+    state_space = "M" in (cfg.hybrid_override_pattern or "") or (
+        "mamba" in (cfg.layer_types or ()))
     if "linear_attention" in (cfg.layer_types or ()) or state_space:
         decay_min = jax.jit(functools.partial(llama.decay_min, cfg=cfg))
 

@@ -176,6 +176,21 @@ MODULE_BUDGET_OVERRIDES = {
     "test_llama_latent_pattern": 180.0,
     # two whole rehearsals of the new cell, launcher to last line: 69s
     "test_yardstick_nemotron_rehearsal": 150.0,
+    # one whole rehearsal of a fourteen-layer period, every layer a
+    # position of its own in the step's program (PR 68): 45s alone,
+    # 82s beside three other workers
+    "test_yardstick_jamba_rehearsal": 150.0,
+    # the same period against the float32 reference and its gradients,
+    # eleven edited references on two batches (PR 68): 57s alone, 79s
+    # beside three other workers, 184s beside five that compile too
+    "test_yardstick_jamba": 300.0,
+    # the selective scan's Pallas kernels in interpret mode, forward
+    # and backward on four shapes (PR 68): 50s alone, 76s beside three
+    # other workers
+    "test_selective_scan": 150.0,
+    # a four-layer stack of mixers jitted forward and backward under
+    # each remat policy (PR 68): 40s alone
+    "test_llama_mamba": 120.0,
     # Pallas kernels in interpret mode, since PR 39 the in-place sum
     # against megablox's on seven pieces: 47s alone, 71s beside five
     # other workers

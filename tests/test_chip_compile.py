@@ -2244,6 +2244,145 @@ def test_sala_step_holds_the_selections_and_the_scans_kernels(
         assert scope in text, scope
 
 
+#: ``peak_memory_in_bytes`` of ``jamba2-3b-l14.steady``'s step as this
+#: file compiles it (1 x 8,192, fourteen layers, the whole vocabulary
+#: tied, remat ``minimal``, the least effort; PERF.md, PR 68): 9.59 GB
+#: of it the state. 12,325,139,456 at the default effort, which the
+#: chip compiles at (the file's ``depth``)
+JAMBA_STEP_BYTES = 11_957_564_928
+
+
+def _selective_scan_on_tpu_path(monkeypatch):
+    from dlrover_tpu.ops import selective_scan
+    from dlrover_tpu.ops.pallas import selective_scan as kernels
+
+    monkeypatch.setattr(
+        selective_scan, "_use_pallas", lambda x, B: (
+            kernels.tiles_the_kernel(x.shape, B.shape)))
+    monkeypatch.setattr(kernels, "_interpret", lambda: False)
+    return selective_scan
+
+
+def test_selective_scan_kernels_compile_at_the_cells_shape(topo, monkeypatch):
+    """The selective scan's forward and backward kernels at the cell's
+    shape (one sequence of 8,192, 5,120 channels of 16 states, rows in
+    bf16, the step and the rates in float32): five tiles of 1,024
+    lanes, 128 chunks of 64, and the entry states kept for the backward
+    pass."""
+    selective_scan = _selective_scan_on_tpu_path(monkeypatch)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def of(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (
+        of((1, 8192, 5120), jnp.bfloat16), of((1, 8192, 5120), jnp.float32),
+        of((1, 8192, 16), jnp.bfloat16), of((1, 8192, 16), jnp.bfloat16),
+        of((5120, 16), jnp.float32), of((5120,), jnp.float32),
+    )
+
+    def loss(*operands):
+        return selective_scan.selective_scan(
+            *operands).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+        *args).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "f32[1,128,16,5120]" in text  # the chunks' entry states
+    # B and C by groups of eight positions, and their gradients
+    assert text.count("f32[1,1024,16,128]") >= 4
+    # no array of a state a position, in any layout
+    assert not re.search(r"\[(\d+,)*8192,(5120,16|16,5120)\]", text)
+
+
+def test_jamba_step_holds_the_scans_kernels(topo, on_tpu_path, monkeypatch):
+    """``jamba2-3b-l14.steady``'s step: it fits under 15.75 GiB and
+    plans no more than was read when the cell was built; the thirteen
+    mixers' scans (each the forward, the forward again under
+    ``minimal`` and the backward over ``[1, 128, 16, 5120]`` entry
+    states) are named as ``selective_scan_ms`` tells them, and as no
+    other reader does, and carry ``mamba.scan``; a mixer's convolution
+    with its bias is three Pallas calls under ``mamba.conv`` by the
+    jitted name ``kda_conv``, which no reader's pattern matches; the
+    one attention layer runs the flash kernels at a group of 20 on one
+    key head (the forward, the forward again, the one backward kernel),
+    so no ``[20, 8192, 8192]`` scores are in the step; every call of
+    the two entries took the kernels; no array of ``[8192, 5120, 16]``
+    is in the step in any layout; no table of angles is built; and
+    every scope of the mixer is in the text."""
+    from dlrover_tpu.ops import kda_conv
+    from dlrover_tpu.ops.pallas import kda_conv as conv_kernels
+    from dlrover_tpu.telemetry.registry import counter
+    from yardstick import cells, worker
+    from yardstick.layer_metrics import (
+        attn_kernel_ms, delta_rule_ms, moe_expert_ms, selective_scan_ms,
+        short_conv_ms, ssd_ms,
+    )
+
+    _selective_scan_on_tpu_path(monkeypatch)
+    monkeypatch.setattr(
+        kda_conv, "_use_pallas", lambda x, w, l2_heads: (
+            conv_kernels.tiles_the_kernel(x.shape, w.shape, l2_heads)))
+    monkeypatch.setattr(conv_kernels, "_interpret", lambda: False)
+    calls = [counter(f"{entry}_{path}_calls", "")
+             for entry in ("selective_scan", "kda_conv")
+             for path in ("kernel", "plain")]
+    before = [c.value for c in calls]
+    _, config, traffic = cells.load_cell("jamba2-3b-l14.steady")
+    cfg = worker.program_config(config, traffic)
+    assert (cfg.remat, cfg.loss_chunk) == ("minimal", 0)
+    # PR 66's rules at a group of 20, which is no power of two
+    assert tuning.heuristic_blocks(8192, 20) == (128, 256)
+    assert tuning.forward_key_block(8192, 20, (128, 256)) == 512
+    mesh = Mesh(
+        np.array(topo.devices[:1]).reshape(1, 1), ("data", "fsdp"))
+    trainer = make_trainer_for_llama(
+        cfg, mesh, strategy=traffic["strategy"],
+        optimizer=optax.adamw(traffic["optimizer"]["learning_rate"]),
+    )
+    compiled = trainer.train_step.lower(*_abstract_step_args(
+        trainer, traffic["global_batch"], traffic["seq"])
+    ).compile(LEAST_EFFORT)
+    planned = compiled.memory_analysis().peak_memory_in_bytes
+    print("jamba step plans", planned)
+    assert planned <= JAMBA_STEP_BYTES < 15.75 * 2 ** 30
+    text = compiled.as_text()
+    kernels = re.findall(
+        r"%([\w.\-]+) = ([^\n]*?) custom-call\([^\n]*"
+        r"custom_call_target=\"tpu_custom_call\"[^\n]*"
+        r"op_name=\"([^\"]*)\"", text)
+    readers = (attn_kernel_ms, delta_rule_ms, moe_expert_ms, short_conv_ms,
+               ssd_ms, selective_scan_ms)
+    scan = [(name, op) for name, _, op in kernels
+            if selective_scan_ms.KERNEL.search(name)]
+    assert len(scan) == 13 * 3, [name for name, _ in scan]
+    assert all("mamba.scan" in op for _, op in scan)
+    conv = [name for name, _, op in kernels if "mamba.conv" in op]
+    assert len(conv) == 13 * 3 and all(
+        name.startswith("kda_conv") for name in conv)
+    attend = [(name, op) for name, _, op in kernels
+              if attn_kernel_ms.KERNEL.search(name)]
+    assert len(attend) == 3 and all("attn.full" in op for _, op in attend)
+    assert len(kernels) == 39 + 39 + 3  # and no other kernel
+    for name, _, op in kernels:
+        found = [r for r in readers if r.KERNEL.search(name)]
+        assert len(found) == ("mamba.conv" not in op), name
+    assert "f32[1,128,16,5120]" in text  # the chunks' entry states
+    assert not re.search(r"\[(\d+,)*8192,(5120,16|16,5120)\]", text)
+    assert not re.search(r"\[(\d+,)*20,8192,8192\]", text)
+    assert fa._one_backward_kernel(20, 8192, 128)
+    selection = tuning.last_selection()
+    assert (selection["gqa_group"], selection["block_q"],
+            selection["block_k"], selection["fwd_block_k"]) == (
+                20, 128, 256, 512)
+    # a call a mixer: the remat's second forward reuses its trace
+    assert [c.value - was for c, was in zip(calls, before)] == 2 * [13, 0]
+    assert "cosine" not in text and "sine" not in text  # no rotary table
+    for scope in ("mamba.in_proj", "mamba.conv", "mamba.x_proj", "mamba.dt",
+                  "mamba.scan", "mamba.gate", "mamba.out_proj", "attn.full"):
+        assert scope in text, scope
+
+
 #: ``peak_memory_in_bytes`` of ``ouro-2.6b-1chip.steady``'s step as
 #: this file compiles it (1 x 8,192, 16 layers walked four times,
 #: remat ``minimal``, the least effort; PERF.md, PR 58): 6.14 GB of it

@@ -177,7 +177,7 @@ def test_a_sequence_within_dense_len_takes_full_attention():
     ("lightning_num_heads", 0, "lightning_num_heads 0"),
     ("lightning_head_dim", 32, "one table of angles"),
     ("total_ut_steps", 2, "looped stack"),
-    ("layer_types", ("sparse_attention", "mamba", "conv", "conv"),
+    ("layer_types", ("sparse_attention", "retention", "conv", "conv"),
      "layer_types names"),
 ])
 def test_what_is_not_built_is_refused(field, value, says):
