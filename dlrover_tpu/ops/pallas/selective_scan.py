@@ -23,20 +23,37 @@ VMEM (``[channels / LANES, n, LANES]``: 320 KB at 5,120 channels of
 - the result, a sum down the sublanes, written a row.
 
 The grid is ``(batch, chunks, channel tiles)``, the tiles innermost:
-``B``'s and ``C``'s blocks stay where they are through a chunk's tiles,
-and their gradients, sums over every channel, are summed in one
-resident block a chunk. ``A``'s gradient, a sum over every position, and
-``D``'s are float32 blocks resident through the whole grid, as
-``short_conv``'s taps and ``gated_norm``'s scales are.
+``B``'s and ``C``'s blocks stay where they are through a chunk's tiles.
+``A``'s gradient, a sum over every position, and ``D``'s are float32
+blocks resident through the whole grid, as ``short_conv``'s taps and
+``gated_norm``'s scales are.
+
+What the backward's position cannot afford is the unit that moves
+values across lanes (PERF.md section 6, PR 69): a column spread along
+the lanes and a sum across them queue there, and with four of each a
+position the kernel waited on that unit more than it computed, whatever
+the tile's width. So the backward kernel
+
+- spreads ``B_t`` and ``C_t`` along the lanes once a chunk, at its
+  first tile, into VMEM (``[CHUNK, n, 128]`` float32 each): every tile
+  of the chunk, making the states again and walking them down, reads a
+  position's column as two whole registers;
+- sums ``B``'s and ``C``'s gradients, sums over every channel, over the
+  tile's lane tiles only at a position (adds of whole registers) and
+  adds them to a lane tile of the position's own in VMEM (``[CHUNK / 8,
+  n, 8 x 128]`` float32 each, summed over the chunk's channel tiles
+  where it lies); the 128 lanes are summed once a chunk, after its last
+  tile, position ``8 g + i`` into lane ``i`` of group ``g``, as ``B``
+  and ``C`` came in.
 
 The forward kernel goes up the sequence. Differentiated, it also writes
 each chunk's entry state (``[batch, chunks, n, channels]`` float32: 42
 MB a layer at 8,192 positions, alive for that layer's backward only).
 The backward kernel goes down the sequence over them: a chunk's states
 are made again from its entry state into VMEM (``[CHUNK + 1, n,
-LANES]``, 4.3 MB), then walked in reverse with the state's cotangent
-carried. No division by a decay: ``h_{t-1}`` is read, never recovered
-from ``h_t``.
+LANES]``, 4.3 MB of the 11 MB the call holds there), then walked in
+reverse with the state's cotangent carried. No division by a decay:
+``h_{t-1}`` is read, never recovered from ``h_t``.
 
 Both calls are made inside one jitted function, ``selective_scan``: a
 device trace names a Pallas call after the innermost jitted function
@@ -59,11 +76,13 @@ LANE = 128
 SUBLANES = 8
 GROUP = 8
 #: the channels of a grid step: the widest of these that divides them
-#: (at 5,120 channels a layer's forward with its backward read 8.1 ms
-#: at 1,024, 12.0 at 512 and 20.9 at 256: PERF.md section 6, PR 68).
-#: Only 1,024 has a benchmark cell behind it; the narrower widths are
-#: for channel counts that 1,024 does not divide, and no workload
-#: measures them
+#: (at 5,120 channels a layer's forward with its backward read 6.9 ms
+#: at 1,024, 6.9 at 512 and 8.6 at 256, the forward alone 2.1, 2.4 and
+#: 3.6, the backward alone 5.0, 4.7 and 5.3: PERF.md section 6, PR 69;
+#: 8.1, 12.0 and 20.9 ms while the backward crossed lanes at every
+#: position). Only 1,024 has a benchmark cell behind it; the narrower
+#: widths are for channel counts that 1,024 does not divide, and no
+#: workload measures them
 LANES = (1024, 512, 256, 128)
 
 F32 = jnp.float32
@@ -91,23 +110,17 @@ def _row(ref, t):
     return ref[pl.ds(t, 1), :]
 
 
-def _states_up(dl_ref, u_ref, bg_ref, A, h, keep=None, out=None):
+def _states_up(dl_ref, u_ref, bg_ref, cg_ref, y_ref, A, h):
     """The chunk's recurrence from the entry state ``h`` [n, lanes]:
-    its last state. ``keep`` [CHUNK + 1, n, lanes] gets every state
-    (``h_t`` at ``t + 1``); ``out`` ``(cg_ref, y_ref)`` the results'
-    rows."""
+    the results' rows into ``y_ref``, and the last state."""
     def group(g, h):
-        bt = bg_ref[g]
-        ct = None if out is None else out[0][g]
+        bt, ct = bg_ref[g], cg_ref[g]
         for i in range(GROUP):
             t = g * GROUP + i
             h = (jnp.exp(_row(dl_ref, t) * A) * h
                  + bt[:, i:i + 1] * _row(u_ref, t))
-            if keep is not None:
-                keep[t + 1] = h
-            if out is not None:
-                out[1][pl.ds(t, 1), :] = jnp.sum(
-                    h * ct[:, i:i + 1], axis=0, keepdims=True)
+            y_ref[pl.ds(t, 1), :] = jnp.sum(
+                h * ct[:, i:i + 1], axis=0, keepdims=True)
         return h
 
     return jax.lax.fori_loop(0, CHUNK // GROUP, group, h)
@@ -127,13 +140,34 @@ def _fwd_kernel(x_ref, dl_ref, bg_ref, cg_ref, a_ref, d_ref, o_ref, *rest):
     if entry_ref:
         entry_ref[0][...] = h
     h_scr[tile] = _states_up(
-        dl_ref, u_scr, bg_ref, a_ref[...], h, out=(cg_ref, y_scr))
+        dl_ref, u_scr, bg_ref, cg_ref, y_scr, a_ref[...], h)
     o_ref[...] = (y_scr[...] + d_ref[...] * x).astype(o_ref.dtype)
+
+
+def _over_lane_tiles(p):
+    """``[n, lanes]`` summed over its lane tiles, ``[n, 128]``: adds of
+    whole registers."""
+    parts = [p[:, k:k + LANE] for k in range(0, p.shape[1], LANE)]
+    return sum(parts[1:], parts[0])
+
+
+def _over_lanes(scr):
+    """``[groups, n, GROUP x 128]``, a position of a group a lane tile,
+    summed over each tile's lanes into ``[groups, n, 128]`` with
+    position ``i`` in lane ``i``."""
+    groups, n, _ = scr.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (groups, n, LANE), 2)
+    out = jnp.zeros((groups, n, LANE), F32)
+    for i in range(GROUP):
+        out = jnp.where(lane == i, jnp.sum(
+            scr[:, :, i * LANE:(i + 1) * LANE], axis=2, keepdims=True), out)
+    return out
 
 
 def _bwd_kernel(x_ref, dl_ref, do_ref, bg_ref, cg_ref, a_ref, d_ref,
                 entry_ref, dx_ref, ddl_ref, dbg_ref, dcg_ref, da_ref,
-                dd_ref, g_scr, h_scr, u_scr, du_scr, do_scr):
+                dd_ref, g_scr, h_scr, u_scr, du_scr, do_scr, b_scr, c_scr,
+                db_scr, dc_scr):
     last_chunk = pl.program_id(1) == 0  # the grid walks the chunks down
     tile = pl.program_id(2)
 
@@ -148,52 +182,74 @@ def _bwd_kernel(x_ref, dl_ref, do_ref, bg_ref, cg_ref, a_ref, d_ref,
 
     @pl.when(tile == 0)
     def _():
-        dbg_ref[...] = jnp.zeros_like(dbg_ref)
-        dcg_ref[...] = jnp.zeros_like(dcg_ref)
+        db_scr[...] = jnp.zeros_like(db_scr)
+        dc_scr[...] = jnp.zeros_like(dc_scr)
+
+        # B_t and C_t along the lanes, once for all the chunk's tiles
+        def group(g, _):
+            bt, ct = bg_ref[g], cg_ref[g]
+            for i in range(GROUP):
+                b_scr[g * GROUP + i] = jnp.broadcast_to(
+                    bt[:, i:i + 1], bt.shape)
+                c_scr[g * GROUP + i] = jnp.broadcast_to(
+                    ct[:, i:i + 1], ct.shape)
+
+        jax.lax.fori_loop(0, CHUNK // GROUP, group, None)
 
     x = x_ref[...].astype(F32)
     do = do_ref[...].astype(F32)
     do_scr[...] = do
     u_scr[...] = dl_ref[...] * x
     A = a_ref[...]
+
+    def column(scr, t):  # [n, 128], every lane the same, to the tile
+        return jnp.concatenate([scr[t]] * (A.shape[1] // LANE), axis=1)
+
+    def up(g, h):  # the chunk's states again, h_t at t + 1
+        for i in range(GROUP):
+            t = g * GROUP + i
+            h = (jnp.exp(_row(dl_ref, t) * A) * h
+                 + column(b_scr, t) * _row(u_scr, t))
+            h_scr[t + 1] = h
+        return h
+
     h_scr[0] = entry_ref[...]
-    _states_up(dl_ref, u_scr, bg_ref, A, entry_ref[...], keep=h_scr)
+    jax.lax.fori_loop(0, CHUNK // GROUP, up, entry_ref[...])
 
-    lane = jax.lax.broadcasted_iota(jnp.int32, (A.shape[0], LANE), 1)
-
-    def group(k, carry):
+    def down(k, carry):
         g, dA = carry
         at = CHUNK // GROUP - 1 - k
-        bt, ct = bg_ref[at], cg_ref[at]
-        dbt, dct = jnp.zeros_like(bt), jnp.zeros_like(ct)
         for i in reversed(range(GROUP)):
             t = at * GROUP + i
             delta, do_t = _row(dl_ref, t), _row(do_scr, t)
-            g = g + ct[:, i:i + 1] * do_t
-            # B's and C's gradients: sums over the channels, a column
-            # a position into the group's lane
-            dct = jnp.where(lane == i, jnp.sum(
-                h_scr[t + 1] * do_t, axis=1, keepdims=True), dct)
-            dbt = jnp.where(lane == i, jnp.sum(
-                g * _row(u_scr, t), axis=1, keepdims=True), dbt)
+            g = g + column(c_scr, t) * do_t
+            # B's and C's gradients, sums over the channels: over the
+            # lane tiles here, into the position's own lane tile of the
+            # chunk's sums; over the lanes once a chunk, below
+            mine = pl.ds(i * LANE, LANE)
+            dc_scr[at, :, mine] += _over_lane_tiles(h_scr[t + 1] * do_t)
+            db_scr[at, :, mine] += _over_lane_tiles(g * _row(u_scr, t))
             du_scr[pl.ds(t, 1), :] = jnp.sum(
-                g * bt[:, i:i + 1], axis=0, keepdims=True)
+                g * column(b_scr, t), axis=0, keepdims=True)
             g = g * jnp.exp(delta * A)  # the cotangent of h_{t-1}
             q = g * h_scr[t]  # d a_t's, times a_t
             dA = dA + q * delta
             ddl_ref[pl.ds(t, 1), :] = jnp.sum(q * A, axis=0, keepdims=True)
-        dbg_ref[at] += dbt
-        dcg_ref[at] += dct
         return g, dA
 
     g, dA = jax.lax.fori_loop(
-        0, CHUNK // GROUP, group, (g_scr[tile], jnp.zeros_like(A)))
+        0, CHUNK // GROUP, down, (g_scr[tile], jnp.zeros_like(A)))
     g_scr[tile] = g
     da_ref[tile] += dA
     dd_ref[tile] += jnp.sum(do * x, axis=0, keepdims=True)
     du = du_scr[...]  # the cotangent of Delta x
     ddl_ref[...] = ddl_ref[...] + du * x
     dx_ref[...] = (du * dl_ref[...] + d_ref[...] * do).astype(dx_ref.dtype)
+
+    @pl.when(tile == pl.num_programs(2) - 1)
+    def _():
+        dbg_ref[...] = _over_lanes(db_scr)
+        dcg_ref[...] = _over_lanes(dc_scr)
 
 
 def _specs(chunks, wide, n, reverse):
@@ -280,6 +336,10 @@ def _backward(x, dl, bg, cg, at, d, entry, do):
             pltpu.VMEM((CHUNK, wide), F32),
             pltpu.VMEM((CHUNK, wide), F32),
             pltpu.VMEM((CHUNK, wide), F32),
+            pltpu.VMEM((CHUNK, n, LANE), F32),
+            pltpu.VMEM((CHUNK, n, LANE), F32),
+            pltpu.VMEM((CHUNK // GROUP, n, GROUP * LANE), F32),
+            pltpu.VMEM((CHUNK // GROUP, n, GROUP * LANE), F32),
         ],
         compiler_params=_params(), interpret=_interpret(),
     )(x, dl, do, bg, cg, at, d, entry)

@@ -75,19 +75,25 @@ def test_plain_path_is_the_recurrence(seq, chunk):
         close(g, w, 2e-5, name)
 
 
-@pytest.mark.parametrize("d,rate", [(256, 1.0), (640, 0.05), (1024, 30.0)])
-def test_kernels_are_the_plain_path(d, rate):
+@pytest.mark.parametrize("d,rate,seq", [
+    (256, 1.0, 128), (640, 0.05, 128), (1024, 30.0, 128),
+    (2048, 0.3, 192),
+])
+def test_kernels_are_the_plain_path(d, rate, seq):
     """Interpret mode: a tile of 256 lanes, five of 128 (what 5,120
-    channels are to 1,024), one of 1,024; two chunks a sequence, two
-    sequences; a slow, a usual and a fast decay. Forward, the forward
-    that keeps the entry states, and every gradient."""
-    ops = operands(d=d, rate=rate)
+    channels are to 1,024), one of 1,024 and two of 1,024; two chunks a
+    sequence and three, two sequences; a slow, a usual and a fast
+    decay. Forward, the forward that keeps the entry states, and every
+    gradient: ``B``'s and ``C``'s are sums over a chunk's channel tiles
+    in the backward kernel's scratch, then over the lanes once a
+    chunk."""
+    ops = operands(d=d, rate=rate, s=seq)
     assert kernels.tiles_the_kernel(ops[0].shape, ops[2].shape)
     want = entry.selective_scan_plain(*ops)
     close(kernels.selective_scan_tpu(*ops), want, 1e-5, "o")
     o, states = kernels.selective_scan(*ops, keep_states=True)
     close(o, want, 1e-5, "o beside the states")
-    assert states.shape == (2, 128 // entry.CHUNK, 16, d)
+    assert states.shape == (2, seq // entry.CHUNK, 16, d)
     assert not states[:, 0].any() and states[:, 1].any()
     weights = jax.random.normal(jax.random.key(5), want.shape)
     for name, g, w in zip(NAMES, gradients(
