@@ -35,6 +35,16 @@ cost) and one whose ``_inverse`` makes no product (the forward's body
 without its longest chain; the backward makes no inverse, so its
 reading there is its own).
 
+``--a-head`` takes the other form of decay, one a head (``g`` in
+``beta``'s shape), on heads of ``--key-dim`` keys by ``--value-dim``
+values: ``olmo-hybrid-7b-vp8.steady``'s shape is ``--a-head --heads 30
+--seq 16384 --key-dim 96 --value-dim 192`` (``delta_rule`` pads a head
+to 128 x 256 inside, so beside the calls as the step makes them it
+times the kernels alone on operands that come padded: the difference
+is the pads' and the cuts'); the least time is
+``yardstick/families/olmo_hybrid.py delta_rule_step``'s bytes, and the
+decays compared may pass ``G_FLOOR``, which this form does not have.
+
 On no cell's path. Only a TPU run says anything:
 ``chiprun -- python3 benchmarks/profile_delta_rule.py``.
 """
@@ -59,7 +69,7 @@ from dlrover_tpu.ops.pallas import delta_rule as kernels  # noqa: E402
 
 HBM_BYTES_PER_S = 819e9  # yardstick/peaks.json, "TPU v5 lite"
 NAMES = ("q", "k", "v", "g", "beta")
-RULE = kernels.HEADS_A_STEP
+RULE, FORWARD_RULE = kernels.HEADS_A_STEP, kernels.FORWARD_HEADS_A_STEP
 
 
 def timed(fn, *args, n=10):
@@ -71,18 +81,30 @@ def timed(fn, *args, n=10):
     return (time.perf_counter() - t0) / n
 
 
-def operands(batch, seq, heads, decay, dtype, seed=0):
+def operands(batch, seq, heads, decay, dtype, seed=0, dk=kernels.HEAD,
+             dv=kernels.HEAD, a_head=False):
+    """``(q, k, v, g, beta), do`` on heads of ``dk`` keys by ``dv``
+    values; ``g`` a number a channel, or with ``a_head`` one a head."""
     keys = jax.random.split(jax.random.key(seed), 6)
-    shape = (batch, seq, heads, kernels.HEAD)
+    shape, wide = (batch, seq, heads, dk), (batch, seq, heads, dv)
     # keys that resemble each other, as silu's leave them
     q, k = (jax.nn.silu(jax.random.normal(key, shape)) for key in keys[:2])
     q = q / jnp.linalg.norm(q, axis=-1, keepdims=True)
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
-    v = jax.nn.silu(jax.random.normal(keys[2], shape))
-    g = -decay * jax.random.uniform(keys[3], shape, minval=0.2)
+    v = jax.nn.silu(jax.random.normal(keys[2], wide))
+    g = -decay * jax.random.uniform(
+        keys[3], shape[:3] if a_head else shape, minval=0.2)
     beta = 2 * jax.nn.sigmoid(jax.random.normal(keys[4], shape[:3]))
-    do = jax.random.normal(keys[5], shape).astype(dtype)
+    do = jax.random.normal(keys[5], wide).astype(dtype)
     return (q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta), do
+
+
+def as_rows(ops):
+    """The operands as the rows the step hands over: a head's columns
+    side by side, ``g`` a head's number as it is."""
+    return (*(x.reshape(*x.shape[:2], -1) for x in ops[:3]),
+            ops[3].reshape(*ops[3].shape[:2], -1) if ops[3].ndim == 4
+            else ops[3], ops[4])
 
 
 def at_heads_a_step(together):
@@ -90,6 +112,8 @@ def at_heads_a_step(together):
     grid step (``None``: what their rule takes), whatever was traced
     before."""
     kernels.HEADS_A_STEP = RULE if together is None else (together,)
+    kernels.FORWARD_HEADS_A_STEP = (
+        FORWARD_RULE if together is None else (together,))
     jax.clear_caches()
 
 
@@ -161,11 +185,11 @@ def kernel_times(flat, do, n):
     }
 
 
-def a_heads_chunk(times, shape):
+def a_heads_chunk(times, shape, heads):
     """``times`` with, beside each call's milliseconds, the
     microseconds it takes a chunk of one head."""
-    batch, seq, width = shape
-    chunks = batch * (width // kernels.HEAD) * (seq // kernels.CHUNK)
+    batch, seq, _ = shape
+    chunks = batch * heads * (seq // kernels.CHUNK)
     out = {}
     for name, ms in times.items():
         out[name] = round(ms, 4)
@@ -203,33 +227,62 @@ def main(argv=None):
                          "the heads a step the kernels' rule takes) also "
                          "time a grid step with an empty body and one "
                          "whose _inverse makes no product")
+    ap.add_argument("--a-head", action="store_true",
+                    help="one decay a head, on heads of --key-dim keys "
+                         "by --value-dim values")
+    ap.add_argument("--key-dim", type=int, default=kernels.HEAD)
+    ap.add_argument("--value-dim", type=int, default=kernels.HEAD)
     ap.add_argument("--out", default="chiprun_out/delta_rule.jsonl")
     args = ap.parse_args(argv)
+    if not args.a_head and (args.key_dim, args.value_dim) != (
+            kernels.HEAD, kernels.HEAD):
+        ap.error("a decay a channel is on heads of 128 x 128")
+    form = dict(dk=args.key_dim, dv=args.value_dim, a_head=args.a_head)
     if jax.default_backend() != "tpu":
         print("not a TPU: a CPU run times nothing", file=sys.stderr)
         return 1
     os.makedirs(os.path.dirname(args.out), exist_ok=True)
-    column = args.batch * args.seq * args.heads * kernels.HEAD
-    betas = 4 * args.batch * args.seq * args.heads
+    positions = args.batch * args.seq * args.heads
+    column, betas = positions * kernels.HEAD, 4 * positions
     least = {
         "forward_ms": 1e3 * ((8 + 4) * column + betas) / HBM_BYTES_PER_S,
         "gradients_ms": 1e3 * (
             (8 + 4) * column + betas + (6 + 4) * column + betas
         ) / HBM_BYTES_PER_S,
     }
+    if args.a_head:
+        # q, k, v and o in bf16, g and beta a float32 a head; then the
+        # five operands and do read and the five gradients written
+        keys, values = (2 * positions * d
+                        for d in (args.key_dim, args.value_dim))
+        forward = 2 * keys + 2 * values + 2 * betas
+        least = {
+            "forward_ms": 1e3 * forward / HBM_BYTES_PER_S,
+            "gradients_ms": 1e3 * (
+                forward + 2 * keys + values + 2 * betas
+            ) / HBM_BYTES_PER_S,
+        }
     rows = []
-    ops, do = operands(args.batch, args.seq, args.heads, 0.3, jnp.bfloat16)
-    flat = (*(x.reshape(*x.shape[:2], -1) for x in ops[:4]), ops[4])
+    ops, do = operands(
+        args.batch, args.seq, args.heads, 0.3, jnp.bfloat16, **form)
+    flat = as_rows(ops)
     flat_do = do.reshape(flat[2].shape)
     first = None
     if args.floors and not args.heads_per_step:
         args.heads_per_step = [kernels.heads_a_step(args.heads)]
     for together in args.heads_per_step if args.n else []:
         at_heads_a_step(together)
+        try:
+            times = kernel_times(flat, flat_do, args.n)
+        except jax.errors.JaxRuntimeError as e:
+            # more heads a step than VMEM holds: said, and on
+            rows.append({"what": "kernels alone", "heads_a_step": together,
+                         "refused": str(e)[:200]})
+            print(json.dumps(rows[-1]), flush=True)
+            continue
         row = {"what": "kernels alone", "shape": list(flat[0].shape),
                "heads_a_step": kernels.heads_a_step(args.heads),
-               **a_heads_chunk(kernel_times(flat, flat_do, args.n),
-                               flat[0].shape)}
+               **a_heads_chunk(times, flat[0].shape, args.heads)}
         got = (kernels.delta_rule(*flat),
                *gradients_of(kernels.delta_rule_tpu)(flat, flat_do))
         first = first or got
@@ -238,7 +291,8 @@ def main(argv=None):
         with built_with(SOLVING_AGAIN, together):
             row.update(a_heads_chunk({
                 "backward_solving_again_ms": kernel_times(
-                    flat, flat_do, args.n)["backward_ms"]}, flat[0].shape))
+                    flat, flat_do, args.n)["backward_ms"]}, flat[0].shape,
+                args.heads))
             row["same_bits_as_solving_again"] = all(
                 bool((a == b).all()) for a, b in zip(
                     got[1:],
@@ -250,10 +304,30 @@ def main(argv=None):
                 rows.append({
                     "what": name, "heads_a_step": together,
                     **a_heads_chunk(kernel_times(flat, flat_do, args.n),
-                                    flat[0].shape)})
+                                    flat[0].shape, args.heads)})
             print(json.dumps(rows[-1]), flush=True)
     at_heads_a_step(None)
+    if args.a_head and args.n:
+        # the kernels alone, on operands that come padded to whole
+        # lane tiles: what the calls below pay beside them is the
+        # pads' and the cuts'
+        wide = kernels.padded_values(args.value_dim)
+        padded = (
+            kernels._padded(flat[0], args.heads, kernels.HEAD),
+            kernels._padded(flat[1], args.heads, kernels.HEAD),
+            kernels._padded(flat[2], args.heads, wide), *flat[3:])
+        rows.append({
+            "what": "kernels alone, operands padded beforehand",
+            "shape": [list(x.shape) for x in padded[:3]],
+            "heads_a_step": kernels.heads_a_step(args.heads),
+            **a_heads_chunk(
+                kernel_times(padded, kernels._padded(
+                    flat_do, args.heads, wide), args.n),
+                flat[0].shape, args.heads)})
+        print(json.dumps(rows[-1]), flush=True)
     row = {"what": "kernels timed", "shape": list(ops[0].shape),
+           "values": args.value_dim,
+           "decay": "a head" if args.a_head else "a channel",
            "chunk": kernels.CHUNK, "sub": kernels.SUB,
            "heads_a_step": kernels.heads_a_step(args.heads),
            **{"least_" + k: round(v, 4) for k, v in least.items()}}
@@ -275,7 +349,7 @@ def main(argv=None):
         for decay in args.decay:
             ops, do = operands(
                 args.batch, args.check_seq, args.check_heads, decay, dtype,
-                seed=1)
+                seed=1, **form)
             # the plain path's float32 products at the highest
             # precision: the chip's default rounds them to bfloat16
             with jax.default_matmul_precision("highest"):

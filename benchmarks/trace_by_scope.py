@@ -46,6 +46,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 SCOPES = (
     "ssm.scan", "ssm.in_proj", "ssm.conv", "ssm.dt", "ssm.gate_norm",
     "ssm.out_proj", "moe.latent_down", "moe.latent_up", "kda.scan", "kda.proj", "kda.conv", "kda.out", "kda.decay",
+    "gdn.scan", "gdn.proj", "gdn.conv", "gdn.out", "gdn.decay",
     "sparse.compress", "sparse.select", "sparse.attn", "lightning.proj",
     "lightning.scan", "lightning.out", "mamba.scan", "mamba.in_proj",
     "mamba.conv", "mamba.x_proj", "mamba.dt", "mamba.gate",

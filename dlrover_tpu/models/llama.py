@@ -28,7 +28,9 @@ import jax.numpy as jnp
 
 from dlrover_tpu.ops.attention import flash_attention
 from dlrover_tpu.ops.delta_rule import gated_delta_rule_rows
-from dlrover_tpu.ops.gated_norm import gated_group_norm, head_norm_gate
+from dlrover_tpu.ops.gated_norm import (
+    gated_group_norm, head_norm_gate, head_norm_silu,
+)
 from dlrover_tpu.ops.kda_conv import conv_silu_norm
 from dlrover_tpu.ops.selective_scan import selective_scan
 from dlrover_tpu.ops.short_conv import gated_short_conv
@@ -41,14 +43,15 @@ from dlrover_tpu.ops.ssd import ssd_scan
 #: the operators a layer's kind may name
 OPERATORS = ("full_attention", "latent_attention", "linear_attention",
              "conv", "state_space", "sparse_attention",
-             "lightning_attention", "mamba", "none")
+             "lightning_attention", "mamba", "gated_delta_net", "none")
 #: those whose kernels reach the step outside any ``shard_map`` (the
 #: selection's compressed keys and top-k are over a whole sequence,
-#: the scans have no state to hand to a neighbour, and the partitioner
-#: cannot cut a Pallas call): the trainer refuses them on every mesh
-#: of more than one device, be its axis ``seq``, ``fsdp`` or ``data``
-#: (ROADMAP B13)
-ONE_DEVICE_OPERATORS = ("sparse_attention", "lightning_attention", "mamba")
+#: the scans, the delta rule with one decay a head among them, have no
+#: state to hand to a neighbour, and the partitioner cannot cut a
+#: Pallas call): the trainer refuses them on every mesh of more than
+#: one device, be its axis ``seq``, ``fsdp`` or ``data`` (ROADMAP B13)
+ONE_DEVICE_OPERATORS = ("sparse_attention", "lightning_attention", "mamba",
+                        "gated_delta_net")
 
 
 class LayerKind(NamedTuple):
@@ -61,7 +64,9 @@ class LayerKind(NamedTuple):
     and v over the key blocks each query selects,
     ``"lightning_attention"``, linear attention with a fixed decay a
     head, ``"mamba"``, a Mamba-1 mixer (a selective scan whose decay
-    differs by channel and by state), or ``"none"``), for attention the
+    differs by channel and by state), ``"gated_delta_net"``, the gated
+    delta rule with one decay a head on heads of two widths, or
+    ``"none"``), for attention the
     window (None: every earlier key) and whether q and
     k are rotated, and its feed-forward (``"dense"``,
     ``"experts"`` or ``"none"``). A block of one branch (``x +
@@ -248,14 +253,41 @@ class LlamaConfig:
     linear_conv_size: int = 4
     linear_gate_rank: int = 128
     linear_allow_neg_eigval: bool = False
+    # Gated DeltaNet, in the source's keys (``OlmoHybridConfig``'s
+    # ``linear_*``): where ``layer_types[l]`` is "gated_delta_net" the
+    # operator is the gated delta rule with ONE decay a head
+    # (ops/delta_rule.py) over ``linear_num_value_heads`` heads of
+    # ``linear_key_head_dim`` keys by ``linear_value_head_dim`` values
+    # (``linear_num_key_heads`` the same number: a key head for every
+    # value head is what is built). q, k and v by one matrix each
+    # through a causal depthwise convolution of
+    # ``linear_conv_kernel_dim`` taps and ``silu``, q and k an l2 norm
+    # a head; the log decay ``-exp(A_log) softplus(y w_a + dt_bias)``
+    # and the step size ``sigmoid(y w_beta)`` (doubled with
+    # ``linear_allow_neg_eigval``) a number a head and position; the
+    # result through an RMSNorm a head and then the gate ``silu(y
+    # wg)``, full rank and without a bias, and ``wo``. One stack holds
+    # this operator or "linear_attention", not both.
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
     # a sigmoid gate on full attention's result, elementwise, from the
     # layer's normed input through ``wg`` (``use_gqa_gate``)
     attn_out_gate: bool = False
-    # four norms a block, in the source's keys (``AfmoeDecoderLayer``):
-    # an RMSNorm on each branch's result ahead of the residual sum,
-    # ``x + RMSNorm(attn(RMSNorm(x)))`` and ``x + RMSNorm(ffn(RMSNorm(
-    # x)))``; the leaves ``post_attn_norm`` and ``post_mlp_norm``
-    post_norms: bool = False
+    # where a block's norms stand. False: ahead of each branch,
+    # ``x + branch(RMSNorm(x))``, the leaves ``attn_norm`` and
+    # ``mlp_norm``. True: four norms a block, in the source's keys
+    # (``AfmoeDecoderLayer``): an RMSNorm on each branch's result too,
+    # ahead of the residual sum, ``x + RMSNorm(attn(RMSNorm(x)))`` and
+    # ``x + RMSNorm(ffn(RMSNorm(x)))``; the further leaves
+    # ``post_attn_norm`` and ``post_mlp_norm``. "alone": on the
+    # branches' results and nowhere else (``Olmo2DecoderLayer``'s
+    # ``post_attention_layernorm`` and ``post_feedforward_layernorm``),
+    # ``x + RMSNorm(attn(x))`` and ``x + RMSNorm(ffn(x))``: the two
+    # further leaves without the first two.
+    post_norms: Any = False
     # the embedding's rows times ``sqrt(hidden_size)`` as they enter
     # the stream (``mup_enabled``)
     mup_enabled: bool = False
@@ -416,6 +448,12 @@ class LlamaConfig:
             object.__setattr__(
                 self, "moe_intermediate_size", self.intermediate_size
             )
+        if self.post_norms not in (False, True, "alone"):
+            raise ValueError(
+                f"post_norms {self.post_norms!r}: a block's norms stand "
+                "ahead of its branches (False), on both sides (True) "
+                "or on the branches' results 'alone'"
+            )
         if self.layer_types is not None:
             object.__setattr__(
                 self, "layer_types", tuple(self.layer_types)
@@ -428,14 +466,14 @@ class LlamaConfig:
             unknown = set(self.layer_types) - {
                 "conv", "full_attention", "latent_attention",
                 "linear_attention", "sparse_attention",
-                "lightning_attention", "mamba"}
+                "lightning_attention", "mamba", "gated_delta_net"}
             if unknown:
                 raise ValueError(
                     f"layer_types names {sorted(unknown)}: the "
                     "operators here are 'conv', 'full_attention', "
                     "'latent_attention', 'linear_attention', "
-                    "'sparse_attention', 'lightning_attention' and "
-                    "'mamba'"
+                    "'sparse_attention', 'lightning_attention', "
+                    "'mamba' and 'gated_delta_net'"
                 )
             if self.latent != ("latent_attention" in self.layer_types) or (
                     self.latent and "full_attention" in self.layer_types):
@@ -561,9 +599,40 @@ class LlamaConfig:
                 )
 
     def _check_operators_and_factors(self):
-        """Refuse what of the two operators and the three factors is
-        not built, rather than run it wrong."""
+        """Refuse what of the operators and the three factors is not
+        built, rather than run it wrong."""
         types = self.layer_types or ()
+        if "gated_delta_net" in types:
+            heads = (self.linear_num_key_heads, self.linear_num_value_heads)
+            if "linear_attention" in types:
+                raise ValueError(
+                    "layer_types names 'gated_delta_net' and "
+                    "'linear_attention': one stack holds one form of "
+                    "the delta rule's decay, a head's or a channel's "
+                    "(both read linear_allow_neg_eigval)"
+                )
+            if heads[0] != heads[1] or heads[0] < 1:
+                raise ValueError(
+                    f"linear_num_key_heads {heads[0]} and "
+                    f"linear_num_value_heads {heads[1]}: the gated delta "
+                    "rule here has a key head for every value head, and "
+                    "at least one (grouped keys: ROADMAP B13)"
+                )
+            if self.linear_key_head_dim < 1 or self.linear_value_head_dim < 1:
+                raise ValueError(
+                    f"linear_key_head_dim {self.linear_key_head_dim} and "
+                    f"linear_value_head_dim {self.linear_value_head_dim}: "
+                    "layer_types names 'gated_delta_net' and a head has "
+                    "no width"
+                )
+            if (self.num_experts > 0 or self.latent or self.mtp_layers
+                    or self.total_ut_steps > 1):
+                raise ValueError(
+                    "'gated_delta_net' layers stand in a plain stack of "
+                    "dense two-branch blocks beside 'full_attention' "
+                    "ones: experts, latent attention, a prediction "
+                    "module or a loop beside them are not built"
+                )
         sparse = "sparse_attention" in types
         lightning = "lightning_attention" in types
         if (sparse or lightning or "mamba" in types) and (
@@ -708,7 +777,8 @@ class LlamaConfig:
             LayerKind(
                 operator, *(
                     (None, False)
-                    if operator in ("conv", "linear_attention", "mamba")
+                    if operator in ("conv", "linear_attention", "mamba",
+                                    "gated_delta_net")
                     else (windows[i], ropes[i])
                 ),
                 "experts" if self.num_experts > 0
@@ -789,6 +859,24 @@ def llama_linear_tiny(**kw) -> LlamaConfig:
     ), **kw})
 
 
+def llama_gdn_tiny(**kw) -> LlamaConfig:
+    """Test-sized Gated DeltaNet/full-attention hybrid of dense blocks
+    normed on their branches' results alone: a period of three layers
+    of the gated delta rule with one decay a head (3 heads of 24 keys
+    by 40 values behind four-tap convolutions, a ``silu`` gate past
+    the heads' norm) and one full-attention layer of 3 ungrouped heads
+    of 16 without positions, q and k normed over their whole
+    projections."""
+    return llama_tiny(**{**dict(
+        num_layers=4, layer_types=("gated_delta_net",) * 3
+        + ("full_attention",), rope_layout=(0,) * 4, num_heads=3,
+        num_kv_heads=3, head_dim=16, qk_norm=True, post_norms="alone",
+        linear_num_key_heads=3, linear_num_value_heads=3,
+        linear_key_head_dim=24, linear_value_head_dim=40,
+        linear_allow_neg_eigval=True, norm_eps=1e-6,
+    ), **kw})
+
+
 def llama_sandwich_tiny(**kw) -> LlamaConfig:
     """Test-sized config with four norms a block and the embedding
     times ``sqrt(hidden_size)``: a leading dense layer, then a period
@@ -864,11 +952,12 @@ def _leaves(cfg: LlamaConfig, kind: LayerKind) -> Dict:
     (deviation 0: zeros)."""
     h, hd = cfg.hidden_size, cfg.head_dim
     nh, nkv = cfg.num_heads, cfg.num_kv_heads
-    # a branch's norm: a block of one branch has the one
+    # a branch's norm: a block of one branch has the one, and a block
+    # normed on its branches' results alone has neither
     norms = {
         name: h for name, branch in (
             ("attn_norm", kind.operator), ("mlp_norm", kind.ffn))
-        if branch != "none"
+        if branch != "none" and cfg.post_norms != "alone"
     }
     if cfg.post_norms:
         norms.update(post_attn_norm=h, post_mlp_norm=h)
@@ -913,6 +1002,21 @@ def _leaves(cfg: LlamaConfig, kind: LayerKind) -> Dict:
             "w_beta": ((h, lh), ("embed", None)),
         }
         norms["o_norm"] = ld
+    elif kind.operator == "gated_delta_net":
+        lh = cfg.linear_num_value_heads
+        keys = lh * cfg.linear_key_head_dim
+        values = lh * cfg.linear_value_head_dim
+        matrices = {
+            "wq": ((h, keys), ("embed", "heads")),
+            "wk": ((h, keys), ("embed", "heads")),
+            "wv": ((h, values), ("embed", "heads")),
+            "wg": ((h, values), ("embed", "heads")),
+            "wo": ((values, h), ("heads", "embed")),
+            # the decay's and the step size's: a number a head
+            "w_a": ((h, lh), ("embed", None)),
+            "w_beta": ((h, lh), ("embed", None)),
+        }
+        norms["o_norm"] = cfg.linear_value_head_dim
     elif kind.operator == "lightning_attention":
         lh, ld = cfg.lightning_num_heads, cfg.lightning_head_dim
         matrices = {
@@ -1009,6 +1113,15 @@ def _leaves(cfg: LlamaConfig, kind: LayerKind) -> Dict:
         # float32 vectors with draws of their own (``_DECAY_DRAWS``)
         leaves["A_log"] = ((lh,), ("norm",), "A_log")
         leaves["dt_bias"] = ((wide,), ("norm",), "dt_bias")
+    if kind.operator == "gated_delta_net":
+        taps = cfg.linear_conv_kernel_dim
+        for name, wide in (("conv_q", keys), ("conv_k", keys),
+                           ("conv_v", values)):
+            leaves[name] = ((wide, taps), ("heads", None), taps ** -0.5)
+        # float32 numbers a head with draws of their own
+        # (``_DECAY_DRAWS``)
+        leaves["A_log"] = ((lh,), ("norm",), "A_log")
+        leaves["dt_bias"] = ((lh,), ("norm",), "dt_bias")
     if kind.operator == "state_space":
         taps, heads = cfg.conv_kernel, cfg.mamba_num_heads
         leaves["ssm_conv_w"] = ((conv, taps), ("mlp", None), taps ** -0.5)
@@ -1051,7 +1164,8 @@ _DRAW = {"wq": 0, "wk": 1, "wv": 2, "wo": 3, "w_gate": 4, "w_up": 5,
          "g_b": 21, "w_beta": 22, "A_log": 23, "dt_bias": 24, "wg": 25,
          "ssm_in": 26, "ssm_out": 27, "ssm_conv_w": 28,
          "w_latent_down": 29, "w_latent_up": 30, "mamba_in": 31,
-         "mamba_x": 32, "mamba_dt": 33, "mamba_out": 34, "mamba_conv_w": 35}
+         "mamba_x": 32, "mamba_dt": 33, "mamba_out": 34, "mamba_conv_w": 35,
+         "w_a": 36}
 
 
 def _draw_A_log(key, shape):
@@ -1393,7 +1507,7 @@ def _pre_attn(cfg: LlamaConfig, x, layer_params, cos, sin,
 
     if kind.operator == "none":  # a block of the feed-forward alone
         return (), logits()
-    y = rms_norm(constrain(x, _RESIDUAL), p["attn_norm"], cfg.norm_eps)
+    y = _ahead_of_branch(cfg, constrain(x, _RESIDUAL), p, "attn_norm")
     if kind.operator == "state_space":
         return _ssm_operands(cfg, y, p, constrain), logits()
     if kind.operator == "mamba":
@@ -1408,6 +1522,8 @@ def _pre_attn(cfg: LlamaConfig, x, layer_params, cos, sin,
         ), logits()
     if kind.operator == "linear_attention":
         return _delta_rule_operands(cfg, y, p, constrain), logits()
+    if kind.operator == "gated_delta_net":
+        return _gdn_operands(cfg, y, p, constrain), logits()
     if kind.operator == "lightning_attention":
         return _lightning_operands(
             cfg, y, p, cos, sin, constrain, kind.rope
@@ -1428,6 +1544,15 @@ def _pre_attn(cfg: LlamaConfig, x, layer_params, cos, sin,
         with jax.named_scope("attn.gate"):
             return (q, k, v, y @ p["wg"]), logits()
     return (q, k, v), logits()
+
+
+def _ahead_of_branch(cfg: LlamaConfig, x, layer_params, norm: str):
+    """What a branch reads of the stream ``x``: its RMSNorm by the
+    leaf ``norm``, or, in a block normed on its branches' results
+    alone (``post_norms`` "alone"), the stream itself."""
+    if cfg.post_norms == "alone":
+        return x
+    return rms_norm(x, layer_params[norm], cfg.norm_eps)
 
 
 def _ssm_operands(cfg: LlamaConfig, y, p, constrain=_free):
@@ -1517,6 +1642,37 @@ def _delta_rule_operands(cfg: LlamaConfig, y, p, constrain=_free):
         g = -rate * jax.nn.softplus(
             decay.astype(jnp.float32) + p["dt_bias"]
         )
+        beta = jax.nn.sigmoid(step.astype(jnp.float32))
+        if cfg.linear_allow_neg_eigval:
+            beta = 2.0 * beta
+    return q, k, v, g, beta, gate
+
+
+def _gdn_operands(cfg: LlamaConfig, y, p, constrain=_free):
+    """A Gated DeltaNet layer's operands from what its branch reads,
+    ``y``, in rows, as the projections write them and the scan's
+    kernels read them: ``(q, k [b, s, heads x dk], v [b, s, heads x
+    dv], g and beta [b, s, heads] float32, the output gate's
+    pre-activation [b, s, heads x dv])``. The log decay is a number a
+    head and position and is never spread over a head's columns. The
+    scopes name every op: ``gdn.proj`` the five projections and the
+    two a head; ``gdn.conv`` the three convolutions with ``silu`` and
+    q's and k's l2 norm a head (``ops/kda_conv.py``); ``gdn.decay``
+    the log decay and the step size."""
+    heads = cfg.linear_num_value_heads
+    with jax.named_scope("gdn.proj"):
+        q, k, v, gate = (
+            constrain(y @ p[w], _MLP) for w in ("wq", "wk", "wv", "wg"))
+        decay, step = y @ p["w_a"], y @ p["w_beta"]
+    with jax.named_scope("gdn.conv"):
+        q, k = (
+            conv_silu_norm(x, p[w], l2_heads=heads)
+            for x, w in ((q, "conv_q"), (k, "conv_k"))
+        )
+        v = conv_silu_norm(v, p["conv_v"])
+    with jax.named_scope("gdn.decay"):
+        g = -jnp.exp(p["A_log"]) * jax.nn.softplus(
+            decay.astype(jnp.float32) + p["dt_bias"])
         beta = jax.nn.sigmoid(step.astype(jnp.float32))
         if cfg.linear_allow_neg_eigval:
             beta = 2.0 * beta
@@ -1701,6 +1857,11 @@ def _operator_out(x, out, layer_params, kind: LayerKind,
         with jax.named_scope("kda.out"):
             return head_norm_gate(
                 *out, p["o_norm"], p["g_bias"], norm_eps) @ p["wo"]
+    if kind.operator == "gated_delta_net":
+        # an RMSNorm a head with one learned scale, then ``silu`` of
+        # the gate's pre-activation: rows in, rows to ``wo``
+        with jax.named_scope("gdn.out"):
+            return head_norm_silu(*out, p["o_norm"], norm_eps) @ p["wo"]
     if kind.operator == "lightning_attention":
         # an RMSNorm a head with one learned scale, a sigmoid gate
         with jax.named_scope("lightning.out"):
@@ -1758,7 +1919,7 @@ def _post_attn(cfg: LlamaConfig, x, out, layer_params,
     counts = None
     if kind.ffn == "none":  # a block of the operator alone
         return x, jnp.zeros((), jnp.float32), counts
-    y = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
+    y = _ahead_of_branch(cfg, x, p, "mlp_norm")
     if kind.ffn == "experts":
         mlp = _expert_mlp(cfg, expert_parallel)
         if router_logits is not None:
@@ -1831,7 +1992,9 @@ def _operator_of(cfg: LlamaConfig, attn_fn, kind: LayerKind):
     ``mha_reference`` do). Linear
     attention's call is ``kda.scan``, the gated delta rule; it hands
     the output gate's logits on beside its result, as attention does
-    with ``attn_out_gate``, for ``_operator_out``. A Mamba-2 mixer's
+    with ``attn_out_gate``, for ``_operator_out``; a Gated DeltaNet
+    layer's is ``gdn.scan``, the same rule with one decay a head, and
+    hands its gate's logits on the same way. A Mamba-2 mixer's
     is ``ssm.scan`` and, on its result, ``ssm.gate_norm``; a block
     of the feed-forward alone calls nothing. Sparse attention's is
     ``sparse.compress``, ``sparse.select`` and ``sparse.attn``
@@ -1887,6 +2050,15 @@ def _operator_of(cfg: LlamaConfig, attn_fn, kind: LayerKind):
                 ), gate
 
         return scan
+    if kind.operator == "gated_delta_net":
+
+        def scan_a_head(q, k, v, g, beta, gate):
+            with jax.named_scope("gdn.scan"):
+                return gated_delta_rule_rows(
+                    q, k, v, g, beta, cfg.linear_num_value_heads
+                ), gate
+
+        return scan_a_head
     if kind.operator == "lightning_attention":
         heads = cfg.lightning_num_heads
 
@@ -2733,9 +2905,8 @@ def _routed(params, tokens, cfg: LlamaConfig, attn_fn, stat):
         if kind.ffn != "experts":
             return None, logits
         if logits is None:
-            logits = router_logits(rms_norm(
-                _past_operator(cfg, x, out, p, kind),
-                p["mlp_norm"], cfg.norm_eps,
+            logits = router_logits(_ahead_of_branch(
+                cfg, _past_operator(cfg, x, out, p, kind), p, "mlp_norm",
             ), p["router"])
         return stat(logits, p), logits
 
@@ -2787,9 +2958,11 @@ def decay_min(params: Dict, tokens: jax.Array, cfg: LlamaConfig,
     layer's gated delta rule takes on ``tokens`` [batch, seq], float32
     [layers]; 1 for a layer of another kind. A forward pass
     (jit-able), before the scan's floor: ``gated_delta_rule_rows``
-    takes a step under ``exp(-10)`` as ``exp(-10)`` (ops/delta_rule.py
-    ``G_FLOOR``), and this is the number that says whether a run's
-    channels get there. A state-space layer's is the least ``a =
+    takes a step of a decay a channel under ``exp(-10)`` as
+    ``exp(-10)`` (ops/delta_rule.py ``G_FLOOR``), and this is the
+    number that says whether a run's channels get there; a Gated
+    DeltaNet layer's is the least ``alpha`` of a head, which no floor
+    touches. A state-space layer's is the least ``a =
     exp(A Delta)`` of a head (ops/ssd.py has no floor), a Mamba-1
     mixer's the least ``exp(Delta_t[d] A[d, n])`` of a channel's
     states (ops/selective_scan.py has none either)."""
@@ -2804,7 +2977,7 @@ def decay_min(params: Dict, tokens: jax.Array, cfg: LlamaConfig,
         if kind.operator == "state_space":
             _, dt, rate = operands[:3]  # a head's a = exp(A Delta)
             return jnp.exp(jnp.min(dt * rate)), logits
-        if kind.operator != "linear_attention":
+        if kind.operator not in ("linear_attention", "gated_delta_net"):
             return jnp.ones((), jnp.float32), logits
         return jnp.exp(jnp.min(operands[3])), logits
 
@@ -2813,16 +2986,18 @@ def decay_min(params: Dict, tokens: jax.Array, cfg: LlamaConfig,
 
 def set_decay_min_gauge(least, name: str = "kda_decay_min") -> float:
     """Set the gauge ``kda_decay_min`` (``GET /metrics``), or for a
-    stack of state-space layers ``ssm_decay_min``, to the least of
-    ``decay_min``'s values at an evaluation."""
+    stack of state-space layers ``ssm_decay_min``, or for one of the
+    delta rule with one decay a head ``gdn_decay_min``, to the least
+    of ``decay_min``'s values at an evaluation."""
     from dlrover_tpu.telemetry.registry import gauge
 
     value = float(jnp.min(least))
     gauge(
         name,
         "least decay of a step (alpha = exp(g) of a key channel of the "
-        "gated delta rule; a = exp(A Delta) of a head of the "
-        "state-space scan), over the layers, at the last evaluation",
+        "gated delta rule, or of a head where the decay is one a head; "
+        "a = exp(A Delta) of a head of the state-space scan), over the "
+        "layers, at the last evaluation",
     ).set(value)
     return value
 
@@ -2831,8 +3006,8 @@ def flops_per_token(cfg: LlamaConfig, seq_len: int) -> float:
     """Approximate training FLOPs per token (6N_active + attention
     quadratic, at ``num_heads x head_dim`` and by each layer's kind;
     the convolution's taps are not counted, and of the gated delta
-    rule, of a state-space mixer, of a lightning layer and of a
-    Mamba-1 mixer the
+    rule in either form of decay, of a state-space mixer, of a
+    lightning layer and of a Mamba-1 mixer the
     projections and low ranks but not the recurrence; sparse
     attention at the keys of a query's ``sparse_topk`` blocks, the
     selection's own scores not counted), a

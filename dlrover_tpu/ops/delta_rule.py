@@ -1,13 +1,18 @@
-"""The gated delta rule with a decay of its own for every key channel
-(Kimi Delta Attention): a linear-attention layer's recurrence, a head
-at a time. With ``alpha_t = exp(g_t)`` in (0, 1]^d and a step size
-``beta_t``, the state ``S`` [d keys, d values] starts at zero and::
+"""The gated delta rule: a linear-attention layer's recurrence, a
+head at a time, in two forms of decay. With a decay of its own for
+every key channel (Kimi Delta Attention), ``alpha_t = exp(g_t)`` in
+(0, 1]^d and a step size ``beta_t``, the state ``S`` [d keys, d
+values] starts at zero and::
 
     S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T
     o_t = S_t^T q_t / sqrt(d)
 
 The state forgets channel by channel, then the rank-one update takes
-out what it holds along ``k_t`` and writes ``v_t`` there.
+out what it holds along ``k_t`` and writes ``v_t`` there. With one
+decay a head (Gated DeltaNet), ``alpha_t = exp(g_t)`` a number, the
+same lines with ``alpha_t I`` for ``Diag(alpha_t)``, and the state
+may be ``[dk keys, dv values]`` of two widths (``o_t = S_t^T q_t /
+sqrt(dk)``). ``g``'s shape says which: ``k``'s, or ``beta``'s.
 
 Computed in chunks of ``CHUNK`` positions. With ``G_t`` the log decay
 cumulated from the chunk's start and ``w_t = beta_t (v_t - S_{t-1}^T
@@ -20,7 +25,10 @@ cumulated from the chunk's start and ``w_t = beta_t (v_t - S_{t-1}^T
     S_C = Diag(exp(G_C)) S_0 + (K * exp(G_C - G))^T W
 
 so the sequential part is one unit lower-triangular system a chunk
-and a [d, d] state handed from chunk to chunk.
+and a [d, d] state handed from chunk to chunk. With one decay a head
+``exp(G_t - G_i)`` leaves the sums over ``c``: one ``[CHUNK, CHUNK]``
+mask a head on ``K K^T`` and ``Q K^T``, whatever the two widths (the
+system and its inverse are ``[CHUNK, CHUNK]`` whatever ``dv``).
 
 The entry is ``gated_delta_rule_rows``, on ``[batch, seq, heads x
 d]``; ``gated_delta_rule`` folds ``[batch, seq, heads, d]`` to it.
@@ -28,8 +36,10 @@ d]``; ``gated_delta_rule`` folds ``[batch, seq, heads, d]`` to it.
 On the TPU, where the shapes tile, the Pallas kernels of
 ops/pallas/delta_rule.py (forward; backward over the chunks' entry
 states that the forward keeps when it is differentiated), which are
-exact while no step forgets faster than ``G_FLOOR``: the entry sees
-to that. Elsewhere
+exact while no step of a decay a channel forgets faster than
+``G_FLOOR``: the entry sees to that. One decay a head has no floor on
+either path: a pair's exponent is a sum of the steps between the two
+positions, never positive and never factored. Elsewhere
 ``gated_delta_rule_plain``: the equations above under a ``lax.scan``
 over chunks, every ``exp(G_t - G_i)`` taken pair by pair (exact at any
 decay: no exponent is positive), the system solved by substitution,
@@ -50,6 +60,13 @@ CHUNK = 64
 G_FLOOR = -10.0
 
 
+def one_decay_a_head(k, g, beta) -> bool:
+    """Which form of decay ``g``'s shape says: ``beta``'s, one a head;
+    ``k``'s, one a channel (a head one column wide is both, and is
+    taken as a channel's)."""
+    return g.shape == beta.shape and g.shape != k.shape
+
+
 def _use_pallas(q: jax.Array, heads: int) -> bool:
     if jax.default_backend() != "tpu":
         return False
@@ -58,12 +75,37 @@ def _use_pallas(q: jax.Array, heads: int) -> bool:
     return tiles_the_kernel(q.shape, heads)
 
 
+def _use_pallas_a_head(q: jax.Array, v: jax.Array, heads: int) -> bool:
+    """``_use_pallas`` for one decay a head, whose heads have two
+    widths."""
+    if jax.default_backend() != "tpu":
+        return False
+    from dlrover_tpu.ops.pallas.delta_rule import tiles_the_kernel
+
+    return tiles_the_kernel(q.shape, heads, v.shape)
+
+
+def _steps_between(g, lower, strictly):
+    """``sum of g_j over i < j <= t`` [..., t, i] of a chunk's log
+    decay a head ``g`` [..., c] where ``i <= t`` (0 elsewhere): a
+    pair's exponent as the sum of the steps between the two, not the
+    difference of two cumulated sums, which at a decay of 30 a step
+    would round what a slow step after a fast one keeps."""
+    between = (lower[:, None, :] & strictly.T[None, :, :]).astype(g.dtype)
+    return jnp.einsum(
+        "...j,tij->...ti", g, between,
+        precision=jax.lax.Precision.HIGHEST,
+    )
+
+
 def gated_delta_rule_plain(q, k, v, g, beta, chunk: int = CHUNK):
     """The chunked equations as they stand, in float32, rounded once.
-    A sequence that is no whole number of chunks is padded with
-    positions that leave the state as it is (``k`` 0, ``g`` 0, ``beta``
-    0)."""
+    ``g`` [b, s, h, d] a decay a channel, or [b, s, h] one a head,
+    with ``v`` then [b, s, h, dv] of any width. A sequence that is no
+    whole number of chunks is padded with positions that leave the
+    state as it is (``k`` 0, ``g`` 0, ``beta`` 0)."""
     b, s, h, d = q.shape
+    a_head = g.ndim == 3
     c = min(chunk, s)
     pad = -s % c
     f32 = jnp.float32
@@ -84,17 +126,28 @@ def gated_delta_rule_plain(q, k, v, g, beta, chunk: int = CHUNK):
     def step(state, x):  # state [b, h, keys, values]
         q, k, v, g, beta = x
         beta = beta[..., None]
-        gc = jnp.cumsum(g, axis=-2)
-        # exp(G_t - G_i) [b, h, t, i, c]: no positive exponent where
-        # i <= t, and none taken where i > t (masked below)
-        decay = jnp.exp(jnp.where(
-            lower[:, :, None],
-            gc[..., :, None, :] - gc[..., None, :, :], 0.0,
-        ))
-        a = jnp.where(strictly, jnp.einsum(
-            "bhtc,bhic,bhtic->bhti", k, k, decay), 0.0)
-        bb = jnp.where(lower, jnp.einsum(
-            "bhtc,bhic,bhtic->bhti", q, k, decay), 0.0)
+        if a_head:
+            # one [t, i] mask a head: exp of the steps between
+            decay = jnp.exp(_steps_between(g, lower, strictly))
+            a = jnp.where(strictly, jnp.einsum(
+                "bhtc,bhic->bhti", k, k) * decay, 0.0)
+            bb = jnp.where(lower, jnp.einsum(
+                "bhtc,bhic->bhti", q, k) * decay, 0.0)
+            gc = jnp.cumsum(g, axis=-1)[..., None]  # [b, h, t, 1]
+            to_end = decay[..., -1, :, None]  # the steps after t
+        else:
+            gc = jnp.cumsum(g, axis=-2)
+            # exp(G_t - G_i) [b, h, t, i, c]: no positive exponent
+            # where i <= t, and none taken where i > t (masked below)
+            decay = jnp.exp(jnp.where(
+                lower[:, :, None],
+                gc[..., :, None, :] - gc[..., None, :, :], 0.0,
+            ))
+            a = jnp.where(strictly, jnp.einsum(
+                "bhtc,bhic,bhtic->bhti", k, k, decay), 0.0)
+            bb = jnp.where(lower, jnp.einsum(
+                "bhtc,bhic,bhtic->bhti", q, k, decay), 0.0)
+            to_end = jnp.exp(gc[..., -1:, :] - gc)
         gamma = jnp.exp(gc)
         rhs = beta * (v - jnp.einsum("bhtc,bhcv->bhtv", k * gamma, state))
         w = jax.scipy.linalg.solve_triangular(
@@ -105,7 +158,7 @@ def gated_delta_rule_plain(q, k, v, g, beta, chunk: int = CHUNK):
         last = gc[..., -1:, :]
         state = (
             jnp.swapaxes(jnp.exp(last), -1, -2) * state
-            + jnp.einsum("bhtc,bhtv->bhcv", k * jnp.exp(last - gc), w)
+            + jnp.einsum("bhtc,bhtv->bhcv", k * to_end, w)
         )
         return state, o
 
@@ -117,18 +170,23 @@ def gated_delta_rule_plain(q, k, v, g, beta, chunk: int = CHUNK):
 
 
 def gated_delta_rule_rows(q, k, v, g, beta, heads: int, folded=False):
-    """The entry, on rows: ``q, k`` [batch, seq, heads x d], ``v``
-    [batch, seq, heads x d], ``g`` the same shape as ``k`` (the log
-    of the decay, float32, at most 0), ``beta`` [batch, seq, heads] to
-    ``o`` in ``v``'s shape and dtype; a head is ``d`` columns of a
-    row, side by side, as a projection writes them and the kernels
-    read them. Differentiable in all five. A sequence is a row of the
-    batch: the state starts at zero at its first position. A log
-    decay under ``G_FLOOR`` is taken as ``G_FLOOR``, on either path:
-    what a channel keeps of its state over such a step is then 4.5e-5
-    and not less, and ``g`` there gets no gradient. ``folded`` is
-    ``gated_delta_rule``'s to say, for the kernels' record."""
-    if not (q.ndim == 3 and q.shape == k.shape == g.shape
+    """The entry, on rows: ``q, k`` [batch, seq, heads x dk], ``v``
+    [batch, seq, heads x dv], ``g`` the log of the decay, float32, at
+    most 0: the same shape as ``k``, a decay a channel, or as
+    ``beta``, one a head; ``beta`` [batch, seq, heads]; to ``o`` in
+    ``v``'s shape and dtype. A head is its columns of a row, side by
+    side, as a projection writes them and the kernels read them.
+    Differentiable in all five. A sequence is a row of the batch: the
+    state starts at zero at its first position. A decay a channel has
+    ``dv = dk`` and a floor: a log decay under ``G_FLOOR`` is taken as
+    ``G_FLOOR``, on either path: what a channel keeps of its state
+    over such a step is then 4.5e-5 and not less, and ``g`` there gets
+    no gradient. One decay a head takes any two widths and has no
+    floor. ``folded`` is ``gated_delta_rule``'s to say, for the
+    kernels' record."""
+    a_head = one_decay_a_head(k, g, beta)
+    if not (q.ndim == 3 and q.shape == k.shape
+            and (a_head or g.shape == k.shape == v.shape)
             and v.shape[:2] == q.shape[:2]
             and beta.shape == (*q.shape[:2], heads)
             and q.shape[2] % heads == v.shape[2] % heads == 0):
@@ -136,8 +194,10 @@ def gated_delta_rule_rows(q, k, v, g, beta, heads: int, folded=False):
             f"gated_delta_rule: q {q.shape}, k {k.shape}, v {v.shape}, "
             f"g {g.shape}, beta {beta.shape} in rows of {heads} heads"
         )
-    g = jnp.maximum(g, G_FLOOR)
-    if _use_pallas(q, heads):
+    if not a_head:
+        g = jnp.maximum(g, G_FLOOR)
+    if (_use_pallas_a_head(q, v, heads) if a_head
+            else _use_pallas(q, heads)):
         from dlrover_tpu.ops.pallas.delta_rule import delta_rule_tpu
 
         return delta_rule_tpu(q, k, v, g, beta, folded)
@@ -146,18 +206,20 @@ def gated_delta_rule_rows(q, k, v, g, beta, heads: int, folded=False):
         return x.reshape(*x.shape[:2], heads, -1)
 
     return gated_delta_rule_plain(
-        apart(q), apart(k), apart(v), apart(g), beta
+        apart(q), apart(k), apart(v), g if a_head else apart(g), beta
     ).reshape(v.shape)
 
 
 def gated_delta_rule(q, k, v, g, beta):
     """``gated_delta_rule_rows`` for a caller that holds heads: ``q,
-    k, g`` [batch, seq, heads, d], ``v`` [batch, seq, heads, d],
-    ``beta`` [batch, seq, heads] to ``o`` [batch, seq, heads, d] in
-    ``v``'s dtype. Folded to rows here and nowhere else: on the chip
-    that is a pass over each operand and over ``o``, which the model
-    does not pay (it holds rows)."""
-    if not (q.ndim == 4 and q.shape == k.shape == g.shape
+    k`` [batch, seq, heads, dk], ``v`` [batch, seq, heads, dv], ``g``
+    as ``k`` (a decay a channel) or as ``beta`` [batch, seq, heads]
+    (one a head) to ``o`` [batch, seq, heads, dv] in ``v``'s dtype.
+    Folded to rows here and nowhere else: on the chip that is a pass
+    over each operand and over ``o``, which the model does not pay (it
+    holds rows)."""
+    if not (q.ndim == 4 and q.shape == k.shape
+            and g.shape in (k.shape, beta.shape)
             and v.shape[:3] == q.shape[:3]):
         raise ValueError(
             f"gated_delta_rule: q {q.shape}, k {k.shape}, v {v.shape}, "
@@ -168,5 +230,6 @@ def gated_delta_rule(q, k, v, g, beta):
         return x.reshape(*x.shape[:2], -1)
 
     return gated_delta_rule_rows(
-        rows(q), rows(k), rows(v), rows(g), beta, q.shape[2], folded=True
+        rows(q), rows(k), rows(v), rows(g) if g.ndim == 4 else g, beta,
+        q.shape[2], folded=True,
     ).reshape(v.shape)

@@ -19,9 +19,17 @@ d] or None::
     sig = sigmoid(z + bias)
     y   = o * r * scale * sig
 
+A Gated DeltaNet layer's, ``head_norm_silu``: the norm a head, then
+``silu`` of the gate's pre-activation, ``scale`` [d] one for every
+head and no bias::
+
+    r = rsqrt(mean over a head's d columns of o^2 + eps)
+    y = o * r * scale * silu(z)
+
 On the TPU one Pallas pass forward and one backward, in float32 and
 rounded once to ``o.dtype`` (ops/pallas/gated_norm.py has the one
-frame, the two bodies and the gradients' equations); elsewhere, and as
+frame, the three bodies and the gradients' equations); elsewhere,
+where a head is no whole lane tiles (192 is a tile and a half), and as
 the tests' other side, the same lines in ``jax.numpy`` through a view
 that names a group's columns (``ops/kda_conv.py heads_apart``): the
 mixer's rounded once too, the heads' where the model's own lines
@@ -31,7 +39,7 @@ rounded before these took them over.
 import jax
 import jax.numpy as jnp
 
-from dlrover_tpu.ops.kda_conv import heads_apart
+from dlrover_tpu.ops.kda_conv import head_sums, heads_apart
 
 
 def _use_pallas(o: jax.Array, groups: int) -> bool:
@@ -69,10 +77,24 @@ def head_norm_gate_plain(o: jax.Array, z: jax.Array, scale: jax.Array,
     return normed.astype(o.dtype).reshape(o.shape) * gate.astype(o.dtype)
 
 
+def head_norm_silu_plain(o: jax.Array, z: jax.Array, scale: jax.Array,
+                         eps: float):
+    """The equations above as they stand, in float32, rounded once;
+    a head's mean square through ``ops/kda_conv.py head_sums``, which
+    on the TPU takes no view of a head of 192 columns."""
+    d = scale.shape[0]
+    o = o.astype(jnp.float32)
+    normed = o * jax.lax.rsqrt(
+        head_sums(o * o, o.shape[-1] // d) / d + eps
+    ) * jnp.tile(scale.astype(jnp.float32), o.shape[-1] // d)
+    return (normed * jax.nn.silu(z.astype(jnp.float32))).astype(z.dtype)
+
+
 #: what a counter's help calls each entry
 _ENTRIES = {
     "gated_norm": "a Mamba-2 mixer's gate and grouped norm",
     "head_norm_gate": "a linear-attention layer's heads' norm and gate",
+    "head_norm_silu": "a Gated DeltaNet layer's heads' norm and silu gate",
 }
 
 
@@ -130,3 +152,25 @@ def head_norm_gate(o: jax.Array, z: jax.Array, scale: jax.Array, bias,
         return gated_norm_tpu(o, z, vectors, "norm, gate", heads, eps)
     _count("head_norm_gate", "plain")
     return head_norm_gate_plain(o, z, scale, bias, eps)
+
+
+def head_norm_silu(o: jax.Array, z: jax.Array, scale: jax.Array,
+                   eps: float):
+    """``o`` and ``z`` [batch, seq, heads x d] and ``scale`` [d] to
+    ``[batch, seq, heads x d]``: each head's columns of ``o`` over
+    their root mean square, times ``scale``, times ``silu(z)``: the
+    norm first, then the gate."""
+    width = o.shape[-1]
+    if z.shape != o.shape or scale.ndim != 1 or width % scale.shape[0]:
+        raise ValueError(
+            f"a gate of {z.shape} and a scale of {scale.shape} for rows "
+            f"of {o.shape}"
+        )
+    heads = width // scale.shape[0]
+    if _use_pallas(o, heads):
+        from dlrover_tpu.ops.pallas.gated_norm import gated_norm_tpu
+
+        _count("head_norm_silu", "kernel")
+        return gated_norm_tpu(o, z, (scale,), "norm, silu", heads, eps)
+    _count("head_norm_silu", "plain")
+    return head_norm_silu_plain(o, z, scale, eps)

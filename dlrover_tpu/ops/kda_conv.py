@@ -33,6 +33,8 @@ L2_NORM_EPS = 1e-6
 #: rows of a float32 tile on the TPU: ``[s, columns]`` lies in tiles of
 #: (8, 128)
 _TILE_ROWS = 8
+#: and its columns
+_LANE = 128
 
 
 def l2norm(x):
@@ -58,6 +60,46 @@ def heads_apart(x, heads):
     return x.reshape(b, s // rows, rows, heads, width // heads)
 
 
+def head_sums_by_product(x, heads):
+    """``head_sums`` with no view of a head's columns: the rows times
+    a ``[heads x d, heads]`` matrix of ones where a column is a
+    head's, and the sums back over the columns by its transpose.
+    Float32 products at the highest precision: a term times one is
+    the term, so the sums are the view's up to the order they are
+    added in."""
+    width = x.shape[-1]
+    of_head = (
+        jnp.arange(width)[:, None] // (width // heads)
+        == jnp.arange(heads)[None, :]
+    ).astype(jnp.float32)
+    highest = jax.lax.Precision.HIGHEST
+    sums = jnp.einsum("bsw,wh->bsh", x, of_head, precision=highest)
+    return jnp.einsum("bsh,wh->bsw", sums, of_head, precision=highest)
+
+
+def _sums_by_product(d: int) -> bool:
+    """Whether ``head_sums`` of heads ``d`` wide takes the products:
+    on the TPU, a head of no whole lane tiles."""
+    return d % _LANE != 0 and jax.default_backend() == "tpu"
+
+
+def head_sums(x, heads):
+    """The sum over each head's columns of the float32 rows ``x`` [b,
+    s, heads x d], at every column of the head: ``[b, s, heads x d]``.
+    Through ``heads_apart``'s view, but on the TPU for a head that is
+    no whole lane tiles (96 columns, or 192): there the view is other
+    bytes than the rows, the compiler copies the array into it and
+    back around the reduction (20 ms a pass over ``[16384, 2880]``
+    float32: PERF.md section 6, PR 70), and two thin products on the
+    MXU make the same sums from the rows as they lie."""
+    if _sums_by_product(x.shape[-1] // heads):
+        return head_sums_by_product(x, heads)
+    apart = heads_apart(x, heads)
+    return jnp.broadcast_to(
+        jnp.sum(apart, axis=-1, keepdims=True), apart.shape
+    ).reshape(x.shape)
+
+
 def _use_pallas(x: jax.Array, w: jax.Array, l2_heads) -> bool:
     if jax.default_backend() != "tpu":
         return False
@@ -74,7 +116,8 @@ def conv_silu_norm_plain(x: jax.Array, w: jax.Array, l2_heads=None,
         a = a + bias.astype(jnp.float32)
     s = jax.nn.silu(a)
     if l2_heads:
-        s = l2norm(heads_apart(s, l2_heads)).reshape(x.shape)
+        # ``l2norm``'s line on the rows as they lie
+        s = s * jax.lax.rsqrt(head_sums(s * s, l2_heads) + L2_NORM_EPS)
     return s.astype(x.dtype)
 
 

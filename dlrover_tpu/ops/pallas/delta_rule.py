@@ -1,9 +1,11 @@
-"""Pallas TPU kernels of the gated delta rule with per-channel decay
-(ops/delta_rule.py has the recurrence and its chunked form).
+"""Pallas TPU kernels of the gated delta rule, with a decay a key
+channel or one a head (ops/delta_rule.py has the recurrence, its two
+forms of decay and its chunked form).
 
 A grid step is one chunk of ``CHUNK`` positions of several adjacent
 heads of one sequence (``heads_a_step``: the most of ``HEADS_A_STEP``
-that divides the operands' heads, one head where none does); the
+that divides the operands' heads, of ``FORWARD_HEADS_A_STEP`` for the
+forward kernels, one head where none does); the
 heads' chunks are walked in order, with each head's [values, keys]
 float32 state resident in VMEM (64 KB a head). The forward kernel goes
 up the sequence. Differentiated, it also writes, for each chunk, the
@@ -72,6 +74,30 @@ caller that holds heads pays a pass over each operand to get here:
 ops/delta_rule.py's 4-D entry is the one place that does, and the
 record says so.
 
+One decay a head (``g`` in ``beta``'s shape). The same two kernels,
+stage for stage, with three differences. The decay of a pair is one
+``[CHUNK, CHUNK]`` mask a head, ``exp`` of the sum of the steps
+between the two positions (``_mask``: one float32 product with a
+triangle of ones, no exponent positive, nothing factored): no ``SUB``,
+no ``CLIP`` and no floor, exact at any decay. ``g`` comes and ``dg``
+goes as ``beta`` does, ``[batch, heads, seq, 1]``, a number a position
+down the sublanes, and is spread over a head's lanes in VMEM only. And
+a head is ``dk`` keys by ``dv`` values of any two widths: the state is
+``[values, keys]``, the chunk's system and its inverse ``[CHUNK,
+CHUNK]`` whatever ``dv``, the scale ``dk ** -0.5``. A width that is
+no lane tile is padded with zero columns inside ``delta_rule``, keys
+to ``HEAD`` and values to whole lane tiles (96 to 128, 192 to 256): a
+zero key column adds nothing to any product over keys and its column
+of the state stays zero, and value columns never meet (every product
+over values is over positions or keys), so the padded columns of ``o``
+are zero and are cut off again; the results are the unpadded
+equations' to the bit. What it costs: a pass over q, k, v and ``o``
+each way (and over ``do``, ``dq``, ``dk``, ``dv``), the entry states
+kept at ``[256, 128]`` where ``[192, 96]`` would do (128 KB a head's
+chunk for 72), and nothing on the MXU or in the vector unit, whose
+tiles are 128 lanes wide whatever a head's width. No weight is padded:
+the model's leaves keep their shapes.
+
 Both calls are made inside one jitted function, ``delta_rule``: a
 device trace names a Pallas call after the innermost jitted function
 that holds it, and the benchmark's ``delta_rule_ms`` tells the
@@ -85,7 +111,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dlrover_tpu.ops.delta_rule import CHUNK, G_FLOOR
+from dlrover_tpu.ops.delta_rule import CHUNK, G_FLOOR, one_decay_a_head
 
 #: rows of a chunk that share a reference for their decay's exponents
 SUB = 16
@@ -105,6 +131,13 @@ BACKWARD_INVERSES = 0
 #: under four in the kernels and 0.6% in the step, for 2.5 s more of
 #: every start spent tracing them (PERF.md, PR 46)
 HEADS_A_STEP = (4, 2)
+#: and of the forward kernels, which take a third pair where the head
+#: count has one: at 30 heads of 96 x 192 six a step read 15.0 ms a
+#: call for two's 20.0, while the backward, with twice the values
+#: alive, read 38.4 for 20.2 (PERF.md, PR 70). Every entry is even
+#: where ``HEADS_A_STEP``'s choice is, so the pairs whose inverses the
+#: forward keeps are the pairs the backward reads
+FORWARD_HEADS_A_STEP = (6,) + HEADS_A_STEP
 
 F32 = jnp.float32
 _NN = (((1,), (0,)), ((), ()))
@@ -112,16 +145,30 @@ _NT = (((1,), (1,)), ((), ()))
 _TN = (((0,), (0,)), ((), ()))
 
 
-def tiles_the_kernel(shape, heads) -> bool:
+#: the widest head of values, padded, whose state a grid step holds
+MOST_VALUES = 256
+
+
+def tiles_the_kernel(shape, heads, v_shape=None) -> bool:
     """Whether the kernels take rows ``[batch, seq, heads x d]``: a
-    head one lane tile wide, the sequence whole chunks."""
-    return shape[2] == heads * HEAD and shape[1] % CHUNK == 0
+    head one lane tile wide, the sequence whole chunks. With
+    ``v_shape``, one decay a head: keys of at most a lane tile and
+    values of at most ``MOST_VALUES`` a head (``delta_rule`` pads both
+    to whole tiles)."""
+    if shape[1] % CHUNK:
+        return False
+    if v_shape is None:
+        return shape[2] == heads * HEAD
+    return (shape[2] <= heads * HEAD
+            and v_shape[2] <= heads * MOST_VALUES)
 
 
-def heads_a_step(heads) -> int:
-    """Heads of one grid step: the most of ``HEADS_A_STEP`` that
-    divides the operands' ``heads``, one where none does."""
-    return next((h for h in HEADS_A_STEP if heads % h == 0), 1)
+def heads_a_step(heads, forward=False) -> int:
+    """Heads of one grid step: the most of ``HEADS_A_STEP`` (of
+    ``FORWARD_HEADS_A_STEP`` for the ``forward`` kernels) that divides
+    the operands' ``heads``, one where none does."""
+    rule = FORWARD_HEADS_A_STEP if forward else HEADS_A_STEP
+    return next((h for h in rule if heads % h == 0), 1)
 
 
 def _interpret() -> bool:
@@ -263,6 +310,49 @@ def _before(q, k, v, g, beta, state, dtype):
 
 
 @jax.jit
+def _mask(g):
+    """One decay a head: ``exp(G_t - G_i)`` [CHUNK, CHUNK] of a
+    chunk's log decay ``g`` [CHUNK, 1], zero above the diagonal. The
+    exponent is the sum of the steps ``i < j <= t``, one float32
+    product of a triangle of ones with ``g`` spread under the
+    diagonal: a sum of terms of one sign, never a difference of two
+    cumulated sums."""
+    under = jnp.where(
+        _triangle(True), jnp.broadcast_to(g, (CHUNK, CHUNK)), 0.0)
+    between = _dot(_ones(_triangle(False)), under, _NN)
+    return jnp.where(_triangle(False), jnp.exp(between), 0.0)
+
+
+@_stage
+def _masked(q, k, mask, dtype):
+    """``A`` (strictly lower) and ``B`` (lower) of a chunk under one
+    decay a head: ``K K^T`` and ``Q K^T`` in one product, times the
+    pairs' decay."""
+    both = _dot(jnp.concatenate([k, q], axis=0), k, _NT, dtype)
+    return (
+        jnp.where(_triangle(True), both[:CHUNK] * mask, 0.0),
+        both[CHUNK:] * mask,
+    )
+
+
+def _before_a_head(q, k, v, g, beta, state, dtype):
+    """``_before`` with one decay a head, ``g`` [CHUNK, 1]: the
+    cumulated log decay spread over a head's lanes, so that
+    ``_against_state`` and every later stage read what they read of a
+    decay a channel, and the pairs' mask in the factors' place."""
+    gc = _cumulated(jnp.broadcast_to(g, q.shape))
+    yield
+    mask = _mask(g)
+    yield
+    a, b = _masked(q, k, mask, dtype)
+    yield
+    return dict(
+        q=q, k=k, v=v, beta=beta, state=state, gc=gc, mask=mask,
+        a=a, b=b, **_against_state(q, k, gc, state, dtype),
+    )
+
+
+@jax.jit
 def _split(n):
     """A strictly lower ``n`` as ``I`` less its blocks of ``SUB`` on
     the diagonal, those blocks, and the rest."""
@@ -354,19 +444,39 @@ def _across(invs, rights, dims):
     return out
 
 
-def _lanes(j):
-    """Head ``j``'s columns of a grid step's block of rows."""
-    return slice(j * HEAD, (j + 1) * HEAD)
+def _lanes(j, width=HEAD):
+    """Head ``j``'s columns of a grid step's block of rows, a head
+    ``width`` of them."""
+    return slice(j * width, (j + 1) * width)
+
+
+def _values(ref, heads):
+    """A head's columns of a block of ``heads`` heads' values."""
+    return ref.shape[1] // heads
 
 
 def _parts(refs, beta_ref, states, dtype):
     """For each head of a grid step the chunked form's parts ahead of
     the inverse. ``refs`` are the blocks of ``q, k, v, g``, a head a
-    lane tile of each, and ``states`` holds the heads' entry states."""
+    lane tile of each (of ``v`` whole tiles), and ``states`` holds the
+    heads' entry states; ``g`` in ``beta``'s shape is one decay a
+    head."""
+    q_ref, k_ref, v_ref, g_ref = refs
+    heads = states.shape[0]
+    wide = _values(v_ref, heads)
+    if g_ref.shape == beta_ref.shape:
+        return _in_turn(
+            _before_a_head(
+                q_ref[:, _lanes(j)].astype(F32),
+                k_ref[:, _lanes(j)].astype(F32),
+                v_ref[:, _lanes(j, wide)].astype(F32),
+                g_ref[j], beta_ref[j], states[j], dtype)
+            for j in range(heads)
+        )
     return _in_turn(
         _before(*(ref[:, _lanes(j)].astype(F32) for ref in refs),
                 beta_ref[j], states[j], dtype)
-        for j in range(states.shape[0])
+        for j in range(heads)
     )
 
 
@@ -388,8 +498,9 @@ def _solved(cs):
 def _kept(cs, inv_ref, w_ref):
     """``_solved`` as the backward has it: read from what the forward
     kept."""
+    wide = _values(w_ref, len(cs))
     for j, c in enumerate(cs):
-        c["w"] = w_ref[:, _lanes(j)]
+        c["w"] = w_ref[:, _lanes(j, wide)]
     return [inv_ref[pair] for pair in range(inv_ref.shape[0])]
 
 
@@ -417,12 +528,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
         states_ref, inv_ref, w_ref = kept
         for pair, inv in enumerate(invs):
             inv_ref[pair] = inv
+    wide = _values(o_ref, len(cs))
     for j, c in enumerate(cs):
         if kept:
             states_ref[j] = c["state"]
-            w_ref[:, _lanes(j)] = c["w"]
+            w_ref[:, _lanes(j, wide)] = c["w"]
         o, state[j] = _result(c, dtype)
-        o_ref[:, _lanes(j)] = (scale * o).astype(o_ref.dtype)
+        o_ref[:, _lanes(j, wide)] = (scale * o).astype(o_ref.dtype)
 
 
 @_stage
@@ -502,6 +614,44 @@ def _to_operands(c, d, after, dtype):
     )
 
 
+@_stage
+def _to_operands_a_head(c, d, after, dtype):
+    """``_to_operands`` with one decay a head: ``dq``, ``dk`` and
+    ``dg`` [CHUNK, 1]. The pairs' decay is the mask, so ``A``'s and
+    ``B``'s cotangents go to ``K K^T`` and ``Q K^T`` through it, and to
+    the log decay as each pair's ``dA A + dB B``: to the row's
+    cumulated sum with one sign, to the column's with the other."""
+    q, k, kd, mask = c["q"], c["k"], c["kd"], c["mask"]
+    da, db = c["beta"] * d["dn"], d["db"]
+    dqg, dkg, dkd = d["dqg"], d["dkg"], d["dkd"]
+    to_kk, to_qk = da * mask, db * mask
+    dq = dqg * c["gamma"] + _dot(to_qk, k, _NN, dtype)
+    dk = (
+        dkg * c["gamma"] + dkd * jnp.exp(c["last"] - c["gc"])
+        + _dot(to_kk, k, _NN, dtype) + _dot(to_kk, k, _TN, dtype)
+        + _dot(to_qk, q, _TN, dtype)
+    )
+    pairs = da * c["a"] + db * c["b"]
+    dgc = (
+        jnp.sum(dqg * c["qg"] + dkg * c["kg"] - dkd * kd,
+                axis=1, keepdims=True)
+        + jnp.sum(pairs, axis=1, keepdims=True)
+        - _dot(pairs, jnp.ones((CHUNK, HEAD), F32), _TN)[:, :1]
+    )
+    last = c["last"][:, :1]
+    dlast = (
+        jnp.sum(jnp.sum(c["state"] * after, axis=1, keepdims=True),
+                axis=0, keepdims=True) * jnp.exp(last)
+        + jnp.sum(jnp.sum(dkd * kd, axis=1, keepdims=True),
+                  axis=0, keepdims=True)
+    )
+    # as ``_to_operands``' last line, on one column spread over a tile
+    dg = _dot(
+        _ones(_triangle(False, upper=True)),
+        jnp.broadcast_to(dgc, (CHUNK, HEAD)), _NN)[:, :1] + dlast
+    return dq, dk, dg
+
+
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, inv_ref,
                 w_ref, do_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
                 dstate, *, scale, dtype):
@@ -510,9 +660,11 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, inv_ref,
         dstate[...] = jnp.zeros_like(dstate)
 
     heads = range(dstate.shape[0])
+    wide = _values(do_ref, len(heads))
+    a_head = g_ref.shape == beta_ref.shape
     cs = _parts((q_ref, k_ref, v_ref, g_ref), beta_ref, states_ref, dtype)
     invs = _kept(cs, inv_ref, w_ref)
-    dos = [scale * do_ref[:, _lanes(j)].astype(F32) for j in heads]
+    dos = [scale * do_ref[:, _lanes(j, wide)].astype(F32) for j in heads]
     afters = [dstate[j] for j in heads]
     ds = [_cotangents(c, do, after, dtype)
           for c, do, after in zip(cs, dos, afters)]
@@ -522,55 +674,63 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, inv_ref,
         d.update(_through_the_inverse(c, dr, dtype))
         yield
         dbeta_ref[j] = d["dbeta"]
-        dv_ref[:, _lanes(j)] = d["dv"].astype(dv_ref.dtype)
+        dv_ref[:, _lanes(j, wide)] = d["dv"].astype(dv_ref.dtype)
         dstate[j] = _before_state(c, d, do, after, dtype)
         yield
-        dq, dk, dg = _to_operands(c, d, after, dtype)
+        dq, dk, dg = (
+            _to_operands_a_head if a_head else _to_operands
+        )(c, d, after, dtype)
         dq_ref[:, _lanes(j)] = dq.astype(dq_ref.dtype)
         dk_ref[:, _lanes(j)] = dk.astype(dk_ref.dtype)
-        dg_ref[:, _lanes(j)] = dg.astype(dg_ref.dtype)
+        if a_head:
+            dg_ref[j] = dg
+        else:
+            dg_ref[:, _lanes(j)] = dg.astype(dg_ref.dtype)
 
     _in_turn(rest_of(*of) for of in zip(heads, cs, ds, dos, afters, drs))
 
 
-def _specs(chunks, together, reverse):
-    """Block specs of a ``[batch, seq, heads x d]`` operand, of
-    ``beta`` as ``[batch, heads, seq, 1]``, of the entry states
-    ``[batch, heads, chunks, d, d]`` and of the pairs' inverses
-    (``_kept_shapes``), for the grid ``(batch, heads / together,
-    chunk)``: a block is ``together`` adjacent heads' part of each;
-    ``reverse`` walks the chunks from the last."""
+def _specs(chunks, together, reverse, values=HEAD):
+    """Block specs of a ``[batch, seq, heads x d]`` operand of keys
+    and of one of ``values`` columns a head, of ``beta`` as ``[batch,
+    heads, seq, 1]``, of the entry states ``[batch, heads, chunks,
+    values, d]`` and of the pairs' inverses (``_kept_shapes``), for
+    the grid ``(batch, heads / together, chunk)``: a block is
+    ``together`` adjacent heads' part of each; ``reverse`` walks the
+    chunks from the last."""
     def at(n):
         return chunks - 1 - n if reverse else n
 
     paired = _paired(together)
-    wide = pl.BlockSpec(
-        (None, CHUNK, together * HEAD), lambda b, h, n: (b, at(n), h))
+    wide, wide_values = (pl.BlockSpec(
+        (None, CHUNK, together * d), lambda b, h, n: (b, at(n), h)
+    ) for d in (HEAD, values))
     beta = pl.BlockSpec(
         (None, together, CHUNK, 1), lambda b, h, n: (b, h, at(n), 0))
     states = pl.BlockSpec(
-        (None, together, None, HEAD, HEAD),
+        (None, together, None, values, HEAD),
         lambda b, h, n: (b, h, at(n), 0, 0))
     invs = pl.BlockSpec(
         (None, together // paired, None, paired * CHUNK, paired * CHUNK),
         lambda b, h, n: (b, h, at(n), 0, 0))
-    return wide, beta, states, invs
+    return wide, wide_values, beta, states, invs
 
 
-def _kept_shapes(batch, seq, heads):
+def _kept_shapes(batch, seq, heads, values=HEAD):
     """What the forward keeps of every chunk for the backward, beside
-    ``o``: the heads' entry states, each pair's inverse as ``_inverse``
-    returns it (two heads' on the diagonal of one matrix; one head's
-    where a grid step takes one), and the heads' ``w`` as rows. All
-    float32: the backward's products see the bits they would make."""
+    ``o``: the heads' entry states ``[values, keys]``, each pair's
+    inverse as ``_inverse`` returns it (two heads' on the diagonal of
+    one matrix; one head's where a grid step takes one), and the
+    heads' ``w`` as rows. All float32: the backward's products see the
+    bits they would make."""
     chunks = seq // CHUNK
     paired = _paired(heads_a_step(heads))
     return [
-        jax.ShapeDtypeStruct((batch, heads, chunks, HEAD, HEAD), F32),
+        jax.ShapeDtypeStruct((batch, heads, chunks, values, HEAD), F32),
         jax.ShapeDtypeStruct(
             (batch, heads // paired, chunks, paired * CHUNK, paired * CHUNK),
             F32),
-        jax.ShapeDtypeStruct((batch, seq, heads * HEAD), F32),
+        jax.ShapeDtypeStruct((batch, seq, heads * values), F32),
     ]
 
 
@@ -580,38 +740,46 @@ def _params():
     )
 
 
-def _forward(q, k, v, g, beta, heads, keep_states):
+def _forward(q, k, v, g, beta, heads, keep_states, scale=HEAD ** -0.5):
+    """``g`` as rows, a decay a channel, or as ``beta`` is, one a
+    head; ``v`` a head of whole lane tiles."""
     batch, seq, _ = q.shape
     chunks = seq // CHUNK
-    together = heads_a_step(heads)
-    wide, beta_spec, states_spec, invs_spec = _specs(chunks, together, False)
-    out_specs = [wide]
+    together = heads_a_step(heads, forward=True)
+    values = v.shape[2] // heads
+    wide, wide_values, beta_spec, states_spec, invs_spec = _specs(
+        chunks, together, False, values)
+    g_spec = beta_spec if g.shape == beta.shape else wide
+    out_specs = [wide_values]
     out_shape = [jax.ShapeDtypeStruct(v.shape, v.dtype)]
     if keep_states:
-        out_specs += [states_spec, invs_spec, wide]
-        out_shape += _kept_shapes(batch, seq, heads)
+        out_specs += [states_spec, invs_spec, wide_values]
+        out_shape += _kept_shapes(batch, seq, heads, values)
     out = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=HEAD ** -0.5, dtype=q.dtype),
+        functools.partial(_fwd_kernel, scale=scale, dtype=q.dtype),
         grid=(batch, heads // together, chunks),
-        in_specs=[wide, wide, wide, wide, beta_spec],
+        in_specs=[wide, wide, wide_values, g_spec, beta_spec],
         out_specs=out_specs, out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((together, HEAD, HEAD), F32)],
+        scratch_shapes=[pltpu.VMEM((together, values, HEAD), F32)],
         compiler_params=_params(), interpret=_interpret(),
     )(q, k, v, g, beta)
     return out if keep_states else out[0]
 
 
-def _backward(q, k, v, g, beta, kept, do, heads):
+def _backward(q, k, v, g, beta, kept, do, heads, scale=HEAD ** -0.5):
     batch, seq, _ = q.shape
     chunks = seq // CHUNK
     together = heads_a_step(heads)
-    wide, beta_spec, states_spec, invs_spec = _specs(chunks, together, True)
+    values = v.shape[2] // heads
+    wide, wide_values, beta_spec, states_spec, invs_spec = _specs(
+        chunks, together, True, values)
+    g_spec = beta_spec if g.shape == beta.shape else wide
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, scale=HEAD ** -0.5, dtype=q.dtype),
+        functools.partial(_bwd_kernel, scale=scale, dtype=q.dtype),
         grid=(batch, heads // together, chunks),
-        in_specs=[wide, wide, wide, wide, beta_spec,
-                  states_spec, invs_spec, wide, wide],
-        out_specs=[wide, wide, wide, wide, beta_spec],
+        in_specs=[wide, wide, wide_values, g_spec, beta_spec,
+                  states_spec, invs_spec, wide_values, wide_values],
+        out_specs=[wide, wide, wide_values, g_spec, beta_spec],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -619,7 +787,7 @@ def _backward(q, k, v, g, beta, kept, do, heads):
             jax.ShapeDtypeStruct(g.shape, g.dtype),
             jax.ShapeDtypeStruct(beta.shape, beta.dtype),
         ],
-        scratch_shapes=[pltpu.VMEM((together, HEAD, HEAD), F32)],
+        scratch_shapes=[pltpu.VMEM((together, values, HEAD), F32)],
         compiler_params=_params(), interpret=_interpret(),
     )(q, k, v, g, beta, *kept, do)
 
@@ -630,6 +798,62 @@ def _beta_by_head(beta):
     return jnp.swapaxes(beta, 1, 2)[..., None]
 
 
+def _padded(x, heads, to):
+    """Rows ``[batch, seq, heads x d]`` with every head's columns
+    padded with zeros to ``to``."""
+    batch, seq, width = x.shape
+    d = width // heads
+    if d == to:
+        return x
+    return jnp.pad(
+        x.reshape(batch, seq, heads, d), ((0, 0),) * 3 + ((0, to - d),)
+    ).reshape(batch, seq, heads * to)
+
+
+def _cut(x, heads, d):
+    """``_padded``'s inverse: every head's first ``d`` columns."""
+    batch, seq, width = x.shape
+    if width == heads * d:
+        return x
+    return x.reshape(batch, seq, heads, -1)[..., :d].reshape(
+        batch, seq, heads * d)
+
+
+def padded_values(dv):
+    """A head's ``dv`` values in whole lane tiles."""
+    return -(-dv // HEAD) * HEAD
+
+
+def _a_head(q, k, v, g, beta, kept, do, keep_states):
+    """``delta_rule`` with one decay a head, ``g`` [batch, seq,
+    heads], on heads of ``dk`` keys by ``dv`` values: the same kernels
+    on heads padded to whole lane tiles (the module's docstring says
+    why that is exact and what it costs)."""
+    heads = beta.shape[2]
+    dk, dv = q.shape[2] // heads, v.shape[2] // heads
+    wide = (
+        _padded(q, heads, HEAD), _padded(k, heads, HEAD),
+        _padded(v, heads, padded_values(dv)),
+        _beta_by_head(g.astype(F32)), _beta_by_head(beta.astype(F32)),
+    )
+    scale = dk ** -0.5
+    if do is None:
+        out = _forward(*wide, heads, keep_states, scale)
+        if keep_states:
+            return _cut(out[0], heads, dv), tuple(out[1:])
+        return _cut(out, heads, dv)
+    grads = _backward(
+        *wide, kept, _padded(do, heads, padded_values(dv)), heads, scale)
+
+    def by_position(x, like):
+        return jnp.swapaxes(x[..., 0], 1, 2).astype(like.dtype)
+
+    return (
+        *(_cut(x, heads, d) for x, d in zip(grads, (dk, dk, dv))),
+        by_position(grads[3], g), by_position(grads[4], beta),
+    )
+
+
 @functools.partial(jax.jit, static_argnames=("keep_states",))
 def delta_rule(q, k, v, g, beta, kept=None, do=None, keep_states=False):
     """On rows ``[batch, seq, heads x 128]`` and ``beta`` ``[batch,
@@ -637,8 +861,12 @@ def delta_rule(q, k, v, g, beta, kept=None, do=None, keep_states=False):
     also what the backward reads: the chunks' entry states, inverses
     and ``w``, ``_kept_shapes``), or with those three as ``kept`` and
     the result's cotangent ``do`` the backward kernel's five gradients,
-    each in its operand's shape. One jitted name for both, which is
-    what a device trace calls them."""
+    each in its operand's shape. With ``g`` in ``beta``'s shape, one
+    decay a head, on rows of ``heads x dk`` keys and ``heads x dv``
+    values (``_a_head``). One jitted name for both calls and both
+    forms, which is what a device trace calls them."""
+    if one_decay_a_head(k, g, beta):
+        return _a_head(q, k, v, g, beta, kept, do, keep_states)
     heads = q.shape[2] // HEAD
     wide = (q, k, v, g.astype(F32), _beta_by_head(beta.astype(F32)))
     if do is None:
@@ -651,13 +879,26 @@ def delta_rule(q, k, v, g, beta, kept=None, do=None, keep_states=False):
     )
 
 
-def _record(folded, heads):
-    """Say what was built, at trace time, for operands of ``heads``
-    heads: the gauges and counters of docs/TELEMETRY.md. Every Pallas
-    call a step holds is counted once, by whether its caller held rows
-    or heads that were ``folded``."""
+#: the labels of the two counters of calls: the form of decay
+#: (``channel`` or ``head``) and a head's ``<keys>x<values>`` as the
+#: caller handed them, ahead of any padding
+CALL_LABELS = ("decay", "head")
+
+
+def _record(folded, k, v, g, beta):
+    """Say what was built, at trace time, for these operands: the
+    gauges and counters of docs/TELEMETRY.md. Every Pallas call a step
+    holds is counted once, by whether its caller held rows or heads
+    that were ``folded``, under the form of decay and the head's two
+    widths as labels."""
     from dlrover_tpu.telemetry.registry import counter, gauge
 
+    heads = beta.shape[2]
+    a_head = one_decay_a_head(k, g, beta)
+    dk, dv = k.shape[2] // heads, v.shape[2] // heads
+    values = padded_values(dv) if a_head else HEAD
+    labels = dict(
+        decay="head" if a_head else "channel", head=f"{dk}x{dv}")
     together = heads_a_step(heads)
     gauge(
         "delta_rule_chunk",
@@ -666,13 +907,13 @@ def _record(folded, heads):
     gauge(
         "delta_rule_heads_per_step",
         "adjacent heads whose chunk one grid step of the gated delta "
-        "rule's kernels takes",
+        "rule's backward kernel takes (the forward's may take more)",
     ).set(together)
     gauge(
         "delta_rule_state_bytes",
         "bytes of the states, a grid step's heads', resident in VMEM "
         "through the gated delta rule's scan",
-    ).set(together * HEAD * HEAD * 4)
+    ).set(together * values * HEAD * 4)
     gauge(
         "delta_rule_backward_kernels",
         "Pallas kernels of the gated delta rule's backward pass, beside "
@@ -690,40 +931,42 @@ def _record(folded, heads):
         "inverse and w, float32",
     ).set(sum(
         array.size * array.dtype.itemsize
-        for array in _kept_shapes(1, CHUNK, together)) // together)
+        for array in _kept_shapes(1, CHUNK, together, values)) // together)
     if folded:
         counter(
             "delta_rule_folded_calls",
             "Pallas calls of the gated delta rule traced on operands "
             "that came as [batch, seq, heads, d] and were folded to "
             "rows: a relayout of each on the chip",
-        ).inc()
+            CALL_LABELS,
+        ).labels(**labels).inc()
     else:
         counter(
             "delta_rule_rows_calls",
             "Pallas calls of the gated delta rule traced on operands "
             "that came as rows [batch, seq, heads x d], as the kernels "
             "read them",
-        ).inc()
+            CALL_LABELS,
+        ).labels(**labels).inc()
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def delta_rule_tpu(q, k, v, g, beta, folded=False):
     """``delta_rule`` with its differentiation rule; ``folded`` says,
     for the record alone, that the caller held heads."""
-    _record(folded, beta.shape[2])
+    _record(folded, k, v, g, beta)
     return delta_rule(q, k, v, g, beta)
 
 
 def _vjp_fwd(q, k, v, g, beta, folded):
-    _record(folded, beta.shape[2])
+    _record(folded, k, v, g, beta)
     o, kept = delta_rule(q, k, v, g, beta, keep_states=True)
     return o, (q, k, v, g, beta, kept)
 
 
 def _vjp_bwd(folded, saved, do):
     *operands, kept = saved
-    _record(folded, operands[4].shape[2])
+    _record(folded, *operands[1:])
     return delta_rule(*operands, kept=kept, do=do)
 
 

@@ -2,9 +2,10 @@
 and its output projection (ops/gated_norm.py has the equations): one
 pass forward, one backward, over the rows ``[batch, seq, groups x w]``
 that the scan's kernels write and the projection reads. One frame (the
-grid, the specs, the walk) and two bodies, ``BODIES``: a Mamba-2
-mixer's gate and then a group's norm, and a linear-attention layer's
-norm a head and then its gate.
+grid, the specs, the walk) and three bodies, ``BODIES``: a Mamba-2
+mixer's gate and then a group's norm, a linear-attention layer's
+norm a head and then its sigmoid gate, and a Gated DeltaNet layer's
+norm a head and then ``silu`` of its gate.
 
 Forward reads ``o`` and ``z`` and writes ``y``: 6 bytes a token and
 column in bf16. Backward reads ``o``, ``z`` and ``dy``, writes ``do``
@@ -26,6 +27,11 @@ and the heads', ``sig = sigmoid(z + bias)``::
     d scale = sum over rows of dy * o * r * sig
     do = r * dn - o * r^3 * mean_head(dn * o)
     dz = dy * o * r * scale * sig * (1 - sig);  d bias = sum over rows of dz
+
+and the heads' with ``silu``, ``sil = silu(z) = z sigmoid(z)``, the
+same lines with ``sil`` for ``sig`` but the last::
+
+    dz = dy * o * r * scale * sigmoid(z) * (1 + z * (1 - sigmoid(z)))
 
 A grid step is a block of time steps of one sequence at a run of whole
 groups on the lanes (one group of 1,024; eight heads of 128), walked
@@ -172,12 +178,44 @@ def _norm_then_gate_back(at, o_ref, z_ref, dy_ref, vector_refs, do_ref,
         _add_rows(db_ref[0], at, dz)
 
 
+def _normed_silu(o_ref, z_ref, at, eps):
+    """``(n, r, z, sigmoid(z))`` of the rows ``at`` in float32: a
+    head's rows over their root mean square, the factor [rows, 1],
+    the gate's pre-activation and its sigmoid."""
+    o, z = o_ref[at].astype(F32), z_ref[at].astype(F32)
+    r = jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
+    return o * r, r, z, jax.nn.sigmoid(z)
+
+
+def _norm_then_silu(at, o_ref, z_ref, vector_refs, y_ref, eps):
+    scale_ref, = vector_refs
+    n, _, z, sig = _normed_silu(o_ref, z_ref, at, eps)
+    y_ref[at] = (n * scale_ref[:, at[1]] * (z * sig)).astype(y_ref.dtype)
+
+
+def _norm_then_silu_back(at, o_ref, z_ref, dy_ref, vector_refs, do_ref,
+                         dz_ref, sum_refs, eps):
+    (scale_ref,), (ds_ref,) = vector_refs, sum_refs
+    n, r, z, sig = _normed_silu(o_ref, z_ref, at, eps)
+    dy = dy_ref[at].astype(F32)
+    sil = z * sig
+    _add_rows(ds_ref, at, dy * n * sil)
+    by_scale = dy * scale_ref[:, at[1]]
+    dn = by_scale * sil
+    do_ref[at] = (r * (
+        dn - n * jnp.mean(dn * n, axis=-1, keepdims=True))).astype(do_ref.dtype)
+    dz_ref[at] = (
+        by_scale * n * sig * (1.0 + z * (1.0 - sig))).astype(dz_ref.dtype)
+
+
 #: a body by the name a caller gives it: ``gate, norm`` a Mamba-2
 #: mixer's, ``norm, gate`` a linear-attention layer's heads' (with a
-#: second vector, the gate's bias)
+#: second vector, the gate's bias), ``norm, silu`` a Gated DeltaNet
+#: layer's heads'
 BODIES = {
     "gate, norm": Body(_gate_then_norm, _gate_then_norm_back),
     "norm, gate": Body(_norm_then_gate, _norm_then_gate_back),
+    "norm, silu": Body(_norm_then_silu, _norm_then_silu_back),
 }
 
 

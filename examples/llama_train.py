@@ -55,6 +55,9 @@ MODELS = {
     "llama_linear_tiny": llama.llama_linear_tiny,
     "llama_sandwich_tiny": llama.llama_sandwich_tiny,
     "llama_mamba_tiny": llama.llama_mamba_tiny,
+    # three layers of the delta rule with one decay a head to one of
+    # full attention, blocks normed on their branches' results alone
+    "llama_gdn_tiny": llama.llama_gdn_tiny,
     # two layers walked four times a step, an exit gate a position
     "llama_loop_tiny": llama.llama_loop_tiny,
 }
@@ -234,7 +237,9 @@ def main():
     decay_min = None
     state_space = "M" in (cfg.hybrid_override_pattern or "") or (
         "mamba" in (cfg.layer_types or ()))
-    if "linear_attention" in (cfg.layer_types or ()) or state_space:
+    a_head = "gated_delta_net" in (cfg.layer_types or ())
+    if ("linear_attention" in (cfg.layer_types or ()) or state_space
+            or a_head):
         decay_min = jax.jit(functools.partial(llama.decay_min, cfg=cfg))
 
     device = devices[0]
@@ -341,16 +346,20 @@ def main():
                         + ",".join(f"{v:.3f}" for v in shares),
                         flush=True)
                 if decay_min is not None:
-                    # how fast the delta rule's fastest channel, or
-                    # the state-space scan's fastest head, forgets on
-                    # this batch (GET /metrics)
+                    # how fast the delta rule's fastest channel (or,
+                    # with one decay a head, its fastest head), or the
+                    # state-space scan's fastest head, forgets on this
+                    # batch (GET /metrics)
                     least = llama.set_decay_min_gauge(
                         decay_min(params, mb[0][0]),
-                        "ssm_decay_min" if state_space else "kda_decay_min",
+                        "ssm_decay_min" if state_space else
+                        "gdn_decay_min" if a_head else "kda_decay_min",
                     )
                     print(
                         f"SSM_DECAY step={step} min_a={least:.3e}"
                         if state_space else
+                        f"GDN_DECAY step={step} min_alpha={least:.3e}"
+                        if a_head else
                         f"KDA_DECAY step={step} min_alpha={least:.3e}",
                         flush=True)
                 ckpt.save(

@@ -127,8 +127,9 @@ MODULE_BUDGET_OVERRIDES = {
     "test_chip_compile": 1600.0,
     # the gate-and-norm frame's two bodies (the heads' with a bias and
     # without) in interpret mode, at widths of 4,096 and 8,192 too (PR
-    # 67): 78s alone
-    "test_gated_norm": 180.0,
+    # 67): 78s alone; since PR 70 a third body (the heads' with
+    # ``silu``) through the same cases, 119 tests for 87: 105s alone
+    "test_gated_norm": 300.0,
     # the sala family's program against its reference, whose
     # selection walks whole score arrays and whose recurrence a
     # position at a time, with seventeen edited references jitted
@@ -143,8 +144,10 @@ MODULE_BUDGET_OVERRIDES = {
     # three other workers
     "test_ssd": 150.0,
     # the convolution's kernels in interpret mode, since PR 54 with a
-    # bias as well (nine more cases): 63s beside three other workers
-    "test_kda_conv": 100.0,
+    # bias as well (nine more cases): 63s beside three other workers;
+    # since PR 70 a head of 96 in rows of 2,880 on the plain path, six
+    # more: 107s beside five other workers
+    "test_kda_conv": 180.0,
     # the walk's written-out backward jitted for experts without a
     # gate, in a latent and not, against a dense loop (PR 54): 69s
     # beside three other workers
@@ -184,6 +187,18 @@ MODULE_BUDGET_OVERRIDES = {
     # eleven edited references on two batches (PR 68): 57s alone, 79s
     # beside three other workers, 184s beside five that compile too
     "test_yardstick_jamba": 300.0,
+    # the same period of four layers, three of them the delta rule with
+    # one decay a head, against the float32 reference and its
+    # gradients, thirteen edited references and the float8 one on two
+    # batches (PR 70): 42s alone on four workers, 125s of tests
+    "test_yardstick_olmo_hybrid": 300.0,
+    # one whole rehearsal of the new cell, launcher to last line (PR
+    # 70): 35s alone
+    "test_yardstick_olmo_hybrid_rehearsal": 150.0,
+    # a four-layer stack of the one-decay delta rule jitted forward
+    # and backward under each remat policy, once through the kernels
+    # in interpret mode (PR 70): 37s alone on four workers, 82s of tests
+    "test_llama_gdn": 200.0,
     # the selective scan's Pallas kernels in interpret mode, forward
     # and backward on four shapes (PR 68): 50s alone, 76s beside three
     # other workers
@@ -255,8 +270,11 @@ MODULE_BUDGET_OVERRIDES = {
     # on both paths in two precisions, 35 tests for 26: 82 s alone,
     # 234 s as the only module of six workers, which all compile at
     # once; since PR 46 the kernels at one, two and four heads a grid
-    # step, 53 tests for 35: 481 s as the only module of six workers
-    "test_delta_rule": 650.0,
+    # step, 53 tests for 35: 481 s as the only module of six workers;
+    # since PR 70 one decay a head on heads of two widths, 94 tests for
+    # 53, the new ones at 24 x 40 and three at 96 x 192: 120 s on four
+    # workers
+    "test_delta_rule": 900.0,
     # eight-layer delta-rule hybrids jitted forward and backward under
     # each remat policy, the kernels in interpret mode inside a model,
     # a trainer over eight CPU devices: 145 s alone, 199 s beside five

@@ -1605,8 +1605,10 @@ def test_solar_step_holds_the_delta_rules_kernels(
         gated_norm, "_use_pallas", lambda o, groups: (
             norm_kernels.tiles_the_kernel(o.shape, groups)))
     monkeypatch.setattr(norm_kernels, "_interpret", lambda: False)
-    calls = [counter(f"delta_rule_{handed}_calls", "")
-             for handed in ("rows", "folded")]
+    calls = [counter(
+        f"delta_rule_{handed}_calls", "", scan_kernels.CALL_LABELS,
+    ).labels(decay="channel", head="128x128")
+        for handed in ("rows", "folded")]
     calls += [counter(f"{entry}_{path}_calls", "")
               for entry in ("kda_conv", "head_norm_gate")
               for path in ("kernel", "plain")]
@@ -1777,8 +1779,10 @@ def test_kimi_step_holds_the_one_backward_and_both_operators_kernels(
         gated_norm, "_use_pallas", lambda o, groups: (
             norm_kernels.tiles_the_kernel(o.shape, groups)))
     monkeypatch.setattr(norm_kernels, "_interpret", lambda: False)
-    calls = [counter(f"delta_rule_{handed}_calls", "")
-             for handed in ("rows", "folded")]
+    calls = [counter(
+        f"delta_rule_{handed}_calls", "", scan_kernels.CALL_LABELS,
+    ).labels(decay="channel", head="128x128")
+        for handed in ("rows", "folded")]
     calls += [counter(f"{entry}_{path}_calls", "")
               for entry in ("kda_conv", "head_norm_gate")
               for path in ("kernel", "plain")]
@@ -2241,6 +2245,156 @@ def test_sala_step_holds_the_selections_and_the_scans_kernels(
     for scope in ("sparse.compress", "sparse.select", "sparse.attn",
                   "lightning.proj", "lightning.scan", "lightning.out",
                   "embed.scale", "branch.scale", "head.scale", "attn.gate"):
+        assert scope in text, scope
+
+
+#: ``peak_memory_in_bytes`` of ``olmo-hybrid-7b-vp8.steady``'s step as
+#: this file compiles it (1 x 16,384, four layers, an eighth of the
+#: vocabulary, remat ``minimal``, the least effort; PERF.md, PR 70):
+#: 5.57 GB of it the state; 12,887,379,968 at the default effort, which
+#: the chip compiles at (the file's ``depth``)
+OLMO_HYBRID_STEP_BYTES = 12_887_527_424
+
+
+def test_olmo_hybrid_step_holds_the_scalar_scans_kernels(
+    topo, on_tpu_path, monkeypatch
+):
+    """``olmo-hybrid-7b-vp8.steady``'s step: it fits under 15.75 GiB
+    and plans no more than was read when the cell was built; the three
+    Gated DeltaNet layers' scans (each the forward, the forward again
+    under ``minimal`` and the one backward over ``[1, 30, 256, 256,
+    128]`` entry states, a head's 96 keys by 192 values padded to
+    whole lane tiles inside the operator) are named as
+    ``delta_rule_ms`` tells them, and as no other reader does, and
+    carry ``gdn.scan``; the one attention layer's kernels, 30
+    ungrouped heads of 128, are named as ``attn_kernel_ms`` tells them
+    under ``attn.full``; v's convolution is a ``kda_conv`` call under
+    ``gdn.conv`` while q's and k's, a head of 96 in rows of 2,880, and
+    the heads' norm and ``silu`` gate at a head of 192 are plain
+    fusions (their frames take whole lane tiles); the log decay is a
+    number a head: no float32 ``[16384, 2880]`` array and no ``[.., 64,
+    64, 96]`` decay is in the step, and ``g`` reaches the kernels as
+    ``[1, 30, 16384, 1]``; no leaf is padded; the counters say the
+    form of decay and the head's two widths; every scope of the layer
+    is in the text."""
+    from dlrover_tpu.ops import delta_rule, gated_norm, kda_conv
+    from dlrover_tpu.ops.pallas import delta_rule as scan_kernels
+    from dlrover_tpu.ops.pallas import gated_norm as norm_kernels
+    from dlrover_tpu.ops.pallas import kda_conv as conv_kernels
+    from dlrover_tpu.telemetry.registry import counter, gauge
+    from yardstick import cells, worker
+    from yardstick.layer_metrics import (
+        attn_kernel_ms, delta_rule_ms, moe_expert_ms, short_conv_ms, ssd_ms,
+    )
+
+    monkeypatch.setattr(
+        delta_rule, "_use_pallas_a_head", lambda q, v, heads: (
+            scan_kernels.tiles_the_kernel(q.shape, heads, v.shape)))
+    monkeypatch.setattr(scan_kernels, "_interpret", lambda: False)
+    monkeypatch.setattr(
+        kda_conv, "_use_pallas", lambda x, w, l2_heads: (
+            conv_kernels.tiles_the_kernel(x.shape, w.shape, l2_heads)))
+    monkeypatch.setattr(conv_kernels, "_interpret", lambda: False)
+    monkeypatch.setattr(
+        gated_norm, "_use_pallas", lambda o, groups: (
+            norm_kernels.tiles_the_kernel(o.shape, groups)))
+    monkeypatch.setattr(norm_kernels, "_interpret", lambda: False)
+    # the plain paths as a TPU process traces them: the heads' sums of
+    # a head of 96 or 192 columns by two thin products, not a view
+    monkeypatch.setattr(kda_conv, "_sums_by_product", lambda d: d % 128 != 0)
+    form = dict(decay="head", head="96x192")
+    calls = [counter(
+        f"delta_rule_{handed}_calls", "", scan_kernels.CALL_LABELS,
+    ).labels(**form) for handed in ("rows", "folded")]
+    calls += [counter(f"{entry}_{path}_calls", "")
+              for entry in ("kda_conv", "head_norm_silu")
+              for path in ("kernel", "plain")]
+    before = [c.value for c in calls]
+    gauge("delta_rule_heads_per_step", "").set(0)
+    _, config, traffic = cells.load_cell("olmo-hybrid-7b-vp8.steady")
+    assert (traffic["global_batch"], traffic["seq"]) == (1, 16384)
+    cfg = worker.program_config(config, traffic)
+    assert (cfg.remat, cfg.loss_chunk, cfg.post_norms) == (
+        "minimal", 0, "alone")
+    # what decides each path, from the shapes alone
+    assert scan_kernels.tiles_the_kernel(
+        (1, 16384, 2880), 30, (1, 16384, 5760))
+    assert not scan_kernels.tiles_the_kernel((1, 16384, 2880), 30)
+    assert conv_kernels.tiles_the_kernel((1, 16384, 5760), (5760, 4))
+    assert not conv_kernels.tiles_the_kernel(
+        (1, 16384, 2880), (2880, 4), 30)
+    assert not norm_kernels.tiles_the_kernel((1, 16384, 5760), 30)
+    mesh = Mesh(
+        np.array(topo.devices[:1]).reshape(1, 1), ("data", "fsdp"))
+    trainer = make_trainer_for_llama(
+        cfg, mesh, strategy=traffic["strategy"],
+        optimizer=optax.adamw(traffic["optimizer"]["learning_rate"]),
+    )
+    layers = gauge("dlrover_model_operator_layers", "", ("operator",))
+    assert [layers.labels(operator=o).value for o in (
+        "gated_delta_net", "linear_attention", "full_attention")] == [
+            3, 0, 1]
+    compiled = trainer.train_step.lower(*_abstract_step_args(
+        trainer, traffic["global_batch"], traffic["seq"])
+    ).compile(LEAST_EFFORT)
+    planned = compiled.memory_analysis().peak_memory_in_bytes
+    print("olmo_hybrid step plans", planned)
+    assert planned <= OLMO_HYBRID_STEP_BYTES < 15.75 * 2 ** 30
+    text = compiled.as_text()
+    kernels = re.findall(
+        r"%([\w.\-]+) = ([^\n]*?) custom-call\([^\n]*"
+        r"custom_call_target=\"tpu_custom_call\"[^\n]*"
+        r"op_name=\"([^\"]*)\"", text)
+    scan = [(name, op) for name, _, op in kernels
+            if delta_rule_ms.KERNEL.search(name)]
+    assert len(scan) == 3 * 3, [name for name, _ in scan]
+    assert all("gdn.scan" in op for _, op in scan)
+    # the chunks' entry states [values, keys], the pairs' inverses
+    # and ``w``, at the padded widths
+    assert _shapes_of_the_keeping_calls(kernels, delta_rule_ms.KERNEL) == [[
+        "bf16[1,16384,7680]", "f32[1,30,256,256,128]",
+        "f32[1,15,256,128,128]", "f32[1,16384,7680]"]] * 6
+    attend = [(name, op) for name, _, op in kernels
+              if attn_kernel_ms.KERNEL.search(name)]
+    assert len(attend) == 3, [name for name, _ in attend]
+    assert all("attn.full" in op for _, op in attend)
+    conv = [name for name, _, op in kernels if "gdn.conv" in op]
+    assert len(conv) == 3 * 3, conv  # v's, of each layer, three times
+    assert all(name.startswith("kda_conv") for name in conv)
+    assert len(kernels) == 9 + 3 + 9  # and no other kernel
+    all_readers = (
+        attn_kernel_ms, delta_rule_ms, moe_expert_ms, short_conv_ms, ssd_ms)
+    for name, _, op in kernels:
+        readers = [r for r in all_readers if r.KERNEL.search(name)]
+        assert len(readers) == ("gdn.conv" not in op), name
+    # one decay a head: nothing of it a key column wide. The float32
+    # arrays of that width are q and k on their way through the plain
+    # convolutions; none is the decay's or the scan's
+    wide = re.findall(
+        r"= f32\[(?:1,)?16384,2880\][^\n]*op_name=\"([^\"]*)\"", text)
+    assert any("gdn.conv" in op for op in wide)
+    assert not [op for op in wide if "gdn.decay" in op or "gdn.scan" in op]
+    assert not re.search(r"\[(\d+,)*64,64,96\]", text)
+    assert "f32[1,30,16384,1]" in text
+    # nine scans on rows and none folded; nine calls of the
+    # convolutions' entry, v's three on the kernels; three of the
+    # heads' norm and gate, plain
+    assert [c.value - was for c, was in zip(calls, before)] == [
+        9, 0, 3, 6, 0, 3]
+    assert gauge("delta_rule_heads_per_step", "").value == 2
+    assert gauge("delta_rule_state_bytes", "").value == 2 * 256 * 128 * 4
+    assert gauge("delta_rule_kept_bytes", "").value == 4 * (
+        256 * 128 + 64 * 128 + 64 * 256)
+    assert tuning.last_selection()["gqa_group"] == 1
+    assert tuning.last_selection()["seq"] == 16384
+    shapes = jax.eval_shape(
+        lambda: llama.init_params(jax.random.key(0), cfg))
+    assert shapes["period"][0]["wq"].shape == (1, 3840, 2880)
+    assert shapes["period"][0]["wo"].shape == (1, 5760, 3840)
+    assert llama.param_count(cfg) == 928_862_196
+    for scope in ("gdn.proj", "gdn.conv", "gdn.decay", "gdn.scan",
+                  "gdn.out", "norm.post_attn", "norm.post_mlp",
+                  "attn.full"):
         assert scope in text, scope
 
 

@@ -457,11 +457,15 @@ def test_rows_entry_refuses_mismatched_operands(wrong):
         delta_rule.gated_delta_rule_rows(*flat, heads=heads)
 
 
-def _calls():
+def _calls(decay="channel", head=f"{D}x{D}"):
+    """The two counters of calls, under the labels of a form of decay
+    and a head's two widths."""
     from dlrover_tpu.telemetry.registry import counter
 
-    return (counter("delta_rule_rows_calls", "").value,
-            counter("delta_rule_folded_calls", "").value)
+    return tuple(
+        counter(f"delta_rule_{handed}_calls", "", kernels.CALL_LABELS)
+        .labels(decay=decay, head=head).value
+        for handed in ("rows", "folded"))
 
 
 def test_dispatch_says_what_it_built(kernels_at_the_entry):
@@ -509,3 +513,251 @@ def test_dispatch_says_what_it_built(kernels_at_the_entry):
         # a pair's inverse is one [128, 128] matrix, half of it a head's
         assert gauge("delta_rule_kept_bytes", "").value == 4 * (
             128 * 128 + (64 * 64 if together == 1 else 128 * 64) + 64 * 128)
+
+
+# ---------------------------------------------------------------------------
+# one decay a head, on heads of two widths
+
+def recurrence_a_head(q, k, v, g, beta):
+    """``S_t = exp(g_t) (I - beta_t k_t k_t^T) S_{t-1} + beta_t k_t
+    v_t^T`` with ``g_t`` a number a head, ``S`` [dk, dv], ``o_t = S_t^T
+    q_t / sqrt(dk)``, a position at a time, float32."""
+    b, s, h, dk = q.shape
+
+    def step(state, x):  # [b, h, keys, values]
+        q_t, k_t, v_t, g_t, beta_t = x
+        state = state * jnp.exp(g_t)[..., None, None]
+        held = jnp.einsum("bhc,bhcv->bhv", k_t, state)
+        state = state + jnp.einsum(
+            "bhc,bhv->bhcv", beta_t[..., None] * k_t, v_t - held)
+        return state, jnp.einsum("bhc,bhcv->bhv", q_t, state)
+
+    xs = tuple(
+        jnp.moveaxis(x.astype(jnp.float32), 1, 0)
+        for x in (q, k, v, g, beta)
+    )
+    _, o = jax.lax.scan(
+        step, jnp.zeros((b, h, dk, v.shape[-1]), jnp.float32), xs)
+    return jnp.moveaxis(o, 0, 1) * dk ** -0.5
+
+
+def operands_a_head(seed, batch, seq, heads, decay, beta=None,
+                    dtype=jnp.float32, dk=24, dv=40):
+    """``operands`` with ``v`` of its own width and ``g`` a number a
+    head and position."""
+    q, k, _, g, step = operands(seed, batch, seq, heads, decay, beta, d=dk)
+    v = jax.random.normal(
+        jax.random.fold_in(jax.random.key(seed), 7), (batch, seq, heads, dv))
+    return (q.astype(dtype), k.astype(dtype), v.astype(dtype),
+            g[..., 0], step)
+
+
+def on_heads_a_head(fn):
+    def folded(q, k, v, g, beta):
+        return fn(rows(q), rows(k), rows(v), g, beta).reshape(v.shape)
+
+    return folded
+
+
+PATHS_A_HEAD = {
+    "plain": delta_rule.gated_delta_rule_plain,
+    "kernels": on_heads_a_head(kernels.delta_rule_tpu),
+}
+#: (sequence, decay, beta) as ``CASES``, and a decay of 30 a step at
+#: every position, which a floor at -10 would change and none touches
+CASES_A_HEAD = {
+    **CASES,
+    "g of -30 a step": (128, (30.0,), None),
+    "g down to -30": (192, 30.0, None),
+    "beta at 2": (128, 0.3, 2.0),
+}
+#: (dk, dv): unequal and neither a power of two; the cell's; square
+WIDTHS = [(24, 40), (96, 192), (128, 128)]
+
+
+#: the plain path at the cases its one line of decay can differ by
+#: (its jitted walk is the slow side here), the kernels at every one
+PATHS_AND_CASES_A_HEAD = [
+    ("plain", case) for case in (
+        "many chunks", "g of -30 a step", "g down to -30", "beta at 2")
+] + [("kernels", case) for case in CASES_A_HEAD]
+
+
+@pytest.mark.parametrize("path,case", PATHS_AND_CASES_A_HEAD)
+def test_one_decay_a_head_is_the_recurrence(path, case):
+    """``o`` and the five gradients, ``g``'s and ``beta``'s a number a
+    head, against the walk position by position at ``dk != dv``: the
+    plain path and the kernels in interpret mode, three heads (a grid
+    step of one) on the plain path's side and two on the kernels'."""
+    seq, decay, beta = CASES_A_HEAD[case]
+    args = operands_a_head(11, 2, seq, 2, decay, beta)
+    cotangent = jax.random.normal(jax.random.key(5), args[2].shape)
+    want_o, want = with_gradients(recurrence_a_head, args, cotangent)
+    got_o, got = with_gradients(PATHS_A_HEAD[path], args, cotangent)
+    assert abs(float(got_o - want_o)) < 1e-4 * (1 + abs(float(want_o)))
+    for name, a, b in zip(NAMES, got, want):
+        assert a.shape == b.shape, name
+        # at -30 a step ``g``'s whole gradient is 4e-5 of q's or
+        # less, and float32's rounding of the sums it is a difference
+        # of shows: held to q's gradient's scale there
+        if name == "g" and "30" in case:
+            assert float(jnp.abs(a - b).max()) < 2e-5 * float(
+                jnp.abs(want[0]).max()), name
+            continue
+        assert relative(a, b) < 2e-5, (name, relative(a, b))
+
+
+@pytest.mark.parametrize("widths", WIDTHS)
+def test_the_kernels_take_a_head_of_two_widths(widths):
+    """A head of ``dk`` keys by ``dv`` values padded to whole lane
+    tiles inside ``delta_rule``: the result and every gradient are the
+    plain path's on the unpadded operands, each in its operand's
+    shape, at three heads (a grid step of one, an inverse a head)."""
+    dk, dv = widths
+    args = operands_a_head(3, 1, 128, 3, 2.0, dk=dk, dv=dv)
+    cotangent = jax.random.normal(jax.random.key(6), args[2].shape)
+    want_o, want = with_gradients(
+        delta_rule.gated_delta_rule_plain, args, cotangent)
+    got_o, got = with_gradients(PATHS_A_HEAD["kernels"], args, cotangent)
+    assert abs(float(got_o - want_o)) < 1e-4 * (1 + abs(float(want_o)))
+    for name, a, b, operand in zip(NAMES, got, want, args):
+        assert a.shape == operand.shape and a.dtype == operand.dtype, name
+        assert relative(a, b) < 2e-5, (name, relative(a, b))
+
+
+def test_a_slow_step_after_fast_ones_keeps_what_it_should():
+    """Thirty-one steps of -30 and then steps of -0.01: a pair's
+    exponent is the sum of the steps between the two positions, not
+    the difference of two cumulated sums near -930, whose float32
+    rounding (6e-5) would be the slow steps' whole decay."""
+    q, k, v, g, beta = operands_a_head(4, 1, 64, 2, (0.01,))
+    g = g.at[:, :31].set(-30.0)
+    want = recurrence_a_head(q, k, v, g, beta)
+    for path in PATHS_A_HEAD.values():
+        assert relative(path(q, k, v, g, beta), want) < 2e-6
+
+
+def test_bfloat16_operands_on_a_head_of_two_widths():
+    args = operands_a_head(8, 1, 128, 2, 1.0, dtype=jnp.bfloat16,
+                           dk=96, dv=192)
+    want = recurrence_a_head(*args)
+    for path in PATHS_A_HEAD.values():
+        got = path(*args)
+        assert got.dtype == jnp.bfloat16
+        assert relative(got, want) < 2 ** -7
+
+
+def test_the_shapes_alone_decide_the_scalar_forms_path(monkeypatch):
+    """``tiles_the_kernel`` with ``v``'s shape: whole chunks, keys of
+    at most a lane tile, values of at most ``MOST_VALUES``; what does
+    not tile takes the plain path, on the TPU's dispatch too."""
+    assert kernels.tiles_the_kernel((1, 16384, 2880), 30, (1, 16384, 5760))
+    assert kernels.tiles_the_kernel((2, 64, 48), 2, (2, 64, 80))
+    assert not kernels.tiles_the_kernel((2, 96, 48), 2, (2, 96, 80))
+    assert not kernels.tiles_the_kernel((1, 64, 2 * 160), 2, (1, 64, 80))
+    assert not kernels.tiles_the_kernel((1, 64, 48), 2, (1, 64, 2 * 320))
+    # a decay a channel: its own rule, as it was
+    assert kernels.tiles_the_kernel((1, 64, 2 * D), 2)
+    assert not kernels.tiles_the_kernel((1, 64, 2 * 96), 2)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    q = jnp.zeros((1, 96, 48))
+    assert not delta_rule._use_pallas_a_head(q, jnp.zeros((1, 96, 80)), 2)
+    assert delta_rule._use_pallas_a_head(
+        q[:, :64], jnp.zeros((1, 64, 80)), 2)
+    args = operands_a_head(2, 1, 96, 2, 1.0)  # no whole chunks
+    flat = (*(rows(x) for x in args[:3]), args[3], args[4])
+    before = _calls("head", "24x40")
+    got = delta_rule.gated_delta_rule_rows(*flat, heads=2)
+    assert _calls("head", "24x40") == before  # no kernel was built
+    assert relative(got.reshape(args[2].shape),
+                    recurrence_a_head(*args)) < 1e-5
+
+
+def test_the_rows_entry_takes_one_decay_a_head(monkeypatch):
+    """``gated_delta_rule_rows`` with ``g`` in ``beta``'s shape
+    against the entry on heads, on the kernels; the counters tell the
+    form and the two widths by their labels, and the state's gauge is
+    the padded ``[values, keys]``."""
+    from dlrover_tpu.telemetry.registry import gauge
+
+    monkeypatch.setattr(
+        delta_rule, "_use_pallas_a_head", lambda q, v, heads: True)
+    args = operands_a_head(29, 2, 128, 2, 1.0, dk=96, dv=192)
+    flat = (*(rows(x) for x in args[:3]), args[3], args[4])
+    before = _calls("head", "96x192"), _calls()
+    want = delta_rule.gated_delta_rule(*args)
+    got = delta_rule.gated_delta_rule_rows(*flat, heads=2)
+    assert got.shape == flat[2].shape
+    assert relative(got.reshape(want.shape), want) < 1e-6
+    assert relative(want, recurrence_a_head(*args)) < 1e-5
+    assert (_calls("head", "96x192"), _calls()) == (
+        (before[0][0] + 1, before[0][1] + 1), before[1])
+    assert gauge("delta_rule_heads_per_step", "").value == 2
+    assert gauge("delta_rule_state_bytes", "").value == 2 * 256 * 128 * 4
+    assert gauge("delta_rule_kept_bytes", "").value == 4 * (
+        256 * 128 + 128 * 64 + 64 * 256)
+
+
+def test_one_decay_a_head_has_no_floor(monkeypatch):
+    """The entry leaves a decay a head as it came: at -6 to -30 a
+    step the state keeps ``exp(g)`` of itself, not ``exp(G_FLOOR)``
+    where that is more, and ``g`` gets its gradient there, on both
+    paths."""
+    args = operands_a_head(6, 1, 64, 2, 30.0)
+    flat = (*(rows(x) for x in args[:3]), args[3], args[4])
+    cotangent = jax.random.normal(jax.random.key(2), args[2].shape)
+    _, want = with_gradients(recurrence_a_head, args, cotangent)
+    assert float(jnp.abs(want[3]).max()) > 0
+    for on_kernels in (False, True):
+        monkeypatch.setattr(
+            delta_rule, "_use_pallas_a_head",
+            lambda q, v, heads: on_kernels)
+        _, got = with_gradients(
+            lambda *a: delta_rule.gated_delta_rule_rows(
+                *a, heads=2).reshape(args[2].shape), flat, cotangent)
+        assert relative(got[3], want[3]) < 1e-4
+        floored = flat[:3] + (jnp.maximum(flat[3], delta_rule.G_FLOOR),
+                              flat[4])
+        assert relative(
+            delta_rule.gated_delta_rule_rows(*floored, heads=2),
+            delta_rule.gated_delta_rule_rows(*flat, heads=2)) > 1e-6
+
+
+@pytest.mark.parametrize("wrong", ["g a column", "v's positions",
+                                   "k's width", "heads"])
+def test_rows_entry_refuses_mismatched_scalar_operands(wrong):
+    q, k, v, g, beta = operands_a_head(1, 1, 64, 2, 0.5)
+    flat = [rows(q), rows(k), rows(v), g, beta]
+    heads = 2
+    if wrong == "g a column":
+        flat[3] = g[..., None]
+    elif wrong == "v's positions":
+        flat[2] = flat[2][:, :32]
+    elif wrong == "k's width":
+        flat[1] = flat[1][..., :24]
+    else:
+        heads = 4  # beta has two
+    with pytest.raises(ValueError):
+        delta_rule.gated_delta_rule_rows(*flat, heads=heads)
+
+
+def test_the_forward_takes_a_third_pair_where_the_head_count_has_one():
+    """The forward kernels' own rule: six heads a grid step where six
+    divide the count (the cell's 30), else what the backward takes;
+    every choice even where the backward's is, so the pairs whose
+    inverses the forward keeps are the pairs the backward reads: at six
+    heads (forward 6 a step, backward 2) the result and the gradients
+    are the plain path's."""
+    assert kernels.heads_a_step(30, forward=True) == 6
+    assert kernels.heads_a_step(30) == 2
+    for heads in (64, 32, 4, 2, 3, 1):
+        assert kernels.heads_a_step(heads, forward=True) == (
+            kernels.heads_a_step(heads))
+    assert all(h % 2 == 0 for h in kernels.FORWARD_HEADS_A_STEP)
+    args = operands_a_head(13, 1, 128, 6, 2.0)
+    cotangent = jax.random.normal(jax.random.key(6), args[2].shape)
+    want_o, want = with_gradients(PATHS_A_HEAD["plain"], args, cotangent)
+    got_o, got = with_gradients(PATHS_A_HEAD["kernels"], args, cotangent)
+    assert abs(float(got_o - want_o)) < 1e-4 * (1 + abs(float(want_o)))
+    for name, a, b in zip(NAMES, got, want):
+        assert relative(a, b) < 2e-5, (name, relative(a, b))

@@ -22,12 +22,16 @@ F32 = jnp.float32
 EPS = 1e-5
 
 
-#: the bodies of the one frame: the mixer's, and the heads' with the
-#: gate's bias and without
-BODIES = ["gate, norm", "norm, gate, bias", "norm, gate"]
+#: the bodies of the one frame: the mixer's, the heads' with the
+#: gate's bias and without, and the heads' with ``silu`` for the gate
+BODIES = ["gate, norm", "norm, gate, bias", "norm, gate", "norm, silu"]
+#: the bodies whose plain path rounds once, as the kernels do (the
+#: heads' sigmoid bodies keep the three roundings of the lines they
+#: took over)
+ROUNDED_ONCE = ("gate, norm", "norm, silu")
 #: the name the counters of each body's entry go by
 ENTRY = {"gate, norm": "gated_norm", "norm, gate, bias": "head_norm_gate",
-         "norm, gate": "head_norm_gate"}
+         "norm, gate": "head_norm_gate", "norm, silu": "head_norm_silu"}
 
 
 def _kernels_body(body):
@@ -55,6 +59,8 @@ def _case(dtype, batch=2, seq=64, groups=2, w=128, body="gate, norm"):
 def _plain(body, o, z, vectors, groups):
     if body == "gate, norm":
         return gated_norm.gated_group_norm_plain(o, z, *vectors, groups, EPS)
+    if body == "norm, silu":
+        return gated_norm.head_norm_silu_plain(o, z, *vectors, EPS)
     scale, bias = (*vectors, None)[:2]
     return gated_norm.head_norm_gate_plain(o, z, scale, bias, EPS)
 
@@ -62,6 +68,8 @@ def _plain(body, o, z, vectors, groups):
 def _through_the_entry(body, o, z, vectors, groups):
     if body == "gate, norm":
         return gated_norm.gated_group_norm(o, z, *vectors, groups, EPS)
+    if body == "norm, silu":
+        return gated_norm.head_norm_silu(o, z, *vectors, EPS)
     scale, bias = (*vectors, None)[:2]
     return gated_norm.head_norm_gate(o, z, scale, bias, EPS)
 
@@ -123,6 +131,52 @@ def test_the_heads_plain_path_is_the_equations(body):
         heads_apart(o, 3), scale, EPS).reshape(o.shape) * gate.astype(o.dtype))
 
 
+@pytest.mark.parametrize("heads,d", [(3, 4), (30, 192)],
+                         ids=["small", "30 heads of 192"])
+def test_the_norm_then_silu_plain_path_is_the_equations(heads, d):
+    """A head's columns over their root mean square, the one scale,
+    THEN ``silu`` of the gate's pre-activation, no bias: against numpy
+    a head at a time, at the cell's head of 192 (a lane tile and a
+    half: the plain path on the chip too) and at a small one; and the
+    other order, the mixer's, is another function."""
+    o, z, (scale,), _ = _case(F32, seq=8, groups=heads, w=d,
+                              body="norm, silu")
+    o_ = np.asarray(o).reshape(2, 8, heads, d)
+    z_ = np.asarray(z)
+    want = (o_ / np.sqrt((o_ * o_).mean(-1, keepdims=True) + EPS)
+            * np.asarray(scale)).reshape(o.shape) * z_ / (1 + np.exp(-z_))
+    got = gated_norm.head_norm_silu(o, z, scale, EPS)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    assert not kernels.tiles_the_kernel((1, 512, heads * d), heads)
+    other = gated_norm.gated_group_norm(
+        o, z, jnp.tile(scale, heads), heads, EPS)
+    assert float(jnp.abs(other - got).max()) > 1e-2
+    sigmoid = gated_norm.head_norm_gate(o, z, scale, None, EPS)
+    assert float(jnp.abs(sigmoid - got).max()) > 1e-2
+
+
+def test_head_sums_by_product_are_the_views():
+    """``ops/kda_conv.py head_sums`` on the TPU for a head of no whole
+    lane tiles: two thin products in the view's place, the same sums
+    (30 heads of 192, and of 96) and, through the plain path, the
+    same result and gradients."""
+    from dlrover_tpu.ops import kda_conv
+
+    for d in (192, 96):
+        x = jax.random.normal(jax.random.key(d), (2, 16, 30 * d)) ** 2
+        np.testing.assert_allclose(
+            kda_conv.head_sums_by_product(x, 30), kda_conv.head_sums(x, 30),
+            rtol=1e-6)
+    o, z, (scale,), dy = _case(F32, seq=16, groups=30, w=192,
+                               body="norm, silu")
+    want = _plain_with_gradients("norm, silu", o, z, (scale,), dy, 30)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        got = _plain_with_gradients("norm, silu", o, z, (scale,), dy, 30)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
+
+
 @pytest.mark.parametrize("batch,seq,groups,w,rows,walk", [
     (1, 16, 1, 128, None, None),   # one block, one walk, one group
     (2, 64, 2, 128, 16, 16),       # four blocks of time, two groups
@@ -148,7 +202,7 @@ def test_the_kernels_agree_with_the_plain_path(body, dtype, batch, seq,
     got = _kernels_with_gradients(
         body, o, z, vectors, dy, groups=groups, rows=rows, walk=walk)
     assert len(got) == len(want) == 3 + len(vectors)
-    if dtype != F32 and body != "gate, norm":
+    if dtype != F32 and body not in ROUNDED_ONCE:
         wide = _plain_with_gradients(
             body, o.astype(F32), z.astype(F32), vectors, dy.astype(F32),
             groups)
@@ -169,10 +223,11 @@ def test_the_kernels_agree_with_the_plain_path(body, dtype, batch, seq,
             # last place of the larger of the two
             half = 2.0 ** -8 * jnp.maximum(jnp.abs(a), jnp.abs(b))
             over = jnp.abs(a - b) - half
-            if groups >= 32:
-                # of the cells' 65,536 and 262,144 elements a few lie
-                # where two orders of a float32 sum straddle a
-                # rounding: a whole unit there
+            if groups >= 32 or (body == "norm, silu" and w > 128):
+                # of the cells' 65,536 and 262,144 elements (and of
+                # the 262,144 of the ``silu`` body's two lane tiles a
+                # head) a few lie where two orders of a float32 sum
+                # straddle a rounding: a whole unit there
                 assert float(jnp.mean(over > 2.0 ** -9)) <= 1e-4, name
                 over = over - half
             assert float(jnp.max(over)) <= 2.0 ** -9, name
@@ -189,7 +244,7 @@ def test_bf16_operands_are_rounded_once(body):
     got = _kernels_with_gradients(body, o, z, vectors, dy, groups=2, rows=16)
     plain = _plain(body, o, z, vectors, 2)
     assert plain.dtype == jnp.bfloat16
-    if body == "gate, norm":
+    if body in ROUNDED_ONCE:
         np.testing.assert_array_equal(plain, wide[0].astype(jnp.bfloat16))
     for a, b in zip(got[:3], wide[:3]):
         assert a.dtype == jnp.bfloat16

@@ -265,3 +265,65 @@ def test_the_custom_rule_hands_the_bias_its_gradient():
             through(lambda x, w, b: kda_conv.conv_silu_norm_plain(
                 x, w, None, b))):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=2e-5)
+
+
+# -- a head of 96 in rows of 2,880 (a Gated DeltaNet layer's q and k) ---------
+
+#: (x's shape, w's, heads, whether the kernels take them): the new
+#: cell's q and k, whose head is three quarters of a lane tile and
+#: whose rows are 22.5 tiles, take the plain path; its v tiles as it is
+GDN_SHAPES = {
+    "q k": ((1, 16384, 2880), (2880, 4), 30, False),
+    "v": ((1, 16384, 5760), (5760, 4), None, True),
+    "rows of 2,880 without the norm": (
+        (1, 16384, 2880), (2880, 4), None, False),
+}
+
+
+@pytest.mark.parametrize("name", list(GDN_SHAPES))
+def test_a_gated_delta_net_layers_shapes_decide_their_paths(name):
+    x_shape, w_shape, l2_heads, tiles = GDN_SHAPES[name]
+    assert kernels.tiles_the_kernel(x_shape, w_shape, l2_heads) is tiles
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_a_head_of_96_in_rows_of_2880_is_the_equations(backend, monkeypatch):
+    """30 heads of 96 columns, four taps: the plain path against numpy
+    a head at a time, forward; off the TPU through the view of a
+    head's columns and, where a TPU process stands, through
+    ``head_sums_by_product`` (no view of a head of 96 exists there
+    without a copy): the same numbers and the same gradients."""
+    x, w, dy = _case(F32, batch=2, seq=16, heads=30, d=96)
+    want = _plain_with_gradients(x, w, dy, 30)
+    a = np.zeros(x.shape, np.float32)
+    for j in range(4):
+        a[:, 3 - j:] += np.asarray(w)[:, j] * np.asarray(x)[:, :16 - (3 - j)]
+    s = (a / (1 + np.exp(-a))).reshape(2, 16, 30, 96)
+    unit = s / np.sqrt((s * s).sum(-1, keepdims=True) + kda_conv.L2_NORM_EPS)
+    np.testing.assert_allclose(
+        want[0], unit.reshape(x.shape), rtol=2e-5, atol=2e-6)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    before = _calls()
+    got = kda_conv.conv_silu_norm(x, w, l2_heads=30)
+    assert _calls() == (before[0], before[1] + 1)  # plain on either
+    np.testing.assert_allclose(got, want[0], rtol=2e-5, atol=2e-6)
+    for a, b in zip(_plain_with_gradients(x, w, dy, 30), want):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
+
+
+def test_head_sums_take_the_view_where_it_is_the_same_bytes(monkeypatch):
+    """A head of whole lane tiles, or any head off the TPU, is summed
+    through ``heads_apart``'s view; only a head of no whole lane tiles
+    on the TPU goes through the two products."""
+    seen = []
+    monkeypatch.setattr(
+        kda_conv, "head_sums_by_product",
+        lambda x, heads: seen.append(heads) or x)
+    x = jnp.ones((1, 8, 256))
+    kda_conv.head_sums(x, 2), kda_conv.head_sums(x[..., :192], 2)
+    assert seen == []
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    kda_conv.head_sums(x, 2)  # heads of 128
+    assert seen == []
+    kda_conv.head_sums(x[..., :192], 2)  # heads of 96
+    assert seen == [2]

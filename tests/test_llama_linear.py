@@ -346,8 +346,11 @@ def test_the_model_runs_the_kernels_where_they_tile(monkeypatch):
 def _calls():
     from dlrover_tpu.telemetry.registry import counter
 
-    return (counter("delta_rule_rows_calls", "").value,
-            counter("delta_rule_folded_calls", "").value)
+    labels = ("decay", "head")  # ops/pallas/delta_rule.py CALL_LABELS
+    return tuple(
+        counter(f"delta_rule_{handed}_calls", "", labels).labels(
+            decay="channel", head="128x128").value
+        for handed in ("rows", "folded"))
 
 
 def test_the_steps_scans_are_handed_rows(monkeypatch):
